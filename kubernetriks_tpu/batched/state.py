@@ -687,7 +687,7 @@ def compare_states(a: ClusterBatchState, b: ClusterBatchState) -> list:
     paths of mismatching leaves (empty list = parity).
 
     The single comparison predicate shared by the suite's interpret-mode
-    Pallas tests and scripts/check_tpu_parity.py's on-hardware check.
+    Pallas tests and chip_smoke.py's on-hardware check.
     """
     flat_a, tdef_a = jax.tree_util.tree_flatten_with_path(a)
     flat_b, tdef_b = jax.tree_util.tree_flatten_with_path(b)

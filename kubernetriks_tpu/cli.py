@@ -177,6 +177,9 @@ def build_batched_simulation(
 def run_batched(config: SimulationConfig, args) -> int:
     import time
 
+    from kubernetriks_tpu.compile_cache import place_compile_cache
+
+    place_compile_cache()
     sim = build_batched_simulation(
         config, args.clusters, args.max_pods_per_cycle, args.pod_window
     )

@@ -1,7 +1,7 @@
 """CLI: `python -m kubernetriks_tpu.lint [paths...]`.
 
 Default scope is the repo's lintable surface: the package, bench.py,
-tests/, scripts/ and experiments/ (the self-test fixtures under
+chip_smoke.py, tests/, scripts/ and experiments/ (the self-test fixtures under
 tests/lint_fixtures/ are excluded — they hold seeded violations on
 purpose; pass their paths explicitly to lint them, as tests/test_lint.py
 does). Exit status: 0 clean, 1 violations (or stale waivers under
@@ -33,6 +33,7 @@ from kubernetriks_tpu.lint import (
 DEFAULT_SCOPE = (
     "kubernetriks_tpu",
     "bench.py",
+    "chip_smoke.py",
     "tests",
     "scripts",
     "experiments",

@@ -32,7 +32,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as jax_enable_x64_ctx
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -41,8 +40,6 @@ _SUB = 8  # f32/i32 sublane tile
 _BIG_I32 = np.iinfo(np.int32).max
 _VMEM_LIMIT = 100 * 1024 * 1024
 
-# pltpu.CompilerParams in newer JAX, TPUCompilerParams in the 0.4.x line.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def ca_down_kernel_fits(n_nodes: int, n_slots: int, k_sd: int) -> bool:
@@ -235,7 +232,7 @@ def fused_ca_scale_down(
     slot_spec = pl.BlockSpec((Sp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     sk_spec = pl.BlockSpec((SKp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         removed_o = pl.pallas_call(
             functools.partial(_ca_down_kernel, k_sd),
             grid=(Cp // _LANE,),
@@ -246,7 +243,7 @@ def fused_ca_scale_down(
                 pltpu.VMEM((Np, _LANE), jnp.int32),
                 pltpu.VMEM((Np, _LANE), jnp.int32),
             ],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT
             ),
             interpret=interpret,
@@ -448,7 +445,7 @@ def fused_ca_scale_up(
     group_spec = pl.BlockSpec((Gp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec((Kp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         planned_o, gpl_o, starved_o = pl.pallas_call(
             _ca_up_kernel,
             grid=(Cp // _LANE,),
@@ -465,7 +462,7 @@ def fused_ca_scale_up(
                 pltpu.VMEM((Sp, _LANE), jnp.int32),
                 pltpu.VMEM((_SUB, _LANE), jnp.int32),
             ],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT
             ),
             interpret=interpret,

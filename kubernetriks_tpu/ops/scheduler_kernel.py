@@ -30,8 +30,8 @@ count, which lax.scan formulations cannot express.
 The decision kernels return per-candidate outputs; the cheap (C,)-shaped
 timing/metric mechanics stay in step.py where they replicate the scan
 path's float-op ordering bit for bit. Parity: interpret-mode unit tests +
-full-sim equivalence in tests/test_pallas_kernel.py, on-hardware 3-way
-check in scripts/check_tpu_parity.py.
+full-sim equivalence in tests/test_pallas_kernel.py, on-hardware check
+against the lax.scan engine in chip_smoke.py.
 """
 
 from __future__ import annotations
@@ -42,7 +42,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as jax_enable_x64_ctx
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -59,8 +58,6 @@ _NEG_INF = float(np.float32(-np.inf))
 _LANE = 128  # clusters per grid program (lane tile)
 _SUB = 8  # f32/i32 sublane tile
 
-# pltpu.CompilerParams in newer JAX, TPUCompilerParams in the 0.4.x line.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def default_enabled() -> bool:
@@ -71,10 +68,7 @@ def default_enabled() -> bool:
     env = flag_tristate("KUBERNETRIKS_PALLAS")
     if env is not None:
         return env
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # Conservative per-core VMEM budget for the kernel's resident blocks; real
@@ -387,7 +381,7 @@ def fused_select_schedule_cycle(
     kernel = functools.partial(
         _select_cycle_kernel, N, K, profile or DEFAULT_PROFILE
     )
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         cpu_o, ram_o, cand_o, valid_o, assign_o, fitany_o, best_o = pl.pallas_call(
             kernel,
             grid=(Cp // _LANE,),
@@ -403,7 +397,7 @@ def fused_select_schedule_cycle(
                 jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
             ],
             scratch_shapes=[pltpu.VMEM((Pp, _LANE), jnp.int32)],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
             interpret=interpret,
@@ -549,7 +543,7 @@ def fused_free_resources(
     pod_spec = pl.BlockSpec((Pp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     stats_spec = pl.BlockSpec((8, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         acpu_o, aram_o, stats_o = pl.pallas_call(
             _free_kernel,
             grid=(Cp // _LANE,),
@@ -561,7 +555,7 @@ def fused_free_resources(
                 jax.ShapeDtypeStruct((8, Cp), jnp.float32),
             ],
             scratch_shapes=[pltpu.VMEM((Pp, _LANE), jnp.int32)],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
             interpret=interpret,
@@ -727,14 +721,14 @@ def fused_event_scatter(
         jax.ShapeDtypeStruct((Pp, Cp), jnp.int32),
         jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
     ]
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         created_o, nrm_o, pcr_o, pseq_o, prm_o = pl.pallas_call(
             _event_kernel,
             grid=(Cp // _LANE,),
             in_specs=[spec(Ep)] * 5 + [spec(Np)] * 2 + [spec(Pp)] * 3,
             out_specs=[spec(Np)] * 2 + [spec(Pp)] * 3,
             out_shape=shapes,
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
             interpret=interpret,
@@ -862,7 +856,7 @@ def fused_commit_scatter(
     def spec(n_sub):
         return pl.BlockSpec((n_sub, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         phase_o, node_o, start_o, park_o = pl.pallas_call(
             _commit_kernel,
             grid=(Cp // _LANE,),
@@ -874,7 +868,7 @@ def fused_commit_scatter(
                 jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
                 jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
             ],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
             interpret=interpret,
@@ -943,9 +937,7 @@ def fused_schedule_cycle(
     # jax_enable_x64 for its f64 time arrays, but under x64 pallas_call's own
     # index bookkeeping traces as i64, which Mosaic fails to legalize
     # (func.return). Everything crossing this boundary is i32/bool.
-    # (jax.experimental.enable_x64: the installed 0.4.x has no top-level
-    # jax.enable_x64.)
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         cpu_o, ram_o, assign_o, fitany_o, best_o = pl.pallas_call(
             kernel,
             grid=(Cp // _LANE,),
@@ -1201,7 +1193,7 @@ def fused_select_cycle_commit(
     kernel = functools.partial(
         _select_cycle_commit_kernel, N, K, profile or DEFAULT_PROFILE
     )
-    with jax_enable_x64_ctx(False):
+    with jax.enable_x64(False):
         (cpu_o, ram_o, phase_o, node_o, start_o, park_o, stats_o) = pl.pallas_call(
             kernel,
             grid=(Cp // _LANE,),
@@ -1217,7 +1209,7 @@ def fused_select_cycle_commit(
                 jax.ShapeDtypeStruct((8, Cp), jnp.float32),
             ],
             scratch_shapes=[pltpu.VMEM((Pp, _LANE), jnp.int32)],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
             interpret=interpret,

@@ -27,37 +27,6 @@ from jax.sharding import Mesh
 from kubernetriks_tpu.sanitize import assert_sync_allowed
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """jax.shard_map across the installed-JAX API drift (the jax.enable_x64
-    / pltpu.CompilerParams treatment, PR 3): newer lines expose a top-level
-    jax.shard_map with `check_vma`; the 0.4.x line ships it as
-    jax.experimental.shard_map.shard_map with the same semantics under
-    `check_rep`. ONE shim so every caller (step._shard_rowwise, the RL
-    attention policy, tests) stays on one spelling."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
-def _distributed_is_initialized() -> bool:
-    """jax.distributed.is_initialized across the API drift: absent on the
-    installed 0.4.x line, where the client object's existence is the
-    equivalent signal."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    state = getattr(jax.distributed, "global_state", None)
-    return state is not None and getattr(state, "client", None) is not None
-
-
 def initialize_from_env(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -71,7 +40,7 @@ def initialize_from_env(
     False, and a repeated call after the runtime (or backend) already
     started returns whether a multi-process runtime is active instead of
     surfacing jax's RuntimeError."""
-    if _distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     try:
         jax.distributed.initialize(

@@ -102,11 +102,7 @@ def ring_attention(
     # queries' attention), but zeros/full literals trace as unvarying —
     # cast them to q's full varying-axis set (e.g. data AND seq on a 2D+
     # mesh) so the fori_loop carry types match the body's outputs.
-    # jax.typeof is the new-API spelling; the installed 0.4.x line has
-    # neither typeof nor varying-axis tracking (shard_map there uses
-    # check_rep), so vma degrades to () and `varying` is the identity.
-    _typeof = getattr(jax, "typeof", None)
-    vma = tuple(getattr(_typeof(q), "vma", ())) if _typeof is not None else ()
+    vma = tuple(jax.typeof(q).vma)
 
     def varying(x):
         return jax.lax.pcast(x, vma, to="varying") if vma else x

@@ -179,16 +179,8 @@ def make_sharded_apply(
     )
     out_specs = (P(data_axis, seq_axis), P(data_axis))
 
-    from kubernetriks_tpu.parallel.multihost import shard_map
-
     return jax.jit(
-        shard_map(
-            fwd, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            # Full varying-axis checking on the new API; the 0.4.x line's
-            # check_rep has a known replication-inference bug for
-            # grad-of-scan (its own error text prescribes check_rep=False),
-            # so checking is off exactly there. Forward/backward parity is
-            # pinned numerically by tests/test_parallel.py either way.
-            check_vma=hasattr(jax, "shard_map"),
+        jax.shard_map(
+            fwd, mesh=mesh, in_specs=in_specs, out_specs=out_specs
         )
     )

@@ -13,7 +13,7 @@ framework invariants; the sanitizer enforces them on a live run:
   runtime budget are the same list.
 
   The CPU backend never fires jax's transfer guard (host-resident
-  buffers make every d2h read zero-copy, measured on jax 0.4.37), so the
+  buffers make every d2h read zero-copy, checked on jax 0.9.0), so the
   guard alone has no teeth on CPU CI. The sanitizer therefore ALSO keeps
   its own thread-local guard depth, and `to_host` — the framework's d2h
   convention (parallel/multihost.py) — asserts through

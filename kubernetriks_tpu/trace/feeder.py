@@ -9,13 +9,15 @@ tensors. The pure-Python pipeline in kubernetriks_tpu.trace.alibaba has
 identical semantics and serves as both fallback and oracle.
 
 The shared library is built on demand with g++ (cached next to the source,
-keyed on source mtime); if no toolchain is available the callers fall back to
-the Python path.
+keyed on a hash of the source — a copied tree does not keep mtimes, and a
+stray library built from other source must never load); if no toolchain is
+available the callers fall back to the Python path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,27 +28,31 @@ import numpy as np
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SOURCE = os.path.join(_REPO_ROOT, "native", "trace_feeder.cc")
-_LIB = os.path.join(_REPO_ROOT, "native", "build", "libtrace_feeder.so")
+_BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
 
 
-def _build_library() -> Optional[str]:
-    """Compile the feeder if missing or stale. Returns an error string or None."""
+def _build_library() -> Tuple[Optional[str], Optional[str]]:
+    """Compile the feeder unless the library for exactly this source is
+    already built. Returns (library path, None) or (None, error string)."""
     try:
-        os.makedirs(os.path.dirname(_LIB), exist_ok=True)
+        os.makedirs(_BUILD_DIR, exist_ok=True)
         if not os.path.exists(_SOURCE):
-            return f"feeder source not found: {_SOURCE}"
-        if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SOURCE):
-            return None
+            return None, f"feeder source not found: {_SOURCE}"
+        with open(_SOURCE, "rb") as fh:
+            digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+        lib = os.path.join(_BUILD_DIR, f"libtrace_feeder.{digest}.so")
+        if os.path.exists(lib):
+            return lib, None
     except OSError as exc:
-        return f"cannot stage native build dir: {exc}"
+        return None, f"cannot stage native build dir: {exc}"
     # Build to a per-process temp path, then rename into place: concurrent
     # builders (pytest workers, parallel CLI runs) must never dlopen a
     # half-written .so.
-    tmp = f"{_LIB}.tmp.{os.getpid()}"
+    tmp = f"{lib}.tmp.{os.getpid()}"
     cmd = [
         "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
         _SOURCE, "-o", tmp,
@@ -54,17 +60,17 @@ def _build_library() -> Optional[str]:
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
         if proc.returncode != 0:
-            return f"g++ failed: {proc.stderr[-2000:]}"
-        os.replace(tmp, _LIB)
+            return None, f"g++ failed: {proc.stderr[-2000:]}"
+        os.replace(tmp, lib)
     except (OSError, subprocess.TimeoutExpired) as exc:
-        return f"g++ invocation failed: {exc}"
+        return None, f"g++ invocation failed: {exc}"
     finally:
         if os.path.exists(tmp):
             try:
                 os.remove(tmp)
             except OSError:
                 pass
-    return None
+    return lib, None
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -72,11 +78,11 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        err = _build_library()
+        path, err = _build_library()
         if err is not None:
             _build_error = err
             return None
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(path)
         lib.feeder_parse_workload.restype = ctypes.c_void_p
         lib.feeder_parse_workload.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
         lib.feeder_parse_machines.restype = ctypes.c_void_p

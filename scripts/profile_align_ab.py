@@ -1,6 +1,6 @@
 """Interleaved A/B of the 128-aligned pod axis at the headline shape
 (1024 x 256-node clusters): aligned (P -> 2048) vs exact-width (P=2026)
-builds alternate chunks in ONE process (tunnel variance discipline).
+builds alternate chunks in ONE process.
 
 Usage: python scripts/profile_align_ab.py [rounds]
 """

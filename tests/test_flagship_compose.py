@@ -122,11 +122,8 @@ def test_flagship_composition_on_mesh(mixed_traces, full_run, mesh):
     per-shard Pallas kernel (interpret mode on the CPU platform) — matches
     the full-resident unsharded scan run.
 
-    `slow`: this test FAILED from the seed onward (jax.shard_map API drift
-    — see docs/DESIGN.md §"Known suite xfails") so tier-1 never carried
-    its ~20 s; now that r9's multihost.shard_map shim fixed it, the heavy
-    sliding+mesh+interpret combination runs in the slow suite while
-    test_pallas_shard_map_matches_scan_on_mesh (also newly fixed, ~3x
+    `slow`: the heavy sliding+mesh+interpret combination (~20 s) runs in
+    the slow suite while test_pallas_shard_map_matches_scan_on_mesh (~3x
     cheaper) keeps per-shard kernel mesh coverage in tier-1."""
     sim = _build(
         mixed_traces,

@@ -8,7 +8,6 @@ cross-process mesh."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kubernetriks_tpu.parallel.multihost import (
@@ -38,17 +37,6 @@ def test_initialize_from_env_is_noop_without_coordinator():
     subprocess.run([sys.executable, "-c", code], env=env, check=True, timeout=120)
 
 
-@pytest.mark.xfail(
-    reason=(
-        "installed jaxlib 0.4.x CPU backend cannot run cross-process "
-        "computations (multihost_utils.process_allgather -> "
-        "'Multiprocess computations aren't implemented on the CPU "
-        "backend') — the worker's to_host allgather dies inside jax, not "
-        "in framework code. Passes on real multi-host backends / newer "
-        "jaxlib; see docs/DESIGN.md §'Known suite xfails'."
-    ),
-    strict=False,
-)
 def test_two_process_cross_process_branches():
     """Two jax.distributed CPU processes (4 virtual devices each, one
     8-device world): put_global assembles global arrays from per-process

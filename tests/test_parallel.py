@@ -15,7 +15,6 @@ from kubernetriks_tpu.rl.attention_policy import (
     make_sharded_apply,
 )
 from kubernetriks_tpu.rl.policy import NODE_FEATURES
-from kubernetriks_tpu.parallel.multihost import shard_map
 
 
 def _seq_mesh(n):
@@ -37,7 +36,7 @@ def test_ring_attention_matches_full_attention():
 
     mesh = _seq_mesh(8)
     ring = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda q, k, v, m: ring_attention(q, k, v, m, "seq"),
             mesh=mesh,
             in_specs=(
@@ -61,7 +60,7 @@ def test_ring_attention_fully_masked_rows_are_zero():
 
     mesh = _seq_mesh(8)
     got = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda q, k, v, m: ring_attention(q, k, v, m, "seq"),
             mesh=mesh,
             in_specs=(

@@ -98,7 +98,7 @@ def test_consume_donated_makes_read_after_donate_crash():
                            donate_argnums=(0,))
     state = {"a": jnp.arange(4), "b": jnp.ones((2, 2))}
     out = donated_step(state)
-    # jax 0.4.37's CPU runtime happens to implement donation (inputs come
+    # jax 0.9.0's CPU runtime implements donation (inputs come
     # back is_deleted) — consume_donated then force-deletes nothing and the
     # read already raises; on runtimes where donation is a no-op it deletes
     # the survivors. Either way the invariant below holds on every backend.
