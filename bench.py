@@ -1274,8 +1274,8 @@ def run_open_loop(
             span_windows=span_windows if lane_async else None,
             # Flight recorder on BOTH fleets so the A/B timing compares
             # identical window programs (the ring record is in-graph);
-            # the async side's lane_active column cross-checks the host
-            # occupancy ledger (ring_lane_occupancy in the record) and
+            # the observatory's lane_occupancy entry reports the pump
+            # ledger's counters (ring_lane_occupancy in the record) and
             # the per-query latency stats flow into the observatory.
             telemetry=True,
         )

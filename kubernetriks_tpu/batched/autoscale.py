@@ -472,6 +472,7 @@ def ca_name_order(
     return sd_order, node_key
 
 
+@jax.named_scope("hpa_pass")
 def hpa_pass(
     state: ClusterBatchState,
     auto: AutoscaleState,
@@ -908,6 +909,7 @@ def _hpa_pass_body(
     )
 
 
+@jax.named_scope("ca_scale_up")
 def _ca_scale_up(
     state: ClusterBatchState,
     auto: AutoscaleState,
@@ -1107,6 +1109,7 @@ def _ca_scale_up(
     return planned, g_planned, starved
 
 
+@jax.named_scope("ca_scale_down")
 def _ca_scale_down(
     state: ClusterBatchState,
     auto: AutoscaleState,
@@ -1484,6 +1487,7 @@ def _per_group(removed, st, rows, Gn):
     return removed, removed_per_group
 
 
+@jax.named_scope("ca_pass")
 def ca_pass(
     state: ClusterBatchState,
     auto: AutoscaleState,

@@ -73,13 +73,14 @@ from kubernetriks_tpu.flags import (
 )
 from kubernetriks_tpu.telemetry import (
     GaugeSeries,
-    NULL_TRACER,
-    SpanTracer,
     log_chunk_throughput,
+    recorder,
 )
 from kubernetriks_tpu.telemetry.tracer import (
+    PH_CHUNK_FENCED,
     PH_CKPT_RESTORE,
     PH_CKPT_SAVE,
+    PH_FLEET_RESET,
     PH_FUSED_CHUNK_SLIDE,
     PH_PRECOMPILE,
     PH_PROGRESS_WAIT,
@@ -91,9 +92,11 @@ from kubernetriks_tpu.telemetry.tracer import (
     PH_STAGE_PUT,
     PH_STAGE_WAIT_FEEDER,
     PH_STAGE_WAIT_UPLOAD,
+    PH_STEP_UNTIL_TIME,
     PH_SUPERSPAN,
     PH_WINDOW_CHUNK,
     PH_WINDOW_GROW,
+    build_span,
 )
 
 
@@ -684,6 +687,7 @@ def build_autoscale_statics(
 
 
 class BatchedSimulation:
+    @build_span
     def __init__(  # ktpu: sync-ok(engine build: cold-path host compilation of traces/tables, outside every timed region)
         self,
         config: SimulationConfig,
@@ -768,18 +772,21 @@ class BatchedSimulation:
         if scheduler_profile is None:
             scheduler_profile = flag_str("KTPU_PROFILE")
         self.profile = compile_profile(scheduler_profile)
-        # Flight recorder (KTPU_TRACE / telemetry arg): host-side span
-        # tracer over every dispatch phase + the device-side per-window
-        # metrics ring carried in ClusterBatchState (attached below, once
-        # C is known). Off: NULL_TRACER no-ops and the state carries
-        # telemetry=None, compiling programs identical to the
+        # Flight recorder. The host span recorder is process-wide and
+        # always on (telemetry/tracer.py); KTPU_TRACE / the telemetry arg
+        # switch what changes compiled programs: the device-side
+        # per-window metrics ring carried in ClusterBatchState (attached
+        # below, once C is known) and the observatory. Off: the state
+        # carries telemetry=None, compiling programs identical to the
         # pre-telemetry build. telemetry_ring: ring capacity in windows
         # (the engine drains before wrap at existing sync boundaries).
         if telemetry is not None:
             self._telemetry = bool(telemetry)
         else:
             self._telemetry = flag_bool("KTPU_TRACE")
-        self.tracer = SpanTracer() if self._telemetry else NULL_TRACER
+        # This engine's handle on it: the shared ring, and aggregates of
+        # its own for telemetry_report().
+        self.tracer = recorder().handle()
         self._telemetry_ring_size = max(8, int(telemetry_ring))
         # Saturation watchdog (KTPU_WATCHDOG / watchdog arg): the capacity
         # observatory's trajectory checks over the ring's reserve-occupancy
@@ -1659,6 +1666,7 @@ class BatchedSimulation:
                 interval=config.scheduling_cycle_interval,
                 capacities=self._reserve_capacities,
                 watchdog=self._watchdog,
+                counters=self.tracer.counters,
             )
         ev_win, ev_off = from_f64_np(ev_time, config.scheduling_cycle_interval)
         self.slab = TraceSlab.build(ev_win, ev_off, ev_kind, ev_slot)
@@ -1698,11 +1706,11 @@ class BatchedSimulation:
         # engine only performs the (waived) device fetches.
         self.collect_gauges = False
         self._gauges = GaugeSeries()
-        # Profiling hooks: set profile_dir to capture a jax.profiler trace of
-        # every step_until_time dispatch; set log_throughput for a per-chunk
-        # decisions/s + cluster-windows/s log line (TPU analog of the scalar
-        # events/s log, reference: src/simulator.rs:363-368).
-        self.profile_dir: Optional[str] = None
+        # Set log_throughput for a per-chunk decisions/s +
+        # cluster-windows/s log line (TPU analog of the scalar events/s
+        # log, reference: src/simulator.rs:363-368). For a profile, start
+        # jax.profiler round ordinary calls: every span of the recorder
+        # is a `ktpu:<phase>` event on the xplane's host plane.
         self.log_throughput = False
         # Raise at readout when a documented autoscaler work bound was
         # crossed (HPA reserve clamp, CA slot-reserve exhaustion) instead of
@@ -2021,7 +2029,7 @@ class BatchedSimulation:
         if fuse_slide:
             self.dispatch_stats["fused_slides"] += 1
             fn = _fused_chunk_slide_donated if self.donate else _fused_chunk_slide
-            t0 = tr.begin()
+            t0 = tr.begin(PH_FUSED_CHUNK_SLIDE)
             state, new_rank, s = fn(
                 self.state,
                 self.slab,
@@ -2061,7 +2069,7 @@ class BatchedSimulation:
             )
 
             skip_fn = run_windows_skip_donated if self.donate else run_windows_skip
-            t0 = tr.begin()
+            t0 = tr.begin(PH_WINDOW_CHUNK)
             self.state = skip_fn(
                 self.state,
                 self.slab,
@@ -2079,7 +2087,7 @@ class BatchedSimulation:
         from kubernetriks_tpu.batched.step import run_windows_donated
 
         win_fn = run_windows_donated if self.donate else run_windows
-        t0 = tr.begin()
+        t0 = tr.begin(PH_WINDOW_CHUNK)
         out = win_fn(
             self.state,
             self.slab,
@@ -2136,7 +2144,7 @@ class BatchedSimulation:
             # warm it instead of the ladder; a no-op progress code compiles
             # the whole while_loop without executing a window. Dispatched
             # against a scratch copy like the ladder shapes (donation).
-            t_warm = self.tracer.begin()
+            t_warm = self.tracer.begin(PH_PRECOMPILE)
             stage, lo = self._current_stage()
             rank = (
                 self.autoscale_statics.pod_name_rank
@@ -2168,7 +2176,7 @@ class BatchedSimulation:
 
         win_fn = run_windows_donated if self.donate else run_windows
         n = 0
-        t_warm = self.tracer.begin()
+        t_warm = self.tracer.begin(PH_PRECOMPILE)
         warm_fused = self._fused_slide_ok()
         for chunk in _CHUNK_LADDER:
             if chunk > max_chunk:
@@ -2286,6 +2294,13 @@ class BatchedSimulation:
         boundary. An explicit lane list resets only those state rows and
         leaves the clock alone (only meaningful while the clock is at a
         wave boundary; the window clock is fleet-global)."""
+        t0 = self.tracer.begin(PH_FLEET_RESET)
+        try:
+            self._fleet_reset_impl(lanes)
+        finally:
+            self.tracer.end(PH_FLEET_RESET, t0)
+
+    def _fleet_reset_impl(self, lanes) -> None:
         from kubernetriks_tpu.batched.fleet import _reset_lanes
 
         if self._pristine is None:
@@ -2489,7 +2504,7 @@ class BatchedSimulation:
         if int(span) not in sizes:
             sizes.insert(0, int(span))
         n = 0
-        t_warm = self.tracer.begin()
+        t_warm = self.tracer.begin(PH_PRECOMPILE)
         for chunk in sizes:
             idxs = jnp.full((chunk,), self.next_window_idx, jnp.int32)
             for freeze in (False, True):
@@ -2547,6 +2562,13 @@ class BatchedSimulation:
         KTPU_SANITIZE it runs inside a device-to-host transfer guard — any
         sync not inside an explicit sanitize.allow_transfer scope (the
         runtime mirror of the lint pass's sync-ok waivers) raises."""
+        t0 = self.tracer.begin(PH_STEP_UNTIL_TIME)
+        try:
+            self._step_until_time_guarded(until_time)
+        finally:
+            self.tracer.end(PH_STEP_UNTIL_TIME, t0)
+
+    def _step_until_time_guarded(self, until_time: float) -> None:
         if self.state.telemetry is not None:
             # Entry-side wrap guard (host arithmetic only): the incoming
             # span's window count is known here, so drain the undrained
@@ -2666,8 +2688,8 @@ class BatchedSimulation:
         """Whether the steady-state loop can dispatch superspans: needs the
         sliding window and the plain run_windows dispatch mode (fast-forward
         and gauge collection keep their own programs), and steps aside for
-        the per-chunk instrumentation paths — profiling and throughput logs
-        want ladder-granular timings, and the ladder is bit-identical.
+        the per-chunk throughput log, which wants ladder-granular timings
+        (the ladder is bit-identical).
         KTPU_DEBUG_FINITE keeps the ladder too: its promise is per-chunk
         NaN/inf localization, and a superspan only surfaces state once per
         up-to-K spans."""
@@ -2676,7 +2698,6 @@ class BatchedSimulation:
             and self.pod_window is not None
             and not self.fast_forward
             and not self.collect_gauges
-            and not self.profile_dir
             and not self.log_throughput
             and not self._debug_finite
         )
@@ -2758,12 +2779,13 @@ class BatchedSimulation:
         [lo, lo + width) ON the engine thread (the non-streaming bounded
         path); the streaming feeder builds slabs through the same two
         halves off-thread."""
-        t0 = self.tracer.begin()
+        ordinal = self.dispatch_stats["superspans"]
+        t0 = self.tracer.begin(PH_STAGE_ASSEMBLE)
         seg = self._stage_arrays(lo, width)
-        self.tracer.end(PH_STAGE_ASSEMBLE, t0)
-        t0 = self.tracer.begin()
+        self.tracer.end(PH_STAGE_ASSEMBLE, t0, ident=ordinal)
+        t0 = self.tracer.begin(PH_STAGE_PUT)
         stage = self._stage_upload(seg)
-        self.tracer.end(PH_STAGE_PUT, t0)
+        self.tracer.end(PH_STAGE_PUT, t0, ident=ordinal)
         return stage
 
     # --- streaming feeder lifecycle ----------------------------------------
@@ -2957,7 +2979,9 @@ class BatchedSimulation:
             while True:
                 try:
                     stage, lo, fresh = feeder.get_stage(
-                        self._pod_base, tracer=self.tracer
+                        self._pod_base,
+                        self.tracer,
+                        ident=self.dispatch_stats["superspans"],
                     )
                     break
                 except FeederProducerError as err:
@@ -3022,9 +3046,11 @@ class BatchedSimulation:
             return
         if self._stage_next is not None and self._stage_next[0] == lo_pred:
             return
-        t0 = self.tracer.begin()
+        t0 = self.tracer.begin(PH_STAGE_PREFETCH)
         self._stage_next = (lo_pred, self._make_stage(lo_pred, Lw))
-        self.tracer.end(PH_STAGE_PREFETCH, t0)
+        self.tracer.end(
+            PH_STAGE_PREFETCH, t0, ident=self.dispatch_stats["superspans"]
+        )
 
     def _run_superspans(self, target: int) -> None:
         """The superspan dispatch loop: one device program per up-to-K
@@ -3051,7 +3077,8 @@ class BatchedSimulation:
             donated_in = (
                 self.state if (self.donate and self._sanitize) else None
             )
-            t0 = tr.begin()
+            ordinal = self.dispatch_stats["superspans"]
+            t0 = tr.begin(PH_SUPERSPAN)
             state, rank, progress = fn(
                 self.state,
                 rank,
@@ -3066,7 +3093,7 @@ class BatchedSimulation:
                 chunk=self._superspan_chunk,
                 **self._window_call_kwargs(),
             )
-            tr.end(PH_SUPERSPAN, t0)
+            tr.end(PH_SUPERSPAN, t0, ident=ordinal)
             self.state = state
             if donated_in is not None:
                 sanitize.consume_donated(donated_in)
@@ -3083,12 +3110,12 @@ class BatchedSimulation:
             # Overlap the next stage's host assembly + H2D with the device
             # program still running, BEFORE the blocking readback.
             self._prefetch_stage(lo)
-            t0 = tr.begin()
+            t0 = tr.begin(PH_PROGRESS_WAIT)
             with sanitize.allow_transfer(
                 self._sanitize, "superspan progress readback"
             ):
                 w, base, spans, code = (int(v) for v in to_host(progress))  # ktpu: sync-ok(THE steady-state sync: one async-prefetched (4,)-i32 progress readback per superspan dispatch)
-            tr.end(PH_PROGRESS_WAIT, t0)
+            tr.end(PH_PROGRESS_WAIT, t0, ident=ordinal)
             tr.flow_end(PH_PROGRESS_WAIT, fid)
             self._check_finite()
             self.dispatch_stats["slide_syncs"] += 1
@@ -3142,7 +3169,7 @@ class BatchedSimulation:
         s_arr = self._pending_shift
         self._pending_shift = None
         self.dispatch_stats["slide_syncs"] += 1
-        t0 = self.tracer.begin()
+        t0 = self.tracer.begin(PH_SHIFT_WAIT)
         with sanitize.allow_transfer(
             self._sanitize, "fused-slide shift readback"
         ):
@@ -3172,7 +3199,7 @@ class BatchedSimulation:
         ):
             return
         self.dispatch_stats["refill_prefetches"] += 1
-        t0 = self.tracer.begin()
+        t0 = self.tracer.begin(PH_REFILL_PREFETCH)
         self._refill_prefetch = (start, width, self._make_refill(start, width))
         self.tracer.end(PH_REFILL_PREFETCH, t0)
 
@@ -3215,7 +3242,7 @@ class BatchedSimulation:
         )
 
     def _advance_pod_window(self) -> bool:
-        t0 = self.tracer.begin()
+        t0 = self.tracer.begin(PH_SLIDE)
         try:
             return self._advance_pod_window_impl()
         finally:
@@ -3385,11 +3412,13 @@ class BatchedSimulation:
         return refill
 
     def _grow_pod_window(self) -> bool:
-        t0 = self.tracer.begin()
+        t0 = self.tracer.begin(PH_WINDOW_GROW)
         try:
             return self._grow_pod_window_impl()
         finally:
-            self.tracer.end(PH_WINDOW_GROW, t0)
+            self.tracer.end(
+                PH_WINDOW_GROW, t0, ident=self.dispatch_stats["superspans"]
+            )
 
     def _grow_pod_window_impl(self) -> bool:
         """Double the sliding window IN PLACE when a dense stretch of the
@@ -3587,51 +3616,35 @@ class BatchedSimulation:
         fuse_slide: bool = False,
         freeze_lanes: bool = True,
     ) -> None:
-        if not (self.profile_dir or self.log_throughput):
+        if not self.log_throughput:
             self._dispatch_windows(
                 idxs, fuse_slide=fuse_slide, freeze_lanes=freeze_lanes
             )
             self._check_finite()
             return
 
-        # Instrumented path: optional jax.profiler capture + a per-chunk
-        # decisions/s log line (TPU analog of the scalar events/s log,
-        # reference: src/simulator.rs:363-368). The per-chunk timing and
-        # log formatting live on the tracer (telemetry/tracer.py); while a
-        # profiler capture is active, tracer spans also enter
-        # jax.profiler.TraceAnnotations so host phases land in the xplane
-        # next to the device ops they dispatched
-        # (scripts/profile_composed_xplane.py correlates them).
-        import contextlib
+        # Instrumented path: a per-chunk decisions/s log line (TPU analog
+        # of the scalar events/s log, reference: src/simulator.rs:363-368),
+        # each chunk fenced so the clock measures device work.
         import logging
         import time
 
-        ctx = (
-            jax.profiler.trace(self.profile_dir)
-            if self.profile_dir
-            else contextlib.nullcontext()
-        )
-        from kubernetriks_tpu.telemetry.tracer import PH_CHUNK_FENCED
-
-        self.tracer.annotate = bool(self.profile_dir)
-        before = self._decisions_total() if self.log_throughput else 0
+        before = self._decisions_total()
         t0 = time.perf_counter()
-        with ctx, self.tracer.span(PH_CHUNK_FENCED):
+        with self.tracer.span(PH_CHUNK_FENCED):
             self._dispatch_windows(
                 idxs, fuse_slide=fuse_slide, freeze_lanes=freeze_lanes
             )
             jax.block_until_ready(self.state.time)  # ktpu: sync-ok(instrumented path: fence so the per-chunk clock measures device work, not dispatch)
         elapsed = time.perf_counter() - t0
-        self.tracer.annotate = False
         self._check_finite()
-        if self.log_throughput:
-            log_chunk_throughput(
-                logging.getLogger(__name__),
-                len(idxs),
-                self.n_clusters,
-                self._decisions_total() - before,
-                elapsed,
-            )
+        log_chunk_throughput(
+            logging.getLogger(__name__),
+            len(idxs),
+            self.n_clusters,
+            self._decisions_total() - before,
+            elapsed,
+        )
 
     def step_window(self) -> None:
         """Advance a single scheduling cycle (useful for tests)."""
@@ -4186,13 +4199,15 @@ class BatchedSimulation:
 
     def telemetry_report(self) -> Dict:
         """Aggregated flight-recorder readout: per-phase host wall time
-        (exact even when the span ring wrapped), dispatch stats incl.
+        and counters of THIS engine (its handle's own aggregates, exact
+        when the shared span ring wrapped and whatever other engines the
+        process runs), dispatch stats incl.
         ladder_fallbacks, the observed sync count vs the documented
         steady-state budget (1 progress readback per superspan + 1 shift
         readback per fused slide — the lint pass's sync-ok waiver set),
         stage-prefetch hit/miss counts, the dispatch-chunk histogram, and
-        the device ring's totals. Callable with telemetry off (dispatch
-        stats only, enabled: False)."""
+        the device ring's totals. With telemetry off (enabled: False) the
+        device-ring and observatory sections are absent."""
         feeder_rep = None
         if self._feeder is not None:
             # ONE snapshot under the feeder's lock: syncing dispatch_stats
@@ -4291,13 +4306,10 @@ class BatchedSimulation:
         return rep
 
     def write_chrome_trace(self, path: str) -> str:
-        """Write the Chrome trace-event JSON (Perfetto-loadable): host
-        spans, async-readback flow arrows, and the device ring as
-        sim-time counter tracks. Requires telemetry on."""
-        if not self._telemetry:
-            raise ValueError(
-                "telemetry is off — build with telemetry=True or KTPU_TRACE=1"
-            )
+        """Write the Chrome trace-event JSON (Perfetto-loadable): the
+        process-wide recorder's host spans and async-readback flow arrows
+        and, with telemetry on, the device ring as sim-time counter
+        tracks."""
         extra = None
         if self.state.telemetry is not None:
             from kubernetriks_tpu.telemetry import ring as dring

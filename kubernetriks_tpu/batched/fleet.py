@@ -111,11 +111,18 @@ from kubernetriks_tpu.batched.faults import (
     ShutdownError,
 )
 from kubernetriks_tpu.telemetry.histogram import LatencyHistogram
+from kubernetriks_tpu.telemetry.observatory import lane_windows_share
 from kubernetriks_tpu.telemetry.tracer import (
+    PH_LANE_DISPATCH,
     PH_LANE_QUARANTINE,
+    PH_PUMP,
+    PH_PUMP_ADMIT,
+    PH_PUMP_DRAIN,
     PH_QUERY_FAIL,
     PH_QUERY_QUEUE,
     PH_QUERY_SERVICE,
+    PH_RESULT_WAIT,
+    build_span,
 )
 
 # Lifecycle records retired at poll() survive in a bounded trail (the
@@ -440,6 +447,7 @@ class ScenarioFleet:
     boundaries.
     """
 
+    @build_span
     def __init__(
         self,
         config: SimulationConfig,
@@ -558,6 +566,7 @@ class ScenarioFleet:
         self._warm_spans: set = set()
         self.lane_busy_windows = np.zeros((self.n_lanes,), np.int64)
         self.lane_total_windows = np.zeros((self.n_lanes,), np.int64)
+        self.lane_windows_dispatched = 0  # every lane-window the device stepped
         # Fault-domain state (PR 19, DESIGN §15). Bounded admission:
         # queue depth + backpressure policy, flag defaults
         # (KTPU_FLEET_QUEUE / KTPU_FLEET_QUEUE_POLICY), unset = the
@@ -749,7 +758,7 @@ class ScenarioFleet:
         self._next_query += 1
         t_submit = time.perf_counter_ns()
         # Lifecycle birth: host stamp + the submit->drain flow arrow's id
-        # (NULL_TRACER returns 0 = no flow; all pure host, zero syncs).
+        # (all pure host, zero syncs).
         self._lifecycle[qid] = {
             "submitted_ns": t_submit,
             "flow_id": self.engine.tracer.flow_start(PH_QUERY_QUEUE),
@@ -803,6 +812,7 @@ class ScenarioFleet:
                 PH_QUERY_FAIL,
                 rec["submitted_ns"],
                 dur=t_fail - rec["submitted_ns"],
+                ident=qid,
             )
             if rec["flow_id"]:
                 tracer.flow_end(PH_QUERY_QUEUE, rec["flow_id"])
@@ -851,11 +861,16 @@ class ScenarioFleet:
         step's own sync; this is the readout ride-along, not a new
         steady-state sync)."""
         m = self.engine.state.metrics
+        # result_wait: where the host waits for the device to finish what
+        # the round (or wave) enqueued.
+        tracer = self.engine.tracer
+        t0 = tracer.begin(PH_RESULT_WAIT)
         host = {
             name: np.asarray(getattr(m, name)) for name in _RESULT_COUNTERS
         }
         host["hpa_reserve_clamped"] = np.asarray(m.hpa_reserve_clamped)
         host["ca_reserve_starved"] = np.asarray(m.ca_reserve_starved)
+        tracer.end(PH_RESULT_WAIT, t0, ident=self.pump_rounds)
         return {
             lane: {name: arr[lane].item() for name, arr in host.items()}
             for lane in lanes
@@ -990,13 +1005,18 @@ class ScenarioFleet:
         if span not in self._warm_spans:
             self.engine.precompile_lane_spans(span)
             self._warm_spans.add(span)
-        if self._sentinel is not None and self._async_warm_done:
-            with self._sentinel.expect_none(
-                f"fleet pump round {self.pump_rounds + 1} (post-warm-up)"
-            ):
+        tracer = self.engine.tracer
+        t_pump = tracer.begin(PH_PUMP)
+        try:
+            if self._sentinel is not None and self._async_warm_done:
+                with self._sentinel.expect_none(
+                    f"fleet pump round {self.pump_rounds + 1} (post-warm-up)"
+                ):
+                    drained = self._pump_inner(span)
+            else:
                 drained = self._pump_inner(span)
-        else:
-            drained = self._pump_inner(span)
+        finally:
+            tracer.end(PH_PUMP, t_pump, ident=self.pump_rounds)
         self.pump_rounds += 1
         if drained and self.pump_rounds >= 1:
             # Assign + step + drain have all run at least once: every
@@ -1006,6 +1026,7 @@ class ScenarioFleet:
 
     def _pump_inner(self, span: int) -> int:
         eng = self.engine
+        tracer = eng.tracer
         # 0. Host-boundary deadline sweep: queued-past-deadline queries
         # fail here, before they can occupy a lane. No-op (one attribute
         # read) unless a deadline was ever submitted.
@@ -1031,6 +1052,7 @@ class ScenarioFleet:
             # enforcing them mid-flight would need new device syncs).
             assigned.append((lane, *self._queue.popleft()[:3]))
         if assigned:
+            t_span = tracer.begin(PH_PUMP_ADMIT)
             for lane, qid, scen, horizon in assigned:
                 for key in SCENARIO_KEYS:
                     self._live_vectors[key][lane] = self._vectors[key][lane]
@@ -1053,10 +1075,10 @@ class ScenarioFleet:
                 eng.next_window_idx,
                 [eng.horizon_windows(h) for _, _, _, h in assigned],
             )
+            tracer.end(PH_PUMP_ADMIT, t_span, ident=self.pump_rounds)
             # Lifecycle: admitted-to-lane — close the queue-wait span
             # (submit -> here) on the tracer with an explicit duration.
             t_admit = time.perf_counter_ns()
-            tracer = eng.tracer
             for lane, qid, scen, horizon in assigned:
                 self._active[lane] = (qid, scen, horizon)
                 rec = self._lifecycle.get(qid)
@@ -1067,6 +1089,7 @@ class ScenarioFleet:
                         PH_QUERY_QUEUE,
                         rec["submitted_ns"],
                         dur=t_admit - rec["submitted_ns"],
+                        ident=qid,
                     )
         if not self._active:
             return 0
@@ -1128,23 +1151,29 @@ class ScenarioFleet:
         # wasted dispatch only while queries were WAITING (queue fed) —
         # parked lanes riding out the drain tail of a dried-up stream are
         # not the async executor's waste (an open-loop feed never dries).
+        busy = 0
         for lane in range(self.n_lanes):
             if lane in self._active:
-                self.lane_busy_windows[lane] += min(
-                    stepped, int(remaining0[lane])
-                )
+                lane_busy = min(stepped, int(remaining0[lane]))
+                busy += lane_busy
+                self.lane_busy_windows[lane] += lane_busy
                 self.lane_total_windows[lane] += stepped
             elif queue_fed:
                 self.lane_total_windows[lane] += stepped
+        # The same round on the process-wide recorder, against what the
+        # device stepped: every lane's `stepped` windows, busy or not.
+        self.lane_windows_dispatched += stepped * self.n_lanes
+        tracer.count("lane_windows_busy", busy)
+        tracer.count("lane_windows_dispatched", stepped * self.n_lanes)
         # 4. Drain completed plans.
         done = eng.lane_windows_done()
         finished = [lane for lane in sorted(self._active) if done[lane]]
         if not finished:
             return 0
+        t_pump_drain = tracer.begin(PH_PUMP_DRAIN)
         rows = self._lane_rows(finished)
         t_drain = time.perf_counter_ns()
         obs = getattr(eng, "observatory", None)
-        tracer = eng.tracer
         for lane in finished:
             qid, scen, horizon = self._active.pop(lane)
             self._drain_lane(
@@ -1162,6 +1191,7 @@ class ScenarioFleet:
                     PH_LANE_QUARANTINE,
                     q["since_ns"],
                     dur=t_drain - q["since_ns"],
+                    ident=lane,
                 )
                 if obs is not None:
                     obs.note_lane_readmitted(lane, probes=q["probes"] + 1)
@@ -1177,7 +1207,7 @@ class ScenarioFleet:
                 t_sub = rec["submitted_ns"]
                 t_adm = rec.get("admitted_ns", t_sub)
                 tracer.end(
-                    PH_QUERY_SERVICE, t_adm, dur=t_drain - t_adm
+                    PH_QUERY_SERVICE, t_adm, dur=t_drain - t_adm, ident=qid
                 )
                 if rec["flow_id"]:
                     tracer.flow_end(PH_QUERY_QUEUE, rec["flow_id"])
@@ -1194,6 +1224,7 @@ class ScenarioFleet:
             self._completed.append(qid)
             if obs is not None:
                 obs.note_query(lat, queue_wait, service)
+        tracer.end(PH_PUMP_DRAIN, t_pump_drain, ident=self.pump_rounds)
         return len(finished)
 
     # -- fault isolation + quarantine (lane-async) ---------------------------
@@ -1216,7 +1247,10 @@ class ScenarioFleet:
                     f"{victim} (seed {chaos.seed})",
                     lane=victim,
                 )
+        tracer = self.engine.tracer
+        t0 = tracer.begin(PH_LANE_DISPATCH)
         self.engine.step_windows(n_windows)
+        tracer.end(PH_LANE_DISPATCH, t0, ident=self.pump_rounds)
 
     def _on_dispatch_fault(self, exc: Exception) -> None:
         """Poison isolation: fail the victim lane's query (typed, via
@@ -1448,16 +1482,25 @@ class ScenarioFleet:
     def lane_occupancy(self) -> Dict[str, float]:
         """Busy fraction of dispatched lane-windows (the open-loop bench
         gate): per-lane busy/total from the pump ledger, reported as the
-        across-lane mean and min. 1.0 before any pump round."""
+        across-lane mean and min (an idle lane counts as waste only while
+        queries waited). 1.0 before any pump round. `share` is THE
+        lane-occupancy gauge — busy over every lane-window the device
+        stepped, idle lanes included — the number the observatory's
+        `lane_occupancy` entry and the benchmark's `lane_busy_share` report
+        from the recorder's `lane_windows_busy` / `lane_windows_dispatched`
+        counters, which count the same rounds."""
         total = np.maximum(self.lane_total_windows, 1)
         frac = self.lane_busy_windows / total
         if not self.lane_total_windows.any():
             frac = np.ones_like(frac)
+        busy = int(self.lane_busy_windows.sum())
         return {
             "mean": float(frac.mean()),
             "min": float(frac.min()),
-            "lane_windows_busy": int(self.lane_busy_windows.sum()),
+            "share": lane_windows_share(busy, self.lane_windows_dispatched),
+            "lane_windows_busy": busy,
             "lane_windows_total": int(self.lane_total_windows.sum()),
+            "lane_windows_dispatched": self.lane_windows_dispatched,
         }
 
     def reset_query_stats(self) -> None:
@@ -1473,6 +1516,7 @@ class ScenarioFleet:
         self.latency_exact_window.clear()
         self.lane_busy_windows[:] = 0
         self.lane_total_windows[:] = 0
+        self.lane_windows_dispatched = 0
         obs = getattr(self.engine, "observatory", None)
         if obs is not None:
             obs.reset_query_stats()

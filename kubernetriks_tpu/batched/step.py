@@ -2596,6 +2596,7 @@ run_windows_donated = jax.jit(
 # megastep (engine._fused_chunk_slide) and the superspan executor below.
 
 
+@jax.named_scope("slide")
 def _slide_shift_core(phase, create_win_pay, base):
     """The window-shift amount, computed ON DEVICE: the leading run of
     terminal-or-padding pod slots across every cluster (min over C of each
@@ -2641,6 +2642,7 @@ def _quantize_shift_device(s0, W: int):
     return s.astype(jnp.int32)
 
 
+@jax.named_scope("slide")
 def _slide_apply_traced(pods, rank, pay, base, s, W: int):
     """Window slide with a TRACED shift amount (s == 0 is the identity): the
     gather formulation of engine._slide_apply_device, so ONE compiled

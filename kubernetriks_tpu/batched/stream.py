@@ -62,9 +62,10 @@ into the two causes a tuner needs to tell apart: `stage_wait_feeder`
 raise the ring depth K or widen segments) vs `stage_wait_upload` (the
 slab is published but its H2D transfer has not settled — PCIe/DMA bound;
 wider segments amortize, deeper rings don't help). Both land on the
-ENGINE's tracer (the wait happens on the engine thread); the producer's
-own assembly/upload wall time is kept as plain counters here (the feeder
-thread never touches the engine's single-threaded span ring).
+span recorder through the engine's handle (the wait happens on the
+engine thread); the
+producer's own assembly/upload wall time is kept as plain counters here
+(the feeder thread never touches the single-threaded span ring).
 
 This module carries the `# ktpu: hot-path` pragma: the lint host-sync
 pass patrols it. Its one blocking primitive on device values —
@@ -84,10 +85,10 @@ from kubernetriks_tpu.batched.faults import (
     FeederProducerError,
     InjectedFeederKill,
 )
-from kubernetriks_tpu.telemetry import NULL_TRACER
 from kubernetriks_tpu.telemetry.tracer import (
     PH_STAGE_WAIT_FEEDER,
     PH_STAGE_WAIT_UPLOAD,
+    recorder,
 )
 
 
@@ -318,15 +319,19 @@ class StreamFeeder:
         with self._cond:
             return self._retired_lo
 
-    def get_stage(self, base: int, tracer=NULL_TRACER):
+    def get_stage(self, base: int, spans=None, ident: int = 0):
         """Return (stage, lo, fresh) for the LARGEST-lo ring slab covering
         `base` (lo <= base and base - lo + W <= L; dominated predecessors
         pop as spent — the max-headroom rule), blocking until the
         producer publishes it; `fresh` is True the first time a slab is
-        served (the engine's stage_refills accounting). Raises
+        served (the engine's stage_refills accounting); the stall spans go
+        to `spans` (the calling engine's handle; else the process-wide
+        recorder) under `ident`, the superspan ordinal. Raises
         AssertionError if the ring would have to re-offer a spent/retired
         slab — the never-re-offer invariant — or if `base` moved backwards
         without a re-seek."""
+        if spans is None:
+            spans = recorder()
         waited = False
         with self._cond:
             # Tell the producer where the consumer is: the next scheduled
@@ -376,7 +381,7 @@ class StreamFeeder:
                 dur = time.perf_counter_ns() - t_wait
                 self.stall_not_ready += 1
                 self.stall_not_ready_ns += dur
-                tracer.end(PH_STAGE_WAIT_FEEDER, t_wait, dur=dur)
+                spans.end(PH_STAGE_WAIT_FEEDER, t_wait, dur=dur, ident=ident)
             assert slot.lo > self._retired_lo, (
                 f"stream ring re-offered a retired slab (lo={slot.lo} <= "
                 f"retired {self._retired_lo})"
@@ -397,7 +402,7 @@ class StreamFeeder:
                     # The settle failed — the event was set only so this
                     # wait could observe the failure, not a usable slab.
                     raise self._producer_error() from self._error
-            tracer.end(PH_STAGE_WAIT_UPLOAD, t_wait, dur=dur)
+            spans.end(PH_STAGE_WAIT_UPLOAD, t_wait, dur=dur, ident=ident)
         return slot.stage, slot.lo, fresh
 
     def retire(self, lo: int) -> None:

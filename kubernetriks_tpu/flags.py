@@ -217,9 +217,9 @@ _FLAGS = [
         "KTPU_TRACE",
         "bool",
         False,
-        "Flight recorder: host-side span tracer over every engine dispatch "
-        "phase plus the device-side per-window metrics ring carried in "
-        "ClusterBatchState. Read out via engine.telemetry_report() / "
+        "Flight recorder: the device-side per-window metrics ring carried "
+        "in ClusterBatchState and the capacity observatory (the host-side "
+        "span recorder is always on). Read out via engine.telemetry_report() / "
         "write_chrome_trace(); bench.py --trace embeds the summary in the "
         "BENCH JSON. Off by default (telemetry-on is bit-identical and "
         "gated <3% overhead, but the ring costs device memory).",

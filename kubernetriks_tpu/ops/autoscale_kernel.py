@@ -39,6 +39,8 @@ _LANE = 128  # clusters per grid program (lane tile)
 _SUB = 8  # f32/i32 sublane tile
 _BIG_I32 = np.iinfo(np.int32).max
 _VMEM_LIMIT = 100 * 1024 * 1024
+# Both pallas_calls carry name= their jitted wrapper's name: the device trace's
+# event for the kernel (see ops/scheduler_kernel.py).
 
 
 
@@ -235,6 +237,7 @@ def fused_ca_scale_down(
     with jax.enable_x64(False):
         removed_o = pl.pallas_call(
             functools.partial(_ca_down_kernel, k_sd),
+            name="fused_ca_scale_down",
             grid=(Cp // _LANE,),
             in_specs=[meta_spec] + [node_spec] * 7 + [slot_spec] * 3 + [sk_spec] * 3,
             out_specs=slot_spec,
@@ -448,6 +451,7 @@ def fused_ca_scale_up(
     with jax.enable_x64(False):
         planned_o, gpl_o, starved_o = pl.pallas_call(
             _ca_up_kernel,
+            name="fused_ca_scale_up",
             grid=(Cp // _LANE,),
             in_specs=[meta_spec] + [group_spec] * 7 + [k_spec] * 3,
             out_specs=[slot_spec, group_spec, meta_spec],

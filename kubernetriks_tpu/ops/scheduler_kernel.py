@@ -76,6 +76,12 @@ def default_enabled() -> bool:
 # surrounding fusion.
 _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
+# Every pallas_call below carries name= its jitted wrapper's name. XLA names
+# the custom call after the last scope of its op_name, so a device trace shows
+# the kernel (the custom call alone; the wrapper's pads and transposes are ops
+# of their own) as `<name>.N` — what benchmark/kernel_names.json matches. The
+# explicit name keeps that event's name when a wrapper is renamed or inlined.
+
 
 # Lane-major hot state (KTPU_LANE_MAJOR; state.NODE_HOT_LEAVES): every
 # wrapper below historically transposed its node-shaped operands into the
@@ -384,6 +390,7 @@ def fused_select_schedule_cycle(
     with jax.enable_x64(False):
         cpu_o, ram_o, cand_o, valid_o, assign_o, fitany_o, best_o = pl.pallas_call(
             kernel,
+            name="fused_select_schedule_cycle",
             grid=(Cp // _LANE,),
             in_specs=[node_spec] * 3 + [pod_spec] * 6,
             out_specs=[node_spec] * 2 + [cand_spec] * 5,
@@ -546,6 +553,7 @@ def fused_free_resources(
     with jax.enable_x64(False):
         acpu_o, aram_o, stats_o = pl.pallas_call(
             _free_kernel,
+            name="fused_free_resources",
             grid=(Cp // _LANE,),
             in_specs=[pod_spec] * 6 + [node_spec] * 2,
             out_specs=[node_spec] * 2 + [stats_spec],
@@ -724,6 +732,7 @@ def fused_event_scatter(
     with jax.enable_x64(False):
         created_o, nrm_o, pcr_o, pseq_o, prm_o = pl.pallas_call(
             _event_kernel,
+            name="fused_event_scatter",
             grid=(Cp // _LANE,),
             in_specs=[spec(Ep)] * 5 + [spec(Np)] * 2 + [spec(Pp)] * 3,
             out_specs=[spec(Np)] * 2 + [spec(Pp)] * 3,
@@ -859,6 +868,7 @@ def fused_commit_scatter(
     with jax.enable_x64(False):
         phase_o, node_o, start_o, park_o = pl.pallas_call(
             _commit_kernel,
+            name="fused_commit_scatter",
             grid=(Cp // _LANE,),
             in_specs=[spec(Kp)] * 6 + [spec(Pp)] * 2,
             out_specs=[spec(Pp)] * 4,
@@ -940,6 +950,7 @@ def fused_schedule_cycle(
     with jax.enable_x64(False):
         cpu_o, ram_o, assign_o, fitany_o, best_o = pl.pallas_call(
             kernel,
+            name="fused_schedule_cycle",
             grid=(Cp // _LANE,),
             in_specs=[node_spec, node_spec, node_spec, cand_spec, cand_spec, cand_spec],
             out_specs=[node_spec, node_spec, cand_spec, cand_spec, cand_spec],
@@ -1196,6 +1207,7 @@ def fused_select_cycle_commit(
     with jax.enable_x64(False):
         (cpu_o, ram_o, phase_o, node_o, start_o, park_o, stats_o) = pl.pallas_call(
             kernel,
+            name="fused_select_cycle_commit",
             grid=(Cp // _LANE,),
             in_specs=[node_spec] * 3 + [pod_spec] * 9 + [cand_spec] * 3,
             out_specs=[node_spec] * 2 + [pod_spec] * 4 + [stat_spec],

@@ -46,6 +46,7 @@ import warnings
 from typing import List, Optional
 
 from kubernetriks_tpu.flags import flag_tristate
+from kubernetriks_tpu.telemetry.tracer import recorder
 
 _COMPILE_LOGGER = "jax._src.dispatch"
 # pxla's "Compiling <fn> with global shapes..." WARNING rides a second
@@ -73,10 +74,14 @@ class _CompileLogHandler(logging.Handler):
             msg = record.getMessage()
             if not msg.startswith(_PREFIX):
                 return
-            name = msg[len(_PREFIX) :].rsplit(" in ", 1)[0]
+            name, _, took = msg[len(_PREFIX) :].rpartition(" in ")
             with self.lock2:
                 for sent in self.sentinels:
                     sent._events.append(name)
+            # One `compile` span per program on the process-wide recorder
+            # (a persistent-cache load logs the same line with its load
+            # time): what set-up's compile_or_load_s sums.
+            recorder().compile_event(name, float(took.split()[0]))
         except Exception:  # a telemetry hook must never break dispatch
             pass
 

@@ -2,12 +2,16 @@
 
 Two synchronized halves (docs/DESIGN.md §"Telemetry"):
 
-- **Host span tracer** (tracer.py): a preallocated ring of
-  perf_counter_ns begin/end records over every engine phase — window
-  chunks, the fused chunk+slide megastep, superspan dispatches, stage
-  prefetch/assembly/upload, slides, window growth, checkpoint I/O — with
+- **Host span recorder** (tracer.py): ONE process-wide, always-on ring
+  (`recorder()`) of perf_counter_ns begin/end records over every engine
+  and fleet phase — window chunks, the fused chunk+slide megastep,
+  superspan dispatches, stage prefetch/assembly/upload, slides, window
+  growth, checkpoint I/O, pump rounds, query lifecycles, compilations —
+  each also a `ktpu:<phase>` event in a live jax.profiler session, with
   the async shift/progress readbacks modeled as flow events, exported as
-  Chrome trace-event JSON (Perfetto) and an aggregated per-phase report.
+  Chrome trace-event JSON (Perfetto) and an aggregated per-phase report
+  (per engine: each engine writes through a handle that keeps its own
+  aggregates); the benchmark's per-layer metrics read the shared ring.
 - **Device metrics ring** (ring.py): per-window scheduling/autoscaler/
   fault aggregates accumulated inside ClusterBatchState and drained only
   at existing host sync boundaries, so telemetry-on adds zero new host
@@ -34,7 +38,8 @@ And the query half (docs/DESIGN.md §14, PR 17):
   `_bucket`/`_sum`/`_count` series — replacing every O(queries) host
   structure on the serving path.
 
-Enable with `KTPU_TRACE=1` (or `BatchedSimulation(telemetry=True)`);
+The span recorder is always on; the device ring and the observatory are
+enabled with `KTPU_TRACE=1` (or `BatchedSimulation(telemetry=True)`);
 `engine.telemetry_report()` / `engine.write_chrome_trace()` /
 `engine.drain_telemetry()` read it out, and `bench.py --trace` embeds
 the summary in the BENCH JSON.
@@ -43,19 +48,17 @@ the summary in the BENCH JSON.
 from kubernetriks_tpu.telemetry.gauges import GaugeSeries
 from kubernetriks_tpu.telemetry.histogram import LatencyHistogram
 from kubernetriks_tpu.telemetry.tracer import (
-    NULL_TRACER,
-    NullTracer,
     PHASE_NAMES,
     SpanTracer,
     log_chunk_throughput,
+    recorder,
 )
 
 __all__ = [
     "GaugeSeries",
     "LatencyHistogram",
-    "NULL_TRACER",
-    "NullTracer",
     "PHASE_NAMES",
     "SpanTracer",
     "log_chunk_throughput",
+    "recorder",
 ]

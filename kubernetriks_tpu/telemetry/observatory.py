@@ -106,6 +106,12 @@ def sample_host_memory() -> Dict[str, int]:
     return {"rss_bytes": rss, "peak_rss_bytes": peak}
 
 
+def lane_windows_share(busy: int, dispatched: int) -> float:
+    """THE lane-occupancy gauge: the share of the lane-windows the device
+    stepped that did a query's work (1.0 before any pump round)."""
+    return busy / dispatched if dispatched else 1.0
+
+
 def fit_slope(x: Sequence[float], y: np.ndarray) -> np.ndarray:
     """Closed-form least-squares slope of y against x. x: (n,) times;
     y: (n,) or (n, C) values. Returns a scalar or (C,) slope (0 where x
@@ -181,7 +187,11 @@ class Observatory:
         lane_idle_frac: float = 0.5,
         slo_ms: Optional[float] = None,
         slo_burn_window_s: Optional[float] = None,
+        counters: Optional[Dict[str, int]] = None,
     ) -> None:
+        # The owning engine's span counters (its recorder handle's LIVE
+        # dict): where the pump's lane-window ledger is read from.
+        self._counters = counters if counters is not None else {}
         self.interval = float(interval)
         self.capacities = dict(capacities or {})
         self.watchdog = bool(watchdog)
@@ -241,6 +251,12 @@ class Observatory:
         self.samples = 0
         self.reset_query_stats()
 
+    def _lane_window_counters(self) -> tuple:
+        return (
+            self._counters.get("lane_windows_busy", 0),
+            self._counters.get("lane_windows_dispatched", 0),
+        )
+
     def reset_query_stats(self) -> None:
         """Reset the query-latency histograms + the SLO sample window
         atomically (the fleet's reset_query_stats() calls this so the
@@ -254,6 +270,9 @@ class Observatory:
         self._service_hist = LatencyHistogram()
         # (t_wall, violated) pairs for the SLO burn-rate windows.
         self._slo_samples: deque = deque(maxlen=_SLO_SAMPLE_CAP)
+        # Lane-occupancy gauge: the engine's lane-window counters, read
+        # as deltas from here (the fleet's ledger zeroes at the same call).
+        self._lane_windows_base = self._lane_window_counters()
         for kind in ("slo_fast_burn", "slo_slow_burn"):
             self.fired.pop(kind, None)
 
@@ -675,6 +694,17 @@ class Observatory:
                 "quarantine_events": self._quarantine_total,
                 "readmissions": self._readmit_total,
             }
+        # Lane-occupancy gauge (lane-async fleets): the pump ledger's two
+        # counters on the recorder, since the last reset_query_stats().
+        busy, dispatched = self._lane_window_counters()
+        busy -= self._lane_windows_base[0]
+        dispatched -= self._lane_windows_base[1]
+        if dispatched:
+            out["lane_occupancy"] = {
+                "share": round(lane_windows_share(busy, dispatched), 4),
+                "lane_windows_busy": busy,
+                "lane_windows_dispatched": dispatched,
+            }
         if not self._points:
             return out
         last = self._points[-1]
@@ -706,16 +736,6 @@ class Observatory:
         out["pod_headroom"] = {
             "min": int(bounded.min()) if bounded.size else None,
             "unbounded_clusters": int((head >= UNBOUNDED_SENTINEL).sum()),
-        }
-        # Lane-occupancy gauge from the lane_active ring column: per-lane
-        # active fraction over the bounded point window, reported as the
-        # across-lane mean and min (1.0 outside lane-async builds — the
-        # column is constant 1 there).
-        active = np.stack([p[4] for p in self._points], axis=0)  # (n, C)
-        fracs = (active > 0).mean(axis=0)
-        out["lane_occupancy"] = {
-            "mean": round(float(fracs.mean()), 4),
-            "min": round(float(fracs.min()), 4),
         }
         return out
 

@@ -1,18 +1,32 @@
 # ktpu: hot-path
-"""Host-side span tracer: the flight recorder's wall-clock half.
+"""Host-side span recorder: the flight recorder's wall-clock half.
 
-Zero-dependency, allocation-free on the hot path: `begin()` is one
-`time.perf_counter_ns()` read, `end(phase, t0)` writes one row of a
-preallocated int64 ring plus four scalar aggregate updates — measured
-well under a microsecond per span, so instrumenting every engine dispatch
-perturbs nothing (the <3% overhead gate in tests/test_telemetry.py pins
-the end-to-end cost). Phases are small-int constants (no string interning
-per record); flow events model the engine's ASYNC readbacks (the fused
-slide's 4-byte shift, the superspan's (4,)-i32 progress vector) so the
-prefetch/execute overlap — and any stall waiting on a stage — is visible
-as an arrow in the rendered trace instead of an inference.
+ONE recorder per process (`recorder()`), always on: every engine and
+fleet writes to the same ring, whatever `telemetry=` / `KTPU_TRACE` say
+(that switch keeps its meaning for what changes compiled programs: the
+device ring, the observatory, the watchdog). A span is `begin(phase)` —
+one `jax.profiler.TraceAnnotation` named `ktpu:<phase>` (a no-op TraceMe
+unless a profiler session is live, then an event on the xplane's host
+plane, on the device trace's clock) and one `time.perf_counter_ns()`
+read — and `end(phase, t0, ident=...)`: one row `[t0, dur, phase, id]`
+of a preallocated int64 ring plus three aggregate updates. About a
+microsecond a span (tests/test_telemetry.py gates it; PERF.md has the
+chip host's reading). The clock is `benchmark/spans.py`'s, so a reader
+cuts the ring to a measured window. Spans nest by interval containment
+on the one engine thread; the feeder thread never writes here. The id says what a row belongs to: the query id on `query_*`, the
+pump round on `pump` and its children, the superspan ordinal on
+`superspan`, `progress_wait` and the stage spans, the ordinal into
+`compiles` on `compile`. Spans given an explicit `dur` after the fact
+(`query_*`, `compile`, the feeder stalls) are ring-only. Flow events
+model the engine's ASYNC readbacks (the fused slide's 4-byte shift, the
+superspan's (4,)-i32 progress vector) so the prefetch/execute overlap is
+an arrow in the rendered trace instead of an inference. Counters are
+time-resolved: `count()` also appends `[t, key, value]` to a sample
+ring, so a reader takes a counter's delta over any window.
 
-Two consumers:
+Consumers:
+- `benchmark/program_spans.py` — the per-layer metrics (`rows()`,
+  `counter_samples()`, `compiles`).
 - `chrome_trace()` — Chrome trace-event JSON (Perfetto-loadable): host
   spans as complete ("X") events, async readbacks as flow ("s"/"f")
   pairs, plus optional device-ring counter tracks on a sim-time process
@@ -20,6 +34,11 @@ Two consumers:
 - `report()` — the aggregated per-phase table (count / total / mean /
   max), exact even when the event ring wraps, because aggregates update
   on every `end()` rather than from the kept events.
+- `EngineSpans` (`recorder().handle()`) — what an engine holds as
+  `engine.tracer`: the same surface, every span and counter written to
+  the shared ring AND into the handle's own aggregates, so
+  `engine.telemetry_report()` is that engine's alone however many
+  engines the process builds or interleaves.
 
 This module carries the `# ktpu: hot-path` pragma ON PURPOSE: the lint
 host-sync pass patrols it like the engine, and it stays golden-clean with
@@ -28,11 +47,14 @@ ZERO sync-ok waivers — the tracer must never touch a device value.
 
 from __future__ import annotations
 
+import functools
 import json
 import time
-from typing import Dict, Optional
+from collections import deque
+from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # Span phase ids. Names index PHASE_NAMES; keep both in lockstep.
 PH_WINDOW_CHUNK = 0  # run_windows / run_windows_skip dispatch
@@ -70,6 +92,23 @@ PH_QUERY_SERVICE = 18
 # re-admission). Both host-stamped via end(phase, t0, dur=...).
 PH_QUERY_FAIL = 19
 PH_LANE_QUARANTINE = 20
+# Lane-async pump round (batched/fleet.py), id = the round: the whole of
+# pump(), its admission step (rounds that admit only), each engine
+# dispatch, the drain step and, inside it, the blocking result fetch.
+PH_PUMP = 21
+PH_PUMP_ADMIT = 22
+PH_LANE_DISPATCH = 23
+PH_PUMP_DRAIN = 24
+PH_RESULT_WAIT = 25
+# Public engine entries: the parent of the stream path's spans, the batch
+# cells' job, and the builds (BatchedSimulation / ScenarioFleet __init__).
+PH_STEP_UNTIL_TIME = 26
+PH_FLEET_RESET = 27
+PH_ENGINE_BUILD = 28
+# One XLA compilation or persistent-cache load, from jax's compile log
+# (recompile.py): t0 = log time less the logged seconds, id = ordinal
+# into `compiles`.
+PH_COMPILE = 29
 
 PHASE_NAMES = (
     "window_chunk",
@@ -93,9 +132,21 @@ PHASE_NAMES = (
     "query_service",
     "query_fail",
     "lane_quarantine",
+    "pump",
+    "pump_admit",
+    "lane_dispatch",
+    "pump_drain",
+    "result_wait",
+    "step_until_time",
+    "fleet_reset",
+    "engine_build",
+    "compile",
 )
 
 _N_PHASES = len(PHASE_NAMES)
+ANNOTATION_PREFIX = "ktpu:"
+_ANNOTATION_NAMES = tuple(ANNOTATION_PREFIX + name for name in PHASE_NAMES)
+_COMPILES_KEPT = 4096
 _FLOW_START = 0
 _FLOW_END = 1
 
@@ -105,50 +156,77 @@ _FLOW_END = 1
 LANE_PID = 2
 
 
-class _AnnotatedSpan:
-    """Reusable context manager: one recorded span, optionally bridged
-    into the active jax.profiler capture as a TraceAnnotation so host
-    phases land in the xplane next to the device ops they caused
-    (scripts/profile_composed_xplane.py correlates them)."""
+class _Span:
+    """Context-manager span for cold paths (checkpoint I/O, the fenced
+    per-chunk loop of log_throughput)."""
 
-    __slots__ = ("_tracer", "_phase", "_t0", "_ann")
+    __slots__ = ("_tracer", "_phase", "_t0")
 
     def __init__(self, tracer: "SpanTracer", phase: int):
         self._tracer = tracer
         self._phase = phase
-        self._ann = None
 
     def __enter__(self):
-        if self._tracer.annotate:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._ann = TraceAnnotation(PHASE_NAMES[self._phase])
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
-        self._t0 = self._tracer.begin()
+        self._t0 = self._tracer.begin(self._phase)
         return self
 
     def __exit__(self, *exc):
         self._tracer.end(self._phase, self._t0)
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
         return False
 
 
-class SpanTracer:
+class _Aggregates:
+    """Exact per-phase span aggregates (ns) and freeform counters of one
+    writer; Python ints, a third of the cost of a numpy scalar update."""
+
+    def __init__(self):
+        self._agg_count = [0] * _N_PHASES
+        self._agg_total = [0] * _N_PHASES
+        self._agg_max = [0] * _N_PHASES
+        # Freeform counters (stage prefetch hits/misses, dispatch
+        # histogram buckets, lane-window ledger, ...). Host ints only.
+        self.counters: Dict[str, int] = {}
+
+    def span(self, phase: int) -> _Span:
+        """Context-manager span for cold paths; hot dispatch sites use
+        begin/end directly to stay allocation-light."""
+        return _Span(self, phase)
+
+    def report(self) -> dict:
+        """Aggregated per-phase wall time (ms totals, µs mean/max) plus
+        the freeform counters — exact even when the span ring wrapped."""
+        spans = {}
+        for pid in range(_N_PHASES):
+            n = self._agg_count[pid]
+            if n == 0:
+                continue
+            total = self._agg_total[pid]
+            spans[PHASE_NAMES[pid]] = {
+                "count": n,
+                "total_ms": total / 1e6,
+                "mean_us": total / n / 1e3,
+                "max_us": self._agg_max[pid] / 1e3,
+            }
+        return {"spans": spans, "counters": dict(self.counters)}
+
+
+class SpanTracer(_Aggregates):
     def __init__(
         self,
         capacity: int = 1 << 16,
         flow_capacity: int = 1 << 14,
         lane_capacity: int = 1 << 14,
+        counter_capacity: int = 1 << 14,
     ):
-        # Span event ring: [t0_ns, dur_ns, phase]; kept events wrap, the
-        # per-phase aggregates below stay exact regardless.
-        self._spans = np.zeros((capacity, 3), np.int64)
+        super().__init__()
+        # Span event ring: [t0_ns, dur_ns, phase, id]; kept events wrap,
+        # the per-phase aggregates stay exact regardless.
+        self._spans = np.zeros((capacity, 4), np.int64)
         self._n_spans = 0
+        # Open begin()s, innermost last: the phase and its live
+        # TraceAnnotation (closed by the matching end()).
+        self._open_phase: List[int] = []
+        self._open_ann: List[TraceAnnotation] = []
         # Flow event ring: [t_ns, phase, flow_id, kind].
         self._flows = np.zeros((flow_capacity, 4), np.int64)
         self._n_flows = 0
@@ -158,40 +236,77 @@ class SpanTracer:
         # the occupying query id as the span name.
         self._lane_spans = np.zeros((lane_capacity, 4), np.int64)
         self._n_lane_spans = 0
-        # Exact per-phase aggregates (ns).
-        self._agg_count = np.zeros(_N_PHASES, np.int64)
-        self._agg_total = np.zeros(_N_PHASES, np.int64)
-        self._agg_max = np.zeros(_N_PHASES, np.int64)
-        # Freeform counters (stage prefetch hits/misses, dispatch
-        # histogram buckets, ...). Host ints only.
-        self.counters: Dict[str, int] = {}
-        self.enabled = True
-        # When True, span() context managers also enter a
-        # jax.profiler.TraceAnnotation (set by the engine while a
-        # profiler capture is active).
-        self.annotate = False
+        # Every counter update also lands in the sample ring
+        # [t_ns, key, value].
+        self._counter_keys: Dict[str, int] = {}
+        self._samples = np.zeros((counter_capacity, 3), np.int64)
+        self._n_samples = 0
+        # (entry name, seconds) of the newest compilations jax logged
+        # while a recompile sentinel was installed; a `compile` row's id
+        # is the ordinal of its entry since process start.
+        self.compiles: deque = deque(maxlen=_COMPILES_KEPT)
+        self.compiles_recorded = 0
         self._epoch = time.perf_counter_ns()
 
     # -- hot path ----------------------------------------------------------
 
-    def begin(self) -> int:
+    def begin(self, phase: int) -> int:
+        self._open_phase.append(phase)
+        self._open_ann.append(TraceAnnotation(_ANNOTATION_NAMES[phase]))
         return time.perf_counter_ns()
 
-    def end(self, phase: int, t0: int, dur: Optional[int] = None) -> None:
-        dur = (time.perf_counter_ns() - t0) if dur is None else dur
+    def end(
+        self, phase: int, t0: int, dur: Optional[int] = None, ident: int = 0
+    ) -> int:
+        """Record the span; returns its duration (ns)."""
+        if dur is None:
+            dur = time.perf_counter_ns() - t0
+            # Close this span's annotation — and any left open above it
+            # by an exception between a begin() and its end().
+            open_phase = self._open_phase
+            while open_phase:
+                self._open_ann.pop().__exit__(None, None, None)
+                if open_phase.pop() == phase:
+                    break
         i = self._n_spans % self._spans.shape[0]
         buf = self._spans
         buf[i, 0] = t0
         buf[i, 1] = dur
         buf[i, 2] = phase
+        buf[i, 3] = ident
         self._n_spans += 1
         self._agg_count[phase] += 1
         self._agg_total[phase] += dur
         if dur > self._agg_max[phase]:
             self._agg_max[phase] = dur
+        return dur
 
     def count(self, name: str, n: int = 1) -> None:
-        self.counters[name] = self.counters.get(name, 0) + n
+        value = self.counters.get(name, 0) + n
+        self.counters[name] = value
+        key = self._counter_keys.get(name)
+        if key is None:
+            key = self._counter_keys[name] = len(self._counter_keys)
+        i = self._n_samples % self._samples.shape[0]
+        buf = self._samples
+        buf[i, 0] = time.perf_counter_ns()
+        buf[i, 1] = key
+        buf[i, 2] = value
+        self._n_samples += 1
+
+    def compile_event(self, name: str, seconds: float) -> None:
+        """One XLA compilation (or cache load) that just finished, as
+        jax's compile log reports it: a `compile` row ending now, and
+        its name in `compiles`."""
+        dur = int(seconds * 1e9)
+        self.end(
+            PH_COMPILE,
+            time.perf_counter_ns() - dur,
+            dur=dur,
+            ident=self.compiles_recorded,
+        )
+        self.compiles.append((name, seconds))
+        self.compiles_recorded += 1
 
     def flow_start(self, phase: int) -> int:
         fid = self._next_flow
@@ -223,11 +338,33 @@ class SpanTracer:
         buf[i, 3] = qid
         self._n_lane_spans += 1
 
-    def span(self, phase: int) -> _AnnotatedSpan:
-        """Context-manager span for cold paths (checkpoint I/O, the
-        instrumented per-chunk loop); hot dispatch sites use begin/end
-        directly to stay allocation-free."""
-        return _AnnotatedSpan(self, phase)
+    def handle(self) -> "EngineSpans":
+        """A new per-engine handle on this recorder."""
+        return EngineSpans(self)
+
+    # -- readers -------------------------------------------------------------
+
+    def rows(self) -> np.ndarray:
+        """The kept span rows `[t0_ns, dur_ns, phase, id]`, oldest first
+        (in the order their spans ENDED), as an owned copy."""
+        return self._kept(self._spans, self._n_spans).copy()
+
+    def counter_samples(self, name: str) -> np.ndarray:
+        """The kept samples `[t_ns, value]` of one counter, oldest first
+        (empty where the counter never counted)."""
+        key = self._counter_keys.get(name)
+        kept = self._kept(self._samples, self._n_samples)
+        if key is None:
+            return kept[:0, :2].copy()
+        return kept[kept[:, 1] == key][:, (0, 2)]
+
+    def dropped(self) -> Dict[str, int]:
+        """Rows each ring has wrapped out (0 = everything recorded is
+        still kept)."""
+        return {
+            "spans": max(0, self._n_spans - self._spans.shape[0]),
+            "counter_samples": max(0, self._n_samples - self._samples.shape[0]),
+        }
 
     # -- export ------------------------------------------------------------
 
@@ -261,7 +398,9 @@ class SpanTracer:
             },
         ]
         epoch = self._epoch
-        for t0, dur, phase in self._kept(self._spans, self._n_spans).tolist():
+        for t0, dur, phase, ident in self._kept(
+            self._spans, self._n_spans
+        ).tolist():
             ev.append(
                 {
                     "ph": "X",
@@ -271,11 +410,17 @@ class SpanTracer:
                     "dur": dur / 1e3,
                     "pid": 0,
                     "tid": 0,
+                    "args": {"id": int(ident)},
                 }
             )
-        for t, phase, fid, kind in self._kept(
-            self._flows, self._n_flows
-        ).tolist():
+        # Flow arrows need both ends: a readback still pending (any
+        # engine's of the process) or one whose start wrapped out is left
+        # out.
+        flows = self._kept(self._flows, self._n_flows)
+        started = flows[flows[:, 3] == _FLOW_START, 2]
+        ended = flows[flows[:, 3] == _FLOW_END, 2]
+        paired = np.isin(flows[:, 2], np.intersect1d(started, ended))
+        for t, phase, fid, kind in flows[paired].tolist():
             ev.append(
                 {
                     "ph": "s" if kind == _FLOW_START else "f",
@@ -340,88 +485,81 @@ class SpanTracer:
         return path
 
     def report(self) -> dict:
-        """Aggregated per-phase wall time (ms totals, µs mean/max) plus
-        the freeform counters — exact even when the span ring wrapped."""
-        spans = {}
-        for pid in range(_N_PHASES):
-            n = int(self._agg_count[pid])
-            if n == 0:
-                continue
-            total = int(self._agg_total[pid])
-            spans[PHASE_NAMES[pid]] = {
-                "count": n,
-                "total_ms": total / 1e6,
-                "mean_us": total / n / 1e3,
-                "max_us": int(self._agg_max[pid]) / 1e3,
-            }
-        return {
-            "spans": spans,
-            "counters": dict(self.counters),
-            "span_events": {
-                "recorded": int(self._n_spans),
-                "kept": int(min(self._n_spans, self._spans.shape[0])),
-            },
-            "lane_spans": {
-                "recorded": int(self._n_lane_spans),
-                "kept": int(
-                    min(self._n_lane_spans, self._lane_spans.shape[0])
-                ),
-            },
+        """The process-wide table and counters, plus how much of the
+        span and lane rings is still kept."""
+        rep = super().report()
+        rep["span_events"] = {
+            "recorded": int(self._n_spans),
+            "kept": int(min(self._n_spans, self._spans.shape[0])),
         }
+        rep["lane_spans"] = {
+            "recorded": int(self._n_lane_spans),
+            "kept": int(min(self._n_lane_spans, self._lane_spans.shape[0])),
+        }
+        return rep
 
 
-class _NullSpan:
-    __slots__ = ()
+class EngineSpans(_Aggregates):
+    """One engine's handle on the process-wide recorder (`engine.tracer`;
+    a fleet writes through its engine's). Every span, flow, lane event
+    and counter goes to the shared rings; the aggregates and counters
+    kept HERE count this engine's alone, so `report()` does not carry
+    another engine's time. `span_events` / `lane_spans` in it describe
+    the shared rings."""
 
-    def __enter__(self):
-        return self
+    def __init__(self, rec: SpanTracer):
+        super().__init__()
+        self._rec = rec
+        self.begin = rec.begin
+        self.flow_start = rec.flow_start
+        self.flow_end = rec.flow_end
+        self.lane_event = rec.lane_event
+        self.write_chrome_trace = rec.write_chrome_trace
 
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class NullTracer:
-    """API-compatible no-op stand-in so the engine's instrumentation sites
-    stay branch-free; `begin()` skips the clock read entirely."""
-
-    annotate = False
-    enabled = False
-    counters: Dict[str, int] = {}
-
-    def begin(self) -> int:
-        return 0
-
-    def end(self, phase: int, t0: int, dur: Optional[int] = None) -> None:
-        pass
+    def end(
+        self, phase: int, t0: int, dur: Optional[int] = None, ident: int = 0
+    ) -> int:
+        dur = self._rec.end(phase, t0, dur, ident)
+        self._agg_count[phase] += 1
+        self._agg_total[phase] += dur
+        if dur > self._agg_max[phase]:
+            self._agg_max[phase] = dur
+        return dur
 
     def count(self, name: str, n: int = 1) -> None:
-        pass
-
-    def flow_start(self, phase: int) -> int:
-        return 0
-
-    def flow_end(self, phase: int, fid: int) -> None:
-        pass
-
-    def lane_event(self, lane: int, qid: int, t0: int, dur: int) -> None:
-        pass
-
-    def span(self, phase: int) -> _NullSpan:
-        return _NULL_SPAN
+        self.counters[name] = self.counters.get(name, 0) + n
+        self._rec.count(name, n)
 
     def report(self) -> dict:
-        return {
-            "spans": {},
-            "counters": {},
-            "span_events": {"recorded": 0, "kept": 0},
-            "lane_spans": {"recorded": 0, "kept": 0},
-        }
+        rep = self._rec.report()
+        rep.update(super().report())
+        return rep
 
 
-NULL_TRACER = NullTracer()
+_RECORDER = SpanTracer()
+
+
+def recorder() -> SpanTracer:
+    """THE process-wide recorder every engine and fleet writes to."""
+    return _RECORDER
+
+
+def build_span(init):
+    """Decorator for an engine's or fleet's `__init__`: one `engine_build`
+    span round the whole constructor, closed whatever it raises, on the
+    handle the constructor left as `self.tracer` (an engine's) or else on
+    the recorder (a fleet's, or a build that failed before its handle)."""
+
+    @functools.wraps(init)
+    def build(self, *args, **kwargs):
+        rec = recorder()
+        t0 = rec.begin(PH_ENGINE_BUILD)
+        try:
+            init(self, *args, **kwargs)
+        finally:
+            getattr(self, "tracer", rec).end(PH_ENGINE_BUILD, t0)
+
+    return build
 
 
 def log_chunk_throughput(logger, n_windows, n_clusters, decisions, elapsed):

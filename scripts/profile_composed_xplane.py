@@ -26,22 +26,21 @@ from profile_autoscale_cost import build
 
 
 def capture(pod_window=512, span=200.0, outdir="/tmp/ktpu_xplane"):
-    # Flight recorder ON (PR 8): host spans over every dispatch phase are
-    # recorded alongside the xplane capture, and — with annotate set while
-    # the profiler trace is active — they ALSO land in the xplane as
-    # TraceAnnotations, so the aggregation below can be correlated with
-    # the engine phases directly instead of re-derived from HLO op names.
+    # Host spans over every dispatch phase are recorded alongside the
+    # xplane capture, and while the profiler session is live they ALSO
+    # land in the xplane as `ktpu:<phase>` TraceAnnotations, so the
+    # aggregation below can be correlated with the engine phases directly
+    # instead of re-derived from HLO op names. KTPU_TRACE adds the device
+    # ring the report's per-window line reads.
     os.environ.setdefault("KTPU_TRACE", "1")
     sim = build(pod_window, True)
     sim.step_until_time(590.0)
     _ = int(np.asarray(sim.state.metrics.scheduling_decisions).sum())
     os.makedirs(outdir, exist_ok=True)
     t0 = time.perf_counter()
-    sim.tracer.annotate = True
     with jax.profiler.trace(outdir):
         sim.step_until_time(590.0 + span)
         _ = int(np.asarray(sim.state.metrics.scheduling_decisions).sum())
-    sim.tracer.annotate = False
     wall = time.perf_counter() - t0
     n_windows = span / 10.0
     print(f"captured {n_windows:.0f} windows in {wall:.2f}s "

@@ -58,14 +58,19 @@ def test_sharded_run_matches_unsharded(mesh):
 
 @pytest.mark.slow
 def test_profiling_hooks(tmp_path, caplog):
-    """profile_dir captures a jax.profiler trace; log_throughput emits the
-    per-chunk decisions/s line (TPU analog of the scalar events/s log,
-    reference: src/simulator.rs:363-368). Slow lane (tier-1 wall-clock
-    budget): instrumentation plumbing, not a correctness gate — the
-    flight recorder's tier-1 suite (test_telemetry) covers the tracing
+    """A jax.profiler capture started round an ordinary call holds the
+    recorder's spans as `ktpu:` host events (here the fenced per-chunk
+    span of the throughput log); log_throughput emits the per-chunk
+    decisions/s line (TPU analog of the scalar events/s log, reference:
+    src/simulator.rs:363-368). Slow lane (tier-1 wall-clock budget):
+    instrumentation plumbing, not a correctness gate — the flight
+    recorder's tier-1 suite (test_telemetry) covers the capture round the
     path the engine actually runs in steady state."""
     import logging
-    import os
+
+    import jax
+
+    from test_telemetry import host_annotations
 
     from kubernetriks_tpu.test_util import default_test_simulation_config
 
@@ -77,15 +82,13 @@ def test_profiling_hooks(tmp_path, caplog):
         GenericWorkloadTrace.from_yaml(workload_yaml).convert_to_simulator_events(),
         n_clusters=4,
     )
-    sim.profile_dir = str(tmp_path / "trace")
     sim.log_throughput = True
     with caplog.at_level(logging.INFO, logger="kubernetriks_tpu.batched.engine"):
-        sim.step_until_time(100.0)
+        with jax.profiler.trace(str(tmp_path / "trace")):
+            sim.step_until_time(100.0)
     assert any("decisions/s" in rec.message for rec in caplog.records)
-    dumped = []
-    for root, _, files in os.walk(tmp_path / "trace"):
-        dumped.extend(files)
-    assert dumped, "profiler trace directory is empty"
+    names = host_annotations(str(tmp_path / "trace"))
+    assert {"ktpu:step_until_time", "ktpu:chunk_fenced", "ktpu:window_chunk"} <= names
 
 
 def test_pod_axis_alignment_full_resident_only():

@@ -15,6 +15,14 @@ Two-tier coverage, split to keep tier-1 inside its wall-clock budget:
   (spans, flow pairs, counter tracks), checkpoint roundtrip of the ring,
   the <3% overhead gate, the ladder-fallback observable, the tracer
   per-span microbenchmark, and the shared JSON/table render path.
+- The host span recorder is ONE process-wide, always-on object (PR 26):
+  engines and fleets built with telemetry=False record their spans with
+  ids, unchanged in state pytree, dispatch_stats and jit caches; every
+  begin/end span is a `ktpu:<phase>` event of a live jax.profiler
+  session; compile-log seconds become `compile` rows. Ring rows are read
+  as DELTAS of the shared recorder (other tests of the worker write to
+  it); `engine.telemetry_report()` is the engine's own (its handle's
+  aggregates), exact under any interleaving of engines.
 """
 
 import json
@@ -23,10 +31,16 @@ import time
 import numpy as np
 import pytest
 
+from benchmark import program_spans
 from kubernetriks_tpu.batched.engine import build_batched_from_traces
 from kubernetriks_tpu.batched.state import compare_states, strip_telemetry
 from kubernetriks_tpu.telemetry.ring import RING_COLUMNS
-from kubernetriks_tpu.telemetry.tracer import PH_WINDOW_CHUNK, SpanTracer
+from kubernetriks_tpu.telemetry.tracer import (
+    PHASE_NAMES,
+    PH_WINDOW_CHUNK,
+    SpanTracer,
+    recorder,
+)
 from kubernetriks_tpu.test_util import default_test_simulation_config
 from kubernetriks_tpu.trace.generator import (
     PoissonWorkloadTrace,
@@ -36,6 +50,42 @@ from kubernetriks_tpu.trace.generator import (
 from test_window_donation_dispatch import _build_dense_sliding
 
 ENDS = (150.0, 300.0, 450.0)
+
+
+def recorded():
+    return recorder().report()["span_events"]["recorded"]
+
+
+def rows_since(n_before, phase_name=None):
+    """The recorder's rows recorded after `n_before` spans had been
+    (oldest first), optionally of one phase."""
+    rows = recorder().rows()
+    rows = rows[len(rows) - (recorded() - n_before):]
+    if phase_name is not None:
+        rows = rows[rows[:, 2] == PHASE_NAMES.index(phase_name)]
+    return rows
+
+
+def host_annotations(trace_dir):
+    """Names of the `ktpu:` events on the host planes of the newest
+    jax.profiler capture under `trace_dir`."""
+    import glob
+    import os
+
+    import jax
+
+    found = sorted(
+        glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    )
+    assert found, f"no .xplane.pb under {trace_dir}"
+    names = set()
+    for plane in jax.profiler.ProfileData.from_file(found[-1]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names |= {
+                    e.name for e in line.events if e.name.startswith("ktpu:")
+                }
+    return names
 
 
 def _build_plain(**kwargs):
@@ -112,13 +162,17 @@ def test_ring_series_is_lossless_and_matches_metrics(cheap_pair):
 
 
 def test_telemetry_report_shape(cheap_pair):
-    on, _ = cheap_pair
+    on, off = cheap_pair
     rep = on.telemetry_report()
-    assert rep["enabled"]
+    assert rep["enabled"] and not off.telemetry_report()["enabled"]
+    # One ring for the process, one report per engine: `off` ran
+    # interleaved with `on` and none of its spans are counted here.
     assert (
         rep["spans"]["window_chunk"]["count"]
         == on.dispatch_stats["window_chunks"]
     )
+    assert rep["spans"]["step_until_time"]["count"] == len(ENDS)
+    assert rep["spans"]["engine_build"]["count"] == 1
     # Full-resident run: zero slides, zero syncs — budget trivially met
     # (the composed-scale budget gate lives in test_superspan.py).
     assert rep["sync_budget"]["observed_slide_syncs"] == (
@@ -327,6 +381,7 @@ def test_staged_superspan_records_prefetch_spans(monkeypatch):
     import kubernetriks_tpu.batched.engine as engine_mod
 
     monkeypatch.setattr(engine_mod, "_DEVICE_SLIDE_BUDGET_BYTES", 0)
+    n0 = recorded()
     sim = _build_dense_sliding(
         telemetry=True, telemetry_ring=16,
         superspan=True, superspan_k=4, superspan_chunk=4,
@@ -335,9 +390,12 @@ def test_staged_superspan_records_prefetch_spans(monkeypatch):
     for end in ENDS:
         sim.step_until_time(end)
     rep = sim.telemetry_report()
-    assert rep["spans"]["stage_assemble"]["count"] >= 1
-    assert rep["spans"]["stage_put"]["count"] >= 1
-    assert rep["spans"]["stage_prefetch"]["count"] >= 1
+    for phase in ("stage_assemble", "stage_put", "stage_prefetch"):
+        assert rep["spans"][phase]["count"] >= 1
+        # A stage span carries the ordinal of the superspan it follows.
+        ids = rows_since(n0, phase)[:, 3]
+        assert len(ids) == rep["spans"][phase]["count"]
+        assert ids.min() >= 0 and ids.max() <= sim.dispatch_stats["superspans"]
     hits = rep["counters"].get("stage_prefetch_hit", 0)
     misses = rep["counters"].get("stage_prefetch_miss", 0)
     assert hits + misses >= 1  # at least the initial install counted
@@ -363,16 +421,31 @@ def test_tracer_span_cost_microbench():
     catches an accidental allocation or string format on the record
     path."""
     tr = SpanTracer(capacity=1 << 12)
+    spans = tr.handle()  # what an engine holds: its own aggregates too
     n = 20_000
     t_start = time.perf_counter_ns()
-    for _ in range(n):
-        t0 = tr.begin()
-        tr.end(PH_WINDOW_CHUNK, t0)
+    for i in range(n):
+        t0 = spans.begin(PH_WINDOW_CHUNK)  # enters the ktpu: TraceAnnotation
+        spans.end(PH_WINDOW_CHUNK, t0, ident=i)
     per_span_us = (time.perf_counter_ns() - t_start) / n / 1e3
     assert per_span_us < 10.0, f"{per_span_us:.2f} µs per span"
+    assert not tr._open_phase and not tr._open_ann  # every annotation closed
     rep = tr.report()
+    # Aggregates exact after the ring wrapped, the recorder's and the
+    # handle's alike; the kept rows are the newest, oldest first, each
+    # with its id.
     assert rep["spans"]["window_chunk"]["count"] == n
-    assert rep["span_events"]["kept"] == 1 << 12  # ring wrapped, report exact
+    assert rep["span_events"] == {"recorded": n, "kept": 1 << 12}
+    assert spans.report() == rep
+    rows = tr.rows()
+    np.testing.assert_array_equal(rows[:, 3], np.arange(n - (1 << 12), n))
+    assert rep["spans"]["window_chunk"]["total_ms"] >= rows[:, 1].sum() / 1e6
+    assert tr.dropped()["spans"] == n - (1 << 12)
+    # A second handle on the same recorder starts from nothing.
+    other = tr.handle()
+    other.count("stage_prefetch_hit")
+    assert other.report()["spans"] == {} and spans.report()["counters"] == {}
+    assert tr.counters == other.counters == {"stage_prefetch_hit": 1}
 
 
 def test_tracer_lane_swimlanes_and_query_phases(tmp_path):
@@ -381,24 +454,24 @@ def test_tracer_lane_swimlanes_and_query_phases(tmp_path):
     swimlane per lane with the occupying query id as the span name (plus
     process/thread metadata), the submit->drain flow pairs match, and
     report() discloses the lane-span ring's recorded/kept counts."""
-    from kubernetriks_tpu.telemetry import PHASE_NAMES
     from kubernetriks_tpu.telemetry.tracer import (
         LANE_PID,
         PH_QUERY_QUEUE,
         PH_QUERY_SERVICE,
-        NullTracer,
     )
 
     assert PHASE_NAMES[PH_QUERY_QUEUE] == "query_queue"
     assert PHASE_NAMES[PH_QUERY_SERVICE] == "query_service"
     tr = SpanTracer()
-    t0 = tr.begin()
+    t0 = time.perf_counter_ns()
     fid = tr.flow_start(PH_QUERY_QUEUE)
-    tr.end(PH_QUERY_QUEUE, t0, dur=1_000)
-    tr.end(PH_QUERY_SERVICE, t0 + 1_000, dur=5_000)
+    tr.end(PH_QUERY_QUEUE, t0, dur=1_000, ident=7)
+    tr.end(PH_QUERY_SERVICE, t0 + 1_000, dur=5_000, ident=7)
     tr.lane_event(2, 7, t0 + 1_000, 5_000)
     tr.lane_event(0, 8, t0 + 1_000, 4_000)
     tr.flow_end(PH_QUERY_QUEUE, fid)
+    # A query still queued (any fleet's of the process) has no arrow yet.
+    pending = tr.flow_start(PH_QUERY_QUEUE)
     doc = tr.chrome_trace()
     evs = doc["traceEvents"]
     lanes = [e for e in evs if e.get("pid") == LANE_PID and e["ph"] == "X"]
@@ -419,7 +492,8 @@ def test_tracer_lane_swimlanes_and_query_phases(tmp_path):
     flows = [e for e in evs if e["ph"] in ("s", "f")]
     assert {e["id"] for e in flows if e["ph"] == "s"} == {
         e["id"] for e in flows if e["ph"] == "f"
-    }
+    } == {fid}
+    assert pending != fid
     rep = tr.report()
     assert rep["lane_spans"] == {"recorded": 2, "kept": 2}
     assert rep["spans"]["query_queue"]["count"] == 1
@@ -432,10 +506,13 @@ def test_tracer_lane_swimlanes_and_query_phases(tmp_path):
         for ev in json.load(fh)["traceEvents"]:
             if ev["ph"] == "X" and ev["pid"] == LANE_PID:
                 assert ev["name"].startswith("q")
-    # NullTracer mirrors the whole surface as no-ops.
-    nt = NullTracer()
-    nt.lane_event(0, 0, 0, 0)
-    assert nt.report()["lane_spans"] == {"recorded": 0, "kept": 0}
+    # The span rows carry the query id, in the Chrome events too.
+    assert [e["args"]["id"] for e in evs if e.get("cat") == "host"] == [7, 7]
+    # The process-wide recorder (there is no no-op stand-in any more)
+    # takes the same surface: a lane event lands in its lane ring.
+    was = recorder().report()["lane_spans"]["recorded"]
+    recorder().lane_event(0, 0, 0, 0)
+    assert recorder().report()["lane_spans"]["recorded"] == was + 1
 
 
 def test_overhead_gate_smoke_scenario():
@@ -499,3 +576,315 @@ def test_shared_render_path_covers_scalar_batched_and_telemetry(cheap_pair):
     rep_table = render_telemetry(on.telemetry_report(), "table")
     assert "window_chunk" in rep_table and "Ring windows kept" in rep_table
     json.loads(render_telemetry(on.telemetry_report(), "json"))
+
+
+# --- the always-on process-wide recorder (PR 26) ---------------------------
+
+# dispatch_stats of the two scenarios below as the PARENT commit's engines
+# (no recorder, NULL_TRACER under telemetry=False) counted them: the
+# recorder changes no dispatch and no sync.
+PARENT_SUPERSPAN_STATS = {
+    "window_chunks": 0, "fused_slides": 0, "slide_dispatches": 0,
+    "slide_syncs": 10, "refill_prefetches": 0, "superspans": 10,
+    "superspan_spans": 20, "stage_refills": 0, "feeder_slabs_produced": 0,
+    "ladder_fallbacks": 0,
+}
+PARENT_FLEET_STATS = dict(
+    PARENT_SUPERSPAN_STATS, window_chunks=6, slide_syncs=0, superspans=0,
+    superspan_spans=0,
+)
+FLEET_HORIZONS = (120.0, 60.0, 90.0)
+
+
+def _inside(child, parent):
+    return parent[0] <= child[0] and child[0] + child[1] <= parent[0] + parent[1]
+
+
+@pytest.fixture(scope="module")
+def superspan_off_run(tmp_path_factory):
+    """A superspan engine built with telemetry=False, stepped through
+    ENDS with a jax.profiler capture round the last call."""
+    import jax
+
+    n0 = recorded()
+    sim = _build_dense_sliding(superspan=True, superspan_k=4, superspan_chunk=4)
+    for end in ENDS[:-1]:
+        sim.step_until_time(end)
+    trace_dir = str(tmp_path_factory.mktemp("xplane"))
+    with jax.profiler.trace(trace_dir):
+        sim.step_until_time(ENDS[-1])
+    return sim, n0, trace_dir
+
+
+def test_recorder_is_on_with_telemetry_off(superspan_off_run):
+    """telemetry=False keeps the device ring out of the state pytree and
+    the parent's dispatch counts, and still records the stream path's
+    spans, each superspan and its progress wait under the ordinal of the
+    dispatch, inside the step_until_time call that made them."""
+    sim, n0, _ = superspan_off_run
+    assert sim.state.telemetry is None and sim.observatory is None
+    assert sim.dispatch_stats == PARENT_SUPERSPAN_STATS
+    n = sim.dispatch_stats["superspans"]
+    supers = rows_since(n0, "superspan")
+    waits = rows_since(n0, "progress_wait")
+    np.testing.assert_array_equal(supers[:, 3], np.arange(1, n + 1))
+    np.testing.assert_array_equal(waits[:, 3], np.arange(1, n + 1))
+    calls = rows_since(n0, "step_until_time")
+    assert len(calls) == len(ENDS)
+    for row in np.concatenate([supers, waits]):
+        assert sum(_inside(row, call) for call in calls) == 1
+    assert len(rows_since(n0, "engine_build")) == 1
+    # The report is this engine's, the device sections are absent.
+    rep = sim.telemetry_report()
+    assert not rep["enabled"] and "ring" not in rep and "resources" not in rep
+    assert rep["spans"]["superspan"]["count"] == n
+    assert rep["spans"]["progress_wait"]["count"] == sim.dispatch_stats["slide_syncs"]
+
+
+def test_profiler_capture_holds_program_spans(superspan_off_run):
+    """A jax.profiler session started round an ordinary step_until_time
+    (superspan engaged: no ladder fallback) holds the recorder's spans as
+    `ktpu:<phase>` events on a host plane."""
+    sim, _, trace_dir = superspan_off_run
+    assert sim.dispatch_stats["ladder_fallbacks"] == 0
+    names = host_annotations(trace_dir)
+    assert {"ktpu:step_until_time", "ktpu:superspan", "ktpu:progress_wait"} <= names
+
+
+def test_chrome_trace_needs_no_telemetry(superspan_off_run, tmp_path):
+    """write_chrome_trace reads the shared recorder: a telemetry-off
+    engine writes its host spans (no device-ring counter track)."""
+    sim, _, _ = superspan_off_run
+    with open(sim.write_chrome_trace(str(tmp_path / "off.json"))) as fh:
+        events = json.load(fh)["traceEvents"]
+    assert any(e["ph"] == "X" and e["name"] == "superspan" for e in events)
+    assert not any(e["ph"] == "C" for e in events)
+
+
+def _small_async_fleet(**kwargs):
+    from kubernetriks_tpu.batched.fleet import ScenarioFleet
+
+    config = default_test_simulation_config()
+    cluster = UniformClusterTrace(8, cpu=64000, ram=128 * 1024**3)
+    workload = PoissonWorkloadTrace(
+        rate_per_second=1.0, horizon=200.0, seed=5, cpu=4000,
+        ram=4 * 1024**3, duration_range=(20.0, 40.0),
+    )
+    return ScenarioFleet(
+        config,
+        cluster.convert_to_simulator_events(),
+        workload.convert_to_simulator_events(),
+        n_lanes=2, horizon=120.0, max_pods_per_cycle=16, use_pallas=False,
+        lane_async=True, span_windows=4, **kwargs,
+    )
+
+
+@pytest.fixture(scope="module")
+def async_fleet_run():
+    from kubernetriks_tpu.batched.fleet import Scenario
+
+    n0 = recorded()
+    fleet = _small_async_fleet()
+    qids = [fleet.submit(Scenario(), h) for h in FLEET_HORIZONS]
+    fleet.run_async()
+    yield fleet, qids, rows_since(n0)
+    fleet.close()
+
+
+def test_fleet_rounds_are_recorded_with_their_round(async_fleet_run):
+    """A lane-async fleet on a telemetry=False engine records pump >
+    pump_admit / lane_dispatch / pump_drain > result_wait, every row under
+    its round, with the parent's dispatch counts and no device ring."""
+    fleet, _, rows = async_fleet_run
+    assert fleet.engine.state.telemetry is None
+    assert fleet.engine.dispatch_stats == PARENT_FLEET_STATS
+    by = lambda name: rows[rows[:, 2] == PHASE_NAMES.index(name)]  # noqa: E731
+    pumps = {int(r[3]): r for r in by("pump")}
+    assert sorted(pumps) == list(range(fleet.pump_rounds)) == list(range(5))
+    for child in ("pump_admit", "lane_dispatch", "pump_drain"):
+        kids = by(child)
+        assert len(kids) >= 2, child
+        for row in kids:
+            assert _inside(row, pumps[int(row[3])]), child
+    assert len(by("lane_dispatch")) == fleet.engine.dispatch_stats["window_chunks"]
+    drains = {int(r[3]): r for r in by("pump_drain")}
+    waits = by("result_wait")
+    assert len(waits) == len(drains)
+    for row in waits:
+        assert _inside(row, drains[int(row[3])])
+    # A round's children cover it but for host arithmetic: self time is
+    # what is left, never negative.
+    assert (program_spans.self_ns(rows) >= 0).all()
+
+
+def test_fleet_queries_are_recorded_with_their_qid(async_fleet_run):
+    """query_queue (submit to admission) and query_service (admission to
+    drain) carry the query id and add up to the fleet's own lifecycle."""
+    fleet, qids, rows = async_fleet_run
+    queue = {int(r[3]): r for r in rows[rows[:, 2] == PHASE_NAMES.index("query_queue")]}
+    service = {int(r[3]): r for r in rows[rows[:, 2] == PHASE_NAMES.index("query_service")]}
+    assert sorted(queue) == sorted(service) == sorted(qids)
+    for qid in qids:
+        assert queue[qid][0] + queue[qid][1] == service[qid][0]  # admitted
+        assert queue[qid][1] >= 0 and service[qid][1] > 0
+
+
+def test_recorder_moves_no_jit_cache(async_fleet_run):
+    """The same stream again on the warm fleet compiles nothing: the
+    recorder touches no traced value."""
+    from kubernetriks_tpu.batched.fleet import Scenario, jit_cache_sizes
+
+    fleet, _, _ = async_fleet_run
+    first = {r.query: r.counters for r in fleet.poll()}
+    sizes = jit_cache_sizes()
+    again = [fleet.submit(Scenario(), h) for h in FLEET_HORIZONS]
+    fleet.run_async()
+    assert jit_cache_sizes() == sizes
+    assert [r.counters for r in sorted(fleet.poll(), key=lambda r: r.query)] == [
+        first[q] for q in sorted(first)
+    ]
+    assert len(again) == len(first)
+
+
+def test_one_lane_occupancy_gauge():
+    """The fleet's ledger and the observatory's lane_occupancy entry are
+    one gauge: the entry reports the recorder's two counters, which the
+    pump adds to where it adds to the ledger."""
+    from kubernetriks_tpu.batched.fleet import Scenario
+
+    fleet = _small_async_fleet(telemetry=True)
+    assert "lane_occupancy" not in fleet.engine.observatory.occupancy()
+    for h in FLEET_HORIZONS:
+        fleet.submit(Scenario(), h)
+    fleet.run_async()
+    mine = fleet.lane_occupancy()
+    theirs = fleet.engine.observatory.occupancy()["lane_occupancy"]
+    assert theirs["lane_windows_busy"] == mine["lane_windows_busy"] == 30
+    assert theirs["lane_windows_dispatched"] == mine["lane_windows_dispatched"] == 34
+    assert theirs["share"] == round(mine["share"], 4) == round(30 / 34, 4)
+    # Both zero together, and the recorder's counters stay time-resolved.
+    fleet.reset_query_stats()
+    assert fleet.lane_occupancy()["share"] == 1.0
+    assert "lane_occupancy" not in fleet.engine.observatory.occupancy()
+    samples = recorder().counter_samples("lane_windows_dispatched")
+    assert (np.diff(samples[:, 0]) >= 0).all() and (np.diff(samples[:, 1]) > 0).all()
+    fleet.close()
+
+
+def test_self_time_of_a_hand_made_nest():
+    """A span's self time is its duration less what its children cover;
+    siblings and later spans do not touch it. (ONE implementation, beside
+    its readers: benchmark/program_spans.py.)"""
+    self_ns = program_spans.self_ns
+    rows = np.array(
+        [
+            [0, 100, 0, 0],    # parent: children cover 20 + 30
+            [10, 20, 1, 0],    # leaf
+            [40, 30, 1, 0],    # child with a child of its own
+            [45, 10, 2, 0],    # grandchild
+            [200, 50, 0, 0],   # later, alone
+            [100, 5, 3, 0],    # starts as the parent ends: a sibling
+        ],
+        np.int64,
+    )
+    np.testing.assert_array_equal(self_ns(rows), [50, 20, 20, 10, 50, 5])
+    # Row order does not matter (the ring keeps rows by END time), and the
+    # rows a recorder kept read the same.
+    perm = np.array([3, 1, 2, 5, 0, 4])
+    np.testing.assert_array_equal(self_ns(rows[perm]), np.array([50, 20, 20, 10, 50, 5])[perm])
+    assert self_ns(rows[:0]).shape == (0,)
+    tr = SpanTracer(capacity=16)
+    for t0, dur, phase, ident in rows[perm].tolist():
+        tr.end(phase, t0, dur=dur, ident=ident)
+    by_phase = np.bincount(tr.rows()[:, 2], weights=self_ns(tr.rows()))
+    np.testing.assert_array_equal(by_phase, [100, 40, 10, 5])
+    assert tr.report()["spans"][PHASE_NAMES[0]]["total_ms"] == pytest.approx(150 / 1e6)
+
+
+def test_report_is_the_engines_own_when_engines_interleave(cheap_pair):
+    """Every engine writes to the one ring through a handle that keeps
+    its own aggregates: two engines stepped turn by turn each report
+    their own spans and time, and the two add up to what the shared
+    recorder took meanwhile (tune/measure.py scores one engine per
+    candidate in one process by this report's ms_per_window)."""
+    was = recorder().report()["spans"]
+    a = _build_plain(telemetry=True, telemetry_ring=16)
+    b = _build_plain(telemetry=True, telemetry_ring=16)
+    for end in ENDS:
+        a.step_until_time(end)
+        b.step_until_time(end)
+    now = recorder().report()["spans"]
+    reps = [a.telemetry_report(), b.telemetry_report()]
+    for rep, sim in zip(reps, (a, b)):
+        assert rep["spans"]["window_chunk"]["count"] == sim.dispatch_stats["window_chunks"]
+        assert rep["spans"]["step_until_time"]["count"] == len(ENDS)
+        assert rep["spans"]["engine_build"]["count"] == 1
+        assert rep["per_window"]["windows"] == sim.next_window_idx
+        assert rep["per_window"]["window_program_ms_total"] == pytest.approx(
+            rep["spans"]["window_chunk"]["total_ms"]
+        )
+    for phase in ("window_chunk", "step_until_time", "engine_build"):
+        grew = now[phase]["total_ms"] - was[phase]["total_ms"]
+        assert sum(r["spans"][phase]["total_ms"] for r in reps) == pytest.approx(grew)
+        assert now[phase]["count"] - was[phase]["count"] == sum(
+            r["spans"][phase]["count"] for r in reps
+        )
+    # The engine that compiled the program (cheap_pair's) keeps that time.
+    first = cheap_pair[0].telemetry_report()["per_window"]
+    assert first["windows"] == reps[0]["per_window"]["windows"]
+    assert first["ms_per_window"] > 0
+
+
+def test_a_failed_build_leaves_no_span_open():
+    """A constructor that raises still closes its `engine_build` span:
+    no annotation stays open under the next engine's spans."""
+    from kubernetriks_tpu.batched.fleet import ScenarioFleet
+
+    rec = recorder()
+    n0, depth = recorded(), len(rec._open_phase)
+    with pytest.raises(ValueError, match="at least one lane"):
+        ScenarioFleet(default_test_simulation_config(), [], [], n_lanes=0, horizon=10.0)
+    assert len(rec._open_phase) == len(rec._open_ann) == depth
+    assert len(rows_since(n0, "engine_build")) == 1
+
+
+def test_compile_rows_carry_the_logs_seconds():
+    """While a sentinel is installed, every 'Finished XLA compilation'
+    line of jax's compile log becomes one `compile` row whose duration is
+    the logged seconds and whose id finds the program's name."""
+    import logging
+
+    from kubernetriks_tpu.recompile import RecompileSentinel
+
+    rec = recorder()
+    n0, c0 = recorded(), rec.compiles_recorded
+    log = logging.getLogger("jax._src.dispatch")
+    line = "Finished XLA compilation of jit(_a_test_program) in 0.250000000 sec"
+    log.warning(line)  # no sentinel installed: not recorded
+    assert rec.compiles_recorded == c0
+    with RecompileSentinel("warn") as sentinel:
+        t_before = time.perf_counter_ns()
+        log.warning(line)
+        log.warning("Compiling jit(_a_test_program) with global shapes")  # another line
+        assert sentinel.events == ["jit(_a_test_program)"]
+    assert rec.compiles_recorded == c0 + 1
+    (row,) = rows_since(n0, "compile")
+    assert row[1] == 250_000_000 and row[3] == c0
+    assert row[0] + row[1] >= t_before  # ends at the log line, starts 0.25 s before
+    assert rec.compiles[-1] == ("jit(_a_test_program)", 0.25)
+
+
+def test_counter_samples_give_a_delta_over_any_window():
+    tr = SpanTracer(counter_capacity=8)
+    tr.count("a", 3)
+    t_mid = time.perf_counter_ns()
+    tr.count("b")
+    tr.count("a", 2)
+    samples = tr.counter_samples("a")
+    np.testing.assert_array_equal(samples[:, 1], [3, 5])
+    assert samples[0, 0] <= t_mid <= samples[1, 0]
+    assert tr.counter_samples("never").shape == (0, 2)
+    for _ in range(10):
+        tr.count("b")
+    assert tr.dropped()["counter_samples"] == 5
+    assert tr.counters == {"a": 5, "b": 11}
