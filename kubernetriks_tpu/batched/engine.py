@@ -4289,6 +4289,15 @@ class BatchedSimulation:
                     if name in dring.GAUGE_COLUMNS
                 },
             }
+            # Of the pod-block row tiles whole-block sweeps would have read
+            # in the megakernel's steps, the share its live-tile sweeps
+            # did read (None: no megakernel step was recorded).
+            totals = rep["ring"]["totals"]
+            rep["ring"]["cycle_rows_swept_share"] = (
+                totals["cycle_tiles_swept"] / totals["cycle_tile_steps"]
+                if totals["cycle_tile_steps"]
+                else None
+            )
         if self.observatory is not None:
             # Capacity-observatory section: occupancy (current +
             # high-water vs reserve capacity), host/device memory

@@ -251,7 +251,15 @@ TELEM_POD_HEADROOM = 10
 # lanes — ring.merge_snapshot keys on it), while every other column is the
 # lane's own (virtual-clock) value.
 TELEM_LANE_ACTIVE = 11
-TELEMETRY_COLS = 12
+# The scheduling megakernel's sweep counter (ops/scheduler_kernel.py, stats
+# rows 5-7): the row tiles of the pod block its steps swept this window, and
+# its steps times the block's tiles (what whole-block sweeps would have
+# cost). Their ratio over a run is telemetry_report()'s
+# cycle_rows_swept_share. Both are one value per 128-cluster grid program,
+# repeated on its lanes; zeros on formulations without the megakernel.
+TELEM_CYCLE_TILES_SWEPT = 12
+TELEM_CYCLE_TILE_STEPS = 13
+TELEMETRY_COLS = 14
 
 
 class TelemetryRing(NamedTuple):

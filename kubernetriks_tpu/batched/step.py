@@ -38,7 +38,7 @@ back to persistent state.
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1477,9 +1477,12 @@ def _run_scheduling_cycle(
     fault_params=None,
     lane_major: bool = False,
     profile=None,
-) -> ClusterBatchState:
+) -> Tuple[ClusterBatchState, Optional[jnp.ndarray]]:
     """One vectorized kube-scheduler cycle at window W for every cluster
-    (scalar equivalent: reference scheduler.rs:246-333).
+    (scalar equivalent: reference scheduler.rs:246-333). Returns the state
+    and, from the megakernel, its (C, 2) int32 sweep counter for the
+    telemetry ring (row tiles swept, steps x the block's tiles; None on the
+    other formulations, which sweep whole blocks).
 
     profile (pipeline.CompiledProfile, static; None = the reference
     default): the compiled scheduler profile whose filter-mask and
@@ -1595,11 +1598,14 @@ def _run_scheduling_cycle(
                 ),
             ),
         )
+        sweep = jnp.stack(
+            [qstats[:, 5], qstats[:, 6] * qstats[:, 7]], axis=-1
+        ).astype(jnp.int32)
         return commit_scattered_tail(
             state, pods, last_flush_win, W, consts, alloc_cpu, alloc_ram,
             metrics, phase, node, start_tmp, park_tmp,
             fault_params=fault_params,
-        )
+        ), sweep
     elif use_pallas and use_pallas_select:
         # Two-kernel fallback (KTPU_MEGAKERNEL=0): in-kernel selection+cycle,
         # commit as a second one-hot kernel — kept for A/B measurement.
@@ -1733,7 +1739,7 @@ def _run_scheduling_cycle(
         pallas_mesh=pallas_mesh,
         pallas_axis=pallas_axis,
         fault_params=fault_params,
-    )
+    ), None
 
 
 def _freeze_lanes(
@@ -1787,13 +1793,16 @@ def _telemetry_record(
     lane_major: bool = False,
     telem_window=None,
     lane_active=None,
+    cycle_sweep=None,
 ):
     """Fold one per-window record row into the device telemetry ring:
     metric-counter deltas vs the window's incoming metrics `m0` plus queue
     depths / alive-node counts / reserve-occupancy gauges read straight
-    off the post-window state. Pure bookkeeping — reads simulation state,
-    writes only the ring — so telemetry-on runs are bit-identical to
-    telemetry-off on every other leaf (tests/test_telemetry.py pins this).
+    off the post-window state, and the megakernel's sweep counter
+    (cycle_sweep, (C, 2); zeros without it). Pure bookkeeping — reads
+    simulation state, writes only the ring — so telemetry-on runs are
+    bit-identical to telemetry-off on every other leaf
+    (tests/test_telemetry.py pins this).
     Cost: two (C, P) phase reductions, one (C, N) reduction, two tiny
     (C, G) occupancy sums and one (C, 1, K) scatter per window, only
     compiled in when the ring exists (state.telemetry is a structural
@@ -1871,6 +1880,11 @@ def _telemetry_record(
                 lane_active.astype(jnp.int32)
                 if lane_active is not None
                 else jnp.ones_like(W)
+            ),
+            *(
+                (cycle_sweep[:, 0], cycle_sweep[:, 1])
+                if cycle_sweep is not None
+                else (jnp.zeros_like(W),) * 2
             ),
         ],
         axis=-1,
@@ -2011,7 +2025,7 @@ def _window_body(
         state.nodes.alloc_cpu,
         state.nodes.alloc_ram,
     )
-    state = _run_scheduling_cycle(
+    state, cycle_sweep = _run_scheduling_cycle(
         state,
         W,
         consts,
@@ -2076,6 +2090,7 @@ def _window_body(
                 lane_major=lane_major,
                 telem_window=telem_W,
                 lane_active=lane_active,
+                cycle_sweep=cycle_sweep,
             )
         )
     return state

@@ -421,11 +421,159 @@ def fused_select_schedule_cycle(
     )
 
 
+# --- live row tiles: what a step of a serial pod-block kernel sweeps --------
+#
+# The serial kernels pick ONE pod row per lane per step out of a (Pp, LC)
+# block. The rows a launch can ever pick are known before its loop starts
+# (the eligible / freed mask only loses members during the loop) and are
+# few: arrivals keep slot order, so a cycle's eligible rows are a narrow
+# band. Each grid program therefore lists, once per launch, the row tiles
+# that hold any candidate of any of its lanes, and every step sweeps those
+# tiles only. The list is read from the mask itself — every tile when the
+# mask is full, two runs of tiles when it has two bands (plain arrivals and
+# an HPA group's ring slots), none when it is empty — so one algorithm
+# serves every shape. Rows outside the list hold no candidate, so the
+# selections, their order and every one-hot write equal a whole-block
+# sweep's bit for bit.
+#
+# Tile height: 16 vregs of rows, chosen on the chip and not a knob (PERF.md
+# section 6, PR 27: on a band-shaped mask 32 to 256 rows time alike, on a
+# full mask 128 is the fastest and the only one that matches a whole-block
+# sweep).
+_ROW_TILE = 128
+
+
+def _row_tiles(n_rows: int) -> Tuple[int, int]:
+    """(tile height, tiles) of a block of n_rows sublane-padded rows."""
+    tile = min(_ROW_TILE, n_rows)
+    return tile, -(-n_rows // tile)
+
+
+def _tile_start(t, n_rows: int):
+    """First row of tile t. Where the tile height does not divide the block
+    the last tile starts early and overlaps its neighbour: every sweep is
+    idempotent (a strict minimum, a one-hot write), so a row seen twice
+    changes nothing."""
+    tile, _ = _row_tiles(n_rows)
+    return pl.multiple_of(jnp.minimum(t * jnp.int32(tile), jnp.int32(n_rows - tile)), _SUB)
+
+
+def _while_i32(lo, hi, body, init):
+    """fori_loop with an explicit int32 induction variable (see the note in
+    _cycle_kernel: under x64 fori_loop's own is i64, which Mosaic refuses)."""
+
+    def step(c):
+        return c[0] + jnp.int32(1), body(c[0], c[1])
+
+    return jax.lax.while_loop(lambda c: c[0] < hi, step, (lo, init))[1]
+
+
+def _list_live_tiles(mask_ref, live_ref):
+    """Write into live_ref (SMEM, one int32 per tile) the indices of the row
+    tiles of mask_ref (int32 0/1) that hold a set entry in any lane, in
+    rising order; return how many there are."""
+    n_rows = mask_ref.shape[0]
+    tile, n_tiles = _row_tiles(n_rows)
+
+    def body(t, n_live):
+        rows = pl.ds(_tile_start(t, n_rows), tile)
+        live_ref[n_live] = t  # kept only if the count moves past it
+        return n_live + (jnp.max(mask_ref[rows, :]) != jnp.int32(0)).astype(jnp.int32)
+
+    return _while_i32(jnp.int32(0), jnp.int32(n_tiles), body, jnp.int32(0))
+
+
+def _sweep_live_tiles(n_live, live_ref, n_rows: int, body, init=None):
+    """body(first row of the tile, carry) -> carry, over the listed tiles."""
+    return _while_i32(
+        jnp.int32(0),
+        n_live,
+        lambda i, c: body(_tile_start(live_ref[i], n_rows), c),
+        init,
+    )
+
+
+def _select_first(n_live, live_ref, n_rows: int, remaining, key_refs, carried):
+    """Per lane, the remaining row that is first in the lexicographic order
+    of key_refs (int32, below INT32_MAX on remaining rows), ties to the
+    lowest slot — with no keys, the first remaining row — found in ONE pass
+    over the live tiles. remaining(rows, slots) says which of 8 rows
+    (a pl.ds and their (8, LC) slot numbers) still compete. `carried` lists
+    (ref, fill) pairs whose value at the chosen row comes back with it
+    (fill where the lane has no remaining row), so the sweep reads each
+    block once and no gather follows.
+
+    The pass keeps a running best per (sublane, lane) position in vregs —
+    row r competes with the rows congruent to it mod 8, elementwise, no
+    cross-sublane traffic — and the 8 survivors of a lane are reduced once
+    at the end. A strict comparison keeps the earlier row on a tie and the
+    sweep visits rows in rising order, so the survivor of a position is its
+    lowest-slot minimum. Returns (slot (1, LC) int32, -1 for a lane with
+    nothing left; [value (1, LC) per carried ref])."""
+    tile, _ = _row_tiles(n_rows)
+    i0 = jnp.int32(0)
+    neg1 = jnp.int32(-1)
+    bigi = jnp.int32(np.iinfo(np.int32).max)
+    shape = (_SUB, _LANE)
+    iota8 = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+
+    def tile_body(start, best):
+        for g in range(tile // _SUB):
+            first = start + jnp.int32(g * _SUB)
+            rows = pl.ds(first, _SUB)
+            slot, keys, vals = best
+            slots = iota8 + first
+            better = slot < i0  # no key: the first remaining row wins
+            for ref, key in zip(reversed(key_refs), reversed(keys)):
+                k = ref[rows, :]
+                better = (k < key) | ((k == key) & better)
+            better = better & remaining(rows, slots)
+            best = (
+                jnp.where(better, slots, slot),
+                tuple(
+                    jnp.where(better, ref[rows, :], key)
+                    for ref, key in zip(key_refs, keys)
+                ),
+                tuple(
+                    jnp.where(better, ref[rows, :], val)
+                    for (ref, _), val in zip(carried, vals)
+                ),
+            )
+        return best
+
+    slot, keys, vals = _sweep_live_tiles(
+        n_live,
+        live_ref,
+        n_rows,
+        tile_body,
+        (
+            jnp.full(shape, neg1),
+            tuple(jnp.full(shape, bigi) for _ in key_refs),
+            tuple(jnp.full(shape, fill) for _, fill in carried),
+        ),
+    )
+    # The 8 survivors of a lane: the staged minimum a whole-block sweep
+    # makes over all rows, here over one vreg.
+    sel = slot >= i0
+    for key in keys:
+        m = jnp.min(jnp.where(sel, key, bigi), axis=0, keepdims=True)
+        sel = sel & (key == m)
+    first = jnp.min(jnp.where(sel, slot, bigi), axis=0, keepdims=True)
+    sel = sel & (slot == first)
+    return (
+        jnp.where(first == bigi, neg1, first),
+        [
+            jnp.max(jnp.where(sel, val, fill), axis=0, keepdims=True)
+            for (_, fill), val in zip(carried, vals)
+        ],
+    )
+
+
 def free_kernel_fits(n_nodes: int, n_pods: int) -> bool:
-    """VMEM fits-check for the freed-resource kernel: 7 pod blocks (incl.
-    finish mask, estimator values and scratch) + 4 node blocks,
-    double-buffered by Mosaic, plus stack temporaries for the loop body's
-    (Pp, LC) masks — the kernel raises the scoped limit to
+    """VMEM fits-check for the freed-resource kernel: 6 pod blocks (incl.
+    finish mask and estimator values) + 4 node blocks, double-buffered by
+    Mosaic, and a seventh pod block's worth of room for the loop body's
+    temporaries — the kernel raises the scoped limit to
     _SELECT_VMEM_LIMIT, the check keeps ~40% headroom."""
     np_pad = -(-n_nodes // _SUB) * _SUB
     pp_pad = -(-n_pods // _SUB) * _SUB
@@ -445,7 +593,7 @@ def _free_kernel(
     acpu_out,      # (Np, LC) int32
     aram_out,      # (Np, LC) int32
     stats_out,     # (8, LC) float32: rows count/total/total_sq/min/max
-    rem_ref,       # (Pp, LC) int32 scratch
+    live_ref,      # SMEM (row tiles,) int32 scratch: the live tile list
 ):
     """Return freed pods' requests to their nodes' allocatable — the batched
     analog of the per-event resource release (reference:
@@ -457,6 +605,13 @@ def _free_kernel(
     the deepest lane's freed count. Integer adds commute, so the result is
     bit-identical to the XLA loop.
 
+    What a step sweeps: ONE pass over the block's live row tiles (those
+    holding a freed row of any lane, _list_live_tiles) that finds the
+    lane's next freed row — the first above the row it took the step
+    before, so no remaining-mask is kept or cleared — and brings its node,
+    requests, finish bit and sample along (_select_first); then the two
+    whole-tile node one-hot adds.
+
     The same iteration also folds the pod-duration estimator samples of the
     FINISHED subset (stats_out rows 0..4: count/total/total_sq/min/max) —
     replacing the five (C, P) masked reductions of _est_add_reduced, whose
@@ -465,7 +620,6 @@ def _free_kernel(
     documented metric-accumulator tolerance (docs/PARITY.md)."""
     i0 = jnp.int32(0)
     neg1 = jnp.int32(-1)
-    bigi = jnp.int32(np.iinfo(np.int32).max)
     f0 = jnp.float32(0.0)
     f1 = jnp.float32(1.0)
     finf = jnp.float32(np.inf)
@@ -475,37 +629,44 @@ def _free_kernel(
     stats_out[:] = jnp.zeros_like(stats_out)
     stats_out[3:4, :] = stats_out[3:4, :] + finf
     stats_out[4:5, :] = stats_out[4:5, :] - finf
-    rem_ref[:] = freed_ref[:]
-    iota_p = jax.lax.broadcasted_iota(jnp.int32, freed_ref.shape, 0)
     iota_n = jax.lax.broadcasted_iota(jnp.int32, acpu_ref.shape, 0)
     k_bound = jnp.max(jnp.sum(freed_ref[:], axis=0, keepdims=True))
+    n_live = _list_live_tiles(freed_ref, live_ref)
 
-    def body(k):
-        rem = rem_ref[:] != i0
-        first = jnp.min(jnp.where(rem, iota_p, bigi), axis=0, keepdims=True)
-        sel = rem & (iota_p == first)
-        seli = sel.astype(jnp.int32)
-        node = jnp.max(jnp.where(sel, node_ref[:], neg1), axis=0, keepdims=True)
-        rc = jnp.max(seli * reqc_ref[:], axis=0, keepdims=True)
-        rr = jnp.max(seli * reqr_ref[:], axis=0, keepdims=True)
+    def body(carry):
+        k, taken = carry  # taken: (1, LC) the slot each lane freed last
+        slot, (node, rc, rr, fin, v) = _select_first(
+            n_live,
+            live_ref,
+            freed_ref.shape[0],
+            lambda rows, slots: (freed_ref[rows, :] != i0) & (slots > taken),
+            (),
+            (
+                (node_ref, neg1),
+                (reqc_ref, i0),
+                (reqr_ref, i0),
+                (finish_ref, i0),
+                (value_ref, -finf),
+            ),
+        )
         oh = iota_n == node  # node == -1 (empty lane) matches nothing
         acpu_out[:] = acpu_out[:] + jnp.where(oh, rc, i0)
         aram_out[:] = aram_out[:] + jnp.where(oh, rr, i0)
-        rem_ref[:] = jnp.where(sel, i0, rem_ref[:])
 
-        fin = jnp.max(seli * finish_ref[:], axis=0, keepdims=True) > i0
-        v = jnp.max(jnp.where(sel, value_ref[:], -finf), axis=0, keepdims=True)
+        fin = fin > i0
         stats_out[0:1, :] = stats_out[0:1, :] + jnp.where(fin, f1, f0)
         stats_out[1:2, :] = stats_out[1:2, :] + jnp.where(fin, v, f0)
         stats_out[2:3, :] = stats_out[2:3, :] + jnp.where(fin, v * v, f0)
         stats_out[3:4, :] = jnp.minimum(stats_out[3:4, :], jnp.where(fin, v, finf))
         stats_out[4:5, :] = jnp.maximum(stats_out[4:5, :], jnp.where(fin, v, -finf))
+        # A lane with nothing left keeps its mark: slot is -1 there.
+        return k + jnp.int32(1), jnp.maximum(taken, slot)
 
-    def loop_body(k):
-        body(k)
-        return k + jnp.int32(1)
-
-    jax.lax.while_loop(lambda k: k < k_bound, loop_body, jnp.int32(0))
+    jax.lax.while_loop(
+        lambda c: c[0] < k_bound,
+        body,
+        (jnp.int32(0), jnp.full((1, freed_ref.shape[1]), neg1)),
+    )
 
 
 @functools.partial(
@@ -562,7 +723,7 @@ def fused_free_resources(
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((8, Cp), jnp.float32),
             ],
-            scratch_shapes=[pltpu.VMEM((Pp, _LANE), jnp.int32)],
+            scratch_shapes=[pltpu.SMEM((_row_tiles(Pp)[1],), jnp.int32)],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
@@ -611,6 +772,7 @@ def _event_kernel(
     pcr_out,
     pseq_out,
     prm_out,
+    live_ref,     # SMEM (row tiles,) int32 scratch: the live tile list
 ):
     """Apply one chunk of due trace events to the per-slot accumulators —
     the Pallas replacement for the five (C, E)-indexed XLA scatters in
@@ -618,9 +780,16 @@ def _event_kernel(
     shapes). Event k is applied across all cluster lanes simultaneously via
     slot one-hots; min/max combiners match the scatter semantics exactly,
     and out-of-range slots (shifted-out sliding-window pods) match no
-    one-hot row, reproducing mode='drop'."""
+    one-hot row, reproducing mode='drop'.
+
+    What a step sweeps: the two node accumulators whole, the three pod
+    accumulators only over the row tiles between the lowest and the highest
+    pod slot any valid event of the chunk names (a window's creates are
+    neighbours in slot order) — no other row can match a one-hot."""
     i0 = jnp.int32(0)
     i1 = jnp.int32(1)
+    neg1 = jnp.int32(-1)
+    bigi = jnp.int32(np.iinfo(np.int32).max)
 
     created_out[:] = created_ref[:]
     nrm_out[:] = nrm_ref[:]
@@ -629,8 +798,27 @@ def _event_kernel(
     prm_out[:] = prm_ref[:]
 
     iota_n = jax.lax.broadcasted_iota(jnp.int32, created_ref.shape, 0)
-    iota_p = jax.lax.broadcasted_iota(jnp.int32, pcr_ref.shape, 0)
     k_bound = jnp.max(jnp.sum(valid_ref[:], axis=0, keepdims=True))
+
+    n_rows = pcr_ref.shape[0]
+    tile, _ = _row_tiles(n_rows)
+    iota_t = jax.lax.broadcasted_iota(jnp.int32, (tile, pcr_ref.shape[1]), 0)
+    kinds, slots = kind_ref[:], slot_ref[:]
+    pod_ev = (
+        (valid_ref[:] != i0)
+        & ((kinds == jnp.int32(_EV_CREATE_POD)) | (kinds == jnp.int32(_EV_REMOVE_POD)))
+        & (slots >= i0)
+        & (slots < jnp.int32(n_rows))
+    )
+    # No pod event: lowest is INT32_MAX and the list stays empty.
+    lowest = jax.lax.div(jnp.min(jnp.where(pod_ev, slots, bigi)), jnp.int32(tile))
+    highest = jax.lax.div(jnp.max(jnp.where(pod_ev, slots, neg1)), jnp.int32(tile))
+    n_live = jnp.maximum(highest + i1 - lowest, i0)
+
+    def list_tile(i, _):
+        live_ref[i] = lowest + i
+
+    _while_i32(i0, n_live, list_tile, None)
 
     def body(k):
         kind = kind_ref[pl.ds(k, 1), :]
@@ -649,16 +837,21 @@ def _event_kernel(
         nrm_out[:] = jnp.where(
             oh_n & is_rn, jnp.minimum(nrm_out[:], rel), nrm_out[:]
         )
-        oh_p = iota_p == slot
-        pcr_out[:] = jnp.where(
-            oh_p & is_cp, jnp.minimum(pcr_out[:], rel), pcr_out[:]
-        )
-        pseq_out[:] = jnp.where(
-            oh_p & is_cp, jnp.maximum(pseq_out[:], seq), pseq_out[:]
-        )
-        prm_out[:] = jnp.where(
-            oh_p & is_rp, jnp.minimum(prm_out[:], rel), prm_out[:]
-        )
+
+        def scatter(start, _):
+            rows = pl.ds(start, tile)
+            oh_p = (iota_t + start) == slot
+            pcr_out[rows, :] = jnp.where(
+                oh_p & is_cp, jnp.minimum(pcr_out[rows, :], rel), pcr_out[rows, :]
+            )
+            pseq_out[rows, :] = jnp.where(
+                oh_p & is_cp, jnp.maximum(pseq_out[rows, :], seq), pseq_out[rows, :]
+            )
+            prm_out[rows, :] = jnp.where(
+                oh_p & is_rp, jnp.minimum(prm_out[rows, :], rel), prm_out[rows, :]
+            )
+
+        _sweep_live_tiles(n_live, live_ref, n_rows, scatter)
 
     def loop_body(k):
         body(k)
@@ -737,6 +930,7 @@ def fused_event_scatter(
             in_specs=[spec(Ep)] * 5 + [spec(Np)] * 2 + [spec(Pp)] * 3,
             out_specs=[spec(Np)] * 2 + [spec(Pp)] * 3,
             out_shape=shapes,
+            scratch_shapes=[pltpu.SMEM((_row_tiles(Pp)[1],), jnp.int32)],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
@@ -989,8 +1183,9 @@ def select_commit_kernel_fits(n_nodes: int, n_pods: int, k_pods: int) -> bool:
 def _argmin_select(rem, qwin_ref, qoff_ref, qseq_ref, iota_p):
     """ONE in-kernel definition of the per-lane lexicographic argmin over
     (queue win, off-bits, seq) — the batched ActiveQueue's sorted order —
-    shared by _select_cycle_kernel and _select_cycle_commit_kernel (the
-    same dedup _fit_score_place provides for the decision core).
+    for _select_cycle_kernel, the two-kernel fallback, which sweeps its
+    whole pod block every step (the megakernel selects over its live row
+    tiles with _select_first instead).
     Returns (sel one-hot (Pp, LC), seli int, slot (1, LC), valid (1, LC))."""
     i0 = jnp.int32(0)
     neg1 = jnp.int32(-1)
@@ -1035,17 +1230,29 @@ def _select_cycle_commit_kernel(
     node_out,       # (Pp, LC) int32
     start_out,      # (Pp, LC) float32 (+inf = untouched)
     park_out,       # (Pp, LC) float32 (+inf = untouched)
-    stats_out,      # (8, LC) float32: count/total/total_sq/min/max of
-                    #   queue-time samples over assigned decisions
+    stats_out,      # (8, LC) float32: rows 0-4 count/total/total_sq/min/max
+                    #   of queue-time samples over assigned decisions; rows
+                    #   5-7 the sweep counter (below)
     rem_ref,        # (Pp, LC) int32 scratch
+    live_ref,       # SMEM (row tiles,) int32 scratch: the live tile list
 ):
     """The whole-window scheduling megakernel (VERDICT r3 item 2): queue
     SELECTION (iterated 3-key argmin, _select_cycle_kernel), the
     fit/score/place CYCLE, and the decision COMMIT (the per-pod phase/node/
-    start/park writes of _commit_kernel — the selection one-hot IS the
-    commit's scatter mask) run in one Pallas launch, plus the queue-time
-    estimator fold (the free kernel's stats pattern). Replaces two kernel
-    launches and the (C, K) timing/metric XLA glue between them.
+    start/park writes of _commit_kernel) run in one Pallas launch, plus the
+    queue-time estimator fold (the free kernel's stats pattern). Replaces
+    two kernel launches and the (C, K) timing/metric XLA glue between them.
+
+    What a step sweeps: the pod side only over the block's LIVE row tiles
+    (the tiles holding an eligible row of any lane, listed once per launch:
+    see _list_live_tiles) — one pass reading rem, the three queue keys, the
+    two requests and waited to pick the lane's next pod with its values
+    (_select_first), and one pass writing phase/node/start/park/rem where
+    the row is the chosen slot. The node side (_fit_score_place) sweeps the
+    whole (Np, LC) tile as before. The copies and +inf fills before the
+    loop are whole-block, once a launch. stats_out rows 5/6/7 report, per
+    lane of the program: live tiles x steps (the row tiles swept), steps,
+    and the block's tiles — the ring's cycle_rows_swept_share.
 
     Timing bit-exactness: the positional tables qpre/start/park are
     computed OUTSIDE with the same cumsum cycle_timing uses on an all-valid
@@ -1057,8 +1264,6 @@ def _select_cycle_commit_kernel(
     documented ulp-level metric tolerance (docs/PARITY.md)."""
     i0 = jnp.int32(0)
     i1 = jnp.int32(1)
-    neg1 = jnp.int32(-1)
-    bigi = jnp.int32(np.iinfo(np.int32).max)
     f0 = jnp.float32(0.0)
     f1 = jnp.float32(1.0)
     finf = jnp.float32(np.inf)
@@ -1077,17 +1282,27 @@ def _select_cycle_commit_kernel(
     iota_n = jax.lax.broadcasted_iota(jnp.int32, alive.shape, 0)
     node_ok = iota_n < jnp.int32(n_nodes)
     rem_ref[:] = elig_ref[:]
-    iota_p = jax.lax.broadcasted_iota(jnp.int32, elig_ref.shape, 0)
     depth = jnp.max(jnp.sum(elig_ref[:], axis=0, keepdims=True))
     k_bound = jnp.minimum(depth, jnp.int32(k_pods))
 
+    n_rows = elig_ref.shape[0]
+    tile, n_tiles = _row_tiles(n_rows)
+    n_live = _list_live_tiles(elig_ref, live_ref)
+    iota_t = jax.lax.broadcasted_iota(jnp.int32, (tile, elig_ref.shape[1]), 0)
+    stats_out[5:6, :] = stats_out[5:6, :] + (n_live * k_bound).astype(jnp.float32)
+    stats_out[6:7, :] = stats_out[6:7, :] + k_bound.astype(jnp.float32)
+    stats_out[7:8, :] = stats_out[7:8, :] + jnp.float32(n_tiles)
+
     def body(k):
-        rem = rem_ref[:] != i0
-        sel, seli, slot, valid = _argmin_select(
-            rem, qwin_ref, qoff_ref, qseq_ref, iota_p
+        slot, (rc, rr, waited) = _select_first(
+            n_live,
+            live_ref,
+            n_rows,
+            lambda rows, _: rem_ref[rows, :] != i0,
+            (qwin_ref, qoff_ref, qseq_ref),
+            ((preq_cpu_ref, i0), (preq_ram_ref, i0), (waited_ref, -finf)),
         )
-        rc = jnp.max(seli * preq_cpu_ref[:], axis=0, keepdims=True)
-        rr = jnp.max(seli * preq_ram_ref[:], axis=0, keepdims=True)
+        valid = slot >= i0
 
         assign, any_fit, best, new_cpu, new_ram = _fit_score_place(
             profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
@@ -1097,22 +1312,27 @@ def _select_cycle_commit_kernel(
         ram_out[:] = new_ram
         park = valid & ~any_fit
 
-        # COMMIT: the selection one-hot is the scatter mask.
+        # COMMIT: the chosen slot's row is the scatter mask (slot -1, a
+        # lane with nothing left, matches no row).
         new_phase = jnp.where(
             assign, jnp.int32(_PHASE_RUNNING), jnp.int32(_PHASE_UNSCHEDULABLE)
         )
         touched = assign | park
-        phase_out[:] = jnp.where(sel & touched, new_phase, phase_out[:])
-        node_out[:] = jnp.where(sel & assign, best, node_out[:])
         start_s = start_ref[pl.ds(k, 1), :]
         park_s = park_ref[pl.ds(k, 1), :]
-        start_out[:] = jnp.where(sel & assign, start_s, start_out[:])
-        park_out[:] = jnp.where(sel & park, park_s, park_out[:])
+
+        def commit(start, _):
+            rows = pl.ds(start, tile)
+            sel = (iota_t + start) == slot
+            phase_out[rows, :] = jnp.where(sel & touched, new_phase, phase_out[rows, :])
+            node_out[rows, :] = jnp.where(sel & assign, best, node_out[rows, :])
+            start_out[rows, :] = jnp.where(sel & assign, start_s, start_out[rows, :])
+            park_out[rows, :] = jnp.where(sel & park, park_s, park_out[rows, :])
+            rem_ref[rows, :] = jnp.where(sel, i0, rem_ref[rows, :])
+
+        _sweep_live_tiles(n_live, live_ref, n_rows, commit)
 
         # Queue-time estimator fold over assigned decisions.
-        waited = jnp.max(
-            jnp.where(sel, waited_ref[:], -finf), axis=0, keepdims=True
-        )
         qtime = waited + qpre_ref[pl.ds(k, 1), :]
         stats_out[0:1, :] = stats_out[0:1, :] + jnp.where(assign, f1, f0)
         stats_out[1:2, :] = stats_out[1:2, :] + jnp.where(assign, qtime, f0)
@@ -1125,8 +1345,6 @@ def _select_cycle_commit_kernel(
         stats_out[4:5, :] = jnp.maximum(
             stats_out[4:5, :], jnp.where(assign, qtime, -finf)
         )
-
-        rem_ref[:] = jnp.where(sel, i0, rem_ref[:])
 
     def loop_body(k):
         body(k)
@@ -1161,9 +1379,11 @@ def fused_select_cycle_commit(
     profile=None,  # pipeline.CompiledProfile; None = the default profile
 ):
     """Megakernel wrapper. Returns (alloc_cpu, alloc_ram, phase, node,
-    start_tmp (+inf untouched), park_tmp, qstats (C, 5)). With
-    nodes_lane_major the node operands arrive and the allocatables return
-    (N, C) lane-major (no transposes at this boundary)."""
+    start_tmp (+inf untouched), park_tmp, qstats (C, 8): the queue-time
+    fold in columns 0-4, the kernel's sweep counter in 5-7, as its
+    stats_out rows). With nodes_lane_major the node operands arrive and the
+    allocatables return (N, C) lane-major (no transposes at this
+    boundary)."""
     C, P = eligible.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
     K = k_pods
@@ -1220,7 +1440,10 @@ def fused_select_cycle_commit(
                 jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
                 jax.ShapeDtypeStruct((8, Cp), jnp.float32),
             ],
-            scratch_shapes=[pltpu.VMEM((Pp, _LANE), jnp.int32)],
+            scratch_shapes=[
+                pltpu.VMEM((Pp, _LANE), jnp.int32),
+                pltpu.SMEM((_row_tiles(Pp)[1],), jnp.int32),
+            ],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
@@ -1238,5 +1461,5 @@ def fused_select_cycle_commit(
         node_o[:P, :C].T,
         start_o[:P, :C].T,
         park_o[:P, :C].T,
-        stats_o[:5, :C].T,
+        stats_o[:, :C].T,
     )

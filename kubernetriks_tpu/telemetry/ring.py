@@ -55,6 +55,12 @@ RING_COLUMNS = (
     # outside lane-async builds. The observatory's lane-occupancy gauge
     # and idle-lane-waste verdict fold this column.
     "lane_active",
+    # The megakernel's sweep counter (state.TELEM_CYCLE_TILES_SWEPT /
+    # _TILE_STEPS): pod-block row tiles its steps swept, and steps x the
+    # block's tiles. telemetry_report()["ring"]["cycle_rows_swept_share"]
+    # is the ratio of their totals.
+    "cycle_tiles_swept",
+    "cycle_tile_steps",
 )
 assert len(RING_COLUMNS) == TELEMETRY_COLS
 

@@ -156,7 +156,7 @@ def test_kernel_matches_scan_reference(shape, profile):
     np.testing.assert_array_equal(np.asarray(out[4]), ram_ref)
 
 
-def _build(use_pallas, profile=None):
+def _build(use_pallas, profile=None, **kwargs):
     config = SimulationConfig.from_yaml(
         "sim_name: pallas_parity\nseed: 9\nscheduling_cycle_interval: 10.0"
     )
@@ -178,6 +178,7 @@ def _build(use_pallas, profile=None):
         use_pallas=use_pallas,
         pallas_interpret=use_pallas,
         scheduler_profile=profile,
+        **kwargs,
     )
 
 
@@ -299,15 +300,170 @@ def test_full_sim_selection_kernel_matches_scan():
     assert not bad, bad
 
 
+# --- live row tiles: the masks the serial pod-block kernels must survive ------
+
+# (C, P) bool masks by name. P = 300 pads to 304 rows: three 128-row tiles,
+# the last starting early (row 176) and overlapping the second; P = 256 is
+# two whole tiles. Each is a case of the megakernel, free and event tests
+# below: the kernels list the tiles holding a set row of any lane and sweep
+# only those, and must equal the whole-block references bit for bit.
+LIVE_MASKS = {
+    "inside_one_tile": lambda rng, C, P: _band(C, P, 10, 30) & (rng.random((C, P)) < 0.7),
+    "across_a_tile_edge": lambda rng, C, P: _band(C, P, 118, 140),
+    "two_bands_far_apart": lambda rng, C, P: _band(C, P, 3, 9)
+    | (_band(C, P, P - 20, P - 4) & (rng.random((C, P)) < 0.5)),
+    "a_band_of_its_own_per_lane": lambda rng, C, P: np.stack(
+        [_band(1, P, 40 * c, 40 * c + 12)[0] for c in range(C)]
+    ),
+    "no_row": lambda rng, C, P: np.zeros((C, P), bool),
+    "every_row": lambda rng, C, P: np.ones((C, P), bool),
+    "scattered": lambda rng, C, P: rng.random((C, P)) < 0.5,
+}
+
+
+def _band(C, P, lo, hi):
+    cols = np.arange(P)[None, :]
+    return np.broadcast_to((cols >= lo) & (cols < hi), (C, P)).copy()
+
+
+def _live_tiles(mask):
+    """(live tiles, tiles) of one 128-lane block as the kernels count them:
+    tiles of scheduler_kernel._ROW_TILE rows over the sublane-padded block,
+    the last one starting early where the height does not divide it."""
+    from kubernetriks_tpu.ops import scheduler_kernel as sk
+
+    n_rows = -(-mask.shape[1] // 8) * 8
+    tile, n_tiles = sk._row_tiles(n_rows)
+    rows = np.zeros(n_rows, bool)
+    rows[: mask.shape[1]] = mask.any(axis=0)
+    starts = [min(t * tile, n_rows - tile) for t in range(n_tiles)]
+    return sum(bool(rows[s : s + tile].any()) for s in starts), n_tiles
+
+
+def megakernel_oracle(alive, alloc_cpu, alloc_ram, eligible, qwin, qoff, qseq,
+                      req_cpu, req_ram, waited, phase, node, qpre, start_t,
+                      park_t, K):
+    """selection_oracle's decisions committed the way commit_cycle's
+    scatters do, and the queue-time estimator folded in decision order in
+    float32 (the kernel's own order, so the sums are exact too)."""
+    cand, valid, assign, fit_any, best, cpu, ram = selection_oracle(
+        alive, alloc_cpu, alloc_ram, eligible, qwin, qoff, qseq,
+        req_cpu, req_ram, K,
+    )
+    C, P = eligible.shape
+    phase, node = phase.copy(), node.copy()
+    start = np.full((C, P), np.inf, np.float32)
+    park = np.full((C, P), np.inf, np.float32)
+    stats = np.zeros((C, 5), np.float32)
+    stats[:, 3], stats[:, 4] = np.inf, -np.inf
+    for c in range(C):
+        for k in range(K):
+            if not valid[c, k]:
+                continue
+            s = cand[c, k]
+            if assign[c, k]:
+                phase[c, s], node[c, s] = 3, best[c, k]
+                start[c, s] = start_t[c, k]
+                q = np.float32(waited[c, s] + qpre[c, k])
+                stats[c, 0] += np.float32(1.0)
+                stats[c, 1] += q
+                stats[c, 2] += np.float32(q * q)
+                stats[c, 3] = min(stats[c, 3], q)
+                stats[c, 4] = max(stats[c, 4], q)
+            else:
+                phase[c, s] = 2
+                park[c, s] = park_t[c, k]
+    return cpu, ram, phase, node, start, park, stats
+
+
+def _megakernel_case(C, N, P, K, eligible, seed):
+    rng = np.random.default_rng(seed)
+    alive = rng.random((C, N)) < 0.8
+    cap = rng.integers(1_000, 64_000, size=(C, N)).astype(np.int32)
+    alloc_cpu = (cap * rng.random((C, N))).astype(np.int32)
+    alloc_ram = (cap * rng.random((C, N))).astype(np.int32)
+    qwin = rng.integers(0, 5, size=(C, P)).astype(np.int32)
+    # Quantized offsets, as in the select test above: (win, off) collide
+    # often, so the seq stage of the order decides.
+    qoff = rng.integers(0, 4, size=(C, P)).astype(np.float32) * np.float32(2.5)
+    qseq = np.stack([rng.permutation(P) for _ in range(C)]).astype(np.int32)
+    # Requests up to 24,000 against allocatables under 64,000: some fit
+    # nowhere, so both the assign and the park writes are exercised.
+    req_cpu = rng.integers(0, 24_000, size=(C, P)).astype(np.int32)
+    req_ram = rng.integers(0, 24_000, size=(C, P)).astype(np.int32)
+    waited = rng.uniform(0.0, 50.0, size=(C, P)).astype(np.float32)
+    phase = rng.integers(0, 4, size=(C, P)).astype(np.int32)
+    node = rng.integers(-1, N, size=(C, P)).astype(np.int32)
+    qpre = np.cumsum(rng.uniform(0, 1, size=(C, K)), axis=1).astype(np.float32)
+    return (alive, alloc_cpu, alloc_ram, eligible, qwin, qoff, qseq, req_cpu,
+            req_ram, waited, phase, node, qpre, qpre + np.float32(0.5),
+            qpre + np.float32(0.25))
+
+
+@pytest.mark.parametrize(
+    "mask,shape",
+    [(name, (5, 20, 300, 9)) for name in LIVE_MASKS]
+    + [
+        ("scattered", (3, 7, 256, 5)),  # the tile height divides the block
+        ("scattered", (3, 7, 20, 5)),  # the block is shorter than a tile
+        ("deepest_lane_at_k", (5, 20, 300, 9)),
+        ("deepest_lane_past_k", (5, 20, 300, 9)),
+        ("scattered", (130, 9, 300, 4)),  # two grid programs
+    ],
+)
+def test_megakernel_matches_sort_scan_and_commit(mask, shape):
+    """The megakernel against the NumPy restatement of what it fuses (the
+    sorted top-K selection, the scan core, commit_cycle's scatters, the
+    estimator fold): every output bit-identical, and the sweep counter in
+    its stats rows 5-7 equal to the live tiles of the mask it was given."""
+    from kubernetriks_tpu.ops.scheduler_kernel import fused_select_cycle_commit
+
+    C, N, P, K = shape
+    rng = np.random.default_rng(len(mask) + P)
+    if mask.startswith("deepest_lane"):
+        # One lane holds exactly K (or K + 3) eligible rows, the others few.
+        eligible = _band(C, P, 50, 53)
+        eligible[2] = _band(1, P, 100, 100 + K + (3 if mask.endswith("past_k") else 0))[0]
+    else:
+        eligible = LIVE_MASKS[mask](rng, C, P)
+    args = _megakernel_case(C, N, P, K, eligible, seed=P + C)
+    got = fused_select_cycle_commit(
+        *(jnp.asarray(a) for a in args), k_pods=K, interpret=True
+    )
+    want = megakernel_oracle(*args, K)
+    got = [np.asarray(g) for g in got]
+    for name, g, w in zip(
+        ("alloc_cpu", "alloc_ram", "phase", "node", "start", "park"), got, want
+    ):
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    np.testing.assert_array_equal(got[6][:, :5], want[6], err_msg="estimator rows")
+    for lo in range(0, C, 128):  # one counter per 128-lane grid program
+        block = eligible[lo : lo + 128]
+        live, tiles = _live_tiles(block)
+        steps = min(int(block.sum(axis=1).max()), K)
+        np.testing.assert_array_equal(
+            got[6][lo : lo + 128, 5:],
+            np.broadcast_to(
+                np.float32([live * steps, steps, tiles]), (len(block), 3)
+            ),
+        )
+
+
 # --- free / event / commit scatter kernels -----------------------------------
 
 
-def test_free_kernel_matches_scatter_add():
+@pytest.mark.parametrize(
+    "mask,shape",
+    [("scattered", (5, 40, 9))]
+    + [(name, (5, 300, 9)) for name in LIVE_MASKS]
+    + [("scattered", (3, 256, 7)), ("scattered", (130, 300, 5))],
+)
+def test_free_kernel_matches_scatter_add(mask, shape):
     from kubernetriks_tpu.ops.scheduler_kernel import fused_free_resources
 
     rng = np.random.default_rng(7)
-    C, P, N = 5, 40, 9
-    freed = rng.random((C, P)) < 0.3
+    C, P, N = shape
+    freed = LIVE_MASKS[mask](rng, C, P)
     node = rng.integers(0, N, size=(C, P)).astype(np.int32)
     req_cpu = rng.integers(1, 500, size=(C, P)).astype(np.int32)
     req_ram = rng.integers(1, 500, size=(C, P)).astype(np.int32)
@@ -332,27 +488,61 @@ def test_free_kernel_matches_scatter_add():
     np.testing.assert_array_equal(np.asarray(got_ram), want_ram)
     # Estimator fold over the finished subset.
     stats = np.asarray(stats)
+    # The kernel frees a lane's pods in rising slot order: a float32 fold in
+    # that order is its sum to the bit.
     for c in range(C):
         vals = value[c][finishes[c]]
         assert stats[c, 0] == len(vals)
-        np.testing.assert_allclose(stats[c, 1], vals.sum(), rtol=1e-6)
-        np.testing.assert_allclose(stats[c, 2], (vals * vals).sum(), rtol=1e-6)
+        total = total_sq = np.float32(0.0)
+        for v in vals:
+            total, total_sq = total + v, total_sq + np.float32(v * v)
+        assert stats[c, 1] == total and stats[c, 2] == total_sq
         assert stats[c, 3] == (vals.min() if len(vals) else np.inf)
         assert stats[c, 4] == (vals.max() if len(vals) else -np.inf)
 
 
-def test_event_kernel_matches_scatters():
+# Pod slots an event chunk names, by case: (rng, shape, P) -> slots. The
+# event kernel sweeps the row tiles from the lowest to the highest in-range
+# pod slot of the chunk's valid pod events.
+EVENT_POD_SLOTS = {
+    "anywhere": lambda rng, shape, P: rng.integers(0, P + 3, size=shape),
+    "inside_one_tile": lambda rng, shape, P: rng.integers(10, 30, size=shape),
+    "across_a_tile_edge": lambda rng, shape, P: rng.integers(118, 140, size=shape),
+    "two_bands_far_apart": lambda rng, shape, P: np.where(
+        rng.random(shape) < 0.5,
+        rng.integers(0, 9, size=shape),
+        rng.integers(P - 20, P, size=shape),
+    ),
+    "a_band_of_its_own_per_lane": lambda rng, shape, P: (
+        40 * np.arange(shape[0])[:, None] + rng.integers(0, 12, size=shape)
+    ),
+    "no_row": lambda rng, shape, P: rng.choice([-1, P + 4, 1 << 29], size=shape),
+    "half_out_of_range": lambda rng, shape, P: np.where(
+        rng.random(shape) < 0.5,
+        rng.integers(100, 120, size=shape),
+        rng.choice([-1, P, P + 3, P + 4, 1 << 29], size=shape),
+    ),
+}
+
+
+@pytest.mark.parametrize(
+    "slots,shape",
+    [("anywhere", (4, 12, 7, 20))]
+    + [(name, (5, 24, 9, 300)) for name in EVENT_POD_SLOTS]
+    + [("anywhere", (3, 12, 7, 256)), ("anywhere", (130, 8, 5, 300))],
+)
+def test_event_kernel_matches_scatters(slots, shape):
     from kubernetriks_tpu.ops.scheduler_kernel import fused_event_scatter
 
     rng = np.random.default_rng(11)
-    C, E, N, P = 4, 12, 7, 20
+    C, E, N, P = shape
     kind = rng.integers(1, 5, size=(C, E)).astype(np.int32)
     # Node events index N-space, pod events P-space; sprinkle out-of-range
     # slots (sliding-window drops).
     slot = np.where(
         (kind == 1) | (kind == 2),
         rng.integers(0, N + 2, size=(C, E)),
-        rng.integers(0, P + 3, size=(C, E)),
+        EVENT_POD_SLOTS[slots](rng, (C, E), P),
     ).astype(np.int32)
     rel = rng.uniform(-5.0, 15.0, size=(C, E)).astype(np.float32)
     seq = rng.integers(0, 1000, size=(C, E)).astype(np.int32)
@@ -385,10 +575,10 @@ def test_event_kernel_matches_scatters():
                 created[c, s] = True
             elif kind[c, e] == 2 and s < N:
                 nrm[c, s] = min(nrm[c, s], rel[c, e])
-            elif kind[c, e] == 3 and s < P:
+            elif kind[c, e] == 3 and 0 <= s < P:
                 pcr[c, s] = min(pcr[c, s], rel[c, e])
                 pseq[c, s] = max(pseq[c, s], seq[c, e])
-            elif kind[c, e] == 4 and s < P:
+            elif kind[c, e] == 4 and 0 <= s < P:
                 prm[c, s] = min(prm[c, s], rel[c, e])
     np.testing.assert_array_equal(np.asarray(got[0]), created)
     np.testing.assert_array_equal(np.asarray(got[1]), nrm)
@@ -532,7 +722,11 @@ cluster_autoscaler:
             scheduler_profile=profile,
         )
         if pallas:
-            sim.use_pallas_select = True  # force the dense kernel set at C=4
+            # Force the dense kernel set at C=4: the build-time gates want
+            # 128 clusters, and the megakernel's gate is read at build, so
+            # the env alone would leave the two-kernel path on.
+            sim.use_pallas_select = True
+            sim.use_megakernel = megakernel == "1"
         return sim
 
     scan_sim, kern_sim = build(False), build(True)
@@ -542,3 +736,50 @@ cluster_autoscaler:
     assert not bad, (seed, bad)
     counters = scan_sim.metrics_summary()["counters"]
     assert counters["scheduling_decisions"] > 0
+
+
+def test_ring_swept_share_is_the_share_of_the_masks(monkeypatch):
+    """The megakernel's sweep counter, carried by the device ring to
+    telemetry_report()["ring"]["cycle_rows_swept_share"], equals the share
+    counted on the host from the very `eligible` masks the kernel was given
+    (live tiles x steps over tiles x steps, summed over the run's cycles);
+    and the counter lives in the ring alone: a telemetry-off build has the
+    leaves it had and the same values in them."""
+    from kubernetriks_tpu.batched.state import strip_telemetry
+    from kubernetriks_tpu.ops import scheduler_kernel as sk
+
+    masks = []
+    real = sk.fused_select_cycle_commit
+
+    def spy(alive, alloc_cpu, alloc_ram, eligible, *rest, **kwargs):
+        jax.debug.callback(lambda e: masks.append(np.asarray(e)), eligible)
+        return real(alive, alloc_cpu, alloc_ram, eligible, *rest, **kwargs)
+
+    monkeypatch.setattr(sk, "fused_select_cycle_commit", spy)
+    on = _build(True, telemetry=True)
+    on.use_pallas_select = on.use_megakernel = True  # the gates want C >= 128
+    on.step_until_time(400.0)
+    jax.effects_barrier()
+    monkeypatch.setattr(sk, "fused_select_cycle_commit", real)
+
+    assert masks and max(m.shape[1] for m in masks) > sk._ROW_TILE
+    swept = whole = 0
+    for mask in masks:
+        live, tiles = _live_tiles(mask)
+        steps = min(int(mask.sum(axis=1).max()), on.max_pods_per_cycle)
+        swept, whole = swept + live * steps, whole + tiles * steps
+    ring = on.telemetry_report()["ring"]
+    assert 0 < swept < whole
+    assert ring["cycle_rows_swept_share"] == swept / whole
+    # One value per grid program, repeated on each of its 3 cluster lanes.
+    assert ring["totals"]["cycle_tiles_swept"] == 3 * swept
+    assert ring["totals"]["cycle_tile_steps"] == 3 * whole
+
+    off = _build(True)
+    off.use_pallas_select = off.use_megakernel = True
+    off.step_until_time(400.0)
+    assert off.state.telemetry is None
+    assert jax.tree.structure(off.state) == jax.tree.structure(
+        strip_telemetry(on.state)
+    )
+    assert compare_states(strip_telemetry(on.state), off.state) == []
