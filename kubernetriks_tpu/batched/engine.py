@@ -1464,6 +1464,19 @@ class BatchedSimulation:
         self.n_clusters = C
         self.n_nodes = node_cap_cpu.shape[1]
         self.n_pods = pod_req_cpu.shape[1]
+        # The profile the cycle programs compile: the user's, with the
+        # exact-ranking static the traces call for (heterogeneous requests;
+        # pipeline.exact_score_bits). self.profile stays the user's own.
+        from kubernetriks_tpu.batched.pipeline import exact_score_bits
+
+        distinct = {id(c): c for c in compiled_traces}.values()
+        self._cycle_profile = self.profile._replace(
+            exact_bits=exact_score_bits(
+                self.profile,
+                [(c.pod_req_cpu, c.pod_req_ram) for c in distinct],
+                [(node_cap_cpu, node_cap_ram)],
+            )
+        )
         # N is only known here (derived from the traces + CA reserve
         # groups), so the tuned profile's node-axis key is re-checked
         # post-build: strict (explicit) profiles raise GeometryMismatch,
@@ -1513,10 +1526,11 @@ class BatchedSimulation:
             # Default-on whenever the blocks fit: even at C=1 (the trace-replay
             # shape, where the 128-lane cluster tile is almost all padding) the
             # kernel's data-dependent early exit over candidates beats the
-            # K-step lax.scan by ~5x on hardware — the scan pays all K
-            # sequential iterations (~16 us each) while typical cycles have
-            # far fewer pending pods (measured 2026-07-30: 0.90 ms vs 4.58 ms
-            # per window at C=1, N=1313, P=4096, K=256).
+            # K-step lax.scan, which pays all K sequential iterations while a
+            # cycle has about ten pending pods: a whole replay job of 1,701
+            # windows takes 0.502 s against 8.35 s at C=1, N=1313, P=4096,
+            # K=256 with exact ranking (one v5e; PERF.md section 6, PR 28; the
+            # cell `alibaba1313.replay` reports which formulation it ran).
             self.use_pallas = (
                 default_enabled()
                 and self.n_clusters % n_shards == 0
@@ -1524,10 +1538,13 @@ class BatchedSimulation:
             )
         # Prefer the fused selection kernel (in-kernel queue argmin instead
         # of the (C, P) lexsort) when its pod blocks fit VMEM AND the
-        # 128-cluster lane tiles are mostly real: its per-candidate passes
-        # sweep whole (P, 128) tiles, so at small C the padding waste loses
-        # to the sort+candidate kernel (measured at C=1, P=4096: 5.3 ms vs
-        # 0.9 ms per window), while dense batches win by dropping the sort.
+        # 128-cluster lane tiles are mostly real: at small C the padding
+        # waste loses to the sort+candidate kernel, while dense batches win
+        # by dropping the sort. Since the kernels sweep only their live pod
+        # rows (PR 27) the loss at C=1 is small: the same replay job takes
+        # 0.609 s with the select kernel forced on and 0.515 s with the
+        # megakernel, against the candidate kernel's 0.502 s (PERF.md
+        # section 6, PR 28).
         self.use_pallas_select = (
             self.use_pallas
             and self.n_clusters // n_shards >= 128
@@ -1815,14 +1832,14 @@ class BatchedSimulation:
         # re-init). Snapshot it only for scenario builds — plain engines
         # must not pay a second full-state copy in device memory.
         self._pristine = None
-        self._pristine_pod_window = self.pod_window
         if self._scenario is not None:
             self._pristine = tree_copy(self.state)
 
     def kernel_formulation(self) -> dict:
         """What the static fit gates picked for this build: the scheduling
-        cycle's formulation (scan < candidate < select < megakernel) and,
-        with the cluster autoscaler on, whether each CA walk runs as its
+        cycle's formulation (scan < candidate < select < megakernel), how
+        it ranks nodes (pipeline.exact_score_bits) and, with the cluster
+        autoscaler on, whether each CA walk runs as its
         Pallas kernel or as the XLA loop (the gates of
         autoscale._ca_scale_up / _ca_scale_down, same predicates). What
         chip_smoke.py and benchmark cells assert engagement on."""
@@ -1832,7 +1849,13 @@ class BatchedSimulation:
             cycle = "select"
         else:
             cycle = "candidate" if self.use_pallas else "scan"
-        out = {"cycle": cycle, "interpret": self.pallas_interpret}
+        out = {
+            "cycle": cycle,
+            "interpret": self.pallas_interpret,
+            # how nodes are ranked for a pod: the float32 score, or the
+            # exact key a trace of heterogeneous requests calls for
+            "ranking": "exact" if self._cycle_profile.exact_bits else "float32",
+        }
         st = self.autoscale_statics
         if st is not None and self.config.cluster_autoscaler.enabled:
             from kubernetriks_tpu.ops.autoscale_kernel import (
@@ -2008,7 +2031,7 @@ class BatchedSimulation:
             ca_descatter=self.ca_descatter,
             reclaim=self.reclaim,
             reclaim_period=self.reclaim_period,
-            profile=self.profile,
+            profile=self._cycle_profile,
         )
 
     def _dispatch_windows(
@@ -2307,13 +2330,6 @@ class BatchedSimulation:
             raise ValueError(
                 "fleet_reset requires an engine built with scenario= "
                 "(the fleet build keeps the pristine state snapshot)"
-            )
-        if self.pod_window != self._pristine_pod_window:
-            raise RuntimeError(
-                f"fleet_reset: the pod window grew ({self._pristine_pod_window}"
-                f" -> {self.pod_window}) during a wave, so the pristine "
-                "snapshot's shapes are stale — build the fleet with a "
-                "larger pod_window so dense waves never grow it"
             )
         mask = np.zeros((self.n_clusters,), bool)
         if lanes is None:
@@ -3469,12 +3485,23 @@ class BatchedSimulation:
         base = self._pod_base
         C = self._pod_create_win.shape[0]
         refill = self._make_refill(base + W, insert)
-        new_pods = jax.tree.map(
-            lambda a, b: jnp.concatenate([a[:, :W], b, a[:, W:]], axis=1),
-            self.state.pods,
-            refill,
-        )
-        self.state = self.state._replace(pods=new_pods)
+
+        def widen(pods, fresh):
+            return jax.tree.map(
+                lambda a, b: jnp.concatenate([a[:, :W], b, a[:, W:]], axis=1),
+                pods,
+                fresh,
+            )
+
+        self.state = self.state._replace(pods=widen(self.state.pods, refill))
+        if self._pristine is not None:
+            # The engine stays grown, so the snapshot fleet_reset and
+            # lane_reset rewind to grows with it: its window sits at base
+            # 0, where the same insert covers global slots [W, new_W) —
+            # the state a build at pod_window=new_W starts from.
+            self._pristine = self._pristine._replace(
+                pods=widen(self._pristine.pods, self._make_refill(W, insert))
+            )
         self.pod_window = new_W
         self._resident_shift = T - new_W
         self.consts = self.consts._replace(
@@ -3679,7 +3706,7 @@ class BatchedSimulation:
             ca_descatter=self.ca_descatter,
             reclaim=self.reclaim,
             reclaim_period=self.reclaim_period,
-            profile=self.profile,
+            profile=self._cycle_profile,
         )
         if self.collect_gauges:
             from kubernetriks_tpu.batched.step import gauge_snapshot

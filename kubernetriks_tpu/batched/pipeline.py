@@ -40,10 +40,19 @@ tests/test_random_equivalence.py):
   agree; -inf keeps the kernels free of NaN-propagation hazards.
 - Tie-breaks: last max in node-slot order == the reference's `>=` sweep
   over name-sorted nodes (kube_scheduler.rs:140-150).
+- Where requests and capacities do not move in lockstep (a trace of
+  heterogeneous pods), two nodes' scores can lie closer than float32
+  resolves while the scalar path's float64 tells them apart: on a replay
+  of 17,899 such pods over 1,313 nodes the float32 argmax put the 4,434th
+  pod on another node and half of all pods after it (PERF.md, PR 28). For
+  such builds the engine sets `exact_bits` (`exact_score_bits`) and the
+  default profile ranks nodes by `exact_least_allocated_key`, a 3-digit
+  fixed-point quotient in int32 that orders as the float64 score does.
 """
 
 from __future__ import annotations
 
+import logging
 from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax.numpy as jnp
@@ -79,6 +88,10 @@ class CompiledProfile(NamedTuple):
     name: str  # display name ("default", "best_fit", or "custom")
     filters: Tuple[str, ...]  # ordered filter plugin names
     scores: Tuple[Tuple[str, float], ...]  # (scorer name, weight) pairs
+    # > 0: rank nodes by exact_least_allocated_key with digits of this many
+    # bits, not by the float32 score. Set by the engine from the traces it is
+    # built over (exact_score_bits), never by a user.
+    exact_bits: int = 0
 
 
 def _zero(x):
@@ -275,6 +288,116 @@ def profile_score(profile: CompiledProfile, fit, cpu, ram, rc, rr):
         # `>=` sweep over all-zero node_scores.
         return jnp.where(fit, jnp.float32(0.0), neg_inf)
     return jnp.where(fit, total, neg_inf)
+
+
+# --- exact ranking for heterogeneous requests ---------------------------------
+
+_EXACT_DIGITS = 3
+_EXACT_BITS_MAX = 14
+_EXACT_BITS_MIN = 10
+# A lockstep trace's score is a function of one integer, free units A, and
+# steps by 100 k / A**2 between neighbours; float32 resolves that to here.
+_LOCKSTEP_MAX_UNITS = 1024
+
+
+def exact_score_bits(profile: CompiledProfile, requests, capacities) -> int:
+    """The `exact_bits` static for an engine built over these pods and nodes.
+
+    `requests` and `capacities` are iterables of (cpu, ram) integer array
+    pairs (pods of every trace, node capacities incl. the CA's templates).
+    0 = the float32 score decides as the scalar path's float64 does, which
+    holds when every request and capacity is a whole multiple k of ONE
+    (cpu, ram) unit pair: allocatable is then (A u_c, A u_m), the score
+    100 - 100 k / A, and distinct A differ by far more than float32's 1e-5.
+    The dense Monte-Carlo and autoscaled deployments are such (4 cores with
+    8 GiB on 64 with 128), and their programs stay as they were. Otherwise
+    the digit width that keeps `remainder << bits` inside int32 for the
+    largest capacity. Only the default profile (Fit + LeastAllocated at
+    weight 1) has the exact key: a build whose traces call for it and that
+    cannot have it (another profile, a capacity too large for 10-bit
+    digits) keeps float32 and says so in a warning, since its placements
+    can then part from the scalar path's."""
+    pairs = [
+        (np.asarray(c, np.int64).ravel(), np.asarray(r, np.int64).ravel())
+        for c, r in (*requests, *capacities)
+    ]
+    if not pairs:
+        return 0
+    cpus = np.concatenate([c for c, _ in pairs])
+    rams = np.concatenate([r for _, r in pairs])
+    unit_cpu, unit_ram = int(np.gcd.reduce(cpus, initial=0)), int(np.gcd.reduce(rams, initial=0))
+    if unit_cpu == 0 or unit_ram == 0:
+        return 0  # no pod or no node asks for anything: every score ties
+    largest = max(int(cpus.max()), int(rams.max()))
+    lockstep = bool((cpus // unit_cpu == rams // unit_ram).all())
+    if lockstep and int(cpus.max()) // unit_cpu <= _LOCKSTEP_MAX_UNITS:
+        return 0
+    bits = min(_EXACT_BITS_MAX, 31 - largest.bit_length())
+    default = profile.filters == (FIT,) and profile.scores == ((LEAST_ALLOCATED, 1.0),)
+    if default and bits >= _EXACT_BITS_MIN:
+        return bits
+    logging.getLogger(__name__).warning(
+        "requests and capacities are not whole multiples of one (cpu, ram) "
+        "unit, so float32 node scores can rank two nodes otherwise than the "
+        "scalar path's float64, and this build keeps float32 ranking (%s): "
+        "placements may differ from the scalar backend's",
+        f"capacity {largest} leaves {bits} bits a digit, under {_EXACT_BITS_MIN}"
+        if default
+        else f"profile {profile.name!r} has no exact key, only the default profile has",
+    )
+    return 0
+
+
+def _quotient_digits(num, den, bits: int):
+    """The first _EXACT_DIGITS base-2**bits digits of num / den after the
+    point, for int32 0 <= num <= den < 2**(31 - bits): long division, each
+    digit estimated in float32 and corrected by its integer remainder, so
+    the digits are exact whatever the backend's division rounds to (the
+    estimate is within one of the digit; int32 wraps, and the remainder
+    that is kept is exact modulo 2**32 and lies inside (-den, 2 den))."""
+    one = jnp.int32(1)
+    den_f = jnp.where(den > _zero(den), den, one).astype(jnp.float32)
+    digits = []
+    rem = num
+    for _ in range(_EXACT_DIGITS):
+        shifted = rem << jnp.int32(bits)
+        digit = jnp.floor(shifted.astype(jnp.float32) / den_f).astype(jnp.int32)
+        rem = shifted - digit * den
+        low, high = rem < _zero(rem), rem >= den
+        digit = digit - low.astype(jnp.int32) + high.astype(jnp.int32)
+        rem = jnp.where(low, rem + den, jnp.where(high, rem - den, rem))
+        digits.append(digit)
+    return digits
+
+
+def exact_least_allocated_key(fit, cpu, ram, rc, rr, bits: int):
+    """(hi, lo) int32: rc / cpu + rr / ram in fixed point, each quotient
+    truncated at 3 * bits bits, so that the lexicographically LEAST key is
+    the node LeastAllocatedResources scores highest (score = 100 - 50 x the
+    sum). Equal allocatables give equal keys, as they give equal float64
+    scores; two unequal nodes closer than 2**-(3 bits - 1) can order
+    otherwise than float64 does (at 14 bits: 2e-11 of a score whose typical
+    gap on 1,313 nodes is 1e-3). Nodes that do not fit, or have nothing
+    allocatable (the scalar NaN), get the largest key."""
+    big = jnp.int32(2**31 - 1)
+    mask = jnp.int32((1 << bits) - 1)
+    c1, c2, c3 = _quotient_digits(jnp.broadcast_to(rc, cpu.shape), cpu, bits)
+    r1, r2, r3 = _quotient_digits(jnp.broadcast_to(rr, ram.shape), ram, bits)
+    lo = c3 + r3
+    hi = ((c1 + r1) << jnp.int32(bits)) + (c2 + r2) + (lo >> jnp.int32(bits))
+    ok = fit & (cpu > _zero(cpu)) & (ram > _zero(ram))
+    return jnp.where(ok, hi, big), jnp.where(ok, lo & mask, big)
+
+
+def exact_best_node(hi, lo, node_ok, iota, axis: int):
+    """Highest node slot among the least (hi, lo) keys along `axis`:
+    the last-max-wins argmax of the float32 path on exact keys."""
+    least_hi = jnp.min(hi, axis=axis, keepdims=True)
+    at_hi = hi == least_hi
+    least_lo = jnp.min(jnp.where(at_hi, lo, jnp.int32(2**31 - 1)), axis=axis, keepdims=True)
+    return jnp.max(
+        jnp.where(at_hi & (lo == least_lo) & node_ok, iota, jnp.int32(-1)), axis=axis, keepdims=True
+    )
 
 
 def profile_fit_score(profile: CompiledProfile, alive, cpu, ram, rc, rr):

@@ -1682,7 +1682,14 @@ def _run_scheduling_cycle(
         acpu0 = state.nodes.alloc_cpu.T if lane_major else state.nodes.alloc_cpu
         aram0 = state.nodes.alloc_ram.T if lane_major else state.nodes.alloc_ram
 
-        from kubernetriks_tpu.batched.pipeline import profile_fit_score
+        from kubernetriks_tpu.batched.pipeline import (
+            exact_best_node,
+            exact_least_allocated_key,
+            profile_fit_mask,
+            profile_fit_score,
+        )
+
+        iota_n = jnp.arange(N, dtype=jnp.int32)[None, :]
 
         def body(carry, xs):
             alloc_cpu, alloc_ram = carry
@@ -1692,21 +1699,21 @@ def _run_scheduling_cycle(
             # (pipeline.py; default = Fit + LeastAllocatedResources,
             # reference: plugin.rs:33-63) — the SAME expressions the Pallas
             # kernels inline, so the scan oracle and the kernels cannot
-            # drift per profile. Scores are float32 on BOTH batched paths;
-            # the precision only affects argmax tie-breaks between
-            # near-equal node scores, which the cross-path equivalence
-            # tests cover.
-            fit, score = profile_fit_score(
-                profile,
-                alive_x,
-                alloc_cpu,
-                alloc_ram,
-                req_cpu[:, None],
-                req_ram[:, None],
-            )
-            # Last-max-wins argmax, matching the reference's `>=` sweep over
-            # name-sorted nodes (kube_scheduler.rs:140-150).
-            best = jnp.int32(N - 1) - jax.lax.argmax(score[:, ::-1], 1, jnp.int32)
+            # drift per profile. Scores are float32 on BOTH batched paths
+            # unless the build's requests call for the exact key
+            # (pipeline.exact_score_bits); float32 only affects the argmax
+            # between near-equal node scores, which lockstep requests
+            # never produce.
+            nodes_and_pod = (alloc_cpu, alloc_ram, req_cpu[:, None], req_ram[:, None])
+            if profile.exact_bits:
+                fit = profile_fit_mask(profile, alive_x, *nodes_and_pod)
+                hi, lo = exact_least_allocated_key(fit, *nodes_and_pod, profile.exact_bits)
+                best = exact_best_node(hi, lo, True, iota_n, axis=1)[:, 0]
+            else:
+                fit, score = profile_fit_score(profile, alive_x, *nodes_and_pod)
+                # Last-max-wins argmax, matching the reference's `>=` sweep
+                # over name-sorted nodes (kube_scheduler.rs:140-150).
+                best = jnp.int32(N - 1) - jax.lax.argmax(score[:, ::-1], 1, jnp.int32)
             any_fit = fit.any(axis=1)
 
             assign = valid & any_fit

@@ -117,6 +117,41 @@ def _event_time_shifts(config) -> Tuple[float, float, float]:
         config.as_to_ps_network_delay,
     )
 
+_NODE_EVENT_KINDS = (EV_CREATE_NODE, EV_REMOVE_NODE, EV_NODE_CRASH, EV_NODE_RECOVER)
+
+
+def _node_slots_in_name_order(trace: CompiledClusterTrace) -> CompiledClusterTrace:
+    """Renumber node slots so that slot order IS sorted-name order.
+
+    The scheduling kernels break a score tie by the highest node slot
+    (ops/scheduler_kernel._fit_score_place), the scalar scheduler by the last
+    node in sorted-name order (core/scheduler/kube_scheduler.py). The two
+    agree only when slots count as names sort: zero-padded names do, the
+    Alibaba trace's bare machine ids (`alibaba_node_9` after
+    `alibaba_node_10`) do not, and on a mostly empty cluster nearly every
+    decision is such a tie. Slots are interned in creation order, so the
+    compilers renumber once at the end; a re-created name keeps its
+    creation order among its own slots. Identity (the same object) for
+    names that already sort as they were created."""
+    names = trace.node_names
+    order = sorted(range(len(names)), key=lambda slot: (names[slot], slot))
+    if order == list(range(len(names))):
+        return trace
+    new_slot = np.empty(len(names), np.int32)
+    new_slot[order] = np.arange(len(names), dtype=np.int32)
+    is_node_event = np.isin(trace.ev_kind, _NODE_EVENT_KINDS)
+    downtime = trace.node_crash_downtime
+    return dataclasses.replace(
+        trace,
+        ev_slot=np.where(
+            is_node_event, new_slot[np.clip(trace.ev_slot, 0, len(names) - 1)], trace.ev_slot
+        ).astype(np.int32),
+        node_cap_cpu=trace.node_cap_cpu[order],
+        node_cap_ram=trace.node_cap_ram[order],
+        node_names=[names[slot] for slot in order],
+        node_crash_downtime=None if downtime is None else downtime[order],
+    )
+
 
 def compile_cluster_trace(
     cluster_events: TraceEvents,
@@ -285,7 +320,7 @@ def compile_cluster_trace(
         for slot, ttr in node_crash_downtime.items():
             crash_downtime_arr[slot] = ttr
 
-    return CompiledClusterTrace(
+    return _node_slots_in_name_order(CompiledClusterTrace(
         ev_time=np.asarray(ev_time, np.float64),
         ev_kind=np.asarray(ev_kind, np.int32),
         ev_slot=np.asarray(ev_slot, np.int32),
@@ -298,7 +333,7 @@ def compile_cluster_trace(
         pod_names=pod_names,
         pod_groups=pod_groups,
         node_crash_downtime=crash_downtime_arr,
-    )
+    ))
 
 
 def segment_pod_slots(
@@ -509,7 +544,7 @@ def compile_from_arrays(
     )
     order = np.lexsort((source, times))  # stable within each source stream
 
-    return CompiledClusterTrace(
+    return _node_slots_in_name_order(CompiledClusterTrace(
         ev_time=times[order],
         ev_kind=kinds[order],
         ev_slot=slots[order],
@@ -521,7 +556,7 @@ def compile_from_arrays(
         node_names=node_names,
         pod_names=pod_names,
         pod_groups=[],
-    )
+    ))
 
 
 def _pad_cols(arr: np.ndarray, lo: int, width: int, fill, dtype) -> np.ndarray:

@@ -154,15 +154,27 @@ def build_batched_simulation(
                 "or set fault_injection.node.mttf to 0 (pod-level faults "
                 "are unaffected)"
             )
-        workload_arrays = feeder.load_workload_arrays(
-            alibaba.batch_instance_trace_path, alibaba.batch_task_trace_path
+        from kubernetriks_tpu.telemetry.tracer import PH_TRACE_INGEST, recorder
+
+        rec = recorder()
+        t0 = rec.begin(PH_TRACE_INGEST)
+        try:
+            workload_arrays = feeder.load_workload_arrays(
+                alibaba.batch_instance_trace_path, alibaba.batch_task_trace_path
+            )
+            cluster_arrays = (
+                feeder.load_cluster_arrays(alibaba.machine_events_trace_path)
+                if alibaba.machine_events_trace_path
+                else None
+            )
+            compiled = compile_from_arrays(cluster_arrays, workload_arrays, config)
+        finally:
+            rec.end(PH_TRACE_INGEST, t0)
+        rec.count("trace_ingest_rows", workload_arrays.rows_read)
+        rec.count(
+            "trace_ingest_rows_dropped",
+            workload_arrays.rows_read - len(workload_arrays.start_ts),
         )
-        cluster_arrays = (
-            feeder.load_cluster_arrays(alibaba.machine_events_trace_path)
-            if alibaba.machine_events_trace_path
-            else None
-        )
-        compiled = compile_from_arrays(cluster_arrays, workload_arrays, config)
         return BatchedSimulation(config, [compiled] * n_clusters, **kwargs)
     cluster_trace, workload_trace = build_traces(config)
     return build_batched_from_traces(

@@ -50,6 +50,9 @@ from jax.experimental.pallas import tpu as pltpu
 # batched/state.py), so kernel-only users still dodge the x64 config flip.
 from kubernetriks_tpu.batched.pipeline import (
     DEFAULT_PROFILE,
+    exact_best_node,
+    exact_least_allocated_key,
+    profile_fit_mask,
     profile_fit_score,
 )
 
@@ -132,13 +135,18 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid):
     i0 = jnp.int32(0)
     neg1 = jnp.int32(-1)
 
-    fit, score = profile_fit_score(profile, alive, cpu, ram, rc, rr)
-    max_score = jnp.max(score, axis=0, keepdims=True)
-    best = jnp.max(
-        jnp.where((score == max_score) & node_ok, iota_n, neg1),
-        axis=0,
-        keepdims=True,
-    )
+    if profile.exact_bits:
+        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr)
+        hi, lo = exact_least_allocated_key(fit, cpu, ram, rc, rr, profile.exact_bits)
+        best = exact_best_node(hi, lo, node_ok, iota_n, axis=0)
+    else:
+        fit, score = profile_fit_score(profile, alive, cpu, ram, rc, rr)
+        max_score = jnp.max(score, axis=0, keepdims=True)
+        best = jnp.max(
+            jnp.where((score == max_score) & node_ok, iota_n, neg1),
+            axis=0,
+            keepdims=True,
+        )
     # any() lowers to an i1 reduction Mosaic rejects; reduce in i32. Padded
     # slots never fit (alive is 0 there).
     any_fit = jnp.max(fit.astype(jnp.int32), axis=0, keepdims=True) > i0

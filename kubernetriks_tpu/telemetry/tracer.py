@@ -109,6 +109,10 @@ PH_ENGINE_BUILD = 28
 # (recompile.py): t0 = log time less the logged seconds, id = ordinal
 # into `compiles`.
 PH_COMPILE = 29
+# The native trace parse and compile_from_arrays of a build from trace files
+# (cli.build_batched_simulation); its counters are `trace_ingest_rows` and
+# `trace_ingest_rows_dropped`.
+PH_TRACE_INGEST = 30
 
 PHASE_NAMES = (
     "window_chunk",
@@ -141,6 +145,7 @@ PHASE_NAMES = (
     "fleet_reset",
     "engine_build",
     "compile",
+    "trace_ingest",
 )
 
 _N_PHASES = len(PHASE_NAMES)

@@ -91,6 +91,8 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.feeder_error.argtypes = [ctypes.c_void_p]
         lib.feeder_workload_count.restype = ctypes.c_int64
         lib.feeder_workload_count.argtypes = [ctypes.c_void_p]
+        lib.feeder_workload_rows_read.restype = ctypes.c_int64
+        lib.feeder_workload_rows_read.argtypes = [ctypes.c_void_p]
         lib.feeder_machine_count.restype = ctypes.c_int64
         lib.feeder_machine_count.argtypes = [ctypes.c_void_p]
         f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
@@ -133,6 +135,9 @@ class WorkloadArrays:
     job_id: np.ndarray          # (P,) int64; -1 encodes a missing job id
     task_id: np.ndarray         # (P,) int64
     pod_no: np.ndarray          # (P,) int64 per-trace running pod counter
+    # Data rows of batch_instance the parse read (len(start_ts) of them passed
+    # the validity filter); None on a row-range segment.
+    rows_read: Optional[int] = None
 
     def pod_name(self, i: int) -> str:
         # Mirrors the Python path's f"{job_id}_{task_id}_{n}" naming, where a
@@ -188,6 +193,7 @@ def load_workload_arrays(
             job_id=np.empty(n, np.int64),
             task_id=np.empty(n, np.int64),
             pod_no=np.empty(n, np.int64),
+            rows_read=int(lib.feeder_workload_rows_read(ctypes.c_void_p(handle))),
         )
         if n:
             lib.feeder_workload_fill(

@@ -232,6 +232,8 @@ struct Handle {
   std::vector<int64_t> job_id;
   std::vector<int64_t> task_id;
   std::vector<int64_t> pod_no;
+  // Data rows of batch_instance read, kept or dropped by the validity filter.
+  int64_t instance_rows = 0;
 
   // Machine-events result (kind: 0 = create, 1 = remove; cpu/ram only valid
   // for creates), in file order then stably sorted by ts.
@@ -314,6 +316,7 @@ Handle* feeder_parse_workload(const char* instance_path,
     if (row.fields.size() < 8) {
       return Fail(h, "batch_instance row has fewer than 8 fields: " + line);
     }
+    h->instance_rows++;
     OptI64 start, end, jid, tid, mid_ignored;
     int64_t seq_ignored;
     if (!ParseOptI64(row.fields[0], &start, &err, "batch_instance.start_ts") ||
@@ -468,6 +471,8 @@ const char* feeder_error(Handle* h) { return h->error.c_str(); }
 int64_t feeder_workload_count(Handle* h) {
   return static_cast<int64_t>(h->start_ts.size());
 }
+
+int64_t feeder_workload_rows_read(Handle* h) { return h->instance_rows; }
 
 void feeder_workload_fill(Handle* h, double* start_ts, int64_t* cpu,
                           int64_t* ram, double* duration, int64_t* job_id,

@@ -233,3 +233,32 @@ events:
     rc, sc = ref.metrics_summary()["counters"], sim.metrics_summary()["counters"]
     assert rc == sc
     assert sc["total_scaled_up_pods"] > 0, "the HPA ring never activated"
+
+
+def test_fleet_reset_after_growth_rewinds_to_the_grown_build():
+    """A scenario build whose window grew inside a job resets: the pristine
+    snapshot grows with the window, so the next job starts from the state a
+    build at the grown width starts from and repeats the first exactly."""
+    from kubernetriks_tpu.batched.fleet import scenario_vectors
+    from kubernetriks_tpu.batched.state import compare_states
+
+    workload = _long_running_workload()
+    config = default_test_simulation_config()
+    scenario = dict(scenario_vectors(config, N_CLUSTERS))
+
+    sim = _build(workload, pod_window=64, scenario=scenario)
+    sim.step_until_time(1200.0)
+    assert sim.pod_window == 200, "the window never grew"
+    first = sim.metrics_summary()["counters"]
+    sim.fleet_reset()
+    assert sim.pod_window == 200 and sim.next_window_idx == 0
+
+    wide = _build(workload, pod_window=200, scenario=scenario)
+    mismatches = compare_states(sim.state, wide.state)
+    assert not mismatches, mismatches
+
+    sim.step_until_time(1200.0)
+    wide.step_until_time(1200.0)
+    assert sim.metrics_summary()["counters"] == first
+    mismatches = compare_states(sim.state, wide.state)
+    assert not mismatches, mismatches
