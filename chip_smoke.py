@@ -123,9 +123,13 @@ def emit(leg: str, t0: float, **fields) -> dict:
 
 
 def assert_sharded(sim, mesh) -> None:
-    """State really lives on every device of the mesh, in equal shards."""
+    """State really lives on every device of the mesh, in equal shards, and
+    the window programs are wrapped in their one shard_map."""
+    sharding = sim.kernel_formulation()["sharding"]
     if mesh is None:
+        assert sharding is None, sharding
         return
+    assert sharding == "shard_map", sharding
     phase = sim.state.pods.phase
     shards = phase.addressable_shards
     shapes = [s.data.shape for s in shards]
@@ -194,7 +198,7 @@ def composed_leg(shape, forced, mesh) -> dict:
     stats = dict(sim.dispatch_stats)
     counters = sim.metrics_summary()["counters"]
     assert formulation["cycle"] == shape["cycle"], formulation
-    assert sim.lane_major == (mesh is None), sim.lane_major
+    assert sim.lane_major, "lane-major node state is off"
     assert stats["superspans"] > 0, stats
     assert stats["window_chunks"] == 0, stats
     assert stats["ladder_fallbacks"] == 0, stats

@@ -76,7 +76,9 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
+from kubernetriks_tpu.batched.sharding import over_clusters
 from kubernetriks_tpu.batched.state import (
     ClusterBatchState,
     PHASE_EMPTY,
@@ -920,8 +922,6 @@ def _ca_scale_up(
     attempts_v: jnp.ndarray,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
 ):
     """Bin-packing scale-up over the unscheduled-pod cache
     (reference: kube_cluster_autoscaler.rs:190-240). Returns
@@ -980,10 +980,6 @@ def _ca_scale_up(
         core = partial(
             fused_ca_scale_up, n_slots=S, interpret=pallas_interpret
         )
-        if pallas_mesh is not None:
-            from kubernetriks_tpu.batched.step import _shard_rowwise
-
-            core = _shard_rowwise(core, 11, 3, pallas_mesh, pallas_axis)
         planned_k, g_planned_k, starved_k = core(
             st.ca_max_nodes[:, None],
             auto.ca_count,
@@ -1123,8 +1119,6 @@ def _ca_scale_down(
     interval,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
     descatter: bool = True,
     sd_order=None,
     node_rank=None,
@@ -1339,10 +1333,6 @@ def _ca_scale_down(
         )[:, None]
 
         core = partial(fused_ca_scale_down, k_sd=K_sd, interpret=pallas_interpret)
-        if pallas_mesh is not None:
-            from kubernetriks_tpu.batched.step import _shard_rowwise
-
-            core = _shard_rowwise(core, 15, 1, pallas_mesh, pallas_axis)
         removed_perm = core(
             branch[:, None],
             thresh,
@@ -1499,8 +1489,6 @@ def ca_pass(
     pre=None,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
     nodes_lane_major: bool = False,
     descatter: bool = True,
     reclaim: bool = False,
@@ -1577,8 +1565,10 @@ def ca_pass(
 
     # Branch around the whole pass bodies: most windows have an empty
     # unscheduled cache (no scale-up work) and scale-down's pod grouping
-    # ((C, P) sort) only matters once CA nodes exist. The predicates reduce
-    # to replicated scalars, so the conds hold under a C-sharded mesh.
+    # ((C, P) sort) only matters once CA nodes exist. Under a mesh the
+    # predicates are the SHARD's own (the whole program sits in one
+    # shard_map): a shard whose clusters have no such work skips the body,
+    # which for them is the identity, whatever another shard does.
     S = st.ca_slots.shape[1]
     Gn = st.ng_ca_start.shape[1]
     planned, planned_per_group, up_starved = jax.lax.cond(
@@ -1587,8 +1577,6 @@ def ca_pass(
             state_row, auto, st, up_branch, K_up, phase_v, attempts_v,
             use_pallas=use_pallas,
             pallas_interpret=pallas_interpret,
-            pallas_mesh=pallas_mesh,
-            pallas_axis=pallas_axis,
         ),
         lambda: (
             jnp.zeros((C, S), bool),
@@ -1609,8 +1597,6 @@ def ca_pass(
             phase_v, alloc_cpu_v, alloc_ram_v, snap, interval,
             use_pallas=use_pallas,
             pallas_interpret=pallas_interpret,
-            pallas_mesh=pallas_mesh,
-            pallas_axis=pallas_axis,
             descatter=descatter,
             sd_order=sd_order,
             node_rank=node_rank,
@@ -1935,14 +1921,14 @@ def hpa_pass_donated(
     return state2._replace(auto=auto2)
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "K_up", "K_sd", "use_pallas", "pallas_interpret", "pallas_mesh",
-        "pallas_axis", "descatter", "reclaim",
-    ),
-    donate_argnums=(0,),
+_CA_PASS_STATICS = (
+    "K_up", "K_sd", "use_pallas", "pallas_interpret", "shards", "descatter",
+    "reclaim",
 )
+
+
+@partial(jax.jit, static_argnames=_CA_PASS_STATICS, donate_argnums=(0,))
+@over_clusters(_CA_PASS_STATICS, lambda axis, _statics: PartitionSpec(axis))
 def ca_pass_donated(
     state: ClusterBatchState,
     st: AutoscaleStatics,
@@ -1953,15 +1939,13 @@ def ca_pass_donated(
     pre=None,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
+    shards=None,
     descatter: bool = True,
     reclaim: bool = False,
 ) -> ClusterBatchState:
     state2, auto2 = ca_pass(
         state, state.auto, st, W, consts, K_up, K_sd, pre=pre,
         use_pallas=use_pallas, pallas_interpret=pallas_interpret,
-        pallas_mesh=pallas_mesh, pallas_axis=pallas_axis,
         descatter=descatter, reclaim=reclaim,
     )
     return state2._replace(auto=auto2)

@@ -5,9 +5,17 @@ clusters through scheduling-cycle windows on-device, and reduces metrics to
 the same summary shape the scalar MetricsCollector prints.
 
 Sharding: all state arrays lead with the cluster axis C; `mesh` shards that
-axis across devices (pure data parallelism over simulated clusters — each
-cluster is independent, so the step needs no cross-device collectives; metric
-reduction at readout is the only communication).
+axis across devices (pure data parallelism over simulated clusters). There is
+ONE boundary: under a mesh every window program is wrapped at its jit entry
+in a single shard_map over the cluster axis (batched/sharding.py), so each
+device runs the program a one-chip build of its shard runs — local C, the
+kernels called directly, lane-major node state by the same tristate — and
+GSPMD never partitions the window body. The only collectives in a window
+program are scalar pmins where a value really spans every cluster (the
+slide's shift, the superspan's capacity read and pod_base, the fast-forward's
+next due window); metric reduction at readout is the rest of the
+communication. The small programs between dispatches (reset, slide apply,
+growth) are row-wise and stay plain jits over the sharded arrays.
 """
 
 from __future__ import annotations
@@ -30,6 +38,12 @@ from kubernetriks_tpu.parallel.multihost import (
     is_cross_process,
     put_global,
     to_host,
+)
+from kubernetriks_tpu.batched.sharding import (
+    ClusterShards,
+    cluster_specs,
+    over_clusters,
+    shard_axis_of,
 )
 from kubernetriks_tpu.batched.state import (
     DEFAULT_RAM_UNIT,
@@ -141,6 +155,18 @@ _CHUNK_LADDER = (128, 64, 32, 16, 8, 4, 2, 1)
 _slide_shift_device = jax.jit(_slide_shift_core)
 
 
+# The fused program shares every window-program static (drift between the
+# fused and plain programs' static sets would make a new kwarg traced in one
+# of them) plus the slide's window width.
+_FUSED_STATICS = _STEP_STATICS + ("W",)
+
+
+def _out_fused(axis, _statics):
+    """(state, windowed pod-name ranks | None, the shift)."""
+    return PartitionSpec(axis), PartitionSpec(axis), PartitionSpec()
+
+
+@over_clusters(_FUSED_STATICS, _out_fused, replicated=("window_idxs",))
 def _fused_chunk_slide_impl(
     state,
     slab,
@@ -156,8 +182,7 @@ def _fused_chunk_slide_impl(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     conditional_move: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
+    shards=None,
     use_pallas_select: bool = False,
     use_megakernel: bool = True,
     hpa_seg=None,
@@ -182,6 +207,7 @@ def _fused_chunk_slide_impl(
     shift)."""
     from kubernetriks_tpu.batched.step import _window_body
 
+    shard_axis = shard_axis_of(shards)
     if lane_major:
         # Hot node leaves flip to the kernels' (N, C) layout for the whole
         # chunk+slide program; state at rest stays row-major
@@ -202,8 +228,6 @@ def _fused_chunk_slide_impl(
             use_pallas,
             pallas_interpret,
             conditional_move,
-            pallas_mesh,
-            pallas_axis,
             use_pallas_select,
             use_megakernel=use_megakernel,
             hpa_seg=hpa_seg,
@@ -215,6 +239,7 @@ def _fused_chunk_slide_impl(
             reclaim=reclaim,
             reclaim_period=reclaim_period,
             profile=profile,
+            shard_axis=shard_axis,
         )
         return new, None
 
@@ -222,7 +247,9 @@ def _fused_chunk_slide_impl(
     if lane_major:
         state = swap_node_layout(state)
     base = jnp.asarray(base, jnp.int32)
-    s0 = _slide_shift_core(state.pods.phase[:, :W], payload["create_win"], base)
+    s0 = _slide_shift_core(
+        state.pods.phase[:, :W], payload["create_win"], base, shard_axis
+    )
     s = _quantize_shift_device(s0, W)
     rank = (
         autoscale_statics.pod_name_rank
@@ -236,10 +263,6 @@ def _fused_chunk_slide_impl(
     return state, new_rank, s
 
 
-# The fused program shares every window-program static (drift between the
-# fused and plain programs' static sets would make a new kwarg traced in one
-# of them) plus the slide's window width.
-_FUSED_STATICS = _STEP_STATICS + ("W",)
 _fused_chunk_slide = jax.jit(
     _fused_chunk_slide_impl, static_argnames=_FUSED_STATICS
 )
@@ -728,6 +751,11 @@ class BatchedSimulation:
         tuned_profile=None,
     ) -> None:
         self.config = config
+        self.mesh = mesh
+        self._batch_axis = batch_axis
+        # The window programs' sharding static (sharding.over_clusters):
+        # None without a mesh, and then no program is wrapped.
+        self._shards = None if mesh is None else ClusterShards(mesh, batch_axis)
         # Tuned-statics profile seam (PR 20, tune/): resolution order for
         # the profile SOURCE is explicit arg > KTPU_TUNED_PROFILE (a
         # path, or 1/auto resolving artifacts/tuned/ then the bundled
@@ -986,8 +1014,8 @@ class BatchedSimulation:
         # (tests/test_layout_razor.py); default on for accelerator
         # backends — on CPU XLA pays the layout copies anyway and the
         # extra program variants would only double compile time, so tests
-        # opt in explicitly. Under a mesh the shard_map in_specs pin the
-        # row-major (C, ...) convention, so the mode is forced off.
+        # opt in explicitly. A mesh build follows the same tristate: the
+        # swap happens inside the program's one shard_map, on the shard.
         if lane_major is not None:
             self.lane_major = bool(lane_major)
         else:
@@ -997,8 +1025,6 @@ class BatchedSimulation:
             self.lane_major = bool(
                 env if env is not None else jax.default_backend() != "cpu"
             )
-        if mesh is not None:
-            self.lane_major = False
         # Window-cost razor (KTPU_WINDOW_RAZOR / window_razor arg): gate
         # the per-window resolution soup behind a cheap due-ness predicate
         # (step._window_work_due) so empty windows in dense traces stop
@@ -1507,9 +1533,10 @@ class BatchedSimulation:
 
         # Finalize the Pallas decision now that shapes are known. Default: on
         # for real-TPU runs whose blocks fit VMEM (overridable via the
-        # use_pallas arg or KUBERNETRIKS_PALLAS=0/1). Under a mesh the kernel
-        # runs per-shard through shard_map (step.py), so the gate is the
-        # PER-SHARD cluster count, and C must divide the mesh evenly.
+        # use_pallas arg or KUBERNETRIKS_PALLAS=0/1). Under a mesh each device
+        # runs the whole window program on its shard (sharding.py), so the
+        # gate is the PER-SHARD cluster count, and C must divide the mesh
+        # evenly.
         from kubernetriks_tpu.ops.scheduler_kernel import (
             default_enabled,
             kernel_fits,
@@ -1517,11 +1544,10 @@ class BatchedSimulation:
         )
 
         n_shards = 1 if mesh is None else mesh.size
-        if self.use_pallas and mesh is not None:
-            assert self.n_clusters % n_shards == 0, (
-                f"use_pallas under a mesh needs n_clusters ({self.n_clusters}) "
-                f"divisible by the mesh size ({n_shards}) for shard_map"
-            )
+        assert self.n_clusters % n_shards == 0, (
+            f"a mesh build needs n_clusters ({self.n_clusters}) divisible by "
+            f"the mesh size ({n_shards}): every device runs an equal shard"
+        )
         if self._use_pallas_requested is None:
             # Default-on whenever the blocks fit: even at C=1 (the trace-replay
             # shape, where the 128-lane cluster tile is almost all padding) the
@@ -1735,8 +1761,6 @@ class BatchedSimulation:
         # runs with strict_autoscaler_bounds = False.
         self.strict_autoscaler_bounds = True
 
-        self.mesh = mesh
-        self._batch_axis = batch_axis
         self._sharding = None
         if mesh is not None:
             # Cross-process meshes (multi-host over DCN) can't device_put a
@@ -1746,13 +1770,7 @@ class BatchedSimulation:
             sharding = NamedSharding(mesh, PartitionSpec(batch_axis))
             self._sharding = sharding
             self.state = put(self.state, self._state_shardings(sharding, self.state))
-            self.slab = put(
-                self.slab,
-                jax.tree.map(
-                    lambda _: NamedSharding(mesh, PartitionSpec(batch_axis, None)),
-                    self.slab,
-                ),
-            )
+            self.slab = put(self.slab, self._state_shardings(sharding, self.slab))
             if self.autoscale_statics is not None:
                 self.autoscale_statics = put(
                     self.autoscale_statics,
@@ -1797,13 +1815,10 @@ class BatchedSimulation:
                 pnr[ci, : min(len(r), self.n_pods)] = r[: self.n_pods]
             ranks = (jnp.asarray(nnr), jnp.asarray(pnr))
             if self.mesh is not None:
-                row = NamedSharding(
-                    self.mesh, PartitionSpec(self._batch_axis, None)
-                )
                 put = (
                     put_global if is_cross_process(self.mesh) else jax.device_put
                 )
-                ranks = put(ranks, (row, row))
+                ranks = put(ranks, self._state_shardings(self._sharding, ranks))
             self._fault_name_ranks = ranks
 
         # Sliding runs: install the initial windowed name-rank slice
@@ -1838,7 +1853,8 @@ class BatchedSimulation:
     def kernel_formulation(self) -> dict:
         """What the static fit gates picked for this build: the scheduling
         cycle's formulation (scan < candidate < select < megakernel), how
-        it ranks nodes (pipeline.exact_score_bits) and, with the cluster
+        it ranks nodes (pipeline.exact_score_bits), how a mesh build is
+        sharded (sharding.over_clusters) and, with the cluster
         autoscaler on, whether each CA walk runs as its
         Pallas kernel or as the XLA loop (the gates of
         autoscale._ca_scale_up / _ca_scale_down, same predicates). What
@@ -1855,7 +1871,16 @@ class BatchedSimulation:
             # how nodes are ranked for a pod: the float32 score, or the
             # exact key a trace of heterogeneous requests calls for
             "ranking": "exact" if self._cycle_profile.exact_bits else "float32",
+            # how a mesh build is sharded: one shard_map round each whole
+            # window program (sharding.py); None = no mesh, nothing wrapped
+            "sharding": None,
         }
+        if self._shards is not None:
+            out.update(
+                sharding="shard_map",
+                shard_axis=self._shards.axis,
+                shards=self._shards.mesh.size,
+            )
         st = self.autoscale_statics
         if st is not None and self.config.cluster_autoscaler.enabled:
             from kubernetriks_tpu.ops.autoscale_kernel import (
@@ -1936,30 +1961,22 @@ class BatchedSimulation:
         if has_rank:
             payload["rank"] = jnp.asarray(seg["rank"])
         if self._sharding is not None:
-            row = NamedSharding(
-                self._sharding.mesh, PartitionSpec(self._batch_axis, None)
-            )
             put = (
                 put_global
                 if is_cross_process(self._sharding.mesh)
                 else jax.device_put
             )
-            payload = put(payload, {k: row for k in payload})
+            payload = put(payload, self._state_shardings(self._sharding, payload))
         self._device_slide = payload
 
     def _state_shardings(self, sharding, tree):
-        """Every non-scalar leaf leads with the C axis; shard axis 0,
-        replicate the rest (scalars are replicated)."""
-
-        def leaf_sharding(leaf):
-            if leaf.ndim == 0:
-                return NamedSharding(sharding.mesh, PartitionSpec())
-            spec = PartitionSpec(
-                *([sharding.spec[0]] + [None] * (leaf.ndim - 1))
-            )
-            return NamedSharding(sharding.mesh, spec)
-
-        return jax.tree.map(leaf_sharding, tree)
+        """Where a per-cluster pytree is placed: by the rule the window
+        programs' shard_map reads its arguments with
+        (sharding.cluster_specs)."""
+        return jax.tree.map(
+            lambda spec: NamedSharding(sharding.mesh, spec),
+            cluster_specs(tree, sharding.spec[0]),
+        )
 
     def _max_events_in_any_window(self, ev_time: np.ndarray) -> int:
         """Worst-case events falling into one (cluster, scheduling-window)
@@ -2019,8 +2036,7 @@ class BatchedSimulation:
             use_pallas=self.use_pallas,
             pallas_interpret=self.pallas_interpret,
             conditional_move=self.conditional_move,
-            pallas_mesh=self.mesh if self.use_pallas else None,
-            pallas_axis=self._batch_axis,
+            shards=self._shards,
             use_pallas_select=self.use_pallas_select,
             use_megakernel=self.use_megakernel,
             hpa_seg=self._hpa_seg,
@@ -2776,18 +2792,12 @@ class BatchedSimulation:
             ),
         )
         if self._sharding is not None:
-            row = NamedSharding(
-                self._sharding.mesh, PartitionSpec(self._batch_axis, None)
-            )
             put = (
                 put_global
                 if is_cross_process(self._sharding.mesh)
                 else jax.device_put
             )
-            stage = put(
-                stage,
-                jax.tree.map(lambda _: row, stage),
-            )
+            stage = put(stage, self._state_shardings(self._sharding, stage))
         return stage
 
     def _make_stage(self, lo: int, width: int) -> RefillStage:
@@ -3686,27 +3696,7 @@ class BatchedSimulation:
             self.slab,
             jnp.asarray(self.next_window_idx, jnp.int32),
             self.consts,
-            self.max_events_per_window,
-            self.max_pods_per_cycle,
-            self.autoscale_statics,
-            self.max_ca_pods_per_cycle,
-            self.max_pods_per_scale_down,
-            self.use_pallas,
-            self.pallas_interpret,
-            self.conditional_move,
-            pallas_mesh=self.mesh if self.use_pallas else None,
-            pallas_axis=self._batch_axis,
-            use_pallas_select=self.use_pallas_select,
-            use_megakernel=self.use_megakernel,
-            hpa_seg=self._hpa_seg,
-            fault_params=self.fault_params,
-            name_ranks=self._fault_name_ranks,
-            lane_major=self.lane_major,
-            window_razor=self.window_razor,
-            ca_descatter=self.ca_descatter,
-            reclaim=self.reclaim,
-            reclaim_period=self.reclaim_period,
-            profile=self._cycle_profile,
+            **self._window_call_kwargs(),
         )
         if self.collect_gauges:
             from kubernetriks_tpu.batched.step import gauge_snapshot

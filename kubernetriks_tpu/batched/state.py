@@ -533,7 +533,9 @@ def init_state(
 # and the pod axis stays row-major everywhere (its sorts / rank builders /
 # candidate gathers are row-major-shaped throughout step.py; see ROADMAP).
 # At rest (engine.state between dispatches, checkpoints, readout) state is
-# ALWAYS row-major; lane-major layout exists only inside compiled programs.
+# ALWAYS row-major; lane-major layout exists only inside compiled programs
+# (under a mesh: inside the program's one shard_map, on the shard's (N,
+# C_local), so a mesh build takes the mode like any other).
 NODE_HOT_LEAVES = (
     "alive",
     "cap_cpu",

@@ -43,6 +43,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from kubernetriks_tpu.batched.state import (
     ClusterBatchState,
@@ -64,6 +65,12 @@ from kubernetriks_tpu.batched.state import (
     StepConstants,
     TraceSlab,
     swap_node_layout,
+)
+from kubernetriks_tpu.batched.sharding import (
+    all_min,
+    cluster_ids,
+    over_clusters,
+    shard_axis_of,
 )
 from kubernetriks_tpu.batched.timerep import (
     TPair,
@@ -132,26 +139,6 @@ def _stable_queue_rank(keys) -> jnp.ndarray:
     )
 
 
-
-def _shard_rowwise(core, n_in: int, n_out: int, mesh, axis: str):
-    """shard_map a kernel wrapper over the cluster axis: every input/output
-    is a (C, ...) array sharded on axis 0 (pallas_call has no GSPMD
-    partitioning rule, so each device runs the kernel on its own shard; the
-    wrappers pad per-shard, and clusters are independent so no collectives
-    are needed)."""
-    from jax.sharding import PartitionSpec
-
-    row = PartitionSpec(axis, None)
-    return jax.shard_map(
-        core,
-        mesh=mesh,
-        in_specs=(row,) * n_in,
-        # A kernel returning one bare array (not a 1-tuple) needs a bare spec.
-        out_specs=(row,) * n_out if n_out > 1 else row,
-        check_vma=False,
-    )
-
-
 def _window_work_due(
     state: ClusterBatchState, slab: TraceSlab, W: jnp.ndarray
 ) -> jnp.ndarray:
@@ -207,8 +194,6 @@ def _apply_window_events(
     conditional_move: bool = False,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
     use_pallas_select: bool = False,
     node_name_rank=None,
     pod_name_rank=None,
@@ -232,8 +217,6 @@ def _apply_window_events(
         conditional_move,
         use_pallas,
         pallas_interpret,
-        pallas_mesh,
-        pallas_axis,
         use_pallas_select,
         node_name_rank,
         pod_name_rank,
@@ -278,8 +261,6 @@ def _apply_window_events_work(
     conditional_move: bool = False,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
     use_pallas_select: bool = False,
     node_name_rank=None,
     pod_name_rank=None,
@@ -358,8 +339,6 @@ def _apply_window_events_work(
             interpret=pallas_interpret,
             nodes_lane_major=lane_major,
         )
-        if pallas_mesh is not None:
-            event_core = _shard_rowwise(event_core, 10, 5, pallas_mesh, pallas_axis)
 
     # --- bulk-apply the window's slab events, E at a time -------------------
     # E is a CHUNK size, not a worst-case bound: chunks apply inside a
@@ -702,8 +681,6 @@ def _apply_window_events_work(
             interpret=pallas_interpret,
             nodes_lane_major=lane_major,
         )
-        if pallas_mesh is not None:
-            core = _shard_rowwise(core, 8, 3, pallas_mesh, pallas_axis)
         # The kernel also folds the finished pods' duration-estimator
         # samples (count/total/total_sq/min/max), replacing the five
         # (C, P) masked reductions below.
@@ -1280,6 +1257,7 @@ def commit_scattered_tail(
     start_tmp,
     park_tmp,
     fault_params=None,
+    shard_axis=None,
 ) -> ClusterBatchState:
     """Shared bottom half of the decision commit: reconstruct absolute
     start/finish/park pairs from the scattered float32 second offsets
@@ -1333,8 +1311,10 @@ def commit_scattered_tail(
             cid = jnp.zeros((C, P), jnp.uint32)
         else:
             seed_key = fault_params.seed
+            # The draw keys on the cluster's index in the BUILD, whatever
+            # shard holds it (sharding.cluster_ids).
             cid = jnp.broadcast_to(
-                jnp.arange(C, dtype=jnp.int32)[:, None], (C, P)
+                cluster_ids(C, shard_axis)[:, None], (C, P)
             ).astype(jnp.uint32)
         u_fail, u_frac = chaos.pod_attempt_uniforms(
             seed_key,
@@ -1396,9 +1376,8 @@ def commit_cycle(
     park_s_k,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
     fault_params=None,
+    shard_axis=None,
 ) -> ClusterBatchState:
     """Scatter the K per-cluster decisions back into (C, P) state.
 
@@ -1422,8 +1401,6 @@ def commit_cycle(
 
     if use_pallas and commit_kernel_fits(P, cand.shape[1]):
         core = partial(fused_commit_scatter, interpret=pallas_interpret)
-        if pallas_mesh is not None:
-            core = _shard_rowwise(core, 8, 4, pallas_mesh, pallas_axis)
         phase, node, start_tmp, park_tmp = core(
             cand, assign_k, park_k, best_k, start_s_k, park_s_k,
             pods.phase, pods.node,
@@ -1458,6 +1435,7 @@ def commit_cycle(
         state, pods, cc.last_flush_win, W, consts, alloc_cpu, alloc_ram,
         metrics, phase, node, start_tmp, park_tmp,
         fault_params=fault_params,
+        shard_axis=shard_axis,
     )
 
 
@@ -1469,14 +1447,13 @@ def _run_scheduling_cycle(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     conditional_move: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
     use_pallas_select: bool = False,
     wake=None,
     use_megakernel: bool = True,
     fault_params=None,
     lane_major: bool = False,
     profile=None,
+    shard_axis=None,
 ) -> Tuple[ClusterBatchState, Optional[jnp.ndarray]]:
     """One vectorized kube-scheduler cycle at window W for every cluster
     (scalar equivalent: reference scheduler.rs:246-333). Returns the state
@@ -1550,8 +1527,6 @@ def _run_scheduling_cycle(
             nodes_lane_major=lane_major,
             profile=profile,
         )
-        if pallas_mesh is not None:
-            core = _shard_rowwise(core, 15, 7, pallas_mesh, pallas_axis)
         (alloc_cpu, alloc_ram, phase, node, start_tmp, park_tmp, qstats) = core(
             alive,
             state.nodes.alloc_cpu,
@@ -1605,6 +1580,7 @@ def _run_scheduling_cycle(
             state, pods, last_flush_win, W, consts, alloc_cpu, alloc_ram,
             metrics, phase, node, start_tmp, park_tmp,
             fault_params=fault_params,
+            shard_axis=shard_axis,
         ), sweep
     elif use_pallas and use_pallas_select:
         # Two-kernel fallback (KTPU_MEGAKERNEL=0): in-kernel selection+cycle,
@@ -1623,8 +1599,6 @@ def _run_scheduling_cycle(
             nodes_lane_major=lane_major,
             profile=profile,
         )
-        if pallas_mesh is not None:
-            core = _shard_rowwise(core, 9, 7, pallas_mesh, pallas_axis)
         cand, cand_valid, assign_k, fitany_k, best_k, alloc_cpu, alloc_ram = core(
             alive,
             state.nodes.alloc_cpu,
@@ -1657,8 +1631,6 @@ def _run_scheduling_cycle(
             nodes_lane_major=lane_major,
             profile=profile,
         )
-        if pallas_mesh is not None:
-            core = _shard_rowwise(core, 6, 5, pallas_mesh, pallas_axis)
         assign_k, fitany_k, best_k, alloc_cpu, alloc_ram = core(
             alive,
             state.nodes.alloc_cpu,
@@ -1743,8 +1715,7 @@ def _run_scheduling_cycle(
         assign_k, park_k, best_k, start_s_k, park_s_k,
         use_pallas=use_pallas and use_pallas_select,
         pallas_interpret=pallas_interpret,
-        pallas_mesh=pallas_mesh,
-        pallas_axis=pallas_axis,
+        shard_axis=shard_axis,
         fault_params=fault_params,
     ), None
 
@@ -1915,8 +1886,6 @@ def _window_body(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     conditional_move: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
     use_pallas_select: bool = False,
     use_megakernel: bool = True,
     hpa_seg=None,
@@ -1929,6 +1898,7 @@ def _window_body(
     reclaim_period: int = 1,
     profile=None,
     freeze_lanes: bool = True,
+    shard_axis=None,
 ) -> ClusterBatchState:
     W = jnp.broadcast_to(jnp.asarray(W, jnp.int32), state.time.shape)
     # Lane-async clock protocol (engine lane_async=True, DESIGN §13): each
@@ -2013,8 +1983,6 @@ def _window_body(
         conditional_move,
         use_pallas,
         pallas_interpret,
-        pallas_mesh,
-        pallas_axis,
         use_pallas_select,
         node_name_rank=node_name_rank,
         pod_name_rank=pod_name_rank,
@@ -2040,14 +2008,13 @@ def _window_body(
         use_pallas,
         pallas_interpret,
         conditional_move,
-        pallas_mesh,
-        pallas_axis,
         use_pallas_select,
         wake=wake,
         use_megakernel=use_megakernel,
         fault_params=fault_params,
         lane_major=lane_major,
         profile=profile,
+        shard_axis=shard_axis,
     )
     if autoscale_statics is not None:
         # Autoscaler ticks due by this window run after the scheduling cycle
@@ -2075,8 +2042,6 @@ def _window_body(
             # Each CA kernel gates on its own VMEM fits-check inside.
             use_pallas=use_pallas,
             pallas_interpret=pallas_interpret,
-            pallas_mesh=pallas_mesh,
-            pallas_axis=pallas_axis,
             nodes_lane_major=lane_major,
             descatter=ca_descatter,
             reclaim=reclaim,
@@ -2158,8 +2123,10 @@ _STEP_STATICS = (
     "use_pallas",
     "pallas_interpret",
     "conditional_move",
-    "pallas_mesh",
-    "pallas_axis",
+    # sharding.ClusterShards (the build's mesh and cluster axis) or None;
+    # read by the entry's over_clusters wrapper, which puts ONE shard_map
+    # round the whole program. None = no mesh: the body runs as it is.
+    "shards",
     "use_pallas_select",
     "use_megakernel",
     "hpa_seg",
@@ -2189,7 +2156,14 @@ _STEP_STATICS = (
 )
 
 
+def _out_state(axis, _statics):
+    """out_specs of a window program that returns the state alone: every
+    leaf leads with the cluster axis."""
+    return PartitionSpec(axis)
+
+
 @partial(jax.jit, static_argnames=_STEP_STATICS)
+@over_clusters(_STEP_STATICS, _out_state)
 def window_step(
     state: ClusterBatchState,
     slab: TraceSlab,
@@ -2203,8 +2177,7 @@ def window_step(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     conditional_move: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
+    shards=None,
     use_pallas_select: bool = False,
     use_megakernel: bool = True,
     hpa_seg=None,
@@ -2237,8 +2210,6 @@ def window_step(
         use_pallas,
         pallas_interpret,
         conditional_move,
-        pallas_mesh,
-        pallas_axis,
         use_pallas_select,
         use_megakernel=use_megakernel,
         hpa_seg=hpa_seg,
@@ -2250,6 +2221,7 @@ def window_step(
         reclaim=reclaim,
         reclaim_period=reclaim_period,
         profile=profile,
+        shard_axis=shard_axis_of(shards),
     )
     if lane_major:
         state = swap_node_layout(state)
@@ -2263,6 +2235,7 @@ def _next_interesting_window(
     consts: StepConstants,
     autoscale_statics,
     flush_windows: int,
+    shard_axis=None,
 ) -> jnp.ndarray:
     """First window index > W whose body could change state (scalar, min
     over clusters). A window with none of the triggers below is PROVABLY the
@@ -2275,7 +2248,9 @@ def _next_interesting_window(
 
     Every trigger is CONSERVATIVE (running a window early is always safe —
     window execution at any index is semantics-preserving); what is never
-    allowed is skipping past a trigger."""
+    allowed is skipping past a trigger. Under a mesh each shard computes
+    its own candidate and ONE pmin at the end takes the least: a trigger is
+    conservative for the shard's clusters, so the least is for all."""
     from kubernetriks_tpu.batched.timerep import INF_WIN
 
     pods, nodes = state.pods, state.nodes
@@ -2336,6 +2311,8 @@ def _next_interesting_window(
             # tick is a trigger like the HPA's own.
             cand = jnp.minimum(cand, amin(auto.col_next.win))
 
+    if shard_axis is not None:
+        cand = all_min(cand, shard_axis)
     return jnp.maximum(W + jnp.int32(1), cand)
 
 
@@ -2408,6 +2385,10 @@ def _catch_up_bookkeeping(
     return state
 
 
+_SKIP_STATICS = _STEP_STATICS + ("flush_windows",)
+
+
+@over_clusters(_SKIP_STATICS, _out_state)
 def _run_windows_skip_impl(
     state: ClusterBatchState,
     slab: TraceSlab,
@@ -2422,8 +2403,7 @@ def _run_windows_skip_impl(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     conditional_move: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
+    shards=None,
     use_pallas_select: bool = False,
     use_megakernel: bool = True,
     flush_windows: int = 3,
@@ -2443,7 +2423,10 @@ def _run_windows_skip_impl(
     bookkeeping exactly, so the final state is bit-identical to stepping
     every index in [first, last]. One compiled program serves any span
     (first/last are traced scalars). No per-window gauge collection — the
-    engine falls back to run_windows when gauges are on."""
+    engine falls back to run_windows when gauges are on. Under a mesh
+    the next window is the earliest any shard needs (one scalar pmin a loop
+    iteration), so every shard runs the same windows."""
+    shard_axis = shard_axis_of(shards)
     if lane_major:
         # _next_interesting_window / _catch_up_bookkeeping read only
         # row-major leaves (pending pairs, pods), so the lane-major carry
@@ -2469,8 +2452,6 @@ def _run_windows_skip_impl(
             use_pallas,
             pallas_interpret,
             conditional_move,
-            pallas_mesh,
-            pallas_axis,
             use_pallas_select,
             use_megakernel=use_megakernel,
             hpa_seg=hpa_seg,
@@ -2482,10 +2463,12 @@ def _run_windows_skip_impl(
             reclaim=reclaim,
             reclaim_period=reclaim_period,
             profile=profile,
+            shard_axis=shard_axis,
         )
         W_next = jnp.minimum(
             _next_interesting_window(
-                state, slab, W, consts, autoscale_statics, flush_windows
+                state, slab, W, consts, autoscale_statics, flush_windows,
+                shard_axis,
             ),
             last + jnp.int32(1),
         )
@@ -2511,16 +2494,27 @@ def _run_windows_skip_impl(
 # tests/test_window_donation_dispatch.py pins it — but a donated call INVALIDATES its
 # input state; callers that keep the input (tests, warm-up against a scratch
 # copy) use the undonated names.
-run_windows_skip = partial(
-    jax.jit, static_argnames=_STEP_STATICS + ("flush_windows",)
-)(_run_windows_skip_impl)
+run_windows_skip = partial(jax.jit, static_argnames=_SKIP_STATICS)(
+    _run_windows_skip_impl
+)
 run_windows_skip_donated = jax.jit(
     _run_windows_skip_impl,
-    static_argnames=_STEP_STATICS + ("flush_windows",),
+    static_argnames=_SKIP_STATICS,
     donate_argnums=(0,),
 )
 
 
+_WINDOWS_STATICS = _STEP_STATICS + ("collect_gauges", "freeze_lanes")
+
+
+def _out_state_gauges(axis, statics):
+    """(state, (Wn, C, 7) gauges) with collect_gauges, else the state."""
+    if statics["collect_gauges"]:
+        return PartitionSpec(axis), PartitionSpec(None, axis)
+    return PartitionSpec(axis)
+
+
+@over_clusters(_WINDOWS_STATICS, _out_state_gauges, replicated=("window_idxs",))
 def _run_windows_impl(
     state: ClusterBatchState,
     slab: TraceSlab,
@@ -2535,8 +2529,7 @@ def _run_windows_impl(
     pallas_interpret: bool = False,
     conditional_move: bool = False,
     collect_gauges: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
+    shards=None,
     use_pallas_select: bool = False,
     use_megakernel: bool = True,
     hpa_seg=None,
@@ -2574,8 +2567,6 @@ def _run_windows_impl(
             use_pallas,
             pallas_interpret,
             conditional_move,
-            pallas_mesh,
-            pallas_axis,
             use_pallas_select,
             use_megakernel=use_megakernel,
             hpa_seg=hpa_seg,
@@ -2588,6 +2579,7 @@ def _run_windows_impl(
             reclaim_period=reclaim_period,
             profile=profile,
             freeze_lanes=freeze_lanes,
+            shard_axis=shard_axis_of(shards),
         )
         return new, (
             gauge_snapshot(new, lane_major=lane_major)
@@ -2603,12 +2595,12 @@ def _run_windows_impl(
     return state
 
 
-run_windows = partial(
-    jax.jit, static_argnames=_STEP_STATICS + ("collect_gauges", "freeze_lanes")
-)(_run_windows_impl)
+run_windows = partial(jax.jit, static_argnames=_WINDOWS_STATICS)(
+    _run_windows_impl
+)
 run_windows_donated = jax.jit(
     _run_windows_impl,
-    static_argnames=_STEP_STATICS + ("collect_gauges", "freeze_lanes"),
+    static_argnames=_WINDOWS_STATICS,
     donate_argnums=(0,),
 )
 
@@ -2619,11 +2611,12 @@ run_windows_donated = jax.jit(
 
 
 @jax.named_scope("slide")
-def _slide_shift_core(phase, create_win_pay, base):
+def _slide_shift_core(phase, create_win_pay, base, shard_axis=None):
     """The window-shift amount, computed ON DEVICE: the leading run of
     terminal-or-padding pod slots across every cluster (min over C of each
-    row's first blocking slot). Bit-identical to the host formulation in
-    engine._advance_pod_window (same terminal set, same padding rule); only
+    row's first blocking slot; inside a window program's shard_map over
+    every shard's, so pod_base stays uniform). Bit-identical to the host
+    formulation in engine._advance_pod_window (same terminal set, same padding rule); only
     a 4-byte scalar reaches the host instead of the full (C, W) phase
     fetch. `base` indexes create_win_pay's columns — GLOBAL plain slots for
     the whole-trace payload, stage-relative under a bounded RefillStage."""
@@ -2642,7 +2635,7 @@ def _slide_shift_core(phase, create_win_pay, base):
         jnp.argmax(blocking, axis=1).astype(jnp.int32),
         jnp.int32(W),
     )
-    return jnp.min(first_live).astype(jnp.int32)
+    return all_min(first_live, shard_axis).astype(jnp.int32)
 
 
 def _quantize_shift_device(s0, W: int):
@@ -2729,6 +2722,15 @@ SUPERSPAN_GROW = 1  # shift == 0: the live-pod span outgrew the window
 SUPERSPAN_STAGE = 2  # next slide needs refill columns beyond the stage
 
 
+_SUPERSPAN_STATICS = _STEP_STATICS + ("W", "K", "chunk")
+
+
+def _out_superspan(axis, _statics):
+    """(state, windowed pod-name ranks | None, the (4,) progress vector)."""
+    return PartitionSpec(axis), PartitionSpec(axis), PartitionSpec()
+
+
+@over_clusters(_SUPERSPAN_STATICS, _out_superspan, replicated=("progress",))
 def _run_superspan_impl(
     state: ClusterBatchState,
     rank,
@@ -2746,8 +2748,7 @@ def _run_superspan_impl(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     conditional_move: bool = False,
-    pallas_mesh=None,
-    pallas_axis: str = "clusters",
+    shards=None,
     use_pallas_select: bool = False,
     use_megakernel: bool = True,
     hpa_seg=None,
@@ -2808,6 +2809,12 @@ def _run_superspan_impl(
     big = jnp.int32(np.iinfo(np.int32).max)
     from kubernetriks_tpu.batched.autoscale import statics_with_pod_rank
 
+    # Under a mesh the three reads below that span every cluster (pod_base,
+    # the capacity column, the slide's shift) are pmin'ned, so `bound`, the
+    # branch taken, the loop's trip count and the exit code are the same on
+    # every shard: the collectives sit where all shards meet.
+    shard_axis = shard_axis_of(shards)
+
     if lane_major:
         # One conversion per superspan dispatch (covers up to K slide-spans
         # of windows); everything the loop touches outside _window_body —
@@ -2844,8 +2851,6 @@ def _run_superspan_impl(
                 use_pallas,
                 pallas_interpret,
                 conditional_move,
-                pallas_mesh,
-                pallas_axis,
                 use_pallas_select,
                 use_megakernel=use_megakernel,
                 hpa_seg=hpa_seg,
@@ -2857,6 +2862,7 @@ def _run_superspan_impl(
                 reclaim=reclaim,
                 reclaim_period=reclaim_period,
                 profile=profile,
+                shard_axis=shard_axis,
             )
             return new, None
 
@@ -2870,8 +2876,8 @@ def _run_superspan_impl(
     def body(carry):
         state, rank, w, spans, code = carry
         # pod_base is uniform across clusters (slides shift every row
-        # together); min() is the replicated-scalar read under a mesh.
-        base = jnp.min(state.pod_base)
+        # together); the min is its scalar read.
+        base = all_min(state.pod_base, shard_axis)
         # Capacity: the last window index dispatchable before a pod creation
         # would land beyond the device window — the create window of global
         # plain slot base + W (engine._pod_capacity_window's device twin).
@@ -2880,10 +2886,11 @@ def _run_superspan_impl(
         # slide branch (which then exits SUPERSPAN_STAGE or GROW).
         gcol = base + jnp.int32(W)
         col = gcol - stage_lo
-        cap_read = jnp.min(
+        cap_read = all_min(
             jax.lax.dynamic_slice_in_dim(
                 stage.create_win, jnp.clip(col, 0, L - 1), 1, axis=1
-            )
+            ),
+            shard_axis,
         ).astype(jnp.int32)
         cap = jnp.where(
             gcol >= consts.trace_pod_bound,
@@ -2914,7 +2921,8 @@ def _run_superspan_impl(
         def slide_branch(op):
             state, rank, w, spans = op
             s0 = _slide_shift_core(
-                state.pods.phase[:, :W], stage.create_win, base - stage_lo
+                state.pods.phase[:, :W], stage.create_win, base - stage_lo,
+                shard_axis,
             )
             s = _quantize_shift_device(s0, W)
             blocked = s <= jnp.int32(0)
@@ -2975,12 +2983,11 @@ def _run_superspan_impl(
     if lane_major:
         state = swap_node_layout(state)
     progress_out = jnp.stack(
-        [w, jnp.min(state.pod_base), spans, code]
+        [w, all_min(state.pod_base, shard_axis), spans, code]
     ).astype(jnp.int32)
     return state, rank, progress_out
 
 
-_SUPERSPAN_STATICS = _STEP_STATICS + ("W", "K", "chunk")
 run_superspan = partial(jax.jit, static_argnames=_SUPERSPAN_STATICS)(
     _run_superspan_impl
 )

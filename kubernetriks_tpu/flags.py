@@ -99,7 +99,8 @@ _FLAGS = [
         "within the documented docs/PARITY.md tolerance). Unset: on for "
         "accelerator backends, off on CPU hosts (where XLA pays the "
         "transposes anyway and the row-major path avoids the extra "
-        "program variants). Unsupported (ignored) under a device mesh.",
+        "program variants). A mesh build follows the same rule: the swap "
+        "happens on the shard, inside the window program's shard_map.",
     ),
     Flag(
         "KTPU_WINDOW_RAZOR",

@@ -472,6 +472,6 @@ def fused_ca_scale_up(
             interpret=interpret,
         )(*args)
 
-    # starved as (C, 1) so shard_map's uniform (axis, None) out_specs apply.
+    # starved as (C, 1): every output leads with the cluster axis.
     return planned_o[:S, :C].T != 0, gpl_o[:Gn, :C].T, starved_o[0:1, :C].T
 

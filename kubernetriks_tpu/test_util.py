@@ -82,3 +82,25 @@ def quirkify_csv(text, crlf=False, quote=False, header=None):
         lines.insert(0, header)
     eol = "\r\n" if crlf else "\n"
     return eol.join(lines) + eol
+
+
+# --- sharded against unsharded ------------------------------------------------
+
+
+def leaves_differing(a, b) -> list:
+    """Key paths of the leaves of two state pytrees that are not EXACTLY
+    equal, float accumulators included (a sharded run is the same simulation
+    as the unsharded one: each device runs the one-chip program on its shard,
+    so not even a reduction's order may differ). [] = identical."""
+    import jax
+    import numpy as np
+
+    flat_a, tree_a = jax.tree_util.tree_flatten_with_path(a)
+    flat_b, tree_b = jax.tree_util.tree_flatten_with_path(b)
+    if tree_a != tree_b:
+        return [f"<tree structure: {tree_a} != {tree_b}>"]
+    return [
+        jax.tree_util.keystr(path)
+        for (path, x), (_, y) in zip(flat_a, flat_b)
+        if not np.array_equal(np.asarray(x), np.asarray(y), equal_nan=True)
+    ]

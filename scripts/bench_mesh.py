@@ -190,7 +190,8 @@ def run_mesh_composed(
         stream_depth=stream_depth,
         stream_segment=stream_segment,
         fast_forward=False,
-        # Auto under a mesh on TPU (kernels go through shard_map); forced
+        # Auto under a mesh on TPU (each device runs the window program,
+        # kernels included, on its shard); forced
         # off on CPU hosts where the Pallas path would only interpret.
         use_pallas=None if devices[0].platform == "tpu" else False,
         trace=True,

@@ -1,15 +1,27 @@
 """Batched path sharded over a device mesh: results must be identical to the
-unsharded run, with the cluster axis split across all 8 virtual CPU devices."""
+unsharded run, with the cluster axis split across all 8 virtual CPU devices.
+
+A mesh build has ONE sharding boundary (batched/sharding.py): each window
+program sits in a single shard_map over the cluster axis, every device runs
+the one-chip program on its shard, and GSPMD never partitions the window
+body. The guard below reads the compiled HLO for that; the other tests hold
+the sharded run to the unsharded one leaf for leaf."""
+
+import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from kubernetriks_tpu.batched import step
 from kubernetriks_tpu.batched.engine import BatchedSimulation, build_batched_from_traces
+from kubernetriks_tpu.batched.step import SUPERSPAN_RUN
 from kubernetriks_tpu.batched.trace_compile import compile_cluster_trace
-from kubernetriks_tpu.test_util import default_test_simulation_config
+from kubernetriks_tpu.test_util import default_test_simulation_config, leaves_differing
 from kubernetriks_tpu.trace.generic import GenericClusterTrace, GenericWorkloadTrace
+from tests.sharded_builds import POD_FAULTS, autoscaled_batch, bare_batch, mesh_of
 from tests.test_batched_equivalence import CLUSTER_YAML, make_workload
 
 
@@ -39,21 +51,96 @@ def test_sharded_run_matches_unsharded(mesh):
     unsharded.step_until_time(2000.0)
     sharded.step_until_time(2000.0)
 
-    for field in ["pods_succeeded", "terminated_pods", "scheduling_decisions"]:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(unsharded.state.metrics, field)),
-            np.asarray(getattr(sharded.state.metrics, field)),
-            err_msg=field,
-        )
-    np.testing.assert_array_equal(
-        np.asarray(unsharded.state.pods.phase), np.asarray(sharded.state.pods.phase)
-    )
-    np.testing.assert_allclose(
-        np.asarray(unsharded.state.pods.start_time),
-        np.asarray(sharded.state.pods.start_time),
-        rtol=1e-6,
-    )
+    assert leaves_differing(unsharded.state, sharded.state) == []
     assert sharded.metrics_summary()["counters"]["pods_succeeded"] == 16 * len(pod_names)
+
+
+def test_kernel_formulation_names_the_sharding(mesh):
+    """kernel_formulation() says how the build is sharded: one shard_map
+    round each window program, with the axis and the shard count; None
+    without a mesh (no program is wrapped)."""
+    assert bare_batch(8).kernel_formulation()["sharding"] is None
+    formulation = bare_batch(8, mesh=mesh_of(4)).kernel_formulation()
+    assert formulation["sharding"] == "shard_map"
+    assert formulation["shard_axis"] == "clusters"
+    assert formulation["shards"] == 4
+
+
+def _compiled_window_program(sim, program: str) -> str:
+    """Optimised HLO of the window program the engine would dispatch."""
+    if program == "run_windows":
+        lowered = step.run_windows.lower(
+            sim.state, sim.slab, jnp.arange(4, dtype=jnp.int32), sim.consts,
+            collect_gauges=False, **sim._window_call_kwargs(),
+        )
+    else:
+        stage, lo = sim._current_stage()
+        rank = None if sim.autoscale_statics is None else sim.autoscale_statics.pod_name_rank
+        lowered = step.run_superspan.lower(
+            sim.state, rank,
+            jnp.asarray([0, sim._pod_base, 0, SUPERSPAN_RUN], jnp.int32),
+            sim.slab, sim.consts, stage, jnp.int32(lo), jnp.int32(40),
+            W=sim.pod_window, K=sim._superspan_k, chunk=sim._superspan_chunk,
+            **sim._window_call_kwargs(),
+        )
+    return lowered.compile().as_text()
+
+
+_COLLECTIVE = re.compile(
+    r"= (?P<type>.*?) (?P<op>all-gather|all-to-all|collective-permute|all-reduce"
+    r"|reduce-scatter|collective-broadcast)(?:-start)?\("
+)
+
+
+@pytest.mark.parametrize("n_devices", [4, 8])
+@pytest.mark.parametrize("build", ["bare", "autoscaled"])
+@pytest.mark.parametrize("program", ["run_windows", "run_superspan"])
+def test_sharded_window_program_holds_no_gspmd_collective(program, build, n_devices):
+    """The guard that keeps GSPMD out of the window body. Clusters exchange
+    nothing, so the compiled sharded program may hold no all-gather,
+    all-to-all or collective-permute at all, and every all-reduce is the
+    pmin of a scalar (the superspan's pod_base, capacity read and shift) or
+    at most a progress-sized vector. Partitioned by GSPMD the same programs
+    held 39 all-gathers and 10 all-reduces over (C_global, ...) operands:
+    the `x.at[arange(C)[:, None], idx]` gathers and scatters."""
+    make = bare_batch if build == "bare" else autoscaled_batch
+    sim = make(16, mesh=mesh_of(n_devices), pod_window=64, superspan=True)
+    hlo = _compiled_window_program(sim, program)
+    found = [m.groupdict() for m in _COLLECTIVE.finditer(hlo)]
+    assert [c for c in found if c["op"] != "all-reduce"] == []
+    for c in found:
+        shapes = re.findall(r"\w+\[([\d,]*)\]", c["type"])
+        assert shapes and all(
+            int(np.prod([int(d) for d in dims.split(",") if d] or [1])) <= 4
+            for dims in shapes
+        ), c
+    # not vacuous: the superspan really reduces over the mesh, the plain
+    # window scan has nothing to
+    assert bool(found) == (program == "run_superspan"), found
+
+
+def test_kernels_and_lane_major_under_mesh_match_unsharded_every_leaf():
+    """The combination that could not exist before: interpreted kernels
+    (megakernel, event and free scatters) called directly on the shard with
+    lane-major node state, two clusters a device, pod faults on (their draw
+    keys on the cluster's index in the BUILD, not in the shard). Every leaf
+    of the final state equals the unsharded run's."""
+
+    def run(**kwargs):
+        sim = bare_batch(
+            16, POD_FAULTS, use_pallas=True, pallas_interpret=True,
+            lane_major=True, fast_forward=False, **kwargs,
+        )
+        # the dense kernel set below 128 clusters a shard (interpret mode)
+        sim.use_pallas_select = sim.use_megakernel = True
+        sim.step_until_time(900.0)
+        return sim
+
+    unsharded, sharded = run(), run(mesh=mesh_of(8))
+    assert sharded.lane_major and sharded.kernel_formulation()["cycle"] == "megakernel"
+    counters = sharded.metrics_summary()["counters"]
+    assert counters["pod_restarts"] > 0 and counters["pods_succeeded"] > 0
+    assert leaves_differing(unsharded.state, sharded.state) == []
 
 
 @pytest.mark.slow

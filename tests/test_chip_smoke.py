@@ -34,7 +34,7 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     assert start["device"]["platform"] == "cpu"
     assert os.path.basename(start["compile_cache"]) == ".jax_cache"
 
-    kernels = {"cycle": "candidate", "interpret": True, "ranking": "float32"}  # uniform pods: lockstep
+    kernels = {"cycle": "candidate", "interpret": True, "ranking": "float32", "sharding": None}  # uniform pods: lockstep; no mesh
     ca_kernels = {**kernels, "ca_up": "kernel", "ca_down": "kernel"}
     for rec in (pure, composed, served, cli):
         assert rec.pop("wall_s") >= 0

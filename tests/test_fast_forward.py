@@ -201,3 +201,26 @@ def test_gauge_collection_forces_per_window_stepping():
     assert len(times) == 101
     np.testing.assert_allclose(np.diff(times), 10.0)
     assert samples.shape[0] == 101
+
+
+def test_fast_forward_sharded_matches_unsharded_every_leaf():
+    """Fast-forward inside the window program's one shard_map, over clusters
+    that differ (sparse next to dense): each shard finds its own next due
+    window and one pmin takes the earliest, so every shard runs the same
+    windows. Every leaf of the final state equals the unsharded
+    fast-forwarded run's and the dense-stepped one's."""
+    from kubernetriks_tpu.test_util import leaves_differing
+    from tests.sharded_builds import bare_batch, mesh_of
+
+    def run(**kwargs):
+        sim = bare_batch(16, **kwargs)
+        sim.step_until_time(1200.0)
+        return sim
+
+    stepped = run(fast_forward=False)
+    unsharded = run(fast_forward=True)
+    sharded = run(fast_forward=True, mesh=mesh_of(8))
+    assert sharded.fast_forward and sharded.next_window_idx == stepped.next_window_idx
+    assert sharded.metrics_summary()["counters"]["pods_succeeded"] > 0
+    assert leaves_differing(unsharded.state, sharded.state) == []
+    assert leaves_differing(stepped.state, sharded.state) == []

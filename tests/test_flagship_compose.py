@@ -213,3 +213,25 @@ def test_heterogeneous_batch_segmented_layout():
         if i == 0:
             # The group cluster's replica trajectory is its own.
             assert hetero.hpa_replicas(0) == solo.hpa_replicas(0)
+
+
+def test_autoscaled_build_sharded_matches_unsharded_every_leaf():
+    """The small autoscaled build (HPA + CA, full-resident) on a mesh of 4
+    over clusters with workloads of their own: the autoscale passes' conds
+    gate on the SHARD's clusters, and a shard that skips what another runs
+    changes no leaf. Every leaf equals the unsharded run's."""
+    from kubernetriks_tpu.test_util import leaves_differing
+    from tests.sharded_builds import autoscaled_batch, mesh_of
+
+    def run(**kwargs):
+        sim = autoscaled_batch(8, **kwargs)
+        sim.step_until_time(HORIZON)
+        return sim
+
+    unsharded, sharded = run(), run(mesh=mesh_of(4))
+    counters = sharded.metrics_summary()["counters"]
+    for key in ("total_scaled_up_pods", "total_scaled_up_nodes", "total_scaled_down_nodes"):
+        assert counters[key] > 0, (key, counters)
+    per_cluster = np.asarray(sharded.state.metrics.scaled_up_nodes)
+    assert len(set(per_cluster.tolist())) > 1, "the clusters do not differ"
+    assert leaves_differing(unsharded.state, sharded.state) == []

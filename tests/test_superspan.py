@@ -338,3 +338,32 @@ def test_superspan_capacity_edge_restages_instead_of_growing(monkeypatch):
     ladder.step_until_time(2200.0)
     assert ladder.pod_window == W
     _assert_superspan_matches_ladder(ss, ladder)
+
+
+def test_superspan_sliding_stream_sharded_matches_unsharded_every_leaf():
+    """A sliding stream through the superspan executor on a mesh of 4, over
+    clusters whose pod windows would slide by different amounts: pod_base,
+    the capacity read and the slide's shift are pmin'ned over the shards, so
+    the loop's trip count and every exit code are the same on each, and the
+    final state (pod faults on, HPA + CA acting) equals the unsharded run's
+    in every leaf, with the same slide trajectory."""
+    from kubernetriks_tpu.test_util import leaves_differing
+    from tests.sharded_builds import POD_FAULTS, autoscaled_batch, mesh_of
+
+    def run(**kwargs):
+        sim = autoscaled_batch(8, POD_FAULTS, pod_window=64, superspan=True, **kwargs)
+        return _run(sim, ends=(500.0, 1000.0, 1500.0))
+
+    unsharded, sharded = run(), run(mesh=mesh_of(4))
+    assert sharded.dispatch_stats["superspans"] > 0
+    assert sharded.dispatch_stats["window_chunks"] == 0
+    assert sharded._pod_base == unsharded._pod_base > 0
+    assert sharded.dispatch_stats == unsharded.dispatch_stats
+    counters = sharded.metrics_summary()["counters"]
+    for key in ("total_scaled_up_pods", "total_scaled_up_nodes", "pod_restarts"):
+        assert counters[key] > 0, (key, counters)
+    assert leaves_differing(unsharded.state, sharded.state) == []
+    np.testing.assert_array_equal(
+        np.asarray(sharded.autoscale_statics.pod_name_rank),
+        np.asarray(unsharded.autoscale_statics.pod_name_rank),
+    )

@@ -888,3 +888,22 @@ def test_counter_samples_give_a_delta_over_any_window():
         tr.count("b")
     assert tr.dropped()["counter_samples"] == 5
     assert tr.counters == {"a": 5, "b": 11}
+
+
+def test_device_ring_sharded_matches_unsharded_every_leaf():
+    """The device ring on under a mesh: a ring row is per cluster, so it
+    shards like every other leaf and the drained series, totals included,
+    equals the unsharded run's; so does every other leaf of the state."""
+    from kubernetriks_tpu.test_util import leaves_differing
+    from tests.sharded_builds import bare_batch, mesh_of
+
+    def run(**kwargs):
+        sim = bare_batch(16, telemetry=True, fast_forward=False, **kwargs)
+        sim.step_until_time(600.0)
+        return sim
+
+    unsharded, sharded = run(), run(mesh=mesh_of(8))
+    assert leaves_differing(unsharded.state, sharded.state) == []
+    assert sharded.state.telemetry is not None
+    a, b = unsharded.telemetry_report()["ring"], sharded.telemetry_report()["ring"]
+    assert a == b and a["windows_recorded"] > 0 and a["totals"]["decisions"] > 0
