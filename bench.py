@@ -215,9 +215,7 @@ def _composed_inputs(
     faults: bool = False,
 ):
     """The composed flagship scenario's (config, cluster events, workload
-    events) — shared by run_composed and the autotuner's measurement
-    backend, so the tuner measures candidates on EXACTLY the tracked
-    line's traces."""
+    events)."""
     from kubernetriks_tpu.config import SimulationConfig
     from kubernetriks_tpu.trace.generator import (
         PoissonWorkloadTrace,
@@ -292,13 +290,11 @@ def run_composed(
     trace_path: str = None,  # Chrome trace output (Perfetto-loadable)
     metrics_path: str = None,  # capacity-observatory JSONL/prom export stem
     # PR 9 window-cost switches (None = engine/platform default) — exposed
-    # so the A/B capture protocol can isolate each front against the same
-    # bench scenario (see BENCH_r07.json).
+    # so an A/B can isolate each front against the same bench scenario.
     lane_major=None,
     window_razor=None,
     ca_descatter=None,
     profile=None,  # --profile: named scheduler profile (None = default)
-    **engine_kwargs,  # tuned_profile=... and other build passthroughs
 ) -> dict:
     """The COMPOSED flagship configuration as a tracked line (VERDICT r3
     item 4): HPA pod groups + cluster autoscaler + sliding pod window +
@@ -351,7 +347,6 @@ def run_composed(
         # Without --trace, pass None so a user's KTPU_TRACE=1 still arms
         # the recorder (a concrete False would override the env flag).
         telemetry=True if trace else None,
-        **engine_kwargs,
     )
 
     _assert_profile_compiled(sim, profile, "composed bench")
@@ -387,7 +382,7 @@ def run_composed(
     # Span VALIDITY (r7 protocol fix): a timed span that committed ZERO
     # decisions ran past trace exhaustion (or landed wholly inside an HPA
     # load-curve trough) — its rate is 0 by construction and poisons the
-    # min/median (BENCH_r06.json recorded spans.min = 0 exactly this way).
+    # min/median.
     # Zero-decision spans are DROPPED from the protocol; if fewer than 5
     # valid spans remain by t_end, the bench re-arms extra spans (the HPA
     # churn cycles indefinitely, so decisions resume) up to a hard cap and
@@ -452,10 +447,10 @@ def run_composed(
             sim.dispatch_stats["slide_syncs"]
             == sim.dispatch_stats["superspans"]
         ), "composed bench: streaming added host syncs beyond the budget"
-    # Span-spread disclosure (PR 20): BENCH_r07 recorded a 6.3x max/min
-    # span ratio — the median is still the honest headline, but a wide
-    # spread means the per-span rate is load-phase-dependent and single
-    # A/B deltas within the spread band are noise. WARN (never fail):
+    # Span-spread disclosure: the median is still the honest headline,
+    # but a wide max/min span ratio means the per-span rate is
+    # load-phase-dependent and single A/B deltas within the spread band
+    # are noise. WARN (never fail):
     # spread is a property of the scenario's load curve, not a bench bug.
     spread_frac = (
         round(max(valid) / min(valid), 3) if min(valid) > 0 else 0.0
@@ -1679,275 +1674,11 @@ def run_host_chaos(
     return out
 
 
-def _tune_roundtrip_check(config, cluster_events, workload, *,
-                          n_clusters, statics, build_kwargs):
-    """Persisted-profile roundtrip gate: an engine built from the profile
-    FILE must resolve bit-for-bit the statics table an engine built from
-    hand-passed kwargs resolves (engine.tuning_statics) — 'the profile
-    loads back build-identical'. Builds two engines WITHOUT stepping them
-    (statics resolution is a build-time affair), returns (n_nodes,
-    saved-profile -> check callable) so the caller can write the profile
-    once the node axis is known."""
-    from kubernetriks_tpu.batched.engine import build_batched_from_traces
-
-    sim_hand = build_batched_from_traces(
-        config, cluster_events, workload, n_clusters=n_clusters,
-        tuned_profile=False, **statics, **build_kwargs,
-    )
-    hand = sim_hand.tuning_statics()
-    n_nodes = sim_hand.n_nodes
-    sim_hand.close()
-
-    def check(profile_file: str) -> None:
-        sim_prof = build_batched_from_traces(
-            config, cluster_events, workload, n_clusters=n_clusters,
-            tuned_profile=profile_file, **build_kwargs,
-        )
-        got = sim_prof.tuning_statics()
-        sim_prof.close()
-        assert got == hand, (
-            f"tuned profile {profile_file} did not load back "
-            f"build-identical: profile build resolved {got}, hand-passed "
-            f"statics resolved {hand}"
-        )
-
-    return n_nodes, hand, check
-
-
-# The composed flagship at the CPU-safe smoke shape — the tuner's
-# measurement scenario (and the smoke tune line's roundtrip shape).
-_TUNE_SMOKE_SHAPE = dict(
-    rate_per_second=0.375, horizon=500.0, max_group_pods=16,
-    burst=(100.0, 150.0, 250.0),
-)
-_TUNE_SMOKE_BUILD = dict(
-    max_pods_per_cycle=64, pod_window=128, use_pallas=False,
-)
-# The hand-picked BENCH_r07 all-on reference: always seeded into the
-# sweep, so the chosen config matches or beats it by construction
-# (search.py takes the argmin over everything measured).
-_TUNE_ALL_ON_SEED = {
-    "superspan": True,
-    "lane_major": True,
-    "window_razor": True,
-    "ca_descatter": True,
-}
-
-
-def run_tune(
-    budget=None,
-    *,
-    n_clusters: int = 4,
-    n_nodes: int = 8,
-    warm_until: float = 290.0,
-    t_end: float = 490.0,
-    step: float = 40.0,
-    json_path: str = None,
-) -> dict:
-    """--tune: the REAL measurement-driven sweep (tune/) over the
-    registered performance statics, on the composed flagship scenario at
-    the given shape. Staged coordinate descent, bench-protocol
-    measurements (>= 5 valid spans each, recompile sentinel armed per
-    candidate, whole-grid bit-identity), the observatory objective —
-    then the winning profile persists to
-    artifacts/tuned/<backend>_<C>x<N>.json (resumable: an existing
-    profile there is the resume cache) and the record carries the
-    tuned-vs-default A/B from the sweep's own measurements."""
-    import jax
-
-    from kubernetriks_tpu.flags import flag_int
-    from kubernetriks_tpu.tune import (
-        BenchMeasurementBackend,
-        load_profile,
-        profile_path,
-        save_profile,
-        staged_coordinate_descent,
-    )
-    from kubernetriks_tpu.tune.search import profile_doc
-
-    if budget is None:
-        budget = flag_int("KTPU_TUNE_BUDGET")
-    backend_name = jax.default_backend()
-    config, cluster_events, workload = _composed_inputs(
-        n_nodes, **_TUNE_SMOKE_SHAPE
-    )
-    be = BenchMeasurementBackend(
-        config, cluster_events, workload,
-        n_clusters=n_clusters,
-        warm_until=warm_until, t_end=t_end, step=step,
-        build_kwargs=dict(_TUNE_SMOKE_BUILD),
-    )
-    # Resume: an existing profile for this backend + lane count (N is
-    # unknown until the first build, hence the glob) is the cache — its
-    # candidates replay for free, budget caps only NEW measurements. A
-    # stale/unreadable profile is disclosed and the sweep starts fresh.
-    import glob as _glob
-
-    from kubernetriks_tpu.tune.profile import ARTIFACT_DIR
-
-    resume = None
-    pattern = json_path or os.path.join(
-        ARTIFACT_DIR, f"{backend_name}_{n_clusters}x*.json"
-    )
-    for candidate_path in sorted(_glob.glob(pattern)):
-        try:
-            resume = load_profile(candidate_path).doc.get("candidates")
-            print(
-                f"tune: resuming from {candidate_path} "
-                f"({len(resume or [])} cached candidates)",
-                file=sys.stderr, flush=True,
-            )
-            break
-        except (ValueError, OSError) as exc:
-            print(
-                f"tune: ignoring unreadable profile {candidate_path}: "
-                f"{exc}",
-                file=sys.stderr, flush=True,
-            )
-    result = staged_coordinate_descent(
-        be,
-        budget=budget,
-        resume_candidates=resume,
-        seed_configs=[dict(_TUNE_ALL_ON_SEED)],
-        log=lambda msg: print(msg, file=sys.stderr, flush=True),
-    )
-    assert be.n_nodes is not None
-    path = json_path or profile_path(backend_name, n_clusters, be.n_nodes)
-    doc = profile_doc(
-        result,
-        backend=backend_name,
-        n_clusters=n_clusters,
-        n_nodes=be.n_nodes,
-        budget=budget,
-        protocol=(
-            "bench.run_composed smoke-shape protocol: warm to "
-            f"{warm_until}s, >=5 valid {step}s spans to {t_end}s, "
-            "zero-decision spans dropped, recompile sentinel armed per "
-            "candidate, whole-grid final-state bit-identity vs the first "
-            "candidate; objective = observatory tuning_objective"
-        ),
-    )
-    save_profile(doc, path)
-    # Roundtrip gate: the file we just wrote builds an engine identical
-    # to hand-passing the chosen statics.
-    _, _, check = _tune_roundtrip_check(
-        config, cluster_events, workload,
-        n_clusters=n_clusters, statics=result.chosen,
-        build_kwargs=dict(_TUNE_SMOKE_BUILD, fast_forward=False),
-    )
-    check(path)
-    baseline_obj = result.baseline["objective"]
-    all_on = result.candidates[1] if len(result.candidates) > 1 else None
-    return {
-        "value": result.objective,
-        "tune": {
-            "backend": backend_name,
-            "profile": path,
-            "chosen": result.chosen,
-            "objective": result.objective,
-            "baseline_objective": baseline_obj,
-            "all_on_objective": (
-                all_on["objective"] if all_on else None
-            ),
-            "ab_vs_default_frac": (
-                round(result.objective / baseline_obj, 4)
-                if baseline_obj else None
-            ),
-            "candidates": len(result.candidates),
-            "measured": result.measured,
-            "reused": result.reused,
-            "complete": result.complete,
-            "roundtrip_build_identical": True,
-            "measurement": "bench",
-        },
-    }
-
-
-def run_tune_fake(json_path: str = None) -> dict:
-    """The fake-backend tune grid (the smoke tune line and --tune-fake /
-    the CI tune-smoke job): the full staged coordinate descent driven by
-    the PINNED FakeMeasurementBackend — a 2-knob bonus table
-    (lane_major, window_razor), so the winner is known — then the real
-    persistence + build seam end to end: the profile JSON is written
-    (geometry taken from a real engine build at the smoke composed
-    shape) and asserted to load back BUILD-IDENTICAL to hand-passed
-    statics. No timings: this line gates the tune plumbing, not
-    performance."""
-    import jax
-
-    from kubernetriks_tpu.tune import (
-        FakeMeasurementBackend,
-        save_profile,
-        staged_coordinate_descent,
-    )
-    from kubernetriks_tpu.tune.search import profile_doc
-
-    backend_name = jax.default_backend()
-    be = FakeMeasurementBackend(
-        {"lane_major": {True: 5.0}, "window_razor": {True: 3.0}}
-    )
-    result = staged_coordinate_descent(be)
-    assert result.chosen["lane_major"] is True, (
-        "fake tune grid: the pinned bonus table makes lane_major=True "
-        f"the winner, got {result.chosen!r}"
-    )
-    assert result.chosen["window_razor"] is True, (
-        "fake tune grid: the pinned bonus table makes window_razor=True "
-        f"the winner, got {result.chosen!r}"
-    )
-    config, cluster_events, workload = _composed_inputs(
-        8, **_TUNE_SMOKE_SHAPE
-    )
-    n_nodes, hand, check = _tune_roundtrip_check(
-        config, cluster_events, workload,
-        n_clusters=4, statics=result.chosen,
-        build_kwargs=dict(_TUNE_SMOKE_BUILD, fast_forward=False),
-    )
-    doc = profile_doc(
-        result,
-        backend=backend_name,
-        n_clusters=4,
-        n_nodes=n_nodes,
-        protocol="FakeMeasurementBackend pinned grid (plumbing gate)",
-    )
-    path = json_path or _tune_path()
-    save_profile(doc, path)
-    check(path)
-    return {
-        "value": result.objective,
-        "tune": {
-            "backend": backend_name,
-            "profile": path,
-            "chosen": result.chosen,
-            "objective": result.objective,
-            "baseline_objective": result.baseline["objective"],
-            "candidates": len(result.candidates),
-            "measured": result.measured,
-            "reused": result.reused,
-            "complete": result.complete,
-            "roundtrip_build_identical": True,
-            "measurement": "fake",
-        },
-    }
-
-
 def _sweep_path() -> str:
     from kubernetriks_tpu.flags import flag_str
 
     stem = flag_str("KTPU_SWEEP_PATH") or "ktpu_sweep"
     return f"{stem}.json"
-
-
-def _tune_path() -> str:
-    """The fake-grid tune line's profile artifact rides the sweep stem:
-    <KTPU_SWEEP_PATH or ./ktpu_sweep>_tuned.json (CI uploads it as the
-    `ktpu-tuned-profile` artifact). The REAL --tune sweep writes to
-    artifacts/tuned/<backend>_<C>x<N>.json instead (tune/profile.py's
-    canonical auto-resolution key)."""
-    from kubernetriks_tpu.flags import flag_str
-
-    stem = flag_str("KTPU_SWEEP_PATH") or "ktpu_sweep"
-    return f"{stem}_tuned.json"
 
 
 def _open_loop_path() -> str:
@@ -2025,20 +1756,6 @@ def _emit_host_chaos(metric: str, value: dict) -> None:
         "host_chaos": value["host_chaos"],
         "value": round(value["value"], 4),
         "unit": "availability",
-    }
-    print(json.dumps(rec), flush=True)
-
-
-def _emit_tune(metric: str, value: dict) -> None:
-    """The tune line's unit is ms/window (the observatory objective the
-    sweep minimizes), not decisions/s — the full sweep disclosure
-    (chosen statics, profile path, baseline A/B, budget accounting)
-    rides in the record."""
-    rec = {
-        "metric": metric,
-        "tune": value["tune"],
-        "value": round(value["value"], 4),
-        "unit": "ms/window",
     }
     print(json.dumps(rec), flush=True)
 
@@ -2164,37 +1881,6 @@ def main(argv=None) -> None:
             ),
         )
         return
-    # --tune-fake: the pinned fake-backend grid + real persistence/build
-    # seam standalone (the CI tune-smoke job: fast, deterministic, no
-    # timings — uploads the written profile as the ktpu-tuned-profile
-    # artifact).
-    if "--tune-fake" in args:
-        _emit_tune(
-            "tuned statics objective (fake-backend grid + profile "
-            "roundtrip, plumbing gate)",
-            run_tune_fake(json_path=_tune_path()),
-        )
-        return
-    # --tune [budget] (or KTPU_TUNE=1): the REAL measurement-driven
-    # sweep — staged coordinate descent over the knob registry with the
-    # bench protocol and the observatory objective, profile persisted to
-    # artifacts/tuned/<backend>_<C>x<N>.json (resumable; KTPU_TUNE_BUDGET
-    # caps new measurements). The record carries the tuned-vs-default
-    # A/B from the sweep's own measurements.
-    from kubernetriks_tpu.flags import flag_bool
-
-    if "--tune" in args or flag_bool("KTPU_TUNE"):
-        budget = None
-        if "--tune" in args:
-            idx = args.index("--tune") + 1
-            if idx < len(args) and not args[idx].startswith("--"):
-                budget = int(args[idx])
-        _emit_tune(
-            "tuned statics objective (measurement-driven sweep over the "
-            "knob registry, composed flagship shape)",
-            run_tune(budget=budget),
-        )
-        return
     if smoke:
         # CPU-safe plumbing check: every line must build, run its full
         # composed machinery (slides, HPA, CA asserts included) and print
@@ -2303,20 +1989,6 @@ def main(argv=None) -> None:
             # performance numbers either way.
             run_shape(4, 8, horizon=200.0, warm_until=90.0, t_end=290.0,
                       step=100.0),
-        )
-        _emit_tune(
-            # The TUNE line: the autotuner's plumbing gate — the full
-            # staged coordinate descent driven by the pinned fake
-            # measurement backend (2-knob bonus table, known winner),
-            # then the REAL persistence + build seam: the profile JSON
-            # is written next to the sweep artifact and asserted (in
-            # run_tune_fake) to load back build-identical to
-            # hand-passed statics via engine.tuning_statics. No
-            # timings; tests/test_bench_smoke.py pins this line's
-            # presence, position and record shape.
-            "tuned statics objective (SMOKE, fake-backend grid + "
-            "profile roundtrip)",
-            run_tune_fake(json_path=_tune_path()),
         )
         if faults:
             _emit(

@@ -20,6 +20,7 @@ growth) are row-wise and stay plain jits over the sharded arrays.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import os
 from functools import partial
@@ -57,6 +58,8 @@ from kubernetriks_tpu.batched.state import (
     swap_node_layout,
     tree_copy,
 )
+from kubernetriks_tpu.batched.statics import NAMES as STATIC_NAMES
+from kubernetriks_tpu.batched.statics import resolve as resolve_statics
 from kubernetriks_tpu.batched.timerep import TPair, from_f64_np, to_f64
 from kubernetriks_tpu.batched.step import (
     _STEP_STATICS,
@@ -78,13 +81,7 @@ from kubernetriks_tpu.batched.trace_compile import (
 )
 from kubernetriks_tpu.config import SimulationConfig
 from kubernetriks_tpu import sanitize
-from kubernetriks_tpu.flags import (
-    flag_bool,
-    flag_int,
-    flag_set,
-    flag_str,
-    flag_tristate,
-)
+from kubernetriks_tpu.flags import flag_bool, flag_str, flag_tristate
 from kubernetriks_tpu.telemetry import (
     GaugeSeries,
     log_chunk_throughput,
@@ -748,7 +745,6 @@ class BatchedSimulation:
         scheduler_profile=None,
         scenario=None,
         lane_async: bool = False,
-        tuned_profile=None,
     ) -> None:
         self.config = config
         self.mesh = mesh
@@ -756,26 +752,6 @@ class BatchedSimulation:
         # The window programs' sharding static (sharding.over_clusters):
         # None without a mesh, and then no program is wrapped.
         self._shards = None if mesh is None else ClusterShards(mesh, batch_axis)
-        # Tuned-statics profile seam (PR 20, tune/): resolution order for
-        # the profile SOURCE is explicit arg > KTPU_TUNED_PROFILE (a
-        # path, or 1/auto resolving artifacts/tuned/ then the bundled
-        # tune/profiles/ dir by backend + geometry) > nothing; per KNOB
-        # the order stays explicit kwarg > the knob's own env flag >
-        # tuned-profile entry > hand-picked platform default, so a
-        # profile never overrides a value someone pinned by hand. An
-        # explicitly named profile raises on backend/geometry mismatch
-        # (naming the field); the n_nodes half of the key is re-checked
-        # after the statics build below, where N is finally known.
-        from kubernetriks_tpu.tune.profile import resolve_build_profile
-
-        self.tuned_profile = resolve_build_profile(
-            tuned_profile,
-            backend=jax.default_backend(),
-            n_clusters=len(compiled_traces),
-        )
-        _tuned = (
-            self.tuned_profile.statics if self.tuned_profile else {}
-        )
         # Scenario-vector fleet (batched/fleet.py): optional per-lane
         # override vectors for the autoscaler control-law parameters.
         # Validated + normalized to (C,) numpy arrays here; the statics
@@ -861,127 +837,86 @@ class BatchedSimulation:
             if sanitize_mode is not None
             else sanitize.sanitize_default()
         )
-        # Buffer donation (KTPU_DONATE / donate arg): the steady-state
-        # dispatch loop consumes its input state buffers in place instead of
-        # re-materializing the full (C,N)/(C,P) state every dispatch.
-        # Bit-identical either way (tests/test_window_donation_dispatch.py);
-        # anything that must keep self.state valid across a dispatch
-        # (precompile_chunks) runs against a scratch copy. Default: on for
-        # accelerator backends — the win is device-buffer reuse;
-        # on CPU hosts it measures neutral-at-best and the donated
-        # program variants would shadow-compile next to any undonated use,
-        # so tests opt in explicitly.
-        if donate is not None:
-            self.donate = bool(donate)
-        else:
-            env = flag_tristate("KTPU_DONATE")
-            if env is None:
-                env = _tuned.get("donate")
-            self.donate = (
-                env if env is not None else jax.default_backend() != "cpu"
-            )
-        # Fused chunk+slide megastep (KTPU_FUSED_SLIDE / fuse_slide arg):
-        # the last ladder chunk of a slide span also computes, quantizes and
-        # applies the window slide on device (see _fused_chunk_slide); the
-        # engine reads one 4-byte shift back asynchronously instead of
-        # dispatching shift + apply separately. Default: on for accelerator
-        # backends — the win is per-span dispatch+sync overhead;
-        # on CPU hosts the extra fused
-        # program variants would only double compile time, so tests opt in
-        # explicitly (tests/test_window_donation_dispatch.py).
-        if fuse_slide is not None:
-            self._fuse_slide = bool(fuse_slide)
-        else:
-            env = flag_tristate("KTPU_FUSED_SLIDE")
-            if env is None:
-                env = _tuned.get("fuse_slide")
-            self._fuse_slide = (
-                env if env is not None else jax.default_backend() != "cpu"
-            )
-        # Superspan executor (KTPU_SUPERSPAN / superspan arg): the
-        # steady-state sliding loop dispatches ONE device program per up-to-K
-        # slide-spans (step.run_superspan) — windows, shift computation,
-        # quantization and slide application all inside one while_loop, refill
-        # columns drawn from a device-resident staging slab — instead of
-        # popcount(span) ladder chunks + a per-span shift readback. The only
-        # host sync left in steady state is the (4,)-int32 progress readback,
-        # one per superspan. Bit-identical to the ladder path
-        # (tests/test_superspan.py); default on for accelerator backends —
-        # on CPU hosts the extra program variant would only double compile
-        # time, so tests opt in explicitly.
-        if superspan is not None:
-            self._superspan = bool(superspan)
-        else:
-            env = flag_tristate("KTPU_SUPERSPAN")
-            if env is None:
-                env = _tuned.get("superspan")
-            self._superspan = bool(
-                env if env is not None else jax.default_backend() != "cpu"
-            )
-        if superspan_k is None:
-            superspan_k = _tuned.get("superspan_k", 16)
-        if superspan_chunk is None:
-            superspan_chunk = _tuned.get("superspan_chunk", 8)
-        if superspan_stage_cols is None:
-            superspan_stage_cols = _tuned.get("superspan_stage_cols")
-        self._superspan_k = max(1, int(superspan_k))
-        self._superspan_chunk = max(1, int(superspan_chunk))
-        self._superspan_stage_cols = superspan_stage_cols
-        # Streaming trace-ingestion pipeline (KTPU_STREAM / stream arg):
-        # a feeder thread (batched/stream.py) compiles trace segments into
-        # a bounded ring of K device-resident RefillStage slabs, running
-        # AHEAD of the superspan dispatch loop — stage-exhaustion exits
-        # find the next slab already uploaded, and the whole-trace device
-        # slide payload is never materialized (host+device staging memory
-        # is O(K x segment), not O(trace)). Rides the superspan executor:
-        # tristate default mirrors KTPU_SUPERSPAN (accelerator on, CPU
-        # off), and an explicit stream=True without the superspan executor
-        # is a loud error rather than a silent whole-trace fallback.
-        if stream is not None:
-            self._stream = bool(stream)
-            if self._stream and not self._superspan:
-                raise ValueError(
-                    "stream=True requires the superspan executor "
-                    "(superspan=True / KTPU_SUPERSPAN): the streaming "
-                    "feeder stages slabs for run_superspan's bounded "
-                    "RefillStage path"
-                )
-        else:
-            env = flag_tristate("KTPU_STREAM")
-            if env is None:
-                env = _tuned.get("stream")
-            self._stream = (
-                bool(env if env is not None else jax.default_backend() != "cpu")
-                and self._superspan
-            )
+        # Every performance static is decided in batched/statics.py (explicit
+        # kwarg > its KTPU_* flag > platform default), once, here. What
+        # follows narrows the record by what only the engine knows (a
+        # cross-process mesh, a lane-async build; reclaim's geometry check
+        # further down) and assigns the attributes the rest of the engine
+        # and its callers read.
+        requested = locals()
+        st = resolve_statics(
+            {name: requested[name] for name in STATIC_NAMES},
+            jax.default_backend(),
+        )
         if mesh is not None and is_cross_process(mesh):
-            # Forced off on CROSS-PROCESS meshes (the lane_major
-            # precedent): the feeder thread's uploads go through
-            # put_global, whose collective ordering across hosts is only
-            # coordinated on the engine thread — an uncoordinated
-            # feeder-thread put could interleave with the engine's
-            # collectives. Single-process meshes (incl. a whole v5e-8)
+            # The feeder thread's uploads go through put_global, whose
+            # collective ordering across hosts is only coordinated on the
+            # engine thread. Single-process meshes (a whole v5e-8 included)
             # stream normally; cross-process runs keep the resident
             # device-slide payload path.
-            self._stream = False
-        if stream_depth is None:
-            # KTPU_STREAM_DEPTH has a concrete registry default (3), so
-            # "flag unset" is checked explicitly — otherwise a tuned
-            # depth could never apply.
-            if flag_set("KTPU_STREAM_DEPTH"):
-                stream_depth = flag_int("KTPU_STREAM_DEPTH")
-            else:
-                stream_depth = _tuned.get(
-                    "stream_depth", flag_int("KTPU_STREAM_DEPTH")
+            st = dataclasses.replace(st, stream=False)
+        # Lane-asynchronous fleet mode (batched/fleet.py, DESIGN §13):
+        # per-lane window clocks in StepConstants (lane_clock/lane_horizon)
+        # let each lane run its own virtual span inside the shared window
+        # programs — a finished lane is frozen by the window body and
+        # re-seeded in place (set_lane_plan + lane_reset) while neighbors
+        # keep stepping. Requires a SCENARIO build (the per-lane reset
+        # pristine + scenario leaves are the substrate) and the
+        # full-resident dispatch path: the sliding window, superspan
+        # executor, streaming feeder, fused slide and fast-forward skip all
+        # assume one fleet-global clock, so composing them here would be a
+        # silent correctness hazard — asked for by name they raise; their
+        # flags and accelerator defaults are turned off instead.
+        self.lane_async = bool(lane_async)
+        if self.lane_async:
+            if self._scenario is None:
+                raise ValueError(
+                    "lane_async=True requires a scenario build (scenario="
+                    "{...} / ScenarioFleet): per-lane resets re-seed from "
+                    "the scenario pristine"
                 )
-        self._stream_depth = max(1, int(stream_depth))
-        if stream_segment is None:
-            stream_segment = flag_int("KTPU_STREAM_SEGMENT")
-        if stream_segment is None:
-            stream_segment = _tuned.get("stream_segment")
-        self._stream_segment = (
-            None if stream_segment is None else int(stream_segment)
+            if pod_window is not None:
+                raise ValueError(
+                    "lane_async=True requires the full-resident pod path "
+                    "(pod_window=None): the sliding window's refill cursor "
+                    "is fleet-global"
+                )
+            global_clock = ("superspan", "stream", "fuse_slide")
+            named = [
+                f"{name}=True"
+                for name in global_clock
+                if st.source[name] == "kwarg" and getattr(st, name)
+            ]
+            if named:
+                raise ValueError(
+                    f"lane_async=True is incompatible with {', '.join(named)}"
+                    ": the superspan executor, the streaming feeder and the "
+                    "fused slide assume one fleet-global window clock"
+                )
+            st = dataclasses.replace(st, **dict.fromkeys(global_clock, False))
+            fast_forward = False
+        self.statics = st
+        self.donate = st.donate
+        self._fuse_slide = st.fuse_slide
+        self._superspan = st.superspan
+        self._superspan_k = st.superspan_k
+        self._superspan_chunk = st.superspan_chunk
+        self._superspan_stage_cols = st.superspan_stage_cols
+        self._stream = st.stream
+        self._stream_depth = st.stream_depth
+        self._stream_segment = st.stream_segment
+        self.lane_major = st.lane_major
+        self.window_razor = st.window_razor
+        self.ca_descatter = st.ca_descatter
+        self.reclaim_period = st.reclaim_period
+        # None: reclaim was left to the platform default, so the build may
+        # turn it off where it cannot hold and a restore may follow the
+        # checkpoint; asked for by kwarg or flag, both raise instead.
+        # Finalized after the autoscale statics are built below.
+        self._reclaim_requested = (
+            None if st.source["reclaim"] == "default" else st.reclaim
         )
+        self.reclaim = False
         # The live feeder (stream.StreamFeeder) — built lazily at the
         # first staged dispatch, closed + rebuilt (re-seek) on window
         # growth and checkpoint restore. _feeder_produced_total carries
@@ -1006,80 +941,6 @@ class BatchedSimulation:
             self._feeder_chaos = HostChaos.from_flag(
                 flag_str("KTPU_HOST_CHAOS")
             )
-        # Lane-major hot node state (KTPU_LANE_MAJOR / lane_major arg): the
-        # window programs carry state.NODE_HOT_LEAVES transposed (N, C) —
-        # the Pallas kernels' layout — killing the per-kernel-boundary
-        # transposes; state at rest stays row-major (conversion lives at
-        # the jit entries). Bit-identical either way
-        # (tests/test_layout_razor.py); default on for accelerator
-        # backends — on CPU XLA pays the layout copies anyway and the
-        # extra program variants would only double compile time, so tests
-        # opt in explicitly. A mesh build follows the same tristate: the
-        # swap happens inside the program's one shard_map, on the shard.
-        if lane_major is not None:
-            self.lane_major = bool(lane_major)
-        else:
-            env = flag_tristate("KTPU_LANE_MAJOR")
-            if env is None:
-                env = _tuned.get("lane_major")
-            self.lane_major = bool(
-                env if env is not None else jax.default_backend() != "cpu"
-            )
-        # Window-cost razor (KTPU_WINDOW_RAZOR / window_razor arg): gate
-        # the per-window resolution soup behind a cheap due-ness predicate
-        # (step._window_work_due) so empty windows in dense traces stop
-        # paying it. Tristate like lane_major: on for accelerator backends,
-        # off on CPU hosts (the cond adds compile to every window program
-        # there against a marginal measured win — BENCH_r07 A/B). CA
-        # de-scatter round 3 (KTPU_CA_DESCATTER / ca_descatter arg):
-        # combined segment-sum + grouping sort in the scale-down cond body
-        # — same program size, so default-on everywhere. All bit-exact.
-        if window_razor is not None:
-            self.window_razor = bool(window_razor)
-        else:
-            env = flag_tristate("KTPU_WINDOW_RAZOR")
-            if env is None:
-                env = _tuned.get("window_razor")
-            self.window_razor = bool(
-                env if env is not None else jax.default_backend() != "cpu"
-            )
-        if ca_descatter is not None:
-            self.ca_descatter = bool(ca_descatter)
-        elif flag_set("KTPU_CA_DESCATTER"):
-            self.ca_descatter = flag_bool("KTPU_CA_DESCATTER")
-        else:
-            self.ca_descatter = bool(
-                _tuned.get("ca_descatter", flag_bool("KTPU_CA_DESCATTER"))
-            )
-        # CA slot reclaim (KTPU_RECLAIM / reclaim arg): a periodic
-        # in-trace compaction returns fully-retired CA reserve slots, so
-        # ca_cursor tracks LIVE occupancy and sustained churn never
-        # exhausts the reserve (ROADMAP #2 — the endurance blocker).
-        # Trajectories are scalar-exact: allocations carry the scalar's
-        # total_allocated naming index and name-ordered walks derive
-        # their order from it (autoscale.ca_name_order). Tristate like
-        # the other perf statics: unset means on for accelerator
-        # backends, off on CPU hosts (the compaction cond + dynamic
-        # orders are extra program text on every window program; tests
-        # and endurance runs opt in explicitly). An explicit reclaim=True
-        # on a trace whose node-name classes interleave (the order
-        # decomposition would be unsound) raises at build; the tristate
-        # default falls back off with a warning. Finalized after the
-        # autoscale statics are built below.
-        self._reclaim_requested = (
-            bool(reclaim) if reclaim is not None else None
-        )
-        if self._reclaim_requested is None:
-            self._reclaim_requested = flag_tristate("KTPU_RECLAIM")
-        if reclaim_period is None:
-            if flag_set("KTPU_RECLAIM_PERIOD"):
-                reclaim_period = flag_int("KTPU_RECLAIM_PERIOD")
-            else:
-                reclaim_period = _tuned.get(
-                    "reclaim_period", flag_int("KTPU_RECLAIM_PERIOD")
-                )
-        self.reclaim_period = max(1, int(reclaim_period))
-        self.reclaim = False
         # (lo, RefillStage) staging buffers for the superspan executor when
         # the whole-trace payload exceeds the device budget: the stage the
         # next dispatch reads, and the double-buffered successor assembled
@@ -1136,44 +997,6 @@ class BatchedSimulation:
         self.conditional_move = bool(
             config.enable_unscheduled_pods_conditional_move
         )
-        # Lane-asynchronous fleet mode (batched/fleet.py, DESIGN §13):
-        # per-lane window clocks in StepConstants (lane_clock/lane_horizon)
-        # let each lane run its own virtual span inside the shared window
-        # programs — a finished lane is frozen by the window body and
-        # re-seeded in place (set_lane_plan + lane_reset) while neighbors
-        # keep stepping. Requires a SCENARIO build (the per-lane reset
-        # pristine + scenario leaves are the substrate) and the
-        # full-resident dispatch path: the sliding window, superspan
-        # executor, streaming feeder and fast-forward skip all assume one
-        # fleet-global clock, so composing them here would be a silent
-        # correctness hazard — loud errors instead (the
-        # stream-without-superspan precedent).
-        self.lane_async = bool(lane_async)
-        if self.lane_async:
-            if self._scenario is None:
-                raise ValueError(
-                    "lane_async=True requires a scenario build (scenario="
-                    "{...} / ScenarioFleet): per-lane resets re-seed from "
-                    "the scenario pristine"
-                )
-            if pod_window is not None:
-                raise ValueError(
-                    "lane_async=True requires the full-resident pod path "
-                    "(pod_window=None): the sliding window's refill cursor "
-                    "is fleet-global"
-                )
-            if superspan or stream:
-                raise ValueError(
-                    "lane_async=True is incompatible with the superspan "
-                    "executor / streaming feeder: their progress carries "
-                    "assume one fleet-global window clock"
-                )
-            # Tristate-off the global-clock perf statics instead of
-            # erroring on their accelerator defaults.
-            self._superspan = False
-            self._stream = False
-            self._fuse_slide = False
-            fast_forward = False
         self.consts = make_step_constants(config)
         self.ram_unit = ram_unit
         compiled_traces = list(compiled_traces)
@@ -1440,9 +1263,7 @@ class BatchedSimulation:
             self._autoscale_aux = aux
             # Finalize the reclaim decision now that the name-order
             # tables' verification outcome is known.
-            want = self._reclaim_requested
-            if want is None:
-                want = jax.default_backend() != "cpu"
+            want = self.statics.reclaim
             supported = ca_on and statics.ca_slot_class is not None
             if want and not supported:
                 reason = aux.get("reclaim_unsupported") or "unsupported"
@@ -1503,12 +1324,6 @@ class BatchedSimulation:
                 [(node_cap_cpu, node_cap_ram)],
             )
         )
-        # N is only known here (derived from the traces + CA reserve
-        # groups), so the tuned profile's node-axis key is re-checked
-        # post-build: strict (explicit) profiles raise GeometryMismatch,
-        # auto-resolved ones warn loudly and keep the applied statics.
-        if self.tuned_profile is not None:
-            self.tuned_profile.check_geometry(n_nodes=self.n_nodes)
         # Real (trace-defined) pod slots, before the 128-alignment padding
         # of the device axis — the count completion/terminal asserts want.
         self.n_real_pods = max((c.n_pods for c in compiled_traces), default=0)
@@ -3829,27 +3644,11 @@ class BatchedSimulation:
                     "victim/walk selection is no longer exact past it"
                 )
 
-    def tuning_statics(self) -> Dict[str, object]:
-        """The RESOLVED values of every closed-domain tuning knob
-        (tune/knobs.py) this build compiled in — after the full per-knob
-        precedence (explicit kwarg > env flag > tuned profile > platform
-        default) played out. The autotuner's profile-roundtrip gates
-        compare this table across builds: a profile that 'loads back
-        build-identical' means equal tables here."""
-        # Every field below is a plain Python jit-static the constructor
-        # already normalised to bool/int — no array readout happens here.
-        return {
-            "superspan": self._superspan,
-            "fuse_slide": self._fuse_slide,
-            "superspan_k": int(self._superspan_k),
-            "superspan_chunk": int(self._superspan_chunk),
-            "lane_major": self.lane_major,
-            "window_razor": self.window_razor,
-            "ca_descatter": self.ca_descatter,
-            "donate": self.donate,
-            "stream": self._stream,
-            "stream_depth": int(self._stream_depth),
-        }
+    def resolved_statics(self) -> Dict[str, object]:
+        """Every static of batched/statics.py as this build stands: the
+        record the constructor resolved and narrowed, with `reclaim` as
+        the geometry (or a restored checkpoint) left it."""
+        return {**self.statics.as_dict(), "reclaim": self.reclaim}
 
     def metrics_summary(self) -> Dict:  # ktpu: sync-ok(readout: one-shot cross-cluster metric reduction after the run)
         """Cross-cluster reduction into the scalar printer's shape. On a

@@ -465,7 +465,6 @@ class ScenarioFleet:
         quarantine_window: int = 64,
         quarantine_backoff: int = 8,
         host_chaos: Optional[HostChaos] = None,
-        tuned_profile=None,
         **engine_kwargs,
     ) -> None:
         from kubernetriks_tpu.batched.engine import build_batched_from_traces
@@ -499,13 +498,8 @@ class ScenarioFleet:
             workload_events,
             n_clusters=self.n_lanes,
             scenario=dict(self._vectors),
-            tuned_profile=tuned_profile,
             **engine_kwargs,
         )
-        # The profile the engine build resolved (explicit arg >
-        # KTPU_TUNED_PROFILE > none) — surfaced here so fleet callers and
-        # the bench record can disclose which statics source served.
-        self.tuned_profile = self.engine.tuned_profile
         self._queue: deque = deque()
         self._next_query = 0
         # Terminal outcome per qid: FleetResult (ok=True) or a typed

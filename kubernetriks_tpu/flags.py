@@ -113,8 +113,8 @@ _FLAGS = [
         "the masked elementwise passes entirely. Bit-exact: the skip "
         "branch fires only when the soup is provably the identity. Unset: "
         "on for accelerator backends; off on CPU hosts, where the cond "
-        "adds compile time to every window program and the measured win "
-        "is marginal (BENCH_r07 A/B). 0/1 force for A/B measurement.",
+        "adds compile time to every window program. 0/1 force for A/B "
+        "measurement.",
     ),
     Flag(
         "KTPU_CA_DESCATTER",
@@ -379,41 +379,6 @@ _FLAGS = [
         "Directory holding the real Alibaba v2017 trace CSVs; enables the "
         "real-trace feeder tests when set.",
     ),
-    Flag(
-        "KTPU_TUNE",
-        "bool",
-        False,
-        "Run the measurement-driven statics autotuner (tune/) from "
-        "bench.py without the --tune CLI flag: sweep the registered "
-        "performance knobs with the bench protocol and the observatory "
-        "objective, then persist the winning per-hardware profile under "
-        "artifacts/tuned/<backend>_<C>x<N>.json. Equivalent to "
-        "`bench.py --tune`.",
-    ),
-    Flag(
-        "KTPU_TUNED_PROFILE",
-        "str",
-        None,
-        "Tuned-statics profile for engine builds (tune/profile.py): a "
-        "path to a profile JSON (strict — missing file or "
-        "backend/geometry mismatch raises, naming the field), or "
-        "1/auto/true/on to auto-resolve artifacts/tuned/ then the "
-        "bundled kubernetriks_tpu/tune/profiles/ directory by the "
-        "build's backend + lane count (no match: hand-picked statics, "
-        "quietly). Per knob the profile ranks BELOW the knob's own env "
-        "flag and explicit build kwargs, ABOVE the platform default. "
-        "Unset: no profile is ever consulted — builds stay byte-for-byte "
-        "the pre-tuner behavior.",
-    ),
-    Flag(
-        "KTPU_TUNE_BUDGET",
-        "int",
-        None,
-        "Cap on NEW measurements per autotuner run (resume-cache hits "
-        "are free): an exhausted budget stops the sweep and persists a "
-        "partial profile marked complete=false, which a rerun resumes "
-        "from. Unset: unbounded (the full staged coordinate descent).",
-    ),
 ]
 
 REGISTRY: Dict[str, Flag] = {f.name: f for f in _FLAGS}
@@ -440,22 +405,6 @@ def _lookup(name: str, expected: str) -> Flag:
 def parse_bool(raw: str) -> bool:
     """THE truthiness rule for flag strings (see module docstring)."""
     return raw.strip().lower() not in _FALSY
-
-
-def flag_set(name: str) -> bool:
-    """Whether the flag is present in the environment at all — for the
-    few flags with a concrete (non-None) registered default that a tuned
-    profile may override: the profile ranks below an explicitly SET flag
-    but above the registry default, so "set vs unset" must be observable
-    (flag_bool/flag_int collapse the two)."""
-    flag = REGISTRY.get(name)
-    if flag is None:
-        raise KeyError(
-            f"environment flag {name!r} is not registered in "
-            "kubernetriks_tpu.flags — declare it (name, type, default, doc) "
-            "before reading it"
-        )
-    return name in os.environ
 
 
 def flag_bool(name: str) -> bool:

@@ -854,39 +854,3 @@ class Observatory:
             },
             "samples": self.samples,
         }
-
-
-# --- the autotuner's objective readout (PR 20, tune/) ------------------------
-
-# Each fired stall/occupancy verdict scales the per-window cost by this
-# much: a config that is 10% faster but starves the feeder or saturates
-# a reserve should lose to a clean one. 0.25 is deliberately blunt —
-# verdicts are rare binary events, not a second cost axis to tune.
-VERDICT_PENALTY_FRAC = 0.25
-
-
-def tuning_objective(report: Dict) -> Dict:
-    """Fold one engine `telemetry_report()` into the autotuner's scalar
-    objective: the per-window window-program cost line (dispatch + the
-    blocking readback waits over ring windows — THE observable the
-    hand A/Bs were sized with, BENCH_r07) scaled by a penalty per
-    DISTINCT fired watchdog verdict kind. Pure host dict math on an
-    already-drained report — no device values, per this module's
-    contract. Returns {ms_per_window, verdicts_fired, penalty, score};
-    lower score is better, and a report with no per-window line scores
-    0.0 (callers that require windows assert ms_per_window > 0)."""
-    per_window = report.get("per_window") or {}
-    ms = float(per_window.get("ms_per_window", 0.0))
-    watchdog = (report.get("resources") or {}).get("watchdog") or {}
-    fired = {
-        str(kind): int(count)
-        for kind, count in (watchdog.get("fired") or {}).items()
-        if count
-    }
-    penalty = 1.0 + VERDICT_PENALTY_FRAC * len(fired)
-    return {
-        "ms_per_window": ms,
-        "verdicts_fired": fired,
-        "penalty": penalty,
-        "score": ms * penalty,
-    }

@@ -37,7 +37,7 @@ Usage:
   python scripts/bench_mesh.py --devices 8 --clusters-per-device 1250 \
       --nodes 1000                               # explicit north star
   python scripts/bench_mesh.py --smoke           # tiny shapes (suite smoke)
-  python scripts/bench_mesh.py --composed --out MULTICHIP_r06.json
+  python scripts/bench_mesh.py --composed --out MULTICHIP.json
                                                  # composed+chaos flagship,
                                                  # streaming feeder on
 

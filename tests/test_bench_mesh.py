@@ -19,7 +19,7 @@ import bench_mesh
 def test_bench_mesh_composed_smoke_streams_on_virtual_mesh():
     """--composed --smoke: the composed + chaos flagship shard_mapped
     over the 8-device virtual mesh with the STREAMING feeder staging
-    every slab — the dry-run form of the MULTICHIP_r06 protocol (ISSUE
+    every slab — the dry-run form of the multi-chip protocol (ISSUE
     10). Slow: the sharded composed superspan program is a heavy CPU
     compile; CI runs the same line as its own step and uploads the JSON
     artifact."""

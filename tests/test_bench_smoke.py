@@ -1,7 +1,6 @@
 """bench.py --smoke: the CPU-safe plumbing check for the tracked bench
 lines (continuity shape, composed flagship, superspan machinery,
-streaming feeder, endurance churn, north-star stand-in, tune plumbing).
-Asserts every
+streaming feeder, endurance churn, north-star stand-in). Asserts every
 line builds, RUNS its full machinery — the composed lines include real
 window slides, HPA scale-ups and CA provisioning, the same in-bench
 asserts the flagship line enforces on hardware; the superspan line
@@ -51,13 +50,6 @@ def _smoke_records(capsys, args):
             assert set(rec) == {"metric", "value", "unit", "host_chaos"}
             assert 0.0 <= rec["value"] <= 1.0
             continue
-        if rec.get("unit") == "ms/window":
-            # The tune line (PR 20): the autotuner objective (fake
-            # units on smoke) + the full tune block (chosen statics,
-            # profile path, budget accounting).
-            assert set(rec) == {"metric", "value", "unit", "tune"}
-            assert rec["value"] > 0
-            continue
         assert set(rec) - {"spans", "telemetry", "endurance"} == {
             "metric", "value", "unit", "vs_baseline",
         }
@@ -69,7 +61,7 @@ def _smoke_records(capsys, args):
     return records
 
 
-def test_bench_smoke_emits_ten_parseable_lines(capsys, tmp_path, monkeypatch):
+def test_bench_smoke_emits_nine_parseable_lines(capsys, tmp_path, monkeypatch):
     # --trace rides along (the CI smoke job runs it this way): the
     # composed lines must carry the flight-recorder summary AND write a
     # Perfetto-loadable Chrome trace per traced line.
@@ -77,10 +69,10 @@ def test_bench_smoke_emits_ten_parseable_lines(capsys, tmp_path, monkeypatch):
     monkeypatch.setenv("KTPU_METRICS_PATH", str(tmp_path / "ktpu_metrics"))
     monkeypatch.setenv("KTPU_SWEEP_PATH", str(tmp_path / "ktpu_sweep"))
     records = _smoke_records(capsys, ["--smoke", "--trace"])
-    assert len(records) == 10, records
+    assert len(records) == 9, records
     # Line order is part of the contract: continuity, composed, superspan
     # machinery, streaming feeder, endurance churn, compiled profile,
-    # north-star, tune plumbing, open-loop lane-async fleet, scenario
+    # north-star, open-loop lane-async fleet, scenario
     # fleet (the sweep runs LAST: its cold-process baseline clears the
     # jit caches, which would cold-start anything after it).
     assert "composed" in records[1]["metric"]
@@ -92,29 +84,8 @@ def test_bench_smoke_emits_ten_parseable_lines(capsys, tmp_path, monkeypatch):
     # falls back to the default pipeline, so its presence IS the gate.
     assert "best_fit profile" in records[5]["metric"]
     assert "north-star" in records[6]["metric"]
-    assert "tuned statics" in records[7]["metric"]
-    assert "open-loop lane-async fleet" in records[8]["metric"]
-    assert "scenario-vector fleet" in records[9]["metric"]
-    # The TUNE line (PR 20): run_tune_fake's in-bench assert already
-    # proved the written profile loads back BUILD-IDENTICAL to
-    # hand-passed statics (engine.tuning_statics equality); pin the
-    # disclosure, the pinned fake winner, and the JSON artifact CI
-    # uploads (a valid ktpu-tuned-profile document with every measured
-    # candidate disclosed).
-    tune = records[7]["tune"]
-    assert tune["measurement"] == "fake"
-    assert tune["chosen"]["lane_major"] is True
-    assert tune["chosen"]["window_razor"] is True
-    assert tune["objective"] < tune["baseline_objective"]
-    assert tune["roundtrip_build_identical"] is True
-    assert tune["complete"] is True
-    tuned_doc = json.loads(
-        (tmp_path / "ktpu_sweep_tuned.json").read_text()
-    )
-    assert tuned_doc["kind"] == "ktpu-tuned-profile"
-    assert tuned_doc["statics"] == tune["chosen"]
-    assert len(tuned_doc["candidates"]) == tune["candidates"]
-    assert tuned_doc["knob_registry"]
+    assert "open-loop lane-async fleet" in records[7]["metric"]
+    assert "scenario-vector fleet" in records[8]["metric"]
     # The ENDURANCE line (r14): run_endurance's in-bench gates (reclaim
     # actually fired, flat RSS/slab watermarks, zero recompiles after
     # warm-up, no reserve saturation verdict) already ran — the record's
@@ -136,7 +107,7 @@ def test_bench_smoke_emits_ten_parseable_lines(capsys, tmp_path, monkeypatch):
     # after warm-up, no lane cross-talk on the duplicate-scenario probes)
     # already ran inside run_sweep — the record's sweep block discloses
     # what was checked, and the JSON artifact landed for CI upload.
-    sweep = records[9]["sweep"]
+    sweep = records[8]["sweep"]
     assert sweep["scenarios"] == 8 and sweep["lanes"] == 4
     assert sweep["waves"] == 2
     assert sweep["recompiles_after_warmup"] == 0
@@ -153,7 +124,7 @@ def test_bench_smoke_emits_ten_parseable_lines(capsys, tmp_path, monkeypatch):
     # rounds) already ran; pin the disclosure + the JSON artifact CI
     # uploads. The occupancy/speedup hard gates arm on the full --sweep
     # only — smoke pins the machinery, not toy-shape performance.
-    ol = records[8]["open_loop"]
+    ol = records[7]["open_loop"]
     assert ol["queries"] == 8 and ol["lanes"] == 4
     assert ol["ab_identity_checked"] == 8
     assert ol["recompiles_after_warmup"] == 0
@@ -273,7 +244,7 @@ def test_bench_smoke_faults_adds_chaos_line(capsys, tmp_path, monkeypatch):
     --trace rides along so the traced composed lines are jit-cache hits
     from the previous test (same programs); the chaos line itself is
     untraced either way. Slow lane (tier-1 wall-clock budget): the
-    ten-line test covers every line contract including the sweep; this
+    nine-line test covers every line contract including the sweep; this
     variant only adds the chaos line's presence on top of chaos-path
     coverage tier-1 already carries (test_superspan / test_streaming /
     test_soak fault engines, test_chaos)."""
@@ -281,14 +252,13 @@ def test_bench_smoke_faults_adds_chaos_line(capsys, tmp_path, monkeypatch):
     monkeypatch.setenv("KTPU_METRICS_PATH", str(tmp_path / "ktpu_metrics"))
     monkeypatch.setenv("KTPU_SWEEP_PATH", str(tmp_path / "ktpu_sweep"))
     records = _smoke_records(capsys, ["--smoke", "--faults", "--trace"])
-    assert len(records) == 11, records
-    assert "tuned statics" in records[7]["metric"]
-    assert "chaos" in records[8]["metric"]
-    assert records[8]["value"] > 0
-    assert records[8]["spans"]["n"] >= 5
-    assert "telemetry" not in records[8]
-    assert "open-loop lane-async fleet" in records[9]["metric"]
-    assert "scenario-vector fleet" in records[10]["metric"]
+    assert len(records) == 10, records
+    assert "chaos" in records[7]["metric"]
+    assert records[7]["value"] > 0
+    assert records[7]["spans"]["n"] >= 5
+    assert "telemetry" not in records[7]
+    assert "open-loop lane-async fleet" in records[8]["metric"]
+    assert "scenario-vector fleet" in records[9]["metric"]
 
 
 @pytest.mark.slow
@@ -303,16 +273,15 @@ def test_bench_smoke_host_chaos_adds_availability_line(
     delivery, availability >= 90% under the pinned-seed injector, every
     lane faulted, quarantine fired AND re-admitted, zero post-warm-up
     recompiles; pin the disclosure + the JSON artifact CI uploads. Slow
-    lane: the ten-line test covers the default contract (no flag = no
+    lane: the nine-line test covers the default contract (no flag = no
     line); fault-path unit coverage lives in test_fleet_faults.py."""
     monkeypatch.setenv("KTPU_SWEEP_PATH", str(tmp_path / "ktpu_sweep"))
     records = _smoke_records(capsys, ["--smoke", "--host-chaos"])
-    assert len(records) == 11, records
-    assert "tuned statics" in records[7]["metric"]
-    assert "open-loop lane-async fleet" in records[8]["metric"]
-    assert "host-chaos" in records[9]["metric"]
-    assert "scenario-vector fleet" in records[10]["metric"]
-    hc = records[9]["host_chaos"]
+    assert len(records) == 10, records
+    assert "open-loop lane-async fleet" in records[7]["metric"]
+    assert "host-chaos" in records[8]["metric"]
+    assert "scenario-vector fleet" in records[9]["metric"]
+    hc = records[8]["host_chaos"]
     assert hc["availability"] >= 0.90
     assert hc["lanes"] == 4 and hc["victim_lanes"] == [0, 1, 2, 3]
     assert hc["quarantine_events"] >= 1 and hc["readmissions"] >= 1

@@ -326,7 +326,7 @@ def test_same_tick_create_remove_with_asymmetric_shifts(tmp_path):
 
 
 def test_native_rejects_malformed_required_fields(tmp_path):
-    """Field-validation parity (ADVICE r1): the native parser must reject the
+    """Field-validation parity: the native parser must reject the
     same malformed rows the Python parser raises on, even for columns the
     simulation never reads."""
     import pytest
