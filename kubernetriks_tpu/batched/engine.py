@@ -1337,7 +1337,10 @@ class BatchedSimulation:
         # window with a burst-sized gather/scatter.
         # 32: scatter cost scales with C x E, and typical windows carry far
         # fewer events than a burst; smaller chunks measurably beat 128 on
-        # the TPU (burst windows just loop a few more times).
+        # the TPU (burst windows just loop a few more times). 32 is also a
+        # slab block (state.SLAB_BLOCK_EVENTS): a chunk of at most a block
+        # lies in two neighbouring blocks, so its read costs 2 x C gather
+        # indices whatever the cursor (TraceSlab.read_chunk).
         if max_events_per_window is None:
             max_events_per_window = min(self._max_events_in_any_window(ev_time), 32)
         self.max_events_per_window = max(1, max_events_per_window)
@@ -1539,7 +1542,7 @@ class BatchedSimulation:
             # compiling anything.
             from kubernetriks_tpu.batched.stream import LaneTraceMux
 
-            self._lane_mux = LaneTraceMux(np.asarray(self.slab.packed))  # ktpu: sync-ok(build-time host copy of the freshly built trace slab for the lane mux — construction boundary, no steady-state device read)
+            self._lane_mux = LaneTraceMux(np.asarray(self.slab.rows())[:, : self.n_events])  # ktpu: sync-ok(build-time host copy of the freshly built trace slab's real rows for the lane mux — construction boundary, no steady-state device read)
             rows = self._lane_mux.offer(0)
             self._lane_mux.retire([0])
             self._install_lane_rows(
@@ -2233,13 +2236,14 @@ class BatchedSimulation:
         )
 
     def _install_lane_rows(self, lane: int, rows: np.ndarray) -> None:
-        """Data-only device install of one lane's (E, 4) trace rows via
-        dynamic_update_slice with TRACED start indices — one compiled
+        """Data-only device install of one lane's (E, 4) trace rows (blocked
+        on the host, with the slab's sentinel tail: TraceSlab.block_rows)
+        via dynamic_update_slice with TRACED start indices — one compiled
         program for every lane (a static `.at[lane].set` would compile
         per lane index and trip the post-warm-up sentinel)."""
         packed = jax.lax.dynamic_update_slice(
             self.slab.packed,
-            jnp.asarray(rows, jnp.int32)[None],
+            jnp.asarray(TraceSlab.block_rows(rows))[None],
             (
                 jnp.asarray(lane, jnp.int32),
                 jnp.asarray(0, jnp.int32),
@@ -4112,6 +4116,17 @@ class BatchedSimulation:
             rep["ring"]["cycle_rows_swept_share"] = (
                 totals["cycle_tiles_swept"] / totals["cycle_tile_steps"]
                 if totals["cycle_tile_steps"]
+                else None
+            )
+            # Slab reads a window: the event loop runs until no cluster has
+            # a due event left, so a window paid its clusters' maximum.
+            rep["ring"]["event_chunks_per_window"] = (
+                float(
+                    data[:, :, dring.RING_COLUMNS.index("event_chunks")]
+                    .max(axis=1)
+                    .mean()
+                )
+                if len(wins)
                 else None
             )
         if self.observatory is not None:

@@ -40,6 +40,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from kubernetriks_tpu.batched.timerep import (  # noqa: E402
+    INF_WIN,
     TPair,
     from_f64_np,
     t_inf,
@@ -259,7 +260,14 @@ TELEM_LANE_ACTIVE = 11
 # repeated on its lanes; zeros on formulations without the megakernel.
 TELEM_CYCLE_TILES_SWEPT = 12
 TELEM_CYCLE_TILE_STEPS = 13
-TELEMETRY_COLS = 14
+# The event loop's chunk count (step._apply_window_events_work): how many
+# chunks of max_events_per_window slab entries this cluster's due events
+# took this window. The loop runs until no cluster has one left, so a
+# window's MAXIMUM over clusters is the slab reads it paid;
+# telemetry_report()'s event_chunks_per_window is the mean of those maxima.
+# 0 on a window the razor skipped.
+TELEM_EVENT_CHUNKS = 14
+TELEMETRY_COLS = 15
 
 
 class TelemetryRing(NamedTuple):
@@ -315,29 +323,109 @@ class RefillStage(NamedTuple):
     rank: Optional[jnp.ndarray] = None  # (C, L) int32 lexicographic name ranks
 
 
+# Events a slab block: 4 int32 fields each, so a block is ONE 128-lane row
+# (512 aligned bytes), the unit the TPU's gather moves whole.
+SLAB_BLOCK_EVENTS = 32
+_SLAB_FIELDS = 4  # [win, off-bits, kind, slot]
+_SLAB_BLOCK_LANES = _SLAB_FIELDS * SLAB_BLOCK_EVENTS
+
+
 class TraceSlab(NamedTuple):
     """(C, E) compiled trace events, time-sorted per cluster, padded with
     EV_NONE/time=+inf (win=INF_WIN).
 
-    Columns are stored PACKED — (C, E, 4) int32 [win, off-bits, kind, slot] —
-    and ONLY packed: the hot event loop gathers one (C, chunk, 4) slice
-    instead of four separate (C, chunk) gathers (gather cost is per-index,
-    not per-byte, on TPU), and the slab — the one component that still
-    scales with trace length — carries no duplicate device memory."""
+    Stored BLOCKED, and only so: (C, n_blocks, 128) int32, a block the
+    [win, off-bits, kind, slot] of SLAB_BLOCK_EVENTS consecutive events
+    (lane 4 * e + field: the row-major (E, 4) rows reshaped), after the
+    last real event a tail of sentinel rows (win=INF_WIN, EV_NONE) that
+    fills its block and one whole block more. A gather on the TPU costs
+    per INDEX, some 12 ns each whether the index fetches 16 bytes or 512
+    (PERF.md section 6, PR 31): the chunk a cluster's cursor points at
+    lies in at most two neighbouring blocks when it is no longer than a
+    block, so `read_chunk` fetches those as whole rows, 2 x C indices, and
+    realigns them in registers, where the point gather it replaced paid
+    C x chunk indices of one 16-byte row each. The sentinel block makes
+    every read total: a cursor at or past the end, and a block index
+    clamped to the last block, read events that are never due, so no
+    caller compares a cursor with the number of real rows. The slab (the
+    one component that still scales with trace length) carries no
+    duplicate device memory."""
 
-    packed: jnp.ndarray  # (C, E, 4) int32 [win, off-bits, kind, slot]
+    packed: jnp.ndarray  # (C, n_blocks, 128) int32, lane = 4 * event + field
+
+    @staticmethod
+    def block_rows(rows) -> np.ndarray:
+        """Host (..., E, 4) int32 event rows -> (..., n_blocks, 128) blocks
+        with the sentinel tail."""
+        rows = np.asarray(rows, np.int32)
+        n_blocks = -(-rows.shape[-2] // SLAB_BLOCK_EVENTS) + 1
+        out = np.empty(
+            rows.shape[:-2] + (n_blocks * SLAB_BLOCK_EVENTS, _SLAB_FIELDS),
+            np.int32,
+        )
+        out[..., : rows.shape[-2], :] = rows
+        out[..., rows.shape[-2] :, :] = (INF_WIN, 0, EV_NONE, 0)
+        return out.reshape(rows.shape[:-2] + (n_blocks, _SLAB_BLOCK_LANES))
 
     @staticmethod
     def build(win, off, kind, slot) -> "TraceSlab":
-        win = jnp.asarray(win, jnp.int32)
-        off = jnp.asarray(off, jnp.float32)
-        kind = jnp.asarray(kind, jnp.int32)
-        slot = jnp.asarray(slot, jnp.int32)
-        packed = jnp.stack(
-            [win, jax.lax.bitcast_convert_type(off, jnp.int32), kind, slot],
+        rows = np.stack(
+            [
+                np.asarray(win, np.int32),
+                np.asarray(off, np.float32).view(np.int32),
+                np.asarray(kind, np.int32),
+                np.asarray(slot, np.int32),
+            ],
             axis=-1,
         )
-        return TraceSlab(packed=packed)
+        return TraceSlab(packed=jnp.asarray(TraceSlab.block_rows(rows)))
+
+    def rows(self) -> jnp.ndarray:
+        """The slab as (C, n_blocks * SLAB_BLOCK_EVENTS, 4) event rows,
+        sentinel tail included (a reshape: the blocks ARE the rows)."""
+        C, n_blocks, _ = self.packed.shape
+        return self.packed.reshape(
+            C, n_blocks * SLAB_BLOCK_EVENTS, _SLAB_FIELDS
+        )
+
+    def _clip_block(self, block):
+        return jnp.clip(block, 0, self.packed.shape[1] - 1)
+
+    def win_at(self, cursor) -> jnp.ndarray:
+        """(C,) window index of the event at each cluster's cursor; INF_WIN
+        at and past the end."""
+        rows1 = jnp.arange(self.packed.shape[0], dtype=jnp.int32)
+        return self.packed.at[
+            rows1,
+            self._clip_block(cursor // SLAB_BLOCK_EVENTS),
+            _SLAB_FIELDS * (cursor % SLAB_BLOCK_EVENTS),
+        ].get(mode="promise_in_bounds")
+
+    def read_chunk(self, cursor, chunk: int) -> jnp.ndarray:
+        """(C, chunk, 4) rows [cursor, cursor + chunk) of each cluster,
+        sentinel rows past the end: the blocks that hold them as one row
+        gather, then a barrel shift by cursor % SLAB_BLOCK_EVENTS (five
+        selects over static slices; no second gather)."""
+        C = self.packed.shape[0]
+        n_read = (chunk + SLAB_BLOCK_EVENTS - 2) // SLAB_BLOCK_EVENTS + 1
+        rows = jnp.arange(C, dtype=jnp.int32)[:, None]
+        blocks = self._clip_block(
+            cursor[:, None] // SLAB_BLOCK_EVENTS
+            + jnp.arange(n_read, dtype=jnp.int32)[None, :]
+        )
+        x = self.packed.at[rows, blocks].get(mode="promise_in_bounds")
+        x = x.reshape(C, n_read * _SLAB_BLOCK_LANES)
+        shift = cursor % SLAB_BLOCK_EVENTS
+        step = SLAB_BLOCK_EVENTS // 2
+        while step:
+            lanes = _SLAB_FIELDS * step
+            x = jnp.where(
+                ((shift & step) != 0)[:, None],
+                x[:, lanes:],
+                x[:, : x.shape[1] - lanes],
+            )
+            step //= 2
+        return x[:, : _SLAB_FIELDS * chunk].reshape(C, chunk, _SLAB_FIELDS)
 
 
 class StepConstants(NamedTuple):

@@ -8,9 +8,9 @@ one event at a time) with array programs:
   (SURVEY.md §5.8); the compiler pre-shifts event times to their effect times.
 - Pod completions are precomputed finish times invalidated by masks (replacing
   DSLab cancel_event, reference: src/core/node_component.rs:102-104).
-- Event application is BULK: the window's slab segment is gathered once per
-  cluster, node/pod removal times become scatter-min arrays, and the
-  finish-vs-removal interleaving is resolved elementwise per pod by comparing
+- Event application is BULK: the window's slab segment is read once per
+  cluster as whole blocks (state.TraceSlab.read_chunk), node/pod removal
+  times become scatter-min arrays, and the finish-vs-removal interleaving is resolved elementwise per pod by comparing
   finish_time against min(window_end, node_removal_time, pod_removal_time) —
   ordering fidelity without a per-event loop.
 - The kube-scheduler cycle has three equivalent formulations (see
@@ -162,12 +162,7 @@ def _window_work_due(
     branch replicates exactly that. Layout-agnostic: only row-major leaves
     (pending pairs, pod arrays) and the slab are read."""
     C = state.time.shape[0]
-    E_total = slab.packed.shape[1]
-    rows1 = jnp.arange(C, dtype=jnp.int32)
-    cursor = jnp.clip(state.event_cursor, 0, E_total - 1)
-    ev_due = (
-        (state.event_cursor < E_total) & (slab.packed[rows1, cursor, 0] < W)
-    ).any()
+    ev_due = (slab.win_at(state.event_cursor) < W).any()
     pend_due = (
         (state.nodes.create_time.win < W[:, None]).any()
         | (state.nodes.remove_time.win < W[:, None]).any()
@@ -231,6 +226,10 @@ def _apply_window_events(
         return _apply_window_events_work(st, slab, W, *args)
 
     def skip(st):
+        # No chunk read: the ring's event_chunks column (ring on) records 0.
+        chunks = (
+            jnp.zeros_like(W) if st.telemetry is not None else None
+        )
         if conditional_move:
             C, P = st.pods.phase.shape
             N = (
@@ -247,7 +246,7 @@ def _apply_window_events(
             )
         else:
             wake = None
-        return st._replace(time=jnp.maximum(st.time, W)), wake
+        return st._replace(time=jnp.maximum(st.time, W)), wake, chunks
 
     return jax.lax.cond(_window_work_due(state, slab, W), run, skip, state)
 
@@ -305,11 +304,9 @@ def _apply_window_events_work(
     # major, (C, N) row major. n_sum_ax reduces them to (C,).
     n_shape = (N, C) if lane_major else (C, N)
     n_sum_ax = 0 if lane_major else 1
-    E_total = slab.packed.shape[1]
     E = max_events_per_window
     interval = jnp.float32(consts.scheduling_interval)
-    rows1 = jnp.arange(C, dtype=jnp.int32)
-    rows = rows1[:, None]
+    rows = jnp.arange(C, dtype=jnp.int32)[:, None]
     base = W - 1  # (C,) the window the applied events fall in
     f32inf = jnp.float32(INF)
 
@@ -320,6 +317,10 @@ def _apply_window_events_work(
 
     node_faults = fault_params is not None and fault_params.node_faults
     pod_faults = fault_params is not None and fault_params.fail_prob > 0
+    # Device ring on (a structural static): one more loop carry counts the
+    # chunks each cluster needed; their maximum is the loop's iteration
+    # count (TELEM_EVENT_CHUNKS). Ring off: no leaf, the same program.
+    count_chunks = state.telemetry is not None
 
     # The one-hot scatter kernels sweep whole (P, 128-lane) tiles per event,
     # so like the selection kernel they only pay when the cluster lanes are
@@ -347,12 +348,8 @@ def _apply_window_events_work(
     # that one window instead of taxing every window with a burst-sized
     # gather/scatter. Due events are a sorted prefix of the slab, so a chunk
     # boundary never skips one.
-    def chunk_due(cursor):
-        nxt = slab.packed[rows1, jnp.clip(cursor, 0, E_total - 1), 0]
-        return (cursor < E_total) & (nxt < W)
-
     def chunk_cond(carry):
-        return jnp.any(chunk_due(carry[0]))
+        return jnp.any(slab.win_at(carry[0]) < W)
 
     def chunk_body(carry):
         (cursor, created, node_removal, pod_create, pod_create_seq,
@@ -363,15 +360,16 @@ def _apply_window_events_work(
             tail += 1
         if node_faults:
             crash_rm, n_recover = carry[tail], carry[tail + 1]
-        offs = cursor[:, None] + jnp.arange(E, dtype=jnp.int32)[None, :]
-        offs_c = jnp.clip(offs, 0, E_total - 1)
-        # One packed gather instead of four (gather cost is per-index on TPU).
-        pk = slab.packed[rows, offs_c]  # (C, E, 4) int32
+        # The E entries that follow each cursor, as whole 128-lane blocks:
+        # 2 x C gather indices, not C x E (gather cost is per index on the
+        # TPU; TraceSlab). Past the end the slab reads as sentinel events
+        # (win=INF_WIN), which are never due.
+        pk = slab.read_chunk(cursor, E)  # (C, E, 4) int32
         ev_win = pk[..., 0]
         ev_off = jax.lax.bitcast_convert_type(pk[..., 1], jnp.float32)
         ev_k = pk[..., 2]
         ev_s_raw = pk[..., 3]
-        valid = (offs < E_total) & (ev_win < W[:, None])
+        valid = ev_win < W[:, None]
         # Pod event slots are GLOBAL; the device pod arrays are segmented into
         # a sliding window over plain trace pods (global slot <
         # consts.trace_pod_bound, device slot = global - pod_base) and a
@@ -484,6 +482,9 @@ def _apply_window_events_work(
                 crash_rm,
                 n_recover + is_recover.sum(axis=1, dtype=jnp.int32),
             )
+        if count_chunks:
+            # A cluster needed this chunk iff its first entry was due.
+            out = out + (carry[-1] + valid[:, 0].astype(jnp.int32),)
         return out
 
     def n_scatter_min(acc, mask, ev_s, values):
@@ -508,7 +509,10 @@ def _apply_window_events_work(
             jnp.full(n_shape, INF, jnp.float32),
             jnp.zeros((C,), jnp.int32),
         )
+    if count_chunks:
+        carry0 = carry0 + (jnp.zeros((C,), jnp.int32),)
     carry_out = jax.lax.while_loop(chunk_cond, chunk_body, carry0)
+    event_chunks = carry_out[-1] if count_chunks else None
     (event_cursor, created, node_removal, pod_create, pod_create_seq,
      pod_removal, n_creates) = carry_out[:7]
     tail = 7
@@ -952,7 +956,7 @@ def _apply_window_events_work(
         requeue_signal=state.requeue_signal | any_created_node | any_freed,
         time=jnp.maximum(state.time, W),
     )
-    return new_state, wake_events
+    return new_state, wake_events, event_chunks
 
 
 class WakeEvents(NamedTuple):
@@ -1772,12 +1776,14 @@ def _telemetry_record(
     telem_window=None,
     lane_active=None,
     cycle_sweep=None,
+    event_chunks=None,
 ):
     """Fold one per-window record row into the device telemetry ring:
     metric-counter deltas vs the window's incoming metrics `m0` plus queue
     depths / alive-node counts / reserve-occupancy gauges read straight
-    off the post-window state, and the megakernel's sweep counter
-    (cycle_sweep, (C, 2); zeros without it). Pure bookkeeping — reads
+    off the post-window state, the megakernel's sweep counter
+    (cycle_sweep, (C, 2); zeros without it) and the event loop's chunk
+    count (event_chunks, (C,)). Pure bookkeeping — reads
     simulation state, writes only the ring — so telemetry-on runs are
     bit-identical to telemetry-off on every other leaf
     (tests/test_telemetry.py pins this).
@@ -1864,6 +1870,7 @@ def _telemetry_record(
                 if cycle_sweep is not None
                 else (jnp.zeros_like(W),) * 2
             ),
+            event_chunks if event_chunks is not None else jnp.zeros_like(W),
         ],
         axis=-1,
     ).astype(jnp.int32)
@@ -1974,7 +1981,7 @@ def _window_body(
             auto0, autoscale_statics
         )[1]
 
-    state, wake = _apply_window_events(
+    state, wake, event_chunks = _apply_window_events(
         state,
         slab,
         W,
@@ -2063,6 +2070,7 @@ def _window_body(
                 telem_window=telem_W,
                 lane_active=lane_active,
                 cycle_sweep=cycle_sweep,
+                event_chunks=event_chunks,
             )
         )
     return state
@@ -2254,19 +2262,14 @@ def _next_interesting_window(
     from kubernetriks_tpu.batched.timerep import INF_WIN
 
     pods, nodes = state.pods, state.nodes
-    C = state.time.shape[0]
-    rows1 = jnp.arange(C, dtype=jnp.int32)
     big = jnp.int32(INF_WIN)
-    E_total = slab.packed.shape[1]
 
     def amin(x):
         return jnp.min(x).astype(jnp.int32)
 
-    # Next unapplied trace event (applied when stepping win+1).
-    cursor = jnp.clip(state.event_cursor, 0, E_total - 1)
-    ev_win = slab.packed[rows1, cursor, 0]
-    ev_next = jnp.where(state.event_cursor < E_total, ev_win, big)
-    cand = amin(ev_next) + 1
+    # Next unapplied trace event (applied when stepping win+1); a cursor at
+    # the end reads the slab's sentinel, INF_WIN.
+    cand = amin(slab.win_at(state.event_cursor)) + 1
 
     # Pod finishes (resolved in the finish pair's window or the next; running
     # the earlier window is a harmless no-op when off > 0).

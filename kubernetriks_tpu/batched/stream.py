@@ -507,19 +507,20 @@ class LaneTraceMux:
     spent slab. Mutating an in-flight lane's rows would change history
     the scan carry already consumed.
 
-    Host-only: the mux owns a host copy of the packed slab and returns
-    host row blocks; the ENGINE owns the device install
+    Host-only: the mux owns a host copy of the slab's real event rows
+    ((C, E, 4), TraceSlab.rows() less the sentinel tail) and returns host
+    row blocks; the ENGINE blocks them and owns the device install
     (`engine.set_lane_trace`, a data-only dynamic_update_slice at the
     reseed host-block boundary — zero new steady-state syncs).
     """
 
-    def __init__(self, packed) -> None:
+    def __init__(self, rows) -> None:
         import numpy as np
 
-        base = np.array(packed, np.int32)  # ktpu: sync-ok(mux construction: one owned host copy of the freshly built slab, never on the steady-state path)
+        base = np.array(rows, np.int32)  # ktpu: sync-ok(mux construction: one owned host copy of the freshly built slab, never on the steady-state path)
         if base.ndim != 3 or base.shape[-1] != 4:
             raise ValueError(
-                f"LaneTraceMux wants a (C, E, 4) packed slab, got {base.shape}"
+                f"LaneTraceMux wants (C, E, 4) event rows, got {base.shape}"
             )
         self._base = base
         C = base.shape[0]

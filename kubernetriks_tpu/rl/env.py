@@ -258,7 +258,7 @@ def rollout(
         st, rng = carry
         rng, sub = jax.random.split(rng)
         w_arr = jnp.broadcast_to(jnp.asarray(w, jnp.int32), st.time.shape)
-        st, wake = _apply_window_events(
+        st, wake, _ = _apply_window_events(
             st, slab, w_arr, consts, max_events_per_window, conditional_move,
             node_name_rank=(
                 autoscale_statics.node_name_rank

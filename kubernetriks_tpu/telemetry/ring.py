@@ -61,6 +61,9 @@ RING_COLUMNS = (
     # is the ratio of their totals.
     "cycle_tiles_swept",
     "cycle_tile_steps",
+    # The event loop's chunks a cluster (state.TELEM_EVENT_CHUNKS); a
+    # window's maximum over clusters is the slab reads it paid.
+    "event_chunks",
 )
 assert len(RING_COLUMNS) == TELEMETRY_COLS
 

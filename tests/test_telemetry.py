@@ -907,3 +907,46 @@ def test_device_ring_sharded_matches_unsharded_every_leaf():
     assert sharded.state.telemetry is not None
     a, b = unsharded.telemetry_report()["ring"], sharded.telemetry_report()["ring"]
     assert a == b and a["windows_recorded"] > 0 and a["totals"]["decisions"] > 0
+
+
+@pytest.mark.parametrize("chunk", [3, None])
+def test_event_chunks_column_counts_the_event_loops_reads(chunk):
+    """Ring on: a cluster's `event_chunks` in window W is ceil(due / E) for
+    the events due in it (counted on the host from the trace), and the
+    report's `event_chunks_per_window` the mean over windows of the
+    clusters' maximum, which is the iterations the event loop ran (the slab
+    reads the window paid). E = 3 makes most windows take several chunks;
+    the default E takes at most one. Ring off: no leaf and no count (the
+    loop's carry is the parent's), and the same final state."""
+    import jax
+
+    from kubernetriks_tpu.batched import step
+
+    kwargs = {} if chunk is None else {"max_events_per_window": chunk}
+    on = _build_plain(telemetry=True, **kwargs)
+    off = _build_plain(**kwargs)
+    for sim in (on, off):
+        sim.step_until_time(ENDS[-1])
+    E = on.max_events_per_window
+    assert E == (chunk or E)
+    wins, data = on.telemetry_window_series()
+    got = data[:, :, RING_COLUMNS.index("event_chunks")]  # (windows, clusters)
+    t = on._ev_time_np
+    ev_win = np.where(np.isfinite(t), np.floor(t / on.config.scheduling_cycle_interval), -2)
+    # consecutive stepping: window W applies exactly the events of window W - 1
+    due = (ev_win[None, :, :] == (wins[:, None, None] - 1)).sum(axis=2)
+    np.testing.assert_array_equal(got, -(-due // E))
+    assert got.max() == (1 if chunk is None else -(-due.max() // chunk)) and got.max() >= 1
+    per_window = on.telemetry_report()["ring"]["event_chunks_per_window"]
+    assert per_window == pytest.approx(float((-(-due // E)).max(axis=1).mean()))
+    assert compare_states(strip_telemetry(on.state), off.state) == []
+
+    def chunks_of(sim):
+        W = jax.numpy.ones((sim.n_clusters,), jax.numpy.int32)
+        return jax.eval_shape(
+            lambda state: step._apply_window_events(state, sim.slab, W, sim.consts, E)[2],
+            sim.state,
+        )
+
+    assert chunks_of(off) is None
+    assert chunks_of(on).shape == (on.n_clusters,)
