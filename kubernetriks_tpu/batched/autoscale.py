@@ -30,8 +30,8 @@ pins sample-for-sample trajectory equality, incl. conditional-move churn):
   + as_to_ps; effects compose from the fire time.
 - The decision reads the storage's view at s_k exactly: pre-cycle shadows
   when s_k precedes this window's commit visibility (ca_pass `pre`), and
-  finish-visibility reconstruction on both sides of the window boundary
-  (_ca_scale_down vis_gone/vis_back).
+  finish visibility read off the pending-free channel, whose holders the
+  storage hears of one hop before the scheduler (_ca_scale_down vis_gone).
 - Scale-down walks candidates and first-fits re-placements in NODE-NAME
   order (info.nodes is name-sorted); scale-up bin-packs the cache in
   POD-NAME order (scale_up_info sorts names) via the static name ranks.
@@ -89,6 +89,7 @@ from kubernetriks_tpu.batched.state import (
     PHASE_SUCCEEDED,
     PHASE_UNSCHEDULABLE,
     StepConstants,
+    held_frees,
     swap_node_layout,
 )
 from kubernetriks_tpu.batched.timerep import (
@@ -784,6 +785,10 @@ def _hpa_pass_body(
         | (pods.phase == PHASE_REMOVED)
         | (pods.phase == PHASE_FAILED)
     )
+    if consts.delta_free_visible is not None:
+        # A removed replica's slot is not reused while its free is still
+        # on the pending-free channel (the new occupant would wipe it).
+        reusable = reusable & ~held_frees(pods)
     reuse_in_g = in_group & reusable
     n_reusable = (
         jnp.zeros((C, Gp + 1), jnp.int32)
@@ -1129,10 +1134,15 @@ def _ca_scale_down(
 
     phase_v/alloc_*_v are the storage-visible views from ca_pass; on top of
     them the finish-visibility correction reconstructs what the storage
-    knows at the snapshot time `snap`: a running pod whose finish became
-    visible by snap counts as gone (its resources freed), and a
-    just-succeeded pod whose finish is NOT yet visible still counts as
-    running (its resources held, and it still needs re-placement).
+    knows at the snapshot time `snap`. The allocatable is the scheduler's
+    and holds the requests of every running pod and of every pod on the
+    pending-free channel (state.held_frees: off its node, the news still on
+    its way to the scheduler). The storage lies one hop short of the
+    scheduler on that way, so it is the channel's second reader, with its
+    own chain: a holder whose finish reached the storage by snap
+    (finish_time + ca_finish_vis) counts as gone, its resources freed; one
+    whose finish has not still counts as running and still needs
+    re-placement, whether the node has finished it or not.
 
     descatter (KTPU_CA_DESCATTER, r9 — round 3 of the de-scatter
     campaign): the correction segment-sum and the node-grouping sort above
@@ -1170,22 +1180,21 @@ def _ca_scale_down(
     finish_vis = TPair(
         win=st.ca_finish_vis.win[:, None], off=st.ca_finish_vis.off[:, None]
     )
-    # Running pod whose finish notification reached storage by snap: gone.
-    vis_gone = (phase_v == PHASE_RUNNING) & t_le(
+    # The allocatable's holders: running (at the snapshot's side of the
+    # cycle) or on the pending-free channel (the cycle moves no pod on or
+    # off it).
+    held = held_frees(pods)
+    holds = (phase_v == PHASE_RUNNING) | held
+    # Holder whose finish notification reached storage by snap: gone.
+    vis_gone = holds & t_le(
         t_add(pods.finish_time, finish_vis, interval), snap_p
     )
-    # Succeeded pod the storage hasn't seen finish yet: still running there.
-    # (finish = start + duration; service pods never reach SUCCEEDED.)
-    succ_finish = t_add(
-        t_add(pods.start_time, pods.duration, interval),
-        finish_vis,
-        interval,
-    )
-    vis_back = (phase_v == PHASE_SUCCEEDED) & ~t_le(succ_finish, snap_p)
-    # HPA removals whose storage effect landed by snap: gone (removal_time
-    # is already a storage-effect time, d_hpa_down).
+    # Removals whose storage effect landed by snap: gone. An HPA removal
+    # still pending is read from removal_time (already a storage-effect
+    # time, d_hpa_down); a removed pod on the channel left the storage
+    # before it left its node.
     vis_removed = (phase_v == PHASE_RUNNING) & t_le(pods.removal_time, snap_p)
-    vis_gone = vis_gone | vis_removed
+    vis_gone = vis_gone | vis_removed | (held & (pods.phase == PHASE_REMOVED))
 
     # Virtual allocatables as the storage sees them. The per-node
     # correction sums are SEGMENT SUMS over a node-sorted copy of the
@@ -1194,20 +1203,16 @@ def _ca_scale_down(
     # (xplane-measured ~1.1 ms/window at the composed shape; this
     # formulation is ~0.3). Integer adds, so any summation order is exact.
     node_c = jnp.clip(pods.node, 0, N - 1)
-    d_cpu = jnp.where(vis_gone, pods.req_cpu, 0) - jnp.where(
-        vis_back, pods.req_cpu, 0
-    )
-    d_ram = jnp.where(vis_gone, pods.req_ram, 0) - jnp.where(
-        vis_back, pods.req_ram, 0
-    )
-    touched = vis_gone | vis_back
-    on_any = ((phase_v == PHASE_RUNNING) & ~vis_gone) | vis_back
+    d_cpu = jnp.where(vis_gone, pods.req_cpu, 0)
+    d_ram = jnp.where(vis_gone, pods.req_ram, 0)
+    touched = vis_gone
+    on_any = holds & ~vis_gone
     zero_col = jnp.zeros((C, 1), jnp.int32)
     if descatter:
         # Combined de-scatter (see docstring): one 2-key sort — node slot,
         # then storage-running FIRST — serves the correction AND the
-        # grouping. on_any pods have node >= 0 and touched pods are
-        # RUNNING-phase, so node_c == the old sorts' key values.
+        # grouping. on_any and touched pods are holders, which have
+        # node >= 0, so node_c == the old sorts' key values.
         in_seg = touched | on_any
         key_node = jnp.where(in_seg, node_c, jnp.int32(N))
         key2 = jnp.where(on_any, 0, 1).astype(jnp.int32)
@@ -1695,13 +1700,11 @@ def ca_reclaim_pass(
 
     A slot is retired when its node's removal has fully drained:
     - the node is dead with no pending create/remove effect, and
-    - no pod still binds it as RUNNING, and no SUCCEEDED pod's finish
-      visibility is still in flight (a future CA cycle's storage snapshot
-      lands at or after this window's start, so a finish visible by
-      (W, 0) can never be resurrected by the scale-down's vis_back
-      reconstruction; terminal pods past that horizon contribute nothing
-      to any later pass and their stale slot pointers are remapped along
-      with the move).
+    - no pod still binds it as RUNNING or from the pending-free channel
+      (state.held_frees: its free is still owed to the slot, and a storage
+      snapshot may still count the pod on it; pods past the channel
+      contribute nothing to any later pass and their stale slot pointers
+      are remapped along with the move).
 
     Compaction is STABLE per group (keepers pack to the group's reserve
     prefix in slot order), which preserves the two orderings exactness
@@ -1730,7 +1733,6 @@ def ca_reclaim_pass(
     alive_row = nodes.alive.T if nodes_lane_major else nodes.alive
     N = alive_row.shape[1]
     n_trace = N - S
-    interval = jnp.float32(consts.scheduling_interval)
     slots = st.ca_slots
     slotc = jnp.clip(slots, 0, N - 1)
     occupied = auto.ca_alloc >= 0
@@ -1770,26 +1772,12 @@ def ca_reclaim_pass(
         capc_r = nodes.cap_cpu.T if nodes_lane_major else nodes.cap_cpu
         capr_r = nodes.cap_ram.T if nodes_lane_major else nodes.cap_ram
 
-        # Retirement safety: pods still binding the node. RUNNING blocks
-        # outright; a SUCCEEDED pod blocks until its finish visibility
-        # (finish + ca_finish_vis) reaches this window's start — after
-        # that no future storage snapshot can reconstruct it (vis_back).
-        Tp = TPair(
-            win=jnp.broadcast_to(W[:, None], (C, P)),
-            off=jnp.zeros((C, P), jnp.float32),
-        )
-        finish_vis = TPair(
-            win=st.ca_finish_vis.win[:, None],
-            off=st.ca_finish_vis.off[:, None],
-        )
-        succ_vis = t_add(
-            t_add(pods.start_time, pods.duration, interval),
-            finish_vis,
-            interval,
-        )
+        # Retirement safety: pods still binding the node, which are the
+        # allocatable's holders: RUNNING, or on the pending-free channel
+        # (a free still owed to the slot, and a pod a storage snapshot
+        # may still count on it, _ca_scale_down).
         blocking = (
-            (pods.phase == PHASE_RUNNING)
-            | ((pods.phase == PHASE_SUCCEEDED) & ~t_le(succ_vis, Tp))
+            (pods.phase == PHASE_RUNNING) | held_frees(pods)
         ) & (pods.node >= 0)
         tgt_b = jnp.where(blocking, pods.node, N)
         node_blocked = (

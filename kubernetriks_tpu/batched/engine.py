@@ -55,6 +55,7 @@ from kubernetriks_tpu.batched.state import (
     TraceSlab,
     init_state,
     make_step_constants,
+    slide_phase,
     swap_node_layout,
     tree_copy,
 )
@@ -245,7 +246,8 @@ def _fused_chunk_slide_impl(
         state = swap_node_layout(state)
     base = jnp.asarray(base, jnp.int32)
     s0 = _slide_shift_core(
-        state.pods.phase[:, :W], payload["create_win"], base, shard_axis
+        slide_phase(state.pods, consts)[:, :W], payload["create_win"], base,
+        shard_axis,
     )
     s = _quantize_shift_device(s0, W)
     rank = (
@@ -3134,7 +3136,7 @@ class BatchedSimulation:
             ):
                 s = int(  # ktpu: sync-ok(blocking 4-byte shift readback gating the slide decision on the two-dispatch path; the steady-state loop fuses this away)
                     _slide_shift_device(
-                        self.state.pods.phase[:, :W],
+                        slide_phase(self.state.pods, self.consts)[:, :W],
                         self._device_slide["create_win"],
                         jnp.asarray(win_lo, jnp.int32),
                     )
@@ -3144,7 +3146,7 @@ class BatchedSimulation:
             with sanitize.allow_transfer(
                 self._sanitize, "host slide path phase fetch"
             ):
-                phases = to_host(self.state.pods.phase)[:, :W]  # ktpu: sync-ok(host slide path: blocking (C, W) phase fetch — the round-trip the device-resident payload eliminates)
+                phases = to_host(slide_phase(self.state.pods, self.consts))[:, :W]  # ktpu: sync-ok(host slide path: blocking (C, W) phase fetch — the round-trip the device-resident payload eliminates)
             terminal = (
                 (phases == PHASE_SUCCEEDED)
                 | (phases == PHASE_REMOVED)
@@ -3663,6 +3665,14 @@ class BatchedSimulation:
 
         self.check_autoscaler_bounds()
         m = jax.tree.map(to_host, self.state.metrics)
+        # Counters with no scalar counterpart go to the recorder and not
+        # into the summary: the pending-free channel's two, and the windows
+        # whose event application ran. The state's totals as they stand,
+        # not a growth (a reset state reads 0).
+        counters = recorder().counters
+        counters["frees_total"] = int(np.asarray(m.frees_total).sum())
+        counters["frees_deferred"] = int(np.asarray(m.frees_deferred).sum())
+        counters["event_windows"] = int(np.asarray(m.event_windows).max())
 
         def est(e):
             count = np.asarray(e.count, np.int64)

@@ -107,7 +107,12 @@ class PodArrays(NamedTuple):
     attempts: jnp.ndarray  # int32
     node: jnp.ndarray  # int32 node slot, -1 = none
     start_time: TPair
-    finish_time: TPair  # +inf = no pending finish
+    # Finite while the SCHEDULER's allocatable still holds the pod's
+    # requests: a running pod's finish, and after it the NODE-side time of
+    # a free still on its way to the scheduler's cache (the pending-free
+    # channel, step._apply_window_events_work; never outlives the window of
+    # the finish when the control-plane delays are zero). +inf otherwise.
+    finish_time: TPair
     removal_time: TPair  # pending HPA scale-down effect; +inf = none
     # HPA replica index of the slot's CURRENT occupant ("{group}_{idx}"
     # names; -1 = not an HPA replica). Set at activation; the scale-down
@@ -120,6 +125,33 @@ class PodArrays(NamedTuple):
     # when fault injection is off.
     restarts: jnp.ndarray  # int32
     will_fail: jnp.ndarray  # bool
+
+
+def held_frees(pods: PodArrays) -> jnp.ndarray:
+    """(C, P) the pods ON the pending-free channel: off their node (finished,
+    failed or removed there) with their requests still in the scheduler's
+    allocatable, because the news has not reached its cache by the last
+    cycle. Their finish_time is the node-side time the free set out at.
+    Empty by construction where StepConstants.delta_free_visible is None."""
+    return (pods.phase != PHASE_RUNNING) & (pods.finish_time.win < INF_WIN)
+
+
+def alloc_holders(pods: PodArrays, consts: "StepConstants") -> jnp.ndarray:
+    """(C, P) the pods whose finish_time still owes the window body work:
+    the running ones and, where the build has the channel, those on it."""
+    running = pods.phase == PHASE_RUNNING
+    if consts.delta_free_visible is None:
+        return running
+    return running | held_frees(pods)
+
+
+def slide_phase(pods: PodArrays, consts: "StepConstants") -> jnp.ndarray:
+    """The phases the pod window's slide may read: a pod on the pending-free
+    channel counts as RUNNING, so that no slide moves it out of the window
+    with a free still owed to its node."""
+    if consts.delta_free_visible is None:
+        return pods.phase
+    return jnp.where(held_frees(pods), PHASE_RUNNING, pods.phase)
 
 
 class EstArrays(NamedTuple):
@@ -186,6 +218,19 @@ class MetricArrays(NamedTuple):
     pod_interruptions: jnp.ndarray  # int32
     pod_restarts: jnp.ndarray  # int32
     pods_failed: jnp.ndarray  # int32
+    # The pending-free channel's two counters (step._apply_window_events_work;
+    # no scalar counterpart): the frees that left a node (a finish, a failed
+    # attempt, the removal of a running pod), and those of them that missed
+    # the next cycle, because the news was still on its way to the scheduler.
+    frees_total: jnp.ndarray  # int32
+    frees_deferred: jnp.ndarray  # int32
+    # Windows in which the cluster's event application was due
+    # (step._window_work_due). Under the window razor the free kernel and
+    # the event scatter kernel launch in the windows in which ANY cluster's
+    # was, so the largest count of a batch is a lower bound of their
+    # launches, and the launch count itself where the clusters carry like
+    # loads.
+    event_windows: jnp.ndarray  # int32
     queue_time: EstArrays
     algo_latency: EstArrays
     pod_duration: EstArrays
@@ -267,7 +312,11 @@ TELEM_CYCLE_TILE_STEPS = 13
 # telemetry_report()'s event_chunks_per_window is the mean of those maxima.
 # 0 on a window the razor skipped.
 TELEM_EVENT_CHUNKS = 14
-TELEMETRY_COLS = 15
+# Frees the pending-free channel deferred this window (the growth of
+# MetricArrays.frees_deferred): 0 on every window of a build whose
+# control-plane delays are zero.
+TELEM_FREES_DEFERRED = 15
+TELEMETRY_COLS = 16
 
 
 class TelemetryRing(NamedTuple):
@@ -469,11 +518,30 @@ class StepConstants(NamedTuple):
     # (the default) keeps programs identical to the wave-aligned build.
     lane_clock: Optional[jnp.ndarray] = None  # (C,) int32 global start window
     lane_horizon: Optional[jnp.ndarray] = None  # (C,) int32 windows to run
+    # The pending-free channel's two chains (step._apply_window_events_work).
+    # A pod's requests leave its node at the node-side finish (or cancel)
+    # and reach the scheduler's cache delta_free_visible later: node -> api
+    # server -> storage -> scheduler. A removal the storage applied reaches
+    # the node delta_free_unbind after the storage's drop: storage -> api
+    # server -> node. None where the three delays they are made of are all
+    # zero: no free can then be deferred, and the None compiles the window
+    # programs of a build without the channel (the structural-static idiom
+    # of fault_seed and lane_clock; both None or neither).
+    delta_free_visible: Optional[float] = None
+    delta_free_unbind: Optional[float] = None
 
 
 def make_step_constants(config) -> StepConstants:
     """Compose effective delays from the six config delays, mirroring the event
     chains of the scalar path (SURVEY.md §3.2: eleven hops pod lifecycle)."""
+    # What a node reports (a finished pod, its own removal) reaches the
+    # scheduler through api server and storage.
+    node_to_sched = (
+        config.as_to_node_network_delay
+        + config.as_to_ps_network_delay
+        + config.ps_to_sched_network_delay
+    )
+    channel = node_to_sched > 0
     return StepConstants(
         scheduling_interval=config.scheduling_cycle_interval,
         time_per_node=1e-6,
@@ -482,13 +550,16 @@ def make_step_constants(config) -> StepConstants:
         delta_bind_start=config.sched_to_as_network_delay
         + 2.0 * config.as_to_ps_network_delay
         + config.as_to_node_network_delay,
-        # Relative to the (already-shifted) node-removal effect time: the
-        # NodeRemovedFromCluster -> api server -> storage -> scheduler chain.
-        delta_reschedule=config.as_to_node_network_delay
-        + config.as_to_ps_network_delay
-        + config.ps_to_sched_network_delay,
+        # Relative to the (already-shifted) node-removal effect time.
+        delta_reschedule=node_to_sched,
         flush_interval=30.0,
         max_unschedulable_stay=300.0,
+        delta_free_visible=node_to_sched if channel else None,
+        delta_free_unbind=(
+            config.as_to_ps_network_delay + config.as_to_node_network_delay
+            if channel
+            else None
+        ),
     )
 
 
@@ -585,6 +656,9 @@ def init_state(
         pod_interruptions=jnp.zeros((C,), jnp.int32),
         pod_restarts=jnp.zeros((C,), jnp.int32),
         pods_failed=jnp.zeros((C,), jnp.int32),
+        frees_total=jnp.zeros((C,), jnp.int32),
+        frees_deferred=jnp.zeros((C,), jnp.int32),
+        event_windows=jnp.zeros((C,), jnp.int32),
         queue_time=EstArrays.zeros((C,)),
         algo_latency=EstArrays.zeros((C,)),
         pod_duration=EstArrays.zeros((C,)),
@@ -700,6 +774,8 @@ STEP_CONSTANTS_LEAVES = (
     "fault_seed",
     "lane_clock",
     "lane_horizon",
+    "delta_free_visible",
+    "delta_free_unbind",
 )
 
 # Declared axis signatures of state leaves (the shapecontract lint pass):
@@ -762,6 +838,9 @@ AXIS_SIGNATURES = {
     "pod_interruptions": "C",
     "pod_restarts": "C",
     "pods_failed": "C",
+    "frees_total": "C",
+    "frees_deferred": "C",
+    "event_windows": "C",
 }
 
 

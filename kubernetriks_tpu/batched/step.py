@@ -8,6 +8,9 @@ one event at a time) with array programs:
   (SURVEY.md §5.8); the compiler pre-shifts event times to their effect times.
 - Pod completions are precomputed finish times invalidated by masks (replacing
   DSLab cancel_event, reference: src/core/node_component.rs:102-104).
+- The allocatable is the SCHEDULER's cache: a pod that left its node stays on
+  the pending-free channel until the news has crossed the control plane
+  (_apply_window_events_work; no channel where the delays are zero).
 - Event application is BULK: the window's slab segment is read once per
   cluster as whole blocks (state.TraceSlab.read_chunk), node/pod removal
   times become scatter-min arrays, and the finish-vs-removal interleaving is resolved elementwise per pod by comparing
@@ -64,6 +67,9 @@ from kubernetriks_tpu.batched.state import (
     NODE_HOT_LEAVES,
     StepConstants,
     TraceSlab,
+    alloc_holders,
+    held_frees,
+    slide_phase,
     swap_node_layout,
 )
 from kubernetriks_tpu.batched.sharding import (
@@ -140,10 +146,15 @@ def _stable_queue_rank(keys) -> jnp.ndarray:
 
 
 def _window_work_due(
-    state: ClusterBatchState, slab: TraceSlab, W: jnp.ndarray
+    state: ClusterBatchState,
+    slab: TraceSlab,
+    W: jnp.ndarray,
+    consts: StepConstants,
 ) -> jnp.ndarray:
-    """Scalar bool: could _apply_window_events_work change ANY state leaf at
-    window W? The window-cost razor's due-ness predicate — a handful of
+    """(C,) bool: could _apply_window_events_work change ANY state leaf of
+    the cluster at window W? Any cluster's is the window-cost razor's
+    due-ness predicate (each cluster's own is counted in
+    MetricArrays.event_windows) — a handful of
     cheap compares + reductions against the ~35 masked elementwise passes
     of the resolution soup. CONSERVATIVE by construction (true whenever any
     trigger below could fire; running the soup needlessly is always exact):
@@ -154,7 +165,10 @@ def _window_work_due(
       phase refinements — supersets, so never missed);
     - a running pod's finish due by the window end. With none of the other
       triggers firing, every interrupt source is +inf, so the soup's cutoff
-      is exactly the window-end pair this predicate compares against.
+      is exactly the window-end pair this predicate compares against;
+    - a free on the pending-free channel (its node-side time is behind the
+      window end by construction, so the same compare catches it once the
+      phase no longer masks it).
 
     When false, the soup is the identity on everything except
     time = max(time, W) (metric folds add masked zeros, estimator min/max
@@ -162,11 +176,11 @@ def _window_work_due(
     branch replicates exactly that. Layout-agnostic: only row-major leaves
     (pending pairs, pod arrays) and the slab are read."""
     C = state.time.shape[0]
-    ev_due = (slab.win_at(state.event_cursor) < W).any()
+    ev_due = slab.win_at(state.event_cursor) < W
     pend_due = (
-        (state.nodes.create_time.win < W[:, None]).any()
-        | (state.nodes.remove_time.win < W[:, None]).any()
-        | (state.pods.removal_time.win < W[:, None]).any()
+        (state.nodes.create_time.win < W[:, None]).any(axis=1)
+        | (state.nodes.remove_time.win < W[:, None]).any(axis=1)
+        | (state.pods.removal_time.win < W[:, None]).any(axis=1)
     )
     P = state.pods.phase.shape[1]
     window_end = TPair(
@@ -174,9 +188,9 @@ def _window_work_due(
         off=jnp.zeros((C, P), jnp.float32),
     )
     fin_due = (
-        (state.pods.phase == PHASE_RUNNING)
+        alloc_holders(state.pods, consts)
         & t_le(state.pods.finish_time, window_end)
-    ).any()
+    ).any(axis=1)
     return ev_due | pend_due | fin_due
 
 
@@ -219,11 +233,20 @@ def _apply_window_events(
         lane_major,
         node_key_fn,
     )
-    if not window_razor:
-        return _apply_window_events_work(state, slab, W, *args)
+    due = _window_work_due(state, slab, W, consts)
 
     def run(st):
-        return _apply_window_events_work(st, slab, W, *args)
+        """The soup, and the window counted for the clusters it was due in
+        (a function of the state alone, so the razor's on and off builds
+        and a sharded build count alike)."""
+        st, wake, chunks = _apply_window_events_work(st, slab, W, *args)
+        metrics = st.metrics._replace(
+            event_windows=st.metrics.event_windows + due.astype(jnp.int32)
+        )
+        return st._replace(metrics=metrics), wake, chunks
+
+    if not window_razor:
+        return run(state)
 
     def skip(st):
         # No chunk read: the ring's event_chunks column (ring on) records 0.
@@ -248,7 +271,7 @@ def _apply_window_events(
             wake = None
         return st._replace(time=jnp.maximum(st.time, W)), wake, chunks
 
-    return jax.lax.cond(_window_work_due(state, slab, W), run, skip, state)
+    return jax.lax.cond(due.any(), run, skip, state)
 
 
 def _apply_window_events_work(
@@ -672,6 +695,51 @@ def _apply_window_events_work(
     # chunks — correct everywhere, but each round's lax.top_k lowers to a
     # full (C, P) sort on TPU (~4 ms/window at dense shapes).
     freed = finishes | removed_running
+    # The pending-free channel. `freed` is the NODE's side: the pods that
+    # left their nodes this window. The allocatable below is the
+    # SCHEDULER's cache, which hears of a free one chain later (scalar:
+    # node -> api server -> storage -> scheduler.on_pod_finished_running /
+    # on_remove_pod_from_cache, where the requests return and the
+    # unschedulable queue wakes). So a free joins the channel at its
+    # node-side time (pods.finish_time stays finite, state.held_frees) and
+    # leaves it in the window whose cycle is the first to see it: visible
+    # iff finish_time + chain lies in a window before W. Strictly: the
+    # cycle's own event was emitted a whole interval ago, the
+    # notification's by the storage a hop ago, and the scalar queue runs
+    # events of one instant in emission order, so a free that arrives AT
+    # the cycle instant is the next cycle's. A removal's node-side time is
+    # the node's cancel, delta_free_unbind after the storage's drop that
+    # `pod_removal` is. Node-side effects (phases, counters, the duration
+    # estimator, finish against removal) keep the node's time, as before.
+    channel = consts.delta_free_visible is not None
+    if channel:
+        held = held_frees(pods)
+        free_t = t_where(
+            removed_running,
+            t_norm(
+                jnp.broadcast_to(base[:, None], (C, P)),
+                jnp.where(removed_running, pod_removal, 0.0)
+                + jnp.float32(consts.delta_free_unbind),
+                interval,
+            ),
+            pods.finish_time,
+        )
+        free_vis = t_norm(
+            free_t.win,
+            free_t.off + jnp.float32(consts.delta_free_visible),
+            interval,
+        )
+        visible = (freed | held) & (free_vis.win < W[:, None])
+        deferred = (freed | held) & ~visible
+        # The free kernel visits the rows of its first mask, adds a visited
+        # row's requests to the node it names and folds the row's sample
+        # where the finish bit is set: one launch visits the visible frees
+        # and this window's finishes, a deferred finish naming no node.
+        kernel_rows = visible | real_fin
+        kernel_node = jnp.where(visible, pods.node, -1)
+    else:
+        visible = kernel_rows = freed
+        kernel_node = pods.node
     from kubernetriks_tpu.ops.scheduler_kernel import (
         free_kernel_fits,
         fused_free_resources,
@@ -689,7 +757,7 @@ def _apply_window_events_work(
         # samples (count/total/total_sq/min/max), replacing the five
         # (C, P) masked reductions below.
         alloc_cpu, alloc_ram, dur_stats = core(
-            freed, pods.node, pods.req_cpu, pods.req_ram,
+            kernel_rows, kernel_node, pods.req_cpu, pods.req_ram,
             real_fin, duration_s, alloc_cpu, alloc_ram,
         )
     else:
@@ -715,7 +783,7 @@ def _apply_window_events_work(
             return (pending, acpu, aram)
 
         _, alloc_cpu, alloc_ram = jax.lax.while_loop(
-            free_cond, free_body, (freed, alloc_cpu, alloc_ram)
+            free_cond, free_body, (visible, alloc_cpu, alloc_ram)
         )
 
     # Finished pods.
@@ -859,7 +927,19 @@ def _apply_window_events_work(
         )
         initial_attempt_ts = t_where(retry, retry_ts, initial_attempt_ts)
         attempts = jnp.where(retry, 1, attempts)
-        pod_node = jnp.where(fails, -1, pod_node)
+        if channel:
+            # A failed attempt leaves its node when its free does: the
+            # channel reads the node until then. (The retry enters the
+            # queue no earlier than the free is visible, so no cycle can
+            # bind it before.)
+            off_node = visible & (
+                fails
+                | (held & (pods.phase != PHASE_SUCCEEDED)
+                   & (pods.phase != PHASE_REMOVED))
+            )
+        else:
+            off_node = fails
+        pod_node = jnp.where(off_node, -1, pod_node)
         restarts_arr = jnp.where(fails, new_restarts, pods.restarts)
         will_fail_arr = jnp.where(fails, False, pods.will_fail)
         n_fail_retries = retry.sum(axis=1, dtype=jnp.int32)
@@ -879,6 +959,12 @@ def _apply_window_events_work(
     )
     phase = jnp.where(removed_running, PHASE_REMOVED, phase)
     finish_time = t_where(removed_running, t_inf((C, P)), finish_time)
+    if channel:
+        finish_time = t_where(
+            deferred,
+            free_t,
+            t_where(visible, t_inf((C, P)), finish_time),
+        )
 
     # Removal of queued/unschedulable (or just-created) pods: dropped from the
     # queues with NO removed/terminated metrics (scalar parity: only
@@ -895,11 +981,20 @@ def _apply_window_events_work(
     alive = alive & ~(node_removal < f32inf)
 
     any_created_node = created.any(axis=n_sum_ax)
-    any_freed = (n_done > 0) | (n_removed_running > 0)
+    n_freed = n_done + n_removed_running
     if pod_faults:
         # Failing attempts free their resources too (scalar: the failure
         # handler wakes the unschedulable queue like a finish).
-        any_freed = any_freed | fails.any(axis=1)
+        n_freed = n_freed + n_fail_retries + n_perma
+    metrics = metrics._replace(frees_total=metrics.frees_total + n_freed)
+    if channel:
+        any_freed = visible.any(axis=1)
+        metrics = metrics._replace(
+            frees_deferred=metrics.frees_deferred
+            + (freed & deferred).sum(axis=1, dtype=jnp.int32)
+        )
+    else:
+        any_freed = n_freed > 0
 
     # Conditional-move wake events (consumed by prepare_cycle's per-event
     # wake scans when enable_unscheduled_pods_conditional_move is on;
@@ -909,18 +1004,24 @@ def _apply_window_events_work(
     # (scheduler.rs:366-380). Only built on the conditional-move path.
     if conditional_move:
         node_rel = jnp.where(created, node_create_rel, f32inf)
+        if channel:  # a free wakes the queue when the scheduler hears of it
+            freed_rel = jnp.where(
+                visible, _rel_seconds(free_vis, base[:, None], interval), f32inf
+            )
+        else:
+            freed_rel = jnp.where(
+                finishes,
+                _rel_seconds(pods.finish_time, base[:, None], interval),
+                jnp.where(removed_running, pod_removal, f32inf),
+            )
         wake_events = WakeEvents(
             # WakeEvents is row-major by contract (its consumer concatenates
             # the node and pod axes); transpose the lane-major accumulators
             # once here — conditional-move runs only.
             node_mask=created.T if lane_major else created,
             node_rel=node_rel.T if lane_major else node_rel,
-            freed_mask=freed,
-            freed_rel=jnp.where(
-                finishes,
-                _rel_seconds(pods.finish_time, base[:, None], interval),
-                jnp.where(removed_running, pod_removal, f32inf),
-            ),
+            freed_mask=visible,
+            freed_rel=freed_rel,
         )
     else:
         wake_events = None
@@ -1871,6 +1972,7 @@ def _telemetry_record(
                 else (jnp.zeros_like(W),) * 2
             ),
             event_chunks if event_chunks is not None else jnp.zeros_like(W),
+            m1.frees_deferred - m0.frees_deferred,
         ],
         axis=-1,
     ).astype(jnp.int32)
@@ -2273,8 +2375,10 @@ def _next_interesting_window(
 
     # Pod finishes (resolved in the finish pair's window or the next; running
     # the earlier window is a harmless no-op when off > 0).
-    running = pods.phase == PHASE_RUNNING
-    cand = jnp.minimum(cand, amin(jnp.where(running, pods.finish_time.win, big)))
+    # A free on the pending-free channel has its node-side time behind W,
+    # so no window is skipped while one is held.
+    holds = alloc_holders(pods, consts)
+    cand = jnp.minimum(cand, amin(jnp.where(holds, pods.finish_time.win, big)))
 
     # Pending effect times (applied when stepping win+1): CA node
     # creations/removals, HPA pod removals.
@@ -2924,7 +3028,8 @@ def _run_superspan_impl(
         def slide_branch(op):
             state, rank, w, spans = op
             s0 = _slide_shift_core(
-                state.pods.phase[:, :W], stage.create_win, base - stage_lo,
+                slide_phase(state.pods, consts)[:, :W], stage.create_win,
+                base - stage_lo,
                 shard_axis,
             )
             s = _quantize_shift_device(s0, W)

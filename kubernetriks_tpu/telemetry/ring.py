@@ -64,6 +64,9 @@ RING_COLUMNS = (
     # The event loop's chunks a cluster (state.TELEM_EVENT_CHUNKS); a
     # window's maximum over clusters is the slab reads it paid.
     "event_chunks",
+    # Frees the pending-free channel carried past the window's cycle
+    # (state.TELEM_FREES_DEFERRED).
+    "frees_deferred",
 )
 assert len(RING_COLUMNS) == TELEMETRY_COLS
 

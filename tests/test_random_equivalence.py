@@ -119,13 +119,14 @@ END_TIME = 12000.0  # past last event + max duration + stale flush + slack
 # batched/pipeline.py): the SAME generated traces run under non-default
 # profiles on both paths — the scalar KubeScheduler interprets the profile
 # through the plugin registry, the batched engine compiles it into the
-# scan path — and must still agree pod-for-pod. Seeds are pinned to runs
-# whose pod finishes keep clear of the freed-resource visibility gap
-# (docs/PARITY.md "Freed-resource visibility at cycle boundaries"):
-# packing profiles actively chase just-freed nodes, so a finish landing
-# within the notification chain (0.21 s) of a cycle boundary makes the
-# batched cycle see space the scalar scheduler's cache doesn't yet —
-# a documented model residue, not a profile-lowering defect.
+# scan path — and must still agree pod-for-pod. Packing profiles actively
+# chase just-freed nodes, so a finish within the notification chain
+# (0.21 s) of a cycle boundary used to show the batched cycle space the
+# scalar scheduler's cache did not yet have, and the first three packing
+# cases were pinned to seeds whose finishes kept clear of that gap. The
+# pending-free channel closed it (docs/PARITY.md "Freed-resource
+# visibility at cycle boundaries", tests/test_pending_free.py): the cases
+# after them are seeds the pins had avoided.
 @pytest.mark.parametrize(
     "seed,conditional_move,profile",
     [
@@ -137,6 +138,12 @@ END_TIME = 12000.0  # past last event + max duration + stale flush + slack
         (101, False, "best_fit"),
         (505, False, "best_fit"),
         (101, False, "balanced_packing"),
+        (202, False, "best_fit"),
+        (303, False, "best_fit"),
+        (404, True, "best_fit"),
+        (202, False, "balanced_packing"),
+        (303, False, "balanced_packing"),
+        (505, True, "balanced_packing"),
     ],
 )
 def test_random_trace_cross_path_equivalence(seed, conditional_move, profile):
