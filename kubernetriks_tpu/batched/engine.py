@@ -2074,60 +2074,55 @@ class BatchedSimulation:
 
     # --- scenario-vector fleet support (batched/fleet.py) -------------------
 
-    def _pair_np(self, x) -> TPair:
-        """Host f64 seconds (scalar or array) -> device TPair."""
-        w, o = from_f64_np(
-            np.asarray(x, np.float64), self.config.scheduling_cycle_interval  # ktpu: sync-ok(scenario update: host numpy over per-lane config vectors, no device values)
-        )
-        return TPair(win=jnp.asarray(w), off=jnp.asarray(o))
+    def _scenario_rows(self) -> dict:
+        """The scenario-bearing device leaves as HOST arrays, composed from
+        `self._scenario` through fleet.scenario_leaves: the thirteen
+        SCENARIO_TRACED_LEAVES of AutoscaleStatics (None without
+        autoscalers) and consts.fault_seed (None where the build carries
+        no seed leaf), each in its device dtype. The float64 arithmetic
+        that splits a time into (win, off) runs here, on the host, so a
+        device value is the same bits whichever transport carries it
+        (update_scenario's per-leaf puts, admit_lanes' one buffer)."""
+        from kubernetriks_tpu.batched.fleet import scenario_leaves
 
-    def update_scenario(self, scenario) -> None:
-        """Install new per-lane scenario vectors into the RESIDENT engine:
-        the scenario-bearing statics leaves (scan intervals, thresholds,
-        CA period/quota, autoscaler-chain delays, per-lane HPA enables)
-        and the pod-fault seed vector are all traced (C,)-shaped DATA, so
-        this is a handful of host->device puts — never a recompile
-        (bench.py --sweep asserts exactly that via fleet.jit_cache_sizes).
-        Only legal on an engine built with scenario= (the fleet build):
-        a scenario-less build may carry a different consts pytree
-        (no fault_seed leaf), where a late update would shadow-compile."""
-        from kubernetriks_tpu.batched.fleet import (
-            normalize_scenario,
-            scenario_leaves,
-        )
-
-        if self._scenario is None:
-            raise ValueError(
-                "update_scenario requires an engine built with scenario= "
-                "(the fleet build): scenario-less engines compile the "
-                "pre-fleet consts pytree and a late scenario would "
-                "shadow-compile next to it"
-            )
-        updates = normalize_scenario(scenario, self.n_clusters) or {}
-        self._scenario.update(updates)
         leaves = scenario_leaves(self.config, self.n_clusters, self._scenario)
+        interval = self.config.scheduling_cycle_interval
+
+        def pair(seconds) -> TPair:
+            win, off = from_f64_np(seconds, interval)
+            return TPair(win=win, off=off)
+
+        rows = {"statics": None, "fault_seed": None}
         if self.autoscale_statics is not None:
-            active_when_on = self._autoscale_aux["pg_active_when_on"]
             pg_active_from = np.where(
-                leaves["hpa_enabled"][:, None], active_when_on, np.inf
+                leaves["hpa_enabled"][:, None],
+                self._autoscale_aux["pg_active_when_on"],
+                np.inf,
             )
-            st = self.autoscale_statics._replace(
-                hpa_interval=self._pair_np(leaves["hpa_interval_s"]),
-                hpa_tolerance=jnp.asarray(
-                    leaves["hpa_tolerance"], jnp.float64
-                ),
-                ca_threshold=jnp.asarray(leaves["ca_threshold"], jnp.float64),
-                ca_max_nodes=jnp.asarray(leaves["ca_max_nodes"], jnp.int32),
-                pg_active_from=self._pair_np(pg_active_from),
-                d_hpa_up=self._pair_np(leaves["d_hpa_up_s"]),
-                d_hpa_down=self._pair_np(leaves["d_hpa_down_s"]),
-                d_ca_up=self._pair_np(leaves["d_ca_up_s"]),
-                d_ca_down=self._pair_np(leaves["d_ca_down_s"]),
-                ca_period=self._pair_np(leaves["ca_period_s"]),
-                ca_snap=self._pair_np(leaves["ca_snap_s"]),
-                ca_finish_vis=self._pair_np(leaves["ca_finish_vis_s"]),
-                ca_commit_vis=self._pair_np(leaves["ca_commit_vis_s"]),
+            rows["statics"] = dict(
+                hpa_interval=pair(leaves["hpa_interval_s"]),
+                hpa_tolerance=leaves["hpa_tolerance"].astype(np.float64),
+                ca_threshold=leaves["ca_threshold"].astype(np.float64),
+                ca_max_nodes=leaves["ca_max_nodes"].astype(np.int32),
+                pg_active_from=pair(pg_active_from),
+                d_hpa_up=pair(leaves["d_hpa_up_s"]),
+                d_hpa_down=pair(leaves["d_hpa_down_s"]),
+                d_ca_up=pair(leaves["d_ca_up_s"]),
+                d_ca_down=pair(leaves["d_ca_down_s"]),
+                ca_period=pair(leaves["ca_period_s"]),
+                ca_snap=pair(leaves["ca_snap_s"]),
+                ca_finish_vis=pair(leaves["ca_finish_vis_s"]),
+                ca_commit_vis=pair(leaves["ca_commit_vis_s"]),
             )
+        if self.consts.fault_seed is not None:
+            rows["fault_seed"] = leaves["fault_seed"].astype(np.uint32)
+        return rows
+
+    def _install_scenario(self, rows: dict) -> None:
+        """Bind DEVICE scenario leaves (the pytree of _scenario_rows) into
+        the resident statics and consts."""
+        if rows["statics"] is not None:
+            st = self.autoscale_statics._replace(**rows["statics"])
             if self._sharding is not None:
                 put = (
                     put_global
@@ -2136,12 +2131,108 @@ class BatchedSimulation:
                 )
                 st = put(st, self._state_shardings(self._sharding, st))
             self.autoscale_statics = st
-        if self.consts.fault_seed is not None:
-            self.consts = self.consts._replace(
-                fault_seed=jnp.asarray(
-                    leaves["fault_seed"].astype(np.uint32), jnp.uint32
-                )
+        if rows["fault_seed"] is not None:
+            self.consts = self.consts._replace(fault_seed=rows["fault_seed"])
+
+    def _require_scenario_build(self, what: str) -> None:
+        if self._scenario is None:
+            raise ValueError(
+                f"{what} requires an engine built with scenario= "
+                "(the fleet build): scenario-less engines compile the "
+                "pre-fleet consts pytree and a late scenario would "
+                "shadow-compile next to it"
             )
+
+    def update_scenario(self, scenario) -> None:
+        """Install new per-lane scenario vectors into the RESIDENT engine:
+        the scenario-bearing statics leaves (scan intervals, thresholds,
+        CA period/quota, autoscaler-chain delays, per-lane HPA enables)
+        and the pod-fault seed vector are all traced (C,)-shaped DATA, so
+        this is one host->device put a leaf (some two dozen) and never a
+        recompile (bench.py --sweep asserts exactly that via
+        fleet.jit_cache_sizes). The wave boundary's transport; a
+        lane-async pump round admits through admit_lanes, one put.
+        Only legal on an engine built with scenario= (the fleet build):
+        a scenario-less build may carry a different consts pytree
+        (no fault_seed leaf), where a late update would shadow-compile."""
+        from kubernetriks_tpu.batched.fleet import normalize_scenario
+
+        self._require_scenario_build("update_scenario")
+        self._scenario.update(
+            normalize_scenario(scenario, self.n_clusters) or {}
+        )
+        self._install_scenario(jax.tree.map(jnp.asarray, self._scenario_rows()))
+
+    def admit_lanes(self, lanes, scenario, horizons) -> None:
+        """A lane-async pump round's whole admission in ONE host->device
+        put and ONE donated program (fleet._admit_lanes), whatever the
+        number of lanes: for the listed lanes, (a) write the scenario
+        leaves of AutoscaleStatics and consts.fault_seed composed from
+        `scenario` (update_scenario's values, bit for bit), (b) select
+        the pristine build state into them, the telemetry ring preserved
+        (lane_reset's select), (c) start their clocks at the engine's
+        current global window with `horizons[i]` windows to run
+        (set_lane_plan). The host rows of ALL lanes travel as one
+        (C, K) float64 buffer with the lane mask as its first column
+        (fleet.pack_admission: a float64 leaf as it is, a 32-bit leaf as
+        two 16-bit halves, exact on any backend); the program takes them
+        into the masked lanes only. The numpy mirrors
+        (_scenario, the lane clocks, the trace mux's offered ranges) are
+        updated as the three public calls update them."""
+        from kubernetriks_tpu.batched.fleet import (
+            _admit_lanes,
+            normalize_scenario,
+            pack_admission,
+        )
+
+        if not self.lane_async:
+            raise ValueError(
+                "admit_lanes requires an engine built with lane_async=True"
+            )
+        self._require_scenario_build("admit_lanes")
+        lanes = np.asarray(list(lanes), np.int64)  # ktpu: sync-ok(python lane-index list, no device value)
+        mask = np.zeros((self.n_clusters,), bool)
+        mask[lanes] = True
+        if self._lane_mux is not None:
+            self._lane_mux.retire(lanes.tolist())
+        self._lane_clock_np[lanes] = self.next_window_idx
+        self._lane_horizon_np[lanes] = np.asarray(horizons, np.int64)  # ktpu: sync-ok(python horizon list into the host mirror, no device value)
+        self._scenario.update(
+            normalize_scenario(scenario, self.n_clusters) or {}
+        )
+        rows = self._scenario_rows()
+        rows["lane_clock"] = self._lane_clock_np.astype(np.int32)
+        rows["lane_horizon"] = self._lane_horizon_np.astype(np.int32)
+        buf = pack_admission(mask, rows)
+        live = {
+            "statics": (
+                None
+                if rows["statics"] is None
+                else {
+                    name: getattr(self.autoscale_statics, name)
+                    for name in rows["statics"]
+                }
+            ),
+            "fault_seed": self.consts.fault_seed,
+            "lane_clock": self.consts.lane_clock,
+            "lane_horizon": self.consts.lane_horizon,
+        }
+        ring = self.state.telemetry
+        state = self.state._replace(telemetry=None)
+        donated_in = state if self._sanitize else None
+        state, live = _admit_lanes(
+            state,
+            self._pristine._replace(telemetry=None),
+            live,
+            jax.device_put(buf),
+        )
+        if donated_in is not None:
+            sanitize.consume_donated(donated_in)
+        self.state = state._replace(telemetry=ring)
+        self.consts = self.consts._replace(
+            lane_clock=live["lane_clock"], lane_horizon=live["lane_horizon"]
+        )
+        self._install_scenario(live)
 
     def fleet_reset(self, lanes=None) -> None:
         """Reset cluster lanes to the PRISTINE build state in place — the
@@ -2254,14 +2345,16 @@ class BatchedSimulation:
         )
         self.slab = TraceSlab(packed=packed)
 
-    def set_lane_trace(self, lane: int, lo: int = 0, hi=None) -> None:
+    def set_lane_trace(self, lane: int, lo: int = 0, hi=None) -> bool:
         """Install a per-lane workload row-range (stream.LaneTraceMux):
         the lane replays only slab rows [lo, hi) (pod creates outside the
         range and their removes masked to EV_NONE in place — host copy,
         sort order preserved). Reseed-boundary call: the mux's never-
         re-offer invariant refuses a lane whose previous range was not
-        retired by lane_reset. Pure data install — zero recompiles, zero
-        new steady-state syncs."""
+        retired by lane_reset / admit_lanes. Pure data install — zero
+        recompiles, zero new steady-state syncs. Returns whether the
+        range changed, that is whether rows went to the device (one put
+        and one program; the mux skips an unchanged range)."""
         if not self.lane_async or self._lane_mux is None:
             raise ValueError(
                 "set_lane_trace requires an engine built with "
@@ -2270,6 +2363,7 @@ class BatchedSimulation:
         rows = self._lane_mux.offer(int(lane), lo, hi)
         if rows is not None:
             self._install_lane_rows(int(lane), rows)
+        return rows is not None
 
     def lane_windows_remaining(self) -> np.ndarray:
         """(C,) host ints: windows left on each lane's plan from the

@@ -55,6 +55,15 @@ Lane reset protocol — two modes:
   mirrors (zero new syncs), and the telemetry ring's lane_active column
   feeds the observatory's lane-occupancy gauge + idle-lane verdict.
 
+A pump round's transport (PR 33, DESIGN §13.3): one packed readback of
+every lane's result row (`_pack_lane_rows`, one blocking copy, rows sliced
+on the host for the finished lanes; the wave path reads its horizons
+through the same function) and one packed admission (`engine.admit_lanes`
+-> `_admit_lanes`: scenario rows, pristine select and lane clocks of the
+masked lanes from one host buffer), so the host-device round trips of a
+round do not depend on how many lanes finished or were admitted. The
+recorder's `pump_transfers_down` / `pump_transfers_up` count them.
+
 Query observatory (PR 17, DESIGN §14): every query carries a host-side
 lifecycle record (submitted → admitted-to-lane → first-dispatch →
 horizon-drained → polled, all perf_counter_ns stamps — no device reads),
@@ -379,6 +388,9 @@ _RESULT_COUNTERS = (
     "pod_restarts",
     "pods_failed",
 )
+# The columns of the packed readback (_pack_lane_rows) before the
+# autoscaler's: the counters, then the two divergence counters.
+_ROW_COUNTERS = _RESULT_COUNTERS + ("hpa_reserve_clamped", "ca_reserve_starved")
 
 
 def jit_cache_sizes() -> Dict[str, int]:
@@ -402,6 +414,8 @@ def jit_cache_sizes() -> Dict[str, int]:
         "ca_pass_donated": autoscale.ca_pass_donated,
         "tree_copy": state.tree_copy,
         "reset_lanes": _reset_lanes,
+        "admit_lanes": _admit_lanes,
+        "pack_lane_rows": _pack_lane_rows,
     }
     out = {}
     for name, fn in entries.items():
@@ -412,9 +426,12 @@ def jit_cache_sizes() -> Dict[str, int]:
     return out
 
 
-def _make_reset_lanes():
+def _make_lane_programs():
     import jax
     import jax.numpy as jnp
+
+    def select(mask, new, cur):
+        return jnp.where(mask.reshape((-1,) + (1,) * (cur.ndim - 1)), new, cur)
 
     @partial(jax.jit, donate_argnums=(0,))
     def reset(state, pristine, mask):
@@ -423,17 +440,85 @@ def _make_reset_lanes():
         donation reuses the live state's device buffers in place (no fresh
         full-state allocation per wave). Every state leaf leads with the
         cluster axis, so one broadcasted select covers the whole pytree."""
+        return jax.tree.map(
+            lambda cur, ini: select(mask, ini, cur), state, pristine
+        )
 
-        def leaf(cur, ini):
-            m = mask.reshape((-1,) + (1,) * (cur.ndim - 1))
-            return jnp.where(m, ini, cur)
+    @partial(jax.jit, donate_argnums=(0,))
+    def admit(state, pristine, live, buf):
+        """A pump round's admission as one program over one buffer
+        (`pack_admission`'s, put once by engine.admit_lanes): masked
+        lanes take the pristine state's rows (reset's select) and the
+        buffer's rows of every leaf of `live` (the scenario-bearing
+        statics leaves, the fault seeds, the lane clocks); every other
+        lane keeps what it has. One compiled shape whatever the lanes
+        admitted: the mask is data."""
+        mask = buf[:, 0] != 0
+        state = jax.tree.map(
+            lambda cur, ini: select(mask, ini, cur), state, pristine
+        )
+        leaves, treedef = jax.tree.flatten(live)
+        out, col = [], 1
+        for cur in leaves:
+            width = cur.size // cur.shape[0]
+            if cur.dtype.itemsize == 8:
+                new = buf[:, col : col + width].reshape(cur.shape)
+                out.append(select(mask, new, cur))
+                col += width
+                continue
+            # Selected as WORDS: a float select flushes a subnormal on a
+            # TPU, and every bit of a 32-bit leaf is the host's.
+            high, low = (
+                buf[:, at : at + width].astype(jnp.uint32).reshape(cur.shape)
+                for at in (col, col + width)
+            )
+            words = jax.lax.bitcast_convert_type(cur, jnp.uint32)
+            words = select(mask, (high << 16) | low, words)
+            out.append(jax.lax.bitcast_convert_type(words, cur.dtype))
+            col += 2 * width
+        return state, jax.tree.unflatten(treedef, out)
 
-        return jax.tree.map(leaf, state, pristine)
+    @jax.jit
+    def pack_rows(counters, auto):
+        """Everything a drained query returns, for ALL lanes, as one
+        (C, R) integer array: the `_ROW_COUNTERS` columns, then (with
+        autoscalers) each pod group's created replicas, hpa_tail -
+        hpa_head, and each node group's ca_count. Every leaf is int32
+        today; a wider one would widen the array, never narrow a leaf."""
+        cols = [c[:, None] for c in counters]
+        if auto is not None:
+            hpa_head, hpa_tail, ca_count = auto
+            cols += [hpa_tail - hpa_head, ca_count]
+        dtype = jnp.result_type(*cols)
+        return jnp.concatenate([c.astype(dtype) for c in cols], axis=1)
 
-    return reset
+    return reset, admit, pack_rows
 
 
-_reset_lanes = _make_reset_lanes()
+_reset_lanes, _admit_lanes, _pack_lane_rows = _make_lane_programs()
+
+
+def pack_admission(mask: np.ndarray, rows) -> np.ndarray:
+    """The one host buffer of a packed admission, `_admit_lanes`' `buf`:
+    `(C, K)` float64, column 0 the lane mask, then the host rows of every
+    leaf of the pytree `rows` in its own leaf order. A float64 leaf
+    travels as it is (the device gets the value a put of the leaf alone
+    would give it); a 32-bit leaf (int32, uint32, float32) as the high
+    and the low 16 bits of its words, two columns an element, which the
+    program shifts back together and bitcasts: whole numbers under 2**16
+    are exact in whatever a backend makes of float64 (a TPU keeps a pair
+    of float32, which holds neither every uint32 nor every int32)."""
+    import jax
+
+    cols = [mask.astype(np.float64)[:, None]]
+    for leaf in jax.tree.leaves(rows):
+        leaf = np.ascontiguousarray(leaf).reshape(len(mask), -1)
+        if leaf.dtype.itemsize == 8:
+            cols.append(leaf.astype(np.float64))
+        else:
+            words = leaf.view(np.uint32)
+            cols += [words >> 16, words & 0xFFFF]
+    return np.concatenate(cols, axis=1, dtype=np.float64)
 
 
 class ScenarioFleet:
@@ -514,6 +599,14 @@ class ScenarioFleet:
         # zero-recompiles-after-warm-up capture covers every program the
         # steady query stream can touch.
         self.engine.fleet_reset(lanes=[])
+        # The same for the packed readback a drain makes and, on a
+        # lane-async fleet, for the packed admission and the ring-preserving
+        # crash-reset (with the device ring on a program of its own): an
+        # empty lane list admits and resets nothing.
+        self._pack_rows()
+        if self.lane_async:
+            self.engine.lane_reset([])
+            self.engine.admit_lanes([], self._vectors, [])
         # KTPU_EXPLAIN_RECOMPILES=1: guard every post-warm-up wave with
         # the recompile sentinel — the runtime cross-check of the
         # scenariotrace lint pass's static compile-once guarantee. Wave 1
@@ -525,8 +618,8 @@ class ScenarioFleet:
         self._sentinel = maybe_sentinel()
         # Lane-async bookkeeping (pump/poll, DESIGN §13). _live_vectors is
         # the CURRENT per-lane config row set: assignments rewrite only
-        # the re-seeded lanes' rows, so update_scenario hands in-flight
-        # lanes bit-identical values and their trajectories are untouched.
+        # the re-seeded lanes' rows, and engine.admit_lanes writes only
+        # those lanes on the device, so in-flight trajectories are untouched.
         self._live_vectors = {k: v.copy() for k, v in self._vectors.items()}
         self._active: Dict[int, tuple] = {}  # lane -> (qid, scen, horizon)
         self._trace_rows: Dict[int, tuple] = {}  # qid -> (lo, hi)
@@ -849,26 +942,32 @@ class ScenarioFleet:
 
     # -- wave machinery ------------------------------------------------------
 
-    def _lane_rows(self, lanes: Sequence[int]) -> Dict[int, Dict[str, float]]:
-        """Per-lane counter rows, fetched in ONE host block per metric
-        leaf at a horizon boundary (the engine just blocked there for the
-        step's own sync; this is the readout ride-along, not a new
-        steady-state sync)."""
-        m = self.engine.state.metrics
+    def _lane_rows(self) -> np.ndarray:
+        """Every lane's packed result row, `(C, R)` on the host: ONE
+        small program over the state (_pack_lane_rows: the counters,
+        created replicas and CA node counts in one integer array) and ONE
+        blocking device-to-host copy a round or wave horizon, however
+        many lanes finished; the caller takes the finished lanes' rows.
+        The copy lands at a horizon boundary, where the host has nothing
+        else to do until the device finishes the step: no new
+        steady-state sync."""
         # result_wait: where the host waits for the device to finish what
         # the round (or wave) enqueued.
         tracer = self.engine.tracer
         t0 = tracer.begin(PH_RESULT_WAIT)
-        host = {
-            name: np.asarray(getattr(m, name)) for name in _RESULT_COUNTERS
-        }
-        host["hpa_reserve_clamped"] = np.asarray(m.hpa_reserve_clamped)
-        host["ca_reserve_starved"] = np.asarray(m.ca_reserve_starved)
+        packed = np.asarray(self._pack_rows())
+        tracer.count("pump_transfers_down")
         tracer.end(PH_RESULT_WAIT, t0, ident=self.pump_rounds)
-        return {
-            lane: {name: arr[lane].item() for name, arr in host.items()}
-            for lane in lanes
-        }
+        return packed
+
+    def _pack_rows(self):
+        """Dispatch the packed readback's program; the rows, on the device."""
+        st = self.engine.state
+        auto = st.auto
+        return _pack_lane_rows(
+            tuple(getattr(st.metrics, name) for name in _ROW_COUNTERS),
+            None if auto is None else (auto.hpa_head, auto.hpa_tail, auto.ca_count),
+        )
 
     def _drain_lane(
         self,
@@ -876,12 +975,12 @@ class ScenarioFleet:
         lane: int,
         horizon: float,
         scen: Scenario,
-        rows: Dict,
+        rows: np.ndarray,
         wave: Optional[int] = None,
     ) -> None:
-        row = rows[lane]
-        clamped = int(row.pop("hpa_reserve_clamped"))
-        starved = int(row.pop("ca_reserve_starved"))
+        row = rows[lane].tolist()
+        n = len(_RESULT_COUNTERS)
+        clamped, starved = row[n], row[n + 1]
         if self.strict_divergence and (clamped > 0 or starved > 0):
             raise RuntimeError(
                 f"fleet query {qid} (lane {lane}): autoscaler reserve "
@@ -894,15 +993,18 @@ class ScenarioFleet:
         hpa = None
         ca = None
         if eng.state.auto is not None:
-            hpa = eng.hpa_replicas(lane)
-            ca = [int(v) for v in eng.ca_node_counts(lane)]
+            # The row's tail: a column a pod group (the lane's own names
+            # cover the first of them), then a column a node group.
+            groups = eng.state.auto.hpa_head.shape[1]
+            hpa = dict(zip(eng.pod_group_names[lane], row[n + 2 :]))
+            ca = row[n + 2 + groups :]
         self.results[qid] = FleetResult(
             query=qid,
             wave=self.waves_run if wave is None else wave,
             lane=lane,
             horizon=horizon,
             scenario=scen,
-            counters={k: int(v) for k, v in row.items()},
+            counters=dict(zip(_RESULT_COUNTERS, row)),
             hpa_replicas=hpa,
             ca_nodes=ca,
             hpa_reserve_clamped=clamped,
@@ -950,8 +1052,7 @@ class ScenarioFleet:
         tracer = eng.tracer
         for horizon in sorted(by_horizon):
             eng.step_until_time(horizon)
-            lanes = [lane for _, lane, _ in by_horizon[horizon]]
-            rows = self._lane_rows(lanes)
+            rows = self._lane_rows()
             t_drain = time.perf_counter_ns()
             for qid, lane, scen in by_horizon[horizon]:
                 self._drain_lane(qid, lane, horizon, scen, rows)
@@ -1052,23 +1153,23 @@ class ScenarioFleet:
                     self._live_vectors[key][lane] = self._vectors[key][lane]
                 for key, val in scen.overrides().items():
                     self._live_vectors[key][lane] = val
-            eng.update_scenario(
-                {k: v.copy() for k, v in self._live_vectors.items()}
+            # One put and one program for the whole admission (scenario
+            # rows, pristine select, lane clocks), whatever len(assigned).
+            eng.admit_lanes(
+                [lane for lane, _, _, _ in assigned],
+                self._live_vectors,
+                [eng.horizon_windows(h) for _, _, _, h in assigned],
             )
-            lanes = [lane for lane, _, _, _ in assigned]
-            eng.lane_reset(lanes)
+            tracer.count("pump_transfers_up")
             for lane, qid, _, _ in assigned:
                 # Always (re)install the lane's workload range at the
                 # reseed boundary: a previous query's mask must not leak
                 # into this one (full range when the query carries none;
-                # the mux skips the device write when nothing changed).
+                # the mux skips the device write when nothing changed,
+                # and a changed range is one more put).
                 lo, hi = self._trace_rows.pop(qid, (0, None))
-                eng.set_lane_trace(lane, lo, hi)
-            eng.set_lane_plan(
-                lanes,
-                eng.next_window_idx,
-                [eng.horizon_windows(h) for _, _, _, h in assigned],
-            )
+                if eng.set_lane_trace(lane, lo, hi):
+                    tracer.count("pump_transfers_up")
             tracer.end(PH_PUMP_ADMIT, t_span, ident=self.pump_rounds)
             # Lifecycle: admitted-to-lane — close the queue-wait span
             # (submit -> here) on the tracer with an explicit duration.
@@ -1165,7 +1266,7 @@ class ScenarioFleet:
         if not finished:
             return 0
         t_pump_drain = tracer.begin(PH_PUMP_DRAIN)
-        rows = self._lane_rows(finished)
+        rows = self._lane_rows()
         t_drain = time.perf_counter_ns()
         obs = getattr(eng, "observatory", None)
         for lane in finished:
@@ -1244,6 +1345,8 @@ class ScenarioFleet:
         tracer = self.engine.tracer
         t0 = tracer.begin(PH_LANE_DISPATCH)
         self.engine.step_windows(n_windows)
+        # The chunk's window-index vector: the one put a dispatch makes.
+        tracer.count("pump_transfers_up")
         tracer.end(PH_LANE_DISPATCH, t0, ident=self.pump_rounds)
 
     def _on_dispatch_fault(self, exc: Exception) -> None:

@@ -109,7 +109,7 @@ CONSUMERS: Tuple[Registry, ...] = (
     Registry(
         "fleet-reset",
         FLEET_PY,
-        "_make_reset_lanes",
+        "_make_lane_programs",
         ("ClusterBatchState", "AutoscaleState", "TelemetryRing"),
     ),
     Registry(

@@ -404,3 +404,275 @@ def test_async_every_qid_streams_exactly_one_terminal_outcome(
     assert asy.poll() == []
     assert asy.poll(q_dead) == [] and asy.poll(q_live) == []
     assert asy.failed_queries.get("deadline_exceeded", 0) >= 1
+
+
+# --- the pump round's transport (PR 33): one packed readback, one packed
+# admission ------------------------------------------------------------------
+
+GOLDEN = os.path.join(os.path.dirname(__file__), "data", "fleet_pump_golden.json")
+TRANSPORT_LANES = 8
+TRANSPORT_SPAN = 4
+RANGED = 160 // 2  # half the composed trace's rows (the golden records 160)
+
+# Three horizons, scenarios with and without overrides (a seed with the
+# high bit set, a disabled HPA, a CA quota of zero), a trace range on some.
+MIXED = [
+    (Scenario(), 90.0, None),
+    (Scenario(fault_seed=11, hpa_scan_interval=30.0), 250.0, None),
+    (Scenario(ca_threshold=0.7), 150.0, (0, RANGED)),
+    (Scenario(fault_seed=22, hpa_tolerance=0.25), 90.0, (10, None)),
+    (Scenario(hpa_enabled=False), 150.0, None),
+    (
+        Scenario(
+            fault_seed=33, ca_max_node_count=0, as_to_ca_network_delay=0.9
+        ),
+        250.0,
+        None,
+    ),
+    (Scenario(), 250.0, (0, RANGED)),
+    (Scenario(fault_seed=2**31 + 5, ca_scan_interval=20.0), 90.0, None),
+]
+RESULT_FIELDS = (
+    "counters",
+    "hpa_replicas",
+    "ca_nodes",
+    "hpa_reserve_clamped",
+    "ca_reserve_starved",
+)
+
+
+def _full_ranges(fleet):
+    """Every lane back on the whole trace (a ranged query leaves its range
+    installed until the lane's next admission changes it), so that the
+    rounds counted below carry no range change of an earlier test's."""
+    horizon = (TRANSPORT_SPAN - 1) * fleet.config.scheduling_cycle_interval
+    for _ in range(TRANSPORT_LANES):
+        fleet.submit(Scenario(), horizon)
+    fleet.run_async()
+    fleet.poll()
+
+
+def _transfers(fleet):
+    counters = fleet.engine.tracer.counters
+    return (
+        counters.get("pump_transfers_down", 0),
+        counters.get("pump_transfers_up", 0),
+    )
+
+
+@pytest.fixture(scope="module")
+def transport_runs(async_ab_runs):
+    """An 8-lane lane-async fleet under the armed sentinel, fed the MIXED
+    stream (the golden's order), and the module's wave fleet fed the
+    queries of it that carry no trace range (a wave fleet has no trace
+    multiplexer)."""
+    import json
+
+    wave = async_ab_runs[0]
+    config = default_test_simulation_config(
+        COMPOSED_CONFIG_SUFFIX + FAULT_SUFFIX
+    )
+    cluster_events, workload = _composed_traces()
+    os.environ["KTPU_EXPLAIN_RECOMPILES"] = "1"
+    try:
+        fleet = ScenarioFleet(
+            config,
+            cluster_events,
+            workload,
+            n_lanes=TRANSPORT_LANES,
+            horizon=450.0,
+            max_pods_per_cycle=16,
+            use_pallas=False,
+            ca_slot_multiplier=4,
+            lane_async=True,
+            span_windows=TRANSPORT_SPAN,
+        )
+        assert fleet._sentinel is not None
+        assert fleet.engine._lane_mux.n_rows == 2 * RANGED
+        qids = [fleet.submit(s, h, trace_rows=r) for s, h, r in MIXED]
+        fleet.run_async()
+        fleet.poll()
+        wave_qids = {
+            i: wave.submit(s, h)
+            for i, (s, h, r) in enumerate(MIXED)
+            if r is None
+        }
+        wave.run()
+        with open(GOLDEN) as fh:
+            golden = json.load(fh)["results"]
+        yield fleet, qids, wave, wave_qids, golden
+        fleet.close()
+    finally:
+        os.environ.pop("KTPU_EXPLAIN_RECOMPILES", None)
+
+
+@pytest.mark.parametrize("i", range(len(MIXED)))
+def test_pump_result_equals_wave_and_parent_golden(transport_runs, i):
+    """Same integers through the packed readback and the packed
+    admission: every FleetResult field of the pump path equals the golden
+    the PARENT's per-leaf transport returned for the same query (JSON:
+    plain ints, so a numpy scalar in a result fails here too), and, where
+    the query carries no trace range, the wave path's."""
+    fleet, qids, wave, wave_qids, golden = transport_runs
+    scen, horizon, _ = MIXED[i]
+    got = fleet.results[qids[i]]
+    assert got.ok and got.scenario == scen and got.horizon == horizon
+    for name in RESULT_FIELDS:
+        assert getattr(got, name) == golden[i][name], (i, name)
+    assert all(type(v) is int for v in got.counters.values())
+    assert all(type(v) is int for v in got.ca_nodes)
+    assert all(type(v) is int for v in got.hpa_replicas.values())
+    if i in wave_qids:
+        ref = wave.results[wave_qids[i]]
+        for name in RESULT_FIELDS:
+            assert getattr(got, name) == getattr(ref, name), (i, name)
+
+
+@pytest.mark.parametrize(
+    "drains,admits", [(1, 1), (7, 7), (8, 8), (1, 7), (7, 1)]
+)
+def test_round_transfers_do_not_depend_on_the_lanes(
+    transport_runs, drains, admits
+):
+    """One transfer down and two up (the admission's buffer, the
+    dispatch's window indices) in a round that drains `drains` lanes and
+    admits `admits`, whatever the two numbers: the transport is a mask,
+    never a loop over lanes."""
+    fleet = transport_runs[0]
+    _full_ranges(fleet)
+    interval = fleet.config.scheduling_cycle_interval
+    one_round = (TRANSPORT_SPAN - 1) * interval  # span windows exactly
+    two_rounds = (2 * TRANSPORT_SPAN - 1) * interval
+    if drains + admits <= TRANSPORT_LANES:
+        for _ in range(drains):
+            fleet.submit(Scenario(fault_seed=5), two_rounds)
+        assert fleet.pump() == 0  # admitted and stepped, none finished
+        for _ in range(admits):
+            fleet.submit(Scenario(fault_seed=6), two_rounds)
+    else:  # the same lanes, admitted and drained by one round
+        assert drains == admits
+        for _ in range(admits):
+            fleet.submit(Scenario(fault_seed=5), one_round)
+    before = _transfers(fleet)
+    assert fleet.pump() == drains
+    after = _transfers(fleet)
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 2)
+    fleet.run_async()
+    assert not any(q for q in fleet.poll() if not q.ok)
+
+
+def test_changed_trace_range_is_one_more_put(transport_runs):
+    """A lane whose workload range changes at admission costs one put of
+    its own, on the same counter; an unchanged range costs none."""
+    fleet = transport_runs[0]
+    _full_ranges(fleet)
+    horizon = (TRANSPORT_SPAN - 1) * fleet.config.scheduling_cycle_interval
+    ups = []
+    for rows in ((0, RANGED), (0, RANGED), None):
+        fleet.submit(Scenario(), horizon, trace_rows=rows)
+        before = _transfers(fleet)
+        assert fleet.pump() == 1
+        ups.append(_transfers(fleet)[1] - before[1])
+        fleet.poll()
+    # lane 0 each time: range installed, range kept, full range restored
+    assert ups == [3, 2, 3]
+
+
+@pytest.mark.parametrize("ranged", [False, True])
+def test_fifty_rounds_compile_nothing(transport_runs, ranged):
+    """Compile-once: under the armed sentinel (a compile inside a round
+    raises), fifty more rounds of the mixed stream leave every jit entry
+    of the dispatch loop, the packed admission and the packed readback
+    among them, at its warm-up count."""
+    fleet = transport_runs[0]
+    sizes0 = jit_cache_sizes()
+    assert sizes0["admit_lanes"] >= 1 and sizes0["pack_lane_rows"] >= 1
+    rounds0 = fleet.pump_rounds
+    k = 0
+    while fleet.pump_rounds - rounds0 < 50:
+        if fleet.pending < TRANSPORT_LANES:
+            scen, horizon, rows = MIXED[k % len(MIXED)]
+            fleet.submit(scen, horizon, trace_rows=rows if ranged else None)
+            k += 1
+        fleet.pump()
+    while fleet.pending or fleet._active:
+        fleet.pump()
+    assert jit_cache_sizes() == sizes0
+    assert all(q.ok for q in fleet.poll())
+
+
+@pytest.mark.parametrize(
+    "counter", ["hpa_reserve_clamped", "ca_reserve_starved"]
+)
+def test_strict_divergence_still_raises_from_the_packed_row(
+    transport_runs, counter
+):
+    """The loud readout reads the packed row: a lane whose divergence
+    counter is non-zero at its drain raises, naming the lane and the
+    counter; the lane's next admission selects the pristine state back
+    in, and the same query then returns the reference's integers."""
+    import jax.numpy as jnp
+
+    fleet, qids = transport_runs[:2]
+    scen, horizon, _ = MIXED[0]
+    fleet.submit(scen, horizon)
+    assert fleet.pump() == 0
+    (lane,) = fleet._active
+    eng = fleet.engine
+    poked = getattr(eng.state.metrics, counter) + (
+        jnp.arange(TRANSPORT_LANES) == lane
+    ).astype(jnp.int32) * 3
+    eng.state = eng.state._replace(
+        metrics=eng.state.metrics._replace(**{counter: poked})
+    )
+    with pytest.raises(RuntimeError, match=rf"lane {lane}\).*{counter}=3"):
+        fleet.run_async()
+    assert lane not in fleet._active
+    again = fleet.submit(scen, horizon)
+    fleet.run_async()
+    got = fleet.results[again]
+    assert got.lane == lane and got.ok
+    assert _same_result(got, fleet.results[qids[0]])
+    assert (got.hpa_reserve_clamped, got.ca_reserve_starved) == (0, 0)
+    fleet.poll()
+
+
+def test_crash_reset_lane_is_readmitted_through_the_packed_admission(
+    transport_runs,
+):
+    """A dispatch fault crash-resets its lane through the public
+    lane_reset + set_lane_plan; the next query lands on that lane through
+    admit_lanes and returns what a lane that never faulted returns."""
+    from kubernetriks_tpu.batched.faults import LaneFaultError
+
+    fleet, qids = transport_runs[:2]
+    scen, horizon, _ = MIXED[1]
+
+    class FaultLaneZeroOnce:
+        seed = -1
+        fired = False
+
+        def stall_s(self):
+            return 0.0
+
+        def dispatch_fault(self, active):
+            if not self.fired and 0 in active:
+                self.fired = True
+                return 0
+            return None
+
+        def report(self):
+            return {}
+
+    dead = fleet.submit(scen, horizon)
+    fleet.arm_host_chaos(FaultLaneZeroOnce())
+    fleet.pump()
+    fleet.arm_host_chaos(None)
+    assert isinstance(fleet.results[dead], LaneFaultError)
+    assert "InjectedFault" in fleet.results[dead].cause
+    again = fleet.submit(scen, horizon)
+    fleet.run_async()
+    got = fleet.results[again]
+    assert got.ok and got.lane == 0
+    assert _same_result(got, fleet.results[qids[1]])
+    fleet.poll()
