@@ -112,7 +112,9 @@ _BIG_I32 = jnp.iinfo(jnp.int32).max
 # The Fit feasibility predicate, shared with the scheduler pipeline's
 # device-plugin registry: CA placement simulation stays first-fit by
 # reference semantics, but "fits" means the same thing everywhere.
-_fit_filter = DEVICE_FILTER_PLUGINS[FIT]
+def _fit_filter(cpu, ram, rc, rr):
+    # The CA's what-if placements read resources alone (no spread table).
+    return DEVICE_FILTER_PLUGINS[FIT](cpu, ram, rc, rr, None)
 
 
 class AutoscaleStatics(NamedTuple):

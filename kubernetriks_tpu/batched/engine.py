@@ -137,6 +137,9 @@ CKPT_COVERED_LEAVES = {
     "col_run": "config-derived (see col_next)",
     "col_util_cpu": "config-derived (see col_next)",
     "col_util_ram": "config-derived (see col_next)",
+    "spread": "derived from the profile and the traces at build (a constraint "
+    "under a profile that runs PodTopologySpread); the restoring engine's own "
+    "state template supplies the structure (same-build contract, as `auto`)",
 }
 
 # Power-of-two dispatch chunk ladder for the sliding path: any span is its
@@ -306,6 +309,59 @@ def _slide_apply_device(pods, rank, pay, base, s: int, W: int):
             [rank[:, s:W], sl(pay["rank"]), rank[:, W:]], axis=1
         )
     return new_pods, new_rank
+
+
+def _build_spread(profile, compiled_traces, config, n_nodes: int, n_pods: int, pod_window):
+    """Host arrays of the build's state.SpreadState vocabulary leaves, or
+    None where no pod is held to a topology-spread constraint: the profile
+    does not run PodTopologySpread (the constraints are then inert, as
+    upstream's are with the plugin off), or no trace carries one. Each
+    cluster keeps its own interned workloads and domains; the table is as
+    wide as the widest. Refuses, by name, what the batched filter does not
+    cover."""
+    from kubernetriks_tpu.batched.pipeline import (
+        UnsupportedProfileError,
+        uses_spread,
+    )
+
+    carrying = [c.spread for c in compiled_traces if c.spread is not None]
+    if not carrying or not uses_spread(profile):
+        return None
+    if (
+        config.horizontal_pod_autoscaler.enabled
+        or config.cluster_autoscaler.enabled
+        or any(c.pod_groups for c in compiled_traces)
+    ):
+        raise UnsupportedProfileError(
+            "topology-spread constraints together with the horizontal pod autoscaler or the "
+            "cluster autoscaler are not supported: pods and nodes made at run time would need "
+            "labels of their own"
+        )
+    C = len(compiled_traces)
+    G = max(len(sp.workloads) for sp in carrying)
+    Z = max(max(len(sp.domains) for sp in carrying), 1)
+    # Global pod planes: as wide as the device pod axis where that holds the
+    # whole trace; under a sliding pod window, which cuts its columns out of
+    # them at pod_base (step.spread_window_view), room for the widest window
+    # (the whole trace) at the highest base.
+    T = max(max((c.n_pods for c in compiled_traces), default=0), 1)
+    width = n_pods if pod_window is None else 2 * T
+    out = {
+        "domain": np.full((C, n_nodes), -1, np.int32),
+        "max_skew": np.full((C, G, Z), np.iinfo(np.int32).max, np.int32),
+        "pod_group": np.full((C, width), -1, np.int32),
+        "pod_bits": np.zeros((C, width), np.int32),
+        "pod_zone": np.full((C, width), -1, np.int32),
+    }
+    for ci, trace in enumerate(compiled_traces):
+        sp = trace.spread
+        if sp is None:
+            continue
+        out["domain"][ci, : len(sp.node_domain)] = sp.node_domain
+        out["max_skew"][ci, : len(sp.max_skew), :] = sp.max_skew[:, None]
+        out["pod_group"][ci, : len(sp.pod_group)] = sp.pod_group
+        out["pod_bits"][ci, : len(sp.pod_bits)] = sp.pod_bits
+    return out
 
 
 def _lex_name_ranks(names) -> np.ndarray:  # ktpu: sync-ok(host-side name-rank table builder over python name lists, no device values)
@@ -1326,6 +1382,16 @@ class BatchedSimulation:
                 [(node_cap_cpu, node_cap_ram)],
             )
         )
+        # Topology spread: a pod of the build is held to a constraint where
+        # the profile runs PodTopologySpread AND a trace carries one. Only
+        # then does the state get the filter's leaves (state.SpreadState) and
+        # the programs its table; the fit gates below count its blocks.
+        spread_host = _build_spread(
+            self.profile, compiled_traces, config, self.n_nodes, self.n_pods, pod_window
+        )
+        self._spread_shape = (
+            None if spread_host is None else tuple(spread_host["max_skew"].shape[1:])
+        )
         # Real (trace-defined) pod slots, before the 128-alignment padding
         # of the device axis — the count completion/terminal asserts want.
         self.n_real_pods = max((c.n_pods for c in compiled_traces), default=0)
@@ -1380,7 +1446,9 @@ class BatchedSimulation:
             self.use_pallas = (
                 default_enabled()
                 and self.n_clusters % n_shards == 0
-                and kernel_fits(self.n_nodes, self.max_pods_per_cycle)
+                and kernel_fits(
+                    self.n_nodes, self.max_pods_per_cycle, self._spread_shape
+                )
             )
         # Prefer the fused selection kernel (in-kernel queue argmin instead
         # of the (C, P) lexsort) when its pod blocks fit VMEM AND the
@@ -1395,7 +1463,8 @@ class BatchedSimulation:
             self.use_pallas
             and self.n_clusters // n_shards >= 128
             and select_kernel_fits(
-                self.n_nodes, self.n_pods, self.max_pods_per_cycle
+                self.n_nodes, self.n_pods, self.max_pods_per_cycle,
+                self._spread_shape,
             )
         )
         # The r4 megakernel (selection + cycle + commit in one launch) is the
@@ -1411,7 +1480,8 @@ class BatchedSimulation:
             self.use_pallas_select
             and flag_bool("KTPU_MEGAKERNEL")
             and select_commit_kernel_fits(
-                self.n_nodes, self.n_pods, self.max_pods_per_cycle
+                self.n_nodes, self.n_pods, self.max_pods_per_cycle,
+                self._spread_shape,
             )
         )
         # The fit gates above (and the CA kernels' in autoscale.py) degrade
@@ -1449,6 +1519,16 @@ class BatchedSimulation:
             interval=config.scheduling_cycle_interval,
             node_crash_downtime=node_crash_downtime,
         )
+        if spread_host is not None:
+            from kubernetriks_tpu.batched.state import SpreadState
+
+            self.state = self.state._replace(
+                spread=SpreadState(
+                    **{k: jnp.asarray(v) for k, v in spread_host.items()},
+                    decisions=jnp.zeros((C,), jnp.int32),
+                    decisions_bound=jnp.zeros((C,), jnp.int32),
+                )
+            )
         # Static (lo, hi) device-slot bounds covering every pod-group slot:
         # the HPA pass only touches group slots, so its body (victim sort
         # included) and its not-due cond carry run on this slice instead of
@@ -3488,14 +3568,16 @@ class BatchedSimulation:
         self.use_pallas_select = (
             self.use_pallas_select
             and select_kernel_fits(
-                self.n_nodes, self.n_pods, self.max_pods_per_cycle
+                self.n_nodes, self.n_pods, self.max_pods_per_cycle,
+                self._spread_shape,
             )
         )
         self.use_megakernel = (
             self.use_megakernel
             and self.use_pallas_select
             and select_commit_kernel_fits(
-                self.n_nodes, self.n_pods, self.max_pods_per_cycle
+                self.n_nodes, self.n_pods, self.max_pods_per_cycle,
+                self._spread_shape,
             )
         )
         import logging
@@ -3750,6 +3832,22 @@ class BatchedSimulation:
         the geometry (or a restored checkpoint) left it."""
         return {**self.statics.as_dict(), "reclaim": self.reclaim}
 
+    def _spread_counters(self) -> Dict[str, int]:  # ktpu: sync-ok(readout: the spread filter's two (C,) counters, read with the metrics)
+        """The spread filter's counters summed over clusters ({} in a build
+        without it), also left on this engine's tracer handle so that
+        telemetry_report() carries them."""
+        spread = self.state.spread
+        if spread is None:
+            return {}
+        from kubernetriks_tpu.parallel.multihost import to_host
+
+        got = {
+            "spread_decisions": int(np.asarray(to_host(spread.decisions)).sum()),
+            "spread_decisions_bound": int(np.asarray(to_host(spread.decisions_bound)).sum()),
+        }
+        self.tracer.counters.update(got)
+        return got
+
     def metrics_summary(self) -> Dict:  # ktpu: sync-ok(readout: one-shot cross-cluster metric reduction after the run)
         """Cross-cluster reduction into the scalar printer's shape. On a
         cross-process mesh the metric arrays allgather over DCN first.
@@ -3767,6 +3865,7 @@ class BatchedSimulation:
         counters["frees_total"] = int(np.asarray(m.frees_total).sum())
         counters["frees_deferred"] = int(np.asarray(m.frees_deferred).sum())
         counters["event_windows"] = int(np.asarray(m.event_windows).max())
+        counters.update(self._spread_counters())
 
         def est(e):
             count = np.asarray(e.count, np.int64)
@@ -4144,6 +4243,7 @@ class BatchedSimulation:
             )
         stats = dict(self.dispatch_stats)
         rep = {"enabled": self._telemetry, "dispatch_stats": stats}
+        self._spread_counters()
         rep.update(self.tracer.report())
         if feeder_rep is not None:
             # Streaming-feeder section: production counters, the

@@ -1,6 +1,9 @@
 """Compiled scheduler-profile pipeline: the device-plugin subsystem that
 lowers a KubeScheduler profile (ordered filter refs + weighted score refs)
-into the batched hot path.
+into the batched hot path. The device registry below lowers every built-in of
+the scalar registry (core/scheduler/plugins.py): the filters Fit and
+PodTopologySpread, the scorers LeastAllocatedResources, MostAllocatedResources
+and BalancedResourceAllocation.
 
 The scalar path interprets profiles per pod through the plugin registry
 (core/scheduler/plugins.py, kube_scheduler.py). The batched path cannot —
@@ -32,6 +35,15 @@ the default profile, and against the scalar oracle for every profile by
 tests/test_random_equivalence.py):
 
 - Filters AND into the alive mask (scalar: list comprehension chain).
+- PodTopologySpread (DoNotSchedule; semantics in core/scheduler/plugins.py
+  and docs/PARITY.md) is the one filter that reads more than the node and
+  the pod: a (G workloads x Z domains) table of matching placed pods, which
+  the decision core carries from one placement of a cycle to the next
+  beside the allocatables. `spread_zone_ok` / `spread_node_mask` /
+  `spread_place` below are its ONE definition, in the kernels' layout
+  (domains and nodes on axis 0, clusters on axis 1); the scan body feeds
+  them transposed arrays. A build none of whose pods is held to a
+  constraint carries no table and traces none of it.
 - Scores are float32, summed over scorers after weighting; a weight of
   exactly 1.0 skips the multiply so the default profile's expression tree
   is textually identical to the historical hard-fused one.
@@ -55,6 +67,7 @@ from __future__ import annotations
 import logging
 from typing import Callable, Dict, NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -68,6 +81,7 @@ from kubernetriks_tpu.core.scheduler.plugins import (
     FIT,
     LEAST_ALLOCATED,
     MOST_ALLOCATED,
+    TOPOLOGY_SPREAD,
 )
 
 _NEG_INF = float(np.float32(-np.inf))
@@ -101,14 +115,22 @@ def _zero(x):
 
 
 # --- device plugin registry ---------------------------------------------------
-# Filters: fn(cpu, ram, rc, rr) -> bool mask (AND-composed onto `alive`).
-# Scorers: fn(cpu, ram, rc, rr) -> float32 score (summed after weighting).
-# cpu/ram are the nodes' current allocatable, rc/rr the candidate's requests;
-# any broadcast-compatible shapes (the scan path and the kernels differ).
+# Filters: fn(cpu, ram, rc, rr, spread_ok) -> bool mask (AND-composed onto
+# `alive`), or None for "passes every node". Scorers: fn(cpu, ram, rc, rr) ->
+# float32 score (summed after weighting). cpu/ram are the nodes' current
+# allocatable, rc/rr the candidate's requests; any broadcast-compatible shapes
+# (the scan path and the kernels differ). `spread_ok` is what a filter may
+# know of where other pods sit: the node mask the decision core derived from
+# the carried spread table for this candidate (spread_node_mask), None in a
+# build that carries no table.
 
 
-def _filter_fit(cpu, ram, rc, rr):
+def _filter_fit(cpu, ram, rc, rr, spread_ok):
     return (rc <= cpu) & (rr <= ram)
+
+
+def _filter_topology_spread(cpu, ram, rc, rr, spread_ok):
+    return spread_ok
 
 
 def _score_least_allocated(cpu, ram, rc, rr):
@@ -167,6 +189,7 @@ def _score_balanced(cpu, ram, rc, rr):
 
 DEVICE_FILTER_PLUGINS: Dict[str, Callable] = {
     FIT: _filter_fit,
+    TOPOLOGY_SPREAD: _filter_topology_spread,
 }
 
 DEVICE_SCORE_PLUGINS: Dict[str, Callable] = {
@@ -183,6 +206,14 @@ DEFAULT_PROFILE = CompiledProfile(
     filters=(FIT,),
     scores=((LEAST_ALLOCATED, 1.0),),
 )
+
+
+def _supported() -> str:
+    return (
+        f"the batched path supports filters {sorted(DEVICE_FILTER_PLUGINS)} and scorers "
+        f"{sorted(DEVICE_SCORE_PLUGINS)} (kubernetriks_tpu/batched/pipeline.py); run the scalar "
+        "backend for scalar-only plugins"
+    )
 
 
 def compile_profile(spec=None) -> CompiledProfile:
@@ -217,21 +248,13 @@ def compile_profile(spec=None) -> CompiledProfile:
         if fname not in DEVICE_FILTER_PLUGINS:
             raise UnsupportedProfileError(
                 f"scheduler profile {prof.name!r}: filter plugin {fname!r} "
-                f"has no device lowering — the batched path supports "
-                f"filters {sorted(DEVICE_FILTER_PLUGINS)} and scorers "
-                f"{sorted(DEVICE_SCORE_PLUGINS)} "
-                f"(kubernetriks_tpu/batched/pipeline.py); run the scalar "
-                f"backend for scalar-only plugins"
+                f"has no device lowering — {_supported()}"
             )
     for sname, weight in prof.scores:
         if sname not in DEVICE_SCORE_PLUGINS:
             raise UnsupportedProfileError(
                 f"scheduler profile {prof.name!r}: score plugin {sname!r} "
-                f"has no device lowering — the batched path supports "
-                f"filters {sorted(DEVICE_FILTER_PLUGINS)} and scorers "
-                f"{sorted(DEVICE_SCORE_PLUGINS)} "
-                f"(kubernetriks_tpu/batched/pipeline.py); run the scalar "
-                f"backend for scalar-only plugins"
+                f"has no device lowering — {_supported()}"
             )
         if not (weight > 0.0) or not np.isfinite(weight):
             # Scalar NaN-score semantics survive any positive weight; a
@@ -262,13 +285,88 @@ def to_kube_scheduler_config(profile: CompiledProfile) -> KubeSchedulerConfig:
 # --- compiled expressions -----------------------------------------------------
 
 
-def profile_fit_mask(profile: CompiledProfile, alive, cpu, ram, rc, rr):
+def profile_fit_mask(profile: CompiledProfile, alive, cpu, ram, rc, rr, spread_ok=None):
     """The profile's filter chain ANDed onto the alive mask. Elementwise;
     usable in the scan body and inside Mosaic kernels."""
     fit = alive
     for fname in profile.filters:
-        fit = fit & DEVICE_FILTER_PLUGINS[fname](cpu, ram, rc, rr)
+        mask = DEVICE_FILTER_PLUGINS[fname](cpu, ram, rc, rr, spread_ok)
+        if mask is not None:
+            fit = fit & mask
     return fit
+
+
+# --- PodTopologySpread: the carried table --------------------------------------
+# Kernel layout throughout: a workload's per-domain match counts are ONE
+# (SPREAD_ZONE_TILE, L) tile (domains on sublanes, clusters on lanes; domains
+# past the build's Z are never alive), the table a list of G such tiles;
+# `group` / `bits` are the candidate's (1, L) workload (-1: no constraint) and
+# match bits. Static Python loops over G and Z, typed literals only: the same
+# code runs in the scan body and inside the Mosaic kernels.
+
+SPREAD_ZONE_TILE = 8
+
+
+def uses_spread(profile: CompiledProfile) -> bool:
+    return TOPOLOGY_SPREAD in profile.filters
+
+
+def spread_tiles(table):
+    """(C, G, Z) -> G tiles of (SPREAD_ZONE_TILE, C) int32: the layout the
+    three functions below work in (the kernels stack the tiles into one
+    block, the scan body carries them as they are)."""
+    pad = ((0, 0), (0, 0), (0, SPREAD_ZONE_TILE - table.shape[2]))
+    padded = jnp.pad(table.astype(jnp.int32), pad)
+    return tuple(padded[:, g, :].T for g in range(table.shape[1]))
+
+
+def spread_alive_tile(zone_alive):
+    """(C, Z) bool -> (SPREAD_ZONE_TILE, C) bool; padded domains are dead."""
+    return jnp.pad(zone_alive, ((0, 0), (0, SPREAD_ZONE_TILE - zone_alive.shape[1]))).T
+
+
+def spread_zone_ok(tiles, limits, zone_alive, group, bits):
+    """(zone_ok (8, L) bool, constrained (1, L) bool, closed (1, L) bool) for
+    one candidate a lane: the domains its constraint leaves open
+    (match(d) + self - minMatch <= maxSkew over the live domains), whether it
+    carries one, and whether the skew closed any live domain."""
+    i0, i1 = jnp.int32(0), jnp.int32(1)
+    big = jnp.int32(2**31 - 1)
+    cnt = jnp.zeros_like(tiles[0])
+    lim = jnp.zeros_like(tiles[0])
+    own = jnp.zeros_like(group)
+    for g, (tile, limit) in enumerate(zip(tiles, limits)):
+        pick = group == jnp.int32(g)
+        cnt = jnp.where(pick, tile, cnt)
+        lim = jnp.where(pick, limit, lim)
+        own = jnp.where(pick, (bits >> jnp.int32(g)) & i1, own)
+    constrained = group >= i0
+    least = jnp.min(jnp.where(zone_alive, cnt, big), axis=0, keepdims=True)
+    zone_ok = zone_alive & (cnt + own - least <= lim)
+    shut = jnp.max((zone_alive & ~zone_ok).astype(jnp.int32), axis=0, keepdims=True) > i0
+    return zone_ok, constrained, constrained & shut
+
+
+def spread_node_mask(domain, zone_ok, constrained, n_domains: int):
+    """(Np, L) the nodes the candidate's constraint admits: those whose
+    domain is open (a node without the key, domain -1, is in none); every
+    node for a candidate without a constraint."""
+    ok = ~constrained
+    for z in range(n_domains):
+        ok = ok | ((domain == jnp.int32(z)) & zone_ok[z : z + 1, :])
+    return ok
+
+
+def spread_place(tiles, zbest, assign, bits):
+    """The table after one placement: +1 at the placed node's domain in
+    every workload whose selector the placed pod satisfies."""
+    i0, i1 = jnp.int32(0), jnp.int32(1)
+    iota = jax.lax.broadcasted_iota(jnp.int32, tiles[0].shape, 0)
+    hit = (iota == zbest) & assign
+    return [
+        tile + jnp.where(hit & (((bits >> jnp.int32(g)) & i1) != i0), i1, i0)
+        for g, tile in enumerate(tiles)
+    ]
 
 
 def profile_score(profile: CompiledProfile, fit, cpu, ram, rc, rr):
@@ -400,11 +498,11 @@ def exact_best_node(hi, lo, node_ok, iota, axis: int):
     )
 
 
-def profile_fit_score(profile: CompiledProfile, alive, cpu, ram, rc, rr):
+def profile_fit_score(profile: CompiledProfile, alive, cpu, ram, rc, rr, spread_ok=None):
     """(fit mask, masked score) in one call — the decision core both the
     lax.scan path (batched/step.py) and the Pallas kernels
     (ops/scheduler_kernel._fit_score_place) build on."""
-    fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr)
+    fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, spread_ok)
     return fit, profile_score(profile, fit, cpu, ram, rc, rr)
 
 

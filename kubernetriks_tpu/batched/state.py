@@ -236,6 +236,36 @@ class MetricArrays(NamedTuple):
     pod_duration: EstArrays
 
 
+class SpreadState(NamedTuple):
+    """The PodTopologySpread filter's device state (batched/pipeline.py;
+    semantics: core/scheduler/plugins.PodTopologySpread). Present only in a
+    build whose profile runs the filter over traces that carry a constraint;
+    None otherwise, and the window programs then trace none of it (the
+    structural idiom of `auto` and `telemetry`).
+
+    The first four are the trace's interned vocabulary
+    (trace_compile.CompiledSpread) and never change. The pod planes are in
+    GLOBAL pod-slot coordinates, the whole trace wide: the device pod window
+    reads and writes its own columns of them at `pod_base`
+    (step.spread_window_view), so no slide, refill or window growth moves
+    them. `pod_zone` is the one plane the cycle writes: the domain of the
+    node each placement went to, which spares the count pass a (C, P)
+    gather of `domain` at `pods.node` (a gather on the TPU costs per index)."""
+
+    domain: jnp.ndarray  # (C, N) int32 domain of the node slot, -1 without the key
+    # (C, G, Z) int32 maxSkew of workload g, repeated over the domains: its
+    # shape is where the programs read G and Z from.
+    max_skew: jnp.ndarray
+    pod_group: jnp.ndarray  # (C, T) int32 workload whose constraint the pod carries, -1 none
+    pod_bits: jnp.ndarray  # (C, T) int32 bit g: the pod's labels satisfy workload g's selector
+    pod_zone: jnp.ndarray  # (C, T) int32 domain of the pod's last placement, -1 never placed
+    # Always-on counters (no scalar counterpart): assignments of pods that
+    # carry a constraint, and those of them at whose instant the skew closed
+    # at least one live domain.
+    decisions: jnp.ndarray  # (C,) int32
+    decisions_bound: jnp.ndarray  # (C,) int32
+
+
 class ClusterBatchState(NamedTuple):
     """Complete batched simulation state; a pytree of arrays with leading
     cluster axis C, shardable across a device mesh on that axis."""
@@ -261,6 +291,9 @@ class ClusterBatchState(NamedTuple):
     # pre-telemetry build, the same structural-static trick `auto` and
     # `fault_params` use.
     telemetry: Optional[TelemetryRing] = None
+    # Topology-spread vocabulary, pod planes and counters (SpreadState) or
+    # None when no pod of the build is held to a constraint.
+    spread: Optional[SpreadState] = None
 
 
 # Column layout of the device-side telemetry ring (TelemetryRing.buf).
@@ -316,7 +349,11 @@ TELEM_EVENT_CHUNKS = 14
 # MetricArrays.frees_deferred): 0 on every window of a build whose
 # control-plane delays are zero.
 TELEM_FREES_DEFERRED = 15
-TELEMETRY_COLS = 16
+# Assignments this window of pods carrying a topology-spread constraint at
+# whose instant the skew closed a live domain (the growth of
+# SpreadState.decisions_bound): 0 in a build without constraints.
+TELEM_SPREAD_BOUND = 16
+TELEMETRY_COLS = 17
 
 
 class TelemetryRing(NamedTuple):
@@ -713,10 +750,13 @@ def swap_node_layout(state: "ClusterBatchState") -> "ClusterBatchState":
     (N, C). Self-inverse; everything else (pods, metrics, pending-effect
     pairs, auto, telemetry) is untouched. Exact — a transpose moves bits."""
     nodes = state.nodes
+    spread = state.spread
     return state._replace(
         nodes=nodes._replace(
             **{name: getattr(nodes, name).T for name in NODE_HOT_LEAVES}
-        )
+        ),
+        # The spread filter's node plane is read by the same kernels.
+        spread=spread if spread is None else spread._replace(domain=spread.domain.T),
     )
 
 
@@ -743,8 +783,18 @@ CLUSTER_STATE_LEAVES = (
     "metrics",
     "auto",
     "telemetry",
+    "spread",
 )
 TELEMETRY_RING_LEAVES = ("buf", "cursor")
+SPREAD_STATE_LEAVES = (
+    "domain",
+    "max_skew",
+    "pod_group",
+    "pod_bits",
+    "pod_zone",
+    "decisions",
+    "decisions_bound",
+)
 
 # StepConstants leaves that are per-lane TRACED scenario data (the
 # scenariotrace lint pass forbids them from flowing into Python control

@@ -1170,6 +1170,67 @@ def _conditional_wake_exact(
     return jnp.zeros((C, P), bool).at[rows, order].set(moved_sorted)
 
 
+def spread_window_view(spread, pod_base: jnp.ndarray, P: int):
+    """The device pod window's columns of the spread state's global pod
+    planes: (workload, match bits, placed domain), (C, P) each. A build
+    whose window is the whole trace holds planes of that width and reads
+    them as they are; a sliding build cuts one run of P columns a cluster
+    at `pod_base` (C row slices, not C x P indices)."""
+    planes = (spread.pod_group, spread.pod_bits, spread.pod_zone)
+    if planes[0].shape[1] == P:
+        return planes
+    cut = jax.vmap(lambda row, lo: jax.lax.dynamic_slice(row, (lo,), (P,)))
+    return tuple(cut(x, pod_base) for x in planes)
+
+
+def _spread_with_zone(spread, pod_base: jnp.ndarray, zone_w: jnp.ndarray):
+    """The spread state with the window's placed-domain columns written
+    back (spread_window_view's inverse for the one plane a cycle writes)."""
+    if spread.pod_zone.shape[1] == zone_w.shape[1]:
+        return spread._replace(pod_zone=zone_w)
+    put = jax.vmap(lambda row, lo, new: jax.lax.dynamic_update_slice(row, new, (lo,)))
+    return spread._replace(pod_zone=put(spread.pod_zone, pod_base, zone_w))
+
+
+@jax.named_scope("spread_counts")
+def spread_counts(state: ClusterBatchState, consts: StepConstants, lane_major: bool = False):
+    """What the PodTopologySpread filter knows at the start of a cycle
+    (semantics: core/scheduler/plugins.PodTopologySpread): counts (C, G, Z)
+    int32, the pods that hold the scheduler's allocatable (alloc_holders: so
+    a free still on the pending-free channel counts, as it does in the
+    scalar scheduler's cache) on nodes of domain z whose labels satisfy
+    workload g's selector; and zone_alive (C, Z) bool, the domains of the
+    nodes in the scheduler's cache. One masked reduction over the pod
+    window: a holder's domain was written when it was placed
+    (SpreadState.pod_zone), so nothing is gathered."""
+    sp = state.spread
+    _, G, Z = sp.max_skew.shape
+    _, bits, zone = spread_window_view(sp, state.pod_base, state.pods.phase.shape[1])
+    held = jnp.where(alloc_holders(state.pods, consts), zone, jnp.int32(-1))
+    # ONE variadic reduce, G x Z sums off the same two planes: its producers
+    # fuse into it, where one reduce of a (C, G, Z, P) broadcast leaves XLA
+    # 28 MB of predicates between three fusions (compiled for a described
+    # v5e: 32 MB a pass against 175).
+    hits = tuple(
+        ((((bits >> g) & 1) != 0) & (held == z)).astype(jnp.int32)
+        for g in range(G)
+        for z in range(Z)
+    )
+    sums = jax.lax.reduce(
+        hits,
+        (jnp.int32(0),) * len(hits),
+        lambda a, b: tuple(x + y for x, y in zip(a, b)),
+        (1,),
+    )
+    counts = jnp.stack(sums, axis=1).reshape(-1, G, Z)
+    node_axis = 0 if lane_major else 1
+    zone_alive = jnp.stack(
+        [(state.nodes.alive & (sp.domain == z_)).any(axis=node_axis) for z_ in range(Z)],
+        axis=1,
+    )
+    return counts, zone_alive
+
+
 class CycleCandidates(NamedTuple):
     """Compacted per-cycle scheduling candidates (top-K of the sorted queue);
     a pytree, so it composes with jit/scan like the rest of the state."""
@@ -1182,6 +1243,10 @@ class CycleCandidates(NamedTuple):
     req_ram: jnp.ndarray
     # (C, K) float32 queue wait at cycle start: T - initial_attempt_ts.
     waited: jnp.ndarray
+    # (C, K) the candidates' spread workload and match bits; None in a build
+    # without the spread filter.
+    spread_group: Optional[jnp.ndarray] = None
+    spread_bits: Optional[jnp.ndarray] = None
 
 
 def cycle_timing(valid, waited, pod_sched_time, consts: StepConstants):
@@ -1298,10 +1363,13 @@ def candidates_from_slots(
     valid: jnp.ndarray,
     W: jnp.ndarray,
     consts: StepConstants,
+    spread_pods=None,
 ) -> CycleCandidates:
     """Assemble CycleCandidates from chosen candidate slots — the gathers
     and the `waited` formula shared by the sorted path and the in-kernel
-    selection path (ONE definition, so the paths cannot drift)."""
+    selection path (ONE definition, so the paths cannot drift).
+    `spread_pods`: the window's (workload, match bits) planes where the
+    build runs the spread filter."""
     C = cand.shape[0]
     rows = jnp.arange(C, dtype=jnp.int32)[:, None]
     interval = jnp.float32(consts.scheduling_interval)
@@ -1316,6 +1384,13 @@ def candidates_from_slots(
         req_cpu=pods.req_cpu[rows, cand],
         req_ram=pods.req_ram[rows, cand],
         waited=waited,
+        **(
+            {}
+            if spread_pods is None
+            else dict(
+                spread_group=spread_pods[0][rows, cand], spread_bits=spread_pods[1][rows, cand]
+            )
+        ),
     )
 
 
@@ -1327,6 +1402,7 @@ def prepare_cycle(
     conditional_move: bool = False,
     wake=None,
     lane_major: bool = False,
+    spread_pods=None,
 ) -> CycleCandidates:
     """prepare_queue + queue sort + top-K compaction. W: (C,) int32 window
     index (cycle time T = W * interval)."""
@@ -1343,7 +1419,7 @@ def prepare_cycle(
 
     cand = order[:, :K]
     return candidates_from_slots(
-        pods, last_flush_win, cand, eligible[rows, cand], W, consts
+        pods, last_flush_win, cand, eligible[rows, cand], W, consts, spread_pods
     )
 
 
@@ -1599,6 +1675,27 @@ def _run_scheduling_cycle(
     ).astype(jnp.float32)
     pod_sched_time = jnp.float32(consts.time_per_node) * alive_count  # (C,)
 
+    # PodTopologySpread: the table the decision core carries through the
+    # cycle's placements, counted once here, and the window's pod planes.
+    # `spread_done(state, zone_w, stats)` closes every formulation alike.
+    sp = state.spread
+    spread_nodes = spread_pods = zone_w = None
+    if sp is not None:
+        counts, zone_alive = spread_counts(state, consts, lane_major)
+        group_w, bits_w, zone_w = spread_window_view(sp, state.pod_base, P)
+        spread_nodes = (sp.domain, counts, sp.max_skew, zone_alive)
+        spread_pods = (group_w, bits_w)
+
+    def spread_done(new_state, new_zone_w, stats):
+        # stats: (C, 2) assignments of constrained pods, and those of them
+        # for which the skew had closed a live domain.
+        return new_state._replace(
+            spread=_spread_with_zone(sp, state.pod_base, new_zone_w)._replace(
+                decisions=sp.decisions + stats[:, 0],
+                decisions_bound=sp.decisions_bound + stats[:, 1],
+            )
+        )
+
     if use_pallas and use_pallas_select and use_megakernel:
         # MEGAKERNEL path: queue selection (iterated 3-key argmin), the
         # fit/score/place cycle AND the decision commit run in ONE Pallas
@@ -1631,8 +1728,9 @@ def _run_scheduling_cycle(
             interpret=pallas_interpret,
             nodes_lane_major=lane_major,
             profile=profile,
+            spread=None if sp is None else spread_nodes + spread_pods,
         )
-        (alloc_cpu, alloc_ram, phase, node, start_tmp, park_tmp, qstats) = core(
+        (alloc_cpu, alloc_ram, phase, node, start_tmp, park_tmp, qstats, *placed) = core(
             alive,
             state.nodes.alloc_cpu,
             state.nodes.alloc_ram,
@@ -1681,12 +1779,18 @@ def _run_scheduling_cycle(
         sweep = jnp.stack(
             [qstats[:, 5], qstats[:, 6] * qstats[:, 7]], axis=-1
         ).astype(jnp.int32)
-        return commit_scattered_tail(
+        new_state = commit_scattered_tail(
             state, pods, last_flush_win, W, consts, alloc_cpu, alloc_ram,
             metrics, phase, node, start_tmp, park_tmp,
             fault_params=fault_params,
             shard_axis=shard_axis,
-        ), sweep
+        )
+        if placed:
+            zone_new, stats = placed
+            new_state = spread_done(
+                new_state, jnp.where(zone_new > jnp.int32(-2), zone_new, zone_w), stats
+            )
+        return new_state, sweep
     elif use_pallas and use_pallas_select:
         # Two-kernel fallback (KTPU_MEGAKERNEL=0): in-kernel selection+cycle,
         # commit as a second one-hot kernel — kept for A/B measurement.
@@ -1703,8 +1807,9 @@ def _run_scheduling_cycle(
             interpret=pallas_interpret,
             nodes_lane_major=lane_major,
             profile=profile,
+            spread=None if sp is None else spread_nodes + spread_pods,
         )
-        cand, cand_valid, assign_k, fitany_k, best_k, alloc_cpu, alloc_ram = core(
+        cand, cand_valid, assign_k, fitany_k, best_k, alloc_cpu, alloc_ram, *placed_k = core(
             alive,
             state.nodes.alloc_cpu,
             state.nodes.alloc_ram,
@@ -1722,7 +1827,7 @@ def _run_scheduling_cycle(
     elif use_pallas:
         cc = prepare_cycle(
             state, W, consts, max_pods_per_cycle, conditional_move, wake,
-            lane_major=lane_major,
+            lane_major=lane_major, spread_pods=spread_pods,
         )
         cand_valid, cand_req_cpu, cand_req_ram = cc.valid, cc.req_cpu, cc.req_ram
         # The (C, N)-heavy core runs as a fused VMEM kernel; the (C,)-shaped
@@ -1735,8 +1840,11 @@ def _run_scheduling_cycle(
             interpret=pallas_interpret,
             nodes_lane_major=lane_major,
             profile=profile,
+            spread=None
+            if sp is None
+            else spread_nodes + (cc.spread_group, cc.spread_bits),
         )
-        assign_k, fitany_k, best_k, alloc_cpu, alloc_ram = core(
+        assign_k, fitany_k, best_k, alloc_cpu, alloc_ram, *placed_k = core(
             alive,
             state.nodes.alloc_cpu,
             state.nodes.alloc_ram,
@@ -1748,7 +1856,7 @@ def _run_scheduling_cycle(
     else:
         cc = prepare_cycle(
             state, W, consts, max_pods_per_cycle, conditional_move, wake,
-            lane_major=lane_major,
+            lane_major=lane_major, spread_pods=spread_pods,
         )
         cand_valid, cand_req_cpu, cand_req_ram = cc.valid, cc.req_cpu, cc.req_ram
         # The scan fallback's body is (C, N)-row-major-shaped (per-row
@@ -1764,13 +1872,33 @@ def _run_scheduling_cycle(
             exact_least_allocated_key,
             profile_fit_mask,
             profile_fit_score,
+            spread_alive_tile,
+            spread_node_mask,
+            spread_place,
+            spread_tiles,
+            spread_zone_ok,
         )
 
         iota_n = jnp.arange(N, dtype=jnp.int32)[None, :]
+        tiles0 = ()
+        if sp is not None:
+            # The kernels' layout (pipeline.spread_*): domains and nodes on
+            # axis 0, clusters on axis 1; a workload's counts one (8, C) tile.
+            domain_t = sp.domain if lane_major else sp.domain.T
+            Z = counts.shape[2]
+            tiles0, limits = spread_tiles(counts), spread_tiles(sp.max_skew)
+            zalive_t = spread_alive_tile(zone_alive)
 
         def body(carry, xs):
-            alloc_cpu, alloc_ram = carry
-            valid, req_cpu, req_ram = xs
+            alloc_cpu, alloc_ram, tiles = carry
+            valid, req_cpu, req_ram, *cand_spread = xs
+            spread_ok = None
+            if sp is not None:
+                group, bits = (x[None, :] for x in cand_spread)
+                zone_ok, constrained, closed = spread_zone_ok(
+                    list(tiles), limits, zalive_t, group, bits
+                )
+                spread_ok = spread_node_mask(domain_t, zone_ok, constrained, Z).T
 
             # The compiled profile's filter mask + weighted score
             # (pipeline.py; default = Fit + LeastAllocatedResources,
@@ -1783,11 +1911,11 @@ def _run_scheduling_cycle(
             # never produce.
             nodes_and_pod = (alloc_cpu, alloc_ram, req_cpu[:, None], req_ram[:, None])
             if profile.exact_bits:
-                fit = profile_fit_mask(profile, alive_x, *nodes_and_pod)
+                fit = profile_fit_mask(profile, alive_x, *nodes_and_pod, spread_ok)
                 hi, lo = exact_least_allocated_key(fit, *nodes_and_pod, profile.exact_bits)
                 best = exact_best_node(hi, lo, True, iota_n, axis=1)[:, 0]
             else:
-                fit, score = profile_fit_score(profile, alive_x, *nodes_and_pod)
+                fit, score = profile_fit_score(profile, alive_x, *nodes_and_pod, spread_ok)
                 # Last-max-wins argmax, matching the reference's `>=` sweep
                 # over name-sorted nodes (kube_scheduler.rs:140-150).
                 best = jnp.int32(N - 1) - jax.lax.argmax(score[:, ::-1], 1, jnp.int32)
@@ -1799,11 +1927,20 @@ def _run_scheduling_cycle(
             best_c = jnp.clip(best, 0, None)
             alloc_cpu = alloc_cpu.at[rows1, best_c].add(jnp.where(assign, -req_cpu, 0))
             alloc_ram = alloc_ram.at[rows1, best_c].add(jnp.where(assign, -req_ram, 0))
-            return (alloc_cpu, alloc_ram), (assign, park, best)
+            if sp is None:
+                return (alloc_cpu, alloc_ram, tiles), (assign, park, best)
+            zbest = jnp.where(assign, domain_t[best_c, rows1], jnp.int32(-1))
+            tiles = tuple(spread_place(list(tiles), zbest[None, :], assign[None, :], bits))
+            flags = (assign & constrained[0]).astype(jnp.int32) + 2 * (
+                assign & closed[0]
+            ).astype(jnp.int32)
+            return (alloc_cpu, alloc_ram, tiles), (assign, park, best, zbest, flags)
 
         xs = (cand_valid.T, cand_req_cpu.T, cand_req_ram.T)
-        (alloc_cpu, alloc_ram), outs = jax.lax.scan(body, (acpu0, aram0), xs)
-        assign_k, park_k, best_k = (o.T for o in outs)
+        if sp is not None:
+            xs += (cc.spread_group.T, cc.spread_bits.T)
+        (alloc_cpu, alloc_ram, _), outs = jax.lax.scan(body, (acpu0, aram0, tiles0), xs)
+        assign_k, park_k, best_k, *placed_k = (o.T for o in outs)
         if lane_major:
             alloc_cpu, alloc_ram = alloc_cpu.T, alloc_ram.T
 
@@ -1815,14 +1952,28 @@ def _run_scheduling_cycle(
     metrics = decision_metrics(
         state.metrics, assign_k, pod_queue_time_k, pod_sched_time
     )
-    return commit_cycle(
+    new_state = commit_cycle(
         state, cc, W, consts, alloc_cpu, alloc_ram, metrics,
         assign_k, park_k, best_k, start_s_k, park_s_k,
         use_pallas=use_pallas and use_pallas_select,
         pallas_interpret=pallas_interpret,
         shard_axis=shard_axis,
         fault_params=fault_params,
-    ), None
+    )
+    if placed_k:
+        # The placed domains go back into the pod plane as the decisions'
+        # nodes do (commit_cycle's scatter; candidate slots are unique).
+        zbest_k, flags_k = placed_k
+        rows = jnp.arange(C, dtype=jnp.int32)[:, None]
+        new_state = spread_done(
+            new_state,
+            zone_w.at[rows, jnp.where(assign_k, cc.cand, P)].set(zbest_k, mode="drop"),
+            jnp.stack(
+                [(flags_k & 1).sum(axis=1, dtype=jnp.int32), (flags_k >> 1).sum(axis=1, dtype=jnp.int32)],
+                axis=1,
+            ),
+        )
+    return new_state, None
 
 
 def _freeze_lanes(
@@ -1860,11 +2011,21 @@ def _freeze_lanes(
             for name in nodes._fields
         }
     )
+    # The spread filter's node plane never changes and is laid out like the
+    # hot node leaves: it passes through.
+    def rest_of(st):
+        spread = st.spread
+        return st._replace(
+            nodes=None,
+            telemetry=None,
+            spread=spread if spread is None else spread._replace(domain=None),
+        )
+
     rest = jax.tree.map(
-        lambda cur, prev: keep(cur, prev, 0),
-        state._replace(nodes=None, telemetry=None),
-        state0._replace(nodes=None, telemetry=None),
+        lambda cur, prev: keep(cur, prev, 0), rest_of(state), rest_of(state0)
     )
+    if rest.spread is not None:
+        rest = rest._replace(spread=rest.spread._replace(domain=state.spread.domain))
     return rest._replace(nodes=frozen_nodes, telemetry=state.telemetry)
 
 
@@ -1878,6 +2039,7 @@ def _telemetry_record(
     lane_active=None,
     cycle_sweep=None,
     event_chunks=None,
+    spread0=None,
 ):
     """Fold one per-window record row into the device telemetry ring:
     metric-counter deltas vs the window's incoming metrics `m0` plus queue
@@ -1973,6 +2135,11 @@ def _telemetry_record(
             ),
             event_chunks if event_chunks is not None else jnp.zeros_like(W),
             m1.frees_deferred - m0.frees_deferred,
+            (
+                state.spread.decisions_bound - spread0.decisions_bound
+                if spread0 is not None
+                else jnp.zeros_like(W)
+            ),
         ],
         axis=-1,
     ).astype(jnp.int32)
@@ -2034,6 +2201,7 @@ def _window_body(
     # Telemetry ring (flight recorder): the window's incoming metric
     # counters, diffed at the end of the body into one per-window record.
     m0 = state.metrics
+    spread0 = state.spread
     # CA slot reclaim (KTPU_RECLAIM): compaction runs FIRST — a clean
     # state boundary, and a scale-up later in this window then sees every
     # reclaimable slot (the loud starvation bound can only fire on true
@@ -2173,6 +2341,7 @@ def _window_body(
                 lane_active=lane_active,
                 cycle_sweep=cycle_sweep,
                 event_chunks=event_chunks,
+                spread0=spread0,
             )
         )
     return state

@@ -74,6 +74,91 @@ def _compile_usage_model(model_config) -> Tuple[List[Tuple[float, float]], bool]
     raise ValueError(f"unknown usage model {model_config.model_name!r}")
 
 
+# What one build's static spread table holds (ops/scheduler_kernel.py keeps a
+# workload's per-domain match counts in ONE (8, 128) vreg tile and a pod's
+# match bits in an int32): a trace past either is refused by name.
+SPREAD_MAX_WORKLOADS = 16
+SPREAD_MAX_DOMAINS = 8
+
+
+@dataclass
+class CompiledSpread:
+    """One cluster's topology-spread vocabulary, interned (PodTopologySpread,
+    core/scheduler/plugins.py): strings never reach the device. Present only
+    where a pod of the trace carries a constraint."""
+
+    topology_key: str
+    domains: List[str]  # sorted label values; index = domain
+    # (sorted selector items, maxSkew), sorted; index = workload
+    workloads: List[Tuple[Tuple[Tuple[str, str], ...], int]]
+    node_domain: np.ndarray  # (N,) int32 domain of the slot's node, -1 without the key
+    pod_group: np.ndarray  # (P,) int32 workload whose constraint the pod carries, -1 none
+    pod_bits: np.ndarray  # (P,) int32 bit g set: the pod's labels satisfy workload g's selector
+    max_skew: np.ndarray  # (G,) int32
+
+
+def _compile_spread(node_labels, pods, n_nodes: int) -> Optional[CompiledSpread]:
+    """Intern the labels and constraints of one trace. `node_labels`: the
+    label dict of each node slot; `pods`: the Pod of each pod slot (None for
+    a slot no CreatePod names). Raises what PodTopologySpread refuses."""
+    from kubernetriks_tpu.core.scheduler.plugins import (
+        selector_matches,
+        supported_spread_constraint,
+    )
+
+    constraints = [None if pod is None else supported_spread_constraint(pod) for pod in pods]
+    carried = [c for c in constraints if c is not None]
+    if not carried:
+        return None
+    keys = sorted({c.topology_key for c in carried})
+    if len(keys) > 1:
+        raise ValueError(
+            f"topology-spread constraints over more than one topologyKey in one trace ({keys}) are "
+            "not supported: the batched build holds one domain plane a node"
+        )
+    key = keys[0]
+
+    def triple(c):
+        return tuple(sorted(c.match_labels.items())), int(c.max_skew)
+
+    workloads = sorted({triple(c) for c in carried})
+    domains = sorted({labels[key] for labels in node_labels if key in labels})
+    if len(workloads) > SPREAD_MAX_WORKLOADS or len(domains) > SPREAD_MAX_DOMAINS:
+        raise ValueError(
+            f"{len(workloads)} spread workloads over {len(domains)} domains of {key!r}: more workloads or "
+            f"domains than the build's static table holds ({SPREAD_MAX_WORKLOADS} workloads, "
+            f"{SPREAD_MAX_DOMAINS} domains)"
+        )
+    domain_of = {value: i for i, value in enumerate(domains)}
+    workload_of = {w: g for g, w in enumerate(workloads)}
+    bits_of_labels: Dict[Tuple, int] = {}
+
+    def bits(labels: Dict[str, str]) -> int:
+        memo = tuple(sorted(labels.items()))
+        got = bits_of_labels.get(memo)
+        if got is None:
+            got = bits_of_labels[memo] = sum(
+                1 << g for g, (selector, _) in enumerate(workloads) if selector_matches(dict(selector), labels)
+            )
+        return got
+
+    return CompiledSpread(
+        topology_key=key,
+        domains=domains,
+        workloads=workloads,
+        node_domain=np.asarray(
+            [domain_of.get(labels.get(key), -1) for labels in node_labels], np.int32
+        ).reshape(n_nodes),
+        pod_group=np.asarray(
+            [-1 if c is None else workload_of[triple(c)] for c in constraints], np.int32
+        ).reshape(len(pods)),
+        pod_bits=np.asarray(
+            [0 if pod is None else bits(pod.metadata.labels) for pod in pods], np.int32
+        ).reshape(len(pods)),
+        max_skew=np.asarray([skew for _, skew in workloads], np.int32),
+    )
+
+
 @dataclass
 class CompiledClusterTrace:
     """One cluster's compiled trace + payload tables (numpy, host-side)."""
@@ -92,6 +177,9 @@ class CompiledClusterTrace:
     # (N,) sampled repair span of each slot's chaos-engine crash event
     # (0 where the slot never crashes); None when no faults were injected.
     node_crash_downtime: Optional[np.ndarray] = None
+    # Interned labels and topology-spread constraints; None where no pod of
+    # the trace carries a constraint (labels alone intern nothing).
+    spread: Optional[CompiledSpread] = None
 
     @property
     def n_events(self) -> int:
@@ -150,6 +238,9 @@ def _node_slots_in_name_order(trace: CompiledClusterTrace) -> CompiledClusterTra
         node_cap_ram=trace.node_cap_ram[order],
         node_names=[names[slot] for slot in order],
         node_crash_downtime=None if downtime is None else downtime[order],
+        spread=None
+        if trace.spread is None
+        else dataclasses.replace(trace.spread, node_domain=trace.spread.node_domain[order]),
     )
 
 
@@ -215,6 +306,8 @@ def compile_cluster_trace(
     pod_slot: Dict[str, int] = {}
     pod_groups: List[CompiledPodGroup] = []
     node_crash_downtime: Dict[int, float] = {}
+    node_labels: List[Dict[str, str]] = []
+    pod_objects: List[object] = []
 
     for ts, _, event in merged:
         if isinstance(event, CreateNodeRequest):
@@ -225,6 +318,7 @@ def compile_cluster_trace(
             node_cap_cpu.append(int(node.status.capacity.cpu))
             node_cap_ram.append(int(node.status.capacity.ram) // ram_unit)
             node_names.append(node.metadata.name)
+            node_labels.append(node.metadata.labels)
             live_node_slot[node.metadata.name] = slot
             ev_time.append(ts)
             ev_kind.append(EV_NODE_RECOVER if event.recovered else EV_CREATE_NODE)
@@ -247,6 +341,7 @@ def compile_cluster_trace(
             duration = pod.spec.running_duration
             pod_duration.append(-1.0 if duration is None else float(duration))
             pod_names.append(pod.metadata.name)
+            pod_objects.append(pod)
             pod_slot[pod.metadata.name] = slot
             ev_time.append(ts)
             ev_kind.append(EV_CREATE_POD)
@@ -279,6 +374,12 @@ def compile_cluster_trace(
                 pod_group_slot_multiplier * group.max_pod_count
             )
             requests = template.spec.resources.requests
+            if template.spec.topology_spread_constraints:
+                raise ValueError(
+                    f"pod group {group.name!r}: topology-spread constraints on an HPA pod group's template "
+                    "are not supported (pods made at run time would need labels of their own)"
+                )
+            pod_objects.extend([None] * slot_count)
             for i in range(slot_count):
                 pod_req_cpu.append(int(requests.cpu))
                 pod_req_ram.append(-(-int(requests.ram) // ram_unit))
@@ -319,6 +420,12 @@ def compile_cluster_trace(
         crash_downtime_arr = np.zeros(len(node_cap_cpu), np.float32)
         for slot, ttr in node_crash_downtime.items():
             crash_downtime_arr[slot] = ttr
+    spread = _compile_spread(node_labels, pod_objects, len(node_cap_cpu))
+    if spread is not None and pod_groups:
+        raise ValueError(
+            "topology-spread constraints together with HPA pod groups are not supported (pods made at "
+            "run time would need labels of their own)"
+        )
 
     return _node_slots_in_name_order(CompiledClusterTrace(
         ev_time=np.asarray(ev_time, np.float64),
@@ -333,6 +440,7 @@ def compile_cluster_trace(
         pod_names=pod_names,
         pod_groups=pod_groups,
         node_crash_downtime=crash_downtime_arr,
+        spread=spread,
     ))
 
 

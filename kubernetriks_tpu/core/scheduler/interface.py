@@ -3,9 +3,12 @@
 from __future__ import annotations
 
 import enum
-from typing import Dict
+from typing import TYPE_CHECKING, Dict, Optional
 
 from kubernetriks_tpu.core.types import Node, Pod
+
+if TYPE_CHECKING:
+    from kubernetriks_tpu.core.scheduler.plugins import SchedulerCache
 
 
 class ScheduleError(enum.Enum):
@@ -23,10 +26,15 @@ class SchedulingFailure(Exception):
 
 
 class PodSchedulingAlgorithm:
-    """Any scheduler must implement schedule_one(pod, nodes) -> node name,
-    raising SchedulingFailure on error (reference:
+    """Any scheduler must implement schedule_one(pod, nodes, cache) -> node
+    name, raising SchedulingFailure on error (reference:
     src/core/scheduler/interface.rs:14-23). ``nodes`` is name-keyed; algorithms
-    must iterate in sorted-name order for determinism parity."""
+    must iterate in sorted-name order for determinism parity. ``cache`` is the
+    scheduler's cache (plugins.SchedulerCache: cached nodes and pods, and the
+    pods it has assigned to each node), for decisions that depend on where
+    other pods sit."""
 
-    def schedule_one(self, pod: Pod, nodes: Dict[str, Node]) -> str:
+    def schedule_one(
+        self, pod: Pod, nodes: Dict[str, Node], cache: "Optional[SchedulerCache]" = None
+    ) -> str:
         raise NotImplementedError

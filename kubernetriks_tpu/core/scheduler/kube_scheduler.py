@@ -18,7 +18,9 @@ from kubernetriks_tpu.core.scheduler.plugins import (
     LEAST_ALLOCATED,
     MOST_ALLOCATED,
     PLUGIN_REGISTRY,
+    SchedulerCache,
     ScorePlugin,
+    TOPOLOGY_SPREAD,
 )
 from kubernetriks_tpu.core.types import Node, Pod
 
@@ -68,6 +70,10 @@ NAMED_PROFILE_SPECS: Dict[str, tuple] = {
     # Weighted filter+score combination: pack first, but trade up to ~12.5
     # score points of tightness for an even cpu/ram drain.
     "balanced_packing": ((FIT,), ((MOST_ALLOCATED, 1.0), (BALANCED, 0.25))),
+    # The default with kube-scheduler's zone-spread filter in front of the
+    # scorer: pods that carry a topologySpreadConstraint (DoNotSchedule) are
+    # held to it, pods without one schedule as under "default".
+    "topology_spread": ((FIT, TOPOLOGY_SPREAD), ((LEAST_ALLOCATED, 1.0),)),
 }
 
 
@@ -150,10 +156,16 @@ class KubeScheduler(PodSchedulingAlgorithm):
     def __init__(self, config: Optional[KubeSchedulerConfig] = None) -> None:
         self.config = config or default_kube_scheduler_config()
 
-    def schedule_one(self, pod: Pod, nodes: Dict[str, Node]) -> str:
+    def schedule_one(
+        self, pod: Pod, nodes: Dict[str, Node], cache: Optional[SchedulerCache] = None
+    ) -> str:
         """Filter then weighted-score over name-sorted nodes; argmax keeps the
         reference's `>=` tie-break: among equal max scores the last node in
-        sorted-name order wins (reference: src/core/scheduler/kube_scheduler.rs:63-152)."""
+        sorted-name order wins (reference: src/core/scheduler/kube_scheduler.rs:63-152).
+        `cache` is the scheduler's cache the filters may read; a caller
+        without one gets a cache of these nodes and no placed pod."""
+        if cache is None:
+            cache = SchedulerCache(nodes=nodes)
         requests = pod.spec.resources.requests
         if requests.cpu == 0 and requests.ram == 0:
             raise SchedulingFailure(ScheduleError.REQUESTED_RESOURCES_ARE_ZEROS)
@@ -169,7 +181,7 @@ class KubeScheduler(PodSchedulingAlgorithm):
             assert isinstance(plugin, FilterPlugin), (
                 f"{filter_ref.name!r} plugin is not a FilterPlugin"
             )
-            filtered_nodes = plugin.filter(pod, filtered_nodes)
+            filtered_nodes = plugin.filter(pod, filtered_nodes, cache)
 
         if not filtered_nodes:
             raise SchedulingFailure(ScheduleError.NO_SUFFICIENT_RESOURCES)

@@ -30,6 +30,7 @@ from kubernetriks_tpu.core.scheduler.model import (
     ConstantTimePerNodeModel,
     PodSchedulingTimeModel,
 )
+from kubernetriks_tpu.core.scheduler.plugins import SchedulerCache
 from kubernetriks_tpu.core.scheduler.queue import (
     ActiveQueue,
     DEFAULT_POD_MAX_IN_UNSCHEDULABLE_PODS_DURATION,
@@ -118,7 +119,13 @@ class Scheduler(EventHandler):
         node.status.allocatable.ram += pod.spec.resources.requests.ram
 
     def schedule_one(self, pod: Pod) -> str:
-        return self.scheduler_algorithm.schedule_one(pod, self.objects_cache.nodes)
+        return self.scheduler_algorithm.schedule_one(
+            pod,
+            self.objects_cache.nodes,
+            SchedulerCache(
+                self.objects_cache.nodes, self.objects_cache.pods, self.assignments
+            ),
+        )
 
     # --- queue movement -----------------------------------------------------
 

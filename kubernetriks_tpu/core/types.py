@@ -246,6 +246,71 @@ class Resources:
     usage_model_config: Optional[RuntimeResourcesUsageModelConfig] = None
 
 
+# Upstream spells these keys in camelCase, the trace vocabulary here in
+# snake_case; both are read, snake_case is written.
+_SPREAD_KEYS = {
+    "maxSkew": "max_skew",
+    "topologyKey": "topology_key",
+    "whenUnsatisfiable": "when_unsatisfiable",
+    "labelSelector": "label_selector",
+    "matchLabels": "match_labels",
+    "matchExpressions": "match_expressions",
+    "minDomains": "min_domains",
+    "matchLabelKeys": "match_label_keys",
+}
+
+
+def _snake(d: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    return {_SPREAD_KEYS.get(k, k): v for k, v in (d or {}).items()}
+
+
+@dataclass
+class TopologySpreadConstraint:
+    """One entry of a pod's `spec.topology_spread_constraints` (upstream
+    `topologySpreadConstraints`). Everything upstream's entry can say is
+    kept, so that what the scheduler does not implement is REFUSED by name
+    where it is used (core/scheduler/plugins.supported_spread_constraint),
+    never dropped at parse."""
+
+    max_skew: int = 1
+    topology_key: str = ""
+    when_unsatisfiable: str = "DoNotSchedule"
+    match_labels: Dict[str, str] = field(default_factory=dict)
+    match_expressions: List[Any] = field(default_factory=list)
+    min_domains: Optional[int] = None
+    match_label_keys: List[str] = field(default_factory=list)
+
+    @staticmethod
+    def from_dict(d: Dict[str, Any]) -> "TopologySpreadConstraint":
+        d = _snake(d)
+        selector = _snake(d.get("label_selector"))
+        return TopologySpreadConstraint(
+            max_skew=int(d.get("max_skew", 1)),
+            topology_key=str(d.get("topology_key", "")),
+            when_unsatisfiable=str(d.get("when_unsatisfiable", "DoNotSchedule")),
+            match_labels={str(k): str(v) for k, v in (selector.get("match_labels") or {}).items()},
+            match_expressions=list(selector.get("match_expressions") or []),
+            min_domains=d.get("min_domains"),
+            match_label_keys=list(d.get("match_label_keys") or []),
+        )
+
+    def to_dict(self) -> Dict[str, Any]:
+        selector: Dict[str, Any] = {"match_labels": dict(self.match_labels)}
+        if self.match_expressions:
+            selector["match_expressions"] = list(self.match_expressions)
+        out: Dict[str, Any] = {
+            "max_skew": self.max_skew,
+            "topology_key": self.topology_key,
+            "when_unsatisfiable": self.when_unsatisfiable,
+            "label_selector": selector,
+        }
+        if self.min_domains is not None:
+            out["min_domains"] = self.min_domains
+        if self.match_label_keys:
+            out["match_label_keys"] = list(self.match_label_keys)
+        return out
+
+
 @dataclass
 class PodSpec:
     """running_duration=None means an infinitely long-running service
@@ -253,6 +318,10 @@ class PodSpec:
 
     resources: Resources = field(default_factory=Resources)
     running_duration: Optional[float] = None
+    # The scheduler's PodTopologySpread filter reads these against the
+    # nodes' and the placed pods' metadata.labels; immutable once parsed
+    # (copies share the list).
+    topology_spread_constraints: List[TopologySpreadConstraint] = field(default_factory=list)
 
 
 @dataclass
@@ -308,6 +377,7 @@ class Pod:
                     usage_model_config=self.spec.resources.usage_model_config,
                 ),
                 running_duration=self.spec.running_duration,
+                topology_spread_constraints=self.spec.topology_spread_constraints,
             ),
             status=PodStatus(
                 start_time=self.status.start_time,
@@ -334,8 +404,37 @@ class Pod:
                     ),
                 ),
                 running_duration=spec.get("running_duration"),
+                topology_spread_constraints=[
+                    TopologySpreadConstraint.from_dict(c)
+                    for c in spec.get("topology_spread_constraints")
+                    or spec.get("topologySpreadConstraints")
+                    or []
+                ],
             ),
         )
+
+    def to_dict(self) -> Dict[str, Any]:
+        """The pod as the generic trace's `!CreatePod {pod: ...}` carries it
+        (`from_dict` reads it back equal)."""
+        resources: Dict[str, Any] = {
+            "requests": self.spec.resources.requests.to_dict(),
+            "limits": self.spec.resources.limits.to_dict(),
+        }
+        umc = self.spec.resources.usage_model_config
+        if umc is not None:
+            resources["usage_model_config"] = {
+                key: cfg.to_dict()
+                for key, cfg in (("cpu_config", umc.cpu_config), ("ram_config", umc.ram_config))
+                if cfg is not None
+            }
+        spec: Dict[str, Any] = {"resources": resources}
+        if self.spec.running_duration is not None:
+            spec["running_duration"] = self.spec.running_duration
+        if self.spec.topology_spread_constraints:
+            spec["topology_spread_constraints"] = [
+                c.to_dict() for c in self.spec.topology_spread_constraints
+            ]
+        return {"metadata": self.metadata.to_dict(), "spec": spec}
 
 
 @dataclass

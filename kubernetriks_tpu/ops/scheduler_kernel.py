@@ -50,10 +50,16 @@ from jax.experimental.pallas import tpu as pltpu
 # batched/state.py), so kernel-only users still dodge the x64 config flip.
 from kubernetriks_tpu.batched.pipeline import (
     DEFAULT_PROFILE,
+    SPREAD_ZONE_TILE,
     exact_best_node,
     exact_least_allocated_key,
     profile_fit_mask,
     profile_fit_score,
+    spread_alive_tile,
+    spread_node_mask,
+    spread_place,
+    spread_tiles,
+    spread_zone_ok,
 )
 
 _NEG_INF = float(np.float32(-np.inf))
@@ -108,17 +114,82 @@ def _unprep_node(x, lane_major: bool, n: int, c: int):
     return out if lane_major else out.T
 
 
-def kernel_fits(n_nodes: int, k_pods: int) -> bool:
-    """Whether one grid program's VMEM blocks (5 node blocks of (Np, 128) +
-    6 candidate blocks of (Kp, 128), all int32) fit the budget; callers fall
-    back to the lax.scan formulation when they don't."""
+def _spread_table_rows(spread_shape) -> int:
+    """Rows of the spread filter's small blocks in a decision kernel of a
+    build of `spread_shape` = (G, Z), 0 for a build without it: the count
+    table in and out and its limits (G tiles each), the live domains and a
+    stats tile."""
+    if spread_shape is None:
+        return 0
+    return (3 * spread_shape[0] + 2) * SPREAD_ZONE_TILE
+
+
+def kernel_fits(n_nodes: int, k_pods: int, spread_shape=None) -> bool:
+    """Whether one grid program's VMEM blocks (3 node blocks in and 2 out of
+    (Np, 128), 3 candidate blocks in and 3 out of (Kp, 128), all int32; with
+    the spread filter one node block, four candidate blocks and the table
+    more) fit the budget; callers fall back to the lax.scan
+    formulation when they don't."""
     np_pad = -(-n_nodes // _SUB) * _SUB
     kp_pad = -(-k_pods // _SUB) * _SUB
-    resident = (5 * np_pad + 6 * kp_pad) * _LANE * 4
+    s = int(spread_shape is not None)
+    resident = (
+        (5 + s) * np_pad + (6 + 4 * s) * kp_pad + _spread_table_rows(spread_shape)
+    ) * _LANE * 4
     return resident <= _VMEM_BUDGET_BYTES
 
 
-def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid):
+def _spread_tiles(ref, n_workloads: int):
+    return [
+        ref[g * SPREAD_ZONE_TILE : (g + 1) * SPREAD_ZONE_TILE, :]
+        for g in range(n_workloads)
+    ]
+
+
+def _spread_in_specs(spread_shape, node_spec, side_spec, side_blocks: int):
+    """BlockSpecs of the spread filter's operands, in the order every
+    decision kernel takes them after its own: the domain plane, the count
+    table, the limits, the live domains, then the pod's (or candidate's)
+    workload and match bits."""
+    rows = spread_shape[0] * SPREAD_ZONE_TILE
+    table = pl.BlockSpec((rows, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
+    tile = pl.BlockSpec((SPREAD_ZONE_TILE, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
+    return [node_spec, table, table, tile] + [side_spec] * side_blocks, table, tile
+
+
+def _spread_operands(spread, nodes_lane_major: bool, Np: int, Cp: int, side_rows: int, node_spec, side_spec):
+    """What a decision kernel's wrapper adds to its pallas_call for the
+    spread filter: ((G, Z), the padded operands, their in_specs, the table's
+    and a tile's BlockSpec and the table's shape), all empty for
+    `spread` None. `spread` = (domain (C, N) | (N, C), counts (C, G, Z),
+    limits (C, G, Z), zone_alive (C, Z), and the pods' or candidates'
+    workload and match bits, `side_rows` rows in the kernel layout). Padded
+    nodes carry no key, padded domains are never alive, padded lanes hold
+    nothing."""
+    if spread is None:
+        return None, (), [], None, None, None
+    domain, counts, limits, zone_alive, group, bits = spread
+
+    def table(x):
+        return _pad_axis(jnp.concatenate(spread_tiles(x), axis=0), 1, Cp, 0)
+
+    def side(x, fill):
+        return _pad_axis(_pad_axis(x.astype(jnp.int32).T, 0, side_rows, fill), 1, Cp, fill)
+
+    args = (
+        _prep_node(domain, nodes_lane_major, Np, Cp, -1),
+        table(counts),
+        table(limits),
+        _pad_axis(spread_alive_tile(zone_alive).astype(jnp.int32), 1, Cp, 0),
+        side(group, -1),
+        side(bits, 0),
+    )
+    shape = tuple(counts.shape[1:])
+    in_specs, table_spec, tile_spec = _spread_in_specs(shape, node_spec, side_spec, 2)
+    return shape, args, in_specs, table_spec, tile_spec, jax.ShapeDtypeStruct(args[1].shape, jnp.int32)
+
+
+def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, spread=None):
     """ONE in-kernel definition of the per-candidate decision core shared by
     _cycle_kernel, _select_cycle_kernel and _select_cycle_commit_kernel:
     the compiled profile's filter mask + weighted score
@@ -131,16 +202,30 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid):
     expressions inline into the kernel body like the shape statics do.
     Inputs: (Np, LC) node tiles, (1, LC) candidate requests/validity.
     Returns (assign (1, LC) bool, any_fit (1, LC) bool, best (1, LC) i32,
-    new_cpu (Np, LC), new_ram (Np, LC))."""
+    new_cpu (Np, LC), new_ram (Np, LC)).
+
+    `spread` (a build whose pods are held to topology-spread constraints;
+    pipeline.spread_*) = (domain (Np, LC) node plane, the count table's G
+    tiles, the limits' G tiles, zone_alive (8, LC) bool, n_domains, the
+    candidate's workload and match bits (1, LC)): the SECOND thing the core
+    carries across a cycle's placements. The table is read by this
+    candidate's filter and returned with its placement added; a sixth result
+    then follows the five: (new tiles, the placed node's domain (1, LC),
+    assigned & constrained, assigned & a live domain was closed)."""
     i0 = jnp.int32(0)
     neg1 = jnp.int32(-1)
 
+    spread_ok = None
+    if spread is not None:
+        domain, tiles, limits, zone_alive, n_domains, group, bits = spread
+        zone_ok, constrained, closed = spread_zone_ok(tiles, limits, zone_alive, group, bits)
+        spread_ok = spread_node_mask(domain, zone_ok, constrained, n_domains)
     if profile.exact_bits:
-        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr)
+        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, spread_ok)
         hi, lo = exact_least_allocated_key(fit, cpu, ram, rc, rr, profile.exact_bits)
         best = exact_best_node(hi, lo, node_ok, iota_n, axis=0)
     else:
-        fit, score = profile_fit_score(profile, alive, cpu, ram, rc, rr)
+        fit, score = profile_fit_score(profile, alive, cpu, ram, rc, rr, spread_ok)
         max_score = jnp.max(score, axis=0, keepdims=True)
         best = jnp.max(
             jnp.where((score == max_score) & node_ok, iota_n, neg1),
@@ -154,25 +239,70 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid):
     upd = assign & (iota_n == best)
     new_cpu = cpu - jnp.where(upd, rc, i0)
     new_ram = ram - jnp.where(upd, rr, i0)
-    return assign, any_fit, best, new_cpu, new_ram
+    if spread is None:
+        return assign, any_fit, best, new_cpu, new_ram
+    zbest = jnp.max(jnp.where(upd, domain, neg1), axis=0, keepdims=True)
+    placed = (spread_place(tiles, zbest, assign, bits), zbest, assign & constrained, assign & closed)
+    return assign, any_fit, best, new_cpu, new_ram, placed
+
+
+def _spread_step(refs, n_workloads: int, n_domains: int, group, bits):
+    """The `spread` argument of _fit_score_place from a kernel's refs
+    (domain, the carried table, limits, live domains) and the candidate's
+    workload and bits."""
+    domain_ref, table_ref, limit_ref, zalive_ref = refs
+    return (
+        domain_ref[:],
+        _spread_tiles(table_ref, n_workloads),
+        _spread_tiles(limit_ref, n_workloads),
+        zalive_ref[:] != jnp.int32(0),
+        n_domains,
+        group,
+        bits,
+    )
+
+
+def _spread_store(table_ref, tiles) -> None:
+    for g, tile in enumerate(tiles):
+        table_ref[g * SPREAD_ZONE_TILE : (g + 1) * SPREAD_ZONE_TILE, :] = tile
+
+
+def _spread_store_decision(table_ref, zbest_out, sflag_out, k, placed) -> None:
+    """Candidate k's spread results of _fit_score_place into the kernel's
+    outputs: the table, the placed domain, and the two facts as one int32
+    (bit 0 the assigned pod carried a constraint, bit 1 the skew had closed
+    a live domain for it)."""
+    tiles, zbest, constrained, closed = placed
+    _spread_store(table_ref, tiles)
+    zbest_out[pl.ds(k, 1), :] = zbest
+    sflag_out[pl.ds(k, 1), :] = constrained.astype(jnp.int32) + jnp.int32(2) * closed.astype(
+        jnp.int32
+    )
 
 
 def _cycle_kernel(
     n_real: int,
     k_pods: int,
     profile,        # pipeline.CompiledProfile (kernel static)
+    spread_shape,   # (G, Z) static, None without the spread filter
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
     valid_ref,      # (Kp, LC) int32
     req_cpu_ref,    # (Kp, LC) int32
     req_ram_ref,    # (Kp, LC) int32
-    cpu_out,        # (Np, LC) int32
-    ram_out,        # (Np, LC) int32
-    assign_out,     # (Kp, LC) int32
-    fitany_out,     # (Kp, LC) int32
-    best_out,       # (Kp, LC) int32
+    *refs,
 ):
+    # refs: with the spread filter the inputs domain (Np, LC), table and
+    # limits (G*8, LC), live domains (8, LC), the candidates' workload and
+    # match bits (Kp, LC); then the outputs cpu, ram (Np, LC), assign,
+    # fitany, best (Kp, LC); with the filter the carried table (G*8, LC) and
+    # the candidates' placed domain and flags (Kp, LC).
+    if spread_shape is not None:
+        domain_ref, table_in, limit_ref, zalive_ref, cgroup_ref, cbits_ref = refs[:6]
+        refs = refs[6:]
+        table_out, zbest_out, sflag_out = refs[5:]
+    cpu_out, ram_out, assign_out, fitany_out, best_out = refs[:5]
     # All literals are explicitly typed: with jax_enable_x64 on (the batched
     # path's time arrays are f64), bare Python scalars trace as weak i64/f64
     # constants, which Mosaic cannot lower inside the kernel.
@@ -188,6 +318,10 @@ def _cycle_kernel(
     assign_out[:] = jnp.zeros_like(assign_out)
     fitany_out[:] = jnp.zeros_like(fitany_out)
     best_out[:] = jnp.zeros_like(best_out)
+    if spread_shape is not None:
+        table_out[:] = table_in[:]
+        zbest_out[:] = jnp.zeros_like(zbest_out)
+        sflag_out[:] = jnp.zeros_like(sflag_out)
 
     # The loop only needs to reach the tile's last valid candidate — a
     # data-dependent early exit the lax.scan formulation cannot express.
@@ -204,10 +338,18 @@ def _cycle_kernel(
         req_ram = req_ram_ref[pl.ds(k, 1), :]
         valid = valid_ref[pl.ds(k, 1), :] != i0
 
-        assign, any_fit, best, new_cpu, new_ram = _fit_score_place(
+        spread = None
+        if spread_shape is not None:
+            spread = _spread_step(
+                (domain_ref, table_out, limit_ref, zalive_ref), *spread_shape,
+                cgroup_ref[pl.ds(k, 1), :], cbits_ref[pl.ds(k, 1), :],
+            )
+        assign, any_fit, best, new_cpu, new_ram, *placed = _fit_score_place(
             profile, alive, node_ok, iota, cpu_out[:], ram_out[:],
-            req_cpu, req_ram, valid,
+            req_cpu, req_ram, valid, spread,
         )
+        if placed:
+            _spread_store_decision(table_out, zbest_out, sflag_out, k, placed[0])
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         assign_out[pl.ds(k, 1), :] = assign.astype(jnp.int32)
@@ -230,17 +372,23 @@ def _cycle_kernel(
 _SELECT_VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def select_kernel_fits(n_nodes: int, n_pods: int, k_pods: int) -> bool:
+def select_kernel_fits(n_nodes: int, n_pods: int, k_pods: int, spread_shape=None) -> bool:
     """Whether the selection+cycle kernel's VMEM blocks fit: 6 pod blocks of
-    (Pp, 128) + 5 node blocks + 5 candidate output blocks + 1 pod scratch,
-    all int32, double-buffered across grid programs by Mosaic. The pod
-    blocks dominate; the budget is more generous than the candidate
-    kernel's because this kernel REPLACES the (C, P) lexsort and gathers,
-    so its win grows with P (v5e VMEM is ~128 MiB/core)."""
+    (Pp, 128) in + 1 pod scratch, 3 node blocks in + 2 out, 5 candidate
+    output blocks, all int32, double-buffered across grid programs by
+    Mosaic; with the spread filter one node block, two pod blocks, two
+    candidate output blocks and the table more. The pod blocks dominate; the budget is more
+    generous than the candidate kernel's because this kernel REPLACES the
+    (C, P) lexsort and gathers, so its win grows with P (v5e VMEM is
+    ~128 MiB/core)."""
     np_pad = -(-n_nodes // _SUB) * _SUB
     pp_pad = -(-n_pods // _SUB) * _SUB
     kp_pad = -(-k_pods // _SUB) * _SUB
-    resident = (5 * np_pad + 7 * pp_pad + 5 * kp_pad) * _LANE * 4
+    s = int(spread_shape is not None)
+    resident = (
+        (5 + s) * np_pad + (7 + 2 * s) * pp_pad + (5 + 2 * s) * kp_pad
+        + _spread_table_rows(spread_shape)
+    ) * _LANE * 4
     return 2 * resident <= int(0.8 * _SELECT_VMEM_LIMIT)
 
 
@@ -248,6 +396,7 @@ def _select_cycle_kernel(
     n_nodes: int,
     k_pods: int,
     profile,        # pipeline.CompiledProfile (kernel static)
+    spread_shape,   # (G, Z) static, None without the spread filter
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -258,15 +407,20 @@ def _select_cycle_kernel(
     qseq_ref,       # (Pp, LC) int32
     preq_cpu_ref,   # (Pp, LC) int32
     preq_ram_ref,   # (Pp, LC) int32
-    cpu_out,        # (Np, LC) int32
-    ram_out,        # (Np, LC) int32
-    cand_out,       # (Kp, LC) int32 selected pod slot
-    valid_out,      # (Kp, LC) int32
-    assign_out,     # (Kp, LC) int32
-    fitany_out,     # (Kp, LC) int32
-    best_out,       # (Kp, LC) int32
-    rem_ref,        # (Pp, LC) int32 scratch: not-yet-selected eligible pods
+    *refs,
 ):
+    # refs: with the spread filter the inputs domain (Np, LC), table and
+    # limits (G*8, LC), live domains (8, LC), the pods' workload and match
+    # bits (Pp, LC); then the outputs cpu, ram (Np, LC), cand (the selected
+    # pod slot), valid, assign, fitany, best (Kp, LC); with the filter the
+    # carried table (G*8, LC) and the decisions' placed domain and flags
+    # (Kp, LC); last the scratch rem (Pp, LC): not-yet-selected eligible pods.
+    if spread_shape is not None:
+        domain_ref, table_in, limit_ref, zalive_ref, pgroup_ref, pbits_ref = refs[:6]
+        refs = refs[6:]
+        table_out, zbest_out, sflag_out = refs[7:10]
+    cpu_out, ram_out, cand_out, valid_out, assign_out, fitany_out, best_out = refs[:7]
+    rem_ref = refs[-1]
     """Fused queue selection + scheduling cycle: candidate k is extracted
     IN-KERNEL by an iterated per-lane lexicographic argmin over
     (queue win, off, seq) — exactly the sorted order of the batched
@@ -292,6 +446,10 @@ def _select_cycle_kernel(
     fitany_out[:] = jnp.zeros_like(fitany_out)
     best_out[:] = jnp.zeros_like(best_out)
     rem_ref[:] = elig_ref[:]
+    if spread_shape is not None:
+        table_out[:] = table_in[:]
+        zbest_out[:] = jnp.zeros_like(zbest_out)
+        sflag_out[:] = jnp.zeros_like(sflag_out)
 
     iota_p = jax.lax.broadcasted_iota(jnp.int32, elig_ref.shape, 0)
     # Early exit: the deepest per-lane queue in this tile bounds the loop.
@@ -317,10 +475,19 @@ def _select_cycle_kernel(
         rc = jnp.max(seli * preq_cpu_ref[:], axis=0, keepdims=True)
         rr = jnp.max(seli * preq_ram_ref[:], axis=0, keepdims=True)
 
-        assign, any_fit, best, new_cpu, new_ram = _fit_score_place(
+        spread = None
+        if spread_shape is not None:
+            spread = _spread_step(
+                (domain_ref, table_out, limit_ref, zalive_ref), *spread_shape,
+                jnp.max(jnp.where(sel, pgroup_ref[:], neg1), axis=0, keepdims=True),
+                jnp.max(seli * pbits_ref[:], axis=0, keepdims=True),
+            )
+        assign, any_fit, best, new_cpu, new_ram, *placed = _fit_score_place(
             profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid,
+            rc, rr, valid, spread,
         )
+        if placed:
+            _spread_store_decision(table_out, zbest_out, sflag_out, k, placed[0])
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         cand_out[pl.ds(k, 1), :] = jnp.where(valid, slot, i0)
@@ -355,6 +522,7 @@ def fused_select_schedule_cycle(
     interpret: bool = False,
     nodes_lane_major: bool = False,
     profile=None,  # pipeline.CompiledProfile; None = the default profile
+    spread=None,  # (domain, counts, limits, zone_alive, pod group, pod bits)
 ):
     """Fused selection + scheduling loop in VMEM.
 
@@ -364,7 +532,10 @@ def fused_select_schedule_cycle(
     by the lax.scan/_cycle_kernel loop (invalid rows are zeroed; every
     consumer gates on valid). With nodes_lane_major the node operands arrive
     and the allocatables return in (N, C) lane-major layout (no transposes
-    at this boundary — see _prep_node)."""
+    at this boundary — see _prep_node). With `spread` (_spread_operands: the
+    filter's four operands and the pods' (C, P) workload and match bits) two
+    more follow:
+    each decision's placed domain and spread flags, (C, K) int32."""
     C, P = eligible.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
     K = k_pods
@@ -392,16 +563,23 @@ def fused_select_schedule_cycle(
     pod_spec = pl.BlockSpec((Pp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     cand_spec = pl.BlockSpec((Kp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
+    spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
+        spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+    )
+    spread_out, spread_shapes = [], []
+    if spread is not None:
+        spread_out = [table_spec, cand_spec, cand_spec]
+        spread_shapes = [table_shape] + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)] * 2
     kernel = functools.partial(
-        _select_cycle_kernel, N, K, profile or DEFAULT_PROFILE
+        _select_cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape
     )
     with jax.enable_x64(False):
-        cpu_o, ram_o, cand_o, valid_o, assign_o, fitany_o, best_o = pl.pallas_call(
+        cpu_o, ram_o, cand_o, valid_o, assign_o, fitany_o, best_o, *spread_o = pl.pallas_call(
             kernel,
             name="fused_select_schedule_cycle",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec] * 3 + [pod_spec] * 6,
-            out_specs=[node_spec] * 2 + [cand_spec] * 5,
+            in_specs=[node_spec] * 3 + [pod_spec] * 6 + spread_in,
+            out_specs=[node_spec] * 2 + [cand_spec] * 5 + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -410,13 +588,14 @@ def fused_select_schedule_cycle(
                 jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
-            ],
+            ]
+            + spread_shapes,
             scratch_shapes=[pltpu.VMEM((Pp, _LANE), jnp.int32)],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
             interpret=interpret,
-        )(alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p, reqc_p, reqr_p)
+        )(alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p, reqc_p, reqr_p, *spread_args)
 
     return (
         cand_o[:K, :C].T,
@@ -426,6 +605,7 @@ def fused_select_schedule_cycle(
         best_o[:K, :C].T,
         _unprep_node(cpu_o, nodes_lane_major, N, C),
         _unprep_node(ram_o, nodes_lane_major, N, C),
+        *(x[:K, :C].T for x in spread_o[1:]),
     )
 
 
@@ -1116,13 +1296,17 @@ def fused_schedule_cycle(
     interpret: bool = False,
     nodes_lane_major: bool = False,
     profile=None,  # pipeline.CompiledProfile; None = the default profile
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    spread=None,  # (domain, counts, limits, zone_alive, cand group, cand bits)
+):
     """Run the K-pod scheduling loop in VMEM.
 
     Returns (assign (C,K) bool, fit_any (C,K) bool, best (C,K) int32,
     new_alloc_cpu, new_alloc_ram), identical to the lax.scan formulation in
     batched/step.py. With nodes_lane_major the node operands arrive and the
-    allocatables return (N, C) lane-major (no transposes).
+    allocatables return (N, C) lane-major (no transposes). With `spread`
+    (_spread_operands: the topology-spread filter's four operands and the
+    candidates' (C, K) workload and match bits) two more follow: the placed
+    node's domain and the decision's spread flags, (C, K) int32 each.
     """
     C, K = valid.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
@@ -1144,27 +1328,35 @@ def fused_schedule_cycle(
     node_spec = pl.BlockSpec((Np, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     cand_spec = pl.BlockSpec((Kp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    kernel = functools.partial(_cycle_kernel, N, K, profile or DEFAULT_PROFILE)
+    spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
+        spread, nodes_lane_major, Np, Cp, Kp, node_spec, cand_spec
+    )
+    spread_out, spread_shapes = [], []
+    if spread is not None:
+        spread_out = [table_spec, cand_spec, cand_spec]
+        spread_shapes = [table_shape] + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)] * 2
+    kernel = functools.partial(_cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape)
     # Trace the kernel with x64 semantics OFF: the batched path enables
     # jax_enable_x64 for its f64 time arrays, but under x64 pallas_call's own
     # index bookkeeping traces as i64, which Mosaic fails to legalize
     # (func.return). Everything crossing this boundary is i32/bool.
     with jax.enable_x64(False):
-        cpu_o, ram_o, assign_o, fitany_o, best_o = pl.pallas_call(
+        cpu_o, ram_o, assign_o, fitany_o, best_o, *spread_o = pl.pallas_call(
             kernel,
             name="fused_schedule_cycle",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec, node_spec, node_spec, cand_spec, cand_spec, cand_spec],
-            out_specs=[node_spec, node_spec, cand_spec, cand_spec, cand_spec],
+            in_specs=[node_spec, node_spec, node_spec, cand_spec, cand_spec, cand_spec] + spread_in,
+            out_specs=[node_spec, node_spec, cand_spec, cand_spec, cand_spec] + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Kp, Cp), jnp.int32),
-            ],
+            ]
+            + spread_shapes,
             interpret=interpret,
-        )(alive_p, cpu_p, ram_p, valid_p, reqc_p, reqr_p)
+        )(alive_p, cpu_p, ram_p, valid_p, reqc_p, reqr_p, *spread_args)
 
     return (
         assign_o[:K, :C].T != 0,
@@ -1172,19 +1364,26 @@ def fused_schedule_cycle(
         best_o[:K, :C].T,
         _unprep_node(cpu_o, nodes_lane_major, N, C),
         _unprep_node(ram_o, nodes_lane_major, N, C),
+        *(x[:K, :C].T for x in spread_o[1:]),
     )
 
 
 # --- round-4 megakernel: selection + cycle + commit in ONE launch -----------
 
-def select_commit_kernel_fits(n_nodes: int, n_pods: int, k_pods: int) -> bool:
-    """VMEM budget for the megakernel: ~5 node-shaped + 14 pod-shaped +
-    3 K-shaped blocks + the (8, LANE) stats block, double-buffered by
-    Mosaic (~2x block bytes)."""
+def select_commit_kernel_fits(n_nodes: int, n_pods: int, k_pods: int, spread_shape=None) -> bool:
+    """VMEM budget for the megakernel: 3 node blocks in + 2 out, 9 pod
+    blocks in + 4 out + 1 scratch, 3 K-shaped blocks and the (8, LANE) stats
+    block; with the spread filter one node block, two pod blocks in and one
+    out, and the table, its limits, the live domains and a stats tile more;
+    double-buffered by Mosaic (~2x block bytes)."""
     Np = -(-n_nodes // _SUB) * _SUB
     Pp = -(-n_pods // _SUB) * _SUB
     Kp = -(-k_pods // _SUB) * _SUB
-    per_lane_bytes = 2 * (5 * Np + 14 * Pp + 3 * Kp + 8) * 4 * _LANE
+    s = int(spread_shape is not None)
+    per_lane_bytes = (
+        2 * ((5 + s) * Np + (14 + 3 * s) * Pp + 3 * Kp + 8 + _spread_table_rows(spread_shape))
+        * 4 * _LANE
+    )
     return per_lane_bytes <= int(_SELECT_VMEM_LIMIT * 0.8)
 
 
@@ -1217,6 +1416,7 @@ def _select_cycle_commit_kernel(
     n_nodes: int,
     k_pods: int,
     profile,        # pipeline.CompiledProfile (kernel static)
+    spread_shape,   # (G, Z) static, None without the spread filter
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -1232,18 +1432,27 @@ def _select_cycle_commit_kernel(
     qpre_ref,       # (Kp, LC) float32 positional cd_pre table
     start_ref,      # (Kp, LC) float32 positional start-offset table
     park_ref,       # (Kp, LC) float32 positional park-offset table
-    cpu_out,        # (Np, LC) int32
-    ram_out,        # (Np, LC) int32
-    phase_out,      # (Pp, LC) int32
-    node_out,       # (Pp, LC) int32
-    start_out,      # (Pp, LC) float32 (+inf = untouched)
-    park_out,       # (Pp, LC) float32 (+inf = untouched)
-    stats_out,      # (8, LC) float32: rows 0-4 count/total/total_sq/min/max
-                    #   of queue-time samples over assigned decisions; rows
-                    #   5-7 the sweep counter (below)
-    rem_ref,        # (Pp, LC) int32 scratch
-    live_ref,       # SMEM (row tiles,) int32 scratch: the live tile list
+    *refs,
 ):
+    # refs: with the spread filter the inputs domain (Np, LC), table and
+    # limits (G*8, LC), live domains (8, LC), the pods' workload and match
+    # bits (Pp, LC); then the outputs
+    #   cpu_out, ram_out      (Np, LC) int32
+    #   phase_out, node_out   (Pp, LC) int32
+    #   start_out, park_out   (Pp, LC) float32 (+inf = untouched)
+    #   stats_out             (8, LC) float32: rows 0-4 count/total/
+    #                         total_sq/min/max of queue-time samples over
+    #                         assigned decisions; rows 5-7 the sweep counter
+    # with the filter the carried table (G*8, LC), zone_out (Pp, LC) int32
+    # the placed node's domain (-2 = untouched) and sstats_out (8, LC) int32
+    # (row 0 assignments of constrained pods, row 1 those with a live domain
+    # closed); last the scratches rem (Pp, LC) and live (SMEM, row tiles).
+    if spread_shape is not None:
+        domain_ref, table_in, limit_ref, zalive_ref, pgroup_ref, pbits_ref = refs[:6]
+        refs = refs[6:]
+        table_out, zone_out, sstats_out = refs[7:10]
+    cpu_out, ram_out, phase_out, node_out, start_out, park_out, stats_out = refs[:7]
+    rem_ref, live_ref = refs[-2:]
     """The whole-window scheduling megakernel (VERDICT r3 item 2): queue
     SELECTION (iterated 3-key argmin, _select_cycle_kernel), the
     fit/score/place CYCLE, and the decision COMMIT (the per-pod phase/node/
@@ -1285,6 +1494,10 @@ def _select_cycle_commit_kernel(
     stats_out[:] = jnp.zeros_like(stats_out)
     stats_out[3:4, :] = stats_out[3:4, :] + finf
     stats_out[4:5, :] = stats_out[4:5, :] - finf
+    if spread_shape is not None:
+        table_out[:] = table_in[:]
+        zone_out[:] = jnp.full_like(zone_out, jnp.int32(-2))
+        sstats_out[:] = jnp.zeros_like(sstats_out)
 
     alive = alive_ref[:] != i0
     iota_n = jax.lax.broadcasted_iota(jnp.int32, alive.shape, 0)
@@ -1301,21 +1514,37 @@ def _select_cycle_commit_kernel(
     stats_out[6:7, :] = stats_out[6:7, :] + k_bound.astype(jnp.float32)
     stats_out[7:8, :] = stats_out[7:8, :] + jnp.float32(n_tiles)
 
+    carried = ((preq_cpu_ref, i0), (preq_ram_ref, i0), (waited_ref, -finf))
+    if spread_shape is not None:
+        # The chosen pod's workload and match bits come back with its
+        # requests: the same sweep, two more blocks read.
+        carried += ((pgroup_ref, jnp.int32(-1)), (pbits_ref, i0))
+
     def body(k):
-        slot, (rc, rr, waited) = _select_first(
+        slot, (rc, rr, waited, *pod_spread) = _select_first(
             n_live,
             live_ref,
             n_rows,
             lambda rows, _: rem_ref[rows, :] != i0,
             (qwin_ref, qoff_ref, qseq_ref),
-            ((preq_cpu_ref, i0), (preq_ram_ref, i0), (waited_ref, -finf)),
+            carried,
         )
         valid = slot >= i0
 
-        assign, any_fit, best, new_cpu, new_ram = _fit_score_place(
+        spread = None
+        if spread_shape is not None:
+            spread = _spread_step(
+                (domain_ref, table_out, limit_ref, zalive_ref), *spread_shape, *pod_spread
+            )
+        assign, any_fit, best, new_cpu, new_ram, *placed = _fit_score_place(
             profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid,
+            rc, rr, valid, spread,
         )
+        if placed:
+            tiles, zbest, constrained, closed = placed[0]
+            _spread_store(table_out, tiles)
+            sstats_out[0:1, :] = sstats_out[0:1, :] + constrained.astype(jnp.int32)
+            sstats_out[1:2, :] = sstats_out[1:2, :] + closed.astype(jnp.int32)
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         park = valid & ~any_fit
@@ -1337,6 +1566,8 @@ def _select_cycle_commit_kernel(
             start_out[rows, :] = jnp.where(sel & assign, start_s, start_out[rows, :])
             park_out[rows, :] = jnp.where(sel & park, park_s, park_out[rows, :])
             rem_ref[rows, :] = jnp.where(sel, i0, rem_ref[rows, :])
+            if placed:
+                zone_out[rows, :] = jnp.where(sel & assign, zbest, zone_out[rows, :])
 
         _sweep_live_tiles(n_live, live_ref, n_rows, commit)
 
@@ -1385,13 +1616,18 @@ def fused_select_cycle_commit(
     interpret: bool = False,
     nodes_lane_major: bool = False,
     profile=None,  # pipeline.CompiledProfile; None = the default profile
+    spread=None,  # (domain, counts, limits, zone_alive, pod group, pod bits)
 ):
     """Megakernel wrapper. Returns (alloc_cpu, alloc_ram, phase, node,
     start_tmp (+inf untouched), park_tmp, qstats (C, 8): the queue-time
     fold in columns 0-4, the kernel's sweep counter in 5-7, as its
     stats_out rows). With nodes_lane_major the node operands arrive and the
     allocatables return (N, C) lane-major (no transposes at this
-    boundary)."""
+    boundary). With `spread` (_spread_operands: the filter's four operands and
+    the pods' (C, P) workload and match bits) two more follow: the placed node's
+    domain a pod, (C, P) int32 with -2 where the cycle placed nothing, and
+    the (C, 2) counters (assignments of constrained pods, those with a live
+    domain closed)."""
     C, P = eligible.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
     K = k_pods
@@ -1429,16 +1665,27 @@ def fused_select_cycle_commit(
     cand_spec = pl.BlockSpec((Kp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     stat_spec = pl.BlockSpec((8, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
+    spread_shape, spread_args, spread_in, table_spec, tile_spec, table_shape = _spread_operands(
+        spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+    )
+    spread_out, spread_shapes = [], []
+    if spread is not None:
+        spread_out = [table_spec, pod_spec, tile_spec]
+        spread_shapes = [
+            table_shape,
+            jax.ShapeDtypeStruct((Pp, Cp), jnp.int32),
+            jax.ShapeDtypeStruct((SPREAD_ZONE_TILE, Cp), jnp.int32),
+        ]
     kernel = functools.partial(
-        _select_cycle_commit_kernel, N, K, profile or DEFAULT_PROFILE
+        _select_cycle_commit_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape
     )
     with jax.enable_x64(False):
-        (cpu_o, ram_o, phase_o, node_o, start_o, park_o, stats_o) = pl.pallas_call(
+        (cpu_o, ram_o, phase_o, node_o, start_o, park_o, stats_o, *spread_o) = pl.pallas_call(
             kernel,
             name="fused_select_cycle_commit",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec] * 3 + [pod_spec] * 9 + [cand_spec] * 3,
-            out_specs=[node_spec] * 2 + [pod_spec] * 4 + [stat_spec],
+            in_specs=[node_spec] * 3 + [pod_spec] * 9 + [cand_spec] * 3 + spread_in,
+            out_specs=[node_spec] * 2 + [pod_spec] * 4 + [stat_spec] + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -1447,7 +1694,8 @@ def fused_select_cycle_commit(
                 jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
                 jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
                 jax.ShapeDtypeStruct((8, Cp), jnp.float32),
-            ],
+            ]
+            + spread_shapes,
             scratch_shapes=[
                 pltpu.VMEM((Pp, _LANE), jnp.int32),
                 pltpu.SMEM((_row_tiles(Pp)[1],), jnp.int32),
@@ -1459,7 +1707,7 @@ def fused_select_cycle_commit(
         )(
             alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p,
             reqc_p, reqr_p, waited_p, phase_p, node_p,
-            qpre_p, start_p, park_p,
+            qpre_p, start_p, park_p, *spread_args,
         )
 
     return (
@@ -1470,4 +1718,5 @@ def fused_select_cycle_commit(
         start_o[:P, :C].T,
         park_o[:P, :C].T,
         stats_o[:, :C].T,
+        *((spread_o[1][:P, :C].T, spread_o[2][:2, :C].T) if spread_o else ()),
     )

@@ -67,6 +67,9 @@ RING_COLUMNS = (
     # Frees the pending-free channel carried past the window's cycle
     # (state.TELEM_FREES_DEFERRED).
     "frees_deferred",
+    # Assignments of pods under a topology-spread constraint for which the
+    # skew had closed a live domain (state.TELEM_SPREAD_BOUND).
+    "spread_bound",
 )
 assert len(RING_COLUMNS) == TELEMETRY_COLS
 
