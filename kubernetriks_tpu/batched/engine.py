@@ -52,6 +52,7 @@ from kubernetriks_tpu.batched.state import (
     PHASE_RUNNING,
     PHASE_UNSCHEDULABLE,
     RefillStage,
+    SLAB_BLOCK_EVENTS,
     TraceSlab,
     init_state,
     make_step_constants,
@@ -362,6 +363,33 @@ def _build_spread(profile, compiled_traces, config, n_nodes: int, n_pods: int, p
         out["pod_group"][ci, : len(sp.pod_group)] = sp.pod_group
         out["pod_bits"][ci, : len(sp.pod_bits)] = sp.pod_bits
     return out
+
+
+def event_chunk_size(ev_time: np.ndarray, interval: float) -> int:
+    """The event loop's chunk (max_events_per_window) a trace asks for.
+
+    A window's event application runs ceil(m / chunk) passes, m the largest
+    count of events any cluster of the batch has due in it, and a pass
+    costs about the same whether it applies 5 events or 60 (a kernel
+    launch, a slab read, the loop condition's gather: PERF.md section 6,
+    PR 38). So the chunk is the 90th percentile, over the windows that hold
+    events, of that batch-wide maximum: the typical window takes ONE pass,
+    and a burst window (1000 CreateNodes at t = 0) stays the outlier the
+    while_loop absorbs in a few more. Rounded up to whole slab blocks
+    (TraceSlab.read_chunk fetches whole blocks), at least one and at most
+    four."""
+    rows, cols = np.nonzero(np.isfinite(ev_time))
+    if rows.size == 0:
+        return SLAB_BLOCK_EVENTS
+    win = np.floor_divide(ev_time[rows, cols], interval).astype(np.int64)
+    # Counts a (window, cluster), window-major, then the maximum a window.
+    keys, per_key = np.unique(win * ev_time.shape[0] + rows, return_counts=True)
+    key_win = keys // ev_time.shape[0]
+    starts = np.flatnonzero(np.r_[True, key_win[1:] != key_win[:-1]])
+    per_window = np.maximum.reduceat(per_key, starts)
+    typical = int(np.percentile(per_window, 90, method="lower"))
+    blocks = -(-typical // SLAB_BLOCK_EVENTS)
+    return SLAB_BLOCK_EVENTS * min(max(blocks, 1), 4)
 
 
 def _lex_name_ranks(names) -> np.ndarray:  # ktpu: sync-ok(host-side name-rank table builder over python name lists, no device values)
@@ -1402,15 +1430,13 @@ class BatchedSimulation:
         # typical-case tile size, not a worst-case bound: a trace whose worst
         # window has thousands of events (e.g. the t=0 cluster creation burst)
         # pays a few extra loop iterations there instead of taxing every
-        # window with a burst-sized gather/scatter.
-        # 32: scatter cost scales with C x E, and typical windows carry far
-        # fewer events than a burst; smaller chunks measurably beat 128 on
-        # the TPU (burst windows just loop a few more times). 32 is also a
-        # slab block (state.SLAB_BLOCK_EVENTS): a chunk of at most a block
-        # lies in two neighbouring blocks, so its read costs 2 x C gather
-        # indices whatever the cursor (TraceSlab.read_chunk).
+        # window with a burst-sized gather/scatter. Sized from the trace's
+        # own per-window counts (event_chunk_size) unless given: a
+        # performance static only, the loop is exact at any chunk.
         if max_events_per_window is None:
-            max_events_per_window = min(self._max_events_in_any_window(ev_time), 32)
+            max_events_per_window = event_chunk_size(
+                ev_time, config.scheduling_cycle_interval
+            )
         self.max_events_per_window = max(1, max_events_per_window)
         # Cap per-cycle scheduling work (the scalar path drains the queue
         # unboundedly, reference scheduler.rs:261; the batched path bounds each
@@ -1877,18 +1903,6 @@ class BatchedSimulation:
             lambda spec: NamedSharding(sharding.mesh, spec),
             cluster_specs(tree, sharding.spec[0]),
         )
-
-    def _max_events_in_any_window(self, ev_time: np.ndarray) -> int:
-        """Worst-case events falling into one (cluster, scheduling-window)
-        bucket — the static per-window event budget."""
-        interval = self.config.scheduling_cycle_interval
-        rows, cols = np.nonzero(np.isfinite(ev_time))
-        if rows.size == 0:
-            return 1
-        win = np.floor_divide(ev_time[rows, cols], interval).astype(np.int64)
-        keys = rows * (win.max() + 2) + win
-        _, per_key = np.unique(keys, return_counts=True)
-        return int(per_key.max())
 
     # --- stepping -----------------------------------------------------------
 
@@ -3708,7 +3722,7 @@ class BatchedSimulation:
         """Step until every trace pod has terminated (scalar equivalent:
         RunUntilAllPodsAreFinishedCallbacks), bounded by max_time."""
         interval = self.config.scheduling_cycle_interval
-        chunk = max(64, self.max_events_per_window)
+        chunk = 64  # windows a step; how far past completion the run stops follows it
         finite = self._ev_time_np[np.isfinite(self._ev_time_np)]
         last_event_time = float(finite.max()) if finite.size else 0.0
         while True:

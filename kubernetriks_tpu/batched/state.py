@@ -427,10 +427,13 @@ class TraceSlab(NamedTuple):
     fills its block and one whole block more. A gather on the TPU costs
     per INDEX, some 12 ns each whether the index fetches 16 bytes or 512
     (PERF.md section 6, PR 31): the chunk a cluster's cursor points at
-    lies in at most two neighbouring blocks when it is no longer than a
-    block, so `read_chunk` fetches those as whole rows, 2 x C indices, and
-    realigns them in registers, where the point gather it replaced paid
-    C x chunk indices of one 16-byte row each. The sentinel block makes
+    lies in at most b + 1 neighbouring blocks when it is no longer than b
+    blocks (the engine sizes it in whole blocks, one to four:
+    engine.event_chunk_size), so `read_chunk` fetches those as whole rows,
+    (b + 1) x C indices (2 x C for a chunk of one block, 3 x C for 64
+    events), and realigns them in registers, where the point gather it
+    replaced paid C x chunk indices of one 16-byte row each. The sentinel
+    block makes
     every read total: a cursor at or past the end, and a block index
     clamped to the last block, read events that are never due, so no
     caller compares a cursor with the number of real rows. The slab (the

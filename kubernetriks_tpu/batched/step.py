@@ -301,6 +301,12 @@ def _apply_window_events_work(
     row-major convention (their producers/consumers are row-major-shaped
     sorts/gathers); the handful of row-major pending-effect masks that
     merge into lane-major accumulators transpose exactly once below.
+    The one exception is inside the event chunk loop: where the build takes
+    the event kernel, all five accumulators of the loop, the three pod
+    planes among them, are carried in that kernel's padded lane-major
+    layout (scheduler_kernel.event_accumulators) whatever lane_major says,
+    and leave it once after the loop; the plain scatter path carries them
+    in the conventions above.
 
     fault_params (chaos.FaultParams, static): with node_faults, the slab may
     carry EV_NODE_CRASH (remove semantics + crash/downtime accounting; a
@@ -334,6 +340,8 @@ def _apply_window_events_work(
     f32inf = jnp.float32(INF)
 
     from kubernetriks_tpu.ops.scheduler_kernel import (
+        event_accumulators,
+        event_accumulators_unpack,
         event_kernel_fits,
         fused_event_scatter,
     )
@@ -358,11 +366,7 @@ def _apply_window_events_work(
         and not node_faults
     )
     if use_event_kernel:
-        event_core = partial(
-            fused_event_scatter,
-            interpret=pallas_interpret,
-            nodes_lane_major=lane_major,
-        )
+        event_core = partial(fused_event_scatter, interpret=pallas_interpret)
 
     # --- bulk-apply the window's slab events, E at a time -------------------
     # E is a CHUNK size, not a worst-case bound: chunks apply inside a
@@ -384,9 +388,9 @@ def _apply_window_events_work(
         if node_faults:
             crash_rm, n_recover = carry[tail], carry[tail + 1]
         # The E entries that follow each cursor, as whole 128-lane blocks:
-        # 2 x C gather indices, not C x E (gather cost is per index on the
-        # TPU; TraceSlab). Past the end the slab reads as sentinel events
-        # (win=INF_WIN), which are never due.
+        # (E / 32 + 1) x C gather indices, not C x E (gather cost is per
+        # index on the TPU; TraceSlab). Past the end the slab reads as
+        # sentinel events (win=INF_WIN), which are never due.
         pk = slab.read_chunk(cursor, E)  # (C, E, 4) int32
         ev_win = pk[..., 0]
         ev_off = jax.lax.bitcast_convert_type(pk[..., 1], jnp.float32)
@@ -437,7 +441,9 @@ def _apply_window_events_work(
         if use_event_kernel:
             # One Pallas call replaces the five (C, E)-indexed scatters
             # below (~5 ms/window at dense shapes; scatter cost is
-            # per-index on TPU).
+            # per-index on TPU). The five accumulators ride the loop in
+            # the kernel's padded lane-major layout (carry0): a pass pays
+            # no pad, transpose or slice for them.
             created, node_removal, pod_create, pod_create_seq, pod_removal = (
                 event_core(
                     ev_k, ev_s, ev_rel, ev_seq, valid,
@@ -516,14 +522,18 @@ def _apply_window_events_work(
             return acc.at[tgt, rows].min(values, mode="drop")
         return acc.at[rows, tgt].min(values, mode="drop")
 
+    if use_event_kernel:
+        accumulators0 = event_accumulators(C, N, P)
+    else:
+        accumulators0 = (
+            jnp.zeros(n_shape, bool),
+            jnp.full(n_shape, INF, jnp.float32),
+            jnp.full((C, P), INF, jnp.float32),
+            jnp.zeros((C, P), jnp.int32),
+            jnp.full((C, P), INF, jnp.float32),
+        )
     carry0 = (
-        state.event_cursor,
-        jnp.zeros(n_shape, bool),
-        jnp.full(n_shape, INF, jnp.float32),
-        jnp.full((C, P), INF, jnp.float32),
-        jnp.zeros((C, P), jnp.int32),
-        jnp.full((C, P), INF, jnp.float32),
-        jnp.zeros((C,), jnp.int32),
+        (state.event_cursor,) + accumulators0 + (jnp.zeros((C,), jnp.int32),)
     )
     if conditional_move:
         carry0 = carry0 + (jnp.full(n_shape, INF, jnp.float32),)
@@ -538,6 +548,15 @@ def _apply_window_events_work(
     event_chunks = carry_out[-1] if count_chunks else None
     (event_cursor, created, node_removal, pod_create, pod_create_seq,
      pod_removal, n_creates) = carry_out[:7]
+    if use_event_kernel:
+        # Out of the kernel's layout once a window, where the row-major
+        # consumers start.
+        created, node_removal, pod_create, pod_create_seq, pod_removal = (
+            event_accumulators_unpack(
+                (created, node_removal, pod_create, pod_create_seq, pod_removal),
+                C, N, P, lane_major,
+            )
+        )
     tail = 7
     node_create_rel = None
     if conditional_move:

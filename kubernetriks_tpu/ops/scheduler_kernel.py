@@ -101,7 +101,11 @@ _VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 # hot node leaves as (N, C): the wrapper pads WITHOUT transposing (a no-op
 # copy at tile-aligned shapes) and returns node outputs lane-major. Pod-,
 # candidate- and event-shaped operands keep the row-major convention — their
-# producers/consumers in step.py are row-major-shaped sorts and gathers.
+# producers/consumers in step.py are row-major-shaped sorts and gathers. The
+# event kernel's accumulators are the exception: they live only inside the
+# event chunk loop, which carries all five (the three pod planes too) padded
+# in the kernel's layout whatever nodes_lane_major says
+# (event_accumulators / event_accumulators_unpack).
 def _prep_node(x, lane_major: bool, n_sub: int, n_lane: int, fill):
     x = x.astype(jnp.int32)
     if not lane_major:
@@ -1048,90 +1052,118 @@ def _event_kernel(
     jax.lax.while_loop(lambda k: k < k_bound, loop_body, jnp.int32(0))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("interpret", "nodes_lane_major")
-)
+def event_accumulators(n_clusters: int, n_nodes: int, n_pods: int):
+    """The event loop's five accumulators, empty, in the layout
+    fused_event_scatter takes and returns them in: slots on sublanes padded
+    to 8, clusters on lanes padded to 128. created (Np, Cp) int32 0,
+    node_removal (Np, Cp) float32 +inf, pod_create (Pp, Cp) float32 +inf,
+    pod_create_seq (Pp, Cp) int32 0, pod_removal (Pp, Cp) float32 +inf.
+    Constants, so a loop that carries them pays no pad and no transpose on
+    the way in; pad rows match no in-range slot and pad lanes carry no valid
+    event, and event_accumulators_unpack cuts both off."""
+    Cp = -(-n_clusters // _LANE) * _LANE
+    Np = -(-n_nodes // _SUB) * _SUB
+    Pp = -(-n_pods // _SUB) * _SUB
+    f32inf = jnp.float32(np.inf)
+    return (
+        jnp.zeros((Np, Cp), jnp.int32),
+        jnp.full((Np, Cp), f32inf, jnp.float32),
+        jnp.full((Pp, Cp), f32inf, jnp.float32),
+        jnp.zeros((Pp, Cp), jnp.int32),
+        jnp.full((Pp, Cp), f32inf, jnp.float32),
+    )
+
+
+def event_accumulators_unpack(
+    acc, n_clusters: int, n_nodes: int, n_pods: int, nodes_lane_major: bool
+):
+    """The five accumulators as batched/step.py's row-major consumers read
+    them, ONCE after the loop: created as bool and node_removal (C, N), or
+    (N, C) with nodes_lane_major (a slice, no transpose); the three pod
+    planes (C, P)."""
+    created, node_removal, pod_create, pod_create_seq, pod_removal = acc
+    return (
+        _unprep_node(created, nodes_lane_major, n_nodes, n_clusters) != 0,
+        _unprep_node(node_removal, nodes_lane_major, n_nodes, n_clusters),
+        _unprep_node(pod_create, False, n_pods, n_clusters),
+        _unprep_node(pod_create_seq, False, n_pods, n_clusters),
+        _unprep_node(pod_removal, False, n_pods, n_clusters),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_event_scatter(
     ev_kind: jnp.ndarray,   # (C, E) int32
     ev_slot: jnp.ndarray,   # (C, E) int32 device coords
     ev_rel: jnp.ndarray,    # (C, E) float32
     ev_seq: jnp.ndarray,    # (C, E) int32
     ev_valid: jnp.ndarray,  # (C, E) bool (per-lane prefix)
-    created: jnp.ndarray,       # (C, N) bool — (N, C) when nodes_lane_major
-    node_removal: jnp.ndarray,  # (C, N) float32 — (N, C) when nodes_lane_major
-    pod_create: jnp.ndarray,    # (C, P) float32
-    pod_create_seq: jnp.ndarray,  # (C, P) int32
-    pod_removal: jnp.ndarray,   # (C, P) float32
+    created: jnp.ndarray,       # (Np, Cp) int32
+    node_removal: jnp.ndarray,  # (Np, Cp) float32
+    pod_create: jnp.ndarray,    # (Pp, Cp) float32
+    pod_create_seq: jnp.ndarray,  # (Pp, Cp) int32
+    pod_removal: jnp.ndarray,   # (Pp, Cp) float32
     interpret: bool = False,
-    nodes_lane_major: bool = False,
 ):
     """Returns the five accumulators with this chunk's events applied,
-    bit-identical to the XLA scatter formulation. With nodes_lane_major the
-    two NODE accumulators arrive and return (N, C) lane-major — the event
-    chunk loop carries them in the kernel layout across iterations, so the
-    per-iteration transposes vanish (the event columns are per-chunk data
-    and keep the row-major convention)."""
+    bit-identical to the XLA scatter formulation. The accumulators arrive
+    and return in the kernel's own layout (event_accumulators: padded,
+    clusters on lanes): the event chunk loop carries all five that way
+    across its passes, so a pass pays no pad, transpose, slice or bool
+    round trip at this boundary, and the caller leaves the layout once
+    after the loop (event_accumulators_unpack). The event columns are
+    per-chunk data and keep the row-major convention: five (E, Cp) planes
+    transposed and padded a pass."""
     C, E = ev_kind.shape
-    N = created.shape[0] if nodes_lane_major else created.shape[1]
-    P = pod_create.shape[1]
-    Cp = -(-C // _LANE) * _LANE
-    Np = -(-N // _SUB) * _SUB
-    Pp = -(-P // _SUB) * _SUB
+    Np, Cp = created.shape
+    Pp = pod_create.shape[0]
     Ep = -(-E // _SUB) * _SUB
+    assert Cp == -(-C // _LANE) * _LANE and Np % _SUB == 0 and Pp % _SUB == 0, (
+        "accumulators not in the kernel's layout (event_accumulators)"
+    )
 
-    def prep(x, n_sub, fill):
-        return _pad_axis(_pad_axis(x.T, 0, n_sub, fill), 1, Cp, fill)
+    def prep(x, fill):
+        return _pad_axis(_pad_axis(x.T, 0, Ep, fill), 1, Cp, fill)
 
-    def prep_n(x, fill):
-        x2 = x if nodes_lane_major else x.T
-        return _pad_axis(_pad_axis(x2, 0, Np, fill), 1, Cp, fill)
-
-    f32inf = jnp.float32(np.inf)
     args = (
-        prep(ev_kind.astype(jnp.int32), Ep, 0),
-        prep(ev_slot.astype(jnp.int32), Ep, -1),
-        prep(ev_rel.astype(jnp.float32), Ep, 0.0),
-        prep(ev_seq.astype(jnp.int32), Ep, 0),
-        prep(ev_valid.astype(jnp.int32), Ep, 0),
-        prep_n(created.astype(jnp.int32), 0),
-        prep_n(node_removal.astype(jnp.float32), f32inf),
-        prep(pod_create.astype(jnp.float32), Pp, f32inf),
-        prep(pod_create_seq.astype(jnp.int32), Pp, 0),
-        prep(pod_removal.astype(jnp.float32), Pp, f32inf),
+        prep(ev_kind.astype(jnp.int32), 0),
+        prep(ev_slot.astype(jnp.int32), -1),
+        prep(ev_rel.astype(jnp.float32), 0.0),
+        prep(ev_seq.astype(jnp.int32), 0),
+        prep(ev_valid.astype(jnp.int32), 0),
+        created,
+        node_removal,
+        pod_create,
+        pod_create_seq,
+        pod_removal,
     )
 
     def spec(n_sub):
         return pl.BlockSpec((n_sub, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    shapes = [
-        jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((Np, Cp), jnp.float32),
-        jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
-        jax.ShapeDtypeStruct((Pp, Cp), jnp.int32),
-        jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
-    ]
     with jax.enable_x64(False):
-        created_o, nrm_o, pcr_o, pseq_o, prm_o = pl.pallas_call(
-            _event_kernel,
-            name="fused_event_scatter",
-            grid=(Cp // _LANE,),
-            in_specs=[spec(Ep)] * 5 + [spec(Np)] * 2 + [spec(Pp)] * 3,
-            out_specs=[spec(Np)] * 2 + [spec(Pp)] * 3,
-            out_shape=shapes,
-            scratch_shapes=[pltpu.SMEM((_row_tiles(Pp)[1],), jnp.int32)],
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=_SELECT_VMEM_LIMIT
-            ),
-            interpret=interpret,
-        )(*args)
-
-    return (
-        _unprep_node(created_o, nodes_lane_major, N, C) != 0,
-        _unprep_node(nrm_o, nodes_lane_major, N, C),
-        pcr_o[:P, :C].T,
-        pseq_o[:P, :C].T,
-        prm_o[:P, :C].T,
-    )
+        return tuple(
+            pl.pallas_call(
+                _event_kernel,
+                name="fused_event_scatter",
+                grid=(Cp // _LANE,),
+                in_specs=[spec(Ep)] * 5 + [spec(Np)] * 2 + [spec(Pp)] * 3,
+                out_specs=[spec(Np)] * 2 + [spec(Pp)] * 3,
+                out_shape=[
+                    jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args[5:]
+                ],
+                scratch_shapes=[pltpu.SMEM((_row_tiles(Pp)[1],), jnp.int32)],
+                # Each accumulator is updated in place: the loop's carry is
+                # the kernel's operand AND its result, so XLA copies no
+                # plane between passes. A grid program reads its own lane
+                # tile whole before it writes it, and no other's.
+                input_output_aliases={5 + i: i for i in range(5)},
+                compiler_params=pltpu.CompilerParams(
+                    vmem_limit_bytes=_SELECT_VMEM_LIMIT
+                ),
+                interpret=interpret,
+            )(*args)
+        )
 
 
 def commit_kernel_fits(n_pods: int, k_pods: int) -> bool:

@@ -508,6 +508,81 @@ def test_multi_chunk_event_drain_matches_single_chunk():
         )
 
 
+def _burst_traces():
+    """A burst window (40 CreateNodes at t = 0, beside the first pods) and a
+    window of more than 64 due events (70 pods created inside [20, 30) s),
+    then a thin tail; names sort in creation order."""
+    cluster = "events:" + "".join(
+        f"""
+- timestamp: 0
+  event_type:
+    !CreateNode
+      node:
+        metadata: {{name: node_{i:03d}}}
+        status: {{capacity: {{cpu: {8000 + 1000 * (i % 5)}, ram: {(16 + 2 * (i % 5)) * GiB}}}}}
+"""
+        for i in range(40)
+    )
+    stamps = [1.0, 2.5, 4.0] + [20.0 + 0.14 * k for k in range(70)] + [41.0 + 7.0 * k for k in range(12)]
+    specs = [
+        (f"pod_{k:03d}", 1000 + 500 * (k % 3), (2 + k % 3) * GiB, 30.0 + 5.0 * (k % 7), ts)
+        for k, ts in enumerate(stamps)
+    ]
+    return cluster, "events:" + "".join(pod_yaml(*spec) for spec in specs), [spec[0] for spec in specs]
+
+
+def _burst_run(chunk):
+    cluster, workload, _ = _burst_traces()
+    sim = build_batched_from_traces(
+        default_test_simulation_config(),
+        GenericClusterTrace.from_yaml(cluster).convert_to_simulator_events(),
+        GenericWorkloadTrace.from_yaml(workload).convert_to_simulator_events(),
+        n_clusters=2,
+        max_events_per_window=chunk,
+    )
+    sim.step_until_time(400.0)
+    return sim
+
+
+@pytest.fixture(scope="module")
+def burst_references():
+    """The same traces through one whole-window chunk and through the scalar path."""
+    cluster, workload, names = _burst_traces()
+    scalar = run_scalar(default_test_simulation_config(), cluster, workload, 400.0)
+    return _burst_run(1024), scalar, names
+
+
+@pytest.mark.parametrize("chunk", [8, 32, 64, 96])
+def test_every_chunk_size_gives_the_same_final_state(chunk, burst_references):
+    """The event loop is exact at any chunk: a chunk smaller than a slab
+    block, one block, and the two- and three-block chunks the engine's rule
+    picks (event_chunk_size), over a window that takes several passes at
+    each of them and a window of 73 due events that takes one pass only at
+    96. Every leaf of the final state equals the single-chunk build's, so
+    the four equal each other, and every pod sits where the scalar path put
+    it."""
+    import jax
+
+    whole, scalar, names = burst_references
+    sim = _burst_run(chunk)
+    assert sim.max_events_per_window == chunk
+    due = (sim._ev_time_np[0][:, None] < np.asarray([10.0, 30.0])).sum(axis=0)
+    assert due[0] >= 43 and due[1] - due[0] > 64  # the burst, then the busy window
+    flat_a, _ = jax.tree_util.tree_flatten_with_path(whole.state)
+    flat_b, _ = jax.tree_util.tree_flatten_with_path(sim.state)
+    for (path, a), (_, b) in zip(flat_a, flat_b):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path))
+    view = sim.pod_view(0)
+    assert sim.pod_view(1) == view
+    for name in names:
+        scalar_pod = scalar.persistent_storage.succeeded_pods.get(name)
+        assert scalar_pod is not None, f"{name} did not succeed in the scalar run"
+        assert view[name]["phase"] == PHASE_SUCCEEDED, name
+        assert view[name]["node"] == scalar_pod.status.assigned_node, name
+        start = scalar_pod.get_condition(PodConditionType.POD_RUNNING).last_transition_time
+        assert view[name]["start_time"] == pytest.approx(start, abs=1e-2), name
+
+
 def test_larger_batch_replicates_cluster_zero():
     """Every cluster in a homogeneous batch produces identical results."""
     config = default_test_simulation_config()

@@ -308,7 +308,15 @@ def test_cycle_kernel_lane_major_identity():
 
 
 def test_event_kernel_lane_major_identity():
-    from kubernetriks_tpu.ops.scheduler_kernel import fused_event_scatter
+    """The event kernel keeps ONE layout for its accumulators (padded,
+    clusters on lanes: the event loop's carry); what differs with
+    lane_major is how the loop leaves it, node outputs sliced as they are
+    or transposed back."""
+    from kubernetriks_tpu.ops.scheduler_kernel import (
+        event_accumulators_unpack,
+        fused_event_scatter,
+    )
+    from tests.test_pallas_kernel import pack_event_accumulators
 
     rng = np.random.default_rng(2)
     C, N, P, E = 3, 5, 7, 6
@@ -324,17 +332,19 @@ def test_event_kernel_lane_major_identity():
     pcr = np.full((C, P), np.inf, np.float32)
     pseq = np.zeros((C, P), np.int32)
     prm = np.full((C, P), np.inf, np.float32)
-    row = fused_event_scatter(
-        kind, slot, rel, seq, valid, created, nrm, pcr, pseq, prm,
+    out = fused_event_scatter(
+        kind, slot, rel, seq, valid,
+        *pack_event_accumulators(created, nrm, pcr, pseq, prm),
         interpret=True,
     )
-    lane = fused_event_scatter(
-        kind, slot, rel, seq, valid, created.T, nrm.T, pcr, pseq, prm,
-        interpret=True, nodes_lane_major=True,
-    )
+    row = event_accumulators_unpack(out, C, N, P, False)
+    lane = event_accumulators_unpack(out, C, N, P, True)
+    assert row[0].dtype == lane[0].dtype == bool
+    assert (np.asarray(row[0]) >= created).all() and np.asarray(row[0]).any()
     np.testing.assert_array_equal(np.asarray(row[0]), np.asarray(lane[0]).T)
     np.testing.assert_array_equal(np.asarray(row[1]), np.asarray(lane[1]).T)
     for i in (2, 3, 4):
+        assert row[i].shape == (C, P)
         np.testing.assert_array_equal(np.asarray(row[i]), np.asarray(lane[i]))
 
 

@@ -525,6 +525,20 @@ EVENT_POD_SLOTS = {
 }
 
 
+def pack_event_accumulators(created, nrm, pcr, pseq, prm):
+    """Row-major (C, N) / (C, P) accumulators in the event kernel's padded
+    lane-major layout (scheduler_kernel.event_accumulators): what the event
+    loop's carry holds."""
+    from kubernetriks_tpu.ops.scheduler_kernel import event_accumulators
+
+    C, N = created.shape
+    empty = event_accumulators(C, N, pcr.shape[1])
+    return tuple(
+        e.at[: x.shape[1], :C].set(jnp.asarray(x.T).astype(e.dtype))
+        for e, x in zip(empty, (created, nrm, pcr, pseq, prm))
+    )
+
+
 @pytest.mark.parametrize(
     "slots,shape",
     [("anywhere", (4, 12, 7, 20))]
@@ -532,7 +546,10 @@ EVENT_POD_SLOTS = {
     + [("anywhere", (3, 12, 7, 256)), ("anywhere", (130, 8, 5, 300))],
 )
 def test_event_kernel_matches_scatters(slots, shape):
-    from kubernetriks_tpu.ops.scheduler_kernel import fused_event_scatter
+    from kubernetriks_tpu.ops.scheduler_kernel import (
+        event_accumulators_unpack,
+        fused_event_scatter,
+    )
 
     rng = np.random.default_rng(11)
     C, E, N, P = shape
@@ -559,10 +576,10 @@ def test_event_kernel_matches_scatters(slots, shape):
     got = fused_event_scatter(
         jnp.asarray(kind), jnp.asarray(slot), jnp.asarray(rel),
         jnp.asarray(seq), jnp.asarray(valid),
-        jnp.asarray(created0), jnp.asarray(nrm0), jnp.asarray(pcr0),
-        jnp.asarray(pseq0), jnp.asarray(prm0),
+        *pack_event_accumulators(created0, nrm0, pcr0, pseq0, prm0),
         interpret=True,
     )
+    got = event_accumulators_unpack(got, C, N, P, False)
     created, nrm, pcr, pseq, prm = (
         created0.copy(), nrm0.copy(), pcr0.copy(), pseq0.copy(), prm0.copy()
     )

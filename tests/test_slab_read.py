@@ -20,6 +20,7 @@ from kubernetriks_tpu.batched.state import (
     TraceSlab,
     compare_states,
     strip_telemetry,
+    swap_node_layout,
 )
 from kubernetriks_tpu.batched.timerep import INF_WIN
 from kubernetriks_tpu.test_util import leaves_differing
@@ -91,6 +92,78 @@ def test_read_chunk_is_the_point_gather_where_valid(C, E, E_total):
         assert (pk[offs >= E_total] == (INF_WIN, 0, EV_NONE, 0)).all()
         want_win = np.where(cursor < E_total, rows[np.arange(C), np.clip(cursor, 0, E_total - 1), 0], INF_WIN)
         np.testing.assert_array_equal(win_at, want_win)
+
+
+@pytest.mark.parametrize("E,n_rows", [(64, 3), (96, 4), (128, 5)])
+def test_read_chunk_of_several_blocks_is_rows_sliced_on_the_host(E, n_rows):
+    """The chunks the engine's rule picks beyond one block (engine.
+    event_chunk_size: 64, 96, 128 entries, read as 3, 4, 5 block rows a
+    cluster), with the cursor at every offset of a block and at, one before
+    and past the last real event: each read is `rows()[c, cursor : cursor +
+    E]` sliced on the host, sentinel rows where that runs past the slab."""
+    C, E_total = 3, 5 * SLAB_BLOCK_EVENTS + 11
+    _, slab = _random_slab(C, E_total, seed=E)
+    assert (E + SLAB_BLOCK_EVENTS - 2) // SLAB_BLOCK_EVENTS + 1 == n_rows
+    rows = np.asarray(slab.rows())
+    sentinel = np.asarray((INF_WIN, 0, EV_NONE, 0), np.int32)
+    # A cluster's last real event, by its own ragged length.
+    last_real = (rows[..., 2] != EV_NONE).sum(axis=1) - 1
+    chunk_at = jax.jit(lambda cursor: slab.read_chunk(cursor, E))
+    cursors = [np.full((C,), SLAB_BLOCK_EVENTS + o, np.int32) for o in range(SLAB_BLOCK_EVENTS)]
+    cursors += [(last_real + d).astype(np.int32) for d in (-1, 0, 1, E, 4 * E)]
+    for cursor in cursors:
+        pk = np.asarray(chunk_at(jnp.asarray(cursor)))
+        assert pk.shape == (C, E, 4)
+        for c in range(C):
+            want = np.tile(sentinel, (E, 1))
+            have = rows[c, cursor[c] : cursor[c] + E]
+            want[: len(have)] = have
+            np.testing.assert_array_equal(pk[c], want, err_msg=f"cluster {c} cursor {cursor[c]}")
+
+
+def _ev_time(per_window_counts, interval=10.0):
+    """(C, E) event times, +inf padded: cluster c has per_window_counts[c][w]
+    events spread inside window w."""
+    rows = [
+        np.concatenate([w * interval + interval * (np.arange(n) + 0.5) / max(n, 1) for w, n in enumerate(counts)] or [[]])
+        for counts in per_window_counts
+    ]
+    out = np.full((len(rows), max(len(r) for r in rows) + 2), np.inf)
+    for c, r in enumerate(rows):
+        out[c, : len(r)] = r
+    return out
+
+
+@pytest.mark.parametrize(
+    "what,counts,chunk",
+    [
+        # 1000 CreateNodes at t = 0 next to twenty windows of 30-45 arrivals: the burst is an outlier, not the chunk
+        ("burst window excluded", [[1000 + 40] + [30 + (3 * w + c) % 16 for w in range(20)] for c in range(3)], 64),
+        # the batch-wide maximum a window decides, not a cluster's own typical count
+        ("one busy cluster decides", [[5] * 12, [5] * 12, [70] * 12], 96),
+        ("whole blocks, up", [[33] * 12], 64),
+        ("a block exactly", [[32] * 12], 32),
+        ("floor: one block", [[1, 0, 2, 1]], 32),
+        ("no events at all", [[]], 32),
+        ("ceiling: four blocks", [[500] * 12], 128),
+        # nine windows in ten take one pass: the tenth busiest is the chunk
+        ("the 90th percentile", [[10] * 17 + [40] * 2 + [70] * 2], 64),
+    ],
+)
+def test_event_chunk_is_sized_from_the_traces_own_windows(what, counts, chunk):
+    from kubernetriks_tpu.batched.engine import event_chunk_size
+
+    assert event_chunk_size(_ev_time(counts), 10.0) == chunk, what
+    assert chunk % SLAB_BLOCK_EVENTS == 0 and SLAB_BLOCK_EVENTS <= chunk <= 4 * SLAB_BLOCK_EVENTS
+
+
+def test_an_explicit_chunk_wins_over_the_traces_rule():
+    from kubernetriks_tpu.batched.engine import event_chunk_size
+
+    sim = bare_batch(4)
+    assert sim.max_events_per_window == event_chunk_size(sim._ev_time_np, 10.0) == SLAB_BLOCK_EVENTS
+    assert bare_batch(4, max_events_per_window=8).max_events_per_window == 8
+    assert bare_batch(4, max_events_per_window=200).max_events_per_window == 200
 
 
 def test_slab_rows_are_the_build_rows_with_a_sentinel_tail():
@@ -175,6 +248,62 @@ def test_whole_job_matches_the_scan_formulation(build, executor):
     assert kernel.metrics_summary()["counters"]["pods_succeeded"] > 0
     bad = compare_states(plain.state, kernel.state)
     assert not bad, bad
+
+
+@pytest.mark.parametrize(
+    "C,passes,lane_major", [(5, 2, False), (5, 3, True), (130, 2, True), (130, 3, False)]
+)
+def test_kernel_layout_carry_is_the_scatter_path_bit_for_bit(monkeypatch, C, passes, lane_major):
+    """The event loop of a build that takes the event kernel carries its
+    five accumulators in the kernel's padded lane-major layout, (Np, Cp) and
+    (Pp, Cp), and leaves it once after the loop; the plain scatter path
+    carries them row-major. One window whose due events take two and three
+    passes, at C not a multiple of 128 (one lane tile, and two with a ragged
+    second) and P not a multiple of 8: every leaf of the state after the
+    event application is the same, bit for bit."""
+    from kubernetriks_tpu.batched.engine import BatchedSimulation
+    from kubernetriks_tpu.batched.trace_compile import compile_cluster_trace
+    from kubernetriks_tpu.config import SimulationConfig
+    from kubernetriks_tpu.trace.generator import PoissonWorkloadTrace, UniformClusterTrace
+
+    monkeypatch.setenv("KTPU_ALIGN_PODS", "0")  # keep P the trace's own count
+    config = SimulationConfig.from_yaml("sim_name: carry\nseed: 1\nscheduling_cycle_interval: 10.0\n")
+    cluster = UniformClusterTrace(11, cpu=16000, ram=32 * 1024**3).convert_to_simulator_events()
+    compiled = [
+        compile_cluster_trace(
+            cluster,
+            PoissonWorkloadTrace(
+                rate_per_second=0.8 + 0.1 * (i % 7), horizon=30.0, seed=300 + i, cpu=1000, ram=2 * 1024**3,
+                duration_range=(15.0, 40.0),
+            ).convert_to_simulator_events(),
+            config,
+        )
+        for i in range(C)
+    ]
+    sim = BatchedSimulation(config, compiled, fast_forward=False)
+    N, P = sim.n_nodes, sim.n_pods
+    # A state at rest is row-major; a lane-major program swaps the hot node leaves at its entry.
+    state0 = swap_node_layout(sim.state) if lane_major else sim.state
+    assert C % 128 and P % 8 and N % 8, (C, N, P)
+    due = int((sim._ev_time_np < 10.0).sum(axis=1).max())
+    E = -(-due // passes)
+    assert -(-due // E) == passes and due > N  # pod creations among the due events
+    W = jnp.ones((C,), jnp.int32)
+
+    def apply(**kernels):
+        return jax.jit(
+            lambda state: step._apply_window_events_work(
+                state, sim.slab, W, sim.consts, E, lane_major=lane_major, **kernels
+            )
+        )(state0)
+
+    plain = apply(use_pallas=False)
+    kernel = apply(use_pallas=True, pallas_interpret=True, use_pallas_select=True)
+    assert leaves_differing(plain, kernel) == []
+    state = kernel[0]
+    assert int(np.asarray(state.nodes.alive).sum()) == C * N
+    assert int(np.asarray(state.event_cursor).max()) == due
+    assert (np.asarray(state.pods.phase) != 0).sum() == int((sim._ev_time_np < 10.0).sum()) - C * N
 
 
 def test_whole_job_under_a_mesh_of_four_matches_unsharded_every_leaf():

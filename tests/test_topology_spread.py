@@ -479,12 +479,16 @@ def test_unsupported_profile_message_lists_the_registry():
 @pytest.mark.parametrize("cell", __import__("window_program_digest").CELLS)
 def test_accepted_cells_lower_the_programs_they_lowered(cell):
     """The window program of each accepted cell's rehearsal build, lowered
-    and stripped of debug locations, against the digest taken on the commit
-    before the spread filter came (tests/data/window_program_digests.json,
-    written by `python tests/window_program_digest.py --write` THERE): the
-    filter's state is structurally None in a build without constraints, so
-    nothing of it is traced. A PR that changes the window program on purpose
-    writes the file anew on its own tree and says so."""
+    and stripped of debug locations, against the pinned digest
+    (tests/data/window_program_digests.json, written by `python
+    tests/window_program_digest.py --write`): a PR that means to leave the
+    cells' programs alone finds out here that it did not. The pins were
+    taken on the commit before the spread filter came (the filter's state
+    is structurally None in a build without constraints, so nothing of it
+    is traced) and written anew by PR 38, which changed every cell's event
+    chunk on purpose (the replay's program alone came out as it was) and
+    added `sched1k-spread.montecarlo`. A PR that changes the window program
+    on purpose writes the file anew on its own tree and says so."""
     import window_program_digest as wpd
 
     with open(wpd.DIGESTS) as fh:

@@ -1,7 +1,8 @@
 """Digests of the window programs the accepted benchmark cells' rehearsal
 builds lower, debug locations stripped: the yardstick of "this PR did not
-change what a cell compiles". Imports nothing newer than PR 33, so the same
-file runs on an older checkout:
+change what a cell compiles". Imports nothing newer than PR 37 (and, for the
+six cells before `sched1k-spread.montecarlo`, than PR 33), so the same file
+runs on an older checkout:
 
     python tests/window_program_digest.py --write   # on the tree to pin
 
@@ -28,6 +29,7 @@ CELLS = [
     "autoscaled.stream",
     "autoscaled.whatif",
     "alibaba1313.replay",
+    "sched1k-spread.montecarlo",
 ]
 
 # `loc(...)` trailers and `#loc` lines: where in the source an op was traced.
@@ -79,6 +81,13 @@ def rehearsal_engine(cell_name: str):
         compiled = batch_jobs.prepare(cell, SEED).result()
         return program.build_engine(
             config_text, compiled, resettable=True, mesh=mesh, **batch_jobs._engine_kwargs(cell)
+        )
+    if driver == "batch_jobs_labelled":
+        from benchmark.drivers import batch_jobs, batch_jobs_labelled
+
+        compiled = batch_jobs_labelled.prepare(cell, SEED).result()
+        return program.build_engine(
+            config_text, compiled, resettable=True, **batch_jobs._engine_kwargs(cell)
         )
     if driver == "served_open_loop":
         from benchmark.drivers import served_open_loop
