@@ -1681,6 +1681,7 @@ def ca_pass(
     return state, auto
 
 
+@jax.named_scope("ca_reclaim")
 def ca_reclaim_pass(
     state: ClusterBatchState,
     auto: AutoscaleState,

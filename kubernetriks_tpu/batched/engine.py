@@ -248,21 +248,22 @@ def _fused_chunk_slide_impl(
     state, _ = jax.lax.scan(body, state, jnp.asarray(window_idxs, jnp.int32))
     if lane_major:
         state = swap_node_layout(state)
-    base = jnp.asarray(base, jnp.int32)
-    s0 = _slide_shift_core(
-        slide_phase(state.pods, consts)[:, :W], payload["create_win"], base,
-        shard_axis,
-    )
-    s = _quantize_shift_device(s0, W)
-    rank = (
-        autoscale_statics.pod_name_rank
-        if (autoscale_statics is not None and "rank" in payload)
-        else None
-    )
-    new_pods, new_rank = _slide_apply_traced(
-        state.pods, rank, payload, base, s, W
-    )
-    state = state._replace(pods=new_pods, pod_base=state.pod_base + s)
+    with jax.named_scope("slide"):
+        base = jnp.asarray(base, jnp.int32)
+        s0 = _slide_shift_core(
+            slide_phase(state.pods, consts)[:, :W], payload["create_win"], base,
+            shard_axis,
+        )
+        s = _quantize_shift_device(s0, W)
+        rank = (
+            autoscale_statics.pod_name_rank
+            if (autoscale_statics is not None and "rank" in payload)
+            else None
+        )
+        new_pods, new_rank = _slide_apply_traced(
+            state.pods, rank, payload, base, s, W
+        )
+        state = state._replace(pods=new_pods, pod_base=state.pod_base + s)
     return state, new_rank, s
 
 
@@ -277,6 +278,7 @@ _fused_chunk_slide_donated = jax.jit(
 
 
 @partial(jax.jit, static_argnames=("s", "W"))
+@jax.named_scope("slide")
 def _slide_apply_device(pods, rank, pay, base, s: int, W: int):
     """Apply a quantized window slide of `s` slots entirely on device:
     slice the refill segment out of the device-resident payload at
@@ -1983,16 +1985,22 @@ class BatchedSimulation:
             self.dispatch_stats["fused_slides"] += 1
             fn = _fused_chunk_slide_donated if self.donate else _fused_chunk_slide
             t0 = tr.begin(PH_FUSED_CHUNK_SLIDE)
-            state, new_rank, s = fn(
+            args = (
                 self.state,
                 self.slab,
                 jnp.asarray(idxs, jnp.int32),
                 self.consts,
                 self._device_slide,
                 np.int32(self._pod_base),
+            )
+            kwargs = dict(
                 W=self.pod_window,
                 **self._window_call_kwargs(),
             )
+            tr.program(
+                "fused_chunk_slide", (len(idxs), self.pod_window), fn, args, kwargs
+            )
+            state, new_rank, s = fn(*args, **kwargs)
             tr.end(PH_FUSED_CHUNK_SLIDE, t0)
             self.state = state
             if donated_in is not None:
@@ -2023,15 +2031,21 @@ class BatchedSimulation:
 
             skip_fn = run_windows_skip_donated if self.donate else run_windows_skip
             t0 = tr.begin(PH_WINDOW_CHUNK)
-            self.state = skip_fn(
+            args = (
                 self.state,
                 self.slab,
                 np.int32(idxs[0]),
                 np.int32(idxs[-1]),
                 self.consts,
+            )
+            kwargs = dict(
                 flush_windows=self._flush_windows,
                 **self._window_call_kwargs(),
             )
+            tr.program(
+                "run_windows_skip", (self.pod_window,), skip_fn, args, kwargs
+            )
+            self.state = skip_fn(*args, **kwargs)
             tr.end(PH_WINDOW_CHUNK, t0)
             if donated_in is not None:
                 sanitize.consume_donated(donated_in)
@@ -2041,15 +2055,25 @@ class BatchedSimulation:
 
         win_fn = run_windows_donated if self.donate else run_windows
         t0 = tr.begin(PH_WINDOW_CHUNK)
-        out = win_fn(
+        args = (
             self.state,
             self.slab,
             jnp.asarray(idxs, jnp.int32),
             self.consts,
+        )
+        kwargs = dict(
             collect_gauges=self.collect_gauges,
             freeze_lanes=freeze_lanes,
             **self._window_call_kwargs(),
         )
+        tr.program(
+            "run_windows",
+            (len(idxs), freeze_lanes, self.pod_window),
+            win_fn,
+            args,
+            kwargs,
+        )
+        out = win_fn(*args, **kwargs)
         tr.end(PH_WINDOW_CHUNK, t0)
         if self.collect_gauges:
             self.state, gauges = out
@@ -2314,12 +2338,16 @@ class BatchedSimulation:
         ring = self.state.telemetry
         state = self.state._replace(telemetry=None)
         donated_in = state if self._sanitize else None
-        state, live = _admit_lanes(
+        args = (
             state,
             self._pristine._replace(telemetry=None),
             live,
             jax.device_put(buf),
         )
+        self.tracer.program(
+            "admit_lanes", (self.pod_window,), _admit_lanes, args, {}
+        )
+        state, live = _admit_lanes(*args)
         if donated_in is not None:
             sanitize.consume_donated(donated_in)
         self.state = state._replace(telemetry=ring)
@@ -2358,9 +2386,15 @@ class BatchedSimulation:
         else:
             mask[np.asarray(list(lanes), np.int64)] = True  # ktpu: sync-ok(fleet reset: host numpy over a python lane list, no device values)
         donated_in = self.state if self._sanitize else None
-        self.state = _reset_lanes(
-            self.state, self._pristine, jnp.asarray(mask)
+        args = (
+            self.state,
+            self._pristine,
+            jnp.asarray(mask),
         )
+        self.tracer.program(
+            "reset_lanes", (self.pod_window,), _reset_lanes, args, {}
+        )
+        self.state = _reset_lanes(*args)
         if donated_in is not None:
             sanitize.consume_donated(donated_in)
         if lanes is not None:
@@ -3114,7 +3148,7 @@ class BatchedSimulation:
             )
             ordinal = self.dispatch_stats["superspans"]
             t0 = tr.begin(PH_SUPERSPAN)
-            state, rank, progress = fn(
+            args = (
                 self.state,
                 rank,
                 progress_in,
@@ -3123,11 +3157,17 @@ class BatchedSimulation:
                 stage,
                 jnp.int32(lo),
                 jnp.int32(target),
+            )
+            kwargs = dict(
                 W=W,
                 K=self._superspan_k,
                 chunk=self._superspan_chunk,
                 **self._window_call_kwargs(),
             )
+            tr.program(
+                "run_superspan", (W, stage.req_cpu.shape[1]), fn, args, kwargs
+            )
+            state, rank, progress = fn(*args, **kwargs)
             tr.end(PH_SUPERSPAN, t0, ident=ordinal)
             self.state = state
             if donated_in is not None:

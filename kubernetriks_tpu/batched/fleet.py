@@ -964,10 +964,12 @@ class ScenarioFleet:
         """Dispatch the packed readback's program; the rows, on the device."""
         st = self.engine.state
         auto = st.auto
-        return _pack_lane_rows(
+        args = (
             tuple(getattr(st.metrics, name) for name in _ROW_COUNTERS),
             None if auto is None else (auto.hpa_head, auto.hpa_tail, auto.ca_count),
         )
+        self.engine.tracer.program("pack_lane_rows", (), _pack_lane_rows, args, {})
+        return _pack_lane_rows(*args)
 
     def _drain_lane(
         self,

@@ -748,6 +748,7 @@ NODE_HOT_LEAVES = (
 )
 
 
+@jax.named_scope("bookkeeping")
 def swap_node_layout(state: "ClusterBatchState") -> "ClusterBatchState":
     """Transpose the hot node leaves between row-major (C, N) and lane-major
     (N, C). Self-inverse; everything else (pods, metrics, pending-effect

@@ -194,6 +194,7 @@ def _window_work_due(
     return ev_due | pend_due | fin_due
 
 
+@jax.named_scope("events")
 def _apply_window_events(
     state: ClusterBatchState,
     slab: TraceSlab,
@@ -1639,6 +1640,7 @@ def commit_cycle(
     )
 
 
+@jax.named_scope("cycle")
 def _run_scheduling_cycle(
     state: ClusterBatchState,
     W: jnp.ndarray,
@@ -1995,6 +1997,7 @@ def _run_scheduling_cycle(
     return new_state, None
 
 
+@jax.named_scope("bookkeeping")
 def _freeze_lanes(
     state: ClusterBatchState,
     state0: ClusterBatchState,
@@ -2048,6 +2051,7 @@ def _freeze_lanes(
     return rest._replace(nodes=frozen_nodes, telemetry=state.telemetry)
 
 
+@jax.named_scope("bookkeeping")
 def _telemetry_record(
     state: ClusterBatchState,
     m0,
@@ -2195,7 +2199,8 @@ def _window_body(
     freeze_lanes: bool = True,
     shard_axis=None,
 ) -> ClusterBatchState:
-    W = jnp.broadcast_to(jnp.asarray(W, jnp.int32), state.time.shape)
+    with jax.named_scope("bookkeeping"):
+        W = jnp.broadcast_to(jnp.asarray(W, jnp.int32), state.time.shape)
     # Lane-async clock protocol (engine lane_async=True, DESIGN §13): each
     # lane steps its VIRTUAL window W - lane_clock[c] — bit-identical to a
     # fresh run's window of that index — and is active only inside
@@ -2213,10 +2218,11 @@ def _window_body(
     lane_active = None
     state0 = None
     if consts.lane_clock is not None:
-        rel = W - consts.lane_clock
-        lane_active = (rel >= 0) & (rel < consts.lane_horizon)
+        with jax.named_scope("bookkeeping"):
+            rel = W - consts.lane_clock
+            lane_active = (rel >= 0) & (rel < consts.lane_horizon)
+            W = jnp.maximum(rel, 0)
         state0 = state if freeze_lanes else None
-        W = jnp.maximum(rel, 0)
     # Telemetry ring (flight recorder): the window's incoming metric
     # counters, diffed at the end of the body into one per-window record.
     m0 = state.metrics
@@ -2366,6 +2372,7 @@ def _window_body(
     return state
 
 
+@jax.named_scope("bookkeeping")
 def gauge_snapshot(
     state: ClusterBatchState, lane_major: bool = False
 ) -> jnp.ndarray:
@@ -2526,6 +2533,7 @@ def window_step(
     return state
 
 
+@jax.named_scope("bookkeeping")
 def _next_interesting_window(
     state: ClusterBatchState,
     slab: TraceSlab,
@@ -2611,6 +2619,7 @@ def _next_interesting_window(
     return jnp.maximum(W + jnp.int32(1), cand)
 
 
+@jax.named_scope("bookkeeping")
 def _catch_up_bookkeeping(
     state: ClusterBatchState,
     from_w: jnp.ndarray,
@@ -2730,7 +2739,8 @@ def _run_windows_skip_impl(
 
     def cond(carry):
         _, W = carry
-        return W <= last
+        with jax.named_scope("bookkeeping"):
+            return W <= last
 
     def body(carry):
         state, W = carry
@@ -2760,16 +2770,17 @@ def _run_windows_skip_impl(
             profile=profile,
             shard_axis=shard_axis,
         )
-        W_next = jnp.minimum(
-            _next_interesting_window(
-                state, slab, W, consts, autoscale_statics, flush_windows,
-                shard_axis,
-            ),
-            last + jnp.int32(1),
-        )
-        state = _catch_up_bookkeeping(
-            state, W + jnp.int32(1), W_next, consts, autoscale_statics
-        )
+        with jax.named_scope("bookkeeping"):
+            W_next = jnp.minimum(
+                _next_interesting_window(
+                    state, slab, W, consts, autoscale_statics, flush_windows,
+                    shard_axis,
+                ),
+                last + jnp.int32(1),
+            )
+            state = _catch_up_bookkeeping(
+                state, W + jnp.int32(1), W_next, consts, autoscale_statics
+            )
         return state, W_next
 
     state, _ = jax.lax.while_loop(
@@ -2933,6 +2944,7 @@ def _slide_shift_core(phase, create_win_pay, base, shard_axis=None):
     return all_min(first_live, shard_axis).astype(jnp.int32)
 
 
+@jax.named_scope("slide")
 def _quantize_shift_device(s0, W: int):
     """Device mirror of _advance_pod_window's host shift quantization (same
     small set of slide amounts, so fused and unfused runs follow identical
@@ -3166,53 +3178,59 @@ def _run_superspan_impl(
 
     def cond(carry):
         _, _, w, spans, code = carry
-        return (w <= last) & (code == SUPERSPAN_RUN) & (spans < jnp.int32(K))
+        with jax.named_scope("bookkeeping"):
+            return (w <= last) & (code == SUPERSPAN_RUN) & (spans < jnp.int32(K))
 
     def body(carry):
         state, rank, w, spans, code = carry
         # pod_base is uniform across clusters (slides shift every row
         # together); the min is its scalar read.
-        base = all_min(state.pod_base, shard_axis)
         # Capacity: the last window index dispatchable before a pod creation
         # would land beyond the device window — the create window of global
         # plain slot base + W (engine._pod_capacity_window's device twin).
         # Beyond the trace's plain segment capacity is unbounded; a stage
         # whose headroom is fully consumed reports capacity -1, forcing the
-        # slide branch (which then exits SUPERSPAN_STAGE or GROW).
-        gcol = base + jnp.int32(W)
-        col = gcol - stage_lo
-        cap_read = all_min(
-            jax.lax.dynamic_slice_in_dim(
-                stage.create_win, jnp.clip(col, 0, L - 1), 1, axis=1
-            ),
-            shard_axis,
-        ).astype(jnp.int32)
-        cap = jnp.where(
-            gcol >= consts.trace_pod_bound,
-            big,
-            jnp.where(col < jnp.int32(L), cap_read, jnp.int32(-1)),
-        )
-        bound = jnp.minimum(cap, last)
+        # slide branch (which then exits SUPERSPAN_STAGE or GROW). The read
+        # is the slide's trigger and carries its device phase.
+        with jax.named_scope("slide"):
+            base = all_min(state.pod_base, shard_axis)
+            gcol = base + jnp.int32(W)
+            col = gcol - stage_lo
+            cap_read = all_min(
+                jax.lax.dynamic_slice_in_dim(
+                    stage.create_win, jnp.clip(col, 0, L - 1), 1, axis=1
+                ),
+                shard_axis,
+            ).astype(jnp.int32)
+            cap = jnp.where(
+                gcol >= consts.trace_pod_bound,
+                big,
+                jnp.where(col < jnp.int32(L), cap_read, jnp.int32(-1)),
+            )
+            bound = jnp.minimum(cap, last)
 
         def run_branch(op):
             state, rank, w, spans = op
-            can_chunk = (w + jnp.int32(chunk - 1)) <= bound
+            with jax.named_scope("bookkeeping"):
+                can_chunk = (w + jnp.int32(chunk - 1)) <= bound
 
-            def run_k(op2):
-                state, rank, w = op2
-                idxs = w + jnp.arange(chunk, dtype=jnp.int32)
-                return step_windows(state, rank, idxs), rank, w + jnp.int32(chunk)
+            def run_n(n):
+                def run(op2):
+                    state, rank, w = op2
+                    with jax.named_scope("bookkeeping"):
+                        idxs = w + jnp.arange(n, dtype=jnp.int32)
+                    state = step_windows(state, rank, idxs)
+                    with jax.named_scope("bookkeeping"):
+                        return state, rank, w + jnp.int32(n)
 
-            def run_1(op2):
-                state, rank, w = op2
-                idxs = w + jnp.arange(1, dtype=jnp.int32)
-                return step_windows(state, rank, idxs), rank, w + jnp.int32(1)
+                return run
 
             state, rank, w = jax.lax.cond(
-                can_chunk, run_k, run_1, (state, rank, w)
+                can_chunk, run_n(chunk), run_n(1), (state, rank, w)
             )
             return state, rank, w, spans, jnp.int32(SUPERSPAN_RUN)
 
+        @jax.named_scope("slide")
         def slide_branch(op):
             state, rank, w, spans = op
             s0 = _slide_shift_core(
@@ -3270,17 +3288,16 @@ def _run_superspan_impl(
             w <= bound, run_branch, slide_branch, (state, rank, w, spans)
         )
 
-    progress = jnp.asarray(progress, jnp.int32)
-    state, rank, w, spans, code = jax.lax.while_loop(
-        cond,
-        body,
-        (state, rank, progress[0], jnp.int32(0), progress[3]),
-    )
+    with jax.named_scope("bookkeeping"):
+        progress = jnp.asarray(progress, jnp.int32)
+        carry = (state, rank, progress[0], jnp.int32(0), progress[3])
+    state, rank, w, spans, code = jax.lax.while_loop(cond, body, carry)
     if lane_major:
         state = swap_node_layout(state)
-    progress_out = jnp.stack(
-        [w, all_min(state.pod_base, shard_axis), spans, code]
-    ).astype(jnp.int32)
+    with jax.named_scope("bookkeeping"):
+        progress_out = jnp.stack(
+            [w, all_min(state.pod_base, shard_axis), spans, code]
+        ).astype(jnp.int32)
     return state, rank, progress_out
 
 

@@ -228,6 +228,10 @@ def run_batched(config: SimulationConfig, args) -> int:
         # report in the same format and write the Perfetto trace. ONE
         # report serves both the render and the Prometheus textfile (a
         # second call would only force a redundant drain).
+        # Read the op-to-phase map of each program this engine dispatched
+        # first (on demand, nothing is compiled for it), so that the
+        # report's `device_phases` counts the programs' instructions a phase.
+        sim.tracer.program_phases()
         telemetry_rep = sim.telemetry_report()
         print(render_telemetry(telemetry_rep, args.report or "json"))
         from kubernetriks_tpu.flags import flag_str

@@ -88,8 +88,8 @@ def render_metrics(d: Dict[str, Any], fmt: str) -> str:
 
 def render_telemetry(rep: Dict[str, Any], fmt: str) -> str:
     """Render engine.telemetry_report() as "json" or "table": the
-    per-phase span table, the dispatch stats, the sync budget, and the
-    device-ring totals."""
+    per-phase span table, the dispatch stats, the sync budget, the
+    device-ring totals, and each program's instructions by device phase."""
     if fmt == "json":
         return json.dumps(rep, indent=2, default=float)
     if fmt != "table":
@@ -130,6 +130,12 @@ def render_telemetry(rep: Dict[str, Any], fmt: str) -> str:
         rows += [
             [f"Ring high-water {humanize(k).lower()}", v]
             for k, v in ring.get("high_water", {}).items()
+        ]
+    for program, counts in rep.get("device_phases", {}).get("programs", {}).items():
+        # Instructions of each dispatched program by device phase (after
+        # recorder().program_phases() has read the compiled text).
+        rows += [
+            [f"Device phase {phase} ops, {program}", n] for phase, n in counts.items()
         ]
     resources = rep.get("resources")
     if resources:

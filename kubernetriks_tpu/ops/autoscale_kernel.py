@@ -204,30 +204,31 @@ def fused_ca_scale_down(
     def prep(x, n_sub, fill):
         return _pad_axis(_pad_axis(x.T, 0, n_sub, fill), 1, Cp, fill)
 
-    meta = jnp.concatenate(
-        [
-            branch.astype(jnp.float32).T,
-            jnp.broadcast_to(thresh.astype(jnp.float32).T, (1, C)),
-        ],
-        axis=0,
-    )
-    meta_p = _pad_axis(_pad_axis(meta, 0, _SUB, 0.0), 1, Cp, 0.0)
-    args = (
-        meta_p,
-        prep(alive.astype(jnp.int32), Np, 0),
-        prep(not_pending.astype(jnp.int32), Np, 0),
-        prep(cap_cpu.astype(jnp.int32), Np, 0),
-        prep(cap_ram.astype(jnp.int32), Np, 0),
-        prep(vcpu.astype(jnp.int32), Np, 0),
-        prep(vram.astype(jnp.int32), Np, 0),
-        prep(name_rank.astype(jnp.int32), Np, _BIG_I32),
-        prep(slot_perm.astype(jnp.int32), Sp, -1),
-        prep(cand_alive.astype(jnp.int32), Sp, 0),
-        prep(cnt.astype(jnp.int32), Sp, 0),
-        prep(pr_cpu.astype(jnp.int32), SKp, 0),
-        prep(pr_ram.astype(jnp.int32), SKp, 0),
-        prep(pv0.astype(jnp.int32), SKp, 0),
-    )
+    with jax.named_scope("kernel_io"):
+        meta = jnp.concatenate(
+            [
+                branch.astype(jnp.float32).T,
+                jnp.broadcast_to(thresh.astype(jnp.float32).T, (1, C)),
+            ],
+            axis=0,
+        )
+        meta_p = _pad_axis(_pad_axis(meta, 0, _SUB, 0.0), 1, Cp, 0.0)
+        args = (
+            meta_p,
+            prep(alive.astype(jnp.int32), Np, 0),
+            prep(not_pending.astype(jnp.int32), Np, 0),
+            prep(cap_cpu.astype(jnp.int32), Np, 0),
+            prep(cap_ram.astype(jnp.int32), Np, 0),
+            prep(vcpu.astype(jnp.int32), Np, 0),
+            prep(vram.astype(jnp.int32), Np, 0),
+            prep(name_rank.astype(jnp.int32), Np, _BIG_I32),
+            prep(slot_perm.astype(jnp.int32), Sp, -1),
+            prep(cand_alive.astype(jnp.int32), Sp, 0),
+            prep(cnt.astype(jnp.int32), Sp, 0),
+            prep(pr_cpu.astype(jnp.int32), SKp, 0),
+            prep(pr_ram.astype(jnp.int32), SKp, 0),
+            prep(pv0.astype(jnp.int32), SKp, 0),
+        )
 
     meta_spec = pl.BlockSpec((_SUB, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     node_spec = pl.BlockSpec((Np, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
@@ -252,7 +253,8 @@ def fused_ca_scale_down(
             interpret=interpret,
         )(*args)
 
-    return removed_o[:S, :C].T != 0
+    with jax.named_scope("kernel_io"):
+        return removed_o[:S, :C].T != 0
 
 
 def ca_up_kernel_fits(n_slots: int, n_groups: int, k_up: int) -> bool:
@@ -428,20 +430,21 @@ def fused_ca_scale_up(
     def prep(x, n_sub, fill):
         return _pad_axis(_pad_axis(x.T, 0, n_sub, fill), 1, Cp, fill)
 
-    meta_p = prep(max_nodes.astype(jnp.int32), _SUB, 0)
-    args = (
-        meta_p,
-        prep(ca_count.astype(jnp.int32), Gp, 0),
-        prep(ca_cursor.astype(jnp.int32), Gp, 0),
-        prep(ng_max.astype(jnp.int32), Gp, 0),
-        prep(ng_slots.astype(jnp.int32), Gp, 0),
-        prep(ng_tmpl_cpu.astype(jnp.int32), Gp, 0),
-        prep(ng_tmpl_ram.astype(jnp.int32), Gp, 0),
-        prep(ng_start.astype(jnp.int32), Gp, 0),
-        prep(cvalid.astype(jnp.int32), Kp, 0),
-        prep(creq_cpu.astype(jnp.int32), Kp, 0),
-        prep(creq_ram.astype(jnp.int32), Kp, 0),
-    )
+    with jax.named_scope("kernel_io"):
+        meta_p = prep(max_nodes.astype(jnp.int32), _SUB, 0)
+        args = (
+            meta_p,
+            prep(ca_count.astype(jnp.int32), Gp, 0),
+            prep(ca_cursor.astype(jnp.int32), Gp, 0),
+            prep(ng_max.astype(jnp.int32), Gp, 0),
+            prep(ng_slots.astype(jnp.int32), Gp, 0),
+            prep(ng_tmpl_cpu.astype(jnp.int32), Gp, 0),
+            prep(ng_tmpl_ram.astype(jnp.int32), Gp, 0),
+            prep(ng_start.astype(jnp.int32), Gp, 0),
+            prep(cvalid.astype(jnp.int32), Kp, 0),
+            prep(creq_cpu.astype(jnp.int32), Kp, 0),
+            prep(creq_ram.astype(jnp.int32), Kp, 0),
+        )
 
     meta_spec = pl.BlockSpec((_SUB, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     slot_spec = pl.BlockSpec((Sp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
@@ -473,5 +476,6 @@ def fused_ca_scale_up(
         )(*args)
 
     # starved as (C, 1): every output leads with the cluster axis.
-    return planned_o[:S, :C].T != 0, gpl_o[:Gn, :C].T, starved_o[0:1, :C].T
+    with jax.named_scope("kernel_io"):
+        return planned_o[:S, :C].T != 0, gpl_o[:Gn, :C].T, starved_o[0:1, :C].T
 

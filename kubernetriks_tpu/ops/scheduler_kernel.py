@@ -551,25 +551,27 @@ def fused_select_schedule_cycle(
     def prep(x, n_sub, fill):
         return _pad_axis(_pad_axis(x.astype(jnp.int32).T, 0, n_sub, fill), 1, Cp, fill)
 
-    alive_p = _prep_node(alive, nodes_lane_major, Np, Cp, 0)
-    cpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
-    ram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
-    elig_p = prep(eligible, Pp, 0)
-    qwin_p = prep(qwin, Pp, 0)
-    # Non-negative f32 bit patterns sort like the floats; move them through
-    # the kernel as i32 so every block shares one dtype.
-    qoff_p = prep(jax.lax.bitcast_convert_type(qoff, jnp.int32), Pp, 0)
-    qseq_p = prep(qseq, Pp, 0)
-    reqc_p = prep(pod_req_cpu, Pp, 0)
-    reqr_p = prep(pod_req_ram, Pp, 0)
+    with jax.named_scope("kernel_io"):
+        alive_p = _prep_node(alive, nodes_lane_major, Np, Cp, 0)
+        cpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
+        ram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
+        elig_p = prep(eligible, Pp, 0)
+        qwin_p = prep(qwin, Pp, 0)
+        # Non-negative f32 bit patterns sort like the floats; move them through
+        # the kernel as i32 so every block shares one dtype.
+        qoff_p = prep(jax.lax.bitcast_convert_type(qoff, jnp.int32), Pp, 0)
+        qseq_p = prep(qseq, Pp, 0)
+        reqc_p = prep(pod_req_cpu, Pp, 0)
+        reqr_p = prep(pod_req_ram, Pp, 0)
 
     node_spec = pl.BlockSpec((Np, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     pod_spec = pl.BlockSpec((Pp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     cand_spec = pl.BlockSpec((Kp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
-        spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
-    )
+    with jax.named_scope("kernel_io"):
+        spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
+            spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, cand_spec, cand_spec]
@@ -601,16 +603,17 @@ def fused_select_schedule_cycle(
             interpret=interpret,
         )(alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p, reqc_p, reqr_p, *spread_args)
 
-    return (
-        cand_o[:K, :C].T,
-        valid_o[:K, :C].T != 0,
-        assign_o[:K, :C].T != 0,
-        fitany_o[:K, :C].T != 0,
-        best_o[:K, :C].T,
-        _unprep_node(cpu_o, nodes_lane_major, N, C),
-        _unprep_node(ram_o, nodes_lane_major, N, C),
-        *(x[:K, :C].T for x in spread_o[1:]),
-    )
+    with jax.named_scope("kernel_io"):
+        return (
+            cand_o[:K, :C].T,
+            valid_o[:K, :C].T != 0,
+            assign_o[:K, :C].T != 0,
+            fitany_o[:K, :C].T != 0,
+            best_o[:K, :C].T,
+            _unprep_node(cpu_o, nodes_lane_major, N, C),
+            _unprep_node(ram_o, nodes_lane_major, N, C),
+            *(x[:K, :C].T for x in spread_o[1:]),
+        )
 
 
 # --- live row tiles: what a step of a serial pod-block kernel sweeps --------
@@ -890,14 +893,15 @@ def fused_free_resources(
     def prep(x, n_sub, fill):
         return _pad_axis(_pad_axis(x.T, 0, n_sub, fill), 1, Cp, fill)
 
-    freed_p = prep(freed.astype(jnp.int32), Pp, 0)
-    node_p = prep(node.astype(jnp.int32), Pp, -1)
-    reqc_p = prep(req_cpu.astype(jnp.int32), Pp, 0)
-    reqr_p = prep(req_ram.astype(jnp.int32), Pp, 0)
-    fin_p = prep(finishes.astype(jnp.int32), Pp, 0)
-    val_p = prep(value.astype(jnp.float32), Pp, 0.0)
-    acpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
-    aram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
+    with jax.named_scope("kernel_io"):
+        freed_p = prep(freed.astype(jnp.int32), Pp, 0)
+        node_p = prep(node.astype(jnp.int32), Pp, -1)
+        reqc_p = prep(req_cpu.astype(jnp.int32), Pp, 0)
+        reqr_p = prep(req_ram.astype(jnp.int32), Pp, 0)
+        fin_p = prep(finishes.astype(jnp.int32), Pp, 0)
+        val_p = prep(value.astype(jnp.float32), Pp, 0.0)
+        acpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
+        aram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
 
     node_spec = pl.BlockSpec((Np, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     pod_spec = pl.BlockSpec((Pp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
@@ -922,11 +926,12 @@ def fused_free_resources(
             interpret=interpret,
         )(freed_p, node_p, reqc_p, reqr_p, fin_p, val_p, acpu_p, aram_p)
 
-    return (
-        _unprep_node(acpu_o, nodes_lane_major, N, C),
-        _unprep_node(aram_o, nodes_lane_major, N, C),
-        stats_o[:5, :C].T,
-    )
+    with jax.named_scope("kernel_io"):
+        return (
+            _unprep_node(acpu_o, nodes_lane_major, N, C),
+            _unprep_node(aram_o, nodes_lane_major, N, C),
+            stats_o[:5, :C].T,
+        )
 
 
 def event_kernel_fits(n_nodes: int, n_pods: int, n_events: int) -> bool:
@@ -1082,13 +1087,14 @@ def event_accumulators_unpack(
     (N, C) with nodes_lane_major (a slice, no transpose); the three pod
     planes (C, P)."""
     created, node_removal, pod_create, pod_create_seq, pod_removal = acc
-    return (
-        _unprep_node(created, nodes_lane_major, n_nodes, n_clusters) != 0,
-        _unprep_node(node_removal, nodes_lane_major, n_nodes, n_clusters),
-        _unprep_node(pod_create, False, n_pods, n_clusters),
-        _unprep_node(pod_create_seq, False, n_pods, n_clusters),
-        _unprep_node(pod_removal, False, n_pods, n_clusters),
-    )
+    with jax.named_scope("kernel_io"):
+        return (
+            _unprep_node(created, nodes_lane_major, n_nodes, n_clusters) != 0,
+            _unprep_node(node_removal, nodes_lane_major, n_nodes, n_clusters),
+            _unprep_node(pod_create, False, n_pods, n_clusters),
+            _unprep_node(pod_create_seq, False, n_pods, n_clusters),
+            _unprep_node(pod_removal, False, n_pods, n_clusters),
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -1125,18 +1131,19 @@ def fused_event_scatter(
     def prep(x, fill):
         return _pad_axis(_pad_axis(x.T, 0, Ep, fill), 1, Cp, fill)
 
-    args = (
-        prep(ev_kind.astype(jnp.int32), 0),
-        prep(ev_slot.astype(jnp.int32), -1),
-        prep(ev_rel.astype(jnp.float32), 0.0),
-        prep(ev_seq.astype(jnp.int32), 0),
-        prep(ev_valid.astype(jnp.int32), 0),
-        created,
-        node_removal,
-        pod_create,
-        pod_create_seq,
-        pod_removal,
-    )
+    with jax.named_scope("kernel_io"):
+        args = (
+            prep(ev_kind.astype(jnp.int32), 0),
+            prep(ev_slot.astype(jnp.int32), -1),
+            prep(ev_rel.astype(jnp.float32), 0.0),
+            prep(ev_seq.astype(jnp.int32), 0),
+            prep(ev_valid.astype(jnp.int32), 0),
+            created,
+            node_removal,
+            pod_create,
+            pod_create_seq,
+            pod_removal,
+        )
 
     def spec(n_sub):
         return pl.BlockSpec((n_sub, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
@@ -1265,16 +1272,17 @@ def fused_commit_scatter(
     def prep(x, n_sub, fill):
         return _pad_axis(_pad_axis(x.T, 0, n_sub, fill), 1, Cp, fill)
 
-    args = (
-        prep(cand.astype(jnp.int32), Kp, -1),
-        prep(assign.astype(jnp.int32), Kp, 0),
-        prep(park.astype(jnp.int32), Kp, 0),
-        prep(best.astype(jnp.int32), Kp, 0),
-        prep(start_s.astype(jnp.float32), Kp, 0.0),
-        prep(park_s.astype(jnp.float32), Kp, 0.0),
-        prep(phase.astype(jnp.int32), Pp, 0),
-        prep(node.astype(jnp.int32), Pp, 0),
-    )
+    with jax.named_scope("kernel_io"):
+        args = (
+            prep(cand.astype(jnp.int32), Kp, -1),
+            prep(assign.astype(jnp.int32), Kp, 0),
+            prep(park.astype(jnp.int32), Kp, 0),
+            prep(best.astype(jnp.int32), Kp, 0),
+            prep(start_s.astype(jnp.float32), Kp, 0.0),
+            prep(park_s.astype(jnp.float32), Kp, 0.0),
+            prep(phase.astype(jnp.int32), Pp, 0),
+            prep(node.astype(jnp.int32), Pp, 0),
+        )
 
     def spec(n_sub):
         return pl.BlockSpec((n_sub, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
@@ -1298,12 +1306,13 @@ def fused_commit_scatter(
             interpret=interpret,
         )(*args)
 
-    return (
-        phase_o[:P, :C].T,
-        node_o[:P, :C].T,
-        start_o[:P, :C].T,
-        park_o[:P, :C].T,
-    )
+    with jax.named_scope("kernel_io"):
+        return (
+            phase_o[:P, :C].T,
+            node_o[:P, :C].T,
+            start_o[:P, :C].T,
+            park_o[:P, :C].T,
+        )
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, to: int, value) -> jnp.ndarray:
@@ -1350,19 +1359,21 @@ def fused_schedule_cycle(
         # (C, n) -> padded transposed (n_sub, Cp) with clusters on lanes.
         return _pad_axis(_pad_axis(x.astype(jnp.int32).T, 0, n_sub, fill), 1, Cp, fill)
 
-    alive_p = _prep_node(alive, nodes_lane_major, Np, Cp, 0)
-    cpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
-    ram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
-    valid_p = prep(valid, Kp, 0)
-    reqc_p = prep(req_cpu, Kp, 0)
-    reqr_p = prep(req_ram, Kp, 0)
+    with jax.named_scope("kernel_io"):
+        alive_p = _prep_node(alive, nodes_lane_major, Np, Cp, 0)
+        cpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
+        ram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
+        valid_p = prep(valid, Kp, 0)
+        reqc_p = prep(req_cpu, Kp, 0)
+        reqr_p = prep(req_ram, Kp, 0)
 
     node_spec = pl.BlockSpec((Np, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     cand_spec = pl.BlockSpec((Kp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
-        spread, nodes_lane_major, Np, Cp, Kp, node_spec, cand_spec
-    )
+    with jax.named_scope("kernel_io"):
+        spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
+            spread, nodes_lane_major, Np, Cp, Kp, node_spec, cand_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, cand_spec, cand_spec]
@@ -1390,14 +1401,15 @@ def fused_schedule_cycle(
             interpret=interpret,
         )(alive_p, cpu_p, ram_p, valid_p, reqc_p, reqr_p, *spread_args)
 
-    return (
-        assign_o[:K, :C].T != 0,
-        fitany_o[:K, :C].T != 0,
-        best_o[:K, :C].T,
-        _unprep_node(cpu_o, nodes_lane_major, N, C),
-        _unprep_node(ram_o, nodes_lane_major, N, C),
-        *(x[:K, :C].T for x in spread_o[1:]),
-    )
+    with jax.named_scope("kernel_io"):
+        return (
+            assign_o[:K, :C].T != 0,
+            fitany_o[:K, :C].T != 0,
+            best_o[:K, :C].T,
+            _unprep_node(cpu_o, nodes_lane_major, N, C),
+            _unprep_node(ram_o, nodes_lane_major, N, C),
+            *(x[:K, :C].T for x in spread_o[1:]),
+        )
 
 
 # --- round-4 megakernel: selection + cycle + commit in ONE launch -----------
@@ -1676,30 +1688,32 @@ def fused_select_cycle_commit(
             _pad_axis(x.astype(jnp.float32).T, 0, n_sub, fill), 1, Cp, fill
         )
 
-    alive_p = _prep_node(alive, nodes_lane_major, Np, Cp, 0)
-    cpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
-    ram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
-    elig_p = prep(eligible, Pp, 0)
-    qwin_p = prep(qwin, Pp, 0)
-    qoff_p = prep(jax.lax.bitcast_convert_type(qoff, jnp.int32), Pp, 0)
-    qseq_p = prep(qseq, Pp, 0)
-    reqc_p = prep(pod_req_cpu, Pp, 0)
-    reqr_p = prep(pod_req_ram, Pp, 0)
-    waited_p = prep_f(waited, Pp, 0.0)
-    phase_p = prep(phase, Pp, 0)
-    node_p = prep(node, Pp, 0)
-    qpre_p = prep_f(qpre_t, Kp, 0.0)
-    start_p = prep_f(start_t, Kp, 0.0)
-    park_p = prep_f(park_t, Kp, 0.0)
+    with jax.named_scope("kernel_io"):
+        alive_p = _prep_node(alive, nodes_lane_major, Np, Cp, 0)
+        cpu_p = _prep_node(alloc_cpu, nodes_lane_major, Np, Cp, 0)
+        ram_p = _prep_node(alloc_ram, nodes_lane_major, Np, Cp, 0)
+        elig_p = prep(eligible, Pp, 0)
+        qwin_p = prep(qwin, Pp, 0)
+        qoff_p = prep(jax.lax.bitcast_convert_type(qoff, jnp.int32), Pp, 0)
+        qseq_p = prep(qseq, Pp, 0)
+        reqc_p = prep(pod_req_cpu, Pp, 0)
+        reqr_p = prep(pod_req_ram, Pp, 0)
+        waited_p = prep_f(waited, Pp, 0.0)
+        phase_p = prep(phase, Pp, 0)
+        node_p = prep(node, Pp, 0)
+        qpre_p = prep_f(qpre_t, Kp, 0.0)
+        start_p = prep_f(start_t, Kp, 0.0)
+        park_p = prep_f(park_t, Kp, 0.0)
 
     node_spec = pl.BlockSpec((Np, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     pod_spec = pl.BlockSpec((Pp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     cand_spec = pl.BlockSpec((Kp, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
     stat_spec = pl.BlockSpec((8, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    spread_shape, spread_args, spread_in, table_spec, tile_spec, table_shape = _spread_operands(
-        spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
-    )
+    with jax.named_scope("kernel_io"):
+        spread_shape, spread_args, spread_in, table_spec, tile_spec, table_shape = _spread_operands(
+            spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, pod_spec, tile_spec]
@@ -1742,13 +1756,14 @@ def fused_select_cycle_commit(
             qpre_p, start_p, park_p, *spread_args,
         )
 
-    return (
-        _unprep_node(cpu_o, nodes_lane_major, N, C),
-        _unprep_node(ram_o, nodes_lane_major, N, C),
-        phase_o[:P, :C].T,
-        node_o[:P, :C].T,
-        start_o[:P, :C].T,
-        park_o[:P, :C].T,
-        stats_o[:, :C].T,
-        *((spread_o[1][:P, :C].T, spread_o[2][:2, :C].T) if spread_o else ()),
-    )
+    with jax.named_scope("kernel_io"):
+        return (
+            _unprep_node(cpu_o, nodes_lane_major, N, C),
+            _unprep_node(ram_o, nodes_lane_major, N, C),
+            phase_o[:P, :C].T,
+            node_o[:P, :C].T,
+            start_o[:P, :C].T,
+            park_o[:P, :C].T,
+            stats_o[:, :C].T,
+            *((spread_o[1][:P, :C].T, spread_o[2][:2, :C].T) if spread_o else ()),
+        )

@@ -34,6 +34,10 @@ Consumers:
 - `report()` — the aggregated per-phase table (count / total / mean /
   max), exact even when the event ring wraps, because aggregates update
   on every `end()` rather than from the kept events.
+- `program_phases()` — for every program an engine noted at a dispatch
+  site (`program()`), which device phase (`DEVICE_PHASES`, the named
+  scopes of the window body) each instruction of its compiled text
+  belongs to; `benchmark/phase_times.py` joins it with a device trace.
 - `EngineSpans` (`recorder().handle()`) — what an engine holds as
   `engine.tracer`: the same surface, every span and counter written to
   the shared ring AND into the handle's own aggregates, so
@@ -49,10 +53,12 @@ from __future__ import annotations
 
 import functools
 import json
+import re
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -147,6 +153,183 @@ PHASE_NAMES = (
     "compile",
     "trace_ingest",
 )
+
+# Device phases: the closed set of `jax.named_scope` names the window
+# programs carry (batched/step.py, batched/autoscale.py and the kernel
+# wrappers of ops/), so that every device op of a window says in its
+# `op_name` path which part of the simulator it belongs to. Seven top-level
+# phases partition `_window_body` and the loops round it; `kernel_io` is the
+# one NESTED phase, inside the kernel wrappers, round the pads, transposes,
+# casts and slices that marshal a pallas_call's operands and results (never
+# round the call itself). An op's TOP-LEVEL phase is the FIRST name of this
+# tuple in its `op_name` path, its INNERMOST phase the LAST (`phase_of`);
+# other scopes (`spread_counts`, `ca_scale_up`, `ca_scale_down`) nest inside
+# a phase and name no phase themselves. A scope is location metadata: the
+# lowered program with debug locations stripped does not change by a byte.
+DEVICE_PHASES = (
+    "events",  # _apply_window_events: the razor, the slab read, the event loop, the frees, the wake
+    "cycle",  # _run_scheduling_cycle: queue, candidates, the decision kernels, the commit
+    "hpa_pass",
+    "ca_pass",
+    "ca_reclaim",
+    "slide",  # the pod window's shift, refill and the superspan's capacity read
+    "bookkeeping",  # lane freeze, the ring's record, fast-forward, layout swaps, loop counters
+    "kernel_io",  # nested: a kernel wrapper's operand and result marshalling
+)
+
+
+def phase_of(op_name: str) -> Optional[Tuple[str, str]]:
+    """(top-level, innermost) device phase of one op, from the scopes of its
+    `op_name` path (`jit(run_windows)/while/body/cycle/kernel_io/pad`):
+    the first and the last component that names a phase; None where the
+    path names none."""
+    found = [part for part in op_name.split("/") if part in DEVICE_PHASES]
+    return (found[0], found[-1]) if found else None
+
+
+# An instruction line of optimized HLO text, its opcode, its `op_name`, and
+# the computations it names (`compiled.as_text()`).
+_HLO_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->\s.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s=\s(.*)$")
+_HLO_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_ATTRIBUTES = re.compile(r", (?:metadata|backend_config|frontend_attributes)=")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_HLO_CALLED = re.compile(
+    r"\b(calls|to_apply|body|condition|true_computation|false_computation"
+    r"|branch_computations)=(\{[^}]*\}|%?[\w.\-]+)"
+)
+# Who runs a called computation's instructions as device ops of their own
+# (the trace times them one by one): a loop, a branch, a call. A fusion's,
+# a reduce's or a sort's computation runs inside the one op that names it.
+_HLO_RUNS_CALLED = {"while", "conditional", "call", "async-start"}
+
+
+def instruction_phases(hlo_text: str) -> Dict[str, Optional[Tuple[str, str, str]]]:
+    """{instruction name: (top-level phase, innermost phase, how it is
+    known), None without one} over the instructions of an optimized HLO
+    module that run as device ops of their own: those of the entry
+    computation and of every loop body, loop condition, branch and called
+    computation reached from it. A fused computation's inner instructions
+    are left out: the trace times the fusion, which carries ONE `op_name`,
+    its root's, and goes whole to that phase even where XLA fused ops of
+    two.
+
+    `how` is `"scope"` where the instruction's own `op_name` names the phase
+    (`phase_of`). The compiler also makes instructions that carry none: the
+    copies, `copy-start` / `copy-done` pairs and broadcasts that lay out a
+    loop's or a branch's operands, the pieces a cumulative sum is expanded
+    into. Such an instruction takes the phase of what CONSUMES it
+    (`"consumer"`: followed through other phaseless instructions, tuples
+    included, to the first that name a phase, where those agree on the
+    top-level phase) or, where nothing that consumes it names one (a loop's
+    carry), of what PRODUCES its operands (`"producer"`); it stays None
+    where its neighbours disagree or name nothing."""
+    computations: Dict[str, list] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        if current is None:
+            head = _HLO_COMPUTATION.match(line)
+            if head:
+                current = computations.setdefault(head.group(2), [])
+                if head.group(1):
+                    entry = head.group(2)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        inst = _HLO_INSTRUCTION.match(line)
+        if inst:
+            current.append(inst.groups())
+    out: Dict[str, Optional[Tuple[str, str, str]]] = {}
+    todo, seen = [entry], {entry}
+    while todo:
+        instructions = computations.get(todo.pop(), ())
+        own: Dict[str, Optional[Tuple[str, str]]] = {}
+        for name, rest in instructions:
+            op_name = _HLO_OP_NAME.search(rest)
+            own[name] = phase_of(op_name.group(1)) if op_name else None
+        operands: Dict[str, list] = {}
+        users: Dict[str, list] = {name: [] for name in own}
+        for name, rest in instructions:
+            reads = _HLO_ATTRIBUTES.split(rest, 1)[0]
+            operands[name] = [o for o in _HLO_OPERAND.findall(reads) if o in own and o != name]
+            for operand in operands[name]:
+                users[operand].append(name)
+            opcode = _HLO_OPCODE.search(rest)
+            if opcode is None or opcode.group(1) not in _HLO_RUNS_CALLED:
+                continue
+            for _, called in _HLO_CALLED.findall(rest):
+                for comp in re.findall(r"[\w.\-]+", called):
+                    if comp not in seen:
+                        seen.add(comp)
+                        todo.append(comp)
+        for name, phases in own.items():
+            out[name] = phases + ("scope",) if phases else _neighbours_phase(name, own, users, operands)
+    return out
+
+
+def _neighbours_phase(name, own, users, operands) -> Optional[Tuple[str, str, str]]:
+    """The phase a phaseless instruction takes from its consumers, else from
+    its producers (`instruction_phases`), or None."""
+    for edges, how in ((users, "consumer"), (operands, "producer")):
+        found, todo, seen = set(), [name], {name}
+        while todo:
+            for nxt in edges[todo.pop()]:
+                if nxt in seen:
+                    continue
+                seen.add(nxt)
+                if own[nxt] is not None:
+                    found.add(own[nxt])
+                else:
+                    todo.append(nxt)
+        tops = {top for top, _ in found}
+        if len(tops) == 1:
+            inners = {inner for _, inner in found}
+            top = tops.pop()
+            return top, inners.pop() if len(inners) == 1 else top, how
+        if found:
+            return None  # the consumers disagree
+    return None
+
+
+def _abstract(leaf):
+    """A dispatched operand as the shape a later lowering needs: no array,
+    no device memory. A committed device array keeps its sharding, so that
+    the lowering is the dispatch's own and its executable is found again."""
+    if isinstance(leaf, jax.Array):
+        sharding = leaf.sharding if getattr(leaf, "committed", True) else None
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding)
+    if isinstance(leaf, (np.ndarray, np.generic)):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+    return leaf
+
+
+def _program_label(key: tuple) -> str:
+    serial, name, variant = key
+    return f"{name}{list(variant)}@engine{serial}"
+
+
+class _Program:
+    """One dispatched program: what gets its compiled text later, and the
+    map read from it once asked for."""
+
+    __slots__ = ("fn", "args", "kwargs", "phases", "first_ns", "last_ns")
+
+    def __init__(self, fn: Callable, args, kwargs):
+        self.fn = fn
+        self.args, self.kwargs = jax.tree.map(_abstract, (args, kwargs))
+        self.phases: Optional[Dict[str, Optional[Tuple[str, str, str]]]] = None
+        # perf_counter_ns of the first and the newest dispatch
+        self.first_ns = self.last_ns = time.perf_counter_ns()
+
+    def read_phases(self) -> Dict[str, Optional[Tuple[str, str, str]]]:
+        if self.phases is None:
+            compiled = self.fn.lower(*self.args, **self.kwargs).compile()
+            self.phases = instruction_phases(compiled.as_text())
+            self.fn = self.args = self.kwargs = None
+        return self.phases
+
 
 _N_PHASES = len(PHASE_NAMES)
 ANNOTATION_PREFIX = "ktpu:"
@@ -251,6 +434,11 @@ class SpanTracer(_Aggregates):
         # is the ordinal of its entry since process start.
         self.compiles: deque = deque(maxlen=_COMPILES_KEPT)
         self.compiles_recorded = 0
+        # Every distinct program an engine dispatched inside a window, by
+        # (engine handle, name, variant): what gets its compiled text later
+        # (program_phases). A dozen a process.
+        self._programs: Dict[tuple, _Program] = {}
+        self._n_handles = 0
         self._epoch = time.perf_counter_ns()
 
     # -- hot path ----------------------------------------------------------
@@ -299,6 +487,20 @@ class SpanTracer(_Aggregates):
         buf[i, 2] = value
         self._n_samples += 1
 
+    def program(self, key: tuple, fn: Callable, args: tuple, kwargs: dict) -> None:
+        """Note the program a dispatch site is about to run: `fn(*args,
+        **kwargs)`, a jitted function with the operands it gets. One
+        dictionary lookup a dispatch; the first dispatch of a distinct
+        `key` keeps the function and the abstract shapes and shardings of
+        its operands (statics as they are), nothing lowered or compiled.
+        Every dispatch leaves its time, so that a reader can tell the
+        programs that ran in a window from those a warm-up ran."""
+        program = self._programs.get(key)
+        if program is None:
+            self._programs[key] = _Program(fn, args, kwargs)
+        else:
+            program.last_ns = time.perf_counter_ns()
+
     def compile_event(self, name: str, seconds: float) -> None:
         """One XLA compilation (or cache load) that just finished, as
         jax's compile log reports it: a `compile` row ending now, and
@@ -345,7 +547,8 @@ class SpanTracer(_Aggregates):
 
     def handle(self) -> "EngineSpans":
         """A new per-engine handle on this recorder."""
-        return EngineSpans(self)
+        self._n_handles += 1
+        return EngineSpans(self, self._n_handles)
 
     # -- readers -------------------------------------------------------------
 
@@ -362,6 +565,52 @@ class SpanTracer(_Aggregates):
         if key is None:
             return kept[:0, :2].copy()
         return kept[kept[:, 1] == key][:, (0, 2)]
+
+    def program_phases(
+        self,
+        since_ns: int = 0,
+        until_ns: Optional[int] = None,
+        handle: Optional[int] = None,
+    ) -> Dict[str, Dict[str, Optional[Tuple[str, str, str]]]]:
+        """{program: {instruction name: (top-level device phase, innermost
+        device phase, how it is known: by its own scope, or from its
+        consumers or producers) or None}} of every program dispatched so far
+        (`instruction_phases` of its compiled text), or of those whose dispatches, first to
+        newest, reach into `[since_ns, until_ns)` on
+        `time.perf_counter_ns()`: the programs that ran in a window, not
+        what a warm-up or a later engine ran (`handle`: one engine
+        handle's alone, `EngineSpans.program_phases`). On demand: the first call
+        after a program's first dispatch lowers it from the kept shapes and
+        compiles it, which finds the dispatch's own executable again (jax
+        keeps it by the same lowering; at worst a load from the persistent
+        compile cache), and the answer is kept."""
+        return {
+            _program_label(key): program.read_phases()
+            for key, program in list(self._programs.items())
+            if program.last_ns >= since_ns
+            and (until_ns is None or program.first_ns < until_ns)
+            and handle in (None, key[0])
+        }
+
+    def device_phases(self, handle: Optional[int] = None) -> dict:
+        """The closed set of device phases and, for each program dispatched
+        so far (one engine handle's, or all), how many of its instructions
+        each top-level phase holds (`inherited`: those of them that name no
+        phase themselves and take their consumers' or producers'). Forces
+        no compile: a program nobody asked `program_phases()` about yet
+        reports nothing."""
+        programs = {}
+        for key, program in self._programs.items():
+            if program.phases is None or handle not in (None, key[0]):
+                continue
+            counts: Dict[str, int] = {}
+            for phases in program.phases.values():
+                top = phases[0] if phases else "unscoped"
+                counts[top] = counts.get(top, 0) + 1
+                if phases and phases[2] != "scope":
+                    counts["inherited"] = counts.get("inherited", 0) + 1
+            programs[_program_label(key)] = counts
+        return {"phases": list(DEVICE_PHASES), "programs": programs}
 
     def dropped(self) -> Dict[str, int]:
         """Rows each ring has wrapped out (0 = everything recorded is
@@ -501,6 +750,7 @@ class SpanTracer(_Aggregates):
             "recorded": int(self._n_lane_spans),
             "kept": int(min(self._n_lane_spans, self._lane_spans.shape[0])),
         }
+        rep["device_phases"] = self.device_phases()
         return rep
 
 
@@ -512,9 +762,10 @@ class EngineSpans(_Aggregates):
     another engine's time. `span_events` / `lane_spans` in it describe
     the shared rings."""
 
-    def __init__(self, rec: SpanTracer):
+    def __init__(self, rec: SpanTracer, serial: int):
         super().__init__()
         self._rec = rec
+        self._serial = serial
         self.begin = rec.begin
         self.flow_start = rec.flow_start
         self.flow_end = rec.flow_end
@@ -535,9 +786,22 @@ class EngineSpans(_Aggregates):
         self.counters[name] = self.counters.get(name, 0) + n
         self._rec.count(name, n)
 
+    def program(self, name: str, variant: tuple, fn: Callable, args: tuple, kwargs: dict) -> None:
+        """`SpanTracer.program` under this engine's key: the program's
+        name and whatever tells two of its compiled shapes apart (a chunk's
+        length, a flag, the pod window's width)."""
+        self._rec.program((self._serial, name, variant), fn, args, kwargs)
+
+    def program_phases(self) -> Dict[str, Dict[str, Optional[Tuple[str, str, str]]]]:
+        """`SpanTracer.program_phases` of this engine's programs alone: a
+        process that has built other engines reads (and, where jax has
+        dropped their executables, compiles) none of theirs."""
+        return self._rec.program_phases(handle=self._serial)
+
     def report(self) -> dict:
         rep = self._rec.report()
         rep.update(super().report())
+        rep["device_phases"] = self._rec.device_phases(self._serial)
         return rep
 
 
