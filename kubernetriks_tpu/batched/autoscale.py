@@ -406,6 +406,61 @@ def _broadcast_pair(p: TPair, shape) -> TPair:
     )
 
 
+def _rows_at(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Row c of `x` (C, L) at the indices idx[c, :] ((C, M) int32): the
+    value of `x[rows, idx]` / `take_along_axis(x, idx, 1)`, bit for bit, as
+    a dense contraction — `where(idx == l, x[c, l], 0)` reduced over L —
+    instead of an XLA gather. The TPU's gather costs per INDEX whatever it
+    reads (4.5-10 ns each at the composed shapes, PERF.md section 5); the
+    fused compare-and-reduce costs per ELEMENT (C x M x L of them at some
+    0.4-1.8 ps), which is the cheaper side wherever L is a node or slot
+    axis. Exact: each index matches one l, so the reduction adds zeros to
+    one value (`any` for bools; floats travel as their int32 bit patterns,
+    so -0.0, inf and nan payloads come back as they went in). With L == 1
+    (one node group) the look-up is a broadcast.
+
+    CONTRACT: 0 <= idx < L. Every call site clips its index first; an
+    index outside the row matches nothing and reads 0 / False where the
+    gather would have clamped."""
+    L = x.shape[1]
+    if L == 1:
+        return jnp.broadcast_to(x, idx.shape)
+    hit = idx[:, None, :] == jnp.arange(L, dtype=idx.dtype)[None, :, None]
+    if x.dtype == jnp.bool_:
+        return (hit & x[:, :, None]).any(axis=1)
+    bits = x if x.dtype == jnp.int32 else jax.lax.bitcast_convert_type(x, jnp.int32)
+    out = jnp.where(hit, bits[:, :, None], 0).sum(axis=1, dtype=jnp.int32)
+    return out if x.dtype == jnp.int32 else jax.lax.bitcast_convert_type(out, x.dtype)
+
+
+def _rows_put(idx: jnp.ndarray, v: jnp.ndarray, L: int) -> jnp.ndarray:
+    """The other direction: out[c, l] = the `v[c, j]` whose idx[c, j] == l,
+    combined over j — summed for int32 `v` (`zeros.at[rows, idx].add(v)`;
+    equally `.set(v)` where no two j share a target, as through a
+    permutation), or-ed for bool `v` (`zeros.at[rows, idx].set(True)` under
+    the mask `v`). An index outside [0, L) matches no column and drops,
+    like `mode="drop"`. Dense for the reason `_rows_at` is: the TPU's
+    scatter sorts its indices and pays per index too."""
+    hit = idx[:, :, None] == jnp.arange(L, dtype=idx.dtype)[None, None, :]
+    if v.dtype == jnp.bool_:
+        return (hit & v[:, :, None]).any(axis=1)
+    return jnp.where(hit, v[:, :, None], 0).sum(axis=1, dtype=jnp.int32)
+
+
+def _segment_sums(key: jnp.ndarray, N: int, *values: jnp.ndarray):
+    """Per-segment totals of (C, P) rows grouped by an int32 key in [0, N]
+    (N = in no segment), WITHOUT sorting. Returns (start (C, N): the keys
+    below n, which is where segment n starts once the row is sorted by
+    key; [for each value, (C, N): sum_p where(key == n, value, 0), a bool
+    value counting]): `_rows_put` by the key, so XLA makes ONE (C, P, N)
+    compare of it and reduces that over P every way asked. int32 adds in
+    any order: equal, bit for bit, to the differences of the sorted row's
+    cumulative sums at the segment's two boundaries, wrap-around included."""
+    col = jnp.arange(N, dtype=key.dtype)[None, None, :]
+    start = (key[:, :, None] < col).sum(axis=1, dtype=jnp.int32)
+    return start, [_rows_put(key, v.astype(jnp.int32), N) for v in values]
+
+
 def decimal_string_key(idx: jnp.ndarray) -> jnp.ndarray:
     """int32 key whose order equals the LEXICOGRAPHIC order of str(idx)
     for 0 <= idx < 10^8 ("g_10" < "g_2"): left-align the value to 8
@@ -428,7 +483,12 @@ def decimal_string_key(idx: jnp.ndarray) -> jnp.ndarray:
         [0, 10_000_000, 1_000_000, 100_000, 10_000, 1_000, 100, 10, 1],
         jnp.int32,
     )
-    return idx * pow10[digits] * jnp.int32(16) + digits
+    # pow10[digits] as nine selects: a table look-up is an XLA gather, paid
+    # per index on the TPU (_rows_at).
+    scale = jnp.where(
+        digits[..., None] == jnp.arange(9, dtype=jnp.int32), pow10, 0
+    ).sum(axis=-1, dtype=jnp.int32)
+    return idx * scale * jnp.int32(16) + digits
 
 
 def ca_name_order(
@@ -453,7 +513,6 @@ def ca_name_order(
     orders coincide with the static tables exactly."""
     C, S = auto.ca_alloc.shape
     Gn = st.ca_class_start.shape[1]
-    rows = jnp.arange(C, dtype=jnp.int32)[:, None]
     iota_s = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (C, S))
     occupied = auto.ca_alloc >= 0
     suffix = jnp.where(
@@ -465,15 +524,16 @@ def ca_name_order(
     )
     # Sorted position of each slot -> within-group rank (each group's
     # slots are contiguous in class order; ca_class_start is the static
-    # first position of the group's segment).
-    pos = jnp.zeros((C, S), jnp.int32).at[rows, sd_order].set(iota_s)
+    # first position of the group's segment). sd_order is a permutation:
+    # its inverse is one dense put (_rows_put), no scatter.
+    pos = _rows_put(sd_order, iota_s, S)
     gidc = jnp.clip(st.ca_slot_group, 0, Gn - 1)
     within = jnp.where(
-        occupied, pos - st.ca_class_start[rows, gidc], 0
+        occupied, pos - _rows_at(st.ca_class_start, gidc), 0
     )
     N = st.node_class_key.shape[1]
     tgt = jnp.where(occupied & (st.ca_slots >= 0), st.ca_slots, N)
-    node_key = st.node_class_key.at[rows, tgt].add(within, mode="drop")
+    node_key = st.node_class_key + _rows_put(tgt, within, N)
     return sd_order, node_key
 
 
@@ -941,7 +1001,6 @@ def _ca_scale_up(
     S = st.ca_slots.shape[1]
     Gn = st.ng_ca_start.shape[1]
     rows1 = jnp.arange(C, dtype=jnp.int32)
-    rows = rows1[:, None]
 
     # The storage unscheduled-pods cache: parked pods plus woken-but-unscheduled
     # pods (attempts>=2 after a wake, reference: persistent_storage.rs cache
@@ -979,9 +1038,9 @@ def _ca_scale_up(
         num_keys=4, is_stable=True,
     )
     order = order_full[:, :K_up]
-    cvalid = in_cache[rows, order] & branch[:, None]
-    creq_cpu = pods.req_cpu[rows, order]
-    creq_ram = pods.req_ram[rows, order]
+    cvalid = _rows_at(in_cache, order) & branch[:, None]
+    creq_cpu = _rows_at(pods.req_cpu, order)
+    creq_ram = _rows_at(pods.req_ram, order)
 
     if use_pallas and ca_up_kernel_fits(S, Gn, K_up):
         core = partial(
@@ -1147,19 +1206,28 @@ def _ca_scale_down(
     re-placement, whether the node has finished it or not.
 
     descatter (KTPU_CA_DESCATTER, r9 — round 3 of the de-scatter
-    campaign): the correction segment-sum and the node-grouping sort above
-    were the down-cond's two remaining expensive blocks after r5 (each a
-    (C, P) sort + a pair of (C, P, N) rank-count reductions — DESIGN.md
-    names them as the ~2.5 ms residue). They share a node key, so ONE
-    combined 2-key sort (node, on_any-last... see below) and ONE pair of
-    boundary reductions now serve both: the secondary key puts each node's
-    storage-RUNNING pods first in its segment (so the grouping tables
-    slice the same prefix the old single-key sort produced), the
-    correction deltas ride the same sort as values (untouched rows carry
-    0, so the full-segment integer sums equal the old touched-only sums
-    exactly), and the per-node running count folds from a sorted
-    indicator cumsum. Bit-exact by integer-additivity + stable-sort
-    prefix order; descatter=False keeps the r5 two-sort path for A/B."""
+    campaign): the correction segment-sum and the node-grouping sort
+    share a node key, so ONE combined 2-key sort serves the grouping (the
+    secondary key puts each node's storage-RUNNING pods first in its
+    segment, so the grouping tables slice the same prefix the r5
+    single-key sort produced) and ONE (C, P, N) compare of the key
+    against the node axis gives every per-node total (_segment_sums:
+    untouched rows carry 0, so the full-segment integer sums equal the old
+    touched-only sums exactly). Bit-exact by integer-additivity +
+    stable-sort prefix order; descatter=False keeps the r5 two-sort path
+    for A/B.
+
+    What this pass costs on the chip was measured op by op in PR 39's
+    traced runs and read in PR 40 (PERF.md sections 5 and 6): of the
+    parent's 12.6 ms `ca_pass` a stream window, 9.1 were XLA gathers (paid
+    per index: the six boundary reads of the cumulative sums 3.2, the
+    per-candidate pod table 2.1, every (C, S) row look-up 0.3-0.5) and 1.4
+    the sorts; the (C, P, N) rank-count pair that r9 and DESIGN.md called
+    "the ~2.5 ms residue" was 0.16. Hence the dense forms (_rows_at,
+    _rows_put, _segment_sums) at every look-up whose row is a node or slot
+    axis: no XLA gather or scatter is left in the kernel path of this
+    pass, in ca_pass or in ca_reclaim_pass. The XLA while_loop walk below
+    the kernel branch keeps its per-step reads."""
     pods, nodes = state.pods, state.nodes
     C, P = pods.phase.shape
     N = nodes.alive.shape[1]
@@ -1198,12 +1266,11 @@ def _ca_scale_down(
     vis_removed = (phase_v == PHASE_RUNNING) & t_le(pods.removal_time, snap_p)
     vis_gone = vis_gone | vis_removed | (held & (pods.phase == PHASE_REMOVED))
 
-    # Virtual allocatables as the storage sees them. The per-node
-    # correction sums are SEGMENT SUMS over a node-sorted copy of the
-    # deltas (sort + cumsum + boundary gathers) instead of a (C, P)
-    # scatter-add: XLA's TPU scatter lowering costs per-index
-    # (xplane-measured ~1.1 ms/window at the composed shape; this
-    # formulation is ~0.3). Integer adds, so any summation order is exact.
+    # Virtual allocatables as the storage sees them: the per-node
+    # correction sums are SEGMENT SUMS of the deltas by node, not a (C, P)
+    # scatter-add (XLA's TPU scatter lowering costs per index:
+    # xplane-measured ~1.1 ms/window at the composed shape). Integer adds,
+    # so any summation order is exact.
     node_c = jnp.clip(pods.node, 0, N - 1)
     d_cpu = jnp.where(vis_gone, pods.req_cpu, 0)
     d_ram = jnp.where(vis_gone, pods.req_ram, 0)
@@ -1218,39 +1285,28 @@ def _ca_scale_down(
         in_seg = touched | on_any
         key_node = jnp.where(in_seg, node_c, jnp.int32(N))
         key2 = jnp.where(on_any, 0, 1).astype(jnp.int32)
-        key_s, _, dc_s, dr_s, ind_s, rc_sorted, rr_sorted = jax.lax.sort(
-            (
-                key_node,
-                key2,
-                d_cpu,
-                d_ram,
-                on_any.astype(jnp.int32),
-                pods.req_cpu,
-                pods.req_ram,
-            ),
+        # Only the grouping rides the sort: the request values, which the
+        # per-candidate tables slice by segment below.
+        _, _, rc_sorted, rr_sorted = jax.lax.sort(
+            (key_node, key2, pods.req_cpu, pods.req_ram),
             dimension=1,
             num_keys=2,
             is_stable=True,
         )
-        # ONE pair of (C, P, N) rank-count boundary reductions shared by
-        # the correction and the grouping (was two pairs).
-        tstart = (key_s[:, :, None] < col_n[:, None, :]).sum(
-            axis=1, dtype=jnp.int32
+        # The per-node totals directly (_segment_sums), off the UNSORTED
+        # key: where node n's segment starts in the sorted order, the freed
+        # cpu / ram, and the storage-running count. They replace three
+        # cumulative sums over the sorted values read at both segment
+        # boundaries: six (C, N) gathers, 3.2 ms a stream window against
+        # 0.16 for the compare-and-sum pair this extends (PERF.md section
+        # 6, PR 40). Node n's segment LEADS with its on_any pods in slot
+        # order (stable sort, key2), so the grouping tables slice the same
+        # prefix the old single-key sort produced.
+        seg_start, (freed_cpu, freed_ram, seg_count) = _segment_sums(
+            key_node, N, d_cpu, d_ram, on_any
         )
-        tend = tstart + (key_s[:, :, None] == col_n[:, None, :]).sum(
-            axis=1, dtype=jnp.int32
-        )
-        ecs_c = jnp.concatenate([zero_col, jnp.cumsum(dc_s, axis=1)], axis=1)
-        ecs_r = jnp.concatenate([zero_col, jnp.cumsum(dr_s, axis=1)], axis=1)
-        ecs_n = jnp.concatenate([zero_col, jnp.cumsum(ind_s, axis=1)], axis=1)
-        alloc_cpu_v = alloc_cpu_v + ecs_c[rows, tend] - ecs_c[rows, tstart]
-        alloc_ram_v = alloc_ram_v + ecs_r[rows, tend] - ecs_r[rows, tstart]
-        # Node n's segment LEADS with its on_any pods in slot order (stable
-        # sort, key2), so the grouping tables slice the same prefix the old
-        # single-key sort produced; the running count folds from the
-        # indicator cumsum over the same boundaries.
-        seg_start = tstart
-        seg_count = ecs_n[rows, tend] - ecs_n[rows, tstart]
+        alloc_cpu_v = alloc_cpu_v + freed_cpu
+        alloc_ram_v = alloc_ram_v + freed_ram
     else:
         # r5 two-sort path, kept for A/B (KTPU_CA_DESCATTER=0).
         tkey = jnp.where(touched, node_c, jnp.int32(N))
@@ -1300,9 +1356,9 @@ def _ca_scale_down(
     # Candidate walk order and liveness, shared by both paths: CA slots in
     # node-name order, alive where allocated (the kernel derives its walk
     # bound from cand_alive; the XLA path bounds its while_loop the same way).
-    slot_perm = jnp.take_along_axis(st.ca_slots, sd_order, axis=1)
+    slot_perm = _rows_at(st.ca_slots, sd_order)
     slotc_perm = jnp.clip(slot_perm, 0, N - 1)
-    cand_alive = (slot_perm >= 0) & nodes.alive[rows, slotc_perm]
+    cand_alive = (slot_perm >= 0) & _rows_at(nodes.alive, slotc_perm)
 
     from kubernetriks_tpu.ops.autoscale_kernel import (
         ca_down_kernel_fits,
@@ -1310,26 +1366,23 @@ def _ca_scale_down(
     )
 
     if use_pallas and ca_down_kernel_fits(N, S, K_sd):
-        # Per-candidate pod tables in name order, via ONE stacked gather
-        # from the sort-carried request values (gather cost is per-index on
-        # TPU; the old porder->req double indirection paid three (C, S*K)
-        # gathers — xplane-measured ~4 ms/window at the composed shape).
+        # Per-candidate pod tables in name order: each candidate's K_sd
+        # slice of the sort-carried request values, S * K_sd look-ups a row
+        # out of P. Dense too: XLA fuses the (C, P, S * K_sd) compare with
+        # both reductions into one op with no temporary (0.93 ms a stream
+        # window against the stacked gather's 2.14; faster in the what-if
+        # as well: PERF.md section 6, PR 40).
         cnt_perm = jnp.where(
-            slot_perm >= 0, seg_count[rows, slotc_perm], 0
+            slot_perm >= 0, _rows_at(seg_count, slotc_perm), 0
         )
-        seg_pos = jnp.clip(seg_start[rows, slotc_perm], 0, P - 1)  # (C, S)
+        seg_pos = jnp.clip(_rows_at(seg_start, slotc_perm), 0, P - 1)  # (C, S)
         take = jnp.clip(
             seg_pos[:, :, None] + jnp.arange(K_sd, dtype=jnp.int32)[None, None, :],
             0,
             P - 1,
         ).reshape(C, S * K_sd)
-        pr = jnp.take_along_axis(
-            jnp.stack([rc_sorted, rr_sorted], axis=-1),
-            take[:, :, None],
-            axis=1,
-        )
-        pr_cpu = pr[..., 0]
-        pr_ram = pr[..., 1]
+        pr_cpu = _rows_at(rc_sorted, take)
+        pr_ram = _rows_at(rr_sorted, take)
         pv0 = (
             jnp.arange(K_sd, dtype=jnp.int32)[None, None, :]
             < cnt_perm[:, :, None]
@@ -1358,11 +1411,9 @@ def _ca_scale_down(
             pv0,
         )
         # Back from name-order positions to CA-slot indices (ca_sd_order is
-        # a permutation, so .set() touches each slot exactly once).
-        removed = (
-            jnp.zeros((C, S), bool).at[rows, sd_order].set(removed_perm)
-        )
-        return _per_group(removed, st, rows, Gn)
+        # a permutation, so each slot has exactly one source).
+        removed = _rows_put(sd_order, removed_perm, S)
+        return _per_group(removed, st, Gn)
 
     def outer(carry, s):
         valloc_cpu, valloc_ram = carry
@@ -1469,19 +1520,16 @@ def _ca_scale_down(
             jnp.zeros((C, S), bool),
         ),
     )
-    return _per_group(removed, st, rows, Gn)
+    return _per_group(removed, st, Gn)
 
 
-def _per_group(removed, st, rows, Gn):
+def _per_group(removed, st, Gn):
     """(removed (C, S) bool, per-group removal counts (C, Gn)) — the
-    shared aggregation tail of both scale-down paths."""
-    group_c = jnp.where(removed, st.ca_slot_group, Gn)
-    removed_per_group = (
-        jnp.zeros(group_c.shape[:1] + (Gn + 1,), jnp.int32)
-        .at[rows, group_c]
-        .add(removed.astype(jnp.int32))[:, :Gn]
+    shared aggregation tail of both scale-down paths. A padding slot
+    (group -1) is never removed and matches no group."""
+    return removed, _rows_put(
+        st.ca_slot_group, removed.astype(jnp.int32), Gn
     )
-    return removed, removed_per_group
 
 
 @jax.named_scope("ca_pass")
@@ -1505,13 +1553,16 @@ def ca_pass(
     unscheduled cache is non-empty, reference: persistent_storage.rs:381-412).
 
     nodes_lane_major (KTPU_LANE_MAJOR): the hot node leaves arrive (N, C);
-    the CA glue is (C, N)-oriented (name-order gathers, grouping sorts), so
+    the CA glue is (C, N)-oriented (name-order look-ups, grouping sorts), so
     it normalizes to row-major VIEWS here — a handful of transposes per
     window against the ~20 kernel-boundary transposes the mode removes in
     the base window (docs/DESIGN.md §"window-cost anatomy"). The pass only
     WRITES the pending pairs (create_time / remove_time — row-major
     always), so nothing converts back. descatter (KTPU_CA_DESCATTER):
-    see _ca_scale_down.
+    see _ca_scale_down, whose docstring also says what the pass costs on
+    the chip and why no look-up in it is an XLA gather or scatter (the
+    slot-table touches and the allocation stamp below included: _rows_put,
+    _rows_at; with one node group the stamp's three reads are broadcasts).
 
     Exact cadence + snapshot semantics (r4): `auto.ca_next` is the TRUE
     cycle-fire time c_k (the scalar re-arms scan_interval after the info
@@ -1618,23 +1669,16 @@ def ca_pass(
     )
 
     # Planned slots come alive at their effect time; removals likewise. The
-    # effect-time value is one (C,) pair — scatter a boolean touch mask (fast
-    # 32-bit path) and merge the pair elementwise.
+    # effect-time value is one (C,) pair — put a boolean touch mask through
+    # the slot table (_rows_put) and merge the pair elementwise.
     _, S = planned.shape
     N = nodes_row.alive.shape[1]
-    rows = jnp.arange(C, dtype=jnp.int32)[:, None]
-    tgt_create = jnp.where(planned, st.ca_slots, N)
-    touch_create = (
-        jnp.zeros((C, N), bool).at[rows, tgt_create].set(True, mode="drop")
-    )
+    touch_create = _rows_put(st.ca_slots, planned, N)
     eff_up = _broadcast_pair(t_add(c_k, st.d_ca_up, interval), (C, N))
     create_time = t_where(
         touch_create, t_min(nodes.create_time, eff_up), nodes.create_time
     )
-    tgt_remove = jnp.where(removed, st.ca_slots, N)
-    touch_remove = (
-        jnp.zeros((C, N), bool).at[rows, tgt_remove].set(True, mode="drop")
-    )
+    touch_remove = _rows_put(st.ca_slots, removed, N)
     eff_down = _broadcast_pair(t_add(c_k, st.d_ca_down, interval), (C, N))
     remove_time = t_where(
         touch_remove, t_min(nodes.remove_time, eff_down), nodes.remove_time
@@ -1663,11 +1707,11 @@ def ca_pass(
         iota_s = jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32)[None, :], planned.shape
         )
-        off_in_g = iota_s - st.ng_ca_start[rows, gidc]
+        off_in_g = iota_s - _rows_at(st.ng_ca_start, gidc)
         alloc_new = (
-            auto.ca_total[rows, gidc]
+            _rows_at(auto.ca_total, gidc)
             + off_in_g
-            - auto.ca_cursor[rows, gidc]
+            - _rows_at(auto.ca_cursor, gidc)
         )
         new_auto = new_auto._replace(
             ca_alloc=jnp.where(planned, alloc_new, auto.ca_alloc),
@@ -1728,11 +1772,9 @@ def ca_reclaim_pass(
     if auto is None or auto.ca_alloc is None:
         return state, auto
     nodes, pods = state.nodes, state.pods
-    C, P = pods.phase.shape
+    C = pods.phase.shape[0]
     S = auto.ca_alloc.shape[1]
     Gn = st.ng_ca_start.shape[1]
-    rows1 = jnp.arange(C, dtype=jnp.int32)
-    rows = rows1[:, None]
     alive_row = nodes.alive.T if nodes_lane_major else nodes.alive
     N = alive_row.shape[1]
     n_trace = N - S
@@ -1741,21 +1783,21 @@ def ca_reclaim_pass(
     occupied = auto.ca_alloc >= 0
 
     # Cheap per-window predicate: an occupied slot whose node is dead
-    # with no pending effects ((C, S) gathers only).
+    # with no pending effects ((C, S) look-ups out of the node axis only).
     dead = (
         occupied
         & (slots >= 0)
-        & ~alive_row[rows, slotc]
+        & ~_rows_at(alive_row, slotc)
         & is_inf(
             TPair(
-                win=nodes.create_time.win[rows, slotc],
-                off=nodes.create_time.off[rows, slotc],
+                win=_rows_at(nodes.create_time.win, slotc),
+                off=_rows_at(nodes.create_time.off, slotc),
             )
         )
         & is_inf(
             TPair(
-                win=nodes.remove_time.win[rows, slotc],
-                off=nodes.remove_time.off[rows, slotc],
+                win=_rows_at(nodes.remove_time.win, slotc),
+                off=_rows_at(nodes.remove_time.off, slotc),
             )
         )
     )
@@ -1782,11 +1824,8 @@ def ca_reclaim_pass(
         blocking = (
             (pods.phase == PHASE_RUNNING) | held_frees(pods)
         ) & (pods.node >= 0)
-        tgt_b = jnp.where(blocking, pods.node, N)
-        node_blocked = (
-            jnp.zeros((C, N), bool).at[rows, tgt_b].set(True, mode="drop")
-        )
-        retired = dead & ~node_blocked[rows, slotc]
+        node_blocked = _rows_put(pods.node, blocking, N)
+        retired = dead & ~_rows_at(node_blocked, slotc)
         keep = occupied & ~retired
 
         # Stable per-group partition: keepers first in slot order (slot
@@ -1797,8 +1836,8 @@ def ca_reclaim_pass(
             num_keys=2,
             is_stable=True,
         )
-        inv = jnp.zeros((C, S), jnp.int32).at[rows, order].set(iota_s)
-        take = lambda a: jnp.take_along_axis(a, order, axis=1)  # noqa: E731
+        inv = _rows_put(order, iota_s, S)
+        take = lambda a: _rows_at(a, order)  # noqa: E731
 
         # Permute the CA node segment (caps and crash payload are uniform
         # within a group / zero on CA slots — permutation-invariant, not
@@ -1833,15 +1872,11 @@ def ca_reclaim_pass(
         ca_ptr = pn >= n_trace
         pn2 = jnp.where(
             ca_ptr,
-            n_trace + inv[rows, jnp.clip(pn - n_trace, 0, S - 1)],
+            n_trace + _rows_at(inv, jnp.clip(pn - n_trace, 0, S - 1)),
             pn,
         )
 
-        keep_cnt = (
-            jnp.zeros((C, Gn + 1), jnp.int32)
-            .at[rows, grp]
-            .add(keep.astype(jnp.int32))[:, :Gn]
-        )
+        keep_cnt = _rows_put(grp, keep.astype(jnp.int32), Gn)
         return (
             alive2,
             acpu2,

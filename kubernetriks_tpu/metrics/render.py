@@ -137,6 +137,11 @@ def render_telemetry(rep: Dict[str, Any], fmt: str) -> str:
         rows += [
             [f"Device phase {phase} ops, {program}", n] for phase, n in counts.items()
         ]
+    for program, counts in rep.get("device_phases", {}).get("gathers", {}).items():
+        # Of them, the XLA gathers: per index on the TPU, whatever they read.
+        rows += [
+            [f"Device phase {phase} gathers, {program}", n] for phase, n in counts.items()
+        ]
     resources = rep.get("resources")
     if resources:
         # Capacity-observatory summary: occupancy vs reserve, memory

@@ -269,6 +269,21 @@ def instruction_phases(hlo_text: str) -> Dict[str, Optional[Tuple[str, str, str]
     return out
 
 
+def gather_instructions(hlo_text: str) -> frozenset:
+    """The names of the instructions of an optimized HLO module whose
+    `op_name` ends in the primitive `gather`: a fusion whose root is an XLA
+    gather, and the reshapes and copies the compiler lays round one. On the
+    TPU such an op costs per INDEX whatever it reads (PERF.md section 5),
+    so a reader of a `phases` line wants them counted beside the ops."""
+    found = set()
+    for line in hlo_text.splitlines():
+        inst = _HLO_INSTRUCTION.match(line)
+        op_name = _HLO_OP_NAME.search(line) if inst else None
+        if op_name and op_name.group(1).rsplit("/", 1)[-1] == "gather":
+            found.add(inst.group(1))
+    return frozenset(found)
+
+
 def _neighbours_phase(name, own, users, operands) -> Optional[Tuple[str, str, str]]:
     """The phase a phaseless instruction takes from its consumers, else from
     its producers (`instruction_phases`), or None."""
@@ -314,19 +329,22 @@ class _Program:
     """One dispatched program: what gets its compiled text later, and the
     map read from it once asked for."""
 
-    __slots__ = ("fn", "args", "kwargs", "phases", "first_ns", "last_ns")
+    __slots__ = ("fn", "args", "kwargs", "phases", "gathers", "first_ns", "last_ns")
 
     def __init__(self, fn: Callable, args, kwargs):
         self.fn = fn
         self.args, self.kwargs = jax.tree.map(_abstract, (args, kwargs))
         self.phases: Optional[Dict[str, Optional[Tuple[str, str, str]]]] = None
+        self.gathers: frozenset = frozenset()  # of `phases`: gather_instructions
         # perf_counter_ns of the first and the newest dispatch
         self.first_ns = self.last_ns = time.perf_counter_ns()
 
     def read_phases(self) -> Dict[str, Optional[Tuple[str, str, str]]]:
         if self.phases is None:
             compiled = self.fn.lower(*self.args, **self.kwargs).compile()
-            self.phases = instruction_phases(compiled.as_text())
+            text = compiled.as_text()
+            self.phases = instruction_phases(text)
+            self.gathers = gather_instructions(text) & self.phases.keys()
             self.fn = self.args = self.kwargs = None
         return self.phases
 
@@ -596,21 +614,27 @@ class SpanTracer(_Aggregates):
         """The closed set of device phases and, for each program dispatched
         so far (one engine handle's, or all), how many of its instructions
         each top-level phase holds (`inherited`: those of them that name no
-        phase themselves and take their consumers' or producers'). Forces
+        phase themselves and take their consumers' or producers'), and
+        under `gathers` how many of a phase's instructions are XLA gathers
+        (`gather_instructions`: their `op_name` ends in `gather`). Forces
         no compile: a program nobody asked `program_phases()` about yet
         reports nothing."""
-        programs = {}
+        programs, gathers = {}, {}
         for key, program in self._programs.items():
             if program.phases is None or handle not in (None, key[0]):
                 continue
             counts: Dict[str, int] = {}
-            for phases in program.phases.values():
+            gathered: Dict[str, int] = {}
+            for name, phases in program.phases.items():
                 top = phases[0] if phases else "unscoped"
                 counts[top] = counts.get(top, 0) + 1
                 if phases and phases[2] != "scope":
                     counts["inherited"] = counts.get("inherited", 0) + 1
+                if name in program.gathers:
+                    gathered[top] = gathered.get(top, 0) + 1
             programs[_program_label(key)] = counts
-        return {"phases": list(DEVICE_PHASES), "programs": programs}
+            gathers[_program_label(key)] = gathered
+        return {"phases": list(DEVICE_PHASES), "programs": programs, "gathers": gathers}
 
     def dropped(self) -> Dict[str, int]:
         """Rows each ring has wrapped out (0 = everything recorded is

@@ -11,13 +11,17 @@ of the simulator it belongs to, and the program exports the map.
     `cycle` / `events` and a wrapper's pad to `kernel_io`, and nothing is
     compiled until it is called (nor when it is: jax finds the dispatch's
     own executable again);
-(c) a second engine in the process adds its programs and drops none.
+(c) a second engine in the process adds its programs and drops none;
+(d) the census of XLA gathers and scatters under `ca_pass` / `ca_reclaim` in
+    the two autoscaled cells' lowered programs (PR 40), and the same count,
+    a phase, in `telemetry_report()["device_phases"]["gathers"]`.
 
 The scopes are location metadata: that they change no program by a byte is
 tests/test_topology_spread.py::test_accepted_cells_lower_the_programs_they_lowered.
 """
 
 import collections
+import functools
 import re
 import time
 
@@ -28,6 +32,7 @@ import window_program_digest as wpd
 from kubernetriks_tpu.recompile import RecompileSentinel
 from kubernetriks_tpu.telemetry.tracer import (
     DEVICE_PHASES,
+    gather_instructions,
     instruction_phases,
     phase_of,
     recorder,
@@ -128,13 +133,21 @@ def lowered_window_program(sim):
 # --- (a) the lowered window programs of the accepted cells ------------------
 
 
-@pytest.mark.parametrize("cell", wpd.CELLS)
-def test_lowered_window_program_names_its_phases(cell):
+@functools.lru_cache(maxsize=None)
+def rehearsal_op_paths(cell):
+    """(`lowered_op_paths` of a cell's rehearsal window program, whether the
+    build carries the autoscalers): built and lowered once a worker, for
+    every test of this file that reads it."""
     sim = wpd.rehearsal_engine(cell)
     try:
-        ops = lowered_op_paths(lowered_window_program(sim))
+        return lowered_op_paths(lowered_window_program(sim)), sim.autoscale_statics is not None
     finally:
         sim.close()
+
+
+@pytest.mark.parametrize("cell", wpd.CELLS)
+def test_lowered_window_program_names_its_phases(cell):
+    ops, autoscaled = rehearsal_op_paths(cell)
     assert len(ops) > 500, "not a window program"
     scoped = sum(1 for _, paths in ops if paths and all(phase_of(p) for p in paths))
     assert scoped >= 0.95 * len(ops), (
@@ -150,7 +163,7 @@ def test_lowered_window_program_names_its_phases(cell):
     # cycle; the autoscaled ones the three autoscaler phases.
     seen = {phase_of(p)[0] for _, paths in ops for p in paths if phase_of(p)}
     wanted = {"events", "cycle"}
-    if sim.autoscale_statics is not None:
+    if autoscaled:
         wanted |= {"hpa_pass", "ca_pass"}
     assert wanted <= seen, (cell, seen)
 
@@ -178,6 +191,53 @@ def test_a_misspelt_scope_is_a_stranger():
 )
 def test_phase_of_reads_the_first_and_the_last_phase_of_a_path(op_name, phases):
     assert phase_of(op_name) == phases
+
+
+# --- (d) the census of per-index look-ups in the cluster autoscaler ----------
+
+# `stablehlo.gather` / `stablehlo.scatter` ops whose location names `ca_pass`
+# or `ca_reclaim` in the lowered rehearsal program (the kernel path, as on the
+# chip). At the parent (4597efb) each cell lowered 26 gathers (`ca_scale_down`
+# 11, `ca_scale_up` 3, `ca_pass` itself 5, `ca_reclaim` 7) and 9 scatters
+# (2, 0, 4, 3); on the chip the 26 were 132 timed ops of the stream's
+# superspan and 75.7% of its device time went to gathers (PERF.md section 5).
+# PR 40 left none: a look-up whose row is a node, slot or group axis is a
+# dense contraction (autoscale._rows_at / _rows_put / _segment_sums). A new
+# one here is paid per index on the TPU: 4.5-10 ns each, 0.3-0.5 ms a window
+# for a (C, S) read.
+CA_CENSUS = {
+    "autoscaled.stream": {"stablehlo.gather": 0, "stablehlo.scatter": 0},
+    "autoscaled.whatif": {"stablehlo.gather": 0, "stablehlo.scatter": 0},
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CA_CENSUS))
+def test_the_cluster_autoscaler_lowers_no_per_index_look_up(cell):
+    found = collections.Counter()
+    everywhere = collections.Counter()
+    for op, paths in rehearsal_op_paths(cell)[0]:
+        if op in ("stablehlo.gather", "stablehlo.scatter"):
+            everywhere[op] += 1
+            tops = {(phase_of(path) or ("",))[0] for path in paths}
+            if tops & {"ca_pass", "ca_reclaim"}:
+                found[op] += 1
+    assert dict(found) == {op: n for op, n in CA_CENSUS[cell].items() if n}
+    # The census sees the ops it is there to see: the passes PR 40 left alone
+    # (the slide, the HPA, the event application) still gather.
+    assert everywhere["stablehlo.gather"] >= 10
+
+
+def test_gather_instructions_reads_the_primitive_off_the_op_name():
+    text = """HloModule m
+ENTRY %main (p: s32[8]) -> s32[8] {
+  %p = s32[8]{0} parameter(0)
+  %fusion.1 = s32[8]{0} fusion(%p), kind=kCustom, calls=%fc, metadata={op_name="jit(f)/ca_pass/gather"}
+  %reshape.2 = s32[8]{0} reshape(%fusion.1), metadata={op_name="jit(f)/ca_pass/cond/branch_1_fun/gather"}
+  %fusion.3 = s32[8]{0} fusion(%reshape.2), kind=kLoop, calls=%fd, metadata={op_name="jit(f)/ca_pass/gather_like/add"}
+  ROOT %copy.4 = s32[8]{0} copy(%fusion.3)
+}
+"""
+    assert gather_instructions(text) == {"fusion.1", "reshape.2"}
 
 
 # --- (b) the map of a compiled program ---------------------------------------
@@ -307,6 +367,7 @@ def test_noting_a_program_compiles_nothing_and_reports_nothing(toy):
         assert sim.telemetry_report()["device_phases"] == {
             "phases": list(DEVICE_PHASES),
             "programs": {},
+            "gathers": {},
         }
         # What is kept is shapes: no array, no device memory.
         import jax
@@ -348,6 +409,16 @@ def test_program_phases_of_a_compiled_toy_window_program(toy):
     )
     assert report["programs"][label]["unscoped"] == counts[None]
     assert report["programs"][label]["inherited"] == sum(n for p, n in counts.items() if p and p[2] != "scope") > 0
+    # Beside them, a phase, the instructions that are XLA gathers (PR 40):
+    # the compiled module's own, by the primitive their `op_name` ends in.
+    gathered = report["gathers"][label]
+    assert set(gathered) <= set(report["programs"][label]) - {"inherited"}
+    assert all(0 < n <= report["programs"][label][phase] for phase, n in gathered.items())
+    if fn is not None:
+        wanted = collections.Counter(
+            phases[name][0] if phases[name] else "unscoped" for name in gather_instructions(text) if name in phases
+        )
+        assert gathered == dict(wanted) and sum(wanted.values()) > 0
 
 
 # --- (c) two engines ----------------------------------------------------------

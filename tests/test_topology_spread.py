@@ -487,7 +487,9 @@ def test_accepted_cells_lower_the_programs_they_lowered(cell):
     is structurally None in a build without constraints, so nothing of it
     is traced) and written anew by PR 38, which changed every cell's event
     chunk on purpose (the replay's program alone came out as it was) and
-    added `sched1k-spread.montecarlo`. A PR that changes the window program
+    added `sched1k-spread.montecarlo`; PR 40 wrote the two autoscaled cells'
+    anew (the cluster autoscaler's look-ups became dense contractions) and
+    left the other five as they were. A PR that changes the window program
     on purpose writes the file anew on its own tree and says so."""
     import window_program_digest as wpd
 
