@@ -201,7 +201,7 @@ def _fused_chunk_slide_impl(
     """The composed path's steady-state MEGASTEP: one device program runs a
     whole window chunk (scheduling cycles + the in-trace HPA/CA passes of
     _window_body) AND the following pod-window slide — shift computation,
-    quantization, gather-apply — with a traced shift amount. The engine
+    quantization, block-move apply — with a traced shift amount. The engine
     dispatches this for the LAST ladder chunk of every slide span, so a span
     costs exactly popcount(span) dispatches and its only host sync is the
     asynchronous 4-byte readback of the returned shift (0 = no slide was
@@ -280,12 +280,18 @@ _fused_chunk_slide_donated = jax.jit(
 @partial(jax.jit, static_argnames=("s", "W"))
 @jax.named_scope("slide")
 def _slide_apply_device(pods, rank, pay, base, s: int, W: int):
-    """Apply a quantized window slide of `s` slots entirely on device:
-    slice the refill segment out of the device-resident payload at
+    """Apply a quantized window slide of a STATIC `s` slots entirely on
+    device: slice the refill segment out of the device-resident payload at
     base + W, build pristine refill slots with the SAME constructor
     init_state uses, and concatenate — no host round-trips. Also slides
     the windowed pod-name ranks (autoscale statics) when `rank` is given.
-    Mirrors the host path in _advance_pod_window leaf-for-leaf."""
+    Mirrors the host path in _advance_pod_window leaf-for-leaf.
+
+    One program a distinct shift: the second dispatch of the two-dispatch
+    slide (fast-forward, gauge and fuse-disabled engines), which knows `s`
+    on the host. The steady-state executors (the superspan, the fused
+    chunk+slide) run step._slide_apply_traced, the same block move at a
+    traced `s`; tests/test_slide_apply.py holds the two leaf for leaf."""
     from kubernetriks_tpu.batched.state import fresh_pod_arrays
 
     C = pods.phase.shape[0]

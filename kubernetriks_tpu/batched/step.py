@@ -3158,57 +3158,54 @@ def _quantize_shift_device(s0, W: int):
 @jax.named_scope("slide")
 def _slide_apply_traced(pods, rank, pay, base, s, W: int):
     """Window slide with a TRACED shift amount (s == 0 is the identity): the
-    gather formulation of engine._slide_apply_device, so ONE compiled
-    program covers every quantized shift and the slide can fuse into the
+    block-move twin of engine._slide_apply_device, so ONE compiled program
+    covers every quantized shift and the slide can fuse into the
     window-chunk program (engine._fused_chunk_slide) or the superspan loop
-    (run_superspan). Bit-identical to the concat path: shifted window slots
-    copy their source slot, refill slots combine the device payload with the
-    SAME fresh-slot constructor init_state uses, and the resident pod-group
-    tail (device slots >= W) is untouched. `base` is in the payload's own
-    column coordinates (see _slide_shift_core)."""
+    (run_superspan). The shift and the base are ONE scalar each for the
+    whole batch, so every plane moves as a dynamic_slice at that scalar: no
+    gather. Bit-identical to the concat path: shifted window slots copy
+    their source slot, refill slots combine the device payload with the SAME
+    fresh-slot constructor init_state uses, and the resident pod-group tail
+    (device slots >= W) is untouched. `base` is in the payload's own column
+    coordinates (see _slide_shift_core).
+
+    A dynamic_slice moves a start that would run off the end instead of
+    failing, so both reads stay inside their operand by construction: the
+    window is extended by W columns (s <= W), and the payload is read at
+    base + s over W columns, whose last, base + s + W - 1, is the last a
+    refill needs. The callers promise that one in range (the superspan's
+    `exhausted` exit for a RefillStage; a slide only triggers at
+    base + W < T and the whole-trace payload is padded to T + W)."""
     from kubernetriks_tpu.batched.state import fresh_pod_arrays
 
-    C, P = pods.phase.shape
-    idx = jnp.arange(P, dtype=jnp.int32)[None, :]  # (1, P)
-    in_window = idx < W
-    refill = in_window & (idx >= (jnp.int32(W) - s))
-    # Window slots shift left by s; refill slots read idx (masked out below);
-    # resident-tail slots are the identity. idx + s < W for every shifted
-    # slot, so the gather never crosses into the resident tail.
-    src_old = jnp.broadcast_to(
-        jnp.where(in_window & ~refill, idx + s, idx), (C, P)
-    )
-    # Refill slot idx's payload column is (base + s) + idx; the whole-trace
-    # payload is padded to T + W columns and a RefillStage's exhaustion exit
-    # fires before any out-of-range refill, so every reachable refill column
-    # is covered. Clip for the masked-out rest.
-    pay_cols = pay["req_cpu"].shape[1]
-    pay_col = jnp.broadcast_to(
-        jnp.clip(base + s + idx, 0, pay_cols - 1), (C, P)
-    )
+    C = pods.phase.shape[0]
+    zero = jnp.int32(0)
+    refill = jnp.arange(W, dtype=jnp.int32)[None, :] >= (jnp.int32(W) - s)
 
     def pg(a):
-        return jnp.take_along_axis(a, pay_col, axis=1)
+        # Window slot i's payload column is (base + s) + i.
+        return jax.lax.dynamic_slice(a, (zero, base + s), (C, W))
+
+    def move(old, fr):
+        # Slot i takes slot i + s of the window; what the extension holds
+        # is never kept (exactly the refill slots read it).
+        ext = jnp.pad(old[:, :W], ((0, 0), (0, W)))
+        shifted = jax.lax.dynamic_slice(ext, (zero, s), (C, W))
+        return jnp.concatenate(
+            [jnp.where(refill, fr, shifted), old[:, W:]], axis=1
+        )
 
     fresh = fresh_pod_arrays(
         C,
-        P,
+        W,
         pg(pay["req_cpu"]),
         pg(pay["req_ram"]),
         TPair(win=pg(pay["dur_win"]), off=pg(pay["dur_off"])),
     )
-    new_pods = jax.tree.map(
-        lambda old, fr: jnp.where(
-            refill, fr, jnp.take_along_axis(old, src_old, axis=1)
-        ),
-        pods,
-        fresh,
-    )
+    new_pods = jax.tree.map(move, pods, fresh)
     new_rank = None
     if rank is not None:
-        new_rank = jnp.where(
-            refill, pg(pay["rank"]), jnp.take_along_axis(rank, src_old, axis=1)
-        )
+        new_rank = move(rank, pg(pay["rank"]))
     return new_pods, new_rank
 
 

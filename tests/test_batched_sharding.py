@@ -17,7 +17,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from kubernetriks_tpu.batched import step
 from kubernetriks_tpu.batched.engine import BatchedSimulation, build_batched_from_traces
-from kubernetriks_tpu.batched.step import SUPERSPAN_RUN
 from kubernetriks_tpu.batched.trace_compile import compile_cluster_trace
 from kubernetriks_tpu.test_util import default_test_simulation_config, leaves_differing
 from kubernetriks_tpu.trace.generic import GenericClusterTrace, GenericWorkloadTrace
@@ -74,15 +73,9 @@ def _compiled_window_program(sim, program: str) -> str:
             collect_gauges=False, **sim._window_call_kwargs(),
         )
     else:
-        stage, lo = sim._current_stage()
-        rank = None if sim.autoscale_statics is None else sim.autoscale_statics.pod_name_rank
-        lowered = step.run_superspan.lower(
-            sim.state, rank,
-            jnp.asarray([0, sim._pod_base, 0, SUPERSPAN_RUN], jnp.int32),
-            sim.slab, sim.consts, stage, jnp.int32(lo), jnp.int32(40),
-            W=sim.pod_window, K=sim._superspan_k, chunk=sim._superspan_chunk,
-            **sim._window_call_kwargs(),
-        )
+        from test_device_phases import lowered_superspan_program
+
+        lowered = lowered_superspan_program(sim)
     return lowered.compile().as_text()
 
 

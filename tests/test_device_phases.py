@@ -14,7 +14,9 @@ of the simulator it belongs to, and the program exports the map.
 (c) a second engine in the process adds its programs and drops none;
 (d) the census of XLA gathers and scatters under `ca_pass` / `ca_reclaim` in
     the two autoscaled cells' lowered programs (PR 40), and the same count,
-    a phase, in `telemetry_report()["device_phases"]["gathers"]`.
+    a phase, in `telemetry_report()["device_phases"]["gathers"]`;
+(e) the same census under `slide` in the lowered superspan program of the two
+    cells whose pod window slides (PR 42): block moves, no gather.
 
 The scopes are location metadata: that they change no program by a byte is
 tests/test_topology_spread.py::test_accepted_cells_lower_the_programs_they_lowered.
@@ -25,6 +27,7 @@ import functools
 import re
 import time
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -183,7 +186,7 @@ def test_a_misspelt_scope_is_a_stranger():
         ("jit(_run_windows_impl)/while/body/closed_call/events/cond/branch_1_fun/kernel_io/transpose", ("events", "kernel_io")),
         ("jit(_run_windows_impl)/while/body/closed_call/cycle/spread_counts/reduce", ("cycle", "cycle")),
         ("jit(_run_windows_impl)/while/body/closed_call/ca_pass/cond/branch_1_fun/ca_scale_down/kernel_io/pad", ("ca_pass", "kernel_io")),
-        ("jit(_run_superspan_impl)/while/body/cond/branch_0_fun/slide/slide/gather", ("slide", "slide")),
+        ("jit(_run_superspan_impl)/while/body/cond/branch_0_fun/slide/slide/dynamic_slice", ("slide", "slide")),
         ("jit(_run_superspan_impl)/while/cond/bookkeeping/lt", ("bookkeeping", "bookkeeping")),
         ("jit(_run_windows_impl)/while/body/dynamic_slice", None),
         ("state.pods.phase", None),
@@ -222,9 +225,67 @@ def test_the_cluster_autoscaler_lowers_no_per_index_look_up(cell):
             if tops & {"ca_pass", "ca_reclaim"}:
                 found[op] += 1
     assert dict(found) == {op: n for op, n in CA_CENSUS[cell].items() if n}
-    # The census sees the ops it is there to see: the passes PR 40 left alone
-    # (the slide, the HPA, the event application) still gather.
+    # The census sees the ops it is there to see: the passes of the window
+    # program PR 40 left alone (the HPA, the event application, the cycle's
+    # queue) still gather: 19 in each cell's program as PR 42 read them
+    # (events 9, hpa_pass 6, cycle 4). The slide is no part of `run_windows`:
+    # its census is below.
     assert everywhere["stablehlo.gather"] >= 10
+
+
+# --- (e) the census of per-index look-ups in the slide -------------------------
+
+# The two cells whose pod window slides, both through `step.run_superspan`.
+# At the parent (a205d3b) the slide branch lowered 27 gathers in the stream's
+# program (21 pod planes and the name ranks by `take_along_axis` over (C, P)
+# indices, 5 payload planes the same way) and 25 in the replay's (no name
+# ranks): 4.17 of the stream's 10.8 ms a window on the chip (PERF.md section
+# 6, PR 42). Shift and base are one scalar each for the batch, so PR 42 moves
+# every plane with a `dynamic_slice` at that scalar.
+SLIDE_CELLS = ("autoscaled.stream", "alibaba1313.replay")
+
+
+def lowered_superspan_program(sim, last=40):
+    """The superspan program the engine would dispatch from where it stands,
+    lowered (tests/test_batched_sharding.py compiles the same)."""
+    from kubernetriks_tpu.batched import step
+
+    stage, lo = sim._current_stage()
+    rank = None if sim.autoscale_statics is None else sim.autoscale_statics.pod_name_rank
+    return step.run_superspan.lower(
+        sim.state,
+        rank,
+        jnp.asarray([0, sim._pod_base, 0, step.SUPERSPAN_RUN], jnp.int32),
+        sim.slab,
+        sim.consts,
+        stage,
+        jnp.int32(lo),
+        jnp.int32(last),
+        W=sim.pod_window,
+        K=sim._superspan_k,
+        chunk=sim._superspan_chunk,
+        **sim._window_call_kwargs(),
+    )
+
+
+@pytest.mark.parametrize("cell", SLIDE_CELLS)
+def test_the_slide_lowers_no_per_index_look_up(cell):
+    sim = wpd.rehearsal_engine(cell)
+    try:
+        assert sim._superspan_ok(), "the cell's rehearsal build does not dispatch superspans"
+        ops = lowered_op_paths(lowered_superspan_program(sim))
+        planes = len(jax.tree.leaves(sim.state.pods)) + (sim.autoscale_statics is not None)
+    finally:
+        sim.close()
+    slide = collections.Counter()
+    for op, paths in ops:
+        if {(phase_of(path) or ("",))[0] for path in paths} == {"slide"}:
+            slide[op] += 1
+    assert slide["stablehlo.gather"] == 0 and slide["stablehlo.scatter"] == 0, slide
+    # Not vacuous: the branch is there, a block move a pod plane and a block
+    # read a payload plane (and the shift's and the capacity read's slices).
+    assert slide["stablehlo.dynamic_slice"] >= planes + 4, slide
+    assert slide["stablehlo.case"] >= 1  # the slide branch's apply / skip
 
 
 def test_gather_instructions_reads_the_primitive_off_the_op_name():
