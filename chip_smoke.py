@@ -35,6 +35,12 @@ Legs:
   dispatch (a Mosaic compile error included) into per-query errors and
   carries on, so an error outcome is raised here.
 - cli: cli.main --backend batched on the bundled data/*.yaml traces.
+- faults: the pure scenario on 1000 identical nodes with the chaos engine's
+  node channel on (per-node chains and one failure group a rack of 50, each
+  cluster its own schedule, sampled inside the build), so that the event
+  kernel applies crashes and recoveries and a dead node's pods run again,
+  against the lax.scan engine on its scatter path. `--only faults` runs it
+  alone.
 """
 
 from __future__ import annotations
@@ -92,6 +98,10 @@ CHIP_SHAPES = dict(
         ),
     ),
     cli_clusters=1024,
+    # (clusters, nodes, rack size, trace seconds, traces to a node's and a
+    # rack's failure, formulations): a fifth of the north-star batch keeps
+    # the two builds' per-cluster trace compiles short.
+    faults=(256, 1000, 50, 1000.0, 24.0, "megakernel", "kernel"),
 )
 PLUMBING_SHAPES = dict(
     pure=[(4, 8, 200.0, "candidate")],
@@ -113,6 +123,7 @@ PLUMBING_SHAPES = dict(
         ),
     ),
     cli_clusters=2,
+    faults=(4, 8, 4, 400.0, 2.0, "candidate", "scatter"),
 )
 
 
@@ -166,6 +177,62 @@ def pure_leg(n_clusters, n_nodes, horizon, cycle, run, forced, mesh) -> dict:
     return emit(
         "pure", t0, clusters=n_clusters, nodes=n_nodes, pods=sim.n_pods,
         formulation=formulation, decisions=decisions, reference="lax.scan",
+        mismatches=0,
+    )
+
+
+def faults_leg(shape, run, forced) -> dict:
+    """Node crashes and recoveries through the dense kernel set against the
+    lax.scan engine: identical nodes, so a recovered node has to sit where
+    its name sorts, and the final state holds every rescheduled pod."""
+    import bench
+    from kubernetriks_tpu.batched.engine import build_batched_from_traces
+    from kubernetriks_tpu.batched.state import compare_states
+    from kubernetriks_tpu.config import FailureGroupConfig, FaultInjectionConfig, NodeFaultConfig
+
+    n_clusters, n_nodes, rack, horizon, traces_to_failure, cycle, events = shape
+    t0 = time.perf_counter()
+    config, cluster_events, workload = bench._shape_inputs(n_nodes, horizon)
+    mttf = horizon * traces_to_failure
+    config.fault_injection = FaultInjectionConfig(
+        enabled=True,
+        horizon=horizon,
+        node=NodeFaultConfig(mttf=mttf, mttr=120.0),
+        failure_groups=[
+            FailureGroupConfig(
+                members=[f"gen_node_{i}" for i in range(lo, lo + rack)],
+                mttf=mttf,
+                mttr=240.0,
+            )
+            for lo in range(0, n_nodes, rack)
+        ],
+    )
+
+    def build(**kw):
+        return build_batched_from_traces(
+            config, cluster_events, workload, n_clusters=n_clusters,
+            max_pods_per_cycle=64, **kw,
+        )
+
+    sim = build(**forced)
+    ref = build(use_pallas=False)
+    for s in (sim, ref):
+        s.step_until_time(run["warm_until"])
+        s.step_until_time(horizon + 200.0)
+    formulation = sim.kernel_formulation()
+    assert formulation["cycle"] == cycle and formulation["events"] == events, formulation
+    assert sim.n_nodes == n_nodes, (sim.n_nodes, "a recovery took a fresh slot")
+    counters = sim.metrics_summary()["counters"]
+    assert counters["node_crashes"] > 0 and counters["node_recoveries"] > 0, counters
+    assert counters["pod_interruptions"] > 0, counters
+    mismatches = compare_states(ref.state, sim.state)
+    assert not mismatches, mismatches
+    return emit(
+        "faults", t0, clusters=n_clusters, nodes=n_nodes, pods=sim.n_pods,
+        formulation=formulation, node_crashes=counters["node_crashes"],
+        node_recoveries=counters["node_recoveries"],
+        pod_interruptions=counters["pod_interruptions"],
+        pods_succeeded=counters["pods_succeeded"], reference="lax.scan",
         mismatches=0,
     )
 
@@ -343,6 +410,10 @@ def main(argv=None) -> int:
         help="toy shapes with Pallas in interpret mode on whatever backend "
         "JAX has: debugs this script, proves nothing about the chip",
     )
+    parser.add_argument(
+        "--only", choices=("pure", "composed", "served", "cli", "faults"),
+        help="run this leg alone (one chip)",
+    )
     args = parser.parse_args(argv)
     t_start = time.perf_counter()
 
@@ -396,10 +467,13 @@ def main(argv=None) -> int:
 
         mesh = Mesh(np.array(devices[: args.devices]), ("clusters",))
 
+    def wanted(leg: str) -> bool:
+        return args.only in (None, leg)
+
     legs = []
     for per_device, n_nodes, horizon, cycle in (
         shapes["pure"] if mesh is None else shapes["pure"][:1]
-    ):
+    ) if wanted("pure") else ():
         legs.append(
             pure_leg(
                 per_device * args.devices, n_nodes, horizon, cycle,
@@ -410,7 +484,8 @@ def main(argv=None) -> int:
     composed["n_clusters"] *= args.devices
     # use_pallas=True as in bench.run_composed: the flagship is the kernel
     # path by definition, not by the auto gate.
-    legs.append(composed_leg(composed, {**forced, "use_pallas": True}, mesh))
+    if wanted("composed"):
+        legs.append(composed_leg(composed, {**forced, "use_pallas": True}, mesh))
     if mesh is None:
         # A lane-async engine turns the global-clock statics off by itself
         # and refuses them when asked for by name.
@@ -419,8 +494,12 @@ def main(argv=None) -> int:
             for k, v in forced.items()
             if k not in ("superspan", "stream", "fuse_slide")
         }
-        legs.append(served_leg(shapes["served"], per_lane))
-        legs.append(cli_leg(shapes["cli_clusters"]))
+        if wanted("served"):
+            legs.append(served_leg(shapes["served"], per_lane))
+        if wanted("cli"):
+            legs.append(cli_leg(shapes["cli_clusters"]))
+        if wanted("faults"):
+            legs.append(faults_leg(shapes["faults"], shapes["pure_run"], forced))
 
     print(
         json.dumps(

@@ -71,6 +71,7 @@ from kubernetriks_tpu.batched.step import (
     SUPERSPAN_GROW,
     SUPERSPAN_RUN,
     SUPERSPAN_STAGE,
+    event_path,
     run_superspan,
     run_superspan_donated,
     run_windows,
@@ -1171,7 +1172,7 @@ class BatchedSimulation:
             pod_req_cpu,
             pod_req_ram,
             pod_duration,
-            node_crash_downtime,
+            crash_downtime_cum,
         ) = pad_and_batch(compiled_traces, n_pods=n_pods_aligned)
 
         # Host-side node-event schedule for point-in-time readouts
@@ -1212,7 +1213,18 @@ class BatchedSimulation:
         # hot path is untouched).
         from kubernetriks_tpu.chaos import make_fault_params
 
-        self.fault_params = make_fault_params(config)
+        # Node faults are on wherever the traces carry a crash or a
+        # recovery, whoever sampled them: build_batched_from_traces from
+        # config.fault_injection (chaos.inject_node_faults), or a caller
+        # who hands the build events it sampled itself
+        # (RemoveNodeRequest(crashed=True, downtime_s=...) /
+        # CreateNodeRequest(recovered=True) in the cluster trace).
+        self.fault_params = make_fault_params(
+            config,
+            node_fault_events=bool(
+                np.isin(ev_kind, (EV_NODE_CRASH, EV_NODE_RECOVER)).any()
+            ),
+        )
         self._debug_finite = flag_bool("KTPU_DEBUG_FINITE")
         # Per-lane pod-fault seeds (scenario vector): traced (C,) data in
         # StepConstants — each lane's attempt draws key on (seed[c],
@@ -1532,19 +1544,6 @@ class BatchedSimulation:
             self.kernel_formulation(),
         )
 
-        # The CA's reserved node slots (appended above) never crash — pad
-        # the crash-downtime payload to the final node axis.
-        if node_crash_downtime.shape[1] < self.n_nodes:
-            node_crash_downtime = np.concatenate(
-                [
-                    node_crash_downtime,
-                    np.zeros(
-                        (C, self.n_nodes - node_crash_downtime.shape[1]),
-                        np.float32,
-                    ),
-                ],
-                axis=1,
-            )
         self.state = init_state(
             C,
             self.n_nodes,
@@ -1555,7 +1554,6 @@ class BatchedSimulation:
             pod_req_ram,
             pod_duration,
             interval=config.scheduling_cycle_interval,
-            node_crash_downtime=node_crash_downtime,
         )
         if spread_host is not None:
             from kubernetriks_tpu.batched.state import SpreadState
@@ -1650,7 +1648,16 @@ class BatchedSimulation:
                 counters=self.tracer.counters,
             )
         ev_win, ev_off = from_f64_np(ev_time, config.scheduling_cycle_interval)
-        self.slab = TraceSlab.build(ev_win, ev_off, ev_kind, ev_slot)
+        if (
+            crash_downtime_cum is None
+            and self.fault_params is not None
+            and self.fault_params.node_faults
+        ):
+            # node faults configured, none sampled: a table of no crash
+            crash_downtime_cum = np.zeros((C, 1), np.float32)
+        self.slab = TraceSlab.build(
+            ev_win, ev_off, ev_kind, ev_slot, crash_downtime=crash_downtime_cum
+        )
         self._ev_time_np = ev_time  # host copy (f64) for completion checks
         self._lane_mux = None
         if self.lane_async:
@@ -1809,6 +1816,16 @@ class BatchedSimulation:
             # how nodes are ranked for a pod: the float32 score, or the
             # exact key a trace of heterogeneous requests calls for
             "ranking": "exact" if self._cycle_profile.exact_bits else "float32",
+            # how the event chunk loop applies a chunk: fused_event_scatter,
+            # or the XLA scatters that are its bit-identical fallback
+            "events": event_path(
+                self.use_pallas,
+                self.use_pallas_select,
+                self.n_nodes,
+                self.n_pods,
+                self.max_events_per_window,
+                self.fault_params is not None and self.fault_params.node_faults,
+            ),
             # how a mesh build is sharded: one shard_map round each whole
             # window program (sharding.py); None = no mesh, nothing wrapped
             "sharding": None,
@@ -2481,7 +2498,7 @@ class BatchedSimulation:
                 jnp.asarray(0, jnp.int32),
             ),
         )
-        self.slab = TraceSlab(packed=packed)
+        self.slab = self.slab._replace(packed=packed)
 
     def set_lane_trace(self, lane: int, lo: int = 0, hi=None) -> bool:
         """Install a per-lane workload row-range (stream.LaneTraceMux):
@@ -3949,6 +3966,16 @@ class BatchedSimulation:
         counters.update(cycle)
         self.tracer.counters.update(cycle)
         counters.update(self._spread_counters())
+        if self.fault_params is not None and self.fault_params.node_faults:
+            # A build under node faults publishes the chaos counters the
+            # same way (no new leaf: the metrics state has always held them).
+            faults = {
+                "node_crashes": int(np.asarray(m.node_crashes).sum()),
+                "node_recoveries": int(np.asarray(m.node_recoveries).sum()),
+                "pod_interruptions": int(np.asarray(m.pod_interruptions).sum()),
+            }
+            counters.update(faults)
+            self.tracer.counters.update(faults)
 
         def est(e):
             count = np.asarray(e.count, np.int64)

@@ -63,9 +63,9 @@ EV_REMOVE_NODE = 2
 EV_CREATE_POD = 3
 EV_REMOVE_POD = 4
 # Chaos engine (chaos.py): a crash is EV_REMOVE_NODE semantics plus fault
-# accounting (the slot's pre-staged crash_downtime folds into the downtime
-# metric); a recovery is EV_CREATE_NODE semantics on a FRESH slot (slots are
-# never reused) plus the recovery counter.
+# accounting (the crash counter, the downtime table of TraceSlab, the
+# interruption counter); a recovery is EV_CREATE_NODE semantics, on the
+# node's own slot (trace_compile), plus the recovery counter.
 EV_NODE_CRASH = 5
 EV_NODE_RECOVER = 6
 
@@ -85,11 +85,12 @@ class NodeArrays(NamedTuple):
     # Pending on-device effects (cluster-autoscaler actions); +inf = none.
     create_time: TPair
     remove_time: TPair
-    # Pre-staged chaos payload: the sampled repair span of the slot's crash
-    # event (each slot crashes at most once — recovery opens a fresh slot);
-    # 0 on slots that never crash. Folded into node_downtime_s when
-    # EV_NODE_CRASH applies.
-    crash_downtime: jnp.ndarray  # float32 seconds
+    # Zeros, read by nothing: the sampled repair spans lived here a slot
+    # while a slot crashed at most once; a recovery now returns to its slot
+    # and they ride the trace slab by crash event (TraceSlab.crash_downtime).
+    # The plane stays so that the state's tree, and with it every program
+    # built without faults, is what it was (window_program_digests.json).
+    crash_downtime: jnp.ndarray  # float32
 
 
 class PodArrays(NamedTuple):
@@ -457,6 +458,12 @@ class TraceSlab(NamedTuple):
     duplicate device memory."""
 
     packed: jnp.ndarray  # (C, n_blocks, 128) int32, lane = 4 * event + field
+    # Chaos payload by crash EVENT (a slot may crash again once its node has
+    # recovered onto it): (C, K + 1) float32, entry k the summed sampled
+    # repair spans of the cluster's first k EV_NODE_CRASH events in slab
+    # order, so metrics.node_downtime_s is one look-up a cluster at the
+    # running crash count. None (no leaf) without node faults.
+    crash_downtime: Optional[jnp.ndarray] = None
 
     @staticmethod
     def block_rows(rows) -> np.ndarray:
@@ -473,7 +480,7 @@ class TraceSlab(NamedTuple):
         return out.reshape(rows.shape[:-2] + (n_blocks, _SLAB_BLOCK_LANES))
 
     @staticmethod
-    def build(win, off, kind, slot) -> "TraceSlab":
+    def build(win, off, kind, slot, crash_downtime=None) -> "TraceSlab":
         rows = np.stack(
             [
                 np.asarray(win, np.int32),
@@ -483,7 +490,12 @@ class TraceSlab(NamedTuple):
             ],
             axis=-1,
         )
-        return TraceSlab(packed=jnp.asarray(TraceSlab.block_rows(rows)))
+        return TraceSlab(
+            packed=jnp.asarray(TraceSlab.block_rows(rows)),
+            crash_downtime=None
+            if crash_downtime is None
+            else jnp.asarray(crash_downtime, jnp.float32),
+        )
 
     def rows(self) -> jnp.ndarray:
         """The slab as (C, n_blocks * SLAB_BLOCK_EVENTS, 4) event rows,
@@ -670,13 +682,10 @@ def init_state(
     pod_req_ram: np.ndarray,
     pod_duration: np.ndarray,
     interval: float,
-    node_crash_downtime: Optional[np.ndarray] = None,
 ) -> ClusterBatchState:
     """Build the initial state with pre-staged payloads (all slots start
     EMPTY/dead; trace events bring them to life). pod_duration: float64
-    seconds, <0 marks a long-running service. node_crash_downtime: (C, N)
-    sampled repair spans of the chaos engine's crash events (None = no
-    faults, zeros)."""
+    seconds, <0 marks a long-running service."""
     C, N, P = n_clusters, n_nodes, n_pods
     duration = duration_pair_np(pod_duration, interval)
     nodes = NodeArrays(
@@ -687,11 +696,7 @@ def init_state(
         alloc_ram=jnp.asarray(node_cap_ram, jnp.int32),
         create_time=t_inf((C, N)),
         remove_time=t_inf((C, N)),
-        crash_downtime=(
-            jnp.zeros((C, N), jnp.float32)
-            if node_crash_downtime is None
-            else jnp.asarray(node_crash_downtime, jnp.float32)
-        ),
+        crash_downtime=jnp.zeros((C, N), jnp.float32),
     )
     pods = fresh_pod_arrays(C, P, pod_req_cpu, pod_req_ram, duration)
     metrics = MetricArrays(

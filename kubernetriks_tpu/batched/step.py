@@ -41,6 +41,7 @@ back to persistent state.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
@@ -129,16 +130,21 @@ def _rel_seconds(t: TPair, base_win: jnp.ndarray, interval) -> jnp.ndarray:
     return (t.win - base_win).astype(jnp.float32) * jnp.float32(interval) + t.off
 
 
-def _stable_queue_rank(keys) -> jnp.ndarray:
-    """Dense queue ranks from lexicographic (C, P) sort keys: the
-    scatter-inverse of a stable sort over the pod axis, slot order breaking
-    exact key ties. Shared by the reschedule and CrashLoopBackOff retry
-    dispositions so the scalar-parity ordering rules live in ONE place."""
+def _stable_queue_rank(keys, by_sort: bool = False) -> jnp.ndarray:
+    """Dense queue ranks from lexicographic (C, P) sort keys: the inverse of
+    a stable sort over the pod axis, slot order breaking exact key ties.
+    Shared by the reschedule and CrashLoopBackOff retry dispositions so the
+    scalar-parity ordering rules live in ONE place. The inverse is a scatter
+    (C x P indices, paid per index on the TPU), or with `by_sort` a second
+    sort of the permutation, the same ranks: the form for a build that ranks
+    in most of its windows (node faults; PERF.md section 6, PR 43)."""
     C, P = keys[0].shape
     iota_pp = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None, :], (C, P))
     out = jax.lax.sort(
         (*keys, iota_pp), dimension=1, num_keys=len(keys), is_stable=True
     )
+    if by_sort:
+        return jax.lax.sort((out[-1], iota_pp), dimension=1, num_keys=1)[1]
     return (
         jnp.zeros((C, P), jnp.int32)
         .at[jnp.arange(C, dtype=jnp.int32)[:, None], out[-1]]
@@ -276,6 +282,25 @@ def _apply_window_events(
     return jax.lax.cond(due.any(), run, skip, state)
 
 
+def event_path(
+    use_pallas: bool,
+    use_pallas_select: bool,
+    n_nodes: int,
+    n_pods: int,
+    chunk: int,
+    node_faults: bool,
+) -> str:
+    """How the event chunk loop applies a chunk of `chunk` due events to
+    (n_nodes, n_pods) slots: "kernel" (ops.scheduler_kernel.fused_event_scatter,
+    where the kernels are on, the cluster lanes dense and the blocks fit
+    VMEM) or "scatter" (XLA scatters). The ONE owner of the gate:
+    _apply_window_events_work and engine.kernel_formulation() both ask."""
+    from kubernetriks_tpu.ops.scheduler_kernel import event_kernel_fits
+
+    fits = event_kernel_fits(n_nodes, n_pods, chunk, node_faults)
+    return "kernel" if use_pallas and use_pallas_select and fits else "scatter"
+
+
 def _apply_window_events_work(
     state: ClusterBatchState,
     slab: TraceSlab,
@@ -312,8 +337,11 @@ def _apply_window_events_work(
 
     fault_params (chaos.FaultParams, static): with node_faults, the slab may
     carry EV_NODE_CRASH (remove semantics + crash/downtime accounting; a
-    separate scatter keeps crash attribution for the interruption counter)
-    and EV_NODE_RECOVER (create semantics on a fresh slot + recovery count);
+    removal accumulator of its own keeps crash attribution for the
+    interruption counter, a sixth plane of the event kernel or a separate
+    scatter) and EV_NODE_RECOVER (create semantics on the node's own slot +
+    recovery count); what they add to the window body runs under the named
+    scope `node_faults`;
     with pod_faults, running pods whose will_fail flag is set FAIL at their
     finish_time instead of succeeding — retry via CrashLoopBackOff requeue
     or terminate as PHASE_FAILED past the restart limit.
@@ -344,7 +372,6 @@ def _apply_window_events_work(
     from kubernetriks_tpu.ops.scheduler_kernel import (
         event_accumulators,
         event_accumulators_unpack,
-        event_kernel_fits,
         fused_event_scatter,
     )
 
@@ -358,17 +385,15 @@ def _apply_window_events_work(
     # The one-hot scatter kernels sweep whole (P, 128-lane) tiles per event,
     # so like the selection kernel they only pay when the cluster lanes are
     # dense — use_pallas_select carries exactly that gate (measured: the
-    # C=1 replay regressed 229 s -> 350 s with them always-on). The kernel
-    # predates the chaos event kinds, so fault-bearing slabs take the plain
-    # scatter path (bit-identical fallback).
-    use_event_kernel = (
-        use_pallas
-        and use_pallas_select
-        and event_kernel_fits(N, P, E)
-        and not node_faults
-    )
+    # C=1 replay regressed 229 s -> 350 s with them always-on). The plain
+    # scatter path is the bit-identical fallback.
+    use_event_kernel = event_path(
+        use_pallas, use_pallas_select, N, P, E, node_faults
+    ) == "kernel"
     if use_event_kernel:
         event_core = partial(fused_event_scatter, interpret=pallas_interpret)
+    # What node faults add runs under a scope of its own (nested in `events`).
+    faults_scope = partial(jax.named_scope, "node_faults")
 
     # --- bulk-apply the window's slab events, E at a time -------------------
     # E is a CHUNK size, not a worst-case bound: chunks apply inside a
@@ -426,15 +451,16 @@ def _apply_window_events_work(
         is_cp = valid & (ev_k == EV_CREATE_POD)
         is_rp = valid & (ev_k == EV_REMOVE_POD)
         if node_faults:
-            # Recoveries ARE creations (fresh slot, fresh capacity) — fold
-            # into is_cn so every create-side effect (alive/alloc, wake
+            # Recoveries ARE creations (the node's slot, fresh capacity) —
+            # fold into is_cn so every create-side effect (alive/alloc, wake
             # events, pending-create interplay) applies identically; crashes
-            # scatter into their own removal array so crash attribution
-            # survives for the interruption/downtime metrics, and merge into
+            # go to their own removal accumulator so crash attribution
+            # survives for the interruption metric, and merge into
             # node_removal after the loop.
-            is_crash = valid & (ev_k == EV_NODE_CRASH)
-            is_recover = valid & (ev_k == EV_NODE_RECOVER)
-            is_cn = is_cn | is_recover
+            with faults_scope():
+                is_crash = valid & (ev_k == EV_NODE_CRASH)
+                is_recover = valid & (ev_k == EV_NODE_RECOVER)
+                is_cn = is_cn | is_recover
         # Queue sequence numbers follow slab (== emission) order, continuing
         # across chunks via the running n_creates.
         create_rank = jnp.cumsum(is_cp, axis=1, dtype=jnp.int32) - 1
@@ -446,13 +472,16 @@ def _apply_window_events_work(
             # per-index on TPU). The five accumulators ride the loop in
             # the kernel's padded lane-major layout (carry0): a pass pays
             # no pad, transpose or slice for them.
-            created, node_removal, pod_create, pod_create_seq, pod_removal = (
-                event_core(
-                    ev_k, ev_s, ev_rel, ev_seq, valid,
-                    created, node_removal, pod_create, pod_create_seq,
-                    pod_removal,
-                )
+            # Under node faults the kernel knows the two chaos kinds and
+            # carries the crash accumulator as a sixth plane.
+            acc = event_core(
+                ev_k, ev_s, ev_rel, ev_seq, valid,
+                created, node_removal, pod_create, pod_create_seq,
+                pod_removal, crash_removal=crash_rm if node_faults else None,
             )
+            created, node_removal, pod_create, pod_create_seq, pod_removal = acc[:5]
+            if node_faults:
+                crash_rm = acc[5]
         else:
             # Scatter helpers: out-of-range slot drops the write. Node
             # accumulators are lane-major under lane_major — the scatter
@@ -505,14 +534,16 @@ def _apply_window_events_work(
             )
             out = out + (node_create_rel,)
         if node_faults:
-            crash_rm = n_scatter_min(
-                crash_rm, is_crash, ev_s,
-                jnp.where(is_crash, ev_rel, f32inf),
-            )
-            out = out + (
-                crash_rm,
-                n_recover + is_recover.sum(axis=1, dtype=jnp.int32),
-            )
+            with faults_scope():
+                if not use_event_kernel:
+                    crash_rm = n_scatter_min(
+                        crash_rm, is_crash, ev_s,
+                        jnp.where(is_crash, ev_rel, f32inf),
+                    )
+                out = out + (
+                    crash_rm,
+                    n_recover + is_recover.sum(axis=1, dtype=jnp.int32),
+                )
         if count_chunks:
             # A cluster needed this chunk iff its first entry was due.
             out = out + (carry[-1] + valid[:, 0].astype(jnp.int32),)
@@ -541,7 +572,8 @@ def _apply_window_events_work(
         carry0 = carry0 + (jnp.full(n_shape, INF, jnp.float32),)
     if node_faults:
         carry0 = carry0 + (
-            jnp.full(n_shape, INF, jnp.float32),
+            # the crash accumulator starts as node_removal does, in its layout
+            accumulators0[1],
             jnp.zeros((C,), jnp.int32),
         )
     if count_chunks:
@@ -550,15 +582,6 @@ def _apply_window_events_work(
     event_chunks = carry_out[-1] if count_chunks else None
     (event_cursor, created, node_removal, pod_create, pod_create_seq,
      pod_removal, n_creates) = carry_out[:7]
-    if use_event_kernel:
-        # Out of the kernel's layout once a window, where the row-major
-        # consumers start.
-        created, node_removal, pod_create, pod_create_seq, pod_removal = (
-            event_accumulators_unpack(
-                (created, node_removal, pod_create, pod_create_seq, pod_removal),
-                C, N, P, lane_major,
-            )
-        )
     tail = 7
     node_create_rel = None
     if conditional_move:
@@ -566,21 +589,38 @@ def _apply_window_events_work(
         tail += 1
     if node_faults:
         crash_rm, n_recover = carry_out[tail], carry_out[tail + 1]
-        crashed_now = crash_rm < f32inf
-        metrics = metrics._replace(
-            node_crashes=metrics.node_crashes
-            + crashed_now.sum(axis=n_sum_ax, dtype=jnp.int32),
-            node_recoveries=metrics.node_recoveries + n_recover,
-            # Downtime = the crash's pre-sampled repair span (each slot
-            # crashes at most once; recovery opens a fresh slot).
-            # crash_downtime is a hot leaf, so it shares crashed_now's
-            # layout either way.
-            node_downtime_s=metrics.node_downtime_s
-            + jnp.where(crashed_now, nodes.crash_downtime, 0.0).sum(
-                axis=n_sum_ax
-            ),
+    if use_event_kernel:
+        # Out of the kernel's layout once a window, where the row-major
+        # consumers start.
+        acc = event_accumulators_unpack(
+            (created, node_removal, pod_create, pod_create_seq, pod_removal)
+            + ((crash_rm,) if node_faults else ()),
+            C, N, P, lane_major,
         )
-        node_removal = jnp.minimum(node_removal, crash_rm)
+        created, node_removal, pod_create, pod_create_seq, pod_removal = acc[:5]
+        if node_faults:
+            crash_rm = acc[5]
+    if node_faults:
+        with faults_scope():
+            crashed_now = crash_rm < f32inf
+            node_crashes = metrics.node_crashes + crashed_now.sum(
+                axis=n_sum_ax, dtype=jnp.int32
+            )
+            metrics = metrics._replace(
+                node_crashes=node_crashes,
+                node_recoveries=metrics.node_recoveries + n_recover,
+                # Downtime = the summed pre-sampled repair spans of the
+                # crashes applied so far: the slab's table by crash event
+                # at the running count (a slot crashes again once its node
+                # has recovered onto it, so no per-slot plane can hold the
+                # spans). Crashes apply in slab order, a slot at most once
+                # a window, so the count IS the event ordinal.
+                node_downtime_s=slab.crash_downtime[
+                    rows[:, 0],
+                    jnp.minimum(node_crashes, slab.crash_downtime.shape[1] - 1),
+                ],
+            )
+            node_removal = jnp.minimum(node_removal, crash_rm)
 
     def to_nmaj(x):
         """Row-major (C, N) mask/value -> the node accumulators' layout."""
@@ -658,11 +698,52 @@ def _apply_window_events_work(
     # most expensive ops in the step — and most windows remove no node at
     # all; branch around it (the predicate reduction is replicated, so the
     # cond also holds under a C-sharded mesh).
-    pod_node_removal = jax.lax.cond(
-        (node_removal < f32inf).any(),
-        lambda: jnp.where(pods.node >= 0, n_gather(node_removal), f32inf),
-        lambda: jnp.full((C, P), INF, jnp.float32),
-    )
+    if node_faults:
+        # Under node faults some cluster of the batch loses a node in nearly
+        # every window, so the branch below is taken nearly always and a
+        # (C, P) gather costs per INDEX (8.3 ns: 21 ms a window at 1250 x
+        # 2048, three of them 64 of the window's 78 ms; PERF.md section 6,
+        # PR 43). One dense look-up instead (autoscale._rows_at: a fused
+        # compare-and-reduce over the node axis, paid per element) brings
+        # each pod its node's removal time AND, in the sign bit the time
+        # never uses, whether that removal was the crash's (ties attribute
+        # to the crash, matching the scalar chain where the crash IS the
+        # removal): crash_rm <= the merged removal time.
+        from kubernetriks_tpu.batched.autoscale import _rows_at
+
+        def node_rows(x):
+            """A node-layout plane as (C, N) rows."""
+            return x.T if lane_major else x
+
+        def removal_and_crash():
+            bits = jax.lax.bitcast_convert_type(node_removal, jnp.int32)
+            bits = bits | jnp.where(
+                crash_rm <= node_removal, jnp.int32(-(2**31)), jnp.int32(0)
+            )
+            return _rows_at(node_rows(bits), node_idx)
+
+        with faults_scope():
+            removal_bits = jax.lax.cond(
+                (node_removal < f32inf).any(),
+                removal_and_crash,
+                lambda: jax.lax.bitcast_convert_type(
+                    jnp.full((C, P), INF, jnp.float32), jnp.int32
+                ),
+            )
+            pod_node_removal = jnp.where(
+                pods.node >= 0,
+                jax.lax.bitcast_convert_type(
+                    removal_bits & jnp.int32(2**31 - 1), jnp.float32
+                ),
+                f32inf,
+            )
+            pod_by_crash = (pods.node >= 0) & (removal_bits < 0)
+    else:
+        pod_node_removal = jax.lax.cond(
+            (node_removal < f32inf).any(),
+            lambda: jnp.where(pods.node >= 0, n_gather(node_removal), f32inf),
+            lambda: jnp.full((C, P), INF, jnp.float32),
+        )
     # Earliest interruption of this pod in rel-seconds; +inf = none.
     interrupt = jnp.minimum(pod_node_removal, pod_removal)
     has_interrupt = interrupt < f32inf
@@ -692,18 +773,13 @@ def _apply_window_events_work(
 
     if node_faults:
         # Crash-caused reschedules (the interruption metric): the pod's
-        # earliest node removal came from a crash (ties attribute to the
-        # crash, matching the scalar chain where the crash IS the removal).
-        pod_crash_rm = jax.lax.cond(
-            crashed_now.any(),
-            lambda: jnp.where(pods.node >= 0, n_gather(crash_rm), f32inf),
-            lambda: jnp.full((C, P), INF, jnp.float32),
-        )
-        crash_caused = rescheds & (pod_crash_rm <= pod_node_removal)
-        metrics = metrics._replace(
-            pod_interruptions=metrics.pod_interruptions
-            + crash_caused.sum(axis=1, dtype=jnp.int32)
-        )
+        # earliest node removal came from a crash (the look-up above).
+        with faults_scope():
+            crash_caused = rescheds & pod_by_crash
+            metrics = metrics._replace(
+                pod_interruptions=metrics.pod_interruptions
+                + crash_caused.sum(axis=1, dtype=jnp.int32)
+            )
 
     # Free resources of finished and removed-while-running pods (a dead node's
     # allocatable is irrelevant; slots are never reused). A straight
@@ -848,6 +924,8 @@ def _apply_window_events_work(
             # CURRENT names (allocation-index keys, autoscale.ca_name_order)
             # — the static table describes the slots' first occupants.
             nr = node_key_fn()[jnp.arange(C, dtype=jnp.int32)[:, None], node_c2]
+        elif node_name_rank is not None and node_faults:
+            nr = _rows_at(node_name_rank, node_c2)  # dense, as the removal look-up
         elif node_name_rank is not None:
             nr = node_name_rank[jnp.arange(C, dtype=jnp.int32)[:, None], node_c2]
         else:
@@ -858,13 +936,16 @@ def _apply_window_events_work(
             k3 = jnp.where(rescheds, pod_name_rank, big)
         else:
             k3 = jnp.zeros((C, P), jnp.int32)
-        return _stable_queue_rank((k1, k2, k3))
+        return _stable_queue_rank((k1, k2, k3), by_sort=node_faults)
 
-    resched_rank = jax.lax.cond(
-        rescheds.any(),
-        _resched_rank_exact,
-        lambda: jnp.cumsum(rescheds, axis=1, dtype=jnp.int32) - 1,
-    )
+    # (Under node faults the ordering is the crash path's: a rack's pods
+    # re-enter the queue together.)
+    with faults_scope() if node_faults else contextlib.nullcontext():
+        resched_rank = jax.lax.cond(
+            rescheds.any(),
+            _resched_rank_exact,
+            lambda: jnp.cumsum(rescheds, axis=1, dtype=jnp.int32) - 1,
+        )
     resched_ts = t_norm(
         jnp.broadcast_to(base[:, None], (C, P)),
         jnp.where(rescheds, pod_node_removal, 0.0)

@@ -5,8 +5,14 @@ The batched path's replacement for the reference's trace-to-event emission
 host; payloads (capacities, requests, durations) are pre-staged into per-slot
 arrays; the device sees only (time, kind, slot) triples.
 
-Node re-creations of the same name get fresh slots (the scalar path allocates a
-fresh pool component the same way, reference: src/core/node_component_pool.rs).
+A node re-created under its own name (a chaos recovery, or a trace that
+removes a machine and adds it again) returns to ITS OWN slot wherever the two
+incarnations are the same machine and lie windows apart (`_reusable_slot`):
+incarnations of one name are never alive together, the slot then sits where
+name order puts it, so exact score ties break as the scalar walk breaks them,
+and the node axis stays the deployment's node count whatever the fault
+schedule. Anything else gets a fresh slot, ordered after its elder
+(`_node_slots_in_name_order`).
 """
 
 from __future__ import annotations
@@ -26,6 +32,7 @@ from kubernetriks_tpu.batched.state import (
     EV_REMOVE_NODE,
     EV_REMOVE_POD,
 )
+from kubernetriks_tpu.batched.timerep import from_f64_np
 from kubernetriks_tpu.core.events import (
     CreateNodeRequest,
     CreatePodGroupRequest,
@@ -174,9 +181,10 @@ class CompiledClusterTrace:
     node_names: List[str] = field(default_factory=list)
     pod_names: List[str] = field(default_factory=list)
     pod_groups: List[CompiledPodGroup] = field(default_factory=list)
-    # (N,) sampled repair span of each slot's chaos-engine crash event
-    # (0 where the slot never crashes); None when no faults were injected.
-    node_crash_downtime: Optional[np.ndarray] = None
+    # (K,) sampled repair span of each chaos-engine crash event, in event
+    # order (a slot may crash more than once: a recovery returns to it);
+    # None when no faults were injected.
+    crash_downtime_s: Optional[np.ndarray] = None
     # Interned labels and topology-spread constraints; None where no pod of
     # the trace carries a constraint (labels alone intern nothing).
     spread: Optional[CompiledSpread] = None
@@ -208,6 +216,43 @@ def _event_time_shifts(config) -> Tuple[float, float, float]:
 _NODE_EVENT_KINDS = (EV_CREATE_NODE, EV_REMOVE_NODE, EV_NODE_CRASH, EV_NODE_RECOVER)
 
 
+def _slot_reuse_clock(config):
+    """(window_of, free_chain) for deciding when a removed node's slot may
+    hold the node again: `window_of(t)` is the scheduling window an effect
+    time falls in, None where there is no config to read the window length
+    from (every re-creation then takes a fresh slot). Event application is
+    window-granular (a slot created and removed in one window ends dead),
+    and a finished pod's requests reach the scheduler's cache one
+    pending-free chain after the node let them go (state.make_step_constants,
+    delta_free_visible): the slot's allocatable must not be rebuilt before
+    the last such free of the dead incarnation has been applied, so the
+    re-creation has to fall in a window after the one that holds
+    removal + free_chain."""
+    if config is None:
+        return None, 0.0
+    from kubernetriks_tpu.batched.state import make_step_constants
+
+    interval = float(config.scheduling_cycle_interval)
+
+    def window_of(ts: float) -> int:
+        return int(from_f64_np(np.float64(ts), interval)[0])
+
+    return window_of, float(make_step_constants(config).delta_free_visible or 0.0)
+
+
+def _reusable_slot(dead, window, cap, labels, node_cap_cpu, node_cap_ram, node_labels):
+    """The slot of the name's last incarnation, if a creation in `window`
+    may return to it: windows apart (`dead` is (slot, first window allowed),
+    _slot_reuse_clock) and the same machine (capacity and labels are
+    per-slot tables); else None."""
+    if dead is None or window < dead[1]:
+        return None
+    slot = dead[0]
+    if (node_cap_cpu[slot], node_cap_ram[slot]) != cap or node_labels[slot] != labels:
+        return None
+    return slot
+
+
 def _node_slots_in_name_order(trace: CompiledClusterTrace) -> CompiledClusterTrace:
     """Renumber node slots so that slot order IS sorted-name order.
 
@@ -228,7 +273,6 @@ def _node_slots_in_name_order(trace: CompiledClusterTrace) -> CompiledClusterTra
     new_slot = np.empty(len(names), np.int32)
     new_slot[order] = np.arange(len(names), dtype=np.int32)
     is_node_event = np.isin(trace.ev_kind, _NODE_EVENT_KINDS)
-    downtime = trace.node_crash_downtime
     return dataclasses.replace(
         trace,
         ev_slot=np.where(
@@ -237,7 +281,6 @@ def _node_slots_in_name_order(trace: CompiledClusterTrace) -> CompiledClusterTra
         node_cap_cpu=trace.node_cap_cpu[order],
         node_cap_ram=trace.node_cap_ram[order],
         node_names=[names[slot] for slot in order],
-        node_crash_downtime=None if downtime is None else downtime[order],
         spread=None
         if trace.spread is None
         else dataclasses.replace(trace.spread, node_domain=trace.spread.node_domain[order]),
@@ -305,30 +348,45 @@ def compile_cluster_trace(
     pod_names: List[str] = []
     pod_slot: Dict[str, int] = {}
     pod_groups: List[CompiledPodGroup] = []
-    node_crash_downtime: Dict[int, float] = {}
+    crash_downtime_s: List[float] = []
     node_labels: List[Dict[str, str]] = []
     pod_objects: List[object] = []
+    # name -> (slot, the first window in which the slot may be created
+    # again) of the name's last, removed incarnation.
+    dead_node_slot: Dict[str, Tuple[int, int]] = {}
+    window_of, free_chain = _slot_reuse_clock(config)
 
     for ts, _, event in merged:
         if isinstance(event, CreateNodeRequest):
-            # Chaos recoveries are fresh-slot creations (slots are never
-            # reused); only the event kind differs, for fault accounting.
+            # A chaos recovery differs from a creation in its event kind
+            # alone, for fault accounting.
             node = event.node
-            slot = len(node_cap_cpu)
-            node_cap_cpu.append(int(node.status.capacity.cpu))
-            node_cap_ram.append(int(node.status.capacity.ram) // ram_unit)
-            node_names.append(node.metadata.name)
-            node_labels.append(node.metadata.labels)
-            live_node_slot[node.metadata.name] = slot
+            name = node.metadata.name
+            cap = (int(node.status.capacity.cpu), int(node.status.capacity.ram) // ram_unit)
+            slot = None
+            if window_of is not None:
+                slot = _reusable_slot(
+                    dead_node_slot.pop(name, None), window_of(ts),
+                    cap, node.metadata.labels, node_cap_cpu, node_cap_ram, node_labels,
+                )
+            if slot is None:
+                slot = len(node_cap_cpu)
+                node_cap_cpu.append(cap[0])
+                node_cap_ram.append(cap[1])
+                node_names.append(name)
+                node_labels.append(node.metadata.labels)
+            live_node_slot[name] = slot
             ev_time.append(ts)
             ev_kind.append(EV_NODE_RECOVER if event.recovered else EV_CREATE_NODE)
             ev_slot.append(slot)
         elif isinstance(event, RemoveNodeRequest):
             slot = live_node_slot.pop(event.node_name)
+            if window_of is not None:
+                dead_node_slot[event.node_name] = (slot, window_of(ts + free_chain) + 1)
             ev_time.append(ts)
             if event.crashed:
                 ev_kind.append(EV_NODE_CRASH)
-                node_crash_downtime[slot] = float(event.downtime_s)
+                crash_downtime_s.append(float(event.downtime_s))
             else:
                 ev_kind.append(EV_REMOVE_NODE)
             ev_slot.append(slot)
@@ -415,11 +473,6 @@ def compile_cluster_trace(
                 f"batched path does not support trace event {type(event).__name__}"
             )
 
-    crash_downtime_arr = None
-    if node_crash_downtime:
-        crash_downtime_arr = np.zeros(len(node_cap_cpu), np.float32)
-        for slot, ttr in node_crash_downtime.items():
-            crash_downtime_arr[slot] = ttr
     spread = _compile_spread(node_labels, pod_objects, len(node_cap_cpu))
     if spread is not None and pod_groups:
         raise ValueError(
@@ -439,7 +492,7 @@ def compile_cluster_trace(
         node_names=node_names,
         pod_names=pod_names,
         pod_groups=pod_groups,
-        node_crash_downtime=crash_downtime_arr,
+        crash_downtime_s=np.asarray(crash_downtime_s, np.float64) if crash_downtime_s else None,
         spread=spread,
     ))
 
@@ -520,7 +573,7 @@ def segment_pod_slots(
                 node_names=c.node_names,
                 pod_names=names,
                 pod_groups=groups,
-                node_crash_downtime=c.node_crash_downtime,
+                crash_downtime_s=c.crash_downtime_s,
             )
         )
     return out, T
@@ -533,7 +586,11 @@ def pad_and_batch(
     n_events: Optional[int] = None,
 ) -> Tuple[np.ndarray, ...]:
     """Stack per-cluster compilations into (C, ...) arrays, padding slots and
-    events (pad events: kind=EV_NONE, time=+inf)."""
+    events (pad events: kind=EV_NONE, time=+inf). The last array is the
+    chaos engine's downtime table, (C, K + 1) float32: entry k the summed
+    sampled repair spans of a cluster's first k crash events (summed in
+    float64), K the most crash events of any cluster; None where no trace
+    carries one."""
     C = len(compiled)
     N = n_nodes if n_nodes is not None else max((c.n_nodes for c in compiled), default=0)
     P = n_pods if n_pods is not None else max((c.n_pods for c in compiled), default=0)
@@ -549,7 +606,8 @@ def pad_and_batch(
     pod_req_cpu = np.zeros((C, P), np.int32)
     pod_req_ram = np.zeros((C, P), np.int32)
     pod_duration = np.full((C, P), -1.0, np.float64)
-    node_crash_downtime = np.zeros((C, N), np.float32)
+    K = max((len(c.crash_downtime_s) for c in compiled if c.crash_downtime_s is not None), default=0)
+    crash_downtime_cum = np.zeros((C, K + 1), np.float64) if K else None
 
     for i, c in enumerate(compiled):
         ev_time[i, : c.n_events] = c.ev_time
@@ -560,8 +618,10 @@ def pad_and_batch(
         pod_req_cpu[i, : c.n_pods] = c.pod_req_cpu
         pod_req_ram[i, : c.n_pods] = c.pod_req_ram
         pod_duration[i, : c.n_pods] = c.pod_duration
-        if c.node_crash_downtime is not None:
-            node_crash_downtime[i, : c.n_nodes] = c.node_crash_downtime
+        if c.crash_downtime_s is not None:
+            total = np.cumsum(c.crash_downtime_s)
+            crash_downtime_cum[i, 1 : len(total) + 1] = total
+            crash_downtime_cum[i, len(total) + 1 :] = total[-1]
 
     return (
         ev_time,
@@ -572,7 +632,7 @@ def pad_and_batch(
         pod_req_cpu,
         pod_req_ram,
         pod_duration,
-        node_crash_downtime,
+        None if crash_downtime_cum is None else crash_downtime_cum.astype(np.float32),
     )
 
 

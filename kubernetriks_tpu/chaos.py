@@ -17,7 +17,8 @@ Two fault channels:
   compile exactly. A crash rides the planned node-removal chain (flagged
   `crashed`, carrying its pre-sampled downtime); a recovery is a fresh
   CreateNodeRequest (flagged `recovered`) — the node returns as fresh
-  capacity on a NEW slot/pool component in both paths, visible to the
+  capacity (a new pool component on the scalar path, its own slot on the
+  batched one: trace_compile), visible to the
   cluster autoscaler like any other capacity. TTF/TTR draws are clamped
   below at one scheduling interval so every crash->recover->crash transition
   lands in its own batched window (the bulk event application is
@@ -79,19 +80,22 @@ def has_node_faults(cfg) -> bool:
     )
 
 
-def make_fault_params(config) -> Optional[FaultParams]:
+def make_fault_params(config, node_fault_events: bool = False) -> Optional[FaultParams]:
     """FaultParams from a SimulationConfig; None when fault injection is
-    disabled or configured to do nothing."""
+    disabled or configured to do nothing. `node_fault_events`: the compiled
+    traces carry crash / recover events (sampled from this config or handed
+    to the build already sampled, with no `fault_injection` block at all);
+    they switch the node channel on by themselves."""
     cfg = getattr(config, "fault_injection", None)
-    if cfg is None or not cfg.enabled:
-        return None
-    node_faults = has_node_faults(cfg)
-    pod = cfg.pod
+    if cfg is not None and not cfg.enabled:
+        cfg = None
+    node_faults = has_node_faults(cfg) or node_fault_events
+    pod = cfg.pod if cfg is not None else None
     fail_prob = float(pod.fail_prob) if pod else 0.0
     if not node_faults and fail_prob <= 0:
         return None
     return FaultParams(
-        seed=int(cfg.seed if cfg.seed is not None else config.seed),
+        seed=int(cfg.seed if cfg is not None and cfg.seed is not None else config.seed),
         fail_prob=fail_prob,
         backoff_base=float(pod.backoff_base) if pod else 10.0,
         backoff_cap=float(pod.backoff_cap) if pod else 300.0,
@@ -342,7 +346,7 @@ def inject_node_faults(
     preserved) plus sampled crash/recover events appended in time order.
     Crash = RemoveNodeRequest(crashed=True, downtime_s=sampled TTR);
     recover = CreateNodeRequest(recovered=True) with the node's original
-    capacity (a fresh slot / pool component in both paths). Deterministic in
+    capacity. Deterministic in
     (cfg, seed, cluster_idx, trace)."""
     from kubernetriks_tpu.core.events import CreateNodeRequest, RemoveNodeRequest
 

@@ -37,7 +37,7 @@ against the lax.scan engine in chip_smoke.py.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -947,14 +947,18 @@ def fused_free_resources(
         )
 
 
-def event_kernel_fits(n_nodes: int, n_pods: int, n_events: int) -> bool:
+def event_kernel_fits(
+    n_nodes: int, n_pods: int, n_events: int, node_faults: bool = False
+) -> bool:
     """VMEM fits-check for the event-scatter kernel: 3 pod in + 3 pod out,
-    2 node in + 2 node out, 5 event blocks, int32/f32, double-buffered,
-    plus loop-body temporaries (the kernel raises the scoped limit)."""
+    2 node in + 2 node out (3 + 3 with the crash accumulator of a build
+    under node faults), 5 event blocks, int32/f32, double-buffered, plus
+    loop-body temporaries (the kernel raises the scoped limit)."""
     np_pad = -(-n_nodes // _SUB) * _SUB
     pp_pad = -(-n_pods // _SUB) * _SUB
     ep_pad = -(-n_events // _SUB) * _SUB
-    resident = (6 * pp_pad + 4 * np_pad + 5 * ep_pad) * _LANE * 4
+    node_planes = 6 if node_faults else 4
+    resident = (6 * pp_pad + node_planes * np_pad + 5 * ep_pad) * _LANE * 4
     return 2 * resident <= int(0.8 * _SELECT_VMEM_LIMIT)
 
 
@@ -964,6 +968,18 @@ _EV_CREATE_NODE = 1
 _EV_REMOVE_NODE = 2
 _EV_CREATE_POD = 3
 _EV_REMOVE_POD = 4
+_EV_NODE_CRASH = 5
+_EV_NODE_RECOVER = 6
+
+
+def _event_kernel_faults(*refs):
+    """_event_kernel for a build under node faults: the operands and results
+    of that kernel with the crash-removal accumulator (Np, LC) float32 as a
+    sixth of each, before the scratch."""
+    ins, outs, live_ref = refs[:11], refs[11:17], refs[17]
+    _event_kernel(
+        *ins[:10], *outs[:5], live_ref, crm_ref=ins[10], crm_out=outs[5]
+    )
 
 
 def _event_kernel(
@@ -983,6 +999,8 @@ def _event_kernel(
     pseq_out,
     prm_out,
     live_ref,     # SMEM (row tiles,) int32 scratch: the live tile list
+    crm_ref=None,  # (Np, LC) float32 crash-removal time accumulator (min)
+    crm_out=None,
 ):
     """Apply one chunk of due trace events to the per-slot accumulators —
     the Pallas replacement for the five (C, E)-indexed XLA scatters in
@@ -995,7 +1013,14 @@ def _event_kernel(
     What a step sweeps: the two node accumulators whole, the three pod
     accumulators only over the row tiles between the lowest and the highest
     pod slot any valid event of the chunk names (a window's creates are
-    neighbours in slot order) — no other row can match a one-hot."""
+    neighbours in slot order) — no other row can match a one-hot.
+
+    With `crm_ref` (a build under node faults) the kernel knows the chaos
+    engine's two kinds as batched/step.py's scatter path does: a recovery is
+    a creation, a crash a removal that goes to its own accumulator (the
+    caller merges the two removal planes after the loop, and keeps the
+    crash's for the interruption counter)."""
+    node_faults = crm_ref is not None
     i0 = jnp.int32(0)
     i1 = jnp.int32(1)
     neg1 = jnp.int32(-1)
@@ -1006,6 +1031,8 @@ def _event_kernel(
     pcr_out[:] = pcr_ref[:]
     pseq_out[:] = pseq_ref[:]
     prm_out[:] = prm_ref[:]
+    if node_faults:
+        crm_out[:] = crm_ref[:]
 
     iota_n = jax.lax.broadcasted_iota(jnp.int32, created_ref.shape, 0)
     k_bound = jnp.max(jnp.sum(valid_ref[:], axis=0, keepdims=True))
@@ -1043,6 +1070,12 @@ def _event_kernel(
         is_rp = v & (kind == jnp.int32(_EV_REMOVE_POD))
 
         oh_n = iota_n == slot
+        if node_faults:
+            is_cn = is_cn | (v & (kind == jnp.int32(_EV_NODE_RECOVER)))
+            is_crash = v & (kind == jnp.int32(_EV_NODE_CRASH))
+            crm_out[:] = jnp.where(
+                oh_n & is_crash, jnp.minimum(crm_out[:], rel), crm_out[:]
+            )
         created_out[:] = jnp.where(oh_n & is_cn, i1, created_out[:])
         nrm_out[:] = jnp.where(
             oh_n & is_rn, jnp.minimum(nrm_out[:], rel), nrm_out[:]
@@ -1098,8 +1131,9 @@ def event_accumulators_unpack(
     """The five accumulators as batched/step.py's row-major consumers read
     them, ONCE after the loop: created as bool and node_removal (C, N), or
     (N, C) with nodes_lane_major (a slice, no transpose); the three pod
-    planes (C, P)."""
-    created, node_removal, pod_create, pod_create_seq, pod_removal = acc
+    planes (C, P). A sixth, the crash-removal plane of a build under node
+    faults, comes out as node_removal does."""
+    created, node_removal, pod_create, pod_create_seq, pod_removal = acc[:5]
     with jax.named_scope("kernel_io"):
         return (
             _unprep_node(created, nodes_lane_major, n_nodes, n_clusters) != 0,
@@ -1107,6 +1141,8 @@ def event_accumulators_unpack(
             _unprep_node(pod_create, False, n_pods, n_clusters),
             _unprep_node(pod_create_seq, False, n_pods, n_clusters),
             _unprep_node(pod_removal, False, n_pods, n_clusters),
+        ) + tuple(
+            _unprep_node(x, nodes_lane_major, n_nodes, n_clusters) for x in acc[5:]
         )
 
 
@@ -1122,6 +1158,7 @@ def fused_event_scatter(
     pod_create: jnp.ndarray,    # (Pp, Cp) float32
     pod_create_seq: jnp.ndarray,  # (Pp, Cp) int32
     pod_removal: jnp.ndarray,   # (Pp, Cp) float32
+    crash_removal: Optional[jnp.ndarray] = None,  # (Np, Cp) float32
     interpret: bool = False,
 ):
     """Returns the five accumulators with this chunk's events applied,
@@ -1132,7 +1169,12 @@ def fused_event_scatter(
     round trip at this boundary, and the caller leaves the layout once
     after the loop (event_accumulators_unpack). The event columns are
     per-chunk data and keep the row-major convention: five (E, Cp) planes
-    transposed and padded a pass."""
+    transposed and padded a pass.
+
+    With `crash_removal` (a build under node faults: the chunk may hold
+    EV_NODE_CRASH and EV_NODE_RECOVER) it is the sixth accumulator, in
+    node_removal's layout, and the sixth result; without it the launch is
+    the five-accumulator one, operand for operand."""
     C, E = ev_kind.shape
     Np, Cp = created.shape
     Pp = pod_create.shape[0]
@@ -1156,19 +1198,21 @@ def fused_event_scatter(
             pod_create,
             pod_create_seq,
             pod_removal,
-        )
+        ) + (() if crash_removal is None else (crash_removal,))
+    n_acc = len(args) - 5
 
     def spec(n_sub):
         return pl.BlockSpec((n_sub, _LANE), lambda i: (0, i), memory_space=pltpu.VMEM)
 
+    acc_specs = [spec(Np)] * 2 + [spec(Pp)] * 3 + [spec(Np)] * (n_acc - 5)
     with jax.enable_x64(False):
         return tuple(
             pl.pallas_call(
-                _event_kernel,
+                _event_kernel if crash_removal is None else _event_kernel_faults,
                 name="fused_event_scatter",
                 grid=(Cp // _LANE,),
-                in_specs=[spec(Ep)] * 5 + [spec(Np)] * 2 + [spec(Pp)] * 3,
-                out_specs=[spec(Np)] * 2 + [spec(Pp)] * 3,
+                in_specs=[spec(Ep)] * 5 + acc_specs,
+                out_specs=acc_specs,
                 out_shape=[
                     jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args[5:]
                 ],
@@ -1177,7 +1221,7 @@ def fused_event_scatter(
                 # the kernel's operand AND its result, so XLA copies no
                 # plane between passes. A grid program reads its own lane
                 # tile whole before it writes it, and no other's.
-                input_output_aliases={5 + i: i for i in range(5)},
+                input_output_aliases={5 + i: i for i in range(n_acc)},
                 compiler_params=pltpu.CompilerParams(
                     vmem_limit_bytes=_SELECT_VMEM_LIMIT
                 ),

@@ -158,10 +158,11 @@ PHASE_NAMES = (
 # programs carry (batched/step.py, batched/autoscale.py and the kernel
 # wrappers of ops/), so that every device op of a window says in its
 # `op_name` path which part of the simulator it belongs to. Seven top-level
-# phases partition `_window_body` and the loops round it; `kernel_io` is the
-# one NESTED phase, inside the kernel wrappers, round the pads, transposes,
+# phases partition `_window_body` and the loops round it; two are NESTED:
+# `kernel_io`, inside the kernel wrappers, round the pads, transposes,
 # casts and slices that marshal a pallas_call's operands and results (never
-# round the call itself). An op's TOP-LEVEL phase is the FIRST name of this
+# round the call itself), and `node_faults`, inside `events`, round what a
+# build under node faults adds to the event application. An op's TOP-LEVEL phase is the FIRST name of this
 # tuple in its `op_name` path, its INNERMOST phase the LAST (`phase_of`);
 # other scopes (`spread_counts`, `ca_scale_up`, `ca_scale_down`) nest inside
 # a phase and name no phase themselves. A scope is location metadata: the
@@ -175,6 +176,7 @@ DEVICE_PHASES = (
     "slide",  # the pod window's shift, refill and the superspan's capacity read
     "bookkeeping",  # lane freeze, the ring's record, fast-forward, layout swaps, loop counters
     "kernel_io",  # nested: a kernel wrapper's operand and result marshalling
+    "node_faults",  # nested in events: what crash and recovery add (attribution, counters, the reschedule order)
 )
 
 

@@ -417,3 +417,87 @@ def test_batched_chain_compilation_empty_inputs():
         1, chaos.STREAM_NODE, 0, [], [], [], 100.0, 10.0, 5.0,
         "exponential", 10.0,
     ) == []
+
+
+# --- recovery on identical nodes ----------------------------------------------
+
+EQUAL_NODES_FAULT_YAML = """
+fault_injection:
+  enabled: true
+  node:
+    mttf: 3000.0
+    mttr: 60.0
+  failure_groups:
+  - members: [node_000, node_001, node_002, node_003]
+    mttf: 1500.0
+    mttr: 90.0
+"""
+
+
+def _equal_capacity_events(seed, n_nodes=12, n_pods=160):
+    """IDENTICAL nodes under zero-padded names, small pods: nearly every
+    placement is an exact score tie between empty nodes, broken by the last
+    name in sorted order on the scalar path and by the highest slot on the
+    batched one."""
+    rng = np.random.default_rng(seed)
+    cluster = [
+        (0.0, CreateNodeRequest(node=Node.new(f"node_{i:03d}", 16000, 32 * 1024**3)))
+        for i in range(n_nodes)
+    ]
+    from kubernetriks_tpu.core.events import CreatePodRequest
+    from kubernetriks_tpu.core.types import Pod
+
+    times = np.sort(np.round(rng.uniform(1.0, 1400.0, n_pods), 3))
+    workload = [
+        (
+            float(t),
+            CreatePodRequest(
+                pod=Pod.new(f"pod_{i:04d}", 2000, 4 * 1024**3, float(np.round(rng.uniform(30.0, 200.0), 3)))
+            ),
+        )
+        for i, t in enumerate(times)
+    ]
+    return cluster, workload
+
+
+@pytest.mark.parametrize("seed", [11, 12])
+def test_recovery_on_equal_capacity_nodes_keeps_the_scalar_tie_break(seed):
+    """The case the fault suites dodged with heterogeneous capacities: a
+    recovered node returns to ITS OWN slot (trace_compile), so slot order
+    stays sorted-name order through crash and recovery and equal-capacity
+    nodes tie-break as the scalar walk does, pod for pod; the node axis is
+    the trace's node count whatever the schedule."""
+    from kubernetriks_tpu.trace.interface import Trace
+
+    class _Events(Trace):
+        def __init__(self, events):
+            self._events = events
+
+        def convert_to_simulator_events(self):
+            return self._events
+
+        def event_count(self):
+            return len(self._events)
+
+    config = default_test_simulation_config(EQUAL_NODES_FAULT_YAML)
+    cluster, workload = _equal_capacity_events(seed)
+    scalar = KubernetriksSimulation(config)
+    scalar.initialize(_Events(cluster), _Events(workload))
+    scalar.step_until_time(END_TIME)
+    batched = build_batched_from_traces(config, cluster, workload, n_clusters=1)
+    assert batched.n_nodes == 12
+    batched.step_until_time(END_TIME)
+
+    sm = scalar.metrics_collector.accumulated_metrics
+    bm = batched.metrics_summary()["counters"]
+    assert sm.node_crashes >= 8 and sm.node_recoveries >= 8 and sm.pod_interruptions > 0
+    for name in ("node_crashes", "node_recoveries", "pod_interruptions"):
+        assert bm[name] == getattr(sm, name), name
+    assert bm["node_downtime_s"] == pytest.approx(sm.node_downtime_s, rel=1e-5)
+    assert bm["pods_succeeded"] == sm.pods_succeeded == len(workload)
+    succeeded = scalar.persistent_storage.succeeded_pods
+    for name, b in batched.pod_view(0).items():
+        pod = succeeded[name]
+        assert b["phase"] == PHASE_SUCCEEDED and b["node"] == pod.status.assigned_node, (name, seed)
+        start = pod.get_condition(PodConditionType.POD_RUNNING).last_transition_time
+        assert b["start_time"] == pytest.approx(start, abs=5e-6), (name, seed)

@@ -27,16 +27,17 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     records = [json.loads(line) for line in lines if line.startswith("{")]
     assert json.loads(lines[-1]) == records[-1]
     assert [r.get("leg") for r in records] == [
-        "start", "pure", "composed", "served", "cli", "summary", None,
+        "start", "pure", "composed", "served", "cli", "faults", "summary", None,
     ]
-    start, pure, composed, served, cli, summary, result = records
+    start, pure, composed, served, cli, faults, summary, result = records
     assert start["cpu_plumbing"] is True
     assert start["device"]["platform"] == "cpu"
     assert os.path.basename(start["compile_cache"]) == ".jax_cache"
 
-    kernels = {"cycle": "candidate", "interpret": True, "ranking": "float32", "sharding": None}  # uniform pods: lockstep; no mesh
+    # uniform pods: lockstep; no mesh; four clusters: the event loop on its scatter path
+    kernels = {"cycle": "candidate", "interpret": True, "ranking": "float32", "events": "scatter", "sharding": None}
     ca_kernels = {**kernels, "ca_up": "kernel", "ca_down": "kernel"}
-    for rec in (pure, composed, served, cli):
+    for rec in (pure, composed, served, cli, faults):
         assert rec.pop("wall_s") >= 0
     assert pure == {
         "leg": "pure", "clusters": 4, "nodes": 8, "pods": 512,
@@ -59,10 +60,17 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     assert cli == {
         "leg": "cli", "clusters": 2, "pods_succeeded": 4, "decisions": 4,
     }
+    # identical nodes, crashes and a rack's loss and return, every pod run to its end
+    assert faults == {
+        "leg": "faults", "clusters": 4, "nodes": 8, "pods": 896,
+        "formulation": kernels, "node_crashes": 17, "node_recoveries": 16,
+        "pod_interruptions": 238, "pods_succeeded": 2821, "reference": "lax.scan",
+        "mismatches": 0,
+    }
     assert summary.pop("wall_s") >= 0
     assert summary == {
         "leg": "summary", "cpu_plumbing": True, "devices_used": 1,
-        "legs": ["pure", "composed", "served", "cli"], "claim": None,
+        "legs": ["pure", "composed", "served", "cli", "faults"], "claim": None,
     }
     # The chip check reads the last stdout line and takes these keys only.
     assert result == {"ok": True, "device": start["device"]}
