@@ -3966,6 +3966,17 @@ class BatchedSimulation:
         counters.update(cycle)
         self.tracer.counters.update(cycle)
         counters.update(self._spread_counters())
+        # The reschedule order's counters (step._stable_queue_rank), summed
+        # over clusters: cluster-windows that ranked a removed node's pods,
+        # and those that had more than the compacted rank holds (any of
+        # them sent its window to the sort). Published by every build: the
+        # rank is in every window program.
+        resched = {
+            "resched_rank_windows": int(np.asarray(m.resched_rank_windows).sum()),
+            "resched_rank_sorted": int(np.asarray(m.resched_rank_sorted).sum()),
+        }
+        counters.update(resched)
+        self.tracer.counters.update(resched)
         if self.fault_params is not None and self.fault_params.node_faults:
             # A build under node faults publishes the chaos counters the
             # same way (no new leaf: the metrics state has always held them).

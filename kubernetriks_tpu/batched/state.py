@@ -248,6 +248,14 @@ class MetricArrays(NamedTuple):
     cycle_late_decisions: jnp.ndarray  # int32
     cycle_deepest: jnp.ndarray  # int32
     cycle_overruns: jnp.ndarray  # int32
+    # The reschedule order's two counters (step._stable_queue_rank; no scalar
+    # counterpart): the windows in which the cluster had a pod of a removed
+    # node to rank, and those of them in which it had more than the
+    # compacted rank holds (step.RANK_COMPACT_SLOTS), which is what sends a
+    # window of the batch to the sort of the whole pod axis. A cluster's own,
+    # like the cycle's: zero in the second over a batch says no window sorted.
+    resched_rank_windows: jnp.ndarray  # int32
+    resched_rank_sorted: jnp.ndarray  # int32
     queue_time: EstArrays
     algo_latency: EstArrays
     pod_duration: EstArrays
@@ -725,6 +733,8 @@ def init_state(
         cycle_late_decisions=jnp.zeros((C,), jnp.int32),
         cycle_deepest=jnp.zeros((C,), jnp.int32),
         cycle_overruns=jnp.zeros((C,), jnp.int32),
+        resched_rank_windows=jnp.zeros((C,), jnp.int32),
+        resched_rank_sorted=jnp.zeros((C,), jnp.int32),
         queue_time=EstArrays.zeros((C,)),
         algo_latency=EstArrays.zeros((C,)),
         pod_duration=EstArrays.zeros((C,)),
@@ -926,6 +936,8 @@ AXIS_SIGNATURES = {
     "cycle_late_decisions": "C",
     "cycle_deepest": "C",
     "cycle_overruns": "C",
+    "resched_rank_windows": "C",
+    "resched_rank_sorted": "C",
 }
 
 

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from kubernetriks_tpu.batched.autoscale import _rows_at
 from kubernetriks_tpu.batched.state import (
     ClusterBatchState,
     EstArrays,
@@ -130,25 +131,118 @@ def _rel_seconds(t: TPair, base_win: jnp.ndarray, interval) -> jnp.ndarray:
     return (t.win - base_win).astype(jnp.float32) * jnp.float32(interval) + t.off
 
 
-def _stable_queue_rank(keys, by_sort: bool = False) -> jnp.ndarray:
-    """Dense queue ranks from lexicographic (C, P) sort keys: the inverse of
-    a stable sort over the pod axis, slot order breaking exact key ties.
-    Shared by the reschedule and CrashLoopBackOff retry dispositions so the
-    scalar-parity ordering rules live in ONE place. The inverse is a scatter
-    (C x P indices, paid per index on the TPU), or with `by_sort` a second
-    sort of the permutation, the same ranks: the form for a build that ranks
-    in most of its windows (node faults; PERF.md section 6, PR 43)."""
-    C, P = keys[0].shape
+# Slots of the compacted queue rank (_stable_queue_rank). What it costs grows
+# with the width (sched1k-faults.montecarlo on one v5e: 5.31 ms a window at 64,
+# 6.11 at 128), what it must hold is one cluster's re-queued pods of one
+# window: there 1 or 2 (a single node's) or 40 to 50 (a rack of 50 in the band
+# of nodes the tie-break keeps occupied, a pod a node), 50 at most in 1,250
+# clusters x 120 windows x 3 seeds. 64 holds a rack and a dozen single nodes;
+# two such racks in one cluster's window (one cluster-window in 7,400) go to
+# the sort, as anything larger does (PERF.md section 6, PR 44).
+RANK_COMPACT_SLOTS = 64
+
+
+def _rank_compacted(keys, mask, n, pos, R: int) -> jnp.ndarray:
+    """_stable_queue_rank's ranks where no cluster masks more than R rows
+    (n (C,): rows masked; pos (C, P): their running count less one, a masked
+    row's slot-order position among them). The keys are brought to (C, R) by
+    `pos` (a compare-and-reduce over (C, R, P); a table key is looked up at
+    the R compacted indices, (C, L, R)), each compacted row counts the rows
+    that sort before it (C, R, R), and the counts return through `pos`
+    (autoscale._rows_at). No sort, gather or scatter: paid per element,
+    where those pay per slot or per index of the whole pod axis for the
+    handful a window re-queues (PERF.md section 6, PR 44)."""
+    slot = jnp.arange(R, dtype=jnp.int32)
+    # Laid out (C, R, P) and reduced over its last axis. As
+    # autoscale._rows_put has it, (C, P, R) reduced over P, the same sums
+    # were one fusion of 2.10 ms a window at 1,250 x 2,048 x 128 where these
+    # are one of 0.62, and 0.33 at 64 (PERF.md section 6, PR 44).
+    hit = jnp.where(mask, pos, -1)[:, None, :] == slot[None, :, None]
+
+    def bring(v):
+        bits = v if v.dtype == jnp.int32 else jax.lax.bitcast_convert_type(v, jnp.int32)
+        out = jnp.where(hit, bits[:, None, :], 0).sum(axis=2, dtype=jnp.int32)
+        return out if v.dtype == jnp.int32 else jax.lax.bitcast_convert_type(out, v.dtype)
+
+    ks = [
+        _rows_at(key[0], bring(key[1])) if isinstance(key, tuple) else bring(key)
+        for key in keys
+    ]
+    # before[c, r, s]: compacted row s sorts before compacted row r.
+    before = slot[None, None, :] < slot[None, :, None]
+    for k in reversed(ks):
+        k_r, k_s = k[:, :, None], k[:, None, :]
+        before = (k_s < k_r) | ((k_s == k_r) & before)
+    live = slot[None, None, :] < n[:, None, None]
+    rank = (before & live).sum(axis=2, dtype=jnp.int32)
+    return _rows_at(rank, jnp.clip(pos, 0, R - 1))
+
+
+def _rank_by_sort(keys, mask) -> jnp.ndarray:
+    """_stable_queue_rank's ranks whatever the mask holds: a stable sort of
+    the whole pod axis with the unmasked rows keyed last, and a second sort
+    of its permutation to invert it."""
+    C, P = mask.shape
+    ks = []
+    for key in keys:
+        k = _rows_at(*key) if isinstance(key, tuple) else key
+        last = INF if k.dtype == jnp.float32 else 1 << 30
+        ks.append(jnp.where(mask, k, jnp.asarray(last, k.dtype)))
     iota_pp = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None, :], (C, P))
-    out = jax.lax.sort(
-        (*keys, iota_pp), dimension=1, num_keys=len(keys), is_stable=True
-    )
-    if by_sort:
-        return jax.lax.sort((out[-1], iota_pp), dimension=1, num_keys=1)[1]
+    out = jax.lax.sort((*ks, iota_pp), dimension=1, num_keys=len(ks), is_stable=True)
+    return jax.lax.sort((out[-1], iota_pp), dimension=1, num_keys=1)[1]
+
+
+def _stable_queue_rank(keys, mask):
+    """Dense queue ranks of the `mask` rows ((C, P) bool) under lexicographic
+    sort keys, slot order breaking exact key ties: on a masked row, its
+    position in a stable sort of the pod axis that puts the masked rows
+    first; elsewhere unspecified (callers read it under `mask`). Shared by
+    the reschedule and CrashLoopBackOff retry dispositions so the
+    scalar-parity ordering rules live in ONE place.
+
+    A key is a (C, P) plane (float32 compared as lax.sort compares it:
+    numerically, -0.0 equal to 0.0; a masked row's key is never nan or inf,
+    and its int32 keys are under 2**30) or a pair (table (C, L) int32 or a
+    thunk of one, idx (C, P) in [0, L)) standing for table[c, idx[c, p]],
+    made and looked up only in a window that ranks.
+
+    Three branches, by what the window holds (the predicates reduce over
+    the clusters a program holds, like the razor's): no row masked, zeros,
+    which nobody reads; every cluster masks at most R =
+    min(RANK_COMPACT_SLOTS, P) rows, _rank_compacted; some cluster masks
+    more, _rank_by_sort: the same ranks on the masked rows bit for bit, kept
+    so that R is a width and never a limit on what a window may re-queue.
+
+    Returns (ranks (C, P) int32, over (C,) bool: the clusters whose rows
+    passed R, any of which sends the window to the sort)."""
+    R = min(RANK_COMPACT_SLOTS, mask.shape[1])
+    n = mask.sum(axis=1, dtype=jnp.int32)
+    over = n > R
+
+    def ranked():
+        pos = jnp.cumsum(mask, axis=1, dtype=jnp.int32) - 1
+        # A thunk's table is made once, for whichever form ranks.
+        held = tuple(
+            (key[0]() if callable(key[0]) else key[0], key[1])
+            if isinstance(key, tuple)
+            else key
+            for key in keys
+        )
+        return jax.lax.cond(
+            over.any(),
+            lambda: _rank_by_sort(held, mask),
+            lambda: _rank_compacted(held, mask, n, pos, R),
+        )
+
+    # Nothing masked: nobody reads the ranks, and the running count that
+    # stood here is not free (a `cumsum` over the pod axis of planes that
+    # lie cluster-minor: 0.28 ms a window of cell 1 in one draft).
     return (
-        jnp.zeros((C, P), jnp.int32)
-        .at[jnp.arange(C, dtype=jnp.int32)[:, None], out[-1]]
-        .set(iota_pp)
+        jax.lax.cond(
+            (n > 0).any(), ranked, lambda: jnp.zeros(mask.shape, jnp.int32)
+        ),
+        over,
     )
 
 
@@ -709,8 +803,6 @@ def _apply_window_events_work(
         # never uses, whether that removal was the crash's (ties attribute
         # to the crash, matching the scalar chain where the crash IS the
         # removal): crash_rm <= the merged removal time.
-        from kubernetriks_tpu.batched.autoscale import _rows_at
-
         def node_rows(x):
             """A node-layout plane as (C, N) rows."""
             return x.T if lane_major else x
@@ -915,37 +1007,24 @@ def _apply_window_events_work(
     # the order the removal requests were EMITTED (the CA walks scale-down
     # candidates in node-name order), then sorted pod names within a node.
     # Name ranks come from the autoscale statics when available; slot order
-    # is the fallback (equal keys keep slot order under the stable sort).
-    def _resched_rank_exact():
-        big = jnp.int32(1 << 30)
-        node_c2 = jnp.clip(pods.node, 0, N - 1)
-        if node_key_fn is not None:
-            # Slot reclaim: removed CA nodes order by their occupants'
-            # CURRENT names (allocation-index keys, autoscale.ca_name_order)
-            # — the static table describes the slots' first occupants.
-            nr = node_key_fn()[jnp.arange(C, dtype=jnp.int32)[:, None], node_c2]
-        elif node_name_rank is not None and node_faults:
-            nr = _rows_at(node_name_rank, node_c2)  # dense, as the removal look-up
-        elif node_name_rank is not None:
-            nr = node_name_rank[jnp.arange(C, dtype=jnp.int32)[:, None], node_c2]
-        else:
-            nr = node_c2
-        k1 = jnp.where(rescheds, pod_node_removal, f32inf)
-        k2 = jnp.where(rescheds, nr, big)
-        if pod_name_rank is not None:
-            k3 = jnp.where(rescheds, pod_name_rank, big)
-        else:
-            k3 = jnp.zeros((C, P), jnp.int32)
-        return _stable_queue_rank((k1, k2, k3), by_sort=node_faults)
-
+    # is the fallback (equal keys keep slot order in _stable_queue_rank).
+    if node_key_fn is not None:
+        # Slot reclaim: removed CA nodes order by their occupants' CURRENT
+        # names (allocation-index keys, autoscale.ca_name_order), made only
+        # in a window that ranks: the static table describes the slots'
+        # first occupants.
+        node_key = (node_key_fn, node_idx)
+    elif node_name_rank is not None:
+        node_key = (node_name_rank, node_idx)
+    else:
+        node_key = node_idx
+    resched_keys = (pod_node_removal, node_key)
+    if pod_name_rank is not None:
+        resched_keys += (pod_name_rank,)
     # (Under node faults the ordering is the crash path's: a rack's pods
     # re-enter the queue together.)
     with faults_scope() if node_faults else contextlib.nullcontext():
-        resched_rank = jax.lax.cond(
-            rescheds.any(),
-            _resched_rank_exact,
-            lambda: jnp.cumsum(rescheds, axis=1, dtype=jnp.int32) - 1,
-        )
+        resched_rank, rank_over = _stable_queue_rank(resched_keys, rescheds)
     resched_ts = t_norm(
         jnp.broadcast_to(base[:, None], (C, P)),
         jnp.where(rescheds, pod_node_removal, 0.0)
@@ -963,6 +1042,12 @@ def _apply_window_events_work(
     finish_time = t_where(rescheds, t_inf((C, P)), finish_time)
     pod_node = jnp.where(rescheds, -1, pods.node)
     n_rescheds = rescheds.sum(axis=1, dtype=jnp.int32)
+    metrics = metrics._replace(
+        resched_rank_windows=metrics.resched_rank_windows
+        + (n_rescheds > 0).astype(jnp.int32),
+        resched_rank_sorted=metrics.resched_rank_sorted
+        + rank_over.astype(jnp.int32),
+    )
 
     # Chaos: dispose of failing attempts — CrashLoopBackOff retry (requeue
     # at fail + min(base * 2^k, cap), fresh initial-attempt timestamp,
@@ -996,23 +1081,13 @@ def _apply_window_events_work(
             interval,
         )
 
-        def _fail_rank_exact():
-            # Seq ranks among this window's retries follow the scalar's
-            # failure-event order: fail time, then pod name (slot order as
-            # the rank-less fallback, kept by the stable sort).
-            big = jnp.int32(1 << 30)
-            k1 = jnp.where(retry, fail_rel, f32inf)
-            if pod_name_rank is not None:
-                k2 = jnp.where(retry, pod_name_rank, big)
-            else:
-                k2 = jnp.zeros((C, P), jnp.int32)
-            return _stable_queue_rank((k1, k2))
-
-        fail_rank = jax.lax.cond(
-            retry.any(),
-            _fail_rank_exact,
-            lambda: jnp.cumsum(retry, axis=1, dtype=jnp.int32) - 1,
-        )
+        # Seq ranks among this window's retries follow the scalar's
+        # failure-event order: fail time, then pod name (slot order as the
+        # rank-less fallback).
+        fail_keys = (fail_rel,)
+        if pod_name_rank is not None:
+            fail_keys += (pod_name_rank,)
+        fail_rank, _ = _stable_queue_rank(fail_keys, retry)
         phase = jnp.where(
             retry,
             PHASE_QUEUED,

@@ -6,10 +6,12 @@ batched state across donation on/off and fast-forward on/off, and
 seed-determinism (same seed -> bit-identical, different seed -> different).
 """
 
+import jax
 import numpy as np
 import pytest
 
 from kubernetriks_tpu import chaos
+from kubernetriks_tpu.batched import step
 from kubernetriks_tpu.batched.engine import build_batched_from_traces
 from kubernetriks_tpu.batched.state import (
     PHASE_FAILED,
@@ -239,11 +241,33 @@ def _build_batched(config, seed, **kwargs):
     )
 
 
+@pytest.fixture
+def rank_slots(request, monkeypatch):
+    """The compacted queue rank's width for one test (step.RANK_COMPACT_SLOTS;
+    None: as it is). The width is read when a window program is traced, so the
+    programs traced under another are dropped on both sides of the test."""
+    if request.param is not None:
+        jax.clear_caches()
+        monkeypatch.setattr(step, "RANK_COMPACT_SLOTS", request.param)
+    yield request.param
+    if request.param is not None:
+        jax.clear_caches()
+
+
 @pytest.mark.parametrize(
-    "seed,fault_yaml",
-    [(101, FAULT_YAML), (202, GROUP_FAULT_YAML), (101, SHORT_BACKOFF_YAML)],
+    "seed,fault_yaml,rank_slots",
+    [
+        (101, FAULT_YAML, None),
+        (202, GROUP_FAULT_YAML, None),
+        (101, SHORT_BACKOFF_YAML, None),
+        # A failure group's crash re-queues more pods than a rank of ONE
+        # slot holds: the order comes from the sort of the whole pod axis,
+        # the fallback, end to end.
+        (202, GROUP_FAULT_YAML, 1),
+    ],
+    indirect=["rank_slots"],
 )
-def test_fault_enabled_cross_path_equivalence(seed, fault_yaml):
+def test_fault_enabled_cross_path_equivalence(seed, fault_yaml, rank_slots):
     """The acceptance property: on a fault-enabled random trace the scalar
     and batched paths agree on every terminal counter INCLUDING the fault
     metrics, and pod-for-pod on terminal states."""
@@ -252,6 +276,13 @@ def test_fault_enabled_cross_path_equivalence(seed, fault_yaml):
     scalar = _run_scalar(config, seed)
     batched = _build_batched(config, seed)
     batched.step_until_time(END_TIME)
+
+    # Which branch ordered the re-queued pods: a window in which a cluster
+    # re-queued more than the slots took the sort, and only such a window.
+    ranked = int(np.asarray(batched.state.metrics.resched_rank_windows).sum())
+    by_sort = int(np.asarray(batched.state.metrics.resched_rank_sorted).sum())
+    assert ranked > 0
+    assert (by_sort > 0) == (rank_slots is not None), (ranked, by_sort)
 
     sm = scalar.metrics_collector.accumulated_metrics
     bm = batched.metrics_summary()["counters"]
