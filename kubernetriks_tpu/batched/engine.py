@@ -3951,9 +3951,11 @@ class BatchedSimulation:
         # cycle_passes_most the passes of the cluster that took most: never
         # more than the launches of a K-shaped decision kernel, which runs
         # once a pass in which ANY cluster has work (that count is the
-        # batch's and no cluster's, so the state does not hold it). Also
-        # left on this engine's tracer handle, so telemetry_report() carries
-        # them.
+        # batch's and no cluster's, so the state does not hold it).
+        # cycle_deep / cycle_compacted: the cycles deeper than one pass, and
+        # those of them the megakernel's second launch drained
+        # (step._launch_by_depth). Also left on this engine's tracer handle,
+        # so telemetry_report() carries them.
         cycle = {
             "cycle_passes": int(np.asarray(m.cycle_passes).sum()),
             "cycle_passes_most": int(np.asarray(m.cycle_passes).max(initial=0)),
@@ -3962,6 +3964,8 @@ class BatchedSimulation:
             "cycle_decisions": int(np.asarray(m.scheduling_decisions).sum()),
             "cycle_deepest": int(np.asarray(m.cycle_deepest).max(initial=0)),
             "cycle_overruns": int(np.asarray(m.cycle_overruns).sum()),
+            "cycle_deep": int(np.asarray(m.cycle_deep).sum()),
+            "cycle_compacted": int(np.asarray(m.cycle_compacted).sum()),
         }
         counters.update(cycle)
         self.tracer.counters.update(cycle)

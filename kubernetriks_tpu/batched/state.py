@@ -248,6 +248,14 @@ class MetricArrays(NamedTuple):
     cycle_late_decisions: jnp.ndarray  # int32
     cycle_deepest: jnp.ndarray  # int32
     cycle_overruns: jnp.ndarray  # int32
+    # The cycles in which the cluster's queue was deeper than one pass, and
+    # those of them the megakernel's second launch drained, the cluster
+    # brought into a lane tile of the batch's deep ones
+    # (step._launch_by_depth; the rest ran in the one launch: one tile, more
+    # clusters deep at once than a tile holds, or a move that would not have
+    # paid: step._lanes_to_move). No scalar counterpart.
+    cycle_deep: jnp.ndarray  # int32
+    cycle_compacted: jnp.ndarray  # int32
     # The reschedule order's two counters (step._stable_queue_rank; no scalar
     # counterpart): the windows in which the cluster had a pod of a removed
     # node to rank, and those of them in which it had more than the
@@ -733,6 +741,8 @@ def init_state(
         cycle_late_decisions=jnp.zeros((C,), jnp.int32),
         cycle_deepest=jnp.zeros((C,), jnp.int32),
         cycle_overruns=jnp.zeros((C,), jnp.int32),
+        cycle_deep=jnp.zeros((C,), jnp.int32),
+        cycle_compacted=jnp.zeros((C,), jnp.int32),
         resched_rank_windows=jnp.zeros((C,), jnp.int32),
         resched_rank_sorted=jnp.zeros((C,), jnp.int32),
         queue_time=EstArrays.zeros((C,)),
@@ -936,6 +946,8 @@ AXIS_SIGNATURES = {
     "cycle_late_decisions": "C",
     "cycle_deepest": "C",
     "cycle_overruns": "C",
+    "cycle_deep": "C",
+    "cycle_compacted": "C",
     "resched_rank_windows": "C",
     "resched_rank_sorted": "C",
 }

@@ -141,6 +141,12 @@ def _rel_seconds(t: TPair, base_win: jnp.ndarray, interval) -> jnp.ndarray:
 # the sort, as anything larger does (PERF.md section 6, PR 44).
 RANK_COMPACT_SLOTS = 64
 
+# What bringing the deep clusters together and putting their rows back costs
+# the scheduling cycle (_lanes_to_move), in steps of a lane tile, a lane tile
+# of the batch: read on the chip (PERF.md section 6, PR 45: 0.36 ms a window
+# of ten tiles at 2 us a step, 0.9-1.3 ms at 4 us: 16-32).
+CYCLE_COMPACT_PAYS = 32
+
 
 def _rank_compacted(keys, mask, n, pos, R: int) -> jnp.ndarray:
     """_stable_queue_rank's ranks where no cluster masks more than R rows
@@ -1495,7 +1501,10 @@ class CycleTotals(NamedTuple):
     late: jnp.ndarray  # int32
 
 
-def fold_cycle_totals(metrics, tot: CycleTotals, decided, pod_sched_time, consts: StepConstants, pass_size: int):
+def fold_cycle_totals(
+    metrics, tot: CycleTotals, decided, pod_sched_time, consts: StepConstants, pass_size: int,
+    compacted=None,
+):
     """One cycle's totals into the (C,) metric accumulators (reference
     counters/estimators: scheduler.rs:322-329): ONCE a cycle, so that the
     sums do not depend on how the cycle was cut into passes. algo_latency
@@ -1504,9 +1513,11 @@ def fold_cycle_totals(metrics, tot: CycleTotals, decided, pod_sched_time, consts
     eligible at its start. The
     drain counters (MetricArrays.cycle_*) count, a cluster: the passes of
     pass_size its cycle took, the cycles in which it had a pod to decide,
-    the assignments after its first pass, its deepest cycle, and the cycles
+    the assignments after its first pass, its deepest cycle, the cycles
     whose simulated duration reached the interval (docs/PARITY.md: counted,
-    not modelled)."""
+    not modelled), its cycles deeper than one pass, and those of them that
+    `compacted` (C,) bool marks: drained by the megakernel's second launch
+    (_launch_by_depth; None where the formulation has none)."""
     n = tot.assigned
     nf = n.astype(jnp.float32)
     has = n > 0
@@ -1537,6 +1548,10 @@ def fold_cycle_totals(metrics, tot: CycleTotals, decided, pod_sched_time, consts
         cycle_late_decisions=metrics.cycle_late_decisions + tot.late,
         cycle_deepest=jnp.maximum(metrics.cycle_deepest, decided),
         cycle_overruns=metrics.cycle_overruns + overrun.astype(jnp.int32),
+        cycle_deep=metrics.cycle_deep + (decided > pass_size).astype(jnp.int32),
+        cycle_compacted=metrics.cycle_compacted
+        if compacted is None
+        else metrics.cycle_compacted + compacted.astype(jnp.int32),
     )
 
 
@@ -1893,6 +1908,150 @@ def commit_cycle(
     )
 
 
+def _lanes_to_move(n_eligible, K: int, R: int):
+    """The clusters a second launch drains (_launch_by_depth), (C,) bool:
+    those deeper than one pass, where they fit one tile of R lanes and where
+    moving them pays. A tile runs as many steps as its deepest lane holds
+    pods, so the single launch runs the sum of the tiles' deepest lanes, and
+    the two launches the sum over the lanes that stayed plus the deepest of
+    all: the move is made where that saves more steps than the selection and
+    the put-back cost (CYCLE_COMPACT_PAYS a tile of the batch). A burst in
+    every tile pays many times over; one deep cluster alone never does (its
+    tile's steps only move to the second launch), nor do a few that pass K
+    by little (a rack's re-queued pods on top of a cycle's arrivals)."""
+    C = n_eligible.shape[0]
+    tiles = -(-C // R)
+
+    def steps(n):
+        return jnp.pad(n, (0, tiles * R - C)).reshape(tiles, R).max(axis=1).sum()
+
+    deep = n_eligible > K
+    saved = steps(n_eligible) - steps(jnp.where(deep, 0, n_eligible)) - n_eligible.max()
+    fits = deep.sum(dtype=jnp.int32) <= R
+    return deep & fits & (saved > CYCLE_COMPACT_PAYS * tiles)
+
+
+def _take_lanes(x, index):
+    """The clusters `index` (R,) names out of x (..., C), cluster axis last;
+    zeros in a slot whose index lies past the axis."""
+    return jnp.take(x, index, axis=-1, mode="fill", fill_value=0)
+
+
+def _put_lanes(full, part, slot, moved):
+    """_take_lanes undone: `full` (..., C) with the clusters `moved` (C,)
+    marks replaced by their slots `slot` (C,) of `part` (..., R); every other
+    cluster keeps its own."""
+    return jnp.where(moved, jnp.take(part, slot, axis=-1, mode="clip"), full)
+
+
+def _launch_by_depth(launch, nodes, eligible, pod_planes, pod_time, spread, n_eligible, K, lane_major):
+    """The megakernel's launch, its lanes chosen by depth. A grid program of
+    the kernel holds one tile of clusters (ops/scheduler_kernel._LANE) and
+    loops to its deepest lane's queue, every lane computed at every step: a
+    backlog's few deep clusters (a burst of a thousand pods among queues of
+    twenty), spread over the batch, hold every tile to a thousand steps. So
+    where moving them pays (_lanes_to_move, of `n_eligible` (C,) and the pass
+    size K) the deep ones sit a first launch out, all their rows ineligible,
+    which passes their rows through and leaves each tile the depth of the
+    lanes that stayed; a second launch of the same wrapper drains them,
+    brought together into one tile, from position 0 as a single launch does;
+    and their rows replace the first launch's. The kernel's lanes never read
+    one another (selection, placement, commit and the estimator fold are a
+    lane's own; the trip count and the live row tiles only decide what is
+    swept), so the state cannot tell which clusters shared a tile: every
+    output is bit for bit a single launch's (tests/test_cycle_compact.py).
+
+    The tile is a width, never a limit: if more clusters are deep than it
+    holds, the one launch is the whole cycle, as it is where none is deep.
+    One tile of clusters has nothing to choose and traces the single launch
+    alone. Each chip of a mesh takes its own branch: no collective.
+
+    Where the `cond` sits is what a window with no deep cluster pays, and
+    was settled on the compiled text and the chip (PERF.md section 6,
+    PR 45): round the kernel's OWN operands and results, the planes padded
+    to whole lane tiles with the cluster axis last, which the launch
+    materialises whatever this function does. Both arms launch on them (the
+    wrapper's pads and slices then move nothing) and hand back the padded
+    results, so no plane is passed through an arm, none goes from the branch
+    into the loop's carry without the slice that follows, and whatever the
+    compiler fused into the pads before and the slices after stays fused.
+
+    nodes: (alive, alloc_cpu, alloc_ram); pod_planes: the eight (C, P)
+    planes after `eligible` in the wrapper's order; pod_time (C,);
+    spread: the wrapper's six operands or None. Returns (the wrapper's
+    outputs, moved (C,) bool: the clusters a second launch drained)."""
+    from kubernetriks_tpu.ops.scheduler_kernel import _LANE as R
+
+    C = eligible.shape[0]
+    if -(-C // R) == 1:
+        return launch(nodes, eligible, pod_planes, pod_time, spread), jnp.zeros((C,), jnp.bool_)
+
+    moved = _lanes_to_move(n_eligible, K, R)
+    lanes = -(-C // R) * R
+    n_axis = 1 if lane_major else 0
+
+    def tiles(x, axis=0, fill=0):
+        # Cluster axis last, padded to whole lane tiles with the wrapper's
+        # own fill; a mask as the words the kernel reads.
+        x = jnp.moveaxis(x.astype(jnp.int32) if x.dtype == jnp.bool_ else x, axis, -1)
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - C)], constant_values=fill)
+
+    def clusters_first(planes, axes):
+        return tuple(jnp.moveaxis(x, -1, axis) for x, axis in zip(planes, axes))
+
+    # The queue key's offset as the words the kernel compares (the wrapper
+    # makes them of a float32 plane, and of words nothing: inside an arm that
+    # cast would be a pass over the plane of its own).
+    qwin, qoff, *others = pod_planes
+    pod_planes = (qwin, jax.lax.bitcast_convert_type(qoff, jnp.int32), *others)
+    in_axes = (n_axis,) * 3 + (0,) * (2 + len(pod_planes))
+    spread_axes = (n_axis, 0, 0, 0, 0, 0)
+    # The pads are the wrapper's own, made a step early: the same device phase.
+    with jax.named_scope("kernel_io"):
+        operands = tuple(
+            tiles(x, axis)
+            for x, axis in zip((*nodes, eligible, *pod_planes, pod_time), in_axes)
+        )
+        spread_tiles = spread and tuple(
+            tiles(x, axis, fill)
+            for x, axis, fill in zip(spread, spread_axes, (-1, 0, 0, 0, -1, 0))
+        )
+
+    def launch_on(operands, spread_tiles):
+        """The wrapper on planes that hold the cluster axis last; its outputs
+        the same way."""
+        ops = clusters_first(operands, in_axes)
+        outputs = launch(
+            ops[:3], ops[3], ops[4:-1], ops[-1],
+            spread_tiles and clusters_first(spread_tiles, spread_axes),
+        )
+        out_axes = (n_axis, n_axis) + (0,) * (len(outputs) - 2)
+        return tuple(jnp.moveaxis(x, axis, -1) for x, axis in zip(outputs, out_axes))
+
+    def split():
+        moves = jnp.pad(moved, (0, lanes - C))
+        first = launch_on(
+            operands[:3] + (jnp.where(moves, 0, operands[3]),) + operands[4:], spread_tiles
+        )
+        # Slot r of the second launch holds the r-th moved cluster: its lane
+        # is the number of lanes with at most r moved ones up to and with
+        # them, the batch's width (past the axis) for a slot nobody takes.
+        count = jnp.cumsum(moves, dtype=jnp.int32)
+        index = (count[None, :] <= jnp.arange(R, dtype=jnp.int32)[:, None]).sum(
+            axis=1, dtype=jnp.int32
+        )
+        second = launch_on(
+            tuple(_take_lanes(x, index) for x in operands),
+            spread_tiles and tuple(_take_lanes(x, index) for x in spread_tiles),
+        )
+        return tuple(_put_lanes(a, b, count - 1, moves) for a, b in zip(first, second))
+
+    outputs = jax.lax.cond(moved.any(), split, lambda: launch_on(operands, spread_tiles))
+    out_axes = (n_axis, n_axis) + (0,) * (len(outputs) - 2)
+    with jax.named_scope("kernel_io"):
+        return clusters_first((x[..., :C] for x in outputs), out_axes), moved
+
+
 class _CyclePasses(NamedTuple):
     """What the passes of one cycle write, the carry of its loop: the
     allocatables, the four pod planes of the commit (start / park: second
@@ -2009,7 +2168,7 @@ def _run_scheduling_cycle(
     )
     # The cycle decides every one of them, whatever the formulation.
     n_eligible = eligible.sum(axis=1, dtype=jnp.int32)
-    sweep = None
+    sweep = compacted = None
 
     if use_pallas and use_pallas_select and use_megakernel:
         # MEGAKERNEL path: queue selection (iterated 3-key argmin), the
@@ -2022,18 +2181,36 @@ def _run_scheduling_cycle(
             fused_select_cycle_commit,
         )
 
-        pod_sched_time_k = jnp.broadcast_to(pod_sched_time[:, None], (C, K))
-
         interval = jnp.float32(consts.scheduling_interval)
         waited_p = (
             W[:, None] - pods.initial_attempt_ts.win
         ).astype(jnp.float32) * interval - pods.initial_attempt_ts.off
-        (alloc_cpu, alloc_ram, phase, node, start_tmp, park_tmp, qstats, *placed) = (
-            fused_select_cycle_commit(
-                alive,
-                state.nodes.alloc_cpu,
-                state.nodes.alloc_ram,
+
+        def launch(nodes, eligible, pod_planes, pod_time, spread):
+            # The wrapper reads column 0 of its last K-shaped operand alone.
+            time_k = jnp.broadcast_to(pod_time[:, None], (pod_time.shape[0], K))
+            return fused_select_cycle_commit(
+                *nodes,
                 eligible,
+                *pod_planes,
+                time_k,
+                time_k,
+                time_k,
+                k_pods=K,
+                interpret=pallas_interpret,
+                nodes_lane_major=lane_major,
+                profile=profile,
+                spread=spread,
+            )
+
+        (
+            (alloc_cpu, alloc_ram, phase, node, start_tmp, park_tmp, qstats, *placed),
+            compacted,
+        ) = _launch_by_depth(
+            launch,
+            (alive, state.nodes.alloc_cpu, state.nodes.alloc_ram),
+            eligible,
+            (
                 pods.queue_ts.win,
                 pods.queue_ts.off,
                 pods.queue_seq,
@@ -2042,15 +2219,12 @@ def _run_scheduling_cycle(
                 waited_p,
                 pods.phase,
                 pods.node,
-                pod_sched_time_k,
-                pod_sched_time_k,
-                pod_sched_time_k,
-                k_pods=K,
-                interpret=pallas_interpret,
-                nodes_lane_major=lane_major,
-                profile=profile,
-                spread=None if sp is None else spread_nodes(counts0) + spread_pods,
-            )
+            ),
+            pod_sched_time,
+            None if sp is None else spread_nodes(counts0) + spread_pods,
+            n_eligible,
+            K,
+            lane_major,
         )
         start_tmp = start_tmp + jnp.float32(consts.delta_bind_start)
         totals = CycleTotals(
@@ -2313,7 +2487,9 @@ def _run_scheduling_cycle(
         )
         spread_out = None if sp is None else acc.spread[1:]
 
-    metrics = fold_cycle_totals(state.metrics, totals, n_eligible, pod_sched_time, consts, K)
+    metrics = fold_cycle_totals(
+        state.metrics, totals, n_eligible, pod_sched_time, consts, K, compacted
+    )
     new_state = commit_scattered_tail(
         state, pods, last_flush_win, W, consts, alloc_cpu, alloc_ram,
         metrics, phase, node, start_tmp, park_tmp,

@@ -29,9 +29,10 @@ from tests.sharded_builds import mesh_of
 END = 700.0
 PASS = 8
 FORMULATIONS = ["scan", "candidate", "select", "megakernel"]
-# Leaves that count passes of the pass size: the two that depend on it by
-# definition (MetricArrays.cycle_passes, .cycle_late_decisions).
-PASS_COUNTERS = ("cycle_passes", "cycle_late_decisions")
+# Leaves that count by the pass size: the four that depend on it by
+# definition (MetricArrays.cycle_passes, .cycle_late_decisions, and
+# .cycle_deep / .cycle_compacted, the cycles deeper than one pass).
+PASS_COUNTERS = ("cycle_passes", "cycle_late_decisions", "cycle_deep", "cycle_compacted")
 
 
 def burst_events(seed, n_nodes=12, bursts=(24, 56, 80), rate=0.4, node_cpu=16000, labelled=False):
