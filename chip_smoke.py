@@ -8,39 +8,49 @@ accelerator with its kernels compiled.
                                           # proves nothing about the chip
 
 One process, the entry points a user calls (engine build + step_until_time,
-ScenarioFleet submit/pump/poll, cli.main), random traces from fixed seeds.
-Nothing is caught: any failure is a traceback and a non-zero exit. Without
---cpu-plumbing the run refuses any platform but "tpu" before it builds
-anything. Each leg prints one JSON line, then a summary line; the last
-stdout line is the result the chip check reads, exactly
-{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}} with
-the device as JAX reports it. Wall seconds are set-up facts (compile
+ScenarioFleet submit/pump/poll, cli.main). Nothing is caught: any failure is
+a traceback and a non-zero exit. Without --cpu-plumbing the run refuses any
+platform but "tpu" before it builds anything. Each leg prints one JSON line,
+then a summary line; the last stdout line is the result the chip check reads,
+exactly {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}
+with the device as JAX reports it. Wall seconds are set-up facts (compile
 included), never speeds.
 
+The legs stand on the benchmark's inputs, read-only: a leg names a cell of
+BENCHMARK.json, its deployment and load are that cell's data files
+(benchmark/configs/*.json, benchmark/traffic/*.json) through
+benchmark/deployment.py and benchmark/traffic_gen.py, and --cpu-plumbing
+lays the cell's rehearsal file (benchmark/rehearsal/*.json) over them, so
+the smoke compiles the cells' own shapes. One seeded stream (cluster 0's) is
+replicated over the batch. Where a leg needs a shape no cell has, it
+overrides a number of the files and says which.
+
 Legs:
-- pure: the bench.run_shape scenario at the north-star per-chip share
-  (1250 clusters x 1000 nodes, BASELINE.json) and at 1024 x 256 — warm-up
-  plus one 200 sim-s chunk — against a use_pallas=False lax.scan engine on
-  the same inputs (batched.state.compare_states). Two more shapes sit on the
-  other sides of the engine's VMEM fit gates, so every scheduling
-  formulation is compiled by Mosaic: a 4000 s trace (pod axis too wide for
-  the megakernel: select + commit kernels) and one 1313-node cluster (the
-  reference's Alibaba cluster; lane tile mostly padding: candidate kernel).
-- composed: bench._composed_inputs (HPA burst, CA up and down, sliding pod
-  window) with every tristate at its accelerator default, against an
-  all-off reference build (scan kernels, ladder, host slides). Statics never
-  change semantics; this is where that is checked on the chip.
-- served: a lane-async ScenarioFleet of one full lane tile, heterogeneous
-  horizons, every query answered with a result. fleet.pump turns a failed
-  dispatch (a Mosaic compile error included) into per-query errors and
-  carries on, so an error outcome is raised here.
+- pure: `sched1k.montecarlo` (1250 clusters x 1000 nodes, the north-star
+  per-chip share) and the same load on 1024 x 256 nodes, warm-up plus one
+  200 sim-s chunk, against a use_pallas=False lax.scan engine on the same
+  inputs (batched.state.compare_states). Two more shapes sit on the other
+  sides of the engine's VMEM fit gates, so every scheduling formulation is
+  compiled by Mosaic: arrivals over 4000 s (pod axis too wide for the
+  megakernel: select + commit kernels) and one cluster of as many nodes as
+  `alibaba1313` has machines (lane tile mostly padding: candidate kernel).
+- composed: `autoscaled.stream` (HPA burst, CA up and down, sliding pod
+  window) with every tristate at its accelerator default, against the
+  program's plain formulation (scan kernels, ladder, host slides). Statics
+  never change semantics; this is where that is checked on the chip.
+- served: `autoscaled.whatif`, a lane-async ScenarioFleet of one full lane
+  tile, the mix's catalogue and horizons, every query answered with a
+  result. fleet.pump turns a failed dispatch (a Mosaic compile error
+  included) into per-query errors and carries on, so an error outcome is
+  raised here.
 - cli: cli.main --backend batched on the bundled data/*.yaml traces.
-- faults: the pure scenario on 1000 identical nodes with the chaos engine's
-  node channel on (per-node chains and one failure group a rack of 50, each
-  cluster its own schedule, sampled inside the build), so that the event
-  kernel applies crashes and recoveries and a dead node's pods run again,
-  against the lax.scan engine on its scatter path. `--only faults` runs it
-  alone.
+- faults: `sched1k-faults.montecarlo`'s nodes, racks and failure clocks
+  with the chaos engine's node channel on (per-node chains and one failure
+  group a rack, each cluster its own schedule, sampled inside the build,
+  where the cell hands its schedule over already sampled), so that the
+  event kernel applies crashes and recoveries and a dead node's pods run
+  again, against the lax.scan engine on its scatter path. `--only faults`
+  runs it alone.
 """
 
 from __future__ import annotations
@@ -53,78 +63,104 @@ import os
 import sys
 import tempfile
 import time
+from typing import NamedTuple
 
 import numpy as np
 
-# The accelerator defaults of the engine's tristates, spelled out for
-# --cpu-plumbing: on a CPU backend they all resolve off, and the run would
-# compare the reference with itself.
-ACCELERATOR_STATICS = dict(
-    donate=True,
-    fuse_slide=True,
-    superspan=True,
-    stream=True,
-    lane_major=True,
-    window_razor=True,
-    reclaim=True,
-)
-ALL_OFF = dict.fromkeys(ACCELERATOR_STATICS, False)
+CHECKOUT = os.path.dirname(os.path.abspath(__file__))
+if CHECKOUT not in sys.path:
+    sys.path.insert(0, CHECKOUT)
 
-CHIP_SHAPES = dict(
-    # (clusters per device, nodes, trace seconds, formulation the fit gates
-    # must pick); the first is what the mesh run shards.
+# Every stream of the smoke is drawn from this seed (traffic_gen.derive_seed);
+# the what-if mix fixes its base workload's seed itself.
+SEED = 3
+
+# Per leg: the cell, the overrides of its files' numbers (none: the cell as
+# it runs) and the formulation the fit gates must pick. The first pure shape
+# is what the mesh run shards.
+CHIP_LEGS = dict(
     pure=[
-        (1250, 1000, 1000.0, "megakernel"),
-        (1024, 256, 1000.0, "megakernel"),
-        (1024, 256, 4000.0, "select"),
-        (1, 1313, 1000.0, "candidate"),
+        ({}, "megakernel"),
+        ({"clusters": 1024, "nodes": 256}, "megakernel"),
+        ({"clusters": 1024, "nodes": 256, "horizon_s": 4000.0}, "select"),
+        ({"clusters": 1, "nodes": "alibaba1313"}, "candidate"),
     ],
     pure_run=dict(warm_until=190.0, chunk=200.0),
-    composed=dict(
-        n_clusters=256, n_nodes=32, pod_window=512, t_end=1200.0,
-        cycle="megakernel",
-        inputs=dict(
-            rate_per_second=1.5, horizon=1000.0, max_group_pods=64,
-            burst=(300.0, 300.0, 400.0),
-        ),
-    ),
-    served=dict(
-        # One full lane tile is what engages the dense kernel set.
-        n_lanes=128, n_queries=256, query_horizon=450.0,
-        max_pods_per_cycle=256, cycle="megakernel",
-        setup=dict(
-            n_nodes=64, rate_per_second=3.0, horizon=400.0,
-            max_group_pods=32, burst=(100.0, 150.0, 250.0),
-        ),
-    ),
+    composed="megakernel",
+    served=dict(n_queries=256, cycle="megakernel"),
     cli_clusters=1024,
-    # (clusters, nodes, rack size, trace seconds, traces to a node's and a
-    # rack's failure, formulations): a fifth of the north-star batch keeps
-    # the two builds' per-cluster trace compiles short.
-    faults=(256, 1000, 50, 1000.0, 24.0, "megakernel", "kernel"),
+    # A fifth of the north-star batch keeps the two builds' per-cluster
+    # trace compiles short.
+    faults=({"clusters": 256}, "megakernel", "kernel"),
 )
-PLUMBING_SHAPES = dict(
-    pure=[(4, 8, 200.0, "candidate")],
+PLUMBING_LEGS = dict(
+    pure=[({}, "candidate")],
     pure_run=dict(warm_until=90.0, chunk=100.0),
-    composed=dict(
-        n_clusters=4, n_nodes=8, pod_window=128, t_end=700.0,
-        cycle="candidate",
-        inputs=dict(
-            rate_per_second=0.375, horizon=500.0, max_group_pods=16,
-            burst=(100.0, 150.0, 250.0),
-        ),
-    ),
-    served=dict(
-        n_lanes=4, n_queries=8, query_horizon=450.0, max_pods_per_cycle=64,
-        cycle="candidate",
-        setup=dict(
-            n_nodes=8, rate_per_second=0.375, horizon=400.0,
-            max_group_pods=16, burst=(100.0, 150.0, 250.0),
-        ),
-    ),
+    composed="candidate",
+    served=dict(n_queries=8, cycle="candidate"),
     cli_clusters=2,
-    faults=(4, 8, 4, 400.0, 2.0, "candidate", "scatter"),
+    faults=({}, "candidate", "scatter"),
 )
+
+
+class Leg(NamedTuple):
+    """A cell's files as a leg runs them."""
+
+    cell: object  # benchmark.harness.Cell
+    config: object  # the deployment as the program's SimulationConfig
+    cluster_events: list
+    workload: list
+    width: int  # clusters a chip, or lanes
+    rehearsed: bool
+
+    def engine_kwargs(self) -> dict:
+        return {**self.cell.config["engine"], **self.cell.traffic.get("engine", {})}
+
+    def forced(self, lane_async: bool = False) -> dict:
+        """What the engine under test is built with beside the cell's
+        settings. On the chip nothing: every choice is the engine's own
+        default; rehearsed, the same program family is forced on and its
+        kernels interpreted, as a cell's rehearsal does."""
+        from benchmark import program
+
+        return program.rehearsal_kwargs(lane_async) if self.rehearsed else {}
+
+    def build(self, n_clusters: int, **kw):
+        from kubernetriks_tpu.batched.engine import build_batched_from_traces
+
+        return build_batched_from_traces(
+            self.config, self.cluster_events, self.workload, n_clusters=n_clusters,
+            **{**self.engine_kwargs(), **kw},
+        )
+
+
+def leg_inputs(cell_name: str, rehearsed: bool, clusters=None, nodes=None, horizon_s=None) -> Leg:
+    """`rehearsed` lays the cell's rehearsal file over its files; `clusters`,
+    `nodes` (a count, or the configuration whose machines to count) and
+    `horizon_s` replace that number of the files."""
+    from benchmark import deployment, program, traffic_gen
+    from benchmark.harness import Cell, load_json
+
+    rehearsal = os.path.join(CHECKOUT, "benchmark", "rehearsal", cell_name + ".json")
+    cell = Cell(
+        load_json(os.path.join(CHECKOUT, "BENCHMARK.json")), cell_name,
+        load_json(rehearsal) if rehearsed else None,
+    )
+    dep, traffic = cell.config["deployment"], cell.traffic
+    if isinstance(nodes, str):
+        nodes = load_json(os.path.join(CHECKOUT, "benchmark", "configs", nodes + ".json"))[
+            "deployment"]["machines"]
+    if nodes is not None:
+        dep["nodes"] = nodes
+    if horizon_s is not None:
+        traffic["plain"]["horizon_s"] = horizon_s
+    api = program.program_api()
+    config = api.SimulationConfig.from_yaml(deployment.config_yaml(cell.config_name, dep))
+    seed = int(traffic.get("base_workload_seed", SEED))
+    cluster_events = traffic_gen.to_events(traffic_gen.cluster_records(dep), api)
+    workload = traffic_gen.to_events(traffic_gen.workload_records(traffic, seed, 0), api)
+    width = clusters or traffic.get("clusters_per_chip") or traffic["lanes"]
+    return Leg(cell, config, cluster_events, workload, int(width), rehearsed)
 
 
 def emit(leg: str, t0: float, **fields) -> dict:
@@ -148,22 +184,14 @@ def assert_sharded(sim, mesh) -> None:
     assert len(shapes) == mesh.size and len(set(shapes)) == 1, shapes
 
 
-def pure_leg(n_clusters, n_nodes, horizon, cycle, run, forced, mesh) -> dict:
-    import bench
-    from kubernetriks_tpu.batched.engine import build_batched_from_traces
+def pure_leg(overrides, cycle, run, rehearsed, devices, mesh) -> dict:
     from kubernetriks_tpu.batched.state import compare_states
 
     t0 = time.perf_counter()
-    config, cluster_events, workload = bench._shape_inputs(n_nodes, horizon)
-
-    def build(**kw):
-        return build_batched_from_traces(
-            config, cluster_events, workload, n_clusters=n_clusters,
-            max_pods_per_cycle=64, **kw,
-        )
-
-    sim = build(mesh=mesh, **forced)
-    ref = build(use_pallas=False)
+    leg = leg_inputs("sched1k.montecarlo", rehearsed, **overrides)
+    n_clusters = leg.width * devices
+    sim = leg.build(n_clusters, mesh=mesh, **leg.forced())
+    ref = leg.build(n_clusters, use_pallas=False)
     for s in (sim, ref):
         s.step_until_time(run["warm_until"])
         s.step_until_time(run["warm_until"] + run["chunk"])
@@ -175,50 +203,44 @@ def pure_leg(n_clusters, n_nodes, horizon, cycle, run, forced, mesh) -> dict:
     mismatches = compare_states(ref.state, sim.state)
     assert not mismatches, mismatches
     return emit(
-        "pure", t0, clusters=n_clusters, nodes=n_nodes, pods=sim.n_pods,
+        "pure", t0, clusters=n_clusters, nodes=sim.n_nodes, pods=sim.n_pods,
         formulation=formulation, decisions=decisions, reference="lax.scan",
         mismatches=0,
     )
 
 
-def faults_leg(shape, run, forced) -> dict:
+def faults_leg(shape, run, rehearsed) -> dict:
     """Node crashes and recoveries through the dense kernel set against the
     lax.scan engine: identical nodes, so a recovered node has to sit where
     its name sorts, and the final state holds every rescheduled pod."""
-    import bench
-    from kubernetriks_tpu.batched.engine import build_batched_from_traces
     from kubernetriks_tpu.batched.state import compare_states
     from kubernetriks_tpu.config import FailureGroupConfig, FaultInjectionConfig, NodeFaultConfig
 
-    n_clusters, n_nodes, rack, horizon, traces_to_failure, cycle, events = shape
+    overrides, cycle, events = shape
     t0 = time.perf_counter()
-    config, cluster_events, workload = bench._shape_inputs(n_nodes, horizon)
-    mttf = horizon * traces_to_failure
-    config.fault_injection = FaultInjectionConfig(
+    leg = leg_inputs("sched1k-faults.montecarlo", rehearsed, **overrides)
+    cell, n_clusters = leg.cell, leg.width
+    clocks, rack = cell.config["fault_injection"], cell.config["racks"]["nodes_per_rack"]
+    names = [event.node.metadata.name for _, event in leg.cluster_events]
+    leg.config.fault_injection = FaultInjectionConfig(
         enabled=True,
-        horizon=horizon,
-        node=NodeFaultConfig(mttf=mttf, mttr=120.0),
+        horizon=float(clocks["no_fault_after_s"]),
+        node=NodeFaultConfig(mttf=clocks["node"]["mttf"], mttr=clocks["node"]["mttr"]),
         failure_groups=[
             FailureGroupConfig(
-                members=[f"gen_node_{i}" for i in range(lo, lo + rack)],
-                mttf=mttf,
-                mttr=240.0,
+                members=names[lo : lo + rack],
+                mttf=clocks["failure_groups"]["mttf"],
+                mttr=clocks["failure_groups"]["mttr"],
             )
-            for lo in range(0, n_nodes, rack)
+            for lo in range(0, len(names), rack)
         ],
     )
-
-    def build(**kw):
-        return build_batched_from_traces(
-            config, cluster_events, workload, n_clusters=n_clusters,
-            max_pods_per_cycle=64, **kw,
-        )
-
-    sim = build(**forced)
-    ref = build(use_pallas=False)
+    n_nodes = len(names)
+    sim = leg.build(n_clusters, **leg.forced())
+    ref = leg.build(n_clusters, use_pallas=False)
     for s in (sim, ref):
         s.step_until_time(run["warm_until"])
-        s.step_until_time(horizon + 200.0)
+        s.step_until_time(float(cell.traffic["job_end_s"]))
     formulation = sim.kernel_formulation()
     assert formulation["cycle"] == cycle and formulation["events"] == events, formulation
     assert sim.n_nodes == n_nodes, (sim.n_nodes, "a recovery took a fresh slot")
@@ -237,34 +259,25 @@ def faults_leg(shape, run, forced) -> dict:
     )
 
 
-def composed_leg(shape, forced, mesh) -> dict:
-    import bench
-    from kubernetriks_tpu.batched.engine import build_batched_from_traces
+def composed_leg(cycle, rehearsed, devices, mesh) -> dict:
+    from benchmark import program
     from kubernetriks_tpu.batched.state import compare_states
 
     t0 = time.perf_counter()
-    config, cluster_events, workload = bench._composed_inputs(
-        shape["n_nodes"], **shape["inputs"]
-    )
-
-    def build(**kw):
-        return build_batched_from_traces(
-            config, cluster_events, workload, n_clusters=shape["n_clusters"],
-            max_pods_per_cycle=64, pod_window=shape["pod_window"], **kw,
-        )
-
-    sim = build(mesh=mesh, **forced)
+    leg = leg_inputs("autoscaled.stream", rehearsed)
+    n_clusters = leg.width * devices
+    sim = leg.build(n_clusters, mesh=mesh, **leg.forced())
     # Reclaim compacts CA slots and adds state leaves, so its on/off pair is
     # comparable by trajectory only (tests/test_reclaim.py); the reference
     # keeps the value under test and turns everything else off.
-    ref = build(use_pallas=False, **{**ALL_OFF, "reclaim": sim.reclaim})
-    for t in np.linspace(0.0, shape["t_end"], 5)[1:]:
+    ref = leg.build(n_clusters, **program.plain_formulation_kwargs(sim.reclaim))
+    for t in np.linspace(0.0, float(leg.cell.traffic["job_end_s"]), 5)[1:]:
         sim.step_until_time(float(t))
         ref.step_until_time(float(t))
     formulation = sim.kernel_formulation()
     stats = dict(sim.dispatch_stats)
     counters = sim.metrics_summary()["counters"]
-    assert formulation["cycle"] == shape["cycle"], formulation
+    assert formulation["cycle"] == cycle, formulation
     assert sim.lane_major, "lane-major node state is off"
     assert stats["superspans"] > 0, stats
     assert stats["window_chunks"] == 0, stats
@@ -284,8 +297,8 @@ def composed_leg(shape, forced, mesh) -> dict:
     sim.close()
     ref.close()
     return emit(
-        "composed", t0, clusters=shape["n_clusters"], nodes=sim.n_nodes,
-        pod_window=shape["pod_window"], formulation=formulation,
+        "composed", t0, clusters=n_clusters, nodes=sim.n_nodes,
+        pod_window=sim.pod_window, formulation=formulation,
         lane_major=sim.lane_major, reclaim=sim.reclaim,
         superspans=stats["superspans"],
         feeder_slabs=stats["feeder_slabs_produced"], pod_base=sim._pod_base,
@@ -297,25 +310,33 @@ def composed_leg(shape, forced, mesh) -> dict:
     )
 
 
-def served_leg(shape, forced) -> dict:
-    import bench
-    from kubernetriks_tpu.batched.fleet import ScenarioFleet
+def served_leg(shape, rehearsed) -> dict:
+    from benchmark import traffic_gen
+    from kubernetriks_tpu.batched.fleet import Scenario, ScenarioFleet
     from kubernetriks_tpu.recompile import RecompileSentinel
 
     t0 = time.perf_counter()
-    _, config, cluster_events, workload = bench._sweep_setup(**shape["setup"])
-    scenarios, _ = bench._sweep_scenarios(shape["n_queries"])
-    mix = bench.OPEN_LOOP_HORIZON_MIX
-    horizons = [
-        shape["query_horizon"] * mix[i % len(mix)]
-        for i in range(shape["n_queries"])
+    leg = leg_inputs("autoscaled.whatif", rehearsed)
+    cell, n_lanes = leg.cell, leg.width
+    queries = cell.traffic["queries"]
+    catalogue = [
+        Scenario(**overrides)
+        for overrides in traffic_gen.scenario_catalogue(int(queries["catalogue_size"]))
     ]
+    # As many queries of the mix's open loop as the leg asks for; the smoke
+    # offers them all at once, so their due times are dropped.
+    stream = traffic_gen.query_stream(
+        cell.traffic, SEED, shape["n_queries"] / float(queries["rate_per_second"])
+    )
+    assert len(stream) == shape["n_queries"], len(stream)
+    scenarios = [catalogue[index] for _, index, _ in stream]
+    horizons = [horizon for _, _, horizon in stream]
     sentinel = RecompileSentinel("raise").install()
     fleet = ScenarioFleet(
-        config, cluster_events, workload, n_lanes=shape["n_lanes"],
-        horizon=shape["query_horizon"],
-        max_pods_per_cycle=shape["max_pods_per_cycle"], lane_async=True,
-        span_windows=4, **forced,
+        leg.config, leg.cluster_events, leg.workload, n_lanes=n_lanes,
+        horizon=float(cell.traffic["base_workload_s"]), lane_async=True,
+        span_windows=int(cell.traffic["span_windows"]),
+        **{**leg.engine_kwargs(), **leg.forced(lane_async=True)},
     )
     formulation = fleet.engine.kernel_formulation()
     assert formulation["cycle"] == shape["cycle"], formulation
@@ -348,7 +369,7 @@ def served_leg(shape, forced) -> dict:
     assert decisions > 0
     fleet.close()
     return emit(
-        "served", t0, lanes=shape["n_lanes"], nodes=fleet.engine.n_nodes,
+        "served", t0, lanes=n_lanes, nodes=fleet.engine.n_nodes,
         formulation=formulation, queries=len(outcomes), query_errors=0,
         decisions=int(decisions), warmup_compiles=warmup_compiles,
         recompiles_after_warmup=0,
@@ -453,14 +474,8 @@ def main(argv=None) -> int:
         flush=True,
     )
 
-    shapes = CHIP_SHAPES if on_chip else PLUMBING_SHAPES
-    # On the chip every choice is the engine's own default; off it the same
-    # program family is forced on and its kernels interpreted.
-    forced = (
-        {}
-        if on_chip
-        else dict(use_pallas=True, pallas_interpret=True, **ACCELERATOR_STATICS)
-    )
+    shapes = CHIP_LEGS if on_chip else PLUMBING_LEGS
+    rehearsed = not on_chip
     mesh = None
     if args.devices > 1:
         from jax.sharding import Mesh
@@ -471,35 +486,25 @@ def main(argv=None) -> int:
         return args.only in (None, leg)
 
     legs = []
-    for per_device, n_nodes, horizon, cycle in (
+    for overrides, cycle in (
         shapes["pure"] if mesh is None else shapes["pure"][:1]
     ) if wanted("pure") else ():
         legs.append(
             pure_leg(
-                per_device * args.devices, n_nodes, horizon, cycle,
-                shapes["pure_run"], forced, mesh,
+                overrides, cycle, shapes["pure_run"], rehearsed, args.devices, mesh,
             )
         )
-    composed = dict(shapes["composed"])
-    composed["n_clusters"] *= args.devices
-    # use_pallas=True as in bench.run_composed: the flagship is the kernel
-    # path by definition, not by the auto gate.
     if wanted("composed"):
-        legs.append(composed_leg(composed, {**forced, "use_pallas": True}, mesh))
+        legs.append(
+            composed_leg(shapes["composed"], rehearsed, args.devices, mesh)
+        )
     if mesh is None:
-        # A lane-async engine turns the global-clock statics off by itself
-        # and refuses them when asked for by name.
-        per_lane = {
-            k: v
-            for k, v in forced.items()
-            if k not in ("superspan", "stream", "fuse_slide")
-        }
         if wanted("served"):
-            legs.append(served_leg(shapes["served"], per_lane))
+            legs.append(served_leg(shapes["served"], rehearsed))
         if wanted("cli"):
             legs.append(cli_leg(shapes["cli_clusters"]))
         if wanted("faults"):
-            legs.append(faults_leg(shapes["faults"], shapes["pure_run"], forced))
+            legs.append(faults_leg(shapes["faults"], shapes["pure_run"], rehearsed))
 
     print(
         json.dumps(

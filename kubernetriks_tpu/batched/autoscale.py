@@ -1185,7 +1185,6 @@ def _ca_scale_down(
     interval,
     use_pallas: bool = False,
     pallas_interpret: bool = False,
-    descatter: bool = True,
     sd_order=None,
     node_rank=None,
 ):
@@ -1205,25 +1204,20 @@ def _ca_scale_down(
     whose finish has not still counts as running and still needs
     re-placement, whether the node has finished it or not.
 
-    descatter (KTPU_CA_DESCATTER, r9 — round 3 of the de-scatter
-    campaign): the correction segment-sum and the node-grouping sort
-    share a node key, so ONE combined 2-key sort serves the grouping (the
-    secondary key puts each node's storage-RUNNING pods first in its
-    segment, so the grouping tables slice the same prefix the r5
-    single-key sort produced) and ONE (C, P, N) compare of the key
-    against the node axis gives every per-node total (_segment_sums:
-    untouched rows carry 0, so the full-segment integer sums equal the old
-    touched-only sums exactly). Bit-exact by integer-additivity +
-    stable-sort prefix order; descatter=False keeps the r5 two-sort path
-    for A/B.
+    The correction segment-sum and the node grouping share a node key, so
+    ONE 2-key sort serves the grouping (the secondary key puts each node's
+    storage-RUNNING pods first in its segment, so the grouping tables slice
+    a prefix of it) and ONE (C, P, N) compare of the key against the node
+    axis gives every per-node total (_segment_sums: untouched rows carry 0,
+    and integer sums are exact in any order).
 
     What this pass costs on the chip was measured op by op in PR 39's
     traced runs and read in PR 40 (PERF.md sections 5 and 6): of the
     parent's 12.6 ms `ca_pass` a stream window, 9.1 were XLA gathers (paid
     per index: the six boundary reads of the cumulative sums 3.2, the
     per-candidate pod table 2.1, every (C, S) row look-up 0.3-0.5) and 1.4
-    the sorts; the (C, P, N) rank-count pair that r9 and DESIGN.md called
-    "the ~2.5 ms residue" was 0.16. Hence the dense forms (_rows_at,
+    the sorts; the (C, P, N) rank-count pair was 0.16. Gathers are paid per
+    index on this chip: hence the dense forms (_rows_at,
     _rows_put, _segment_sums) at every look-up whose row is a node or slot
     axis: no XLA gather or scatter is left in the kernel path of this
     pass, in ca_pass or in ca_reclaim_pass. The XLA while_loop walk below
@@ -1276,81 +1270,32 @@ def _ca_scale_down(
     d_ram = jnp.where(vis_gone, pods.req_ram, 0)
     touched = vis_gone
     on_any = holds & ~vis_gone
-    zero_col = jnp.zeros((C, 1), jnp.int32)
-    if descatter:
-        # Combined de-scatter (see docstring): one 2-key sort — node slot,
-        # then storage-running FIRST — serves the correction AND the
-        # grouping. on_any and touched pods are holders, which have
-        # node >= 0, so node_c == the old sorts' key values.
-        in_seg = touched | on_any
-        key_node = jnp.where(in_seg, node_c, jnp.int32(N))
-        key2 = jnp.where(on_any, 0, 1).astype(jnp.int32)
-        # Only the grouping rides the sort: the request values, which the
-        # per-candidate tables slice by segment below.
-        _, _, rc_sorted, rr_sorted = jax.lax.sort(
-            (key_node, key2, pods.req_cpu, pods.req_ram),
-            dimension=1,
-            num_keys=2,
-            is_stable=True,
-        )
-        # The per-node totals directly (_segment_sums), off the UNSORTED
-        # key: where node n's segment starts in the sorted order, the freed
-        # cpu / ram, and the storage-running count. They replace three
-        # cumulative sums over the sorted values read at both segment
-        # boundaries: six (C, N) gathers, 3.2 ms a stream window against
-        # 0.16 for the compare-and-sum pair this extends (PERF.md section
-        # 6, PR 40). Node n's segment LEADS with its on_any pods in slot
-        # order (stable sort, key2), so the grouping tables slice the same
-        # prefix the old single-key sort produced.
-        seg_start, (freed_cpu, freed_ram, seg_count) = _segment_sums(
-            key_node, N, d_cpu, d_ram, on_any
-        )
-        alloc_cpu_v = alloc_cpu_v + freed_cpu
-        alloc_ram_v = alloc_ram_v + freed_ram
-    else:
-        # r5 two-sort path, kept for A/B (KTPU_CA_DESCATTER=0).
-        tkey = jnp.where(touched, node_c, jnp.int32(N))
-        tkey_s, dc_s, dr_s = jax.lax.sort(
-            (tkey, d_cpu, d_ram), dimension=1, num_keys=1, is_stable=True
-        )
-        ecs_c = jnp.concatenate([zero_col, jnp.cumsum(dc_s, axis=1)], axis=1)
-        ecs_r = jnp.concatenate([zero_col, jnp.cumsum(dr_s, axis=1)], axis=1)
-        tstart = (tkey_s[:, :, None] < col_n[:, None, :]).sum(
-            axis=1, dtype=jnp.int32
-        )
-        tend = tstart + (tkey_s[:, :, None] == col_n[:, None, :]).sum(
-            axis=1, dtype=jnp.int32
-        )
-        alloc_cpu_v = alloc_cpu_v + ecs_c[rows, tend] - ecs_c[rows, tstart]
-        alloc_ram_v = alloc_ram_v + ecs_r[rows, tend] - ecs_r[rows, tstart]
-
-        # Group storage-visible running pods by assigned node ONCE (a
-        # per-slot (C, P) mask + argsort made the pass O(S * P log P) per
-        # window — fatal at trace scale); each node's pods become a
-        # contiguous segment of `porder`. The pod requests ride the sort
-        # as VALUES, so the per-candidate tables below slice sorted arrays
-        # instead of gathering through pod_order (one fewer (C, S*K_sd)
-        # gather). Segment starts and counts come from rank-count
-        # reductions over the sorted keys — a fused (C, P, N) compare+sum
-        # — instead of the serial per-index scatter-min/scatter-add pair
-        # (~2.3 ms/window at the composed shape).
-        key_node = jnp.where(on_any, pods.node, jnp.int32(N))
-        key_sorted, rc_sorted, rr_sorted = jax.lax.sort(
-            (key_node, pods.req_cpu, pods.req_ram),
-            dimension=1,
-            num_keys=1,
-            is_stable=True,
-        )
-        # seg_start[n] = #pods on nodes < n = first sorted position of node
-        # n's segment (for a pod-less node this lands on the next segment
-        # instead of the old scatter-min's P sentinel — all consumers mask
-        # by seg_count == 0 first, so the value is never read).
-        seg_start = (key_sorted[:, :, None] < col_n[:, None, :]).sum(
-            axis=1, dtype=jnp.int32
-        )
-        seg_count = (key_sorted[:, :, None] == col_n[:, None, :]).sum(
-            axis=1, dtype=jnp.int32
-        )
+    # One 2-key sort (node slot, then storage-running FIRST) serves the
+    # grouping. on_any and touched pods are holders, which have node >= 0.
+    in_seg = touched | on_any
+    key_node = jnp.where(in_seg, node_c, jnp.int32(N))
+    key2 = jnp.where(on_any, 0, 1).astype(jnp.int32)
+    # Only the grouping rides the sort: the request values, which the
+    # per-candidate tables slice by segment below.
+    _, _, rc_sorted, rr_sorted = jax.lax.sort(
+        (key_node, key2, pods.req_cpu, pods.req_ram),
+        dimension=1,
+        num_keys=2,
+        is_stable=True,
+    )
+    # The per-node totals directly (_segment_sums), off the UNSORTED key:
+    # where node n's segment starts in the sorted order, the freed cpu /
+    # ram, and the storage-running count. Cumulative sums over the sorted
+    # values read at both segment boundaries would be six (C, N) gathers,
+    # 3.2 ms a stream window against 0.16 for the compare-and-sum pair
+    # (PERF.md section 6, PR 40). Node n's segment LEADS with its on_any
+    # pods in slot order (stable sort, key2): the prefix the grouping
+    # tables slice.
+    seg_start, (freed_cpu, freed_ram, seg_count) = _segment_sums(
+        key_node, N, d_cpu, d_ram, on_any
+    )
+    alloc_cpu_v = alloc_cpu_v + freed_cpu
+    alloc_ram_v = alloc_ram_v + freed_ram
     col_k = jnp.arange(K_sd, dtype=jnp.int32)[None, :]
 
     # Candidate walk order and liveness, shared by both paths: CA slots in
@@ -1545,7 +1490,6 @@ def ca_pass(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     nodes_lane_major: bool = False,
-    descatter: bool = True,
     reclaim: bool = False,
 ) -> Tuple[ClusterBatchState, AutoscaleState]:
     """One masked cluster-autoscaler cycle (scalar equivalent:
@@ -1556,11 +1500,10 @@ def ca_pass(
     the CA glue is (C, N)-oriented (name-order look-ups, grouping sorts), so
     it normalizes to row-major VIEWS here — a handful of transposes per
     window against the ~20 kernel-boundary transposes the mode removes in
-    the base window (docs/DESIGN.md §"window-cost anatomy"). The pass only
+    the base window (docs/DESIGN.md §"Lane-major hot state"). The pass only
     WRITES the pending pairs (create_time / remove_time — row-major
-    always), so nothing converts back. descatter (KTPU_CA_DESCATTER):
-    see _ca_scale_down, whose docstring also says what the pass costs on
-    the chip and why no look-up in it is an XLA gather or scatter (the
+    always), so nothing converts back. _ca_scale_down's docstring says
+    what the pass costs on the chip and why no look-up in it is an XLA gather or scatter (the
     slot-table touches and the allocation stamp below included: _rows_put,
     _rows_at; with one node group the stamp's three reads are broadcasts).
 
@@ -1655,7 +1598,6 @@ def ca_pass(
             phase_v, alloc_cpu_v, alloc_ram_v, snap, interval,
             use_pallas=use_pallas,
             pallas_interpret=pallas_interpret,
-            descatter=descatter,
             sd_order=sd_order,
             node_rank=node_rank,
         )
@@ -1730,9 +1672,6 @@ def ca_reclaim_pass(
     state: ClusterBatchState,
     auto: AutoscaleState,
     st: AutoscaleStatics,
-    W: jnp.ndarray,
-    consts: StepConstants,
-    period: int = 1,
     nodes_lane_major: bool = False,
 ) -> Tuple[ClusterBatchState, AutoscaleState]:
     """CA slot reclaim: return fully-RETIRED reserve slots to their group
@@ -1762,12 +1701,6 @@ def ca_reclaim_pass(
     permutation is the identity and the pass is a bit-exact no-op; the
     whole body sits behind a cond on the cheap (C, S) dead-slot predicate
     so quiet windows pay only the predicate.
-
-    period > 1 additionally gates compaction to windows with
-    (W + 1) % period == 0 (batching the (C, P) safety sweep); retired
-    slots then wait, which is semantically invisible but can starve a
-    scale-up the immediate cadence would have served — the default is the
-    immediate cadence.
     """
     if auto is None or auto.ca_alloc is None:
         return state, auto
@@ -1802,8 +1735,6 @@ def ca_reclaim_pass(
         )
     )
     do = dead.any()
-    if period > 1:
-        do = do & ((W + jnp.int32(1)) % jnp.int32(period) == 0).all()
 
     iota_s = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (C, S))
     grp = jnp.where(st.ca_slot_group >= 0, st.ca_slot_group, Gn)
@@ -1948,8 +1879,7 @@ def hpa_pass_donated(
 
 
 _CA_PASS_STATICS = (
-    "K_up", "K_sd", "use_pallas", "pallas_interpret", "shards", "descatter",
-    "reclaim",
+    "K_up", "K_sd", "use_pallas", "pallas_interpret", "shards", "reclaim",
 )
 
 
@@ -1966,12 +1896,11 @@ def ca_pass_donated(
     use_pallas: bool = False,
     pallas_interpret: bool = False,
     shards=None,
-    descatter: bool = True,
     reclaim: bool = False,
 ) -> ClusterBatchState:
     state2, auto2 = ca_pass(
         state, state.auto, st, W, consts, K_up, K_sd, pre=pre,
         use_pallas=use_pallas, pallas_interpret=pallas_interpret,
-        descatter=descatter, reclaim=reclaim,
+        reclaim=reclaim,
     )
     return state2._replace(auto=auto2)

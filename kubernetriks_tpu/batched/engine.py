@@ -193,9 +193,7 @@ def _fused_chunk_slide_impl(
     name_ranks=None,
     lane_major: bool = False,
     window_razor: bool = True,
-    ca_descatter: bool = True,
     reclaim: bool = False,
-    reclaim_period: int = 1,
     profile=None,
     W: int = 0,
 ):
@@ -238,9 +236,7 @@ def _fused_chunk_slide_impl(
             name_ranks=name_ranks,
             lane_major=lane_major,
             window_razor=window_razor,
-            ca_descatter=ca_descatter,
             reclaim=reclaim,
-            reclaim_period=reclaim_period,
             profile=profile,
             shard_axis=shard_axis,
         )
@@ -834,9 +830,7 @@ class BatchedSimulation:
         watchdog: Optional[bool] = None,
         lane_major: Optional[bool] = None,
         window_razor: Optional[bool] = None,
-        ca_descatter: Optional[bool] = None,
         reclaim: Optional[bool] = None,
-        reclaim_period: Optional[int] = None,
         scheduler_profile=None,
         scenario=None,
         lane_async: bool = False,
@@ -1002,8 +996,6 @@ class BatchedSimulation:
         self._stream_segment = st.stream_segment
         self.lane_major = st.lane_major
         self.window_razor = st.window_razor
-        self.ca_descatter = st.ca_descatter
-        self.reclaim_period = st.reclaim_period
         # None: reclaim was left to the platform default, so the build may
         # turn it off where it cannot hold and a restore may follow the
         # checkpoint; asked for by kwarg or flag, both raise instead.
@@ -1064,9 +1056,9 @@ class BatchedSimulation:
         # restage).
         # ladder_fallbacks counts step_until_time calls where a
         # superspan-selected engine dispatched the ladder instead
-        # (instrumented modes, gauge collection, fast-forward) — the
-        # silent-fallback observable bench.py --smoke asserts on, now
-        # visible in every telemetry_report.
+        # (instrumented modes, gauge collection, fast-forward): the
+        # silent-fallback observable, in every telemetry_report
+        # (tests/test_telemetry.py::test_ladder_fallback_counter).
         # feeder_slabs_produced mirrors the streaming feeder's production
         # counter (0 on non-streaming engines): stage_refills counts slabs
         # the dispatch loop INSTALLED, feeder_slabs_produced counts slabs
@@ -1987,9 +1979,7 @@ class BatchedSimulation:
             name_ranks=self._fault_name_ranks,
             lane_major=self.lane_major,
             window_razor=self.window_razor,
-            ca_descatter=self.ca_descatter,
             reclaim=self.reclaim,
-            reclaim_period=self.reclaim_period,
             profile=self._cycle_profile,
         )
 
@@ -2294,8 +2284,8 @@ class BatchedSimulation:
         CA period/quota, autoscaler-chain delays, per-lane HPA enables)
         and the pod-fault seed vector are all traced (C,)-shaped DATA, so
         this is one host->device put a leaf (some two dozen) and never a
-        recompile (bench.py --sweep asserts exactly that via
-        fleet.jit_cache_sizes). The wave boundary's transport; a
+        recompile (tests/test_fleet.py::test_wave_reset_and_zero_recompiles
+        holds it by fleet.jit_cache_sizes). The wave boundary's transport; a
         lane-async pump round admits through admit_lanes, one put.
         Only legal on an engine built with scenario= (the fleet build):
         a scenario-less build may carry a different consts pytree
@@ -2725,7 +2715,7 @@ class BatchedSimulation:
         if self._superspan:
             # Superspan selected but not dispatchable (instrumented mode,
             # gauges, fast-forward, debug-finite): count the silent ladder
-            # fallback so it is observable outside bench.py --smoke.
+            # fallback so that it is observable.
             self.dispatch_stats["ladder_fallbacks"] += 1
         while self.next_window_idx <= target:
             sub = min(target, self._pod_capacity_window())
@@ -4390,10 +4380,8 @@ class BatchedSimulation:
         # the windows the device ring recorded. Dispatch is asynchronous,
         # so execution time surfaces in the waits — dispatch + wait
         # together bound compile + device time per window (on a warm jit
-        # cache the wait share IS the device-execution proxy). THE
-        # observable the lane-major / razor / de-scatter A/Bs are sized
-        # with — bench.py --smoke --trace asserts it, so a layout
-        # regression moves a number CPU CI sees.
+        # cache the wait share IS the device-execution proxy). Held by
+        # tests/test_telemetry.py::test_report_is_the_engines_own_when_engines_interleave.
         from kubernetriks_tpu.telemetry.tracer import PHASE_NAMES as _PN
 
         window_phases = (

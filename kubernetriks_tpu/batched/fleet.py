@@ -91,7 +91,8 @@ finish in-flight, fail queued with `ShutdownError`). With
 `KTPU_HOST_CHAOS` unset and no injector armed, every new path is gated
 on `self._chaos is None` / empty fault ledgers — the layer is provably
 free when quiet (per-query A/B bit-identity + dispatch_stats equality,
-pinned in tests/test_fleet_async.py and bench.py --host-chaos).
+pinned in tests/test_fleet_async.py and
+tests/test_fleet_faults.py::test_quiet_robustness_layer_is_free).
 """
 
 from __future__ import annotations
@@ -396,8 +397,9 @@ _ROW_COUNTERS = _RESULT_COUNTERS + ("hpa_reserve_clamped", "ca_reserve_starved")
 def jit_cache_sizes() -> Dict[str, int]:
     """Compiled-variant counts of every jit entry the dispatch loop can
     touch — the zero-recompile observable: capture after warm-up, compare
-    after the query stream (bench.py --sweep asserts equality; a scenario
-    update that silently became a jit-static shows up here loudly)."""
+    after the query stream (tests/test_fleet.py::test_wave_reset_and_zero_recompiles
+    asserts equality; a scenario update that silently became a jit-static
+    shows up here loudly)."""
     from kubernetriks_tpu.batched import autoscale, engine, state, step
 
     entries = {
@@ -1453,14 +1455,14 @@ class ScenarioFleet:
             obs.note_lane_states(self.lane_states())
 
     def arm_host_chaos(self, chaos: Optional[HostChaos]) -> None:
-        """Attach (or detach, with None) the host-fault injector —
-        bench.py arms chaos AFTER warm-up so the zero-post-warm-up
-        recompile assert runs under injection."""
+        """Attach (or detach, with None) the host-fault injector. Armed
+        AFTER warm-up, a zero-recompile check runs under injection
+        (tests/test_fleet_faults.py::test_fault_path_moves_no_jit_cache_count)."""
         self._chaos = chaos
 
     def fault_report(self) -> Dict:
-        """Availability + fault-domain counters (the bench's host-chaos
-        record): completed/failed split by kind, quarantine activity,
+        """Availability + fault-domain counters: completed/failed split by
+        kind, quarantine activity,
         current lane states, injector event counts."""
         completed_ok = sum(
             1 for r in self.results.values() if getattr(r, "ok", True)

@@ -22,7 +22,7 @@ import dataclasses
 import numbers
 from typing import Dict, Mapping, NamedTuple, Optional
 
-from kubernetriks_tpu.flags import flag_bool, flag_int, flag_tristate
+from kubernetriks_tpu.flags import flag_int, flag_tristate
 
 # Platform default of the accelerator tristates: on for accelerator
 # backends, where the win is device-side (buffer reuse, fewer dispatches
@@ -34,10 +34,10 @@ ACCELERATOR = "accelerator"
 
 class Static(NamedTuple):
     name: str  # the BatchedSimulation build kwarg
-    # Legal values by kind. "tristate" / "bool": True or False (a
-    # tristate's flag may be unset, a bool's has a default in flags.py).
-    # "int": an integer >= 0, raised to at least 1. "optional_int": None
-    # (the engine's own geometry rule decides) or an integer >= 0.
+    # Legal values by kind. "tristate": True or False (its flag may be
+    # unset). "int": an integer >= 0, raised to at least 1.
+    # "optional_int": None (the engine's own geometry rule decides) or an
+    # integer >= 0.
     kind: str
     flag: Optional[str]  # its flag in flags.py, or None
     default: object  # a value, or ACCELERATOR
@@ -84,24 +84,16 @@ TABLE = (
     Static("window_razor", "tristate", "KTPU_WINDOW_RAZOR", ACCELERATOR, None,
            "Gate the per-window resolution soup behind a cheap due-ness "
            "predicate, so empty windows skip it."),
-    Static("ca_descatter", "bool", "KTPU_CA_DESCATTER", True, None,
-           "CA scale-down shares one 2-key sort between the allocatable "
-           "correction and the node grouping. Same program size either "
-           "way, so on everywhere."),
     Static("reclaim", "tristate", "KTPU_RECLAIM", ACCELERATOR, None,
            "Periodic in-trace compaction returns retired CA reserve slots. "
            "The record holds the REQUEST: the engine turns it off (or "
            "raises, if it was asked for by name or flag) where the trace's "
            "node-name classes interleave or there is no CA."),
-    Static("reclaim_period", "int", "KTPU_RECLAIM_PERIOD", 1, "reclaim",
-           "Reclaim compaction cadence in windows."),
 )
 
 NAMES = tuple(row.name for row in TABLE)
-_ON_OFF = ("tristate", "bool")
 _READ_FLAG = {
     "tristate": flag_tristate,
-    "bool": flag_bool,
     "int": flag_int,
     "optional_int": flag_int,
 }
@@ -123,7 +115,7 @@ EngineStatics = dataclasses.make_dataclass(
 
 
 def _normalise(row: Static, value: object) -> object:
-    if row.kind in _ON_OFF:
+    if row.kind == "tristate":
         if not isinstance(value, bool):
             raise ValueError(
                 f"engine static {row.name!r}: {value!r} is not True or False"
@@ -156,7 +148,7 @@ def resolve(kwargs: Mapping[str, object], backend: str) -> "EngineStatics":
             value = backend != "cpu" if row.default is ACCELERATOR else row.default
         if value is not None:
             value = _normalise(row, value)
-        rides = row.requires if row.kind in _ON_OFF and value else None
+        rides = row.requires if row.kind == "tristate" and value else None
         if rides and not values[rides]:
             if level == "kwarg":
                 raise ValueError(

@@ -2704,9 +2704,7 @@ def _window_body(
     name_ranks=None,
     lane_major: bool = False,
     window_razor: bool = True,
-    ca_descatter: bool = True,
     reclaim: bool = False,
-    reclaim_period: int = 1,
     profile=None,
     freeze_lanes: bool = True,
     shard_axis=None,
@@ -2750,9 +2748,6 @@ def _window_body(
             state,
             state.auto,
             autoscale_statics,
-            W,
-            consts,
-            period=reclaim_period,
             nodes_lane_major=lane_major,
         )
         state = state._replace(auto=auto_r)
@@ -2868,7 +2863,6 @@ def _window_body(
             use_pallas=use_pallas,
             pallas_interpret=pallas_interpret,
             nodes_lane_major=lane_major,
-            descatter=ca_descatter,
             reclaim=reclaim,
         )
         state = state._replace(auto=auto)
@@ -2961,19 +2955,15 @@ _STEP_STATICS = (
     # chaos.FaultParams (hashable NamedTuple of scalars) or None; None
     # compiles programs textually identical to the pre-chaos build.
     "fault_params",
-    # PR 9 perf statics, each with a flags.py A/B switch: lane-major hot
-    # node state (KTPU_LANE_MAJOR), the empty-window resolution razor
-    # (KTPU_WINDOW_RAZOR), and the CA scale-down combined segment-sum
-    # (KTPU_CA_DESCATTER). All three are bit-exact either way.
+    # Perf statics, each with a flags.py A/B switch: lane-major hot node
+    # state (KTPU_LANE_MAJOR) and the empty-window resolution razor
+    # (KTPU_WINDOW_RAZOR). Both are bit-exact either way.
     "lane_major",
     "window_razor",
-    "ca_descatter",
     # CA slot reclaim (KTPU_RECLAIM, r14): the compaction pass at the top
     # of the window body + allocation-index name orders in the CA passes.
-    # Off compiles the pre-reclaim programs (the A/B bit-identity gate);
-    # reclaim_period > 1 batches the compaction's (C, P) safety sweep.
+    # Off compiles the pre-reclaim programs (the A/B bit-identity gate).
     "reclaim",
-    "reclaim_period",
     # pipeline.CompiledProfile (hashable NamedTuple of plugin names +
     # weights) or None; the compiled scheduler profile whose filter/score
     # expressions the decision core runs. None compiles programs identical
@@ -3013,9 +3003,7 @@ def window_step(
     name_ranks=None,
     lane_major: bool = False,
     window_razor: bool = True,
-    ca_descatter: bool = True,
     reclaim: bool = False,
-    reclaim_period: int = 1,
     profile=None,
 ) -> ClusterBatchState:
     """Advance every cluster through scheduling-cycle window index W.
@@ -3045,9 +3033,7 @@ def window_step(
         name_ranks=name_ranks,
         lane_major=lane_major,
         window_razor=window_razor,
-        ca_descatter=ca_descatter,
         reclaim=reclaim,
-        reclaim_period=reclaim_period,
         profile=profile,
         shard_axis=shard_axis_of(shards),
     )
@@ -3239,9 +3225,7 @@ def _run_windows_skip_impl(
     name_ranks=None,
     lane_major: bool = False,
     window_razor: bool = True,
-    ca_descatter: bool = True,
     reclaim: bool = False,
-    reclaim_period: int = 1,
     profile=None,
 ):
     """run_windows with FAST-FORWARD over provably no-op windows: a dynamic
@@ -3287,9 +3271,7 @@ def _run_windows_skip_impl(
             name_ranks=name_ranks,
             lane_major=lane_major,
             window_razor=window_razor,
-            ca_descatter=ca_descatter,
             reclaim=reclaim,
-            reclaim_period=reclaim_period,
             profile=profile,
             shard_axis=shard_axis,
         )
@@ -3366,9 +3348,7 @@ def _run_windows_impl(
     name_ranks=None,
     lane_major: bool = False,
     window_razor: bool = True,
-    ca_descatter: bool = True,
     reclaim: bool = False,
-    reclaim_period: int = 1,
     profile=None,
     freeze_lanes: bool = True,
 ):
@@ -3403,9 +3383,7 @@ def _run_windows_impl(
             name_ranks=name_ranks,
             lane_major=lane_major,
             window_razor=window_razor,
-            ca_descatter=ca_descatter,
             reclaim=reclaim,
-            reclaim_period=reclaim_period,
             profile=profile,
             freeze_lanes=freeze_lanes,
             shard_axis=shard_axis_of(shards),
@@ -3583,9 +3561,7 @@ def _run_superspan_impl(
     name_ranks=None,
     lane_major: bool = False,
     window_razor: bool = True,
-    ca_descatter: bool = True,
     reclaim: bool = False,
-    reclaim_period: int = 1,
     profile=None,
     W: int = 0,
     K: int = 16,
@@ -3685,9 +3661,7 @@ def _run_superspan_impl(
                 name_ranks=name_ranks,
                 lane_major=lane_major,
                 window_razor=window_razor,
-                ca_descatter=ca_descatter,
                 reclaim=reclaim,
-                reclaim_period=reclaim_period,
                 profile=profile,
                 shard_axis=shard_axis,
             )

@@ -1,5 +1,5 @@
 """Placement of JAX's persistent compilation cache for the entry points
-(cli.py, bench.py, scripts/bench_mesh.py, chip_smoke.py).
+(cli.py, chip_smoke.py).
 
 The chip tool starts every call with no compiled code, and the path is
 part of the cache's key, so the directory must be fixed and placeable from

@@ -10,7 +10,7 @@ and a helper read of an unregistered name raises here at runtime.
 Why a registry: before PR 6, `"0"` / empty-string / unset truthiness was
 decided ad hoc at each read site (`env != "0"`, `== "1"`,
 `bool(os.environ.get(...))` — three different rules, one of which made
-`KUBERNETRIKS_FAST_TESTS=0` truthy). The registry gives every flag ONE
+a flag set to `0` truthy). The registry gives every flag ONE
 parser, one default, and one greppable declaration.
 
 Truthiness rule (flag_bool / flag_tristate): unset -> default (or None for
@@ -117,17 +117,6 @@ _FLAGS = [
         "measurement.",
     ),
     Flag(
-        "KTPU_CA_DESCATTER",
-        "bool",
-        True,
-        "CA scale-down de-scatter (round 3 of the campaign): the "
-        "finish-visibility allocatable correction and the node-grouping "
-        "sort share ONE combined 2-key (C, P) sort and one set of "
-        "segment-boundary reductions instead of two sorts + four "
-        "(C, P, N) rank-count passes. Integer segment sums — bit-exact. "
-        "0 selects the r5 two-sort path for A/B measurement.",
-    ),
-    Flag(
         "KTPU_RECLAIM",
         "tristate",
         None,
@@ -143,16 +132,6 @@ _FLAGS = [
         "accelerator backends, off on CPU hosts — tests and endurance "
         "runs opt in explicitly. Forced off (warning) when the trace's "
         "node-name classes interleave; an explicit 1 raises there.",
-    ),
-    Flag(
-        "KTPU_RECLAIM_PERIOD",
-        "int",
-        1,
-        "Reclaim compaction cadence in windows: 1 (default) compacts in "
-        "any window with a retired slot (a scale-up can then never "
-        "starve while reclaimable slots exist); larger values batch the "
-        "compaction's (C, P) retirement-safety sweep to every Nth "
-        "window, trading a transiently tighter reserve for less work.",
     ),
     Flag(
         "KTPU_ALIGN_PODS",
@@ -193,7 +172,7 @@ _FLAGS = [
         "str",
         None,
         "Named scheduler profile for batched engines that were not handed "
-        "an explicit profile (bench/CLI selection): a key of "
+        "an explicit profile (CLI selection): a key of "
         "core.scheduler.kube_scheduler.NAMED_PROFILE_SPECS ('default', "
         "'best_fit', 'balanced_packing'). Compiled into the scan and "
         "Pallas kernel paths at engine build (batched/pipeline.py); an "
@@ -209,10 +188,8 @@ _FLAGS = [
         "jax.log_compiles-based monitor that raises RecompileError "
         "naming the jit entry on any post-warm-up XLA compilation — the "
         "runtime cross-check of the fleet's compile-once guarantee (the "
-        "scenariotrace lint pass is the static half). Unset: armed only "
-        "by the bench.py --sweep/--endurance in-bench asserts; 1: "
-        "ScenarioFleet guards every post-warm-up wave; 0: forced off "
-        "everywhere, including the benches.",
+        "scenariotrace lint pass is the static half). 1: ScenarioFleet "
+        "guards every post-warm-up wave; unset or 0: it arms none.",
     ),
     Flag(
         "KTPU_TRACE",
@@ -221,17 +198,17 @@ _FLAGS = [
         "Flight recorder: the device-side per-window metrics ring carried "
         "in ClusterBatchState and the capacity observatory (the host-side "
         "span recorder is always on). Read out via engine.telemetry_report() / "
-        "write_chrome_trace(); bench.py --trace embeds the summary in the "
-        "BENCH JSON. Off by default (telemetry-on is bit-identical and "
+        "write_chrome_trace(); cli.py prints the report and writes the "
+        "trace. Off by default (telemetry-on is bit-identical and "
         "gated <3% overhead, but the ring costs device memory).",
     ),
     Flag(
         "KTPU_TRACE_PATH",
         "str",
         None,
-        "Output path stem for Chrome trace-event JSON written by "
-        "bench.py --trace (Perfetto-loadable). Unset: ktpu_trace under the "
-        "working directory.",
+        "Output path stem for the Chrome trace-event JSON cli.py writes "
+        "when the flight recorder is armed (Perfetto-loadable). Unset: "
+        "ktpu_trace under the working directory.",
     ),
     Flag(
         "KTPU_WATCHDOG",
@@ -247,44 +224,6 @@ _FLAGS = [
         "the ring's occupancy columns, so it rides telemetry; an explicit "
         "1 with telemetry off raises at engine build instead of silently "
         "watching nothing.",
-    ),
-    Flag(
-        "KTPU_METRICS_PATH",
-        "str",
-        None,
-        "Output path stem for the capacity observatory's time-series "
-        "export (telemetry/export.py): bench.py --trace appends drain "
-        "records to <stem>_<label>.jsonl (bounded, rotating) and writes "
-        "the final report as <stem>_<label>.prom (Prometheus textfile). "
-        "Unset: ktpu_metrics under the working directory.",
-    ),
-    Flag(
-        "KTPU_SWEEP_PATH",
-        "str",
-        None,
-        "Output path stem for bench.py --sweep's JSON record (scenario "
-        "fleet vs per-engine baseline, wave timings, recompile/cross-talk "
-        "verdicts): the sweep writes <stem>.json (CI uploads it next to "
-        "the trace artifacts). Unset: ktpu_sweep under the working "
-        "directory.",
-    ),
-    Flag(
-        "KTPU_SWEEP_LANES",
-        "int",
-        None,
-        "Cluster-lane count C of bench.py --sweep's resident scenario "
-        "fleet (batched/fleet.py): N scenarios pack into ceil(N/C) waves "
-        "over ONE compiled engine. Unset: the sweep shape default (16; "
-        "4 on --smoke).",
-    ),
-    Flag(
-        "KTPU_SWEEP_BASELINE",
-        "int",
-        None,
-        "How many independent per-scenario engines the --sweep baseline "
-        "actually builds and times (the rest of the N-engine baseline is "
-        "extrapolated from their mean and disclosed as such in the JSON). "
-        "Unset: 3.",
     ),
     Flag(
         "KTPU_LANE_SPAN",
@@ -311,7 +250,7 @@ _FLAGS = [
         "(seed=7,dispatch=0.04,feeder=0.05,stall=0.03,stall_ms=2.0); a "
         "'k=v,...' spec overrides them. Unset: injection OFF — the fleet "
         "runs the exact pre-chaos code path (per-query bit-identity and "
-        "dispatch_stats equality, gated in tests and bench).",
+        "dispatch_stats equality, tests/test_fleet_faults.py).",
     ),
     Flag(
         "KTPU_FLEET_QUEUE",
@@ -362,15 +301,6 @@ _FLAGS = [
         "str",
         "INFO",
         "CLI logging level (DEBUG/INFO/WARNING/ERROR).",
-    ),
-    Flag(
-        "KUBERNETRIKS_FAST_TESTS",
-        "bool",
-        False,
-        "DEPRECATED no-op since PR 6: the fast scales it used to opt into "
-        "are the tier-1 default, and the reference-scale runs live behind "
-        "`-m slow`. Registered so existing scripts that set it keep "
-        "passing the env-flag lint; nothing reads it.",
     ),
     Flag(
         "KUBERNETRIKS_ALIBABA_DIR",

@@ -1,6 +1,6 @@
 """CLI: `python -m kubernetriks_tpu.lint [paths...]`.
 
-Default scope is the repo's lintable surface: the package, bench.py,
+Default scope is the repo's lintable surface: the package,
 chip_smoke.py, tests/, scripts/ and experiments/ (the self-test fixtures under
 tests/lint_fixtures/ are excluded — they hold seeded violations on
 purpose; pass their paths explicitly to lint them, as tests/test_lint.py
@@ -32,7 +32,6 @@ from kubernetriks_tpu.lint import (
 
 DEFAULT_SCOPE = (
     "kubernetriks_tpu",
-    "bench.py",
     "chip_smoke.py",
     "tests",
     "scripts",

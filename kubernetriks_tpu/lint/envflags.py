@@ -2,7 +2,7 @@
 
 Before PR 6, `"0"` / empty / unset truthiness was decided ad hoc at each
 read site — three different parsing rules across engine.py/step.py/tests,
-one of which made `KUBERNETRIKS_FAST_TESTS=0` truthy. The central registry
+one of which made a flag set to `0` truthy. The central registry
 (`kubernetriks_tpu/flags.py`: name, type, default, doc, one truthiness
 parser) is the single owner; this pass enforces it:
 

@@ -3,7 +3,8 @@ compile-once guarantee (`KTPU_EXPLAIN_RECOMPILES`).
 
 The static half is the scenariotrace lint pass (a scenario leaf can
 never flow into program-shaping positions); the dynamic half is
-jit-cache-size equality asserted by `bench.py --sweep` / `--endurance`.
+jit-cache-size equality (`fleet.jit_cache_sizes`, tests/test_fleet.py,
+tests/test_fleet_async.py, tests/test_fleet_faults.py).
 Both tell you THAT something recompiled — neither names WHICH jit entry
 did. This module hooks `jax_log_compiles` (every XLA compilation logs
 "Finished XLA compilation of <entry> in ... sec" on the
@@ -12,7 +13,7 @@ did. This module hooks `jax_log_compiles` (every XLA compilation logs
 shape-drifting call or a scenario parameter that regressed to a
 jit-static is diagnosed in one line instead of a cache-count diff.
 
-Usage (the fleet and the benches wire this up):
+Usage (the fleet and chip_smoke.py's served leg wire this up):
 
     sent = RecompileSentinel().install()
     ...build + warm up...
@@ -26,10 +27,9 @@ or windowed, immune to neighboring engines compiling in between:
     with sent.expect_none("fleet wave 3"):
         ...one wave...
 
-`KTPU_EXPLAIN_RECOMPILES` (tristate): unset -> armed only where the code
-opts in explicitly (the --sweep/--endurance in-bench asserts); 1 ->
-`ScenarioFleet` arms a raising sentinel around every post-warm-up wave;
-0 -> forced off everywhere, including the benches.
+`KTPU_EXPLAIN_RECOMPILES` (tristate): 1 -> `ScenarioFleet` arms a raising
+sentinel around every post-warm-up wave; unset or 0 -> it arms none (code
+that installs a sentinel of its own, as chip_smoke.py does, is not asked).
 
 The log hook silences the two jax compile loggers' propagation while
 installed (their WARNING-level spam would otherwise hit stderr on every
@@ -228,9 +228,8 @@ class RecompileSentinel:
 
 
 def sentinel_mode() -> Optional[bool]:
-    """The KTPU_EXPLAIN_RECOMPILES tristate: None unset (benches arm
-    their own sentinels, the fleet does not), True -> armed raising,
-    False -> forced off everywhere."""
+    """The KTPU_EXPLAIN_RECOMPILES tristate: True -> the fleet arms a
+    raising sentinel; None (unset) or False -> it does not."""
     return flag_tristate("KTPU_EXPLAIN_RECOMPILES")
 
 

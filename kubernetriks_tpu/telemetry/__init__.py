@@ -41,8 +41,7 @@ And the query half (docs/DESIGN.md §14, PR 17):
 The span recorder is always on; the device ring and the observatory are
 enabled with `KTPU_TRACE=1` (or `BatchedSimulation(telemetry=True)`);
 `engine.telemetry_report()` / `engine.write_chrome_trace()` /
-`engine.drain_telemetry()` read it out, and `bench.py --trace` embeds
-the summary in the BENCH JSON.
+`engine.drain_telemetry()` read it out, and `cli.py --report` prints it.
 """
 
 from kubernetriks_tpu.telemetry.gauges import GaugeSeries

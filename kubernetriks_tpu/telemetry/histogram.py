@@ -18,8 +18,7 @@ with a fixed-size geometric histogram:
   of ``numpy.percentile(..., method="higher")`` over the bucketed
   counts and returns the upper boundary of the rank's bucket, so the
   result is within one :meth:`bucket_width` of the exact same-convention
-  percentile while both exist (pinned by tests/test_soak.py and the
-  in-bench assert in ``bench.py run_open_loop``).
+  percentile while both exist (pinned by tests/test_soak.py).
 
 Pure host code: no jax, no device reads, O(buckets) memory forever —
 safe under the hot-path pragma with zero sync waivers.

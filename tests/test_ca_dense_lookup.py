@@ -11,11 +11,15 @@ cumulative sums they replace, bit for bit.
 (c) `autoscale._segment_sums` against the sort-cumsum-boundary form, with an
     empty segment, a segment longer than K_sd, keys in no segment and sums
     that wrap;
-(d) `_ca_scale_down(descatter=True)` against the untouched `descatter=False`
-    path on a composed state with live CA nodes: the XLA walk and the
-    (interpreted) kernel, a K_sd that binds included.
+(d) `_ca_scale_down` against the two-sort path's verdict on a composed state
+    with live CA nodes, frozen as data when that path went (PR 46,
+    tests/data/ca_scale_down_two_sort_verdict.json): the XLA walk and the
+    (interpreted) kernel, a K_sd that binds included, at an instant at which
+    nothing may go and at one at which two nodes a cluster do.
 """
 
+import json
+import os
 import zlib
 
 import jax
@@ -176,7 +180,7 @@ def test_segment_sums_are_the_cumsum_differences(case):
         assert (np.asarray(sb)[:, 3] > 8).all()
 
 
-# --- (d) the whole pass against the path this PR did not write ---------------
+# --- (d) the whole pass against the two-sort path's frozen verdict ------------
 
 CA_YAML = """
 sim_name: ca_dense
@@ -201,8 +205,9 @@ cluster_autoscaler:
 
 @pytest.fixture(scope="module")
 def composed():
-    """Four clusters whose load opens CA nodes and then drains: stepped to
-    an instant at which CA nodes are alive and hold pods."""
+    """Four clusters whose load opens CA nodes and then drains: the state
+    at an instant at which CA nodes are alive and hold pods (160 s) and at
+    one at which the scale-down takes two a cluster (260 s)."""
     from kubernetriks_tpu.batched.engine import build_batched_from_traces
     from kubernetriks_tpu.config import SimulationConfig
     from kubernetriks_tpu.trace.generator import PoissonWorkloadTrace, UniformClusterTrace
@@ -217,38 +222,47 @@ def composed():
         config, cluster.convert_to_simulator_events(), workload.convert_to_simulator_events(),
         n_clusters=4, max_pods_per_cycle=16, use_pallas=False, fast_forward=False,
     )
-    sim.step_until_time(160.0)
-    yield sim
+    states = {}
+    for instant in (160.0, 260.0):
+        sim.step_until_time(instant)
+        states[f"{instant:g}"] = sim.state
+    yield sim, states
     sim.close()
 
 
-def _scale_down(sim, descatter, k_sd, use_pallas):
-    state, st = sim.state, sim.autoscale_statics
+def _scale_down(sim, state, k_sd, use_pallas):
+    st = sim.autoscale_statics
     n = state.pods.phase.shape[0]
     interval = jnp.float32(sim.consts.scheduling_interval)
     snap = t_add(state.auto.ca_next, st.ca_snap, interval)
     return autoscale._ca_scale_down(
         state, state.auto, st, jnp.ones((n,), bool), k_sd,
         state.pods.phase, state.nodes.alloc_cpu, state.nodes.alloc_ram, snap, interval,
-        use_pallas=use_pallas, pallas_interpret=use_pallas, descatter=descatter,
+        use_pallas=use_pallas, pallas_interpret=use_pallas,
     )
 
 
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["walk", "kernel"])
 @pytest.mark.parametrize("k_sd", [8, 1], ids=["k_sd_8", "k_sd_binds"])
-def test_scale_down_descatter_equals_the_two_sort_path(composed, k_sd, use_pallas):
-    state = composed.state
-    S = composed.autoscale_statics.ca_slots.shape[1]
+def test_scale_down_equals_the_two_sort_paths_frozen_verdict(composed, k_sd, use_pallas, request):
+    sim, states = composed
+    state = states["160"]
+    S = sim.autoscale_statics.ca_slots.shape[1]
     ca_alive = np.asarray(state.nodes.alive)[:, -S:]
     assert int(np.asarray(state.auto.ca_count).sum()) > 0 and ca_alive.any()
     on_ca = np.asarray(state.pods.node) >= state.nodes.alive.shape[1] - S
     running = np.asarray(state.pods.phase) == autoscale.PHASE_RUNNING
     per_node = np.bincount(np.asarray(state.pods.node)[on_ca & running], minlength=1)
     assert per_node.max() > 1, "no CA node holds more than one pod: k_sd = 1 would not bind"
-    new = _scale_down(composed, True, k_sd, use_pallas)
-    old = _scale_down(composed, False, k_sd, use_pallas)
-    for got, want in zip(new, old):
-        assert np.array_equal(got, want)
+    with open(os.path.join(os.path.dirname(__file__), "data", "ca_scale_down_two_sort_verdict.json")) as fh:
+        frozen = json.load(fh)["cases"][request.node.callspec.id]
+    for instant, want in frozen.items():
+        removed, per_group = _scale_down(sim, states[instant], k_sd, use_pallas)
+        assert removed.dtype == bool and per_group.dtype == jnp.int32
+        want_removed = np.array([[c == "1" for c in row] for row in want["removed"]])
+        assert np.array_equal(removed, want_removed), instant
+        assert np.array_equal(per_group, np.array(want["removed_per_group"], np.int32)), instant
+    assert np.array(frozen["260"]["removed_per_group"]).sum() > 0, "the frozen verdict removes nothing"
     if use_pallas:
         from kubernetriks_tpu.ops.autoscale_kernel import ca_down_kernel_fits
 

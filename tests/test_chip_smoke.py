@@ -1,13 +1,16 @@
 """chip_smoke.py off the chip: the default command refuses a CPU backend
 without printing a result, and the explicit --cpu-plumbing mode runs every
-leg at toy shapes (accelerator program family forced on, Pallas interpreted)
-and prints one pinned JSON line per leg, the chip check's result line last.
+leg at its cell's rehearsal shape (accelerator program family forced on,
+Pallas interpreted) and prints one pinned JSON line per leg, the chip check's
+result line last; a leg's deployment and load are its cell's data files.
 The chip run itself is the driver's and the builder's (`python chip_smoke.py`
 through the chip tool)."""
 
 import json
 import os
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -19,6 +22,61 @@ def test_default_command_refuses_cpu(capsys):
     captured = capsys.readouterr()
     assert captured.out == ""
     assert "needs a TPU" in captured.err
+
+
+def _data(kind, name):
+    with open(os.path.join(chip_smoke.CHECKOUT, "benchmark", kind, name + ".json")) as fh:
+        return json.load(fh)
+
+
+@pytest.mark.parametrize(
+    "cell, config, traffic",
+    [
+        ("sched1k.montecarlo", "sched1k", "montecarlo"),
+        ("autoscaled.stream", "autoscaled", "stream"),
+        ("autoscaled.whatif", "autoscaled", "whatif-steady"),
+        ("sched1k-faults.montecarlo", "sched1k-faults", "montecarlo-faults"),
+    ],
+)
+def test_a_legs_deployment_and_load_are_the_cells_files(cell, config, traffic):
+    """What a leg builds on the chip IS what the cell's data files say: node
+    count and shape, arrival rate and count, the batch's width, the HPA group
+    where the mix has one."""
+    dep, mix = _data("configs", config)["deployment"], _data("traffic", traffic)
+    _, sim_config, cluster_events, workload, width, _ = chip_smoke.leg_inputs(cell, rehearsed=False)
+    nodes = [event.node for _, event in cluster_events]
+    assert len(nodes) == dep["nodes"]
+    assert {n.status.capacity.cpu for n in nodes} == {dep["node_cpu_millicores"]}
+    assert {n.status.capacity.ram for n in nodes} == {dep["node_ram_gib"] * 1024**3}
+    assert nodes[7].metadata.name == "gen_node_0007"
+    assert width == mix.get("clusters_per_chip", mix.get("lanes"))
+    plain = mix["plain"]
+    pods = [event.pod for _, event in workload if hasattr(event, "pod")]
+    assert len(pods) == round(plain["rate_per_second"] * plain["horizon_s"])
+    assert {p.spec.resources.requests.cpu for p in pods} == {plain["cpu_millicores"]}
+    assert max(t for t, _ in workload) < plain["horizon_s"]
+    groups = [event for _, event in workload if hasattr(event, "pod_group")]
+    assert len(groups) == (1 if mix["pod_group"] else 0)
+    assert (sim_config.cluster_autoscaler is not None and sim_config.cluster_autoscaler.enabled) == bool(
+        dep["cluster_autoscaler"]
+    )
+    assert sim_config.scheduling_cycle_interval == dep["scheduling_cycle_interval_s"]
+
+
+def test_a_legs_overrides_replace_one_number_of_the_files():
+    """The shapes no cell has: the nodes come from another configuration's
+    machine count, the arrivals run longer, the batch is narrower; everything
+    else stays the files'."""
+    machines = _data("configs", "alibaba1313")["deployment"]["machines"]
+    _, _, cluster_events, workload, width, _ = chip_smoke.leg_inputs(
+        "sched1k.montecarlo", rehearsed=False, clusters=1, nodes="alibaba1313", horizon_s=4000.0
+    )
+    assert (len(cluster_events), width) == (machines, 1)
+    assert len(workload) == round(_data("traffic", "montecarlo")["plain"]["rate_per_second"] * 4000.0)
+    toy = _data("rehearsal", "sched1k.montecarlo")
+    _, _, cluster_events, _, width, _ = chip_smoke.leg_inputs("sched1k.montecarlo", rehearsed=True)
+    assert len(cluster_events) == toy["config"]["deployment"]["nodes"]
+    assert width == toy["traffic"]["clusters_per_chip"]
 
 
 def test_cpu_plumbing_runs_every_leg(capsys):
@@ -40,21 +98,21 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     for rec in (pure, composed, served, cli, faults):
         assert rec.pop("wall_s") >= 0
     assert pure == {
-        "leg": "pure", "clusters": 4, "nodes": 8, "pods": 512,
-        "formulation": kernels, "decisions": 1276, "reference": "lax.scan",
+        "leg": "pure", "clusters": 4, "nodes": 8, "pods": 128,
+        "formulation": kernels, "decisions": 104, "reference": "lax.scan",
         "mismatches": 0,
     }
     assert composed == {
         "leg": "composed", "clusters": 4, "nodes": 24, "pod_window": 128,
         "formulation": ca_kernels, "lane_major": True, "reclaim": True,
-        "superspans": 4, "feeder_slabs": 1, "pod_base": 64, "decisions": 864,
-        "scaled_up_pods": 80, "scaled_up_nodes": 16, "scaled_down_nodes": 16,
+        "superspans": 4, "feeder_slabs": 1, "pod_base": 64, "decisions": 860,
+        "scaled_up_pods": 68, "scaled_up_nodes": 8, "scaled_down_nodes": 8,
         "reference": "scan+ladder, statics off", "mismatches": 0,
     }
     assert served.pop("warmup_compiles") >= 1
     assert served == {
         "leg": "served", "lanes": 4, "nodes": 24, "formulation": ca_kernels,
-        "queries": 8, "query_errors": 0, "decisions": 403,
+        "queries": 8, "query_errors": 0, "decisions": 469,
         "recompiles_after_warmup": 0,
     }
     assert cli == {
@@ -62,9 +120,9 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     }
     # identical nodes, crashes and a rack's loss and return, every pod run to its end
     assert faults == {
-        "leg": "faults", "clusters": 4, "nodes": 8, "pods": 896,
-        "formulation": kernels, "node_crashes": 17, "node_recoveries": 16,
-        "pod_interruptions": 238, "pods_succeeded": 2821, "reference": "lax.scan",
+        "leg": "faults", "clusters": 4, "nodes": 12, "pods": 128,
+        "formulation": kernels, "node_crashes": 20, "node_recoveries": 16,
+        "pod_interruptions": 11, "pods_succeeded": 400, "reference": "lax.scan",
         "mismatches": 0,
     }
     assert summary.pop("wall_s") >= 0

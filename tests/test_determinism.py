@@ -3,8 +3,7 @@ random cluster + workload traces generated from the sim's own seeded RNG, run
 repeatedly; pods_succeeded and all three timing estimators must be
 bit-identical across runs.
 
-Tier-1 runs the FAST scales by default (150/1500 x 3 — the former
-KUBERNETRIKS_FAST_TESTS opt-in semantics, now the default: the
+Tier-1 runs the FAST scales by default (150/1500 x 3: the
 reference-scale run alone dominated the old ~36-min default suite). The
 reference's own scale (~<=1000 node / ~<=10000 pod events, 1 + 10 repeat
 runs, reference: tests/test_determinism.rs:70-126) lives in

@@ -85,7 +85,7 @@ def _composed_traces():
 
 def _apply_scenario_to_config(config, scen: Scenario):
     """Scalar-oracle view of one scenario: its overrides as plain config
-    scalars (the shape bench.py's per-engine baseline builds too)."""
+    scalars."""
     from kubernetriks_tpu.config import (
         KubeClusterAutoscalerConfig,
         KubeHorizontalPodAutoscalerConfig,

@@ -146,7 +146,8 @@ class ScriptedInjector:
     """Duck-typed HostChaos stand-in that faults EXACTLY the scripted
     lanes, in order, whenever the head of the script is active — the
     surgical control the isolation gates need (the probabilistic
-    injector is covered above and by bench.py --host-chaos)."""
+    injector is covered above and, on a fleet, by
+    test_seeded_host_chaos_keeps_the_fleet_available)."""
 
     def __init__(self, script):
         self.script = list(script)
@@ -447,3 +448,35 @@ def test_submit_validation_names_the_field(fault_run):
         ref.submit(Scenario(), 100.0, trace_rows=(4, 2))
     # Nothing above was admitted: the queue is still empty.
     assert ref.pending == 0
+
+
+# --- the seeded injector against a real fleet --------------------------------
+
+
+def test_seeded_host_chaos_keeps_the_fleet_available(fault_run):
+    """`HostChaos` itself (pinned seed, so the same schedule every run), armed
+    on the warm reference fleet: the unit of failure is a query, never the
+    fleet. Every round finishes, at least nine queries in ten come back with
+    a result, every failure is a typed error streamed exactly once, the
+    least-faulted victim rule reaches every lane, and the injected phase
+    compiles nothing."""
+    ref, _, _ = fault_run
+    ref.poll()
+    sizes_before = jit_cache_sizes()
+    ref.arm_host_chaos(HostChaos(seed=7, dispatch_rate=0.05, stall_rate=0.05, stall_ms=1.0))
+    qids, streamed = [], {}
+    for _ in range(8):
+        qids += [ref.submit(s, h) for s, h in SCENS]
+        ref.run_async()
+        for outcome in ref.poll():
+            streamed[outcome.query] = streamed.get(outcome.query, 0) + 1
+    report = ref.fault_report()
+    ref.arm_host_chaos(None)
+    assert [streamed.get(q, 0) for q in qids] == [1] * len(qids)
+    fails = [ref.results[q] for q in qids if not ref.results[q].ok]
+    assert fails and all(isinstance(f, LaneFaultError) for f in fails)
+    assert report["chaos"]["events"]["dispatch_faults"] == len(fails)
+    assert 1.0 - len(fails) / len(qids) >= 0.90, (len(fails), len(qids))
+    assert sorted({f.lane for f in fails}) == [0, 1, 2]
+    assert jit_cache_sizes() == sizes_before
+

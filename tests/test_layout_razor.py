@@ -1,12 +1,11 @@
-"""PR 9 exactness gates: lane-major hot state (KTPU_LANE_MAJOR), the
-empty-window resolution razor (KTPU_WINDOW_RAZOR) and the CA scale-down
-de-scatter (KTPU_CA_DESCATTER) are all bit-identical to the paths they
-replace.
+"""PR 9 exactness gates: lane-major hot state (KTPU_LANE_MAJOR) and the
+empty-window resolution razor (KTPU_WINDOW_RAZOR) are bit-identical to the
+paths they replace.
 
 - Layout-equivalence sweep: lane-major vs row-major final state across the
   ladder, fused chunk+slide and superspan executors on one composed
   HPA+CA+sliding-window engine WITH chaos faults on — the full flagship
-  feature set — with razor+de-scatter also flipped on against an all-off
+  feature set — with the razor also flipped on against an all-off
   reference, and dispatch_stats EQUAL (the modes are device-side layout /
   program changes; zero new host syncs).
 - Empty-window razor gate: a gappy dense-stepped trace (bursts separated by
@@ -128,14 +127,13 @@ def _run_composed(composed_traces, **kwargs):
 
 @pytest.fixture(scope="module")
 def composed_reference(composed_traces):
-    """Row-major, razor off, de-scatter off, ladder executor — the r8
-    path every new mode must reproduce bit for bit."""
+    """Row-major, razor off, ladder executor — the r8 path every new
+    mode must reproduce bit for bit."""
     return _run_composed(
         composed_traces,
         superspan=False,
         lane_major=False,
         window_razor=False,
-        ca_descatter=False,
     )
 
 
@@ -146,7 +144,7 @@ def composed_reference(composed_traces):
 def test_lane_major_bit_identity_across_executors(
     composed_traces, composed_reference, executor
 ):
-    """Lane-major + razor + de-scatter ON vs the all-off row-major
+    """Lane-major + razor ON vs the all-off row-major
     reference: final composed chaos state identical under the parity
     policy, on every steady-state executor."""
     kwargs = dict(superspan=False)
@@ -161,7 +159,6 @@ def test_lane_major_bit_identity_across_executors(
         composed_traces,
         lane_major=True,
         window_razor=True,
-        ca_descatter=True,
         **kwargs,
     )
     bad = compare_states(composed_reference.state, sim.state)

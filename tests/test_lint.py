@@ -19,7 +19,7 @@ def _fixture(name: str):
 
 
 def test_repo_is_golden_clean():
-    """The whole default scope (package, bench.py, tests, scripts,
+    """The whole default scope (package, chip_smoke.py, tests, scripts,
     experiments) lints clean under all NINE passes — every legitimate
     sync/draw/mix carries an explicit waiver with a reason — AND carries
     zero stale waivers (a *-ok that suppresses nothing would silently
@@ -192,8 +192,8 @@ def test_jit_table_is_scanned_not_hardcoded():
 
 def test_flag_registry_truthiness(monkeypatch):
     """The ONE truthiness rule: '0'/''/'false'/'no'/'off' are false, unset
-    takes the default, anything else is true — the KUBERNETRIKS_FAST_TESTS=0
-    bug class (bool(os.environ.get(...)) made '0' truthy) can't recur."""
+    takes the default, anything else is true — the bug class in which
+    bool(os.environ.get(...)) made '0' truthy can't recur."""
     from kubernetriks_tpu.flags import flag_bool, flag_str, flag_tristate
 
     for falsy in ("0", "", "false", "No", "OFF"):
@@ -504,8 +504,8 @@ _DOC_SYNC_ALLOW = {"KTPU_NOT_REGISTERED"}
 
 def test_flag_doc_sync():
     """Every registered flag appears in README/DESIGN, and every KTPU_* /
-    KUBERNETRIKS_* token in docs, bench and tests resolves to a
-    registered flag (or a registered-prefix family like KTPU_SWEEP_*) —
+    KUBERNETRIKS_* token in docs, chip_smoke.py, scripts and tests resolves
+    to a registered flag (or a registered-prefix family like KTPU_STREAM_*) —
     renamed tuners can no longer leave stale documentation behind."""
     import glob
     import re
@@ -521,7 +521,7 @@ def test_flag_doc_sync():
         "them (the README 'Environment flags' table is the catch-all)"
     )
 
-    scan = [os.path.join(ROOT, "README.md"), os.path.join(ROOT, "bench.py")]
+    scan = [os.path.join(ROOT, "README.md"), os.path.join(ROOT, "chip_smoke.py")]
     scan += glob.glob(os.path.join(ROOT, "docs", "*.md"))
     scan += glob.glob(os.path.join(ROOT, "tests", "*.py"))
     scan += glob.glob(os.path.join(ROOT, "scripts", "*.py"))
@@ -532,10 +532,41 @@ def test_flag_doc_sync():
             name = tok.rstrip("_")
             if name in flags.REGISTRY or tok in _DOC_SYNC_ALLOW:
                 continue
-            # KTPU_SWEEP_* style family references resolve to a prefix
+            # KTPU_STREAM_* style family references resolve to a prefix
             if tok.endswith("_") and any(
                 k.startswith(tok) for k in flags.REGISTRY
             ):
                 continue
             bad.setdefault(os.path.relpath(path, ROOT), []).append(tok)
     assert not bad, f"unregistered flag tokens in docs/tests: {bad}"
+
+
+def test_every_registered_flag_has_a_reader():
+    """A registered flag is READ by name somewhere outside flags.py: a
+    `flag_*("NAME")` call in the package, chip_smoke.py or a test, or a row
+    of the statics table (whose one loop reads its rows' flags). A mention
+    in a comment or a docstring is no reader: a flag nothing reads goes,
+    with its README row."""
+    import ast
+    import glob
+
+    from kubernetriks_tpu import flags
+    from kubernetriks_tpu.batched import statics
+
+    files = glob.glob(os.path.join(ROOT, "kubernetriks_tpu", "**", "*.py"), recursive=True)
+    files += glob.glob(os.path.join(ROOT, "tests", "*.py"))
+    files.append(os.path.join(ROOT, "chip_smoke.py"))
+    read = {row.flag for row in statics.TABLE if row.flag}
+    for path in files:
+        if os.path.samefile(path, flags.__file__):
+            continue
+        for node in ast.walk(ast.parse(open(path, encoding="utf-8").read())):
+            if not (isinstance(node, ast.Call) and node.args):
+                continue
+            callee = getattr(node.func, "attr", None) or getattr(node.func, "id", "")
+            name = node.args[0]
+            if callee.startswith("flag_") and isinstance(name, ast.Constant):
+                read.add(name.value)
+    unread = sorted(set(flags.REGISTRY) - read)
+    assert not unread, f"registered flags that nothing reads: {unread}"
+

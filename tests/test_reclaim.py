@@ -20,7 +20,10 @@ Gates here:
    the static reserve.
 3. Checkpoint/restore roundtrip carries the reclaim counters (ckpt meta
    guards a mode mismatch loudly).
-4. The slow-lane endurance gate: sustained churn many times the reserve
+4. The endurance claim at tier-1 size: churn past the reserve under the
+   watchdog fires no reserve verdict, compiles nothing after warm-up and
+   leaves the slab accounting where it was.
+5. The slow-lane endurance gate: sustained churn many times the reserve
    with chaos + streaming feeder + a mid-run checkpoint/restore, exact
    oracle trajectory, zero saturation verdicts, flat slab watermarks.
 """
@@ -309,6 +312,63 @@ events:
             n_clusters=1,
             reclaim=True,
         )
+
+
+@pytest.fixture(scope="module")
+def watched_churn():
+    """Twelve churn waves (16 allocations) through a four-slot reserve with
+    the flight recorder and the watchdog on: warm-up over the first three
+    waves, then the rest under a warning capture, the slab accounting and
+    the jit-cache counts read at both ends of the measured region."""
+    import warnings
+
+    from kubernetriks_tpu.batched.fleet import jit_cache_sizes
+    from kubernetriks_tpu.telemetry.observatory import SaturationWarning
+
+    n_waves = 12
+    _, sim = _build_batched(
+        wave_workload(n_waves), reclaim=True, ca_slot_multiplier=2,
+        telemetry=True, watchdog=True, telemetry_ring=64,
+    )
+    with warnings.catch_warnings(record=True):
+        warnings.simplefilter("always")
+        sim.step_until_time(10.0 + 3 * 200.0)
+    art = dict(sizes=jit_cache_sizes(), slabs=[sim._slab_accounting()])
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        for k in range(4, n_waves + 1):
+            sim.step_until_time(10.0 + k * 200.0)
+            art["slabs"].append(sim._slab_accounting())
+        sim.drain_telemetry()
+    art["sizes_after"] = jit_cache_sizes()
+    art["verdicts"] = [
+        str(w.message) for w in caught if issubclass(w.category, SaturationWarning)
+    ]
+    yield sim, art
+    sim.close()
+
+
+def test_reclaim_churn_keeps_the_watchdog_quiet(watched_churn):
+    """The endurance claim at tier-1 size: cumulative churn four times the
+    reserve, and no reserve verdict, neither warned along the way nor live
+    at the end; the reserve never trends toward exhaustion."""
+    sim, art = watched_churn
+    total = int(np.asarray(sim.state.auto.ca_total).sum())
+    reserve = sim._reserve_capacities["ca_reserve"][0]
+    assert total >= 3 * reserve, (total, reserve)
+    assert int(sim.ca_slots_reclaimed().sum()) >= total - reserve
+    assert [v for v in art["verdicts"] if "reserve" in v] == []
+    fired = sim.telemetry_report()["resources"]["watchdog"]["fired"]
+    assert not any(kind.endswith("_reserve_used") for kind in fired), fired
+    sim.check_autoscaler_bounds()
+
+
+def test_reclaim_churn_compiles_nothing_and_holds_its_slabs(watched_churn):
+    """After warm-up the compaction is a data move: no jit entry gains a
+    variant over nine more waves, and the slab accounting does not move."""
+    _, art = watched_churn
+    assert art["sizes_after"] == art["sizes"]
+    assert all(later == art["slabs"][0] for later in art["slabs"][1:]), art["slabs"]
 
 
 @pytest.mark.slow

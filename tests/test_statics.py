@@ -33,9 +33,8 @@ from kubernetriks_tpu.trace.generator import (
     UniformClusterTrace,
 )
 
-ON_OFF = ("tristate", "bool")
 # What each row turns on with it when asked for by kwarg.
-REQUIRED = {row.name: row.requires for row in TABLE if row.kind in ON_OFF}
+REQUIRED = {row.name: row.requires for row in TABLE if row.kind == "tristate"}
 FLAG_OF = {row.name: row.flag for row in TABLE}
 
 
@@ -52,7 +51,7 @@ def _default(row, backend):
 
 def _other(row, value):
     """A legal value of the row that differs from `value`."""
-    if row.kind in ON_OFF:
+    if row.kind == "tristate":
         return not value
     return 5 if value != 5 else 7
 
@@ -69,7 +68,7 @@ def test_precedence(row, monkeypatch):
     for backend in ("cpu", "tpu"):
         st = resolve({}, backend)
         want = _default(row, backend)
-        if row.requires and row.kind in ON_OFF:
+        if row.requires and row.kind == "tristate":
             want = want and getattr(st, row.requires)
         assert getattr(st, row.name) == want, backend
         own = row.flag and flags.REGISTRY[row.flag].default is not None
@@ -111,8 +110,7 @@ def test_table_covers_every_engine_static(tiny_sim):
     assert [f.name for f in EngineStatics.__dataclass_fields__.values()] == [
         *NAMES, "source",
     ]
-    kinds = {"tristate": "tristate", "bool": "bool", "int": "int",
-             "optional_int": "int"}
+    kinds = {"tristate": "tristate", "int": "int", "optional_int": "int"}
     for row in TABLE:
         assert row.requires is None or row.requires in NAMES
         if row.flag is None:
@@ -196,11 +194,11 @@ def tiny_sim(tiny_traces):
 
 def test_resolved_statics_of_a_default_cpu_build(tiny_sim):
     """No kwarg, no flag: the table's CPU defaults (accelerator tristates
-    off, descatter on), and the attributes the engine reads agree."""
+    off), and the attributes the engine reads agree."""
     want = {row.name: _default(row, "cpu") for row in TABLE}
     assert tiny_sim.resolved_statics() == want
     assert tiny_sim.statics == resolve({}, "cpu")
-    assert tiny_sim.donate is False and tiny_sim.ca_descatter is True
+    assert tiny_sim.donate is False and tiny_sim.window_razor is False
     assert tiny_sim._superspan_k == 16 and tiny_sim._stream_depth == 3
     assert tiny_sim._reclaim_requested is None
 

@@ -404,8 +404,7 @@ def test_staged_superspan_records_prefetch_spans(monkeypatch):
 
 def test_ladder_fallback_counter():
     """A superspan-selected engine forced onto the ladder (log_throughput
-    wants per-chunk timings) counts the fallback — observable outside
-    bench.py --smoke. One short span keeps the compile bill at two small
+    wants per-chunk timings) counts the fallback. One short span keeps the compile bill at two small
     ladder shapes."""
     sim = _build_dense_sliding(superspan=True)
     sim.log_throughput = True
