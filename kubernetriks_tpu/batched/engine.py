@@ -142,6 +142,9 @@ CKPT_COVERED_LEAVES = {
     "spread": "derived from the profile and the traces at build (a constraint "
     "under a profile that runs PodTopologySpread); the restoring engine's own "
     "state template supplies the structure (same-build contract, as `auto`)",
+    "affinity": "derived from the profile and the traces at build (a taint, a "
+    "nodeSelector, a node affinity or a toleration under a profile that runs "
+    "NodeAffinity or TaintToleration); same-build contract, as `spread`",
 }
 
 # Power-of-two dispatch chunk ladder for the sliding path: any span is its
@@ -317,6 +320,26 @@ def _slide_apply_device(pods, rank, pay, base, s: int, W: int):
     return new_pods, new_rank
 
 
+def _makes_objects_at_run_time(config, compiled_traces) -> bool:
+    """Whether the build's autoscalers make pods or nodes at run time, which
+    would need labels, taints and planes of their own."""
+    return (
+        config.horizontal_pod_autoscaler.enabled
+        or config.cluster_autoscaler.enabled
+        or any(c.pod_groups for c in compiled_traces)
+    )
+
+
+def _global_pod_plane_width(compiled_traces, n_pods: int, pod_window) -> int:
+    """Width of a pod plane kept in GLOBAL pod coordinates (the spread and
+    affinity states'): the device pod axis where that holds the whole trace;
+    under a sliding pod window, which cuts its columns out of the plane at
+    pod_base (step.spread_window_view), room for the widest window (the whole
+    trace) at the highest base."""
+    T = max(max((c.n_pods for c in compiled_traces), default=0), 1)
+    return n_pods if pod_window is None else 2 * T
+
+
 def _build_spread(profile, compiled_traces, config, n_nodes: int, n_pods: int, pod_window):
     """Host arrays of the build's state.SpreadState vocabulary leaves, or
     None where no pod is held to a topology-spread constraint: the profile
@@ -333,11 +356,7 @@ def _build_spread(profile, compiled_traces, config, n_nodes: int, n_pods: int, p
     carrying = [c.spread for c in compiled_traces if c.spread is not None]
     if not carrying or not uses_spread(profile):
         return None
-    if (
-        config.horizontal_pod_autoscaler.enabled
-        or config.cluster_autoscaler.enabled
-        or any(c.pod_groups for c in compiled_traces)
-    ):
+    if _makes_objects_at_run_time(config, compiled_traces):
         raise UnsupportedProfileError(
             "topology-spread constraints together with the horizontal pod autoscaler or the "
             "cluster autoscaler are not supported: pods and nodes made at run time would need "
@@ -346,12 +365,7 @@ def _build_spread(profile, compiled_traces, config, n_nodes: int, n_pods: int, p
     C = len(compiled_traces)
     G = max(len(sp.workloads) for sp in carrying)
     Z = max(max(len(sp.domains) for sp in carrying), 1)
-    # Global pod planes: as wide as the device pod axis where that holds the
-    # whole trace; under a sliding pod window, which cuts its columns out of
-    # them at pod_base (step.spread_window_view), room for the widest window
-    # (the whole trace) at the highest base.
-    T = max(max((c.n_pods for c in compiled_traces), default=0), 1)
-    width = n_pods if pod_window is None else 2 * T
+    width = _global_pod_plane_width(compiled_traces, n_pods, pod_window)
     out = {
         "domain": np.full((C, n_nodes), -1, np.int32),
         "max_skew": np.full((C, G, Z), np.iinfo(np.int32).max, np.int32),
@@ -395,6 +409,46 @@ def event_chunk_size(ev_time: np.ndarray, interval: float) -> int:
     typical = int(np.percentile(per_window, 90, method="lower"))
     blocks = -(-typical // SLAB_BLOCK_EVENTS)
     return SLAB_BLOCK_EVENTS * min(max(blocks, 1), 4)
+
+
+def _build_affinity(profile, compiled_traces, config, n_nodes: int, n_pods: int, pod_window):
+    """Host arrays of the build's state.AffinityState planes, or None where
+    the build carries none: the profile runs neither NodeAffinity nor
+    TaintToleration (taints and terms are then inert, as upstream's are with
+    the plugins off), or no trace has a taint, a nodeSelector, a node
+    affinity or a toleration. Each cluster keeps its own interned bits; the
+    build holds as many term planes as its widest pod has terms. Refuses, by
+    name, what the batched filters do not cover."""
+    from kubernetriks_tpu.batched.pipeline import UnsupportedProfileError, uses_affinity
+    from kubernetriks_tpu.batched.trace_compile import AFFINITY_NO_TERM
+
+    carrying = [c.affinity for c in compiled_traces if c.affinity is not None]
+    if not carrying or not uses_affinity(profile):
+        return None
+    if _makes_objects_at_run_time(config, compiled_traces):
+        raise UnsupportedProfileError(
+            "node taints, nodeSelectors, node affinities and tolerations together with the horizontal "
+            "pod autoscaler or the cluster autoscaler are not supported: pods and nodes made at run "
+            "time would need labels and taints of their own"
+        )
+    C = len(compiled_traces)
+    n_terms = max(af.pod_terms.shape[0] for af in carrying)
+    width = _global_pod_plane_width(compiled_traces, n_pods, pod_window)
+    out = {
+        "node_bits": np.zeros((C, n_nodes), np.int32),
+        "pod_terms": np.full((C, n_terms, width), AFFINITY_NO_TERM, np.int32),
+        "pod_forbid": np.zeros((C, width), np.int32),
+    }
+    out["pod_terms"][:, 0, :] = 0  # a slot no trace names holds a pod that names no node
+    for ci, trace in enumerate(compiled_traces):
+        af = trace.affinity
+        if af is None:
+            continue
+        out["node_bits"][ci, : len(af.node_bits)] = af.node_bits
+        terms, pods = af.pod_terms.shape
+        out["pod_terms"][ci, :terms, :pods] = af.pod_terms
+        out["pod_forbid"][ci, :pods] = af.pod_forbid
+    return out
 
 
 def _lex_name_ranks(names) -> np.ndarray:  # ktpu: sync-ok(host-side name-rank table builder over python name lists, no device values)
@@ -1432,6 +1486,16 @@ class BatchedSimulation:
         self._spread_shape = (
             None if spread_host is None else tuple(spread_host["max_skew"].shape[1:])
         )
+        # Node affinity and taints: likewise, one node plane and the pods'
+        # mask planes where the profile runs NodeAffinity or TaintToleration
+        # AND a trace carries a taint, a selector, an affinity or a
+        # toleration (state.AffinityState); the fit gates count the blocks.
+        affinity_host = _build_affinity(
+            self.profile, compiled_traces, config, self.n_nodes, self.n_pods, pod_window
+        )
+        self._affinity_terms = (
+            None if affinity_host is None else int(affinity_host["pod_terms"].shape[1])
+        )
         # Real (trace-defined) pod slots, before the 128-alignment padding
         # of the device axis — the count completion/terminal asserts want.
         self.n_real_pods = max((c.n_pods for c in compiled_traces), default=0)
@@ -1489,7 +1553,8 @@ class BatchedSimulation:
                 default_enabled()
                 and self.n_clusters % n_shards == 0
                 and kernel_fits(
-                    self.n_nodes, self.max_pods_per_cycle, self._spread_shape
+                    self.n_nodes, self.max_pods_per_cycle, self._spread_shape,
+                    self._affinity_terms,
                 )
             )
         # Prefer the fused selection kernel (in-kernel queue argmin instead
@@ -1506,7 +1571,7 @@ class BatchedSimulation:
             and self.n_clusters // n_shards >= 128
             and select_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape,
+                self._spread_shape, self._affinity_terms,
             )
         )
         # The r4 megakernel (selection + cycle + commit in one launch) is the
@@ -1523,7 +1588,7 @@ class BatchedSimulation:
             and flag_bool("KTPU_MEGAKERNEL")
             and select_commit_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape,
+                self._spread_shape, self._affinity_terms,
             )
         )
         # The fit gates above (and the CA kernels' in autoscale.py) degrade
@@ -1555,6 +1620,16 @@ class BatchedSimulation:
                     **{k: jnp.asarray(v) for k, v in spread_host.items()},
                     decisions=jnp.zeros((C,), jnp.int32),
                     decisions_bound=jnp.zeros((C,), jnp.int32),
+                )
+            )
+        if affinity_host is not None:
+            from kubernetriks_tpu.batched.state import AffinityState
+
+            self.state = self.state._replace(
+                affinity=AffinityState(
+                    **{k: jnp.asarray(v) for k, v in affinity_host.items()},
+                    attempts=jnp.zeros((C,), jnp.int32),
+                    attempts_refused=jnp.zeros((C,), jnp.int32),
                 )
             )
         # Static (lo, hi) device-slot bounds covering every pod-group slot:
@@ -3640,7 +3715,7 @@ class BatchedSimulation:
             self.use_pallas_select
             and select_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape,
+                self._spread_shape, self._affinity_terms,
             )
         )
         self.use_megakernel = (
@@ -3648,7 +3723,7 @@ class BatchedSimulation:
             and self.use_pallas_select
             and select_commit_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape,
+                self._spread_shape, self._affinity_terms,
             )
         )
         import logging
@@ -3903,19 +3978,22 @@ class BatchedSimulation:
         the geometry (or a restored checkpoint) left it."""
         return {**self.statics.as_dict(), "reclaim": self.reclaim}
 
-    def _spread_counters(self) -> Dict[str, int]:  # ktpu: sync-ok(readout: the spread filter's two (C,) counters, read with the metrics)
-        """The spread filter's counters summed over clusters ({} in a build
-        without it), also left on this engine's tracer handle so that
-        telemetry_report() carries them."""
-        spread = self.state.spread
-        if spread is None:
-            return {}
+    def _spread_counters(self) -> Dict[str, int]:  # ktpu: sync-ok(readout: the label filters' (C,) counters, read with the metrics)
+        """The spread filter's and the node-affinity and taint filters'
+        counters summed over clusters (none for a build without the filter),
+        also left on this engine's tracer handle so that telemetry_report()
+        carries them."""
         from kubernetriks_tpu.parallel.multihost import to_host
 
-        got = {
-            "spread_decisions": int(np.asarray(to_host(spread.decisions)).sum()),
-            "spread_decisions_bound": int(np.asarray(to_host(spread.decisions_bound)).sum()),
-        }
+        leaves = {}
+        spread, affinity = self.state.spread, self.state.affinity
+        if spread is not None:
+            leaves["spread_decisions"] = spread.decisions
+            leaves["spread_decisions_bound"] = spread.decisions_bound
+        if affinity is not None:
+            leaves["affinity_attempts"] = affinity.attempts
+            leaves["affinity_attempts_refused"] = affinity.attempts_refused
+        got = {name: int(np.asarray(to_host(x)).sum()) for name, x in leaves.items()}
         self.tracer.counters.update(got)
         return got
 

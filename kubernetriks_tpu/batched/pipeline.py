@@ -1,9 +1,10 @@
 """Compiled scheduler-profile pipeline: the device-plugin subsystem that
 lowers a KubeScheduler profile (ordered filter refs + weighted score refs)
 into the batched hot path. The device registry below lowers every built-in of
-the scalar registry (core/scheduler/plugins.py): the filters Fit and
-PodTopologySpread, the scorers LeastAllocatedResources, MostAllocatedResources
-and BalancedResourceAllocation.
+the scalar registry (core/scheduler/plugins.py): the filters Fit,
+PodTopologySpread, NodeAffinity and TaintToleration, the scorers
+LeastAllocatedResources, MostAllocatedResources and
+BalancedResourceAllocation.
 
 The scalar path interprets profiles per pod through the plugin registry
 (core/scheduler/plugins.py, kube_scheduler.py). The batched path cannot —
@@ -44,6 +45,14 @@ tests/test_random_equivalence.py):
   (domains and nodes on axis 0, clusters on axis 1); the scan body feeds
   them transposed arrays. A build none of whose pods is held to a
   constraint carries no table and traces none of it.
+- NodeAffinity and TaintToleration (semantics in core/scheduler/plugins.py
+  and docs/PARITY.md "Node affinity and taints") read one more node plane
+  and the candidate's masks, integers interned at trace compile
+  (trace_compile.CompiledAffinity): a term passes where
+  `node_bits & mask == mask`, the taints where `node_bits & untolerated ==
+  0` (`affinity_node_masks`). No string, no gather and no carried state. A
+  build without a taint, a selector, an affinity or a toleration carries no
+  plane and traces none of it.
 - Scores are float32, summed over scorers after weighting; a weight of
   exactly 1.0 skips the multiply so the default profile's expression tree
   is textually identical to the historical hard-fused one.
@@ -57,15 +66,16 @@ tests/test_random_equivalence.py):
   resolves while the scalar path's float64 tells them apart: on a replay
   of 17,899 such pods over 1,313 nodes the float32 argmax put the 4,434th
   pod on another node and half of all pods after it (PERF.md, PR 28). For
-  such builds the engine sets `exact_bits` (`exact_score_bits`) and the
-  default profile ranks nodes by `exact_least_allocated_key`, a 3-digit
+  such builds the engine sets `exact_bits` (`exact_score_bits`) and a
+  profile that scores by LeastAllocatedResources alone, whatever its
+  filters, ranks nodes by `exact_least_allocated_key`, a 3-digit
   fixed-point quotient in int32 that orders as the float64 score does.
 """
 
 from __future__ import annotations
 
 import logging
-from typing import Callable, Dict, NamedTuple, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +91,8 @@ from kubernetriks_tpu.core.scheduler.plugins import (
     FIT,
     LEAST_ALLOCATED,
     MOST_ALLOCATED,
+    NODE_AFFINITY,
+    TAINT_TOLERATION,
     TOPOLOGY_SPREAD,
 )
 
@@ -114,23 +126,42 @@ def _zero(x):
     return x.dtype.type(0)
 
 
+class NodeFacts(NamedTuple):
+    """What a filter may know of a node beside its allocatable: node masks
+    the decision core derived for this candidate, each None in a build that
+    carries nothing for it (the filter then passes every node)."""
+
+    # Where other pods sit: from the carried spread table (spread_node_mask).
+    spread_ok: Optional[jnp.ndarray] = None
+    # The node's labels against the candidate's terms, and its taints against
+    # the candidate's tolerations (affinity_node_masks).
+    affinity_ok: Optional[jnp.ndarray] = None
+    taints_ok: Optional[jnp.ndarray] = None
+
+
 # --- device plugin registry ---------------------------------------------------
-# Filters: fn(cpu, ram, rc, rr, spread_ok) -> bool mask (AND-composed onto
+# Filters: fn(cpu, ram, rc, rr, facts) -> bool mask (AND-composed onto
 # `alive`), or None for "passes every node". Scorers: fn(cpu, ram, rc, rr) ->
 # float32 score (summed after weighting). cpu/ram are the nodes' current
 # allocatable, rc/rr the candidate's requests; any broadcast-compatible shapes
-# (the scan path and the kernels differ). `spread_ok` is what a filter may
-# know of where other pods sit: the node mask the decision core derived from
-# the carried spread table for this candidate (spread_node_mask), None in a
-# build that carries no table.
+# (the scan path and the kernels differ). `facts` (NodeFacts, or None) is
+# everything else a filter may know.
 
 
-def _filter_fit(cpu, ram, rc, rr, spread_ok):
+def _filter_fit(cpu, ram, rc, rr, facts):
     return (rc <= cpu) & (rr <= ram)
 
 
-def _filter_topology_spread(cpu, ram, rc, rr, spread_ok):
-    return spread_ok
+def _filter_topology_spread(cpu, ram, rc, rr, facts):
+    return None if facts is None else facts.spread_ok
+
+
+def _filter_node_affinity(cpu, ram, rc, rr, facts):
+    return None if facts is None else facts.affinity_ok
+
+
+def _filter_taint_toleration(cpu, ram, rc, rr, facts):
+    return None if facts is None else facts.taints_ok
 
 
 def _score_least_allocated(cpu, ram, rc, rr):
@@ -190,6 +221,8 @@ def _score_balanced(cpu, ram, rc, rr):
 DEVICE_FILTER_PLUGINS: Dict[str, Callable] = {
     FIT: _filter_fit,
     TOPOLOGY_SPREAD: _filter_topology_spread,
+    NODE_AFFINITY: _filter_node_affinity,
+    TAINT_TOLERATION: _filter_taint_toleration,
 }
 
 DEVICE_SCORE_PLUGINS: Dict[str, Callable] = {
@@ -285,15 +318,44 @@ def to_kube_scheduler_config(profile: CompiledProfile) -> KubeSchedulerConfig:
 # --- compiled expressions -----------------------------------------------------
 
 
-def profile_fit_mask(profile: CompiledProfile, alive, cpu, ram, rc, rr, spread_ok=None):
+def profile_fit_mask(profile: CompiledProfile, alive, cpu, ram, rc, rr, facts=None):
     """The profile's filter chain ANDed onto the alive mask. Elementwise;
     usable in the scan body and inside Mosaic kernels."""
     fit = alive
     for fname in profile.filters:
-        mask = DEVICE_FILTER_PLUGINS[fname](cpu, ram, rc, rr, spread_ok)
+        mask = DEVICE_FILTER_PLUGINS[fname](cpu, ram, rc, rr, facts)
         if mask is not None:
             fit = fit & mask
     return fit
+
+
+# --- NodeAffinity and TaintToleration: the planes ------------------------------
+# `node_bits`: a node's int32 of interned bits (the expressions its labels
+# satisfy, the taints it carries; bit 31 never set); `terms`: the candidate's
+# term masks, one array a term plane of the build (bit 31 alone: the pod has
+# no such term, and no node passes it); `forbid`: the taint bits the
+# candidate does not tolerate, bit 31 saying that the pod names its nodes.
+# Any broadcast-compatible shapes: (Np, L) against (1, L) in the kernels,
+# (C, N) against (C, 1) in the scan body.
+
+
+def uses_affinity(profile: CompiledProfile) -> bool:
+    return NODE_AFFINITY in profile.filters or TAINT_TOLERATION in profile.filters
+
+
+def affinity_node_masks(node_bits, terms, forbid):
+    """(affinity_ok, taints_ok): the nodes that satisfy at least one of the
+    candidate's terms, and those none of whose taints it fails to tolerate."""
+    affinity_ok = None
+    for want in terms:
+        hit = (node_bits & want) == want
+        affinity_ok = hit if affinity_ok is None else affinity_ok | hit
+    return affinity_ok, (node_bits & forbid) == jnp.int32(0)
+
+
+def affinity_names_nodes(forbid):
+    """Whether the candidate carries a selector, an affinity or a toleration."""
+    return forbid < jnp.int32(0)
 
 
 # --- PodTopologySpread: the carried table --------------------------------------
@@ -431,7 +493,7 @@ def exact_score_bits(profile: CompiledProfile, requests, capacities) -> int:
     if lockstep and int(cpus.max()) // unit_cpu <= _LOCKSTEP_MAX_UNITS:
         return 0
     bits = min(_EXACT_BITS_MAX, 31 - largest.bit_length())
-    default = profile.filters == (FIT,) and profile.scores == ((LEAST_ALLOCATED, 1.0),)
+    default = profile.scores == ((LEAST_ALLOCATED, 1.0),)
     if default and bits >= _EXACT_BITS_MIN:
         return bits
     logging.getLogger(__name__).warning(
@@ -441,7 +503,8 @@ def exact_score_bits(profile: CompiledProfile, requests, capacities) -> int:
         "placements may differ from the scalar backend's",
         f"capacity {largest} leaves {bits} bits a digit, under {_EXACT_BITS_MIN}"
         if default
-        else f"profile {profile.name!r} has no exact key, only the default profile has",
+        else f"profile {profile.name!r} has no exact key: it scores by {list(profile.scores)}, and only "
+        "LeastAllocatedResources at weight 1 has one, whatever the filters",
     )
     return 0
 
@@ -498,11 +561,11 @@ def exact_best_node(hi, lo, node_ok, iota, axis: int):
     )
 
 
-def profile_fit_score(profile: CompiledProfile, alive, cpu, ram, rc, rr, spread_ok=None):
+def profile_fit_score(profile: CompiledProfile, alive, cpu, ram, rc, rr, facts=None):
     """(fit mask, masked score) in one call — the decision core both the
     lax.scan path (batched/step.py) and the Pallas kernels
     (ops/scheduler_kernel._fit_score_place) build on."""
-    fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, spread_ok)
+    fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
     return fit, profile_score(profile, fit, cpu, ram, rc, rr)
 
 

@@ -299,6 +299,38 @@ class SpreadState(NamedTuple):
     decisions_bound: jnp.ndarray  # (C,) int32
 
 
+class AffinityState(NamedTuple):
+    """The NodeAffinity and TaintToleration filters' device state
+    (batched/pipeline.py; semantics: core/scheduler/plugins.py and
+    docs/PARITY.md "Node affinity and taints"). Present only in a build whose
+    profile runs one of the two filters over traces that carry a taint, a
+    nodeSelector, a node affinity or a toleration; None otherwise, and the
+    window programs then trace none of it (the structural idiom of `spread`).
+
+    The three planes are the traces' interned vocabulary
+    (trace_compile.CompiledAffinity), integers only, and never change: no
+    filter of the two reads where other pods sit, so nothing is carried from
+    one placement to the next. The pod planes are in GLOBAL pod-slot
+    coordinates, the whole trace wide, as SpreadState's are: the device pod
+    window reads its own columns of them at `pod_base`
+    (step.affinity_window_view), so no slide, refill or window growth moves
+    them. `node_bits` is laid out like the hot node leaves."""
+
+    node_bits: jnp.ndarray  # (C, N) int32 the expressions the node satisfies, the taints it carries
+    # (C, T, W) int32 a pod's t-th term: the bits a node must carry
+    # (0: every node passes; bit 31 alone: the pod has no t-th term).
+    pod_terms: jnp.ndarray
+    # (C, W) int32 the taint bits the pod does not tolerate; bit 31: the pod
+    # carries a selector, an affinity or a toleration.
+    pod_forbid: jnp.ndarray
+    # Always-on counters, a cluster's own: a cycle's attempts to place a pod
+    # that carries a selector, an affinity or a toleration, and those of them
+    # that ended unschedulable although some live node passed every other
+    # filter of the chain (the labels and taints, not capacity, refused it).
+    attempts: jnp.ndarray  # (C,) int32
+    attempts_refused: jnp.ndarray  # (C,) int32
+
+
 class ClusterBatchState(NamedTuple):
     """Complete batched simulation state; a pytree of arrays with leading
     cluster axis C, shardable across a device mesh on that axis."""
@@ -327,6 +359,9 @@ class ClusterBatchState(NamedTuple):
     # Topology-spread vocabulary, pod planes and counters (SpreadState) or
     # None when no pod of the build is held to a constraint.
     spread: Optional[SpreadState] = None
+    # Node-affinity and taint planes and counters (AffinityState) or None
+    # when no node of the build is tainted and no pod names its nodes.
+    affinity: Optional[AffinityState] = None
 
 
 # Column layout of the device-side telemetry ring (TelemetryRing.buf).
@@ -801,12 +836,16 @@ def swap_node_layout(state: "ClusterBatchState") -> "ClusterBatchState":
     pairs, auto, telemetry) is untouched. Exact — a transpose moves bits."""
     nodes = state.nodes
     spread = state.spread
+    affinity = state.affinity
     return state._replace(
         nodes=nodes._replace(
             **{name: getattr(nodes, name).T for name in NODE_HOT_LEAVES}
         ),
-        # The spread filter's node plane is read by the same kernels.
+        # The label filters' node planes are read by the same kernels.
         spread=spread if spread is None else spread._replace(domain=spread.domain.T),
+        affinity=affinity
+        if affinity is None
+        else affinity._replace(node_bits=affinity.node_bits.T),
     )
 
 
@@ -834,6 +873,7 @@ CLUSTER_STATE_LEAVES = (
     "auto",
     "telemetry",
     "spread",
+    "affinity",
 )
 TELEMETRY_RING_LEAVES = ("buf", "cursor")
 SPREAD_STATE_LEAVES = (

@@ -1375,6 +1375,19 @@ def _spread_with_zone(spread, pod_base: jnp.ndarray, zone_w: jnp.ndarray):
     return spread._replace(pod_zone=put(spread.pod_zone, pod_base, zone_w))
 
 
+def affinity_window_view(affinity, pod_base: jnp.ndarray, P: int):
+    """The device pod window's columns of the affinity state's global pod
+    planes: (the term planes..., the untolerated-taint plane), (C, P) each,
+    the order the kernels' wrappers take them in. As spread_window_view."""
+    planes = tuple(affinity.pod_terms[:, t, :] for t in range(affinity.pod_terms.shape[1])) + (
+        affinity.pod_forbid,
+    )
+    if planes[0].shape[1] == P:
+        return planes
+    cut = jax.vmap(lambda row, lo: jax.lax.dynamic_slice(row, (lo,), (P,)))
+    return tuple(cut(x, pod_base) for x in planes)
+
+
 @jax.named_scope("spread_counts")
 def spread_counts(state: ClusterBatchState, consts: StepConstants, lane_major: bool = False):
     """What the PodTopologySpread filter knows at the start of a cycle
@@ -1430,6 +1443,9 @@ class CycleCandidates(NamedTuple):
     # without the spread filter.
     spread_group: Optional[jnp.ndarray] = None
     spread_bits: Optional[jnp.ndarray] = None
+    # (C, K) the candidates' node-affinity term masks and, last, their
+    # untolerated-taint masks; None in a build without the label filters.
+    affinity: Optional[Tuple[jnp.ndarray, ...]] = None
 
 
 def cycle_durations(pod_sched_time, K: int, first=0):
@@ -1634,12 +1650,14 @@ def candidates_from_slots(
     W: jnp.ndarray,
     consts: StepConstants,
     spread_pods=None,
+    affinity_pods=None,
 ) -> CycleCandidates:
     """Assemble CycleCandidates from chosen candidate slots — the gathers
     and the `waited` formula shared by the sorted path and the in-kernel
     selection path (ONE definition, so the paths cannot drift).
     `spread_pods`: the window's (workload, match bits) planes where the
-    build runs the spread filter."""
+    build runs the spread filter; `affinity_pods`: its term and
+    untolerated-taint planes where it runs the label filters."""
     C = cand.shape[0]
     rows = jnp.arange(C, dtype=jnp.int32)[:, None]
     interval = jnp.float32(consts.scheduling_interval)
@@ -1660,6 +1678,11 @@ def candidates_from_slots(
             else dict(
                 spread_group=spread_pods[0][rows, cand], spread_bits=spread_pods[1][rows, cand]
             )
+        ),
+        **(
+            {}
+            if affinity_pods is None
+            else dict(affinity=tuple(x[rows, cand] for x in affinity_pods))
         ),
     )
 
@@ -1944,7 +1967,9 @@ def _put_lanes(full, part, slot, moved):
     return jnp.where(moved, jnp.take(part, slot, axis=-1, mode="clip"), full)
 
 
-def _launch_by_depth(launch, nodes, eligible, pod_planes, pod_time, spread, n_eligible, K, lane_major):
+def _launch_by_depth(
+    launch, nodes, eligible, pod_planes, pod_time, spread, n_eligible, K, lane_major, affinity=None
+):
     """The megakernel's launch, its lanes chosen by depth. A grid program of
     the kernel holds one tile of clusters (ops/scheduler_kernel._LANE) and
     loops to its deepest lane's queue, every lane computed at every step: a
@@ -1978,13 +2003,17 @@ def _launch_by_depth(launch, nodes, eligible, pod_planes, pod_time, spread, n_el
 
     nodes: (alive, alloc_cpu, alloc_ram); pod_planes: the eight (C, P)
     planes after `eligible` in the wrapper's order; pod_time (C,);
-    spread: the wrapper's six operands or None. Returns (the wrapper's
-    outputs, moved (C,) bool: the clusters a second launch drained)."""
+    spread: the wrapper's six operands or None; affinity: the label
+    filters' operands (the node plane, then the pod planes) or None. Returns
+    (the wrapper's outputs, moved (C,) bool: the clusters a second launch
+    drained)."""
     from kubernetriks_tpu.ops.scheduler_kernel import _LANE as R
 
     C = eligible.shape[0]
     if -(-C // R) == 1:
-        return launch(nodes, eligible, pod_planes, pod_time, spread), jnp.zeros((C,), jnp.bool_)
+        return launch(nodes, eligible, pod_planes, pod_time, spread, affinity), jnp.zeros(
+            (C,), jnp.bool_
+        )
 
     moved = _lanes_to_move(n_eligible, K, R)
     lanes = -(-C // R) * R
@@ -2006,6 +2035,7 @@ def _launch_by_depth(launch, nodes, eligible, pod_planes, pod_time, spread, n_el
     pod_planes = (qwin, jax.lax.bitcast_convert_type(qoff, jnp.int32), *others)
     in_axes = (n_axis,) * 3 + (0,) * (2 + len(pod_planes))
     spread_axes = (n_axis, 0, 0, 0, 0, 0)
+    affinity_axes = (n_axis,) + (0,) * (len(affinity) - 1) if affinity else ()
     # The pads are the wrapper's own, made a step early: the same device phase.
     with jax.named_scope("kernel_io"):
         operands = tuple(
@@ -2016,14 +2046,18 @@ def _launch_by_depth(launch, nodes, eligible, pod_planes, pod_time, spread, n_el
             tiles(x, axis, fill)
             for x, axis, fill in zip(spread, spread_axes, (-1, 0, 0, 0, -1, 0))
         )
+        affinity_tiles = affinity and tuple(
+            tiles(x, axis) for x, axis in zip(affinity, affinity_axes)
+        )
 
-    def launch_on(operands, spread_tiles):
+    def launch_on(operands, spread_tiles, affinity_tiles):
         """The wrapper on planes that hold the cluster axis last; its outputs
         the same way."""
         ops = clusters_first(operands, in_axes)
         outputs = launch(
             ops[:3], ops[3], ops[4:-1], ops[-1],
             spread_tiles and clusters_first(spread_tiles, spread_axes),
+            affinity_tiles and clusters_first(affinity_tiles, affinity_axes),
         )
         out_axes = (n_axis, n_axis) + (0,) * (len(outputs) - 2)
         return tuple(jnp.moveaxis(x, axis, -1) for x, axis in zip(outputs, out_axes))
@@ -2031,7 +2065,9 @@ def _launch_by_depth(launch, nodes, eligible, pod_planes, pod_time, spread, n_el
     def split():
         moves = jnp.pad(moved, (0, lanes - C))
         first = launch_on(
-            operands[:3] + (jnp.where(moves, 0, operands[3]),) + operands[4:], spread_tiles
+            operands[:3] + (jnp.where(moves, 0, operands[3]),) + operands[4:],
+            spread_tiles,
+            affinity_tiles,
         )
         # Slot r of the second launch holds the r-th moved cluster: its lane
         # is the number of lanes with at most r moved ones up to and with
@@ -2043,13 +2079,25 @@ def _launch_by_depth(launch, nodes, eligible, pod_planes, pod_time, spread, n_el
         second = launch_on(
             tuple(_take_lanes(x, index) for x in operands),
             spread_tiles and tuple(_take_lanes(x, index) for x in spread_tiles),
+            affinity_tiles and tuple(_take_lanes(x, index) for x in affinity_tiles),
         )
         return tuple(_put_lanes(a, b, count - 1, moves) for a, b in zip(first, second))
 
-    outputs = jax.lax.cond(moved.any(), split, lambda: launch_on(operands, spread_tiles))
+    outputs = jax.lax.cond(
+        moved.any(), split, lambda: launch_on(operands, spread_tiles, affinity_tiles)
+    )
     out_axes = (n_axis, n_axis) + (0,) * (len(outputs) - 2)
     with jax.named_scope("kernel_io"):
         return clusters_first((x[..., :C] for x in outputs), out_axes), moved
+
+
+def _flag_counts(flags_k: jnp.ndarray) -> jnp.ndarray:
+    """(C, 2) how many of a pass's (C, K) decisions carry bit 0 and bit 1 of
+    their flags (the spread filter's and the label filters' two facts)."""
+    return jnp.stack(
+        [(flags_k & 1).sum(axis=1, dtype=jnp.int32), (flags_k >> 1).sum(axis=1, dtype=jnp.int32)],
+        axis=1,
+    )
 
 
 class _CyclePasses(NamedTuple):
@@ -2058,7 +2106,7 @@ class _CyclePasses(NamedTuple):
     offsets from T, +inf untouched), the queue-time samples by position in
     the cycle (0 where nothing was assigned), the order-free folds, and with
     the spread filter the count table, the placed domains and the two
-    counters. `passes` is the loop's own count: it ends when passes * K
+    counters, with the label filters theirs. `passes` is the loop's own count: it ends when passes * K
     covers the deepest queue."""
 
     passes: jnp.ndarray  # int32 scalar
@@ -2074,6 +2122,9 @@ class _CyclePasses(NamedTuple):
     assigned: jnp.ndarray
     late: jnp.ndarray
     spread: Optional[tuple] = None  # (counts (C, G, Z), zone_w (C, P), stats (C, 2))
+    # (C, 2) the label filters' counters: attempts of pods that name their
+    # nodes, and those of them the labels and taints alone refused.
+    named: Optional[jnp.ndarray] = None
 
 
 @jax.named_scope("cycle")
@@ -2163,6 +2214,12 @@ def _run_scheduling_cycle(
     def spread_nodes(counts):
         return (sp.domain, counts, sp.max_skew, zone_alive)
 
+    # NodeAffinity / TaintToleration: the node plane and the window's pod
+    # planes, integers interned at trace compile; nothing is carried.
+    af = state.affinity
+    affinity_pods = None if af is None else affinity_window_view(af, state.pod_base, P)
+    affinity_all = None if af is None else (af.node_bits,) + affinity_pods
+
     pods, last_flush_win, eligible = prepare_queue(
         state, W, consts, conditional_move, wake, lane_major=lane_major
     )
@@ -2186,7 +2243,7 @@ def _run_scheduling_cycle(
             W[:, None] - pods.initial_attempt_ts.win
         ).astype(jnp.float32) * interval - pods.initial_attempt_ts.off
 
-        def launch(nodes, eligible, pod_planes, pod_time, spread):
+        def launch(nodes, eligible, pod_planes, pod_time, spread, affinity):
             # The wrapper reads column 0 of its last K-shaped operand alone.
             time_k = jnp.broadcast_to(pod_time[:, None], (pod_time.shape[0], K))
             return fused_select_cycle_commit(
@@ -2201,6 +2258,7 @@ def _run_scheduling_cycle(
                 nodes_lane_major=lane_major,
                 profile=profile,
                 spread=spread,
+                affinity=affinity,
             )
 
         (
@@ -2225,7 +2283,9 @@ def _run_scheduling_cycle(
             n_eligible,
             K,
             lane_major,
+            affinity_all,
         )
+        named_out = placed.pop() if af is not None else None
         start_tmp = start_tmp + jnp.float32(consts.delta_bind_start)
         totals = CycleTotals(
             assigned=qstats[:, 0].astype(jnp.int32),
@@ -2275,6 +2335,7 @@ def _run_scheduling_cycle(
                     nodes_lane_major=lane_major,
                     profile=profile,
                     spread=None if sp is None else spread_nodes(acc.spread[0]) + spread_pods,
+                    affinity=affinity_all,
                 )
             )
             cc = candidates_from_slots(pods, last_flush_win, cand, valid, W, consts)
@@ -2284,7 +2345,7 @@ def _run_scheduling_cycle(
             cand = jax.lax.dynamic_slice_in_dim(order, first, K, axis=1)
             valid = (first + jnp.arange(K, dtype=jnp.int32))[None, :] < n_eligible[:, None]
             return candidates_from_slots(
-                pods, last_flush_win, cand, valid, W, consts, spread_pods
+                pods, last_flush_win, cand, valid, W, consts, spread_pods, affinity_pods
             )
 
         def decide_candidates(acc, first):
@@ -2306,6 +2367,7 @@ def _run_scheduling_cycle(
                 spread=None
                 if sp is None
                 else spread_nodes(acc.spread[0]) + (cc.spread_group, cc.spread_bits),
+                affinity=None if af is None else (af.node_bits,) + cc.affinity,
             )
             return cc, assign_k, cc.valid & ~fitany_k, best_k, alloc_cpu, alloc_ram, placed_k
 
@@ -2315,6 +2377,9 @@ def _run_scheduling_cycle(
             # at this branch boundary — the CPU-parity path, where XLA pays
             # layout copies either way.
             from kubernetriks_tpu.batched.pipeline import (
+                NodeFacts,
+                affinity_names_nodes,
+                affinity_node_masks,
                 exact_best_node,
                 exact_least_allocated_key,
                 profile_fit_mask,
@@ -2339,17 +2404,22 @@ def _run_scheduling_cycle(
                 Z = counts0.shape[2]
                 tiles0, limits = spread_tiles(acc.spread[0]), spread_tiles(sp.max_skew)
                 zalive_t = spread_alive_tile(zone_alive)
+            n_spread = 0 if sp is None else 2
+            if af is not None:
+                node_bits_x = af.node_bits.T if lane_major else af.node_bits
 
             def body(carry, xs):
                 alloc_cpu, alloc_ram, tiles = carry
-                valid, req_cpu, req_ram, *cand_spread = xs
-                spread_ok = None
+                valid, req_cpu, req_ram, *cand_planes = xs
+                facts = None
                 if sp is not None:
-                    group, bits = (x[None, :] for x in cand_spread)
+                    group, bits = (x[None, :] for x in cand_planes[:n_spread])
                     zone_ok, constrained, closed = spread_zone_ok(
                         list(tiles), limits, zalive_t, group, bits
                     )
-                    spread_ok = spread_node_mask(domain_t, zone_ok, constrained, Z).T
+                    facts = NodeFacts(
+                        spread_ok=spread_node_mask(domain_t, zone_ok, constrained, Z).T
+                    )
 
                 # The compiled profile's filter mask + weighted score
                 # (pipeline.py; default = Fit + LeastAllocatedResources,
@@ -2361,12 +2431,21 @@ def _run_scheduling_cycle(
                 # between near-equal node scores, which lockstep requests
                 # never produce.
                 nodes_and_pod = (alloc_cpu, alloc_ram, req_cpu[:, None], req_ram[:, None])
+                if af is not None:
+                    # As the kernels' core (_fit_score_place): the chain
+                    # without the two label filters, then with them.
+                    *terms, forbid = (x[:, None] for x in cand_planes[n_spread:])
+                    rest = profile_fit_mask(profile, alive_x, *nodes_and_pod, facts)
+                    affinity_ok, taints_ok = affinity_node_masks(node_bits_x, terms, forbid)
+                    facts = (facts or NodeFacts())._replace(
+                        affinity_ok=affinity_ok, taints_ok=taints_ok
+                    )
                 if profile.exact_bits:
-                    fit = profile_fit_mask(profile, alive_x, *nodes_and_pod, spread_ok)
+                    fit = profile_fit_mask(profile, alive_x, *nodes_and_pod, facts)
                     hi, lo = exact_least_allocated_key(fit, *nodes_and_pod, profile.exact_bits)
                     best = exact_best_node(hi, lo, True, iota_n, axis=1)[:, 0]
                 else:
-                    fit, score = profile_fit_score(profile, alive_x, *nodes_and_pod, spread_ok)
+                    fit, score = profile_fit_score(profile, alive_x, *nodes_and_pod, facts)
                     # Last-max-wins argmax, matching the reference's `>=` sweep
                     # over name-sorted nodes (kube_scheduler.rs:140-150).
                     best = jnp.int32(N - 1) - jax.lax.argmax(score[:, ::-1], 1, jnp.int32)
@@ -2378,24 +2457,34 @@ def _run_scheduling_cycle(
                 best_c = jnp.clip(best, 0, None)
                 alloc_cpu = alloc_cpu.at[rows1, best_c].add(jnp.where(assign, -req_cpu, 0))
                 alloc_ram = alloc_ram.at[rows1, best_c].add(jnp.where(assign, -req_ram, 0))
-                if sp is None:
-                    return (alloc_cpu, alloc_ram, tiles), (assign, park, best)
-                zbest = jnp.where(assign, domain_t[best_c, rows1], jnp.int32(-1))
-                tiles = tuple(spread_place(list(tiles), zbest[None, :], assign[None, :], bits))
-                flags = (assign & constrained[0]).astype(jnp.int32) + 2 * (
-                    assign & closed[0]
-                ).astype(jnp.int32)
-                return (alloc_cpu, alloc_ram, tiles), (assign, park, best, zbest, flags)
+                outs = (assign, park, best)
+                if sp is not None:
+                    zbest = jnp.where(assign, domain_t[best_c, rows1], jnp.int32(-1))
+                    tiles = tuple(spread_place(list(tiles), zbest[None, :], assign[None, :], bits))
+                    flags = (assign & constrained[0]).astype(jnp.int32) + 2 * (
+                        assign & closed[0]
+                    ).astype(jnp.int32)
+                    outs += (zbest, flags)
+                if af is not None:
+                    attempt = valid & affinity_names_nodes(forbid[:, 0])
+                    outs += (
+                        attempt.astype(jnp.int32)
+                        + 2 * (attempt & ~any_fit & rest.any(axis=1)).astype(jnp.int32),
+                    )
+                return (alloc_cpu, alloc_ram, tiles), outs
 
             xs = (cc.valid.T, cc.req_cpu.T, cc.req_ram.T)
             if sp is not None:
                 xs += (cc.spread_group.T, cc.spread_bits.T)
+            if af is not None:
+                xs += tuple(x.T for x in cc.affinity)
             (alloc_cpu, alloc_ram, tiles), outs = jax.lax.scan(body, (acpu0, aram0, tiles0), xs)
             assign_k, park_k, best_k, *placed_k = (o.T for o in outs)
             if lane_major:
                 alloc_cpu, alloc_ram = alloc_cpu.T, alloc_ram.T
             if sp is not None:
-                placed_k.append(jnp.stack([t[:Z].T for t in tiles], axis=1))
+                # The table goes after the spread's two, before the label flags.
+                placed_k.insert(2, jnp.stack([t[:Z].T for t in tiles], axis=1))
             return cc, assign_k, park_k, best_k, alloc_cpu, alloc_ram, placed_k
 
         decide = (
@@ -2416,6 +2505,9 @@ def _run_scheduling_cycle(
                 pallas_interpret=pallas_interpret,
             )
             n_assigned = assign_k.sum(axis=1, dtype=jnp.int32)
+            named_acc = None
+            if af is not None:
+                named_acc = acc.named + _flag_counts(placed_k.pop())
             spread_acc = None
             if placed_k:
                 # The placed domains go back into the pod plane as the
@@ -2425,14 +2517,7 @@ def _run_scheduling_cycle(
                 spread_acc = (
                     counts,
                     zone_acc.at[rows, jnp.where(assign_k, cc.cand, P)].set(zbest_k, mode="drop"),
-                    stats
-                    + jnp.stack(
-                        [
-                            (flags_k & 1).sum(axis=1, dtype=jnp.int32),
-                            (flags_k >> 1).sum(axis=1, dtype=jnp.int32),
-                        ],
-                        axis=1,
-                    ),
+                    stats + _flag_counts(flags_k),
                 )
             return _CyclePasses(
                 passes=acc.passes + jnp.int32(1),
@@ -2450,6 +2535,7 @@ def _run_scheduling_cycle(
                 assigned=acc.assigned + n_assigned,
                 late=acc.late + jnp.where(acc.passes > 0, n_assigned, 0),
                 spread=spread_acc,
+                named=named_acc,
             )
 
         zeros_c = jnp.zeros((C,), jnp.int32)
@@ -2470,6 +2556,7 @@ def _run_scheduling_cycle(
                 assigned=zeros_c,
                 late=zeros_c,
                 spread=None if sp is None else (counts0, zone_w, jnp.zeros((C, 2), jnp.int32)),
+                named=None if af is None else jnp.zeros((C, 2), jnp.int32),
             ),
         )
         alloc_cpu, alloc_ram = acc.alloc_cpu, acc.alloc_ram
@@ -2486,6 +2573,7 @@ def _run_scheduling_cycle(
             late=acc.late,
         )
         spread_out = None if sp is None else acc.spread[1:]
+        named_out = acc.named
 
     metrics = fold_cycle_totals(
         state.metrics, totals, n_eligible, pod_sched_time, consts, K, compacted
@@ -2504,6 +2592,13 @@ def _run_scheduling_cycle(
             spread=_spread_with_zone(sp, state.pod_base, new_zone_w)._replace(
                 decisions=sp.decisions + stats[:, 0],
                 decisions_bound=sp.decisions_bound + stats[:, 1],
+            )
+        )
+    if named_out is not None:
+        new_state = new_state._replace(
+            affinity=af._replace(
+                attempts=af.attempts + named_out[:, 0],
+                attempts_refused=af.attempts_refused + named_out[:, 1],
             )
         )
     return new_state, sweep
@@ -2548,11 +2643,12 @@ def _freeze_lanes(
     # The spread filter's node plane never changes and is laid out like the
     # hot node leaves: it passes through.
     def rest_of(st):
-        spread = st.spread
+        spread, affinity = st.spread, st.affinity
         return st._replace(
             nodes=None,
             telemetry=None,
             spread=spread if spread is None else spread._replace(domain=None),
+            affinity=affinity if affinity is None else affinity._replace(node_bits=None),
         )
 
     rest = jax.tree.map(
@@ -2560,6 +2656,10 @@ def _freeze_lanes(
     )
     if rest.spread is not None:
         rest = rest._replace(spread=rest.spread._replace(domain=state.spread.domain))
+    if rest.affinity is not None:
+        rest = rest._replace(
+            affinity=rest.affinity._replace(node_bits=state.affinity.node_bits)
+        )
     return rest._replace(nodes=frozen_nodes, telemetry=state.telemetry)
 
 

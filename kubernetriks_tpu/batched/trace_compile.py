@@ -40,6 +40,7 @@ from kubernetriks_tpu.core.events import (
     RemoveNodeRequest,
     RemovePodRequest,
 )
+from kubernetriks_tpu.core.scheduler.plugins import node_taints
 from kubernetriks_tpu.trace.interface import TraceEvents
 
 
@@ -166,6 +167,112 @@ def _compile_spread(node_labels, pods, n_nodes: int) -> Optional[CompiledSpread]
     )
 
 
+# What one build's node-affinity and taint planes hold (batched/pipeline.py
+# "NodeAffinity and TaintToleration"): a node's bits are ONE int32 a node, a
+# bit a distinct expression or taint of the trace, bit 31 never a node's; a
+# pod's terms one plane each. A trace past either is refused by name.
+AFFINITY_MAX_BITS = 31
+AFFINITY_MAX_TERMS = 4
+AFFINITY_NO_TERM = np.int32(-(2**31))  # an unused term plane's mask: bit 31, which no node has
+AFFINITY_NAMES_NODES = np.int32(-(2**31))  # in a pod's untolerated mask: the pod carries a term or a toleration
+
+
+@dataclass
+class CompiledAffinity:
+    """One cluster's node selector expressions and taints, interned
+    (NodeAffinity and TaintToleration, core/scheduler/plugins.py): strings
+    never reach the device, and the expressions are evaluated against the
+    nodes' labels here, on the host. Present only where a node of the trace
+    carries a taint or a pod a nodeSelector, a node affinity or a toleration."""
+
+    expressions: List[Tuple]  # distinct (key, operator, values); index = bit
+    taints: List[Tuple[str, str]]  # distinct NoSchedule (key, value); bit = len(expressions) + index
+    node_bits: np.ndarray  # (N,) int32 the expressions the node's labels satisfy and the taints it carries
+    # (T, P) int32, T the most terms a pod of the trace has (at least 1): the
+    # bits a node must carry to satisfy the pod's t-th term (0: every node
+    # does; AFFINITY_NO_TERM: the pod has no t-th term).
+    pod_terms: np.ndarray
+    # (P,) int32 the taint bits the pod does NOT tolerate (a node passes iff
+    # it carries none of them), with AFFINITY_NAMES_NODES where the pod
+    # carries a selector, an affinity or a toleration.
+    pod_forbid: np.ndarray
+
+
+def _compile_affinity(node_labels, taints_of_node, pods, n_nodes: int) -> Optional[CompiledAffinity]:
+    """Intern one trace's expressions and taints to bits. `node_labels` /
+    `taints_of_node`: the label dict and the NoSchedule (key, value) taints of
+    each node slot; `pods`: the Pod of each pod slot (None for a slot no
+    CreatePod names). Raises what NodeAffinity and TaintToleration refuse."""
+    from kubernetriks_tpu.core.scheduler.plugins import (
+        expression_matches,
+        supported_node_terms,
+        supported_tolerations,
+        tolerates,
+    )
+
+    terms_of = [None if pod is None else supported_node_terms(pod) for pod in pods]
+    tolerations_of = [() if pod is None else supported_tolerations(pod) for pod in pods]
+    taints = sorted({t for ts in taints_of_node for t in ts})
+    if not taints and not any(terms_of) and not any(tolerations_of):
+        return None
+    expressions = sorted({e for terms in terms_of if terms for term in terms for e in term})
+    most_terms = max((len(terms) for terms in terms_of if terms), default=1)
+    if len(expressions) + len(taints) > AFFINITY_MAX_BITS:
+        raise ValueError(
+            f"{len(expressions)} distinct node selector expressions and {len(taints)} distinct taints in one "
+            f"trace (the first: {(expressions + taints)[0]!r}): more than the node plane's "
+            f"{AFFINITY_MAX_BITS} bits hold"
+        )
+    if most_terms > AFFINITY_MAX_TERMS:
+        worst = next(pod for pod, terms in zip(pods, terms_of) if terms and len(terms) == most_terms)
+        raise ValueError(
+            f"pod {worst.metadata.name!r}: {most_terms} nodeSelectorTerms, more than the "
+            f"{AFFINITY_MAX_TERMS} term planes a build holds"
+        )
+    bit_of = {e: np.int32(1 << i) for i, e in enumerate(expressions)}
+    taint_bit = {t: np.int32(1 << (len(expressions) + i)) for i, t in enumerate(taints)}
+    bits_of_labels: Dict[Tuple, int] = {}
+
+    def label_bits(labels: Dict[str, str]) -> int:
+        memo = tuple(sorted(labels.items()))
+        got = bits_of_labels.get(memo)
+        if got is None:
+            got = bits_of_labels[memo] = sum(
+                int(bit_of[e]) for e in expressions if expression_matches(e, labels)
+            )
+        return got
+
+    node_bits = np.zeros(n_nodes, np.int32)
+    for slot, (labels, carried) in enumerate(zip(node_labels, taints_of_node)):
+        node_bits[slot] = label_bits(labels) + sum(int(taint_bit[t]) for t in carried)
+    pod_terms = np.full((most_terms, len(pods)), AFFINITY_NO_TERM, np.int32)
+    pod_forbid = np.zeros(len(pods), np.int32)
+    every_taint = sum(int(b) for b in taint_bit.values())
+    forbid_of: Dict[Tuple, int] = {}
+    for slot, (pod, terms, tolerations) in enumerate(zip(pods, terms_of, tolerations_of)):
+        if terms is None:
+            pod_terms[0, slot] = 0
+        else:
+            for t, term in enumerate(terms):
+                pod_terms[t, slot] = sum(int(bit_of[e]) for e in term)
+        if not tolerations:
+            forbid = every_taint
+        else:
+            memo = tuple((t.key, t.operator, t.value, t.effect) for t in tolerations)
+            forbid = forbid_of.get(memo)
+            if forbid is None:
+                forbid = forbid_of[memo] = sum(
+                    int(taint_bit[t]) for t in taints if not tolerates(tolerations, t)
+                )
+        pod_forbid[slot] = forbid
+        if pod is not None and pod.spec.names_nodes():
+            pod_forbid[slot] |= AFFINITY_NAMES_NODES
+    return CompiledAffinity(
+        expressions=expressions, taints=taints, node_bits=node_bits,
+        pod_terms=pod_terms, pod_forbid=pod_forbid,
+    )
+
+
 @dataclass
 class CompiledClusterTrace:
     """One cluster's compiled trace + payload tables (numpy, host-side)."""
@@ -188,6 +295,10 @@ class CompiledClusterTrace:
     # Interned labels and topology-spread constraints; None where no pod of
     # the trace carries a constraint (labels alone intern nothing).
     spread: Optional[CompiledSpread] = None
+    # Interned node selector expressions and taints; None where no node of
+    # the trace carries a taint and no pod a selector, an affinity or a
+    # toleration.
+    affinity: Optional[CompiledAffinity] = None
 
     @property
     def n_events(self) -> int:
@@ -243,8 +354,8 @@ def _slot_reuse_clock(config):
 def _reusable_slot(dead, window, cap, labels, node_cap_cpu, node_cap_ram, node_labels):
     """The slot of the name's last incarnation, if a creation in `window`
     may return to it: windows apart (`dead` is (slot, first window allowed),
-    _slot_reuse_clock) and the same machine (capacity and labels are
-    per-slot tables); else None."""
+    _slot_reuse_clock) and the same machine (capacity, and labels with
+    taints, are per-slot tables); else None."""
     if dead is None or window < dead[1]:
         return None
     slot = dead[0]
@@ -284,6 +395,9 @@ def _node_slots_in_name_order(trace: CompiledClusterTrace) -> CompiledClusterTra
         spread=None
         if trace.spread is None
         else dataclasses.replace(trace.spread, node_domain=trace.spread.node_domain[order]),
+        affinity=None
+        if trace.affinity is None
+        else dataclasses.replace(trace.affinity, node_bits=trace.affinity.node_bits[order]),
     )
 
 
@@ -349,7 +463,9 @@ def compile_cluster_trace(
     pod_slot: Dict[str, int] = {}
     pod_groups: List[CompiledPodGroup] = []
     crash_downtime_s: List[float] = []
-    node_labels: List[Dict[str, str]] = []
+    # (labels, NoSchedule taints) of each node slot: what a slot's machine is
+    # beside its capacity.
+    node_labels: List[Tuple[Dict[str, str], Tuple]] = []
     pod_objects: List[object] = []
     # name -> (slot, the first window in which the slot may be created
     # again) of the name's last, removed incarnation.
@@ -363,18 +479,19 @@ def compile_cluster_trace(
             node = event.node
             name = node.metadata.name
             cap = (int(node.status.capacity.cpu), int(node.status.capacity.ram) // ram_unit)
+            labelled = (node.metadata.labels, node_taints(node))
             slot = None
             if window_of is not None:
                 slot = _reusable_slot(
                     dead_node_slot.pop(name, None), window_of(ts),
-                    cap, node.metadata.labels, node_cap_cpu, node_cap_ram, node_labels,
+                    cap, labelled, node_cap_cpu, node_cap_ram, node_labels,
                 )
             if slot is None:
                 slot = len(node_cap_cpu)
                 node_cap_cpu.append(cap[0])
                 node_cap_ram.append(cap[1])
                 node_names.append(name)
-                node_labels.append(node.metadata.labels)
+                node_labels.append(labelled)
             live_node_slot[name] = slot
             ev_time.append(ts)
             ev_kind.append(EV_NODE_RECOVER if event.recovered else EV_CREATE_NODE)
@@ -437,6 +554,11 @@ def compile_cluster_trace(
                     f"pod group {group.name!r}: topology-spread constraints on an HPA pod group's template "
                     "are not supported (pods made at run time would need labels of their own)"
                 )
+            if template.spec.names_nodes():
+                raise ValueError(
+                    f"pod group {group.name!r}: a nodeSelector, a node affinity or tolerations on an HPA pod "
+                    "group's template are not supported (pods made at run time would need planes of their own)"
+                )
             pod_objects.extend([None] * slot_count)
             for i in range(slot_count):
                 pod_req_cpu.append(int(requests.cpu))
@@ -473,11 +595,24 @@ def compile_cluster_trace(
                 f"batched path does not support trace event {type(event).__name__}"
             )
 
-    spread = _compile_spread(node_labels, pod_objects, len(node_cap_cpu))
+    spread = _compile_spread(
+        [labels for labels, _ in node_labels], pod_objects, len(node_cap_cpu)
+    )
     if spread is not None and pod_groups:
         raise ValueError(
             "topology-spread constraints together with HPA pod groups are not supported (pods made at "
             "run time would need labels of their own)"
+        )
+    affinity = _compile_affinity(
+        [labels for labels, _ in node_labels],
+        [taints for _, taints in node_labels],
+        pod_objects,
+        len(node_cap_cpu),
+    )
+    if affinity is not None and pod_groups:
+        raise ValueError(
+            "node taints, nodeSelectors, node affinities or tolerations together with HPA pod groups are "
+            "not supported (pods made at run time would need planes of their own)"
         )
 
     return _node_slots_in_name_order(CompiledClusterTrace(
@@ -494,6 +629,7 @@ def compile_cluster_trace(
         pod_groups=pod_groups,
         crash_downtime_s=np.asarray(crash_downtime_s, np.float64) if crash_downtime_s else None,
         spread=spread,
+        affinity=affinity,
     ))
 
 

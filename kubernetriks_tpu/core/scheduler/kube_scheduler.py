@@ -17,9 +17,11 @@ from kubernetriks_tpu.core.scheduler.plugins import (
     FilterPlugin,
     LEAST_ALLOCATED,
     MOST_ALLOCATED,
+    NODE_AFFINITY,
     PLUGIN_REGISTRY,
     SchedulerCache,
     ScorePlugin,
+    TAINT_TOLERATION,
     TOPOLOGY_SPREAD,
 )
 from kubernetriks_tpu.core.types import Node, Pod
@@ -74,6 +76,12 @@ NAMED_PROFILE_SPECS: Dict[str, tuple] = {
     # scorer: pods that carry a topologySpreadConstraint (DoNotSchedule) are
     # held to it, pods without one schedule as under "default".
     "topology_spread": ((FIT, TOPOLOGY_SPREAD), ((LEAST_ALLOCATED, 1.0),)),
+    # The default with the two filters by which pods name their nodes: a
+    # nodeSelector or required node affinity against the nodes' labels, and
+    # tolerations against their NoSchedule taints (node pools, a dedicated
+    # pool behind a taint). Pods that carry none schedule as under "default"
+    # on the untainted nodes.
+    "node_pools": ((FIT, NODE_AFFINITY, TAINT_TOLERATION), ((LEAST_ALLOCATED, 1.0),)),
 }
 
 

@@ -1,8 +1,9 @@
 """Scheduler plugin registry: the reference's Fit and LeastAllocatedResources
 (reference: src/core/scheduler/plugin.rs), the packing-side scorers
 (MostAllocatedResources, BalancedResourceAllocation) and kube-scheduler's
-PodTopologySpread filter (DoNotSchedule). The batched device pipeline lowers
-every one of them.
+filters PodTopologySpread (DoNotSchedule), NodeAffinity (the required half)
+and TaintToleration (NoSchedule). The batched device pipeline lowers every
+one of them.
 
 A filter sees the pod, the nodes still in the running and the scheduler's
 cache (`SchedulerCache`: every cached node, the cached pods, and which pods
@@ -18,9 +19,9 @@ cannot lower)."""
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set, Union
+from typing import Dict, List, Optional, Set, Tuple, Union
 
-from kubernetriks_tpu.core.types import Node, Pod, TopologySpreadConstraint
+from kubernetriks_tpu.core.types import Node, Pod, Toleration, TopologySpreadConstraint
 
 # Shared plugin-name constants (scalar registry keys == device registry keys).
 FIT = "Fit"
@@ -28,6 +29,8 @@ LEAST_ALLOCATED = "LeastAllocatedResources"
 MOST_ALLOCATED = "MostAllocatedResources"
 BALANCED = "BalancedResourceAllocation"
 TOPOLOGY_SPREAD = "PodTopologySpread"
+NODE_AFFINITY = "NodeAffinity"
+TAINT_TOLERATION = "TaintToleration"
 
 
 @dataclass
@@ -154,6 +157,12 @@ def supported_spread_constraint(pod: Pod) -> Optional[TopologySpreadConstraint]:
             "constraint a pod with whenUnsatisfiable DoNotSchedule and a matchLabels selector"
         )
 
+    if pod.spec.names_nodes():
+        raise refuse(
+            "a topology-spread constraint together with a nodeSelector, a node affinity or a "
+            "toleration (upstream's nodeAffinityPolicy / nodeTaintsPolicy decide which nodes count "
+            "for the skew)"
+        )
     if len(constraints) > 1:
         raise refuse(f"more than one constraint a pod ({len(constraints)} topologySpreadConstraints)")
     c = constraints[0]
@@ -186,8 +195,11 @@ class PodTopologySpread(FilterPlugin):
     - `D`: the values of `topologyKey` over the nodes in the scheduler's
       cache that carry the key. Resource fit plays no part in `D`: a zone
       whose nodes are all full still holds the minimum down (the pod then
-      waits, as upstream's does). No node affinity, no taints, `minDomains`
-      unset.
+      waits, as upstream's does). `minDomains` unset. Every node with the
+      key counts, whatever its taints: a pod that carries a constraint AND a
+      nodeSelector, a node affinity or a toleration is refused by name
+      (upstream's `nodeAffinityPolicy` / `nodeTaintsPolicy` decide which
+      nodes count for such a pod's skew; not modelled).
     - `match(d)`: the pods in the scheduler's cache (assigned and not yet
       known to have left: `Scheduler.assignments`) on nodes of domain `d`
       whose labels satisfy `selector` (`matchLabels`, one namespace).
@@ -231,9 +243,161 @@ class PodTopologySpread(FilterPlugin):
         ]
 
 
+class UnsupportedNodePlacement(ValueError):
+    """A pod's node affinity or tolerations, or a node's taints, say
+    something the NodeAffinity and TaintToleration filters do not implement.
+    Raised where it is used, naming the part: never ignored."""
+
+
+# A node selector expression as both filters' callers hold it: (key,
+# operator, values), hashable, values sorted. A term is a tuple of them.
+Expression = Tuple[str, str, Tuple[str, ...]]
+NODE_SELECTOR_OPERATORS = ("In", "NotIn", "Exists", "DoesNotExist")
+
+
+def _refuse_placement(pod: Pod, what: str) -> UnsupportedNodePlacement:
+    return UnsupportedNodePlacement(
+        f"pod {pod.metadata.name!r}: {what} is not supported: NodeAffinity implements nodeSelector and "
+        "requiredDuringSchedulingIgnoredDuringExecution terms of matchExpressions (In, NotIn, Exists, "
+        "DoesNotExist), TaintToleration the effect NoSchedule"
+    )
+
+
+def _refuse_with_spread(pod: Pod) -> None:
+    if pod.spec.topology_spread_constraints and pod.spec.names_nodes():
+        supported_spread_constraint(pod)  # raises, naming the pair
+
+
+def supported_node_terms(pod: Pod) -> Optional[Tuple[Tuple[Expression, ...], ...]]:
+    """The pod's node selector terms as NodeAffinity implements them, None
+    for a pod with neither a nodeSelector nor a node affinity (it passes
+    every node): the required terms, ORed, each a tuple of expressions,
+    ANDed, with the nodeSelector's pairs (`key In [value]`) in every one of
+    them. Raises UnsupportedNodePlacement naming what is refused. The scalar
+    filter and the batched trace compiler both call this."""
+    _refuse_with_spread(pod)
+    affinity = pod.spec.node_affinity
+    selector = tuple(
+        (key, "In", (value,)) for key, value in sorted(pod.spec.node_selector.items())
+    )
+    if affinity is None:
+        return (selector,) if selector else None
+    if affinity.preferred:
+        raise _refuse_placement(
+            pod, "preferredDuringSchedulingIgnoredDuringExecution (the scoring half)"
+        )
+    if not affinity.required_terms:
+        raise _refuse_placement(pod, "a node affinity without nodeSelectorTerms")
+    terms = []
+    for term in affinity.required_terms:
+        if term.match_fields:
+            raise _refuse_placement(pod, "matchFields")
+        if not term.match_expressions:
+            raise _refuse_placement(pod, "a nodeSelectorTerm without matchExpressions")
+        expressions = []
+        for e in term.match_expressions:
+            if e.operator not in NODE_SELECTOR_OPERATORS:
+                raise _refuse_placement(pod, f"the node selector operator {e.operator}")
+            if e.operator in ("In", "NotIn") and not e.values:
+                raise _refuse_placement(pod, f"{e.operator} without values")
+            values = tuple(sorted(e.values)) if e.operator in ("In", "NotIn") else ()
+            expressions.append((e.key, e.operator, values))
+        terms.append(selector + tuple(expressions))
+    return tuple(terms)
+
+
+def expression_matches(expression: Expression, labels: Dict[str, str]) -> bool:
+    key, operator, values = expression
+    if operator == "In":
+        return key in labels and labels[key] in values
+    if operator == "NotIn":
+        return key not in labels or labels[key] not in values
+    if operator == "Exists":
+        return key in labels
+    return key not in labels  # DoesNotExist
+
+
+def node_taints(node: Node) -> Tuple[Tuple[str, str], ...]:
+    """The node's NoSchedule taints as (key, value) pairs; raises
+    UnsupportedNodePlacement naming any other effect."""
+    out = []
+    for taint in node.spec.taints:
+        if taint.effect != "NoSchedule":
+            raise UnsupportedNodePlacement(
+                f"node {node.metadata.name!r}: the taint effect {taint.effect} is not supported "
+                "(NoExecute: eviction is not modelled; PreferNoSchedule: the scoring half): "
+                "TaintToleration implements NoSchedule"
+            )
+        out.append((taint.key, taint.value))
+    return tuple(out)
+
+
+def supported_tolerations(pod: Pod) -> Tuple[Toleration, ...]:
+    """The pod's tolerations as TaintToleration implements them; raises
+    UnsupportedNodePlacement naming what is refused."""
+    _refuse_with_spread(pod)
+    for t in pod.spec.tolerations:
+        if t.operator not in ("Equal", "Exists"):
+            raise _refuse_placement(pod, f"the toleration operator {t.operator}")
+        if t.effect not in ("", "NoSchedule"):
+            raise _refuse_placement(pod, f"a toleration of the effect {t.effect}")
+        if not t.key and t.operator != "Exists":
+            raise _refuse_placement(pod, "a toleration with an empty key and the operator Equal")
+    return tuple(pod.spec.tolerations)
+
+
+def tolerates(tolerations, taint: Tuple[str, str]) -> bool:
+    """Whether one of `tolerations` matches the NoSchedule taint (key, value)."""
+    key, value = taint
+    for t in tolerations:
+        if t.operator == "Exists":
+            if not t.key or t.key == key:
+                return True
+        elif t.key == key and t.value == value:
+            return True
+    return False
+
+
+class NodeAffinity(FilterPlugin):
+    """kube-scheduler's NodeAffinity, the Filter half (docs/PARITY.md "Node
+    affinity and taints"): a node passes iff its labels carry every pair of
+    the pod's `nodeSelector` AND satisfy at least one of its required
+    `nodeSelectorTerms`, a term being the AND of its `matchExpressions` (In,
+    NotIn, Exists, DoesNotExist on `metadata.labels`). A pod with neither
+    passes every node. What it does not implement it refuses by name
+    (`supported_node_terms`)."""
+
+    def filter(self, pod: Pod, nodes: List[Node], cache: SchedulerCache) -> List[Node]:
+        terms = supported_node_terms(pod)
+        if terms is None:
+            return nodes
+        return [
+            node
+            for node in nodes
+            if any(all(expression_matches(e, node.metadata.labels) for e in term) for term in terms)
+        ]
+
+
+class TaintToleration(FilterPlugin):
+    """kube-scheduler's TaintToleration, the Filter half (docs/PARITY.md
+    "Node affinity and taints"): a node passes iff each of its taints of
+    effect NoSchedule is tolerated by the pod. A toleration matches a taint
+    on `key` and `operator` (`Equal`: the value too; `Exists`: the key, or
+    every taint where the key is empty) and `effect` (empty matches every
+    effect). Other effects are refused by name (`node_taints`)."""
+
+    def filter(self, pod: Pod, nodes: List[Node], cache: SchedulerCache) -> List[Node]:
+        tolerations = supported_tolerations(pod)
+        return [
+            node for node in nodes if all(tolerates(tolerations, t) for t in node_taints(node))
+        ]
+
+
 PLUGIN_REGISTRY: Dict[str, Union[FilterPlugin, ScorePlugin]] = {
     FIT: Fit(),
     TOPOLOGY_SPREAD: PodTopologySpread(),
+    NODE_AFFINITY: NodeAffinity(),
+    TAINT_TOLERATION: TaintToleration(),
     LEAST_ALLOCATED: LeastAllocatedResources(),
     MOST_ALLOCATED: MostAllocatedResources(),
     BALANCED: BalancedResourceAllocation(),

@@ -124,11 +124,41 @@ class NodeStatus:
 
 
 @dataclass
+class Taint:
+    """One entry of a node's `spec.taints`. Every effect upstream has is kept,
+    so that what the scheduler does not implement is refused by name where it
+    is used (core/scheduler/plugins.node_taints), never dropped at parse."""
+
+    key: str = ""
+    value: str = ""
+    effect: str = "NoSchedule"
+
+    @staticmethod
+    def from_dict(d: Dict[str, Any]) -> "Taint":
+        return Taint(
+            key=str(d.get("key", "")),
+            value=str(d.get("value") or ""),
+            effect=str(d.get("effect", "NoSchedule")),
+        )
+
+    def to_dict(self) -> Dict[str, Any]:
+        return {"key": self.key, "value": self.value, "effect": self.effect}
+
+
+@dataclass
+class NodeSpec:
+    # The scheduler's TaintToleration filter reads these; immutable once
+    # parsed (copies share the list).
+    taints: List[Taint] = field(default_factory=list)
+
+
+@dataclass
 class Node:
     """reference: src/core/node.rs:44-51."""
 
     metadata: ObjectMeta = field(default_factory=ObjectMeta)
     status: NodeStatus = field(default_factory=NodeStatus)
+    spec: NodeSpec = field(default_factory=NodeSpec)
 
     @staticmethod
     def new(name: str, cpu: int, ram: int) -> "Node":
@@ -166,6 +196,7 @@ class Node:
                     for c in self.status.conditions
                 ],
             ),
+            spec=NodeSpec(taints=self.spec.taints),
         )
         return node
 
@@ -187,16 +218,22 @@ class Node:
         return Node(
             metadata=ObjectMeta.from_dict(d.get("metadata")),
             status=NodeStatus(allocatable=allocatable, capacity=capacity),
+            spec=NodeSpec(
+                taints=[Taint.from_dict(t) for t in (d.get("spec") or {}).get("taints") or []]
+            ),
         )
 
     def to_dict(self) -> Dict[str, Any]:
-        return {
+        out: Dict[str, Any] = {
             "metadata": self.metadata.to_dict(),
             "status": {
                 "allocatable": self.status.allocatable.to_dict(),
                 "capacity": self.status.capacity.to_dict(),
             },
         }
+        if self.spec.taints:
+            out["spec"] = {"taints": [t.to_dict() for t in self.spec.taints]}
+        return out
 
 
 @dataclass
@@ -248,7 +285,7 @@ class Resources:
 
 # Upstream spells these keys in camelCase, the trace vocabulary here in
 # snake_case; both are read, snake_case is written.
-_SPREAD_KEYS = {
+_SNAKE_KEYS = {
     "maxSkew": "max_skew",
     "topologyKey": "topology_key",
     "whenUnsatisfiable": "when_unsatisfiable",
@@ -257,11 +294,20 @@ _SPREAD_KEYS = {
     "matchExpressions": "match_expressions",
     "minDomains": "min_domains",
     "matchLabelKeys": "match_label_keys",
+    "nodeSelector": "node_selector",
+    "nodeAffinity": "node_affinity",
+    "requiredDuringSchedulingIgnoredDuringExecution": "required",
+    "required_during_scheduling_ignored_during_execution": "required",
+    "preferredDuringSchedulingIgnoredDuringExecution": "preferred",
+    "preferred_during_scheduling_ignored_during_execution": "preferred",
+    "nodeSelectorTerms": "node_selector_terms",
+    "matchFields": "match_fields",
+    "tolerationSeconds": "toleration_seconds",
 }
 
 
 def _snake(d: Optional[Dict[str, Any]]) -> Dict[str, Any]:
-    return {_SPREAD_KEYS.get(k, k): v for k, v in (d or {}).items()}
+    return {_SNAKE_KEYS.get(k, k): v for k, v in (d or {}).items()}
 
 
 @dataclass
@@ -312,6 +358,110 @@ class TopologySpreadConstraint:
 
 
 @dataclass
+class NodeSelectorRequirement:
+    """One `matchExpressions` entry of a node selector term: `operator` over
+    the node's `metadata.labels[key]` and `values`, as upstream spells it."""
+
+    key: str = ""
+    operator: str = "In"
+    values: List[str] = field(default_factory=list)
+
+    @staticmethod
+    def from_dict(d: Dict[str, Any]) -> "NodeSelectorRequirement":
+        return NodeSelectorRequirement(
+            key=str(d.get("key", "")),
+            operator=str(d.get("operator", "In")),
+            values=[str(v) for v in d.get("values") or []],
+        )
+
+    def to_dict(self) -> Dict[str, Any]:
+        return {"key": self.key, "operator": self.operator, "values": list(self.values)}
+
+
+@dataclass
+class NodeSelectorTerm:
+    match_expressions: List[NodeSelectorRequirement] = field(default_factory=list)
+    match_fields: List[Any] = field(default_factory=list)
+
+
+@dataclass
+class NodeAffinity:
+    """A pod's `spec.affinity.nodeAffinity`: the required terms (ORed; a
+    term's expressions ANDed) and, kept so that it can be refused by name
+    where it is used (core/scheduler/plugins.supported_node_terms), the
+    preferred list."""
+
+    required_terms: List[NodeSelectorTerm] = field(default_factory=list)
+    preferred: List[Any] = field(default_factory=list)
+
+    @staticmethod
+    def from_dict(d: Optional[Dict[str, Any]]) -> Optional["NodeAffinity"]:
+        if not d:
+            return None
+        d = _snake(d)
+        terms = _snake(d.get("required")).get("node_selector_terms") or []
+        return NodeAffinity(
+            required_terms=[
+                NodeSelectorTerm(
+                    match_expressions=[
+                        NodeSelectorRequirement.from_dict(e)
+                        for e in _snake(t).get("match_expressions") or []
+                    ],
+                    match_fields=list(_snake(t).get("match_fields") or []),
+                )
+                for t in terms
+            ],
+            preferred=list(d.get("preferred") or []),
+        )
+
+    def to_dict(self) -> Dict[str, Any]:
+        out: Dict[str, Any] = {
+            "required": {
+                "node_selector_terms": [
+                    {
+                        "match_expressions": [e.to_dict() for e in t.match_expressions],
+                        **({"match_fields": list(t.match_fields)} if t.match_fields else {}),
+                    }
+                    for t in self.required_terms
+                ]
+            }
+        }
+        if self.preferred:
+            out["preferred"] = list(self.preferred)
+        return out
+
+
+@dataclass
+class Toleration:
+    """One entry of a pod's `spec.tolerations`, as upstream spells it."""
+
+    key: str = ""
+    operator: str = "Equal"
+    value: str = ""
+    effect: str = ""
+    toleration_seconds: Optional[float] = None
+
+    @staticmethod
+    def from_dict(d: Dict[str, Any]) -> "Toleration":
+        d = _snake(d)
+        return Toleration(
+            key=str(d.get("key") or ""),
+            operator=str(d.get("operator") or "Equal"),
+            value=str(d.get("value") or ""),
+            effect=str(d.get("effect") or ""),
+            toleration_seconds=d.get("toleration_seconds"),
+        )
+
+    def to_dict(self) -> Dict[str, Any]:
+        out: Dict[str, Any] = {
+            "key": self.key, "operator": self.operator, "value": self.value, "effect": self.effect,
+        }
+        if self.toleration_seconds is not None:
+            out["toleration_seconds"] = self.toleration_seconds
+        return out
+
+
+@dataclass
 class PodSpec:
     """running_duration=None means an infinitely long-running service
     (reference: src/core/pod.rs:16-23)."""
@@ -322,6 +472,16 @@ class PodSpec:
     # nodes' and the placed pods' metadata.labels; immutable once parsed
     # (copies share the list).
     topology_spread_constraints: List[TopologySpreadConstraint] = field(default_factory=list)
+    # What the NodeAffinity and TaintToleration filters read against a
+    # node's metadata.labels and spec.taints; immutable once parsed (copies
+    # share them).
+    node_selector: Dict[str, str] = field(default_factory=dict)
+    node_affinity: Optional[NodeAffinity] = None
+    tolerations: List[Toleration] = field(default_factory=list)
+
+    def names_nodes(self) -> bool:
+        """Whether the pod carries a selector, an affinity or a toleration."""
+        return bool(self.node_selector or self.node_affinity is not None or self.tolerations)
 
 
 @dataclass
@@ -378,6 +538,9 @@ class Pod:
                 ),
                 running_duration=self.spec.running_duration,
                 topology_spread_constraints=self.spec.topology_spread_constraints,
+                node_selector=self.spec.node_selector,
+                node_affinity=self.spec.node_affinity,
+                tolerations=self.spec.tolerations,
             ),
             status=PodStatus(
                 start_time=self.status.start_time,
@@ -393,6 +556,8 @@ class Pod:
     def from_dict(d: Dict[str, Any]) -> "Pod":
         spec = d.get("spec") or {}
         resources = spec.get("resources") or {}
+        placement = _snake(spec)
+        affinity = _snake(placement.get("affinity"))
         return Pod(
             metadata=ObjectMeta.from_dict(d.get("metadata")),
             spec=PodSpec(
@@ -410,6 +575,13 @@ class Pod:
                     or spec.get("topologySpreadConstraints")
                     or []
                 ],
+                node_selector={
+                    str(k): str(v) for k, v in (placement.get("node_selector") or {}).items()
+                },
+                node_affinity=NodeAffinity.from_dict(
+                    affinity.get("node_affinity") or placement.get("node_affinity")
+                ),
+                tolerations=[Toleration.from_dict(t) for t in placement.get("tolerations") or []],
             ),
         )
 
@@ -434,6 +606,12 @@ class Pod:
             spec["topology_spread_constraints"] = [
                 c.to_dict() for c in self.spec.topology_spread_constraints
             ]
+        if self.spec.node_selector:
+            spec["node_selector"] = dict(self.spec.node_selector)
+        if self.spec.node_affinity is not None:
+            spec["affinity"] = {"node_affinity": self.spec.node_affinity.to_dict()}
+        if self.spec.tolerations:
+            spec["tolerations"] = [t.to_dict() for t in self.spec.tolerations]
         return {"metadata": self.metadata.to_dict(), "spec": spec}
 
 

@@ -51,6 +51,9 @@ from jax.experimental.pallas import tpu as pltpu
 from kubernetriks_tpu.batched.pipeline import (
     DEFAULT_PROFILE,
     SPREAD_ZONE_TILE,
+    NodeFacts,
+    affinity_names_nodes,
+    affinity_node_masks,
     exact_best_node,
     exact_least_allocated_key,
     profile_fit_mask,
@@ -128,17 +131,31 @@ def _spread_table_rows(spread_shape) -> int:
     return (3 * spread_shape[0] + 2) * SPREAD_ZONE_TILE
 
 
-def kernel_fits(n_nodes: int, k_pods: int, spread_shape=None) -> bool:
+def _affinity_blocks(affinity_terms) -> Tuple[int, int]:
+    """(node blocks, pod- or candidate-shaped blocks in) the label filters add
+    to a decision kernel of a build with `affinity_terms` term planes (None:
+    a build without them): the node plane; the term planes and the
+    untolerated-taint plane."""
+    if affinity_terms is None:
+        return 0, 0
+    return 1, affinity_terms + 1
+
+
+def kernel_fits(n_nodes: int, k_pods: int, spread_shape=None, affinity_terms=None) -> bool:
     """Whether one grid program's VMEM blocks (3 node blocks in and 2 out of
     (Np, 128), 3 candidate blocks in and 3 out of (Kp, 128), all int32; with
     the spread filter one node block, four candidate blocks and the table
-    more) fit the budget; callers fall back to the lax.scan
-    formulation when they don't."""
+    more; with the label filters one node block, their candidate planes and
+    one candidate block out more) fit the budget; callers fall back to the
+    lax.scan formulation when they don't."""
     np_pad = -(-n_nodes // _SUB) * _SUB
     kp_pad = -(-k_pods // _SUB) * _SUB
     s = int(spread_shape is not None)
+    a_node, a_side = _affinity_blocks(affinity_terms)
     resident = (
-        (5 + s) * np_pad + (6 + 4 * s) * kp_pad + _spread_table_rows(spread_shape)
+        (5 + s + a_node) * np_pad
+        + (6 + 4 * s + a_side + a_node) * kp_pad
+        + _spread_table_rows(spread_shape)
     ) * _LANE * 4
     return resident <= _VMEM_BUDGET_BYTES
 
@@ -206,7 +223,33 @@ def _spread_results(spread_o, spread_shape, K: int, C: int):
     return (zbest[:K, :C].T, flags[:K, :C].T, counts)
 
 
-def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, spread=None):
+def _affinity_operands(affinity, nodes_lane_major: bool, Np: int, Cp: int, side_rows: int, node_spec, side_spec):
+    """What a decision kernel's wrapper adds to its pallas_call for the
+    NodeAffinity and TaintToleration filters: (the number of term planes, the
+    padded operands, their in_specs), (None, (), []) for `affinity` None.
+    `affinity` = (node_bits (C, N) | (N, C), then the pods' or candidates'
+    term planes and last their untolerated-taint plane, `side_rows` rows in
+    the kernel layout). Padded nodes carry no bit; padded rows and lanes hold
+    a pod that names no node."""
+    if affinity is None:
+        return None, (), []
+    node_bits, *side = affinity
+
+    def prep(x):
+        return _pad_axis(_pad_axis(x.astype(jnp.int32).T, 0, side_rows, 0), 1, Cp, 0)
+
+    args = (_prep_node(node_bits, nodes_lane_major, Np, Cp, 0), *(prep(x) for x in side))
+    return len(side) - 1, args, [node_spec] + [side_spec] * len(side)
+
+
+def _affinity_step(node_bits_ref, side):
+    """The `affinity` argument of _fit_score_place: the node plane and the
+    candidate's (1, LC) term masks and untolerated-taint mask."""
+    *terms, forbid = side
+    return node_bits_ref[:], terms, forbid
+
+
+def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, spread=None, affinity=None):
     """ONE in-kernel definition of the per-candidate decision core shared by
     _cycle_kernel, _select_cycle_kernel and _select_cycle_commit_kernel:
     the compiled profile's filter mask + weighted score
@@ -219,30 +262,43 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, s
     expressions inline into the kernel body like the shape statics do.
     Inputs: (Np, LC) node tiles, (1, LC) candidate requests/validity.
     Returns (assign (1, LC) bool, any_fit (1, LC) bool, best (1, LC) i32,
-    new_cpu (Np, LC), new_ram (Np, LC)).
+    new_cpu (Np, LC), new_ram (Np, LC), placed, named): the last two None
+    without `spread` / `affinity`.
 
     `spread` (a build whose pods are held to topology-spread constraints;
     pipeline.spread_*) = (domain (Np, LC) node plane, the count table's G
     tiles, the limits' G tiles, zone_alive (8, LC) bool, n_domains, the
     candidate's workload and match bits (1, LC)): the SECOND thing the core
     carries across a cycle's placements. The table is read by this
-    candidate's filter and returned with its placement added; a sixth result
-    then follows the five: (new tiles, the placed node's domain (1, LC),
-    assigned & constrained, assigned & a live domain was closed)."""
+    candidate's filter and returned with its placement added: `placed` =
+    (new tiles, the placed node's domain (1, LC), assigned & constrained,
+    assigned & a live domain was closed).
+
+    `affinity` (a build with a taint, a selector, an affinity or a
+    toleration; pipeline.affinity_node_masks) = (node_bits (Np, LC) node
+    plane, the candidate's term masks, its untolerated-taint mask, (1, LC)
+    each). Nothing is carried: `named` = (the candidate names its nodes and
+    is valid, and of those: no node passed the chain although a live node
+    passed every filter but the two label filters), (1, LC) bool each."""
     i0 = jnp.int32(0)
     neg1 = jnp.int32(-1)
 
-    spread_ok = None
+    facts = None
     if spread is not None:
         domain, tiles, limits, zone_alive, n_domains, group, bits = spread
         zone_ok, constrained, closed = spread_zone_ok(tiles, limits, zone_alive, group, bits)
-        spread_ok = spread_node_mask(domain, zone_ok, constrained, n_domains)
+        facts = NodeFacts(spread_ok=spread_node_mask(domain, zone_ok, constrained, n_domains))
+    if affinity is not None:
+        node_bits, terms, forbid = affinity
+        rest = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
+        affinity_ok, taints_ok = affinity_node_masks(node_bits, terms, forbid)
+        facts = (facts or NodeFacts())._replace(affinity_ok=affinity_ok, taints_ok=taints_ok)
     if profile.exact_bits:
-        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, spread_ok)
+        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
         hi, lo = exact_least_allocated_key(fit, cpu, ram, rc, rr, profile.exact_bits)
         best = exact_best_node(hi, lo, node_ok, iota_n, axis=0)
     else:
-        fit, score = profile_fit_score(profile, alive, cpu, ram, rc, rr, spread_ok)
+        fit, score = profile_fit_score(profile, alive, cpu, ram, rc, rr, facts)
         max_score = jnp.max(score, axis=0, keepdims=True)
         best = jnp.max(
             jnp.where((score == max_score) & node_ok, iota_n, neg1),
@@ -256,11 +312,15 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, s
     upd = assign & (iota_n == best)
     new_cpu = cpu - jnp.where(upd, rc, i0)
     new_ram = ram - jnp.where(upd, rr, i0)
-    if spread is None:
-        return assign, any_fit, best, new_cpu, new_ram
-    zbest = jnp.max(jnp.where(upd, domain, neg1), axis=0, keepdims=True)
-    placed = (spread_place(tiles, zbest, assign, bits), zbest, assign & constrained, assign & closed)
-    return assign, any_fit, best, new_cpu, new_ram, placed
+    placed = named = None
+    if spread is not None:
+        zbest = jnp.max(jnp.where(upd, domain, neg1), axis=0, keepdims=True)
+        placed = (spread_place(tiles, zbest, assign, bits), zbest, assign & constrained, assign & closed)
+    if affinity is not None:
+        attempt = valid & affinity_names_nodes(forbid)
+        any_rest = jnp.max(rest.astype(jnp.int32), axis=0, keepdims=True) > i0
+        named = (attempt, attempt & ~any_fit & any_rest)
+    return assign, any_fit, best, new_cpu, new_ram, placed, named
 
 
 def _spread_step(refs, n_workloads: int, n_domains: int, group, bits):
@@ -284,6 +344,13 @@ def _spread_store(table_ref, tiles) -> None:
         table_ref[g * SPREAD_ZONE_TILE : (g + 1) * SPREAD_ZONE_TILE, :] = tile
 
 
+def _named_flags(named):
+    """A decision's label-filter facts as one int32: bit 0 the candidate
+    names its nodes, bit 1 the labels and taints alone refused it."""
+    attempt, refused = named
+    return attempt.astype(jnp.int32) + jnp.int32(2) * refused.astype(jnp.int32)
+
+
 def _spread_store_decision(table_ref, zbest_out, sflag_out, k, placed) -> None:
     """Candidate k's spread results of _fit_score_place into the kernel's
     outputs: the table, the placed domain, and the two facts as one int32
@@ -302,6 +369,7 @@ def _cycle_kernel(
     k_pods: int,
     profile,        # pipeline.CompiledProfile (kernel static)
     spread_shape,   # (G, Z) static, None without the spread filter
+    affinity_terms,  # static number of term planes, None without the label filters
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -312,13 +380,20 @@ def _cycle_kernel(
 ):
     # refs: with the spread filter the inputs domain (Np, LC), table and
     # limits (G*8, LC), live domains (8, LC), the candidates' workload and
-    # match bits (Kp, LC); then the outputs cpu, ram (Np, LC), assign,
-    # fitany, best (Kp, LC); with the filter the carried table (G*8, LC) and
-    # the candidates' placed domain and flags (Kp, LC).
+    # match bits (Kp, LC); with the label filters node_bits (Np, LC), the
+    # candidates' term masks and untolerated taints (Kp, LC each); then the
+    # outputs cpu, ram (Np, LC), assign, fitany, best (Kp, LC); with the
+    # spread filter the carried table (G*8, LC) and the candidates' placed
+    # domain and flags (Kp, LC); with the label filters their flags (Kp, LC).
     if spread_shape is not None:
         domain_ref, table_in, limit_ref, zalive_ref, cgroup_ref, cbits_ref = refs[:6]
         refs = refs[6:]
-        table_out, zbest_out, sflag_out = refs[5:]
+    if affinity_terms is not None:
+        node_bits_ref, *aside_refs = refs[: affinity_terms + 2]
+        refs = refs[affinity_terms + 2 :]
+        aflag_out = refs[-1]
+    if spread_shape is not None:
+        table_out, zbest_out, sflag_out = refs[5:8]
     cpu_out, ram_out, assign_out, fitany_out, best_out = refs[:5]
     # All literals are explicitly typed: with jax_enable_x64 on (the batched
     # path's time arrays are f64), bare Python scalars trace as weak i64/f64
@@ -339,6 +414,8 @@ def _cycle_kernel(
         table_out[:] = table_in[:]
         zbest_out[:] = jnp.zeros_like(zbest_out)
         sflag_out[:] = jnp.zeros_like(sflag_out)
+    if affinity_terms is not None:
+        aflag_out[:] = jnp.zeros_like(aflag_out)
 
     # The loop only needs to reach the tile's last valid candidate — a
     # data-dependent early exit the lax.scan formulation cannot express.
@@ -361,12 +438,17 @@ def _cycle_kernel(
                 (domain_ref, table_out, limit_ref, zalive_ref), *spread_shape,
                 cgroup_ref[pl.ds(k, 1), :], cbits_ref[pl.ds(k, 1), :],
             )
-        assign, any_fit, best, new_cpu, new_ram, *placed = _fit_score_place(
+        affinity = None
+        if affinity_terms is not None:
+            affinity = _affinity_step(node_bits_ref, [ref[pl.ds(k, 1), :] for ref in aside_refs])
+        assign, any_fit, best, new_cpu, new_ram, placed, named = _fit_score_place(
             profile, alive, node_ok, iota, cpu_out[:], ram_out[:],
-            req_cpu, req_ram, valid, spread,
+            req_cpu, req_ram, valid, spread, affinity,
         )
-        if placed:
-            _spread_store_decision(table_out, zbest_out, sflag_out, k, placed[0])
+        if placed is not None:
+            _spread_store_decision(table_out, zbest_out, sflag_out, k, placed)
+        if named is not None:
+            aflag_out[pl.ds(k, 1), :] = _named_flags(named)
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         assign_out[pl.ds(k, 1), :] = assign.astype(jnp.int32)
@@ -389,12 +471,15 @@ def _cycle_kernel(
 _SELECT_VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def select_kernel_fits(n_nodes: int, n_pods: int, k_pods: int, spread_shape=None) -> bool:
+def select_kernel_fits(
+    n_nodes: int, n_pods: int, k_pods: int, spread_shape=None, affinity_terms=None
+) -> bool:
     """Whether the selection+cycle kernel's VMEM blocks fit: 6 pod blocks of
     (Pp, 128) in + 1 pod scratch, 3 node blocks in + 2 out, 5 candidate
     output blocks, all int32, double-buffered across grid programs by
     Mosaic; with the spread filter one node block, two pod blocks, two
-    candidate output blocks and the table more. The pod blocks dominate; the budget is more
+    candidate output blocks and the table more; with the label filters one
+    node block, their pod planes and one candidate output block more. The pod blocks dominate; the budget is more
     generous than the candidate kernel's because this kernel REPLACES the
     (C, P) lexsort and gathers, so its win grows with P (v5e VMEM is
     ~128 MiB/core)."""
@@ -402,8 +487,11 @@ def select_kernel_fits(n_nodes: int, n_pods: int, k_pods: int, spread_shape=None
     pp_pad = -(-n_pods // _SUB) * _SUB
     kp_pad = -(-k_pods // _SUB) * _SUB
     s = int(spread_shape is not None)
+    a_node, a_side = _affinity_blocks(affinity_terms)
     resident = (
-        (5 + s) * np_pad + (7 + 2 * s) * pp_pad + (5 + 2 * s) * kp_pad
+        (5 + s + a_node) * np_pad
+        + (7 + 2 * s + a_side) * pp_pad
+        + (5 + 2 * s + a_node) * kp_pad
         + _spread_table_rows(spread_shape)
     ) * _LANE * 4
     return 2 * resident <= int(0.8 * _SELECT_VMEM_LIMIT)
@@ -414,6 +502,7 @@ def _select_cycle_kernel(
     k_pods: int,
     profile,        # pipeline.CompiledProfile (kernel static)
     spread_shape,   # (G, Z) static, None without the spread filter
+    affinity_terms,  # static number of term planes, None without the label filters
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -428,13 +517,21 @@ def _select_cycle_kernel(
 ):
     # refs: with the spread filter the inputs domain (Np, LC), table and
     # limits (G*8, LC), live domains (8, LC), the pods' workload and match
-    # bits (Pp, LC); then the outputs cpu, ram (Np, LC), cand (the selected
-    # pod slot), valid, assign, fitany, best (Kp, LC); with the filter the
-    # carried table (G*8, LC) and the decisions' placed domain and flags
-    # (Kp, LC); last the scratch rem (Pp, LC): not-yet-selected eligible pods.
+    # bits (Pp, LC); with the label filters node_bits (Np, LC), the pods'
+    # term masks and untolerated taints (Pp, LC each); then the outputs cpu,
+    # ram (Np, LC), cand (the selected pod slot), valid, assign, fitany, best
+    # (Kp, LC); with the spread filter the carried table (G*8, LC) and the
+    # decisions' placed domain and flags (Kp, LC); with the label filters
+    # their flags (Kp, LC); last the scratch rem (Pp, LC): not-yet-selected
+    # eligible pods.
     if spread_shape is not None:
         domain_ref, table_in, limit_ref, zalive_ref, pgroup_ref, pbits_ref = refs[:6]
         refs = refs[6:]
+    if affinity_terms is not None:
+        node_bits_ref, *aside_refs = refs[: affinity_terms + 2]
+        refs = refs[affinity_terms + 2 :]
+        aflag_out = refs[-2]
+    if spread_shape is not None:
         table_out, zbest_out, sflag_out = refs[7:10]
     cpu_out, ram_out, cand_out, valid_out, assign_out, fitany_out, best_out = refs[:7]
     rem_ref = refs[-1]
@@ -467,6 +564,8 @@ def _select_cycle_kernel(
         table_out[:] = table_in[:]
         zbest_out[:] = jnp.zeros_like(zbest_out)
         sflag_out[:] = jnp.zeros_like(sflag_out)
+    if affinity_terms is not None:
+        aflag_out[:] = jnp.zeros_like(aflag_out)
 
     iota_p = jax.lax.broadcasted_iota(jnp.int32, elig_ref.shape, 0)
     # Early exit: the deepest per-lane queue in this tile bounds the loop.
@@ -499,12 +598,22 @@ def _select_cycle_kernel(
                 jnp.max(jnp.where(sel, pgroup_ref[:], neg1), axis=0, keepdims=True),
                 jnp.max(seli * pbits_ref[:], axis=0, keepdims=True),
             )
-        assign, any_fit, best, new_cpu, new_ram, *placed = _fit_score_place(
+        affinity = None
+        if affinity_terms is not None:
+            # The chosen row's masks: a one-hot sum (int32 wraps, and one
+            # row a lane is chosen, so the sum is that row's word).
+            affinity = _affinity_step(
+                node_bits_ref,
+                [jnp.sum(jnp.where(sel, ref[:], i0), axis=0, keepdims=True) for ref in aside_refs],
+            )
+        assign, any_fit, best, new_cpu, new_ram, placed, named = _fit_score_place(
             profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid, spread,
+            rc, rr, valid, spread, affinity,
         )
-        if placed:
-            _spread_store_decision(table_out, zbest_out, sflag_out, k, placed[0])
+        if placed is not None:
+            _spread_store_decision(table_out, zbest_out, sflag_out, k, placed)
+        if named is not None:
+            aflag_out[pl.ds(k, 1), :] = _named_flags(named)
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         cand_out[pl.ds(k, 1), :] = jnp.where(valid, slot, i0)
@@ -540,6 +649,7 @@ def fused_select_schedule_cycle(
     nodes_lane_major: bool = False,
     profile=None,  # pipeline.CompiledProfile; None = the default profile
     spread=None,  # (domain, counts, limits, zone_alive, pod group, pod bits)
+    affinity=None,  # (node_bits, the pods' term planes..., their untolerated taints)
 ):
     """Fused selection + scheduling loop in VMEM.
 
@@ -552,7 +662,9 @@ def fused_select_schedule_cycle(
     at this boundary — see _prep_node). With `spread` (_spread_operands: the
     filter's four operands and the pods' (C, P) workload and match bits) three
     more follow: each decision's placed domain and spread flags, (C, K)
-    int32, and the count table after the launch, (C, G, Z)."""
+    int32, and the count table after the launch, (C, G, Z). With `affinity`
+    (_affinity_operands) one more follows, last: each decision's label-filter
+    flags, (C, K) int32 (_named_flags)."""
     C, P = eligible.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
     K = k_pods
@@ -585,19 +697,25 @@ def fused_select_schedule_cycle(
         spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
             spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
         )
+        affinity_terms, affinity_args, affinity_in = _affinity_operands(
+            affinity, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, cand_spec, cand_spec]
         spread_shapes = [table_shape] + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)] * 2
+    if affinity is not None:
+        spread_out = spread_out + [cand_spec]
+        spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)]
     kernel = functools.partial(
-        _select_cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape
+        _select_cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms
     )
     with jax.enable_x64(False):
         cpu_o, ram_o, cand_o, valid_o, assign_o, fitany_o, best_o, *spread_o = pl.pallas_call(
             kernel,
             name="fused_select_schedule_cycle",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec] * 3 + [pod_spec] * 6 + spread_in,
+            in_specs=[node_spec] * 3 + [pod_spec] * 6 + spread_in + affinity_in,
             out_specs=[node_spec] * 2 + [cand_spec] * 5 + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -614,9 +732,13 @@ def fused_select_schedule_cycle(
                 vmem_limit_bytes=_SELECT_VMEM_LIMIT
             ),
             interpret=interpret,
-        )(alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p, reqc_p, reqr_p, *spread_args)
+        )(
+            alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p, reqc_p, reqr_p,
+            *spread_args, *affinity_args,
+        )
 
     with jax.named_scope("kernel_io"):
+        named_o = (spread_o.pop()[:K, :C].T,) if affinity is not None else ()
         return (
             cand_o[:K, :C].T,
             valid_o[:K, :C].T != 0,
@@ -626,6 +748,7 @@ def fused_select_schedule_cycle(
             _unprep_node(cpu_o, nodes_lane_major, N, C),
             _unprep_node(ram_o, nodes_lane_major, N, C),
             *_spread_results(spread_o, spread_shape, K, C),
+            *named_o,
         )
 
 
@@ -1395,6 +1518,7 @@ def fused_schedule_cycle(
     nodes_lane_major: bool = False,
     profile=None,  # pipeline.CompiledProfile; None = the default profile
     spread=None,  # (domain, counts, limits, zone_alive, cand group, cand bits)
+    affinity=None,  # (node_bits, the candidates' term masks..., their untolerated taints)
 ):
     """Run the K-pod scheduling loop in VMEM.
 
@@ -1405,7 +1529,9 @@ def fused_schedule_cycle(
     (_spread_operands: the topology-spread filter's four operands and the
     candidates' (C, K) workload and match bits) three more follow: the placed
     node's domain and the decision's spread flags, (C, K) int32 each, and the
-    count table after the launch, (C, G, Z).
+    count table after the launch, (C, G, Z). With `affinity`
+    (_affinity_operands) one more follows, last: each decision's label-filter
+    flags, (C, K) int32 (_named_flags).
     """
     C, K = valid.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
@@ -1432,11 +1558,19 @@ def fused_schedule_cycle(
         spread_shape, spread_args, spread_in, table_spec, _, table_shape = _spread_operands(
             spread, nodes_lane_major, Np, Cp, Kp, node_spec, cand_spec
         )
+        affinity_terms, affinity_args, affinity_in = _affinity_operands(
+            affinity, nodes_lane_major, Np, Cp, Kp, node_spec, cand_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, cand_spec, cand_spec]
         spread_shapes = [table_shape] + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)] * 2
-    kernel = functools.partial(_cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape)
+    if affinity is not None:
+        spread_out = spread_out + [cand_spec]
+        spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)]
+    kernel = functools.partial(
+        _cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms
+    )
     # Trace the kernel with x64 semantics OFF: the batched path enables
     # jax_enable_x64 for its f64 time arrays, but under x64 pallas_call's own
     # index bookkeeping traces as i64, which Mosaic fails to legalize
@@ -1446,7 +1580,9 @@ def fused_schedule_cycle(
             kernel,
             name="fused_schedule_cycle",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec, node_spec, node_spec, cand_spec, cand_spec, cand_spec] + spread_in,
+            in_specs=[node_spec, node_spec, node_spec, cand_spec, cand_spec, cand_spec]
+            + spread_in
+            + affinity_in,
             out_specs=[node_spec, node_spec, cand_spec, cand_spec, cand_spec] + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -1457,9 +1593,10 @@ def fused_schedule_cycle(
             ]
             + spread_shapes,
             interpret=interpret,
-        )(alive_p, cpu_p, ram_p, valid_p, reqc_p, reqr_p, *spread_args)
+        )(alive_p, cpu_p, ram_p, valid_p, reqc_p, reqr_p, *spread_args, *affinity_args)
 
     with jax.named_scope("kernel_io"):
+        named_o = (spread_o.pop()[:K, :C].T,) if affinity is not None else ()
         return (
             assign_o[:K, :C].T != 0,
             fitany_o[:K, :C].T != 0,
@@ -1467,23 +1604,35 @@ def fused_schedule_cycle(
             _unprep_node(cpu_o, nodes_lane_major, N, C),
             _unprep_node(ram_o, nodes_lane_major, N, C),
             *_spread_results(spread_o, spread_shape, K, C),
+            *named_o,
         )
 
 
 # --- round-4 megakernel: selection + cycle + commit in ONE launch -----------
 
-def select_commit_kernel_fits(n_nodes: int, n_pods: int, k_pods: int, spread_shape=None) -> bool:
+def select_commit_kernel_fits(
+    n_nodes: int, n_pods: int, k_pods: int, spread_shape=None, affinity_terms=None
+) -> bool:
     """VMEM budget for the megakernel: 3 node blocks in + 2 out, 9 pod
     blocks in + 4 out + 1 scratch, 3 K-shaped blocks (fused_select_cycle_commit
     says what is left of them) and the (8, LANE) stats block; with the spread filter one node block, two pod blocks in and one
     out, and the table, its limits, the live domains and a stats tile more;
-    double-buffered by Mosaic (~2x block bytes)."""
+    with the label filters one node block, their pod planes and a stats tile
+    more; double-buffered by Mosaic (~2x block bytes)."""
     Np = -(-n_nodes // _SUB) * _SUB
     Pp = -(-n_pods // _SUB) * _SUB
     Kp = -(-k_pods // _SUB) * _SUB
     s = int(spread_shape is not None)
+    a_node, a_side = _affinity_blocks(affinity_terms)
     per_lane_bytes = (
-        2 * ((5 + s) * Np + (14 + 3 * s) * Pp + 3 * Kp + 8 + _spread_table_rows(spread_shape))
+        2
+        * (
+            (5 + s + a_node) * Np
+            + (14 + 3 * s + a_side) * Pp
+            + 3 * Kp
+            + 8 * (1 + a_node)
+            + _spread_table_rows(spread_shape)
+        )
         * 4 * _LANE
     )
     return per_lane_bytes <= int(_SELECT_VMEM_LIMIT * 0.8)
@@ -1519,6 +1668,7 @@ def _select_cycle_commit_kernel(
     k_pods: int,
     profile,        # pipeline.CompiledProfile (kernel static)
     spread_shape,   # (G, Z) static, None without the spread filter
+    affinity_terms,  # static number of term planes, None without the label filters
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -1539,7 +1689,8 @@ def _select_cycle_commit_kernel(
 ):
     # refs: with the spread filter the inputs domain (Np, LC), table and
     # limits (G*8, LC), live domains (8, LC), the pods' workload and match
-    # bits (Pp, LC); then the outputs
+    # bits (Pp, LC); with the label filters node_bits (Np, LC), the pods'
+    # term masks and untolerated taints (Pp, LC each); then the outputs
     #   cpu_out, ram_out      (Np, LC) int32
     #   phase_out, node_out   (Pp, LC) int32
     #   start_out, park_out   (Pp, LC) float32 (+inf = untouched)
@@ -1551,10 +1702,18 @@ def _select_cycle_commit_kernel(
     # with the filter the carried table (G*8, LC), zone_out (Pp, LC) int32
     # the placed node's domain (-2 = untouched) and sstats_out (8, LC) int32
     # (row 0 assignments of constrained pods, row 1 those with a live domain
-    # closed); last the scratches rem (Pp, LC) and live (SMEM, row tiles).
+    # closed); with the label filters astats_out (8, LC) int32 (row 0 the
+    # attempts of pods that name their nodes, row 1 those of them that the
+    # labels and taints alone refused); last the scratches rem (Pp, LC) and
+    # live (SMEM, row tiles).
     if spread_shape is not None:
         domain_ref, table_in, limit_ref, zalive_ref, pgroup_ref, pbits_ref = refs[:6]
         refs = refs[6:]
+    if affinity_terms is not None:
+        node_bits_ref, *aside_refs = refs[: affinity_terms + 2]
+        refs = refs[affinity_terms + 2 :]
+        astats_out = refs[-3]
+    if spread_shape is not None:
         table_out, zone_out, sstats_out = refs[7:10]
     cpu_out, ram_out, phase_out, node_out, start_out, park_out, stats_out = refs[:7]
     rem_ref, live_ref = refs[-2:]
@@ -1613,6 +1772,8 @@ def _select_cycle_commit_kernel(
         table_out[:] = table_in[:]
         zone_out[:] = jnp.full_like(zone_out, jnp.int32(-2))
         sstats_out[:] = jnp.zeros_like(sstats_out)
+    if affinity_terms is not None:
+        astats_out[:] = jnp.zeros_like(astats_out)
 
     alive = alive_ref[:] != i0
     iota_n = jax.lax.broadcasted_iota(jnp.int32, alive.shape, 0)
@@ -1634,9 +1795,16 @@ def _select_cycle_commit_kernel(
         # The chosen pod's workload and match bits come back with its
         # requests: the same sweep, two more blocks read.
         carried += ((pgroup_ref, jnp.int32(-1)), (pbits_ref, i0))
+    n_spread = len(carried) - 3
+    if affinity_terms is not None:
+        # And its term masks and untolerated taints. The masks use bit 31,
+        # so the fill is the least int32 (_select_first takes the chosen
+        # row's value as a maximum over the fill); a lane with nothing left
+        # reads it, and is not valid.
+        carried += tuple((ref, jnp.int32(-(2**31))) for ref in aside_refs)
 
     def body(k, before):
-        slot, (rc, rr, waited, *pod_spread) = _select_first(
+        slot, (rc, rr, waited, *pod_planes) = _select_first(
             n_live,
             live_ref,
             n_rows,
@@ -1649,17 +1817,24 @@ def _select_cycle_commit_kernel(
         spread = None
         if spread_shape is not None:
             spread = _spread_step(
-                (domain_ref, table_out, limit_ref, zalive_ref), *spread_shape, *pod_spread
+                (domain_ref, table_out, limit_ref, zalive_ref), *spread_shape,
+                *pod_planes[:n_spread],
             )
-        assign, any_fit, best, new_cpu, new_ram, *placed = _fit_score_place(
+        affinity = None
+        if affinity_terms is not None:
+            affinity = _affinity_step(node_bits_ref, pod_planes[n_spread:])
+        assign, any_fit, best, new_cpu, new_ram, placed, named = _fit_score_place(
             profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid, spread,
+            rc, rr, valid, spread, affinity,
         )
-        if placed:
-            tiles, zbest, constrained, closed = placed[0]
+        if placed is not None:
+            tiles, zbest, constrained, closed = placed
             _spread_store(table_out, tiles)
             sstats_out[0:1, :] = sstats_out[0:1, :] + constrained.astype(jnp.int32)
             sstats_out[1:2, :] = sstats_out[1:2, :] + closed.astype(jnp.int32)
+        if named is not None:
+            astats_out[0:1, :] = astats_out[0:1, :] + named[0].astype(jnp.int32)
+            astats_out[1:2, :] = astats_out[1:2, :] + named[1].astype(jnp.int32)
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         park = valid & ~any_fit
@@ -1680,7 +1855,7 @@ def _select_cycle_commit_kernel(
             start_out[rows, :] = jnp.where(sel & assign, after, start_out[rows, :])
             park_out[rows, :] = jnp.where(sel & park, after, park_out[rows, :])
             rem_ref[rows, :] = jnp.where(sel, i0, rem_ref[rows, :])
-            if placed:
+            if placed is not None:
                 zone_out[rows, :] = jnp.where(sel & assign, zbest, zone_out[rows, :])
 
         _sweep_live_tiles(n_live, live_ref, n_rows, commit)
@@ -1734,6 +1909,7 @@ def fused_select_cycle_commit(
     nodes_lane_major: bool = False,
     profile=None,  # pipeline.CompiledProfile; None = the default profile
     spread=None,  # (domain, counts, limits, zone_alive, pod group, pod bits)
+    affinity=None,  # (node_bits, the pods' term planes..., their untolerated taints)
 ):
     """Megakernel wrapper: the whole cycle, drained, in one launch. Of the
     three K-shaped operands it reads park_t[:, 0], a cluster's per-pod
@@ -1753,7 +1929,9 @@ def fused_select_cycle_commit(
     the pods' (C, P) workload and match bits) two more follow: the placed node's
     domain a pod, (C, P) int32 with -2 where the cycle placed nothing, and
     the (C, 2) counters (assignments of constrained pods, those with a live
-    domain closed)."""
+    domain closed). With `affinity` (_affinity_operands) one more follows,
+    last: the (C, 2) counters (attempts of pods that name their nodes, those
+    of them that the labels and taints alone refused)."""
     C, P = eligible.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
     K = k_pods
@@ -1794,6 +1972,9 @@ def fused_select_cycle_commit(
         spread_shape, spread_args, spread_in, table_spec, tile_spec, table_shape = _spread_operands(
             spread, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
         )
+        affinity_terms, affinity_args, affinity_in = _affinity_operands(
+            affinity, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, pod_spec, tile_spec]
@@ -1802,15 +1983,18 @@ def fused_select_cycle_commit(
             jax.ShapeDtypeStruct((Pp, Cp), jnp.int32),
             jax.ShapeDtypeStruct((SPREAD_ZONE_TILE, Cp), jnp.int32),
         ]
+    if affinity is not None:
+        spread_out = spread_out + [stat_spec]
+        spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((8, Cp), jnp.int32)]
     kernel = functools.partial(
-        _select_cycle_commit_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape
+        _select_cycle_commit_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms
     )
     with jax.enable_x64(False):
         (cpu_o, ram_o, phase_o, node_o, start_o, park_o, stats_o, *spread_o) = pl.pallas_call(
             kernel,
             name="fused_select_cycle_commit",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec] * 3 + [pod_spec] * 9 + [cand_spec] * 3 + spread_in,
+            in_specs=[node_spec] * 3 + [pod_spec] * 9 + [cand_spec] * 3 + spread_in + affinity_in,
             out_specs=[node_spec] * 2 + [pod_spec] * 4 + [stat_spec] + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -1833,10 +2017,11 @@ def fused_select_cycle_commit(
         )(
             alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p,
             reqc_p, reqr_p, waited_p, phase_p, node_p,
-            park_p, park_p, park_p, *spread_args,
+            park_p, park_p, park_p, *spread_args, *affinity_args,
         )
 
     with jax.named_scope("kernel_io"):
+        named_o = (spread_o.pop()[:2, :C].T,) if affinity is not None else ()
         return (
             _unprep_node(cpu_o, nodes_lane_major, N, C),
             _unprep_node(ram_o, nodes_lane_major, N, C),
@@ -1846,4 +2031,5 @@ def fused_select_cycle_commit(
             park_o[:P, :C].T,
             stats_o[:, :C].T,
             *((spread_o[1][:P, :C].T, spread_o[2][:2, :C].T) if spread_o else ()),
+            *named_o,
         )

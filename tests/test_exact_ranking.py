@@ -113,6 +113,19 @@ def test_which_builds_rank_exactly():
     assert bits(DEFAULT_PROFILE, [], []) == 0
 
 
+@pytest.mark.parametrize("name", ["default", "topology_spread", "node_pools"])
+def test_the_key_goes_by_the_scores_not_the_filters(name, caplog):
+    """A profile whose scores are LeastAllocatedResources at weight 1 gets the
+    exact key over mixed capacities whatever its filters, and says nothing;
+    the key ranks the nodes the chain lets through."""
+    pools_pods = [(np.array([500, 4000, 8000]), np.array([1024, 49152, 98304]))]
+    pools_nodes = [(np.array([64000, 64000, 96000, 32000]), np.array([131072, 262144, 196608, 65536]))]
+    profile = pipeline.compile_profile(name)
+    with caplog.at_level("WARNING", logger=pipeline.__name__):
+        assert pipeline.exact_score_bits(profile, pools_pods, pools_nodes) == 12
+    assert not caplog.records
+
+
 def test_a_build_that_needs_the_exact_key_and_cannot_have_it_says_so(caplog):
     bits = pipeline.exact_score_bits
     replay_pods = [(np.array([500, 4000, 64000]), np.array([64, 1000, 4095]))]

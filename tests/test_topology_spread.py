@@ -467,10 +467,10 @@ def test_unsupported_profile_message_lists_the_registry():
     from kubernetriks_tpu.batched.pipeline import DEVICE_FILTER_PLUGINS, compile_profile
 
     with pytest.raises(UnsupportedProfileError) as err:
-        compile_profile({"filters": ["Fit", "NodeAffinity"], "score": []})
+        compile_profile({"filters": ["Fit", "InterPodAffinity"], "score": []})
     for name in DEVICE_FILTER_PLUGINS:
         assert name in str(err.value)
-    assert "PodTopologySpread" in str(err.value)
+    assert "PodTopologySpread" in str(err.value) and "'InterPodAffinity'" in str(err.value)
 
 
 # --- (e) a build without constraints compiles what it compiled -----------------
