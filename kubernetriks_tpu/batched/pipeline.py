@@ -509,26 +509,62 @@ def exact_score_bits(profile: CompiledProfile, requests, capacities) -> int:
     return 0
 
 
-def _quotient_digits(num, den, bits: int):
-    """The first _EXACT_DIGITS base-2**bits digits of num / den after the
-    point, for int32 0 <= num <= den < 2**(31 - bits): long division, each
-    digit estimated in float32 and corrected by its integer remainder, so
-    the digits are exact whatever the backend's division rounds to (the
-    estimate is within one of the digit; int32 wraps, and the remainder
-    that is kept is exact modulo 2**32 and lies inside (-den, 2 den))."""
-    one = jnp.int32(1)
-    den_f = jnp.where(den > _zero(den), den, one).astype(jnp.float32)
+_ESTIMATE_BIAS = 1.0 + 2.0**-16  # see _quotient_digits
+
+
+def _digits_by_reciprocal(num, den, inv, bits: int):
+    """_quotient_digits' long division, handed `inv`, its float32 estimate
+    of 2**bits (1 + 2**-16) / den (a parameter so that a test can hand it
+    one that is some ulp off)."""
     digits = []
     rem = num
     for _ in range(_EXACT_DIGITS):
-        shifted = rem << jnp.int32(bits)
-        digit = jnp.floor(shifted.astype(jnp.float32) / den_f).astype(jnp.int32)
-        rem = shifted - digit * den
-        low, high = rem < _zero(rem), rem >= den
-        digit = digit - low.astype(jnp.int32) + high.astype(jnp.int32)
-        rem = jnp.where(low, rem + den, jnp.where(high, rem - den, rem))
-        digits.append(digit)
+        estimate = (rem.astype(jnp.float32) * inv).astype(jnp.int32)
+        rem = (rem << jnp.int32(bits)) - estimate * den
+        over = rem >> jnp.int32(31)  # -1 where the estimate was one over, else 0
+        digits.append(estimate + over)
+        rem = rem + (den & over)
     return digits
+
+
+def _quotient_digits(num, den, bits: int):
+    """The first _EXACT_DIGITS base-2**bits digits of num / den after the
+    point, for int32 0 <= num <= den < 2**(31 - bits): long division with ONE
+    float32 division a denominator. Each digit is estimated as
+    trunc(float32(rem) * inv), inv = 2**bits (1 + 2**-16) / den, and
+    corrected downwards by the sign of its integer remainder, so the digits
+    are exact whatever the backend's division rounds to. Why one side is
+    enough:
+
+    - q = (rem << bits) / den, the quotient a digit is the floor of, lies in
+      [0, 2**bits], bits <= _EXACT_BITS_MAX = 14: the remainder carried into
+      a digit is under den (for the first, num <= den), so it is never
+      negative, the conversion's truncation is the floor, and rem << bits
+      <= den << bits < 2**31 does not wrap.
+    - The bias lifts the estimate by q 2**-16, at most a quarter of a digit.
+      Against it stand three roundings: rem <= den < 2**21 and the
+      numerator 2**bits + 2**(bits - 16) are exact in float32; the product
+      rounds by 2**-24 of its value, and inv by 2**-24 where the division is
+      correctly rounded (a backend that takes a reciprocal and multiplies
+      rounds twice). Even an inv 32 ulp off (2**-18) leaves the sum under
+      2**-17.7, well inside the bias, so estimate >= q before truncation,
+      and estimate < q (1 + 2**-16 + 2**-17.7) <= q + 0.33: truncated, it is
+      the digit or one over it, never under.
+    - So rem = (rem << bits) - estimate den lies in [-den, den) (the product
+      may pass 2**31 by less than den: int32 wraps and the difference is
+      exact), its sign alone says which, and `rem >> 31` (-1 or 0) corrects
+      both words without a compare: the digit by adding it, the remainder by
+      adding den under it as a mask. num == den (a pod that fills a node:
+      q = 2**bits, an integer the estimate stays within a quarter above)
+      takes the same path: digit 2**bits, remainder 0.
+
+    Where the precondition does not hold (a node the pod does not fit, or
+    one with nothing allocatable, whose divisor is guarded to 1) the digits
+    are garbage without a trap, and exact_least_allocated_key masks them."""
+    one = jnp.int32(1)
+    den_f = jnp.where(den > _zero(den), den, one).astype(jnp.float32)
+    inv = jnp.float32(_ESTIMATE_BIAS * 2.0**bits) / den_f
+    return _digits_by_reciprocal(num, den, inv, bits)
 
 
 def exact_least_allocated_key(fit, cpu, ram, rc, rr, bits: int):

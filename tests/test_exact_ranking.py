@@ -3,9 +3,13 @@ names sort, and the default profile ranks by an exact fixed-point key where
 float32 scores cannot tell two nodes apart that the scalar path's float64
 can."""
 
+import json
+import os
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from kubernetriks_tpu.batched import pipeline
@@ -96,6 +100,113 @@ def test_a_near_tie_float32_cannot_see(cpu, ram, rc, rr):
     assert _float32_best(alive, cpu, ram, rc, rr) == 1
     for bits in (14, 12, 10):
         assert _exact_best(alive, cpu, ram, rc, rr, bits) == 0
+
+
+POOL_SHAPES = [(64000, 128 * 1024), (64000, 256 * 1024), (96000, 192 * 1024), (32000, 64 * 1024)]  # millicores, MiB
+POOL_REQUESTS = [(500, 1024), (1000, 4096), (2000, 4096), (4000, 8192), (8000, 32768), (16000, 32768),
+                 (4000, 49152), (8000, 98304), (8000, 16384)]  # benchmark/traffic/montecarlo-pools.json
+REPLAY_SHAPE = (64000, 88 * 1024)
+
+
+def _assert_long_division(digits, num, den, bits):
+    """`digits` are what _quotient_digits promises, worked out in integers
+    (int64 holds them)."""
+    rem = num.astype(np.int64)
+    for place, digit in enumerate(digits):
+        want, rem = np.divmod(rem << bits, den)
+        assert np.array_equal(np.asarray(digit), want), (bits, place)
+    assert len(digits) == pipeline._EXACT_DIGITS
+
+
+def _division_pairs(bits):
+    """(num, den) with 0 <= num <= den < 2**(31 - bits): random ones, the
+    edges, and what the pools cell and the replay divide (a request by what
+    is left of a machine after j other requests)."""
+    rng = np.random.default_rng(bits)
+    top = 2 ** (31 - bits) - 1
+    den = np.concatenate([
+        rng.integers(1, top + 1, 120_000),
+        (2.0 ** rng.uniform(0, 31 - bits, 60_000)).astype(np.int64).clip(1, top),
+        np.repeat([1, 2, 3, top - 1, top], 2_000),
+    ])
+    num = rng.integers(0, den + 1)
+    num[:: 7] = den[:: 7]  # a pod that fills its node
+    num[1 :: 7] = 0
+    num[2 :: 7] = den[2 :: 7] - 1
+    machines = POOL_SHAPES + [REPLAY_SHAPE]
+    nums, dens = [num], [den]
+    for cap in sorted({c for shape in machines for c in shape}):
+        for held in sorted({r for req in POOL_REQUESTS for r in req} | {10, 64, 1160, 2955, 4095}):
+            left = cap - held * np.arange(0, cap // held + 1)
+            left = left[(left > 0) & (left <= top)]
+            for asked in (held, 500, 1024, 4170, 1690, cap):
+                keep = left[left >= asked]
+                nums.append(np.full(keep.shape, asked))
+                dens.append(keep)
+    return np.concatenate(nums), np.concatenate(dens)
+
+
+@pytest.mark.parametrize("bits", [10, 12, 14])
+def test_quotient_digits_are_the_long_divisions(bits):
+    num, den = _division_pairs(bits)
+    got = pipeline._quotient_digits(jnp.asarray(num, jnp.int32), jnp.asarray(den, jnp.int32), bits)
+    _assert_long_division(got, num, den, bits)
+    assert int(np.asarray(got[0]).max()) == 1 << bits  # num == den was among them
+
+
+@pytest.mark.parametrize("ulps", [-32, -5, -1, 1, 5, 32])
+@pytest.mark.parametrize("bits", [10, 12, 14])
+def test_the_digits_hold_with_a_reciprocal_some_ulp_off(bits, ulps):
+    """The one-sided correction does not lean on how a backend's division
+    rounds: the digit loop handed a reciprocal up to 32 ulp either way."""
+    num, den = _division_pairs(bits)
+    inv = np.float32(pipeline._ESTIMATE_BIAS * 2.0**bits) / den.astype(np.float32)
+    inv = (inv.view(np.int32) + np.int32(ulps)).view(np.float32)
+    got = pipeline._digits_by_reciprocal(
+        jnp.asarray(num, jnp.int32), jnp.asarray(den, jnp.int32), jnp.asarray(inv), bits
+    )
+    _assert_long_division(got, num, den, bits)
+
+
+def test_the_key_divides_once_a_denominator():
+    """What the kernels trace of the key at the megakernel's node tile: one
+    float32 division a denominator and no floor."""
+    plane = jax.ShapeDtypeStruct((1024, 128), jnp.int32)
+    row = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda fit, cpu, ram, rc, rr: pipeline.exact_least_allocated_key(fit, cpu, ram, rc, rr, 12))(
+        jax.ShapeDtypeStruct((1024, 128), jnp.bool_), plane, plane, row, row
+    )
+    names = [eqn.primitive.name for eqn in jaxpr.jaxpr.eqns]
+    assert names.count("div") == 2 and "floor" not in names
+
+
+def _frozen_key_cases():
+    with open(os.path.join(os.path.dirname(__file__), "data", "exact_key_parent.json")) as fh:
+        return json.load(fh)["cases"]
+
+
+@pytest.mark.parametrize("case", _frozen_key_cases(), ids=lambda case: f"bits{case['bits']}")
+def test_the_key_is_word_for_word_what_it_was(case):
+    """(hi, lo) against tests/data/exact_key_parent.json, which PR 47's tree
+    wrote with a float32 division a digit and a two-sided correction; and
+    both against the key in Python integers."""
+    bits = case["bits"]
+    alive = np.array(case["alive"], bool)
+    cpu, ram = np.array(case["cpu"]), np.array(case["ram"])
+    key = jax.jit(pipeline.exact_least_allocated_key, static_argnums=5)
+    for (rc, rr), hi_was, lo_was in zip(case["requests"], case["hi"], case["lo"]):
+        fit = alive & (rc <= cpu) & (rr <= ram)
+        args = (
+            jnp.asarray(fit)[None, :], jnp.asarray(cpu, jnp.int32)[None, :], jnp.asarray(ram, jnp.int32)[None, :],
+            jnp.int32(rc).reshape(1, 1), jnp.int32(rr).reshape(1, 1), bits,
+        )
+        for hi, lo in (pipeline.exact_least_allocated_key(*args), key(*args)):
+            assert np.asarray(hi)[0].tolist() == hi_was and np.asarray(lo)[0].tolist() == lo_was
+        ok = fit & (cpu > 0) & (ram > 0)
+        total = [(rc << 3 * bits) // int(c) + (rr << 3 * bits) // int(r) for c, r in zip(cpu[ok], ram[ok])]
+        assert [t >> bits for t in total] == np.array(hi_was)[ok].tolist()
+        assert [t & ((1 << bits) - 1) for t in total] == np.array(lo_was)[ok].tolist()
+        assert (np.array(hi_was)[~ok] == 2**31 - 1).all() and (np.array(lo_was)[~ok] == 2**31 - 1).all()
 
 
 def test_which_builds_rank_exactly():

@@ -489,7 +489,11 @@ def test_accepted_cells_lower_the_programs_they_lowered(cell):
     chunk on purpose (the replay's program alone came out as it was) and
     added `sched1k-spread.montecarlo`; PR 40 wrote the two autoscaled cells'
     anew (the cluster autoscaler's look-ups became dense contractions) and
-    left the other five as they were. A PR that changes the window program
+    left the other five as they were; PR 48 wrote the replay's anew (the exact
+    key estimates its digits from one reciprocal a denominator: same digits,
+    another program) and added `sched1k-faults.montecarlo` (the parent's
+    text) and `sched1k-pools.montecarlo` (pinned on its own tree, for the
+    same reason as the replay's). A PR that changes the window program
     on purpose writes the file anew on its own tree and says so."""
     import window_program_digest as wpd
 

@@ -1,7 +1,8 @@
 """Digests of the window programs the accepted benchmark cells' rehearsal
 builds lower, debug locations stripped: the yardstick of "this PR did not
 change what a cell compiles". Imports nothing newer than the PR that added
-the cell (PR 41 for `sched1k-backlog.bursts`, PR 37 for
+the cell (PR 47 for `sched1k-pools.montecarlo`, PR 43 for
+`sched1k-faults.montecarlo`, PR 41 for `sched1k-backlog.bursts`, PR 37 for
 `sched1k-spread.montecarlo`, PR 33 for the six before it), so the same file
 runs on an older checkout over the cells that checkout has:
 
@@ -33,6 +34,8 @@ CELLS = [
     "sched1k-spread.montecarlo",
     "sched1k-backlog.bursts",
     "sched1k.saturated",
+    "sched1k-faults.montecarlo",
+    "sched1k-pools.montecarlo",
 ]
 
 # `loc(...)` trailers and `#loc` lines: where in the source an op was traced.
@@ -85,7 +88,7 @@ def rehearsal_engine(cell_name: str):
         return program.build_engine(
             config_text, compiled, resettable=True, mesh=mesh, **batch_jobs._engine_kwargs(cell)
         )
-    if driver in ("batch_jobs_labelled", "batch_jobs_bursts"):
+    if driver in ("batch_jobs_labelled", "batch_jobs_bursts", "batch_jobs_faults", "batch_jobs_pools"):
         import importlib
 
         from benchmark.drivers import batch_jobs
