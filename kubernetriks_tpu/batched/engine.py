@@ -4037,6 +4037,17 @@ class BatchedSimulation:
         }
         counters.update(cycle)
         self.tracer.counters.update(cycle)
+        if m.events_deep is not None:
+            # The event chunk loop's two (step._apply_window_events_work; a
+            # batch of more than one lane tile), summed over clusters:
+            # cluster-windows with more events due than one pass applies, and
+            # those of them finished in a lane tile of their own.
+            events = {
+                "events_deep": int(np.asarray(m.events_deep).sum()),
+                "events_compacted": int(np.asarray(m.events_compacted).sum()),
+            }
+            counters.update(events)
+            self.tracer.counters.update(events)
         counters.update(self._spread_counters())
         # The reschedule order's counters (step._stable_queue_rank), summed
         # over clusters: cluster-windows that ranked a removed node's pods,

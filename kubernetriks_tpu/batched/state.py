@@ -267,6 +267,20 @@ class MetricArrays(NamedTuple):
     queue_time: EstArrays
     algo_latency: EstArrays
     pod_duration: EstArrays
+    # The event chunk loop's two counters (step._apply_window_events_work; no
+    # scalar counterpart): the windows in which the cluster had more slab
+    # events due than one pass of the loop applies (max_events_per_window: a
+    # cluster's own count), and those of them it finished in a lane tile of
+    # the batch's deep clusters, outside the batch's loop (the rest took
+    # their passes in the batch's loop: too few events past the chunk for the
+    # move to pay, or more clusters deep than a tile holds, as where every
+    # cluster creates its nodes at t = 0: step._event_lanes_to_move). As
+    # cycle_compacted, the second reads the batch (the chip's own, under a
+    # mesh). None in a batch of one lane tile, which has nothing to choose:
+    # its window programs trace neither (the structural idiom of
+    # ClusterBatchState.spread).
+    events_deep: Optional[jnp.ndarray] = None  # int32
+    events_compacted: Optional[jnp.ndarray] = None  # int32
 
 
 class SpreadState(NamedTuple):
@@ -559,24 +573,28 @@ class TraceSlab(NamedTuple):
     def _clip_block(self, block):
         return jnp.clip(block, 0, self.packed.shape[1] - 1)
 
-    def win_at(self, cursor) -> jnp.ndarray:
+    def win_at(self, cursor, rows=None) -> jnp.ndarray:
         """(C,) window index of the event at each cluster's cursor; INF_WIN
-        at and past the end."""
-        rows1 = jnp.arange(self.packed.shape[0], dtype=jnp.int32)
+        at and past the end. `rows` (R,) int32 names the clusters whose
+        cursors these are where they are not the whole batch in order."""
+        if rows is None:
+            rows = jnp.arange(self.packed.shape[0], dtype=jnp.int32)
         return self.packed.at[
-            rows1,
+            rows,
             self._clip_block(cursor // SLAB_BLOCK_EVENTS),
             _SLAB_FIELDS * (cursor % SLAB_BLOCK_EVENTS),
         ].get(mode="promise_in_bounds")
 
-    def read_chunk(self, cursor, chunk: int) -> jnp.ndarray:
+    def read_chunk(self, cursor, chunk: int, rows=None) -> jnp.ndarray:
         """(C, chunk, 4) rows [cursor, cursor + chunk) of each cluster,
         sentinel rows past the end: the blocks that hold them as one row
         gather, then a barrel shift by cursor % SLAB_BLOCK_EVENTS (five
-        selects over static slices; no second gather)."""
-        C = self.packed.shape[0]
+        selects over static slices; no second gather). `rows` as win_at's."""
+        C = cursor.shape[0]
         n_read = (chunk + SLAB_BLOCK_EVENTS - 2) // SLAB_BLOCK_EVENTS + 1
-        rows = jnp.arange(C, dtype=jnp.int32)[:, None]
+        if rows is None:
+            rows = jnp.arange(C, dtype=jnp.int32)
+        rows = rows[:, None]
         blocks = self._clip_block(
             cursor[:, None] // SLAB_BLOCK_EVENTS
             + jnp.arange(n_read, dtype=jnp.int32)[None, :]
@@ -737,6 +755,8 @@ def init_state(
     """Build the initial state with pre-staged payloads (all slots start
     EMPTY/dead; trace events bring them to life). pod_duration: float64
     seconds, <0 marks a long-running service."""
+    from kubernetriks_tpu.ops.scheduler_kernel import _LANE
+
     C, N, P = n_clusters, n_nodes, n_pods
     duration = duration_pair_np(pod_duration, interval)
     nodes = NodeArrays(
@@ -783,6 +803,8 @@ def init_state(
         queue_time=EstArrays.zeros((C,)),
         algo_latency=EstArrays.zeros((C,)),
         pod_duration=EstArrays.zeros((C,)),
+        events_deep=jnp.zeros((C,), jnp.int32) if C > _LANE else None,
+        events_compacted=jnp.zeros((C,), jnp.int32) if C > _LANE else None,
     )
     return ClusterBatchState(
         time=jnp.zeros((C,), jnp.int32),
@@ -988,6 +1010,8 @@ AXIS_SIGNATURES = {
     "cycle_overruns": "C",
     "cycle_deep": "C",
     "cycle_compacted": "C",
+    "events_deep": "C",
+    "events_compacted": "C",
     "resched_rank_windows": "C",
     "resched_rank_sorted": "C",
 }

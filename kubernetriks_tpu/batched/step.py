@@ -148,6 +148,14 @@ RANK_COMPACT_SLOTS = 64
 CYCLE_COMPACT_PAYS = 32
 
 
+# What finishing the deep clusters' events in a tile of their own costs a
+# window (_event_lanes_to_move: the lanes taken, the tile loop's own glue and
+# the planes put back), in steps of the event kernel a lane tile of the
+# batch: reasoned from the compiled text, not fitted (PERF.md section 6,
+# PR 49; no cell's traffic sits near it but autoscaled.stream's).
+EVENT_COMPACT_PAYS = 32
+
+
 def _rank_compacted(keys, mask, n, pos, R: int) -> jnp.ndarray:
     """_stable_queue_rank's ranks where no cluster masks more than R rows
     (n (C,): rows masked; pos (C, P): their running count less one, a masked
@@ -327,7 +335,18 @@ def _apply_window_events(
     this gates per window inside dense spans). Bit-exact: the skip branch
     fires only when the soup is provably the identity (see
     _window_work_due). window_razor=False keeps the always-run path for
-    A/B measurement."""
+    A/B measurement.
+
+    A batch of more than one lane tile has a third branch: the soup with the
+    clusters that have a burst of slab events due finished in a lane tile of
+    their own (_apply_window_events_work's `moved`), taken in the windows in
+    which some cluster moves (_event_lanes_to_move). It is the razor's `cond`
+    made a `switch`, so a window in which nobody moves runs the single
+    loop's program and pays the look at the slab and nothing else: a `cond`
+    of its own round the loop cost every window some 25 small operations,
+    its operands' copies among them (PERF.md section 6, PR 49)."""
+    from kubernetriks_tpu.ops.scheduler_kernel import _LANE
+
     args = (
         consts,
         max_events_per_window,
@@ -342,18 +361,26 @@ def _apply_window_events(
         node_key_fn,
     )
     due = _window_work_due(state, slab, W, consts)
+    moved = None
+    if state.time.shape[0] > _LANE:
+        moved = _event_lanes_to_move(
+            slab, state.event_cursor, W, max_events_per_window, _LANE
+        )
 
-    def run(st):
+    def run(st, moved=None):
         """The soup, and the window counted for the clusters it was due in
         (a function of the state alone, so the razor's on and off builds
         and a sharded build count alike)."""
-        st, wake, chunks = _apply_window_events_work(st, slab, W, *args)
+        st, wake, chunks = _apply_window_events_work(st, slab, W, *args, moved=moved)
         metrics = st.metrics._replace(
             event_windows=st.metrics.event_windows + due.astype(jnp.int32)
         )
         return st._replace(metrics=metrics), wake, chunks
 
+    tiled = partial(run, moved=moved)
     if not window_razor:
+        if moved is not None:
+            return jax.lax.cond(moved.any(), tiled, run, state)
         return run(state)
 
     def skip(st):
@@ -379,6 +406,10 @@ def _apply_window_events(
             wake = None
         return st._replace(time=jnp.maximum(st.time, W)), wake, chunks
 
+    if moved is not None:
+        # A cluster that moves has events due: the last branch implies the second's test.
+        branch = jnp.where(moved.any(), 2, due.any().astype(jnp.int32))
+        return jax.lax.switch(branch, (skip, run, tiled), state)
     return jax.lax.cond(due.any(), run, skip, state)
 
 
@@ -401,6 +432,46 @@ def event_path(
     return "kernel" if use_pallas and use_pallas_select and fits else "scatter"
 
 
+class _EventLanes(NamedTuple):
+    """The clusters one event chunk loop runs over
+    (_apply_window_events_work), (L,) each: the whole batch in order, or a
+    lane tile of its clusters."""
+
+    W: jnp.ndarray  # the window; _NOTHING_DUE in a slot that holds no cluster
+    base: jnp.ndarray  # the window the applied events fall in
+    rows: jnp.ndarray  # (L, 1) a lane's position in the loop's planes
+    pod_base: jnp.ndarray
+    queue_seq_counter: jnp.ndarray
+    slab_rows: Optional[jnp.ndarray] = None  # the clusters, where not all in order
+
+
+# A window no slab event lies before (TraceSlab.win_at(...) < W is never true).
+_NOTHING_DUE = np.int32(np.iinfo(np.int32).min)
+
+
+def _event_lanes_to_move(slab: TraceSlab, cursor, W, E: int, R: int):
+    """The clusters whose due slab events finish in a lane tile of their own
+    (_apply_window_events_work), (C,) bool. The loop over the batch runs as
+    many passes of E events as its deepest cluster needs, every pass the
+    event kernel over all the batch's tiles and the slab read, the masks and
+    the pads over all its clusters; a cluster moved to the tile takes its
+    passes there, over one tile of R lanes. That pays where it saves the
+    batch more kernel steps than the move costs (EVENT_COMPACT_PAYS a tile
+    of the batch): the events a cluster has due past its first pass are
+    steps of every pass after it in all the other tiles, so a cluster moves
+    if it has more than E + EVENT_COMPACT_PAYS * tiles / (tiles - 1) due,
+    which one look at the slab that far past its cursor says (due events are
+    a sorted prefix). A job's thousand creations pay many times over, alone
+    too; a rack's crashes on top of a window's arrivals, a few past the
+    chunk, do not. And nobody moves if more clusters have such a burst due
+    than a tile holds (a node burst in every cluster at t = 0): a width,
+    never a limit."""
+    tiles = -(-cursor.shape[0] // R)
+    depth = E + -(-EVENT_COMPACT_PAYS * tiles // (tiles - 1))
+    bursts = slab.win_at(cursor + depth) < W
+    return bursts & (bursts.sum(dtype=jnp.int32) <= R)
+
+
 def _apply_window_events_work(
     state: ClusterBatchState,
     slab: TraceSlab,
@@ -416,9 +487,14 @@ def _apply_window_events_work(
     fault_params=None,
     lane_major: bool = False,
     node_key_fn=None,
+    moved=None,
 ) -> ClusterBatchState:
     """Apply every trace event with effect time STRICTLY before the cycle time
     W * interval, and resolve all pod finishes due in the window.
+
+    moved (_event_lanes_to_move; None: every pass in the one loop over the
+    batch): the clusters, at most a lane tile's worth, that take their
+    passes of the chunk loop in one tile of their own before the batch's.
 
     lane_major (KTPU_LANE_MAJOR): the hot node leaves
     (state.NODE_HOT_LEAVES) and every node-shaped accumulator in this
@@ -470,6 +546,7 @@ def _apply_window_events_work(
     f32inf = jnp.float32(INF)
 
     from kubernetriks_tpu.ops.scheduler_kernel import (
+        _LANE,
         event_accumulators,
         event_accumulators_unpack,
         fused_event_scatter,
@@ -502,10 +579,13 @@ def _apply_window_events_work(
     # that one window instead of taxing every window with a burst-sized
     # gather/scatter. Due events are a sorted prefix of the slab, so a chunk
     # boundary never skips one.
-    def chunk_cond(carry):
-        return jnp.any(slab.win_at(carry[0]) < W)
+    # It runs over `lanes` (_EventLanes): the whole batch, or a lane tile of
+    # its clusters (`moved`, below).
+    def chunk_cond(carry, lanes):
+        return jnp.any(slab.win_at(carry[0], lanes.slab_rows) < lanes.W)
 
-    def chunk_body(carry):
+    def chunk_body(carry, lanes):
+        W, base, rows, slab_rows = lanes.W, lanes.base, lanes.rows, lanes.slab_rows
         (cursor, created, node_removal, pod_create, pod_create_seq,
          pod_removal, n_creates) = carry[:7]
         tail = 7
@@ -518,7 +598,7 @@ def _apply_window_events_work(
         # (E / 32 + 1) x C gather indices, not C x E (gather cost is per
         # index on the TPU; TraceSlab). Past the end the slab reads as
         # sentinel events (win=INF_WIN), which are never due.
-        pk = slab.read_chunk(cursor, E)  # (C, E, 4) int32
+        pk = slab.read_chunk(cursor, E, slab_rows)  # (C, E, 4) int32
         ev_win = pk[..., 0]
         ev_off = jax.lax.bitcast_convert_type(pk[..., 1], jnp.float32)
         ev_k = pk[..., 2]
@@ -537,7 +617,7 @@ def _apply_window_events_work(
         is_pod_ev = (ev_k == EV_CREATE_POD) | (ev_k == EV_REMOVE_POD)
         seg_shift = jnp.where(
             ev_s_raw < consts.trace_pod_bound,
-            state.pod_base[:, None],
+            lanes.pod_base[:, None],
             consts.resident_shift,
         )
         ev_s = jnp.where(is_pod_ev, ev_s_raw - seg_shift, ev_s_raw)
@@ -564,7 +644,7 @@ def _apply_window_events_work(
         # Queue sequence numbers follow slab (== emission) order, continuing
         # across chunks via the running n_creates.
         create_rank = jnp.cumsum(is_cp, axis=1, dtype=jnp.int32) - 1
-        ev_seq = state.queue_seq_counter[:, None] + n_creates[:, None] + create_rank
+        ev_seq = lanes.queue_seq_counter[:, None] + n_creates[:, None] + create_rank
 
         if use_event_kernel:
             # One Pallas call replaces the five (C, E)-indexed scatters
@@ -629,7 +709,7 @@ def _apply_window_events_work(
             # time; _conditional_wake_exact). Only built on the
             # conditional-move path — an extra (C, N) scatter otherwise.
             node_create_rel = n_scatter_min(
-                node_create_rel, is_cn, ev_s,
+                node_create_rel, is_cn, rows, ev_s,
                 jnp.where(is_cn, ev_rel, f32inf),
             )
             out = out + (node_create_rel,)
@@ -637,7 +717,7 @@ def _apply_window_events_work(
             with faults_scope():
                 if not use_event_kernel:
                     crash_rm = n_scatter_min(
-                        crash_rm, is_crash, ev_s,
+                        crash_rm, is_crash, rows, ev_s,
                         jnp.where(is_crash, ev_rel, f32inf),
                     )
                 out = out + (
@@ -649,36 +729,81 @@ def _apply_window_events_work(
             out = out + (carry[-1] + valid[:, 0].astype(jnp.int32),)
         return out
 
-    def n_scatter_min(acc, mask, ev_s, values):
+    def n_scatter_min(acc, mask, rows, ev_s, values):
         tgt = jnp.where(mask, ev_s, N)
         if lane_major:
             return acc.at[tgt, rows].min(values, mode="drop")
         return acc.at[rows, tgt].min(values, mode="drop")
 
-    if use_event_kernel:
-        accumulators0 = event_accumulators(C, N, P)
-    else:
-        accumulators0 = (
-            jnp.zeros(n_shape, bool),
-            jnp.full(n_shape, INF, jnp.float32),
-            jnp.full((C, P), INF, jnp.float32),
-            jnp.zeros((C, P), jnp.int32),
-            jnp.full((C, P), INF, jnp.float32),
+    def carry_at(cursor):
+        """The loop's carry before the first pass over the L clusters whose
+        cursors these are."""
+        L = cursor.shape[0]
+        nodes_shape = (N, L) if lane_major else (L, N)
+        if use_event_kernel:
+            accumulators0 = event_accumulators(L, N, P)
+        else:
+            accumulators0 = (
+                jnp.zeros(nodes_shape, bool),
+                jnp.full(nodes_shape, INF, jnp.float32),
+                jnp.full((L, P), INF, jnp.float32),
+                jnp.zeros((L, P), jnp.int32),
+                jnp.full((L, P), INF, jnp.float32),
+            )
+        carry0 = (cursor,) + accumulators0 + (jnp.zeros((L,), jnp.int32),)
+        if conditional_move:
+            carry0 = carry0 + (jnp.full(nodes_shape, INF, jnp.float32),)
+        if node_faults:
+            carry0 = carry0 + (
+                # the crash accumulator starts as node_removal does, in its layout
+                accumulators0[1],
+                jnp.zeros((L,), jnp.int32),
+            )
+        if count_chunks:
+            carry0 = carry0 + (jnp.zeros((L,), jnp.int32),)
+        return carry0
+
+    def chunk_loop(lanes, carry0):
+        return jax.lax.while_loop(
+            partial(chunk_cond, lanes=lanes), partial(chunk_body, lanes=lanes), carry0
         )
-    carry0 = (
-        (state.event_cursor,) + accumulators0 + (jnp.zeros((C,), jnp.int32),)
-    )
-    if conditional_move:
-        carry0 = carry0 + (jnp.full(n_shape, INF, jnp.float32),)
-    if node_faults:
-        carry0 = carry0 + (
-            # the crash accumulator starts as node_removal does, in its layout
-            accumulators0[1],
-            jnp.zeros((C,), jnp.int32),
+
+    lanes = _EventLanes(W, base, rows, state.pod_base, state.queue_seq_counter)
+    carry0 = carry_at(state.event_cursor)
+    if moved is not None:
+        # The clusters with a burst due finish in ONE lane tile of their own
+        # (the event kernel's; the scatters take the same width, so that
+        # both builds hold one state), before the batch's loop and not in
+        # it, where every further pass would run all the batch's tiles, and
+        # all its glue, for them (a grid program loops to its tile's largest
+        # count, and the loop to the batch's). The tile's accumulators start
+        # empty and are put into the batch's, empty too: a cluster's lanes
+        # never read another's, and the batch's loop, handed the tile's
+        # cursors, finds nothing due in those clusters (every merge is a
+        # min, a max or an or, and these lanes see one side of it), so the
+        # carry is bit for bit the single loop's (tests/test_event_compact.py).
+        slot, index = _tile_slots(moved, _LANE)
+        tile = _EventLanes(
+            jnp.where(index < C, _take_lanes(W, index), _NOTHING_DUE),
+            _take_lanes(base, index),
+            jnp.arange(_LANE, dtype=jnp.int32)[:, None],
+            _take_lanes(state.pod_base, index),
+            _take_lanes(state.queue_seq_counter, index),
+            slab_rows=jnp.minimum(index, C - 1),
         )
-    if count_chunks:
-        carry0 = carry0 + (jnp.zeros((C,), jnp.int32),)
-    carry_out = jax.lax.while_loop(chunk_cond, chunk_body, carry0)
+        done = chunk_loop(tile, carry_at(_take_lanes(state.event_cursor, index)))
+
+        def put(full, part):
+            # The cluster axis is the one along which the tile is narrower:
+            # last in the kernel's padded layout, first in a row-major plane.
+            axis = [f == p for f, p in zip(full.shape, part.shape)].index(False)
+            if axis != full.ndim - 1:
+                return put(full.T, part.T).T
+            pad = (0, full.shape[-1] - C)
+            return _put_lanes(full, part, jnp.pad(slot, pad), jnp.pad(moved, pad))
+
+        carry0 = tuple(put(full, part) for full, part in zip(carry0, done))
+    carry_out = chunk_loop(lanes, carry0)
     event_chunks = carry_out[-1] if count_chunks else None
     (event_cursor, created, node_removal, pod_create, pod_create_seq,
      pod_removal, n_creates) = carry_out[:7]
@@ -689,6 +814,16 @@ def _apply_window_events_work(
         tail += 1
     if node_faults:
         crash_rm, n_recover = carry_out[tail], carry_out[tail + 1]
+    if metrics.events_deep is not None:
+        # The windows in which the cluster had more events due than a pass
+        # applies, and those of them it finished in the tile.
+        deep = event_cursor - state.event_cursor > E
+        metrics = metrics._replace(
+            events_deep=metrics.events_deep + deep.astype(jnp.int32),
+            events_compacted=metrics.events_compacted
+            if moved is None
+            else metrics.events_compacted + moved.astype(jnp.int32),
+        )
     if use_event_kernel:
         # Out of the kernel's layout once a window, where the row-major
         # consumers start.
@@ -1954,6 +2089,20 @@ def _lanes_to_move(n_eligible, K: int, R: int):
     return deep & fits & (saved > CYCLE_COMPACT_PAYS * tiles)
 
 
+def _tile_slots(moved, R: int):
+    """Where the clusters `moved` (C,) marks sit in a tile of R lanes of their
+    own: (slot (C,), a moved cluster's place in the tile, the r-th moved one
+    in slot r; index (R,), the cluster a slot holds, the batch's width (past
+    the axis) for a slot nobody takes)."""
+    count = jnp.cumsum(moved, dtype=jnp.int32)
+    # A slot's cluster is the number of lanes with at most r moved ones up to
+    # and with them.
+    index = (count[None, :] <= jnp.arange(R, dtype=jnp.int32)[:, None]).sum(
+        axis=1, dtype=jnp.int32
+    )
+    return count - 1, index
+
+
 def _take_lanes(x, index):
     """The clusters `index` (R,) names out of x (..., C), cluster axis last;
     zeros in a slot whose index lies past the axis."""
@@ -2069,19 +2218,13 @@ def _launch_by_depth(
             spread_tiles,
             affinity_tiles,
         )
-        # Slot r of the second launch holds the r-th moved cluster: its lane
-        # is the number of lanes with at most r moved ones up to and with
-        # them, the batch's width (past the axis) for a slot nobody takes.
-        count = jnp.cumsum(moves, dtype=jnp.int32)
-        index = (count[None, :] <= jnp.arange(R, dtype=jnp.int32)[:, None]).sum(
-            axis=1, dtype=jnp.int32
-        )
+        slot, index = _tile_slots(moves, R)
         second = launch_on(
             tuple(_take_lanes(x, index) for x in operands),
             spread_tiles and tuple(_take_lanes(x, index) for x in spread_tiles),
             affinity_tiles and tuple(_take_lanes(x, index) for x in affinity_tiles),
         )
-        return tuple(_put_lanes(a, b, count - 1, moves) for a, b in zip(first, second))
+        return tuple(_put_lanes(a, b, slot, moves) for a, b in zip(first, second))
 
     outputs = jax.lax.cond(
         moved.any(), split, lambda: launch_on(operands, spread_tiles, affinity_tiles)

@@ -493,7 +493,10 @@ def test_accepted_cells_lower_the_programs_they_lowered(cell):
     key estimates its digits from one reciprocal a denominator: same digits,
     another program) and added `sched1k-faults.montecarlo` (the parent's
     text) and `sched1k-pools.montecarlo` (pinned on its own tree, for the
-    same reason as the replay's). A PR that changes the window program
+    same reason as the replay's); PR 49 moved none (every rehearsal is one
+    lane tile, whose state has neither of the event loop's two counters and
+    whose program has no tile to choose; the multi-tile builds' tile is held
+    by tests/test_event_compact.py). A PR that changes the window program
     on purpose writes the file anew on its own tree and says so."""
     import window_program_digest as wpd
 
