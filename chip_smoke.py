@@ -51,6 +51,10 @@ Legs:
   event kernel applies crashes and recoveries and a dead node's pods run
   again, against the lax.scan engine on its scatter path. `--only faults`
   runs it alone.
+- kubescore: `sched1k-kubescore.montecarlo`'s pools, labels, taints and
+  classes (benchmark/kubescore_gen.py) on one lane tile of clusters, ranked
+  as kube-scheduler ranks (`ranking: integer`: the one leg whose argmax is
+  not a float32 compare), the megakernel against the lax.scan engine.
 """
 
 from __future__ import annotations
@@ -92,6 +96,8 @@ CHIP_LEGS = dict(
     # A fifth of the north-star batch keeps the two builds' per-cluster
     # trace compiles short.
     faults=({"clusters": 256}, "megakernel", "kernel"),
+    # One lane tile: the integer chain is a lane's own.
+    kubescore=({"clusters": 128}, "megakernel"),
 )
 PLUMBING_LEGS = dict(
     pure=[({}, "candidate")],
@@ -100,6 +106,7 @@ PLUMBING_LEGS = dict(
     served=dict(n_queries=8, cycle="candidate"),
     cli_clusters=2,
     faults=({}, "candidate", "scatter"),
+    kubescore=({"clusters": 4}, "candidate"),
 )
 
 
@@ -154,11 +161,18 @@ def leg_inputs(cell_name: str, rehearsed: bool, clusters=None, nodes=None, horiz
         dep["nodes"] = nodes
     if horizon_s is not None:
         traffic["plain"]["horizon_s"] = horizon_s
-    api = program.program_api()
+    api, gen, place = program.program_api(), traffic_gen, ()
+    if traffic["driver"] == "batch_jobs_kubescore":
+        # Records with labels, taints and placements, and how they go onto
+        # the program's objects.
+        from benchmark import kubescore_gen as gen, kubescore_program
+
+        api = kubescore_program.program_api()
+        place = (kubescore_program.placer(api),)
     config = api.SimulationConfig.from_yaml(deployment.config_yaml(cell.config_name, dep))
     seed = int(traffic.get("base_workload_seed", SEED))
-    cluster_events = traffic_gen.to_events(traffic_gen.cluster_records(dep), api)
-    workload = traffic_gen.to_events(traffic_gen.workload_records(traffic, seed, 0), api)
+    cluster_events = gen.to_events(gen.cluster_records(dep), api, *place)
+    workload = gen.to_events(gen.workload_records(traffic, seed, 0), api, *place)
     width = clusters or traffic.get("clusters_per_chip") or traffic["lanes"]
     return Leg(cell, config, cluster_events, workload, int(width), rehearsed)
 
@@ -256,6 +270,36 @@ def faults_leg(shape, run, rehearsed) -> dict:
         pod_interruptions=counters["pod_interruptions"],
         pods_succeeded=counters["pods_succeeded"], reference="lax.scan",
         mismatches=0,
+    )
+
+
+def kubescore_leg(shape, run, rehearsed) -> dict:
+    """kube-scheduler's integer scores through the dense kernel set against
+    the lax.scan engine: four machine shapes, preferred terms and a
+    PreferNoSchedule pool, no float in the ranking."""
+    from kubernetriks_tpu.batched.state import compare_states
+
+    overrides, cycle = shape
+    t0 = time.perf_counter()
+    leg = leg_inputs("sched1k-kubescore.montecarlo", rehearsed, **overrides)
+    sim = leg.build(leg.width, **leg.forced())
+    ref = leg.build(leg.width, use_pallas=False)
+    for s in (sim, ref):
+        s.step_until_time(run["warm_until"])
+        s.step_until_time(run["warm_until"] + run["chunk"])
+    formulation = sim.kernel_formulation()
+    assert formulation["cycle"] == cycle and formulation["ranking"] == "integer", formulation
+    decisions = int(np.asarray(sim.state.metrics.scheduling_decisions).sum())
+    sim.metrics_summary()
+    report = sim.telemetry_report()["counters"]
+    assert 0 < report["soft_honoured"] <= report["soft_attempts"], report
+    mismatches = compare_states(ref.state, sim.state)
+    assert not mismatches, mismatches
+    return emit(
+        "kubescore", t0, clusters=leg.width, nodes=sim.n_nodes, pods=sim.n_pods,
+        formulation=formulation, decisions=decisions,
+        soft_attempts=report["soft_attempts"], soft_honoured=report["soft_honoured"],
+        reference="lax.scan", mismatches=0,
     )
 
 
@@ -432,7 +476,7 @@ def main(argv=None) -> int:
         "JAX has: debugs this script, proves nothing about the chip",
     )
     parser.add_argument(
-        "--only", choices=("pure", "composed", "served", "cli", "faults"),
+        "--only", choices=("pure", "composed", "served", "cli", "faults", "kubescore"),
         help="run this leg alone (one chip)",
     )
     args = parser.parse_args(argv)
@@ -505,6 +549,8 @@ def main(argv=None) -> int:
             legs.append(cli_leg(shapes["cli_clusters"]))
         if wanted("faults"):
             legs.append(faults_leg(shapes["faults"], shapes["pure_run"], rehearsed))
+        if wanted("kubescore"):
+            legs.append(kubescore_leg(shapes["kubescore"], shapes["pure_run"], rehearsed))
 
     print(
         json.dumps(

@@ -417,14 +417,32 @@ def _build_affinity(profile, compiled_traces, config, n_nodes: int, n_pods: int,
     TaintToleration (taints and terms are then inert, as upstream's are with
     the plugins off), or no trace has a taint, a nodeSelector, a node
     affinity or a toleration. Each cluster keeps its own interned bits; the
-    build holds as many term planes as its widest pod has terms. Refuses, by
-    name, what the batched filters do not cover."""
-    from kubernetriks_tpu.batched.pipeline import UnsupportedProfileError, uses_affinity
+    build holds as many term planes as its widest pod has terms. With the
+    soft planes (`pod_soft_*`) where the profile scores by NodeAffinity or
+    TaintToleration and a trace has a preferred term or a PreferNoSchedule
+    taint. Refuses, by name, what the batched filters do not cover, and a
+    preference the profile would ignore."""
+    from kubernetriks_tpu.batched.pipeline import (
+        UnsupportedProfileError,
+        scores_softly,
+        uses_affinity,
+    )
     from kubernetriks_tpu.batched.trace_compile import AFFINITY_NO_TERM
+    from kubernetriks_tpu.core.scheduler.plugins import (
+        ignores_preferences,
+        unscored_preferred_term,
+        unscored_soft_taint,
+    )
 
     carrying = [c.affinity for c in compiled_traces if c.affinity is not None]
     if not carrying or not uses_affinity(profile):
         return None
+    no_terms, no_taints = ignores_preferences(profile.filters, [name for name, _ in profile.scores])
+    for af in carrying:
+        if no_terms and af.first_preferring is not None:
+            raise unscored_preferred_term(af.first_preferring)
+        if no_taints and af.first_soft_tainted is not None:
+            raise unscored_soft_taint(af.first_soft_tainted)
     if _makes_objects_at_run_time(config, compiled_traces):
         raise UnsupportedProfileError(
             "node taints, nodeSelectors, node affinities and tolerations together with the horizontal "
@@ -440,6 +458,12 @@ def _build_affinity(profile, compiled_traces, config, n_nodes: int, n_pods: int,
         "pod_forbid": np.zeros((C, width), np.int32),
     }
     out["pod_terms"][:, 0, :] = 0  # a slot no trace names holds a pod that names no node
+    soft = [af for af in carrying if af.soft_terms is not None]
+    if soft and scores_softly(profile):
+        n_soft = max(af.soft_terms.shape[0] for af in soft)
+        out["pod_soft_terms"] = np.full((C, n_soft, width), AFFINITY_NO_TERM, np.int32)
+        out["pod_soft_weights"] = np.zeros((C, width), np.int32)
+        out["pod_soft_forbid"] = np.zeros((C, width), np.int32)
     for ci, trace in enumerate(compiled_traces):
         af = trace.affinity
         if af is None:
@@ -448,7 +472,59 @@ def _build_affinity(profile, compiled_traces, config, n_nodes: int, n_pods: int,
         terms, pods = af.pod_terms.shape
         out["pod_terms"][ci, :terms, :pods] = af.pod_terms
         out["pod_forbid"][ci, :pods] = af.pod_forbid
+        if "pod_soft_terms" in out and af.soft_terms is not None:
+            out["pod_soft_terms"][ci, : af.soft_terms.shape[0], :pods] = af.soft_terms
+            out["pod_soft_weights"][ci, :pods] = af.soft_weights
+            out["pod_soft_forbid"][ci, :pods] = af.soft_forbid
     return out
+
+
+def _integer_profile_statics(profile, compiled_traces, node_cap_cpu, node_cap_ram, config):  # ktpu: sync-ok(engine build: host numpy over the traces' capacity tables, no device values)
+    """(units, soft taint bits) of a build whose profile scores in integers
+    (pipeline.is_integer_profile), refusing by name what the integer scorers
+    cannot hold: a capacity pair whose product, in units of the build's gcds,
+    passes int32 a hundred times over; a node or pod whose RAM is not a whole
+    RAM unit (the device would score the rounded number); objects made at
+    run time (their shapes would have to join the gcd)."""
+    from kubernetriks_tpu.batched.pipeline import (
+        INTEGER_PRODUCT_LIMIT,
+        UnsupportedProfileError,
+        integer_score_units,
+    )
+
+    if _makes_objects_at_run_time(config, compiled_traces):
+        raise UnsupportedProfileError(
+            f"scheduler profile {profile.name!r} scores in integers of the build's common resource "
+            "units: together with the horizontal pod autoscaler or the cluster autoscaler it is not "
+            "supported (pods and nodes made at run time would have to join the units)"
+        )
+    distinct = list({id(c): c for c in compiled_traces}.values())
+    for c in distinct:
+        if c.inexact_ram is not None:
+            raise UnsupportedProfileError(
+                f"{c.inexact_ram}: its RAM is not a whole number of RAM units, and profile "
+                f"{profile.name!r} scores in integers: the device holds the rounded number, which "
+                "would score otherwise than the bytes do (give whole units, or a smaller ram_unit)"
+            )
+    units = integer_score_units(
+        [(c.pod_req_cpu, c.pod_req_ram) for c in distinct], [(node_cap_cpu, node_cap_ram)]
+    )
+    whole = (np.asarray(node_cap_cpu, np.int64) // units[0]) * (
+        np.asarray(node_cap_ram, np.int64) // units[1]
+    )
+    if whole.size and int(whole.max()) > INTEGER_PRODUCT_LIMIT:
+        ci, slot = np.unravel_index(int(whole.argmax()), whole.shape)
+        names = compiled_traces[ci].node_names
+        name = names[slot] if slot < len(names) else f"node slot {slot}"
+        raise UnsupportedProfileError(
+            f"node {name!r}: capacity {int(node_cap_cpu[ci, slot])} x {int(node_cap_ram[ci, slot])} is "
+            f"{int(whole.max())} in units of the build's gcds {units}, and 100 times that passes int32: "
+            f"profile {profile.name!r} cannot score it in integers"
+        )
+    soft_taints = max(
+        (len(c.affinity.soft_taints) for c in distinct if c.affinity is not None), default=0
+    )
+    return units, soft_taints
 
 
 def _lex_name_ranks(names) -> np.ndarray:  # ktpu: sync-ok(host-side name-rank table builder over python name lists, no device values)
@@ -1468,6 +1544,8 @@ class BatchedSimulation:
         # pipeline.exact_score_bits). self.profile stays the user's own.
         from kubernetriks_tpu.batched.pipeline import exact_score_bits
 
+        from kubernetriks_tpu.batched.pipeline import is_integer_profile
+
         distinct = {id(c): c for c in compiled_traces}.values()
         self._cycle_profile = self.profile._replace(
             exact_bits=exact_score_bits(
@@ -1476,6 +1554,11 @@ class BatchedSimulation:
                 [(node_cap_cpu, node_cap_ram)],
             )
         )
+        if is_integer_profile(self.profile):
+            units, soft_taints = _integer_profile_statics(
+                self.profile, compiled_traces, node_cap_cpu, node_cap_ram, config
+            )
+            self._cycle_profile = self._cycle_profile._replace(units=units, soft_taints=soft_taints)
         # Topology spread: a pod of the build is held to a constraint where
         # the profile runs PodTopologySpread AND a trace carries one. Only
         # then does the state get the filter's leaves (state.SpreadState) and
@@ -1496,6 +1579,13 @@ class BatchedSimulation:
         self._affinity_terms = (
             None if affinity_host is None else int(affinity_host["pod_terms"].shape[1])
         )
+        # The integer scorers' blocks, for the same gates: None for a profile
+        # that ranks otherwise, else the build's preferred-term planes (0: no
+        # soft plane, the two capacity planes alone).
+        self._kube_terms = None
+        if is_integer_profile(self.profile):
+            soft_terms = (affinity_host or {}).get("pod_soft_terms")
+            self._kube_terms = 0 if soft_terms is None else int(soft_terms.shape[1])
         # Real (trace-defined) pod slots, before the 128-alignment padding
         # of the device axis — the count completion/terminal asserts want.
         self.n_real_pods = max((c.n_pods for c in compiled_traces), default=0)
@@ -1554,7 +1644,7 @@ class BatchedSimulation:
                 and self.n_clusters % n_shards == 0
                 and kernel_fits(
                     self.n_nodes, self.max_pods_per_cycle, self._spread_shape,
-                    self._affinity_terms,
+                    self._affinity_terms, self._kube_terms,
                 )
             )
         # Prefer the fused selection kernel (in-kernel queue argmin instead
@@ -1571,7 +1661,7 @@ class BatchedSimulation:
             and self.n_clusters // n_shards >= 128
             and select_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape, self._affinity_terms,
+                self._spread_shape, self._affinity_terms, self._kube_terms,
             )
         )
         # The r4 megakernel (selection + cycle + commit in one launch) is the
@@ -1588,7 +1678,7 @@ class BatchedSimulation:
             and flag_bool("KTPU_MEGAKERNEL")
             and select_commit_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape, self._affinity_terms,
+                self._spread_shape, self._affinity_terms, self._kube_terms,
             )
         )
         # The fit gates above (and the CA kernels' in autoscale.py) degrade
@@ -1632,6 +1722,13 @@ class BatchedSimulation:
                     attempts_refused=jnp.zeros((C,), jnp.int32),
                 )
             )
+            if self._kube_terms:
+                self.state = self.state._replace(
+                    metrics=self.state.metrics._replace(
+                        soft_attempts=jnp.zeros((C,), jnp.int32),
+                        soft_honoured=jnp.zeros((C,), jnp.int32),
+                    )
+                )
         # Static (lo, hi) device-slot bounds covering every pod-group slot:
         # the HPA pass only touches group slots, so its body (victim sort
         # included) and its not-due cond carry run on this slice instead of
@@ -1882,7 +1979,12 @@ class BatchedSimulation:
             "interpret": self.pallas_interpret,
             # how nodes are ranked for a pod: the float32 score, or the
             # exact key a trace of heterogeneous requests calls for
-            "ranking": "exact" if self._cycle_profile.exact_bits else "float32",
+            # or kube-scheduler's integer scores (no float decides a rank)
+            "ranking": "integer"
+            if self._kube_terms is not None
+            else "exact"
+            if self._cycle_profile.exact_bits
+            else "float32",
             # how the event chunk loop applies a chunk: fused_event_scatter,
             # or the XLA scatters that are its bit-identical fallback
             "events": event_path(
@@ -3715,7 +3817,7 @@ class BatchedSimulation:
             self.use_pallas_select
             and select_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape, self._affinity_terms,
+                self._spread_shape, self._affinity_terms, self._kube_terms,
             )
         )
         self.use_megakernel = (
@@ -3723,7 +3825,7 @@ class BatchedSimulation:
             and self.use_pallas_select
             and select_commit_kernel_fits(
                 self.n_nodes, self.n_pods, self.max_pods_per_cycle,
-                self._spread_shape, self._affinity_terms,
+                self._spread_shape, self._affinity_terms, self._kube_terms,
             )
         )
         import logging
@@ -4048,6 +4150,17 @@ class BatchedSimulation:
             }
             counters.update(events)
             self.tracer.counters.update(events)
+        if m.soft_attempts is not None:
+            # The label scorers' two (pipeline.integer_scores; a build with
+            # soft planes), summed over clusters: decisions a preferred term
+            # or a PreferNoSchedule taint could sway, and those placed on a
+            # node with the largest label score among the feasible ones.
+            soft = {
+                "soft_attempts": int(np.asarray(m.soft_attempts).sum()),
+                "soft_honoured": int(np.asarray(m.soft_honoured).sum()),
+            }
+            counters.update(soft)
+            self.tracer.counters.update(soft)
         counters.update(self._spread_counters())
         # The reschedule order's counters (step._stable_queue_rank), summed
         # over clusters: cluster-windows that ranked a removed node's pods,

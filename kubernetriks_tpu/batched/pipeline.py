@@ -2,9 +2,11 @@
 lowers a KubeScheduler profile (ordered filter refs + weighted score refs)
 into the batched hot path. The device registry below lowers every built-in of
 the scalar registry (core/scheduler/plugins.py): the filters Fit,
-PodTopologySpread, NodeAffinity and TaintToleration, the scorers
+PodTopologySpread, NodeAffinity and TaintToleration, the reference's scorers
 LeastAllocatedResources, MostAllocatedResources and
-BalancedResourceAllocation.
+BalancedResourceAllocation, and kube-scheduler's own integer scorers
+NodeResourcesFit, NodeResourcesBalancedAllocation, NodeAffinity and
+TaintToleration (the score halves).
 
 The scalar path interprets profiles per pod through the plugin registry
 (core/scheduler/plugins.py, kube_scheduler.py). The batched path cannot —
@@ -70,6 +72,12 @@ tests/test_random_equivalence.py):
   profile that scores by LeastAllocatedResources alone, whatever its
   filters, ranks nodes by `exact_least_allocated_key`, a 3-digit
   fixed-point quotient in int32 that orders as the float64 score does.
+- A profile whose scorers are all kube-scheduler's (docs/PARITY.md "Scoring
+  as kube-scheduler scores"; `is_integer_profile`) ranks in int32 and needs
+  no key: "integer scorers" below. It is the one kind of score that is not
+  elementwise in the node: NodeAffinity and TaintToleration are normalised
+  over the nodes that passed the filters, two reductions over the node axis
+  before the argmax. Such a profile never mixes with the float scorers.
 """
 
 from __future__ import annotations
@@ -88,10 +96,13 @@ from kubernetriks_tpu.core.scheduler.kube_scheduler import (
 )
 from kubernetriks_tpu.core.scheduler.plugins import (
     BALANCED,
+    BALANCED_ALLOCATION,
     FIT,
+    INTEGER_SCORE_PLUGINS,
     LEAST_ALLOCATED,
     MOST_ALLOCATED,
     NODE_AFFINITY,
+    NODE_RESOURCES_FIT,
     TAINT_TOLERATION,
     TOPOLOGY_SPREAD,
 )
@@ -118,6 +129,13 @@ class CompiledProfile(NamedTuple):
     # bits, not by the float32 score. Set by the engine from the traces it is
     # built over (exact_score_bits), never by a user.
     exact_bits: int = 0
+    # An integer profile's (cpu, ram) units: the gcds of the build's requests
+    # and capacities, in which the integer scorers multiply
+    # (integer_score_units). Set by the engine like exact_bits.
+    units: Tuple[int, int] = (1, 1)
+    # How many PreferNoSchedule taint bits the build's node plane holds (from
+    # bit 30 downwards; trace_compile._compile_affinity). Set by the engine.
+    soft_taints: int = 0
 
 
 def _zero(x):
@@ -243,10 +261,22 @@ DEFAULT_PROFILE = CompiledProfile(
 
 def _supported() -> str:
     return (
-        f"the batched path supports filters {sorted(DEVICE_FILTER_PLUGINS)} and scorers "
-        f"{sorted(DEVICE_SCORE_PLUGINS)} (kubernetriks_tpu/batched/pipeline.py); run the scalar "
+        f"the batched path supports filters {sorted(DEVICE_FILTER_PLUGINS)}, scorers "
+        f"{sorted(DEVICE_SCORE_PLUGINS)} and, not mixed with them, integer scorers "
+        f"{sorted(INTEGER_SCORE_PLUGINS)} (kubernetriks_tpu/batched/pipeline.py); run the scalar "
         "backend for scalar-only plugins"
     )
+
+
+def is_integer_profile(profile: CompiledProfile) -> bool:
+    """Whether the profile scores by kube-scheduler's integer scorers (all of
+    its scorers then are: compile_profile refuses a mix)."""
+    return any(name in INTEGER_SCORE_PLUGINS for name, _ in profile.scores)
+
+
+def scores_softly(profile: CompiledProfile) -> bool:
+    """Whether the profile scores by NodeAffinity or TaintToleration."""
+    return any(name in (NODE_AFFINITY, TAINT_TOLERATION) for name, _ in profile.scores)
 
 
 def compile_profile(spec=None) -> CompiledProfile:
@@ -283,8 +313,26 @@ def compile_profile(spec=None) -> CompiledProfile:
                 f"scheduler profile {prof.name!r}: filter plugin {fname!r} "
                 f"has no device lowering — {_supported()}"
             )
+    integer = is_integer_profile(prof)
     for sname, weight in prof.scores:
-        if sname not in DEVICE_SCORE_PLUGINS:
+        if integer:
+            if sname in DEVICE_SCORE_PLUGINS:
+                raise UnsupportedProfileError(
+                    f"scheduler profile {prof.name!r}: float score plugin {sname!r} beside integer scorers "
+                    f"{[n for n, _ in prof.scores if n in INTEGER_SCORE_PLUGINS]}: integer scores are "
+                    "normalised to 0-100 and ranked in int32, the float ones are not; score by one kind"
+                )
+            if sname in INTEGER_SCORE_PLUGINS and (weight < 1 or weight != int(weight)):
+                raise UnsupportedProfileError(
+                    f"scheduler profile {prof.name!r}: integer score plugin {sname!r} has weight "
+                    f"{weight!r}; kube-scheduler's weights are positive integers"
+                )
+            if sname in (NODE_AFFINITY, TAINT_TOLERATION) and sname not in prof.filters:
+                raise UnsupportedProfileError(
+                    f"scheduler profile {prof.name!r} scores by {sname!r} without filtering by it: "
+                    "upstream's plugin is both halves; add it to the filters"
+                )
+        if sname not in DEVICE_SCORE_PLUGINS and sname not in INTEGER_SCORE_PLUGINS:
             raise UnsupportedProfileError(
                 f"scheduler profile {prof.name!r}: score plugin {sname!r} "
                 f"has no device lowering — {_supported()}"
@@ -460,6 +508,18 @@ _EXACT_BITS_MIN = 10
 _LOCKSTEP_MAX_UNITS = 1024
 
 
+def _resource_units(requests, capacities):
+    """(every cpu amount, every ram amount, their gcds) over iterables of
+    (cpu, ram) integer array pairs; the gcds 0 where there is no amount."""
+    pairs = [
+        (np.asarray(c, np.int64).ravel(), np.asarray(r, np.int64).ravel())
+        for c, r in (*requests, *capacities)
+    ]
+    cpus = np.concatenate([c for c, _ in pairs]) if pairs else np.zeros(0, np.int64)
+    rams = np.concatenate([r for _, r in pairs]) if pairs else np.zeros(0, np.int64)
+    return cpus, rams, int(np.gcd.reduce(cpus, initial=0)), int(np.gcd.reduce(rams, initial=0))
+
+
 def exact_score_bits(profile: CompiledProfile, requests, capacities) -> int:
     """The `exact_bits` static for an engine built over these pods and nodes.
 
@@ -477,15 +537,9 @@ def exact_score_bits(profile: CompiledProfile, requests, capacities) -> int:
     cannot have it (another profile, a capacity too large for 10-bit
     digits) keeps float32 and says so in a warning, since its placements
     can then part from the scalar path's."""
-    pairs = [
-        (np.asarray(c, np.int64).ravel(), np.asarray(r, np.int64).ravel())
-        for c, r in (*requests, *capacities)
-    ]
-    if not pairs:
-        return 0
-    cpus = np.concatenate([c for c, _ in pairs])
-    rams = np.concatenate([r for _, r in pairs])
-    unit_cpu, unit_ram = int(np.gcd.reduce(cpus, initial=0)), int(np.gcd.reduce(rams, initial=0))
+    if is_integer_profile(profile):
+        return 0  # integer scores are exact as they are: no key to need
+    cpus, rams, unit_cpu, unit_ram = _resource_units(requests, capacities)
     if unit_cpu == 0 or unit_ram == 0:
         return 0  # no pod or no node asks for anything: every score ties
     largest = max(int(cpus.max()), int(rams.max()))
@@ -595,6 +649,195 @@ def exact_best_node(hi, lo, node_ok, iota, axis: int):
     return jnp.max(
         jnp.where(at_hi & (lo == least_lo) & node_ok, iota, jnp.int32(-1)), axis=axis, keepdims=True
     )
+
+
+# --- integer scorers: kube-scheduler's own -------------------------------------
+# docs/PARITY.md "Scoring as kube-scheduler scores" is the text; the scalar
+# plugins (core/scheduler/plugins.py) and the benchmark's reference are
+# written from it too. Everything is int32. cpu / ram / rc / rr are the state's
+# (millicores, RAM units); the products are made in units of the build's gcds
+# (profile.units). Any broadcast-compatible shapes with the node axis `axis`:
+# (Np, L) against (1, L) in the kernels (axis 0), (C, N) against (C, 1) in the
+# scan body (axis 1).
+
+INTEGER_PRODUCT_LIMIT = (2**31 - 1) // 100  # the largest cap_cpu * cap_ram, in units
+
+
+def integer_score_units(requests, capacities) -> Tuple[int, int]:
+    """The (cpu, ram) units of an integer profile's build: the gcd of every
+    request and capacity (iterables of (cpu, ram) integer array pairs, as
+    exact_score_bits takes them); 1 where nothing asks for anything."""
+    _, _, unit_cpu, unit_ram = _resource_units(requests, capacities)
+    return (max(unit_cpu, 1), max(unit_ram, 1))
+
+
+def to_units(x, unit: int):
+    """x / unit for an int32 x that is a whole multiple of `unit` (every
+    free, request and capacity of a build is one of its gcd): the power of
+    two shifted out, the odd part divided by multiplying with its inverse
+    modulo 2**32, which is exact for a multiple (k odd odd**-1 = k, int32
+    wraps). No float, two operations; a non-multiple gives garbage without a
+    trap, and only masked nodes hold one."""
+    shift = (unit & -unit).bit_length() - 1
+    odd = unit >> shift
+    if shift:
+        x = x >> jnp.int32(shift)
+    if odd != 1:
+        inverse = pow(odd, -1, 2**32)
+        x = x * jnp.int32(inverse - 2**32 if inverse >= 2**31 else inverse)
+    return x
+
+
+def biased_reciprocal(den):
+    """floor_quotient's float32 estimate of (1 + 2**-16) / den, the divisor
+    guarded to 1 where den is not positive."""
+    return jnp.float32(_ESTIMATE_BIAS) / jnp.where(den > _zero(den), den, jnp.int32(1)).astype(
+        jnp.float32
+    )
+
+
+def floor_quotient(num, den, inv):
+    """floor(num / den) for int32 0 <= num, 0 < den, num / den < 2**14, with
+    `inv` = biased_reciprocal(den): ONE digit of _quotient_digits' long
+    division (its argument, at bits = 0 and a quotient that may pass 1). The
+    estimate trunc(float32(num) * inv) is the quotient or one over it: the
+    bias lifts it by q 2**-16 (under a quarter), which covers the three
+    float32 roundings (num to float32, inv, the product: 2**-24 of the value
+    each, even an inv 32 ulp off) so it is never under q, and q (1 + 2**-16 +
+    2**-17.7) < q + 0.33 keeps it under floor(q) + 2. Its integer remainder
+    num - estimate den is in [-den, den) (int32 wraps and the difference is
+    exact), and its sign alone corrects the estimate: no float decides the
+    result. Garbage without a trap where the precondition fails (a node the
+    pod does not fit): the callers mask those."""
+    estimate = (num.astype(jnp.float32) * inv).astype(jnp.int32)
+    return estimate + ((num - estimate * den) >> jnp.int32(31))
+
+
+class IntegerNodes(NamedTuple):
+    """What the resource scorers know of the nodes beside their frees, made
+    once a launch (a cycle, in the scan) from the capacity planes: the
+    capacities in units, their product, and the three biased reciprocals."""
+
+    cap_cpu: jnp.ndarray
+    cap_ram: jnp.ndarray
+    whole: jnp.ndarray  # cap_cpu * cap_ram
+    inv_cpu: jnp.ndarray
+    inv_ram: jnp.ndarray
+    inv_whole: jnp.ndarray
+
+
+def integer_nodes(cap_cpu, cap_ram, units: Tuple[int, int]) -> IntegerNodes:
+    cap_cpu, cap_ram = to_units(cap_cpu, units[0]), to_units(cap_ram, units[1])
+    whole = cap_cpu * cap_ram
+    return IntegerNodes(
+        cap_cpu, cap_ram, whole,
+        biased_reciprocal(cap_cpu), biased_reciprocal(cap_ram), biased_reciprocal(whole),
+    )
+
+
+class SoftFacts(NamedTuple):
+    """What the two label scorers read for one candidate: the node plane
+    (AffinityState.node_bits), the candidate's preferred-term masks, its
+    packed weights and its untolerated PreferNoSchedule taint bits, and how
+    many such taints the build's node plane holds (a static)."""
+
+    node_bits: jnp.ndarray
+    terms: Tuple[jnp.ndarray, ...]
+    weights: jnp.ndarray
+    forbid: jnp.ndarray
+    n_taints: int
+
+
+SOFT_WEIGHT_BITS = 7  # trace_compile packs a preferred term's weight so
+SOFT_TAINT_TOP_BIT = 30  # and puts the PreferNoSchedule taints from here down
+
+
+def soft_raw_scores(soft: SoftFacts):
+    """(NodeAffinity's, TaintToleration's) raw scores a node: the weights of
+    the preferred terms its labels match, summed; its PreferNoSchedule taints
+    the candidate does not tolerate, counted."""
+    i0, i1 = jnp.int32(0), jnp.int32(1)
+    affinity = None
+    for t, want in enumerate(soft.terms):
+        weight = (soft.weights >> jnp.int32(SOFT_WEIGHT_BITS * t)) & jnp.int32(2**SOFT_WEIGHT_BITS - 1)
+        term = jnp.where((soft.node_bits & want) == want, weight, i0)
+        affinity = term if affinity is None else affinity + term
+    untolerated = soft.node_bits & soft.forbid
+    taints = None
+    for j in range(soft.n_taints):
+        bit = (untolerated >> jnp.int32(SOFT_TAINT_TOP_BIT - j)) & i1
+        taints = bit if taints is None else taints + bit
+    return affinity, taints
+
+
+def _normalized(raw, fit, axis: int, reverse: bool):
+    """(upstream's DefaultNormalizeScore over the nodes in `fit`, the largest
+    raw score there): raw as its share of the largest, in whole points."""
+    i0, hundred = jnp.int32(0), jnp.int32(100)
+    most = jnp.max(jnp.where(fit, raw, i0), axis=axis, keepdims=True)
+    share = jnp.where(most > i0, floor_quotient(raw * hundred, most, biased_reciprocal(most)), i0)
+    return (hundred - share if reverse else share), most
+
+
+def integer_scores(profile: CompiledProfile, fit, cpu, ram, rc, rr, nodes: IntegerNodes, soft, axis: int):
+    """(total, soft part, soft attempt): the profile's weighted integer score,
+    -1 off the fit set; of it the label scorers' part (None without `soft`,
+    the build's SoftFacts or None) and whether either had something to
+    normalise by (M > 0) for this candidate."""
+    i0, hundred = jnp.int32(0), jnp.int32(100)
+    weights = {name: jnp.int32(int(weight)) for name, weight in profile.scores}
+    free_cpu = to_units(cpu - rc, profile.units[0])
+    free_ram = to_units(ram - rr, profile.units[1])
+    total = None
+
+    def add(total, name, score):
+        part = score * weights[name]
+        return part if total is None else total + part
+
+    if NODE_RESOURCES_FIT in weights:
+        left_cpu = jnp.where(
+            nodes.cap_cpu > i0, floor_quotient(free_cpu * hundred, nodes.cap_cpu, nodes.inv_cpu), i0
+        )
+        left_ram = jnp.where(
+            nodes.cap_ram > i0, floor_quotient(free_ram * hundred, nodes.cap_ram, nodes.inv_ram), i0
+        )
+        total = add(total, NODE_RESOURCES_FIT, (left_cpu + left_ram) >> jnp.int32(1))
+    if BALANCED_ALLOCATION in weights:
+        # U_cpu A_ram - U_ram A_cpu with U = A - free: the capacities cancel.
+        skew = free_ram * nodes.cap_cpu - free_cpu * nodes.cap_ram
+        skew = jnp.maximum(skew, -skew)
+        score = floor_quotient(hundred * nodes.whole - jnp.int32(50) * skew, nodes.whole, nodes.inv_whole)
+        total = add(total, BALANCED_ALLOCATION, jnp.where(nodes.whole > i0, score, i0))
+    part = attempt = None
+    if soft is not None:
+        raw_affinity, raw_taints = soft_raw_scores(soft)
+        if NODE_AFFINITY in weights and raw_affinity is not None:
+            score, most = _normalized(raw_affinity, fit, axis, reverse=False)
+            part, attempt = add(part, NODE_AFFINITY, score), most > i0
+        if TAINT_TOLERATION in weights and raw_taints is not None:
+            score, most = _normalized(raw_taints, fit, axis, reverse=True)
+            part = add(part, TAINT_TOLERATION, score)
+            attempt = most > i0 if attempt is None else attempt | (most > i0)
+    if part is not None:
+        total = part if total is None else total + part
+    return jnp.where(fit, total, jnp.int32(-1)), part, attempt
+
+
+def integer_best_node(total, node_ok, iota, axis: int):
+    """Highest node slot among the largest totals along `axis`: the
+    last-max-wins argmax on integer scores."""
+    most = jnp.max(total, axis=axis, keepdims=True)
+    return jnp.max(jnp.where((total == most) & node_ok, iota, jnp.int32(-1)), axis=axis, keepdims=True)
+
+
+def soft_honoured(part, fit, chosen, axis: int):
+    """Whether the node the candidate went to (`chosen`, one-hot along
+    `axis`, all false where it went nowhere) has the largest label score
+    among the nodes in `fit`."""
+    neg1 = jnp.int32(-1)
+    best = jnp.max(jnp.where(fit, part, neg1), axis=axis, keepdims=True)
+    got = jnp.max(jnp.where(chosen, part, neg1), axis=axis, keepdims=True)
+    return (got == best) & (got >= jnp.int32(0))
 
 
 def profile_fit_score(profile: CompiledProfile, alive, cpu, ram, rc, rr, facts=None):

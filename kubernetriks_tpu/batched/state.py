@@ -281,6 +281,15 @@ class MetricArrays(NamedTuple):
     # ClusterBatchState.spread).
     events_deep: Optional[jnp.ndarray] = None  # int32
     events_compacted: Optional[jnp.ndarray] = None  # int32
+    # The label scorers' two counters (pipeline.integer_scores; docs/PARITY.md
+    # "Scoring as kube-scheduler scores"): a cycle's valid candidates for
+    # which NodeAffinity or TaintToleration had something to normalise by (a
+    # feasible node matched a preferred term or carries an untolerated
+    # PreferNoSchedule taint), and those of them placed on a node whose label
+    # score is the largest among the feasible ones. None in a build without
+    # soft planes (AffinityState.pod_soft_terms), which traces neither.
+    soft_attempts: Optional[jnp.ndarray] = None  # int32
+    soft_honoured: Optional[jnp.ndarray] = None  # int32
 
 
 class SpreadState(NamedTuple):
@@ -343,6 +352,16 @@ class AffinityState(NamedTuple):
     # filter of the chain (the labels and taints, not capacity, refused it).
     attempts: jnp.ndarray  # (C,) int32
     attempts_refused: jnp.ndarray  # (C,) int32
+    # The score halves' planes (trace_compile.CompiledAffinity.soft_*), in the
+    # pod planes' global coordinates; None unless the profile scores by
+    # NodeAffinity or TaintToleration AND a trace carries a preferred term or
+    # a PreferNoSchedule taint (the taints are bits of node_bits, from bit 30
+    # downwards). (C, S, W) a pod's s-th preferred term's mask; (C, W) its
+    # terms' weights, seven bits each; (C, W) the PreferNoSchedule taint bits
+    # it does not tolerate.
+    pod_soft_terms: Optional[jnp.ndarray] = None
+    pod_soft_weights: Optional[jnp.ndarray] = None
+    pod_soft_forbid: Optional[jnp.ndarray] = None
 
 
 class ClusterBatchState(NamedTuple):
@@ -1012,6 +1031,8 @@ AXIS_SIGNATURES = {
     "cycle_compacted": "C",
     "events_deep": "C",
     "events_compacted": "C",
+    "soft_attempts": "C",
+    "soft_honoured": "C",
     "resched_rank_windows": "C",
     "resched_rank_sorted": "C",
 }

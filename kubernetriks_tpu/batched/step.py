@@ -1517,10 +1517,27 @@ def affinity_window_view(affinity, pod_base: jnp.ndarray, P: int):
     planes = tuple(affinity.pod_terms[:, t, :] for t in range(affinity.pod_terms.shape[1])) + (
         affinity.pod_forbid,
     )
+    return _window_columns(planes, pod_base, P)
+
+
+def _window_columns(planes, pod_base: jnp.ndarray, P: int):
     if planes[0].shape[1] == P:
         return planes
     cut = jax.vmap(lambda row, lo: jax.lax.dynamic_slice(row, (lo,), (P,)))
     return tuple(cut(x, pod_base) for x in planes)
+
+
+def soft_window_view(affinity, pod_base: jnp.ndarray, P: int):
+    """affinity_window_view for the score halves' planes: (the preferred-term
+    planes..., the packed weights, the untolerated soft taints), (C, P) each,
+    the order the kernels' wrappers take them in after the two capacity
+    planes."""
+    terms = affinity.pod_soft_terms
+    planes = tuple(terms[:, t, :] for t in range(terms.shape[1])) + (
+        affinity.pod_soft_weights,
+        affinity.pod_soft_forbid,
+    )
+    return _window_columns(planes, pod_base, P)
 
 
 @jax.named_scope("spread_counts")
@@ -1581,6 +1598,9 @@ class CycleCandidates(NamedTuple):
     # (C, K) the candidates' node-affinity term masks and, last, their
     # untolerated-taint masks; None in a build without the label filters.
     affinity: Optional[Tuple[jnp.ndarray, ...]] = None
+    # (C, K) the candidates' preferred-term masks, packed weights and
+    # untolerated soft taints; None in a build without soft planes.
+    soft: Optional[Tuple[jnp.ndarray, ...]] = None
 
 
 def cycle_durations(pod_sched_time, K: int, first=0):
@@ -1786,13 +1806,15 @@ def candidates_from_slots(
     consts: StepConstants,
     spread_pods=None,
     affinity_pods=None,
+    soft_pods=None,
 ) -> CycleCandidates:
     """Assemble CycleCandidates from chosen candidate slots — the gathers
     and the `waited` formula shared by the sorted path and the in-kernel
     selection path (ONE definition, so the paths cannot drift).
     `spread_pods`: the window's (workload, match bits) planes where the
     build runs the spread filter; `affinity_pods`: its term and
-    untolerated-taint planes where it runs the label filters."""
+    untolerated-taint planes where it runs the label filters; `soft_pods`:
+    its soft planes where it scores by them."""
     C = cand.shape[0]
     rows = jnp.arange(C, dtype=jnp.int32)[:, None]
     interval = jnp.float32(consts.scheduling_interval)
@@ -1819,6 +1841,7 @@ def candidates_from_slots(
             if affinity_pods is None
             else dict(affinity=tuple(x[rows, cand] for x in affinity_pods))
         ),
+        **({} if not soft_pods else dict(soft=tuple(x[rows, cand] for x in soft_pods))),
     )
 
 
@@ -2117,7 +2140,8 @@ def _put_lanes(full, part, slot, moved):
 
 
 def _launch_by_depth(
-    launch, nodes, eligible, pod_planes, pod_time, spread, n_eligible, K, lane_major, affinity=None
+    launch, nodes, eligible, pod_planes, pod_time, spread, n_eligible, K, lane_major, affinity=None,
+    kube=None,
 ):
     """The megakernel's launch, its lanes chosen by depth. A grid program of
     the kernel holds one tile of clusters (ops/scheduler_kernel._LANE) and
@@ -2153,14 +2177,16 @@ def _launch_by_depth(
     nodes: (alive, alloc_cpu, alloc_ram); pod_planes: the eight (C, P)
     planes after `eligible` in the wrapper's order; pod_time (C,);
     spread: the wrapper's six operands or None; affinity: the label
-    filters' operands (the node plane, then the pod planes) or None. Returns
+    filters' operands (the node plane, then the pod planes) or None; kube:
+    the integer scorers' (the two capacity planes, then the pod planes) or
+    None. Returns
     (the wrapper's outputs, moved (C,) bool: the clusters a second launch
     drained)."""
     from kubernetriks_tpu.ops.scheduler_kernel import _LANE as R
 
     C = eligible.shape[0]
     if -(-C // R) == 1:
-        return launch(nodes, eligible, pod_planes, pod_time, spread, affinity), jnp.zeros(
+        return launch(nodes, eligible, pod_planes, pod_time, spread, affinity, kube), jnp.zeros(
             (C,), jnp.bool_
         )
 
@@ -2185,6 +2211,7 @@ def _launch_by_depth(
     in_axes = (n_axis,) * 3 + (0,) * (2 + len(pod_planes))
     spread_axes = (n_axis, 0, 0, 0, 0, 0)
     affinity_axes = (n_axis,) + (0,) * (len(affinity) - 1) if affinity else ()
+    kube_axes = (n_axis,) * 2 + (0,) * (len(kube) - 2) if kube else ()
     # The pads are the wrapper's own, made a step early: the same device phase.
     with jax.named_scope("kernel_io"):
         operands = tuple(
@@ -2198,8 +2225,9 @@ def _launch_by_depth(
         affinity_tiles = affinity and tuple(
             tiles(x, axis) for x, axis in zip(affinity, affinity_axes)
         )
+        kube_tiles = kube and tuple(tiles(x, axis) for x, axis in zip(kube, kube_axes))
 
-    def launch_on(operands, spread_tiles, affinity_tiles):
+    def launch_on(operands, spread_tiles, affinity_tiles, kube_tiles):
         """The wrapper on planes that hold the cluster axis last; its outputs
         the same way."""
         ops = clusters_first(operands, in_axes)
@@ -2207,6 +2235,7 @@ def _launch_by_depth(
             ops[:3], ops[3], ops[4:-1], ops[-1],
             spread_tiles and clusters_first(spread_tiles, spread_axes),
             affinity_tiles and clusters_first(affinity_tiles, affinity_axes),
+            kube_tiles and clusters_first(kube_tiles, kube_axes),
         )
         out_axes = (n_axis, n_axis) + (0,) * (len(outputs) - 2)
         return tuple(jnp.moveaxis(x, axis, -1) for x, axis in zip(outputs, out_axes))
@@ -2217,17 +2246,19 @@ def _launch_by_depth(
             operands[:3] + (jnp.where(moves, 0, operands[3]),) + operands[4:],
             spread_tiles,
             affinity_tiles,
+            kube_tiles,
         )
         slot, index = _tile_slots(moves, R)
         second = launch_on(
             tuple(_take_lanes(x, index) for x in operands),
             spread_tiles and tuple(_take_lanes(x, index) for x in spread_tiles),
             affinity_tiles and tuple(_take_lanes(x, index) for x in affinity_tiles),
+            kube_tiles and tuple(_take_lanes(x, index) for x in kube_tiles),
         )
         return tuple(_put_lanes(a, b, slot, moves) for a, b in zip(first, second))
 
     outputs = jax.lax.cond(
-        moved.any(), split, lambda: launch_on(operands, spread_tiles, affinity_tiles)
+        moved.any(), split, lambda: launch_on(operands, spread_tiles, affinity_tiles, kube_tiles)
     )
     out_axes = (n_axis, n_axis) + (0,) * (len(outputs) - 2)
     with jax.named_scope("kernel_io"):
@@ -2268,6 +2299,9 @@ class _CyclePasses(NamedTuple):
     # (C, 2) the label filters' counters: attempts of pods that name their
     # nodes, and those of them the labels and taints alone refused.
     named: Optional[jnp.ndarray] = None
+    # (C, 2) the label scorers' counters: decisions one of them had something
+    # to normalise by, and those of them honoured.
+    soft: Optional[jnp.ndarray] = None
 
 
 @jax.named_scope("cycle")
@@ -2362,6 +2396,17 @@ def _run_scheduling_cycle(
     af = state.affinity
     affinity_pods = None if af is None else affinity_window_view(af, state.pod_base, P)
     affinity_all = None if af is None else (af.node_bits,) + affinity_pods
+    # The integer scorers (a profile that ranks as kube-scheduler does): the
+    # two capacity planes, and the window's soft planes where the build has
+    # them (then `af` is there too: its node plane holds the bits).
+    from kubernetriks_tpu.batched.pipeline import is_integer_profile
+
+    kube_nodes = soft_pods = None
+    if is_integer_profile(profile):
+        kube_nodes = (state.nodes.cap_cpu, state.nodes.cap_ram)
+        if af is not None and af.pod_soft_terms is not None:
+            soft_pods = soft_window_view(af, state.pod_base, P)
+    kube_all = kube_nodes and kube_nodes + (soft_pods or ())
 
     pods, last_flush_win, eligible = prepare_queue(
         state, W, consts, conditional_move, wake, lane_major=lane_major
@@ -2386,7 +2431,7 @@ def _run_scheduling_cycle(
             W[:, None] - pods.initial_attempt_ts.win
         ).astype(jnp.float32) * interval - pods.initial_attempt_ts.off
 
-        def launch(nodes, eligible, pod_planes, pod_time, spread, affinity):
+        def launch(nodes, eligible, pod_planes, pod_time, spread, affinity, kube=None):
             # The wrapper reads column 0 of its last K-shaped operand alone.
             time_k = jnp.broadcast_to(pod_time[:, None], (pod_time.shape[0], K))
             return fused_select_cycle_commit(
@@ -2402,6 +2447,7 @@ def _run_scheduling_cycle(
                 profile=profile,
                 spread=spread,
                 affinity=affinity,
+                kube=kube,
             )
 
         (
@@ -2427,7 +2473,9 @@ def _run_scheduling_cycle(
             K,
             lane_major,
             affinity_all,
+            kube=kube_all,
         )
+        soft_out = placed.pop() if soft_pods else None
         named_out = placed.pop() if af is not None else None
         start_tmp = start_tmp + jnp.float32(consts.delta_bind_start)
         totals = CycleTotals(
@@ -2479,6 +2527,7 @@ def _run_scheduling_cycle(
                     profile=profile,
                     spread=None if sp is None else spread_nodes(acc.spread[0]) + spread_pods,
                     affinity=affinity_all,
+                    kube=kube_all,
                 )
             )
             cc = candidates_from_slots(pods, last_flush_win, cand, valid, W, consts)
@@ -2488,7 +2537,7 @@ def _run_scheduling_cycle(
             cand = jax.lax.dynamic_slice_in_dim(order, first, K, axis=1)
             valid = (first + jnp.arange(K, dtype=jnp.int32))[None, :] < n_eligible[:, None]
             return candidates_from_slots(
-                pods, last_flush_win, cand, valid, W, consts, spread_pods, affinity_pods
+                pods, last_flush_win, cand, valid, W, consts, spread_pods, affinity_pods, soft_pods
             )
 
         def decide_candidates(acc, first):
@@ -2511,6 +2560,7 @@ def _run_scheduling_cycle(
                 if sp is None
                 else spread_nodes(acc.spread[0]) + (cc.spread_group, cc.spread_bits),
                 affinity=None if af is None else (af.node_bits,) + cc.affinity,
+                kube=kube_nodes and kube_nodes + (cc.soft or ()),
             )
             return cc, assign_k, cc.valid & ~fitany_k, best_k, alloc_cpu, alloc_ram, placed_k
 
@@ -2521,11 +2571,16 @@ def _run_scheduling_cycle(
             # layout copies either way.
             from kubernetriks_tpu.batched.pipeline import (
                 NodeFacts,
+                SoftFacts,
                 affinity_names_nodes,
                 affinity_node_masks,
                 exact_best_node,
                 exact_least_allocated_key,
+                integer_best_node,
+                integer_nodes,
+                integer_scores,
                 profile_fit_mask,
+                soft_honoured,
                 profile_fit_score,
                 spread_alive_tile,
                 spread_node_mask,
@@ -2550,6 +2605,11 @@ def _run_scheduling_cycle(
             n_spread = 0 if sp is None else 2
             if af is not None:
                 node_bits_x = af.node_bits.T if lane_major else af.node_bits
+            n_affinity = 0 if af is None else len(cc.affinity)
+            if kube_nodes is not None:
+                caps = integer_nodes(
+                    *(x.T if lane_major else x for x in kube_nodes), profile.units
+                )
 
             def body(carry, xs):
                 alloc_cpu, alloc_ram, tiles = carry
@@ -2577,13 +2637,33 @@ def _run_scheduling_cycle(
                 if af is not None:
                     # As the kernels' core (_fit_score_place): the chain
                     # without the two label filters, then with them.
-                    *terms, forbid = (x[:, None] for x in cand_planes[n_spread:])
+                    *terms, forbid = (
+                        x[:, None] for x in cand_planes[n_spread : n_spread + n_affinity]
+                    )
                     rest = profile_fit_mask(profile, alive_x, *nodes_and_pod, facts)
                     affinity_ok, taints_ok = affinity_node_masks(node_bits_x, terms, forbid)
                     facts = (facts or NodeFacts())._replace(
                         affinity_ok=affinity_ok, taints_ok=taints_ok
                     )
-                if profile.exact_bits:
+                part = None
+                if kube_nodes is not None:
+                    # As the kernels' core: integer scores, the label scorers'
+                    # normalised over the feasible nodes (reductions over the
+                    # node axis), an integer argmax.
+                    fit = profile_fit_mask(profile, alive_x, *nodes_and_pod, facts)
+                    soft = None
+                    if soft_pods:
+                        *wants, weights, soft_forbid = (
+                            x[:, None] for x in cand_planes[n_spread + n_affinity :]
+                        )
+                        soft = SoftFacts(
+                            node_bits_x, tuple(wants), weights, soft_forbid, profile.soft_taints
+                        )
+                    total, part, soft_attempt = integer_scores(
+                        profile, fit, *nodes_and_pod, caps, soft, axis=1
+                    )
+                    best = integer_best_node(total, True, iota_n, axis=1)[:, 0]
+                elif profile.exact_bits:
                     fit = profile_fit_mask(profile, alive_x, *nodes_and_pod, facts)
                     hi, lo = exact_least_allocated_key(fit, *nodes_and_pod, profile.exact_bits)
                     best = exact_best_node(hi, lo, True, iota_n, axis=1)[:, 0]
@@ -2614,6 +2694,13 @@ def _run_scheduling_cycle(
                         attempt.astype(jnp.int32)
                         + 2 * (attempt & ~any_fit & rest.any(axis=1)).astype(jnp.int32),
                     )
+                if part is not None:
+                    chosen = assign[:, None] & (iota_n == best[:, None])
+                    soft_attempt = valid & soft_attempt[:, 0]
+                    outs += (
+                        soft_attempt.astype(jnp.int32)
+                        + 2 * (soft_attempt & soft_honoured(part, fit, chosen, axis=1)[:, 0]).astype(jnp.int32),
+                    )
                 return (alloc_cpu, alloc_ram, tiles), outs
 
             xs = (cc.valid.T, cc.req_cpu.T, cc.req_ram.T)
@@ -2621,6 +2708,8 @@ def _run_scheduling_cycle(
                 xs += (cc.spread_group.T, cc.spread_bits.T)
             if af is not None:
                 xs += tuple(x.T for x in cc.affinity)
+            if soft_pods:
+                xs += tuple(x.T for x in cc.soft)
             (alloc_cpu, alloc_ram, tiles), outs = jax.lax.scan(body, (acpu0, aram0, tiles0), xs)
             assign_k, park_k, best_k, *placed_k = (o.T for o in outs)
             if lane_major:
@@ -2648,7 +2737,9 @@ def _run_scheduling_cycle(
                 pallas_interpret=pallas_interpret,
             )
             n_assigned = assign_k.sum(axis=1, dtype=jnp.int32)
-            named_acc = None
+            soft_acc = named_acc = None
+            if soft_pods:
+                soft_acc = acc.soft + _flag_counts(placed_k.pop())
             if af is not None:
                 named_acc = acc.named + _flag_counts(placed_k.pop())
             spread_acc = None
@@ -2679,6 +2770,7 @@ def _run_scheduling_cycle(
                 late=acc.late + jnp.where(acc.passes > 0, n_assigned, 0),
                 spread=spread_acc,
                 named=named_acc,
+                soft=soft_acc,
             )
 
         zeros_c = jnp.zeros((C,), jnp.int32)
@@ -2700,6 +2792,7 @@ def _run_scheduling_cycle(
                 late=zeros_c,
                 spread=None if sp is None else (counts0, zone_w, jnp.zeros((C, 2), jnp.int32)),
                 named=None if af is None else jnp.zeros((C, 2), jnp.int32),
+                soft=jnp.zeros((C, 2), jnp.int32) if soft_pods else None,
             ),
         )
         alloc_cpu, alloc_ram = acc.alloc_cpu, acc.alloc_ram
@@ -2717,10 +2810,16 @@ def _run_scheduling_cycle(
         )
         spread_out = None if sp is None else acc.spread[1:]
         named_out = acc.named
+        soft_out = acc.soft
 
     metrics = fold_cycle_totals(
         state.metrics, totals, n_eligible, pod_sched_time, consts, K, compacted
     )
+    if soft_out is not None:
+        metrics = metrics._replace(
+            soft_attempts=metrics.soft_attempts + soft_out[:, 0],
+            soft_honoured=metrics.soft_honoured + soft_out[:, 1],
+        )
     new_state = commit_scattered_tail(
         state, pods, last_flush_win, W, consts, alloc_cpu, alloc_ram,
         metrics, phase, node, start_tmp, park_tmp,

@@ -40,6 +40,7 @@ from kubernetriks_tpu.core.events import (
     RemoveNodeRequest,
     RemovePodRequest,
 )
+from kubernetriks_tpu.batched.pipeline import SOFT_TAINT_TOP_BIT, SOFT_WEIGHT_BITS
 from kubernetriks_tpu.core.scheduler.plugins import node_taints
 from kubernetriks_tpu.trace.interface import TraceEvents
 
@@ -175,6 +176,12 @@ AFFINITY_MAX_BITS = 31
 AFFINITY_MAX_TERMS = 4
 AFFINITY_NO_TERM = np.int32(-(2**31))  # an unused term plane's mask: bit 31, which no node has
 AFFINITY_NAMES_NODES = np.int32(-(2**31))  # in a pod's untolerated mask: the pod carries a term or a toleration
+# The score halves (docs/PARITY.md "Scoring as kube-scheduler scores"): a
+# pod's preferred terms are planes of their own, their weights (1-100) packed
+# SOFT_WEIGHT_BITS a term into one word; the PreferNoSchedule taints take the
+# node plane's top bits, SOFT_TAINT_TOP_BIT downwards, so that their count
+# needs no table (pipeline.soft_raw_scores reads both).
+SOFT_MAX_TERMS = 4
 
 
 @dataclass
@@ -196,32 +203,67 @@ class CompiledAffinity:
     # it carries none of them), with AFFINITY_NAMES_NODES where the pod
     # carries a selector, an affinity or a toleration.
     pod_forbid: np.ndarray
+    # The score halves; None where the trace has neither a preferred term nor
+    # a PreferNoSchedule taint. soft_taints: the distinct PreferNoSchedule
+    # (key, value), the i-th at bit SOFT_TAINT_TOP_BIT - i of the node plane;
+    # soft_terms (S, P): the bits a node must carry to match the pod's s-th
+    # preferred term (AFFINITY_NO_TERM: it has none); soft_weights (P,): the
+    # terms' weights, SOFT_WEIGHT_BITS bits each; soft_forbid (P,): the
+    # PreferNoSchedule taint bits the pod does not tolerate.
+    soft_taints: List[Tuple[str, str]] = field(default_factory=list)
+    soft_terms: Optional[np.ndarray] = None
+    soft_weights: Optional[np.ndarray] = None
+    soft_forbid: Optional[np.ndarray] = None
+    # The first pod with a preferred term and the first node with a
+    # PreferNoSchedule taint, for the refusal of a profile that would ignore
+    # them (engine._build_affinity).
+    first_preferring: Optional[str] = None
+    first_soft_tainted: Optional[str] = None
 
 
-def _compile_affinity(node_labels, taints_of_node, pods, n_nodes: int) -> Optional[CompiledAffinity]:
+def _compile_affinity(
+    node_labels, taints_of_node, pods, n_nodes: int, soft_taints_of_node=None, node_names=None
+) -> Optional[CompiledAffinity]:
     """Intern one trace's expressions and taints to bits. `node_labels` /
-    `taints_of_node`: the label dict and the NoSchedule (key, value) taints of
-    each node slot; `pods`: the Pod of each pod slot (None for a slot no
-    CreatePod names). Raises what NodeAffinity and TaintToleration refuse."""
+    `taints_of_node` / `soft_taints_of_node`: the label dict and the
+    NoSchedule and PreferNoSchedule (key, value) taints of each node slot;
+    `pods`: the Pod of each pod slot (None for a slot no CreatePod names).
+    Raises what NodeAffinity and TaintToleration refuse."""
     from kubernetriks_tpu.core.scheduler.plugins import (
         expression_matches,
         supported_node_terms,
+        supported_preferred_terms,
         supported_tolerations,
         tolerates,
     )
 
     terms_of = [None if pod is None else supported_node_terms(pod) for pod in pods]
+    preferred_of = [() if pod is None else supported_preferred_terms(pod) for pod in pods]
     tolerations_of = [() if pod is None else supported_tolerations(pod) for pod in pods]
     taints = sorted({t for ts in taints_of_node for t in ts})
-    if not taints and not any(terms_of) and not any(tolerations_of):
+    soft_taints_of_node = soft_taints_of_node or [()] * len(taints_of_node)
+    soft_taints = sorted({t for ts in soft_taints_of_node for t in ts})
+    soft = bool(soft_taints) or any(preferred_of)
+    if not taints and not soft and not any(terms_of) and not any(tolerations_of):
         return None
-    expressions = sorted({e for terms in terms_of if terms for term in terms for e in term})
+    expressions = sorted(
+        {e for terms in terms_of if terms for term in terms for e in term}
+        | {e for preferred in preferred_of for _, term in preferred for e in term}
+    )
     most_terms = max((len(terms) for terms in terms_of if terms), default=1)
-    if len(expressions) + len(taints) > AFFINITY_MAX_BITS:
+    if len(expressions) + len(taints) + len(soft_taints) > AFFINITY_MAX_BITS:
         raise ValueError(
-            f"{len(expressions)} distinct node selector expressions and {len(taints)} distinct taints in one "
-            f"trace (the first: {(expressions + taints)[0]!r}): more than the node plane's "
+            f"{len(expressions)} distinct node selector expressions and {len(taints) + len(soft_taints)} "
+            f"distinct taints in one "
+            f"trace (the first: {(expressions + taints + soft_taints)[0]!r}): more than the node plane's "
             f"{AFFINITY_MAX_BITS} bits hold"
+        )
+    most_preferred = max((len(preferred) for preferred in preferred_of), default=0)
+    if most_preferred > SOFT_MAX_TERMS:
+        worst = next(pod for pod, p in zip(pods, preferred_of) if len(p) == most_preferred)
+        raise ValueError(
+            f"pod {worst.metadata.name!r}: {most_preferred} preferred terms, more than the "
+            f"{SOFT_MAX_TERMS} preferred-term planes a build holds"
         )
     if most_terms > AFFINITY_MAX_TERMS:
         worst = next(pod for pod, terms in zip(pods, terms_of) if terms and len(terms) == most_terms)
@@ -231,6 +273,7 @@ def _compile_affinity(node_labels, taints_of_node, pods, n_nodes: int) -> Option
         )
     bit_of = {e: np.int32(1 << i) for i, e in enumerate(expressions)}
     taint_bit = {t: np.int32(1 << (len(expressions) + i)) for i, t in enumerate(taints)}
+    soft_bit = {t: np.int32(1 << (SOFT_TAINT_TOP_BIT - i)) for i, t in enumerate(soft_taints)}
     bits_of_labels: Dict[Tuple, int] = {}
 
     def label_bits(labels: Dict[str, str]) -> int:
@@ -243,8 +286,14 @@ def _compile_affinity(node_labels, taints_of_node, pods, n_nodes: int) -> Option
         return got
 
     node_bits = np.zeros(n_nodes, np.int32)
-    for slot, (labels, carried) in enumerate(zip(node_labels, taints_of_node)):
-        node_bits[slot] = label_bits(labels) + sum(int(taint_bit[t]) for t in carried)
+    for slot, (labels, carried, soft_carried) in enumerate(
+        zip(node_labels, taints_of_node, soft_taints_of_node)
+    ):
+        node_bits[slot] = (
+            label_bits(labels)
+            + sum(int(taint_bit[t]) for t in carried)
+            + sum(int(soft_bit[t]) for t in soft_carried)
+        )
     pod_terms = np.full((most_terms, len(pods)), AFFINITY_NO_TERM, np.int32)
     pod_forbid = np.zeros(len(pods), np.int32)
     every_taint = sum(int(b) for b in taint_bit.values())
@@ -267,10 +316,38 @@ def _compile_affinity(node_labels, taints_of_node, pods, n_nodes: int) -> Option
         pod_forbid[slot] = forbid
         if pod is not None and pod.spec.names_nodes():
             pod_forbid[slot] |= AFFINITY_NAMES_NODES
-    return CompiledAffinity(
+    compiled = CompiledAffinity(
         expressions=expressions, taints=taints, node_bits=node_bits,
         pod_terms=pod_terms, pod_forbid=pod_forbid,
     )
+    if not soft:
+        return compiled
+    compiled.soft_taints = soft_taints
+    compiled.soft_terms = np.full((max(most_preferred, 1), len(pods)), AFFINITY_NO_TERM, np.int32)
+    compiled.soft_weights = np.zeros(len(pods), np.int32)
+    compiled.soft_forbid = np.zeros(len(pods), np.int32)
+    every_soft = sum(int(b) for b in soft_bit.values())
+    soft_forbid_of: Dict[Tuple, int] = {}
+    for slot, (pod, preferred, tolerations) in enumerate(zip(pods, preferred_of, tolerations_of)):
+        for s, (weight, term) in enumerate(preferred):
+            compiled.soft_terms[s, slot] = sum(int(bit_of[e]) for e in term)
+            compiled.soft_weights[slot] |= weight << (SOFT_WEIGHT_BITS * s)
+        if preferred and compiled.first_preferring is None:
+            compiled.first_preferring = pod.metadata.name
+        memo = tuple((t.key, t.operator, t.value, t.effect) for t in tolerations)
+        forbid = soft_forbid_of.get(memo)
+        if forbid is None:
+            forbid = soft_forbid_of[memo] = (
+                every_soft
+                if not tolerations
+                else sum(int(soft_bit[t]) for t in soft_taints if not tolerates(tolerations, t, "PreferNoSchedule"))
+            )
+        compiled.soft_forbid[slot] = forbid
+    for slot, carried in enumerate(soft_taints_of_node):
+        if carried:
+            compiled.first_soft_tainted = node_names[slot] if node_names else f"node slot {slot}"
+            break
+    return compiled
 
 
 @dataclass
@@ -299,6 +376,11 @@ class CompiledClusterTrace:
     # the trace carries a taint and no pod a selector, an affinity or a
     # toleration.
     affinity: Optional[CompiledAffinity] = None
+    # The first node or pod whose RAM is not a whole number of ram_unit bytes
+    # ("node 'x'" / "pod 'y'"), None where every one is: the device holds the
+    # rounded number, which an integer score would tell from the bytes
+    # (engine: refused under an integer profile).
+    inexact_ram: Optional[str] = None
 
     @property
     def n_events(self) -> int:
@@ -463,14 +545,15 @@ def compile_cluster_trace(
     pod_slot: Dict[str, int] = {}
     pod_groups: List[CompiledPodGroup] = []
     crash_downtime_s: List[float] = []
-    # (labels, NoSchedule taints) of each node slot: what a slot's machine is
-    # beside its capacity.
-    node_labels: List[Tuple[Dict[str, str], Tuple]] = []
+    # (labels, NoSchedule taints, PreferNoSchedule taints) of each node slot:
+    # what a slot's machine is beside its capacity.
+    node_labels: List[Tuple[Dict[str, str], Tuple, Tuple]] = []
     pod_objects: List[object] = []
     # name -> (slot, the first window in which the slot may be created
     # again) of the name's last, removed incarnation.
     dead_node_slot: Dict[str, Tuple[int, int]] = {}
     window_of, free_chain = _slot_reuse_clock(config)
+    inexact_ram: Optional[str] = None
 
     for ts, _, event in merged:
         if isinstance(event, CreateNodeRequest):
@@ -479,7 +562,11 @@ def compile_cluster_trace(
             node = event.node
             name = node.metadata.name
             cap = (int(node.status.capacity.cpu), int(node.status.capacity.ram) // ram_unit)
-            labelled = (node.metadata.labels, node_taints(node))
+            if inexact_ram is None and int(node.status.capacity.ram) % ram_unit:
+                inexact_ram = f"node {name!r}"
+            labelled = (
+                node.metadata.labels, node_taints(node), node_taints(node, "PreferNoSchedule")
+            )
             slot = None
             if window_of is not None:
                 slot = _reusable_slot(
@@ -513,6 +600,8 @@ def compile_cluster_trace(
             requests = pod.spec.resources.requests
             pod_req_cpu.append(int(requests.cpu))
             pod_req_ram.append(-(-int(requests.ram) // ram_unit))  # ceil
+            if inexact_ram is None and int(requests.ram) % ram_unit:
+                inexact_ram = f"pod {pod.metadata.name!r}"
             duration = pod.spec.running_duration
             pod_duration.append(-1.0 if duration is None else float(duration))
             pod_names.append(pod.metadata.name)
@@ -596,7 +685,7 @@ def compile_cluster_trace(
             )
 
     spread = _compile_spread(
-        [labels for labels, _ in node_labels], pod_objects, len(node_cap_cpu)
+        [labels for labels, _, _ in node_labels], pod_objects, len(node_cap_cpu)
     )
     if spread is not None and pod_groups:
         raise ValueError(
@@ -604,10 +693,12 @@ def compile_cluster_trace(
             "run time would need labels of their own)"
         )
     affinity = _compile_affinity(
-        [labels for labels, _ in node_labels],
-        [taints for _, taints in node_labels],
+        [labels for labels, _, _ in node_labels],
+        [taints for _, taints, _ in node_labels],
         pod_objects,
         len(node_cap_cpu),
+        [soft for _, _, soft in node_labels],
+        node_names,
     )
     if affinity is not None and pod_groups:
         raise ValueError(
@@ -630,6 +721,7 @@ def compile_cluster_trace(
         crash_downtime_s=np.asarray(crash_downtime_s, np.float64) if crash_downtime_s else None,
         spread=spread,
         affinity=affinity,
+        inexact_ram=inexact_ram,
     ))
 
 

@@ -13,16 +13,23 @@ from kubernetriks_tpu.core.scheduler.interface import (
 )
 from kubernetriks_tpu.core.scheduler.plugins import (
     BALANCED,
+    BALANCED_ALLOCATION,
     FIT,
     FilterPlugin,
+    INTEGER_SCORE_PLUGINS,
     LEAST_ALLOCATED,
     MOST_ALLOCATED,
     NODE_AFFINITY,
+    NODE_RESOURCES_FIT,
     PLUGIN_REGISTRY,
     SchedulerCache,
     ScorePlugin,
     TAINT_TOLERATION,
     TOPOLOGY_SPREAD,
+    ignores_preferences,
+    node_taints,
+    unscored_preferred_term,
+    unscored_soft_taint,
 )
 from kubernetriks_tpu.core.types import Node, Pod
 
@@ -82,7 +89,55 @@ NAMED_PROFILE_SPECS: Dict[str, tuple] = {
     # pool behind a taint). Pods that carry none schedule as under "default"
     # on the untainted nodes.
     "node_pools": ((FIT, NODE_AFFINITY, TAINT_TOLERATION), ((LEAST_ALLOCATED, 1.0),)),
+    # kube-scheduler's own default profile (docs/PARITY.md "Scoring as
+    # kube-scheduler scores"): node_pools' filters, and its score plugins at
+    # their default weights, integers normalised over the feasible nodes.
+    # PodTopologySpread 2, InterPodAffinity 2 and ImageLocality 1 score every
+    # node alike here (no pod may carry ScheduleAnyway or an inter-pod term,
+    # no image is modelled) and are left out.
+    "kube_default": (
+        (FIT, NODE_AFFINITY, TAINT_TOLERATION),
+        (
+            (NODE_RESOURCES_FIT, 1.0),
+            (BALANCED_ALLOCATION, 1.0),
+            (NODE_AFFINITY, 2.0),
+            (TAINT_TOLERATION, 3.0),
+        ),
+    ),
 }
+
+# The reference's scorers: float64 here, float32 (or the exact key) on the
+# device. A profile scores by these or by INTEGER_SCORE_PLUGINS, not by both.
+FLOAT_SCORE_PLUGINS = (LEAST_ALLOCATED, MOST_ALLOCATED, BALANCED)
+
+
+def _check_score_kinds(filter_refs, score_refs) -> None:
+    """Refuse, by name, a profile that adds kube-scheduler's integer scores
+    to the reference's float ones, an integer scorer at a weight that is no
+    positive integer, and the score half of a label plugin without its
+    filter half."""
+    integer = [p for p in score_refs if p.name in INTEGER_SCORE_PLUGINS]
+    floats = [p.name for p in score_refs if p.name in FLOAT_SCORE_PLUGINS]
+    if not integer:
+        return
+    if floats:
+        raise ValueError(
+            f"scheduler profile mixes kube-scheduler's integer scorers {[p.name for p in integer]} with the "
+            f"reference's float scorers {floats}: integer scores are normalised to 0-100 and added by "
+            "integer weights, the float ones are not; score by one kind"
+        )
+    for p in integer:
+        weight = 1.0 if p.weight is None else p.weight
+        if weight < 1 or weight != int(weight):
+            raise ValueError(
+                f"scheduler profile: integer score plugin {p.name!r} has weight {weight!r}; "
+                "kube-scheduler's weights are positive integers"
+            )
+        if p.name in (NODE_AFFINITY, TAINT_TOLERATION) and p.name not in {f.name for f in filter_refs}:
+            raise ValueError(
+                f"scheduler profile scores by {p.name!r} without filtering by it: upstream's plugin is "
+                "both halves; add it to `filters`"
+            )
 
 
 def kube_scheduler_config_from_spec(spec) -> KubeSchedulerConfig:
@@ -153,6 +208,7 @@ def kube_scheduler_config_from_spec(spec) -> KubeSchedulerConfig:
                 weight=float(entry.get("weight", 1.0)),
             )
         )
+    _check_score_kinds(filter_refs, score_refs)
     profile = KubeSchedulerProfile(
         scheduler_name=DEFAULT_SCHEDULER_NAME,
         plugins=Plugins(filter=filter_refs, score=score_refs),
@@ -184,6 +240,15 @@ class KubeScheduler(PodSchedulingAlgorithm):
         profile = self.config.profiles[scheduler_name]
 
         filtered_nodes = [nodes[name] for name in sorted(nodes)]
+        no_terms, no_taints = ignores_preferences(
+            {ref.name for ref in profile.plugins.filter}, {ref.name for ref in profile.plugins.score}
+        )
+        if no_terms and pod.spec.node_affinity is not None and pod.spec.node_affinity.preferred:
+            raise unscored_preferred_term(pod.metadata.name)
+        if no_taints:
+            for node in filtered_nodes:
+                if node_taints(node, "PreferNoSchedule"):
+                    raise unscored_soft_taint(node.metadata.name)
         for filter_ref in profile.plugins.filter:
             plugin = PLUGIN_REGISTRY[filter_ref.name]
             assert isinstance(plugin, FilterPlugin), (
@@ -203,10 +268,9 @@ class KubeScheduler(PodSchedulingAlgorithm):
                 f"{scorer_ref.name!r} plugin is not a ScorePlugin"
             )
             weight = 1.0 if scorer_ref.weight is None else scorer_ref.weight
-            for node in filtered_nodes:
-                node_scores[node.metadata.name] += (
-                    plugin.score(pod, node) * weight
-                )
+            scores = plugin.normalize([plugin.score(pod, node) for node in filtered_nodes])
+            for node, score in zip(filtered_nodes, scores):
+                node_scores[node.metadata.name] += score * weight
 
         assigned_node = filtered_nodes[0].metadata.name
         max_score = node_scores[assigned_node]

@@ -1,13 +1,16 @@
 """Scheduler plugin registry: the reference's Fit and LeastAllocatedResources
 (reference: src/core/scheduler/plugin.rs), the packing-side scorers
-(MostAllocatedResources, BalancedResourceAllocation) and kube-scheduler's
+(MostAllocatedResources, BalancedResourceAllocation), kube-scheduler's
 filters PodTopologySpread (DoNotSchedule), NodeAffinity (the required half)
-and TaintToleration (NoSchedule). The batched device pipeline lowers every
-one of them.
+and TaintToleration (NoSchedule), and its default score plugins in integers
+(NodeResourcesFit, NodeResourcesBalancedAllocation, and the score halves of
+NodeAffinity and TaintToleration: docs/PARITY.md "Scoring as kube-scheduler
+scores"). The batched device pipeline lowers every one of them.
 
 A filter sees the pod, the nodes still in the running and the scheduler's
 cache (`SchedulerCache`: every cached node, the cached pods, and which pods
-the scheduler has assigned to which node); a scorer sees one pod and one node.
+the scheduler has assigned to which node); a scorer sees one pod and one node,
+and may then normalise its scores over the nodes that passed the filters.
 
 The plugin NAME constants below are the shared vocabulary between this
 scalar registry and the device-plugin registry in
@@ -31,6 +34,13 @@ BALANCED = "BalancedResourceAllocation"
 TOPOLOGY_SPREAD = "PodTopologySpread"
 NODE_AFFINITY = "NodeAffinity"
 TAINT_TOLERATION = "TaintToleration"
+NODE_RESOURCES_FIT = "NodeResourcesFit"
+BALANCED_ALLOCATION = "NodeResourcesBalancedAllocation"
+# kube-scheduler's own score plugins: integers 0-100, added by integer
+# weights. NodeAffinity and TaintToleration are one plugin each upstream and
+# here: the filter half and the score half under one name.
+INTEGER_SCORE_PLUGINS = (NODE_RESOURCES_FIT, BALANCED_ALLOCATION, NODE_AFFINITY, TAINT_TOLERATION)
+MAX_NODE_SCORE = 100
 
 
 @dataclass
@@ -55,6 +65,12 @@ class FilterPlugin:
 class ScorePlugin:
     def score(self, pod: Pod, node: Node) -> float:
         raise NotImplementedError
+
+    def normalize(self, scores: List) -> List:
+        """The scores of the nodes that passed the filters, in their order,
+        as they enter the weighted sum (upstream's NormalizeScore; most
+        plugins leave them as they are)."""
+        return scores
 
 
 class Fit(FilterPlugin):
@@ -132,6 +148,51 @@ class BalancedResourceAllocation(ScorePlugin):
         cpu_frac = requests.cpu / allocatable.cpu
         ram_frac = requests.ram / allocatable.ram
         return 100.0 - abs(cpu_frac - ram_frac) * 100.0
+
+
+class NodeResourcesFit(ScorePlugin):
+    """kube-scheduler's NodeResourcesFit score, strategy LeastAllocated over
+    cpu and memory at weight 1 (docs/PARITY.md "Scoring as kube-scheduler
+    scores"): per resource floor((A - U) * 100 / A) with A the node's
+    capacity and U what would be requested on it with the pod, 0 where A is
+    0; the floor of their mean. Python integers throughout."""
+
+    def score(self, pod: Pod, node: Node) -> int:
+        requests, free, capacity = pod.spec.resources.requests, node.status.allocatable, node.status.capacity
+
+        def left(a: int, f: int, q: int) -> int:
+            return (f - q) * MAX_NODE_SCORE // a if a else 0
+
+        return (
+            left(capacity.cpu, free.cpu, requests.cpu) + left(capacity.ram, free.ram, requests.ram)
+        ) // 2
+
+
+class NodeResourcesBalancedAllocation(ScorePlugin):
+    """kube-scheduler's NodeResourcesBalancedAllocation over cpu and memory:
+    floor(100 - 50 |U_cpu / A_cpu - U_ram / A_ram|) as an exact rational
+    (upstream rounds through float64), 0 where a capacity is 0."""
+
+    def score(self, pod: Pod, node: Node) -> int:
+        requests, free, capacity = pod.spec.resources.requests, node.status.allocatable, node.status.capacity
+        a_cpu, a_ram = capacity.cpu, capacity.ram
+        if not a_cpu or not a_ram:
+            return 0
+        u_cpu = a_cpu - free.cpu + requests.cpu
+        u_ram = a_ram - free.ram + requests.ram
+        whole = a_cpu * a_ram
+        return (MAX_NODE_SCORE * whole - 50 * abs(u_cpu * a_ram - u_ram * a_cpu)) // whole
+
+
+def normalize_by_max(scores: List[int], reverse: bool) -> List[int]:
+    """Upstream's DefaultNormalizeScore(100, reverse, scores): every raw score
+    as its share of the largest one, in whole points; no largest score leaves
+    0 everywhere (100 reversed)."""
+    most = max(scores, default=0)
+    if most == 0:
+        return [MAX_NODE_SCORE if reverse else 0] * len(scores)
+    shares = [MAX_NODE_SCORE * s // most for s in scores]
+    return [MAX_NODE_SCORE - s for s in shares] if reverse else shares
 
 
 class UnsupportedSpreadConstraint(ValueError):
@@ -258,9 +319,27 @@ NODE_SELECTOR_OPERATORS = ("In", "NotIn", "Exists", "DoesNotExist")
 def _refuse_placement(pod: Pod, what: str) -> UnsupportedNodePlacement:
     return UnsupportedNodePlacement(
         f"pod {pod.metadata.name!r}: {what} is not supported: NodeAffinity implements nodeSelector and "
-        "requiredDuringSchedulingIgnoredDuringExecution terms of matchExpressions (In, NotIn, Exists, "
-        "DoesNotExist), TaintToleration the effect NoSchedule"
+        "required and preferred terms of matchExpressions (In, NotIn, Exists, DoesNotExist), "
+        "TaintToleration the effects NoSchedule and PreferNoSchedule"
     )
+
+
+def _term_expressions(pod: Pod, term) -> Tuple[Expression, ...]:
+    """One nodeSelectorTerm's expressions, hashable; raises naming what is
+    refused."""
+    if term.match_fields:
+        raise _refuse_placement(pod, "matchFields")
+    if not term.match_expressions:
+        raise _refuse_placement(pod, "a nodeSelectorTerm without matchExpressions")
+    expressions = []
+    for e in term.match_expressions:
+        if e.operator not in NODE_SELECTOR_OPERATORS:
+            raise _refuse_placement(pod, f"the node selector operator {e.operator}")
+        if e.operator in ("In", "NotIn") and not e.values:
+            raise _refuse_placement(pod, f"{e.operator} without values")
+        values = tuple(sorted(e.values)) if e.operator in ("In", "NotIn") else ()
+        expressions.append((e.key, e.operator, values))
+    return tuple(expressions)
 
 
 def _refuse_with_spread(pod: Pod) -> None:
@@ -280,30 +359,27 @@ def supported_node_terms(pod: Pod) -> Optional[Tuple[Tuple[Expression, ...], ...
     selector = tuple(
         (key, "In", (value,)) for key, value in sorted(pod.spec.node_selector.items())
     )
-    if affinity is None:
+    if affinity is None or not affinity.has_required:
         return (selector,) if selector else None
-    if affinity.preferred:
-        raise _refuse_placement(
-            pod, "preferredDuringSchedulingIgnoredDuringExecution (the scoring half)"
-        )
     if not affinity.required_terms:
         raise _refuse_placement(pod, "a node affinity without nodeSelectorTerms")
-    terms = []
-    for term in affinity.required_terms:
-        if term.match_fields:
-            raise _refuse_placement(pod, "matchFields")
-        if not term.match_expressions:
-            raise _refuse_placement(pod, "a nodeSelectorTerm without matchExpressions")
-        expressions = []
-        for e in term.match_expressions:
-            if e.operator not in NODE_SELECTOR_OPERATORS:
-                raise _refuse_placement(pod, f"the node selector operator {e.operator}")
-            if e.operator in ("In", "NotIn") and not e.values:
-                raise _refuse_placement(pod, f"{e.operator} without values")
-            values = tuple(sorted(e.values)) if e.operator in ("In", "NotIn") else ()
-            expressions.append((e.key, e.operator, values))
-        terms.append(selector + tuple(expressions))
-    return tuple(terms)
+    return tuple(selector + _term_expressions(pod, term) for term in affinity.required_terms)
+
+
+def supported_preferred_terms(pod: Pod) -> Tuple[Tuple[int, Tuple[Expression, ...]], ...]:
+    """The pod's preferredDuringSchedulingIgnoredDuringExecution terms as
+    NodeAffinity scores them: (weight, expressions) pairs, () for a pod with
+    none. Raises UnsupportedNodePlacement naming what is refused."""
+    affinity = pod.spec.node_affinity
+    if affinity is None or not affinity.preferred:
+        return ()
+    _refuse_with_spread(pod)
+    out = []
+    for preferred in affinity.preferred:
+        if not 1 <= preferred.weight <= MAX_NODE_SCORE:
+            raise _refuse_placement(pod, f"a preferred term of weight {preferred.weight} (upstream: 1-100)")
+        out.append((int(preferred.weight), _term_expressions(pod, preferred.preference)))
+    return tuple(out)
 
 
 def expression_matches(expression: Expression, labels: Dict[str, str]) -> bool:
@@ -317,19 +393,47 @@ def expression_matches(expression: Expression, labels: Dict[str, str]) -> bool:
     return key not in labels  # DoesNotExist
 
 
-def node_taints(node: Node) -> Tuple[Tuple[str, str], ...]:
-    """The node's NoSchedule taints as (key, value) pairs; raises
-    UnsupportedNodePlacement naming any other effect."""
+def node_taints(node: Node, effect: str = "NoSchedule") -> Tuple[Tuple[str, str], ...]:
+    """The node's taints of `effect` (NoSchedule: the filter half's;
+    PreferNoSchedule: the score half's) as (key, value) pairs; raises
+    UnsupportedNodePlacement naming any third effect."""
     out = []
     for taint in node.spec.taints:
-        if taint.effect != "NoSchedule":
+        if taint.effect not in ("NoSchedule", "PreferNoSchedule"):
             raise UnsupportedNodePlacement(
                 f"node {node.metadata.name!r}: the taint effect {taint.effect} is not supported "
-                "(NoExecute: eviction is not modelled; PreferNoSchedule: the scoring half): "
-                "TaintToleration implements NoSchedule"
+                "(NoExecute: eviction is not modelled): TaintToleration implements NoSchedule and "
+                "PreferNoSchedule"
             )
-        out.append((taint.key, taint.value))
+        if taint.effect == effect:
+            out.append((taint.key, taint.value))
     return tuple(out)
+
+
+def unscored_preferred_term(pod_name: str) -> UnsupportedNodePlacement:
+    return UnsupportedNodePlacement(
+        f"pod {pod_name!r}: preferredDuringSchedulingIgnoredDuringExecution (the scoring half) under a "
+        "profile that does not score by NodeAffinity: the preference would be ignored (kube_default "
+        "scores by it)"
+    )
+
+
+def unscored_soft_taint(node_name: str) -> UnsupportedNodePlacement:
+    return UnsupportedNodePlacement(
+        f"node {node_name!r}: the taint effect PreferNoSchedule (the scoring half) under a profile that "
+        "does not score by TaintToleration: the preference would be ignored (kube_default scores by it)"
+    )
+
+
+def ignores_preferences(filtered, scored) -> Tuple[bool, bool]:
+    """(preferred terms, PreferNoSchedule taints): which of the two a profile
+    of these filter and score plugin names would silently ignore, because it
+    filters by the plugin and does not score by it. Both paths refuse a pod or
+    a node that carries one under such a profile, by name."""
+    return (
+        NODE_AFFINITY in filtered and NODE_AFFINITY not in scored,
+        TAINT_TOLERATION in filtered and TAINT_TOLERATION not in scored,
+    )
 
 
 def supported_tolerations(pod: Pod) -> Tuple[Toleration, ...]:
@@ -339,17 +443,20 @@ def supported_tolerations(pod: Pod) -> Tuple[Toleration, ...]:
     for t in pod.spec.tolerations:
         if t.operator not in ("Equal", "Exists"):
             raise _refuse_placement(pod, f"the toleration operator {t.operator}")
-        if t.effect not in ("", "NoSchedule"):
+        if t.effect not in ("", "NoSchedule", "PreferNoSchedule"):
             raise _refuse_placement(pod, f"a toleration of the effect {t.effect}")
         if not t.key and t.operator != "Exists":
             raise _refuse_placement(pod, "a toleration with an empty key and the operator Equal")
     return tuple(pod.spec.tolerations)
 
 
-def tolerates(tolerations, taint: Tuple[str, str]) -> bool:
-    """Whether one of `tolerations` matches the NoSchedule taint (key, value)."""
+def tolerates(tolerations, taint: Tuple[str, str], effect: str = "NoSchedule") -> bool:
+    """Whether one of `tolerations` matches the taint (key, value) of
+    `effect` (a toleration's empty effect matches every effect)."""
     key, value = taint
     for t in tolerations:
+        if t.effect not in ("", effect):
+            continue
         if t.operator == "Exists":
             if not t.key or t.key == key:
                 return True
@@ -358,14 +465,28 @@ def tolerates(tolerations, taint: Tuple[str, str]) -> bool:
     return False
 
 
-class NodeAffinity(FilterPlugin):
-    """kube-scheduler's NodeAffinity, the Filter half (docs/PARITY.md "Node
+class NodeAffinity(FilterPlugin, ScorePlugin):
+    """kube-scheduler's NodeAffinity. The Filter half (docs/PARITY.md "Node
     affinity and taints"): a node passes iff its labels carry every pair of
     the pod's `nodeSelector` AND satisfy at least one of its required
     `nodeSelectorTerms`, a term being the AND of its `matchExpressions` (In,
     NotIn, Exists, DoesNotExist on `metadata.labels`). A pod with neither
-    passes every node. What it does not implement it refuses by name
-    (`supported_node_terms`)."""
+    passes every node. The Score half ("Scoring as kube-scheduler scores"):
+    the sum of the weights of the pod's preferred terms the node's labels
+    match, as its share of the largest sum among the nodes that passed the
+    filters. What it does not implement it refuses by name
+    (`supported_node_terms`, `supported_preferred_terms`)."""
+
+    def score(self, pod: Pod, node: Node) -> int:
+        labels = node.metadata.labels
+        return sum(
+            weight
+            for weight, term in supported_preferred_terms(pod)
+            if all(expression_matches(e, labels) for e in term)
+        )
+
+    def normalize(self, scores: List[int]) -> List[int]:
+        return normalize_by_max(scores, reverse=False)
 
     def filter(self, pod: Pod, nodes: List[Node], cache: SchedulerCache) -> List[Node]:
         terms = supported_node_terms(pod)
@@ -378,13 +499,26 @@ class NodeAffinity(FilterPlugin):
         ]
 
 
-class TaintToleration(FilterPlugin):
-    """kube-scheduler's TaintToleration, the Filter half (docs/PARITY.md
+class TaintToleration(FilterPlugin, ScorePlugin):
+    """kube-scheduler's TaintToleration. The Filter half (docs/PARITY.md
     "Node affinity and taints"): a node passes iff each of its taints of
     effect NoSchedule is tolerated by the pod. A toleration matches a taint
     on `key` and `operator` (`Equal`: the value too; `Exists`: the key, or
     every taint where the key is empty) and `effect` (empty matches every
-    effect). Other effects are refused by name (`node_taints`)."""
+    effect). The Score half ("Scoring as kube-scheduler scores"): how many of
+    the node's PreferNoSchedule taints the pod does not tolerate, reversed
+    against the largest count among the nodes that passed the filters.
+    NoExecute is refused by name (`node_taints`)."""
+
+    def score(self, pod: Pod, node: Node) -> int:
+        tolerations = supported_tolerations(pod)
+        return sum(
+            not tolerates(tolerations, t, "PreferNoSchedule")
+            for t in node_taints(node, "PreferNoSchedule")
+        )
+
+    def normalize(self, scores: List[int]) -> List[int]:
+        return normalize_by_max(scores, reverse=True)
 
     def filter(self, pod: Pod, nodes: List[Node], cache: SchedulerCache) -> List[Node]:
         tolerations = supported_tolerations(pod)
@@ -401,6 +535,8 @@ PLUGIN_REGISTRY: Dict[str, Union[FilterPlugin, ScorePlugin]] = {
     LEAST_ALLOCATED: LeastAllocatedResources(),
     MOST_ALLOCATED: MostAllocatedResources(),
     BALANCED: BalancedResourceAllocation(),
+    NODE_RESOURCES_FIT: NodeResourcesFit(),
+    BALANCED_ALLOCATION: NodeResourcesBalancedAllocation(),
 }
 
 

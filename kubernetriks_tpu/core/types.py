@@ -384,15 +384,51 @@ class NodeSelectorTerm:
     match_fields: List[Any] = field(default_factory=list)
 
 
+    @staticmethod
+    def from_dict(d: Optional[Dict[str, Any]]) -> "NodeSelectorTerm":
+        d = _snake(d)
+        return NodeSelectorTerm(
+            match_expressions=[
+                NodeSelectorRequirement.from_dict(e) for e in d.get("match_expressions") or []
+            ],
+            match_fields=list(d.get("match_fields") or []),
+        )
+
+    def to_dict(self) -> Dict[str, Any]:
+        out: Dict[str, Any] = {"match_expressions": [e.to_dict() for e in self.match_expressions]}
+        if self.match_fields:
+            out["match_fields"] = list(self.match_fields)
+        return out
+
+
+@dataclass
+class PreferredSchedulingTerm:
+    """One entry of `preferredDuringSchedulingIgnoredDuringExecution`: a
+    weight (upstream: 1-100) and the nodeSelectorTerm it is given for."""
+
+    weight: int = 1
+    preference: NodeSelectorTerm = field(default_factory=NodeSelectorTerm)
+
+    @staticmethod
+    def from_dict(d: Dict[str, Any]) -> "PreferredSchedulingTerm":
+        return PreferredSchedulingTerm(
+            weight=int(d.get("weight", 1)),
+            preference=NodeSelectorTerm.from_dict(d.get("preference")),
+        )
+
+    def to_dict(self) -> Dict[str, Any]:
+        return {"weight": self.weight, "preference": self.preference.to_dict()}
+
+
 @dataclass
 class NodeAffinity:
     """A pod's `spec.affinity.nodeAffinity`: the required terms (ORed; a
-    term's expressions ANDed) and, kept so that it can be refused by name
-    where it is used (core/scheduler/plugins.supported_node_terms), the
-    preferred list."""
+    term's expressions ANDed; `has_required` says whether the pod states that
+    half at all) and the preferred terms, each with its weight."""
 
     required_terms: List[NodeSelectorTerm] = field(default_factory=list)
-    preferred: List[Any] = field(default_factory=list)
+    preferred: List[PreferredSchedulingTerm] = field(default_factory=list)
+    has_required: bool = True
 
     @staticmethod
     def from_dict(d: Optional[Dict[str, Any]]) -> Optional["NodeAffinity"]:
@@ -401,33 +437,17 @@ class NodeAffinity:
         d = _snake(d)
         terms = _snake(d.get("required")).get("node_selector_terms") or []
         return NodeAffinity(
-            required_terms=[
-                NodeSelectorTerm(
-                    match_expressions=[
-                        NodeSelectorRequirement.from_dict(e)
-                        for e in _snake(t).get("match_expressions") or []
-                    ],
-                    match_fields=list(_snake(t).get("match_fields") or []),
-                )
-                for t in terms
-            ],
-            preferred=list(d.get("preferred") or []),
+            required_terms=[NodeSelectorTerm.from_dict(t) for t in terms],
+            preferred=[PreferredSchedulingTerm.from_dict(t) for t in d.get("preferred") or []],
+            has_required="required" in d,
         )
 
     def to_dict(self) -> Dict[str, Any]:
-        out: Dict[str, Any] = {
-            "required": {
-                "node_selector_terms": [
-                    {
-                        "match_expressions": [e.to_dict() for e in t.match_expressions],
-                        **({"match_fields": list(t.match_fields)} if t.match_fields else {}),
-                    }
-                    for t in self.required_terms
-                ]
-            }
-        }
+        out: Dict[str, Any] = {}
+        if self.has_required:
+            out["required"] = {"node_selector_terms": [t.to_dict() for t in self.required_terms]}
         if self.preferred:
-            out["preferred"] = list(self.preferred)
+            out["preferred"] = [t.to_dict() for t in self.preferred]
         return out
 
 

@@ -52,11 +52,16 @@ from kubernetriks_tpu.batched.pipeline import (
     DEFAULT_PROFILE,
     SPREAD_ZONE_TILE,
     NodeFacts,
+    SoftFacts,
     affinity_names_nodes,
     affinity_node_masks,
     exact_best_node,
     exact_least_allocated_key,
+    integer_best_node,
+    integer_nodes,
+    integer_scores,
     profile_fit_mask,
+    soft_honoured,
     profile_fit_score,
     spread_alive_tile,
     spread_node_mask,
@@ -141,20 +146,36 @@ def _affinity_blocks(affinity_terms) -> Tuple[int, int]:
     return 1, affinity_terms + 1
 
 
-def kernel_fits(n_nodes: int, k_pods: int, spread_shape=None, affinity_terms=None) -> bool:
+def _kube_blocks(kube_terms) -> Tuple[int, int, int]:
+    """(node blocks, pod- or candidate-shaped blocks in, whether a counter
+    block goes out) the integer scorers add to a decision kernel of a build
+    with `kube_terms` preferred-term planes (None: a build that ranks
+    otherwise; 0: one without soft planes): the two capacity planes and the
+    six planes made of them once a launch (pipeline.IntegerNodes); the term
+    planes, the weights and the untolerated soft taints."""
+    if kube_terms is None:
+        return 0, 0, 0
+    return 8, (kube_terms + 2 if kube_terms else 0), int(kube_terms > 0)
+
+
+def kernel_fits(
+    n_nodes: int, k_pods: int, spread_shape=None, affinity_terms=None, kube_terms=None
+) -> bool:
     """Whether one grid program's VMEM blocks (3 node blocks in and 2 out of
     (Np, 128), 3 candidate blocks in and 3 out of (Kp, 128), all int32; with
     the spread filter one node block, four candidate blocks and the table
     more; with the label filters one node block, their candidate planes and
-    one candidate block out more) fit the budget; callers fall back to the
-    lax.scan formulation when they don't."""
+    one candidate block out more; with the integer scorers _kube_blocks')
+    fit the budget; callers fall back to the lax.scan formulation when they
+    don't."""
     np_pad = -(-n_nodes // _SUB) * _SUB
     kp_pad = -(-k_pods // _SUB) * _SUB
     s = int(spread_shape is not None)
     a_node, a_side = _affinity_blocks(affinity_terms)
+    k_node, k_side, k_out = _kube_blocks(kube_terms)
     resident = (
-        (5 + s + a_node) * np_pad
-        + (6 + 4 * s + a_side + a_node) * kp_pad
+        (5 + s + a_node + k_node) * np_pad
+        + (6 + 4 * s + a_side + a_node + k_side + k_out) * kp_pad
         + _spread_table_rows(spread_shape)
     ) * _LANE * 4
     return resident <= _VMEM_BUDGET_BYTES
@@ -249,7 +270,57 @@ def _affinity_step(node_bits_ref, side):
     return node_bits_ref[:], terms, forbid
 
 
-def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, spread=None, affinity=None):
+def _kube_operands(kube, nodes_lane_major: bool, Np: int, Cp: int, side_rows: int, node_spec, side_spec):
+    """What a decision kernel's wrapper adds to its pallas_call for the
+    integer scorers: (the number of preferred-term planes, 0 without soft
+    planes; the padded operands; their in_specs), (None, (), []) for `kube`
+    None. `kube` = (cap_cpu, cap_ram (C, N) | (N, C), then, in a build with
+    soft planes, the pods' or candidates' preferred-term planes, their packed
+    weights and last their untolerated soft taints, `side_rows` rows in the
+    kernel layout). Padded nodes have no capacity; padded rows and lanes hold
+    a pod that prefers nothing."""
+    if kube is None:
+        return None, (), []
+    cap_cpu, cap_ram, *side = kube
+
+    def prep(x):
+        return _pad_axis(_pad_axis(x.astype(jnp.int32).T, 0, side_rows, 0), 1, Cp, 0)
+
+    args = (
+        _prep_node(cap_cpu, nodes_lane_major, Np, Cp, 0),
+        _prep_node(cap_ram, nodes_lane_major, Np, Cp, 0),
+        *(prep(x) for x in side),
+    )
+    return max(len(side) - 2, 0), args, [node_spec] * 2 + [side_spec] * len(side)
+
+
+def _kube_refs(refs, kube_terms):
+    """A decision kernel's refs past its spread and label-filter inputs,
+    split: (the two capacity refs, the soft side refs, the rest)."""
+    n_side = kube_terms + 2 if kube_terms else 0
+    return refs[:2], refs[2 : 2 + n_side], refs[2 + n_side :]
+
+
+def _kube_step(profile, nodes, side):
+    """The `kube` argument of _fit_score_place: the launch's IntegerNodes and
+    the candidate's (1, LC) preferred-term masks, weights and untolerated
+    soft taints (none in a build without soft planes)."""
+    if not side:
+        return nodes, None
+    *terms, weights, forbid = side
+    return nodes, (tuple(terms), weights, forbid, profile.soft_taints)
+
+
+def _soft_flags(softly):
+    """A decision's label-score facts as one int32: bit 0 a label scorer had
+    something to normalise by, bit 1 the placement honoured it."""
+    attempt, honoured = softly
+    return attempt.astype(jnp.int32) + jnp.int32(2) * honoured.astype(jnp.int32)
+
+
+def _fit_score_place(
+    profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, spread=None, affinity=None, kube=None
+):
     """ONE in-kernel definition of the per-candidate decision core shared by
     _cycle_kernel, _select_cycle_kernel and _select_cycle_commit_kernel:
     the compiled profile's filter mask + weighted score
@@ -262,8 +333,8 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, s
     expressions inline into the kernel body like the shape statics do.
     Inputs: (Np, LC) node tiles, (1, LC) candidate requests/validity.
     Returns (assign (1, LC) bool, any_fit (1, LC) bool, best (1, LC) i32,
-    new_cpu (Np, LC), new_ram (Np, LC), placed, named): the last two None
-    without `spread` / `affinity`.
+    new_cpu (Np, LC), new_ram (Np, LC), placed, named, softly): the last
+    three None without `spread` / `affinity` / soft planes.
 
     `spread` (a build whose pods are held to topology-spread constraints;
     pipeline.spread_*) = (domain (Np, LC) node plane, the count table's G
@@ -279,7 +350,17 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, s
     plane, the candidate's term masks, its untolerated-taint mask, (1, LC)
     each). Nothing is carried: `named` = (the candidate names its nodes and
     is valid, and of those: no node passed the chain although a live node
-    passed every filter but the two label filters), (1, LC) bool each."""
+    passed every filter but the two label filters), (1, LC) bool each.
+
+    `kube` (a build whose profile scores in integers, as kube-scheduler
+    does; pipeline.integer_scores) = (the launch's IntegerNodes, and in a
+    build with soft planes the candidate's preferred-term masks, packed
+    weights, untolerated soft taints (1, LC) and the static count of soft
+    taint bits; `affinity` is then given: its node plane holds the bits).
+    The one scoring chain with reductions over the node axis before the
+    argmax. `softly` = (the candidate is valid and a label scorer had
+    something to normalise by, and of those: it went to a node with the
+    largest label score among the feasible ones), (1, LC) bool each."""
     i0 = jnp.int32(0)
     neg1 = jnp.int32(-1)
 
@@ -293,7 +374,14 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, s
         rest = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
         affinity_ok, taints_ok = affinity_node_masks(node_bits, terms, forbid)
         facts = (facts or NodeFacts())._replace(affinity_ok=affinity_ok, taints_ok=taints_ok)
-    if profile.exact_bits:
+    part = None
+    if kube is not None:
+        nodes, soft_side = kube
+        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
+        soft = None if soft_side is None else SoftFacts(affinity[0], *soft_side)
+        total, part, soft_attempt = integer_scores(profile, fit, cpu, ram, rc, rr, nodes, soft, axis=0)
+        best = integer_best_node(total, node_ok, iota_n, axis=0)
+    elif profile.exact_bits:
         fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
         hi, lo = exact_least_allocated_key(fit, cpu, ram, rc, rr, profile.exact_bits)
         best = exact_best_node(hi, lo, node_ok, iota_n, axis=0)
@@ -312,7 +400,10 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, s
     upd = assign & (iota_n == best)
     new_cpu = cpu - jnp.where(upd, rc, i0)
     new_ram = ram - jnp.where(upd, rr, i0)
-    placed = named = None
+    placed = named = softly = None
+    if part is not None:
+        soft_attempt = valid & soft_attempt
+        softly = (soft_attempt, soft_attempt & assign & soft_honoured(part, fit, upd, axis=0))
     if spread is not None:
         zbest = jnp.max(jnp.where(upd, domain, neg1), axis=0, keepdims=True)
         placed = (spread_place(tiles, zbest, assign, bits), zbest, assign & constrained, assign & closed)
@@ -320,7 +411,7 @@ def _fit_score_place(profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, s
         attempt = valid & affinity_names_nodes(forbid)
         any_rest = jnp.max(rest.astype(jnp.int32), axis=0, keepdims=True) > i0
         named = (attempt, attempt & ~any_fit & any_rest)
-    return assign, any_fit, best, new_cpu, new_ram, placed, named
+    return assign, any_fit, best, new_cpu, new_ram, placed, named, softly
 
 
 def _spread_step(refs, n_workloads: int, n_domains: int, group, bits):
@@ -370,6 +461,7 @@ def _cycle_kernel(
     profile,        # pipeline.CompiledProfile (kernel static)
     spread_shape,   # (G, Z) static, None without the spread filter
     affinity_terms,  # static number of term planes, None without the label filters
+    kube_terms,     # static number of preferred-term planes, None without the integer scorers
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -381,16 +473,24 @@ def _cycle_kernel(
     # refs: with the spread filter the inputs domain (Np, LC), table and
     # limits (G*8, LC), live domains (8, LC), the candidates' workload and
     # match bits (Kp, LC); with the label filters node_bits (Np, LC), the
-    # candidates' term masks and untolerated taints (Kp, LC each); then the
+    # candidates' term masks and untolerated taints (Kp, LC each); with the
+    # integer scorers cap_cpu, cap_ram (Np, LC) and the candidates' soft
+    # planes (Kp, LC each); then the
     # outputs cpu, ram (Np, LC), assign, fitany, best (Kp, LC); with the
     # spread filter the carried table (G*8, LC) and the candidates' placed
-    # domain and flags (Kp, LC); with the label filters their flags (Kp, LC).
+    # domain and flags (Kp, LC); with the label filters their flags (Kp, LC);
+    # with soft planes the label scores' flags (Kp, LC).
     if spread_shape is not None:
         domain_ref, table_in, limit_ref, zalive_ref, cgroup_ref, cbits_ref = refs[:6]
         refs = refs[6:]
     if affinity_terms is not None:
         node_bits_ref, *aside_refs = refs[: affinity_terms + 2]
         refs = refs[affinity_terms + 2 :]
+    if kube_terms is not None:
+        cap_refs, kside_refs, refs = _kube_refs(refs, kube_terms)
+    if kube_terms:
+        *refs, kflag_out = refs
+    if affinity_terms is not None:
         aflag_out = refs[-1]
     if spread_shape is not None:
         table_out, zbest_out, sflag_out = refs[5:8]
@@ -416,6 +516,10 @@ def _cycle_kernel(
         sflag_out[:] = jnp.zeros_like(sflag_out)
     if affinity_terms is not None:
         aflag_out[:] = jnp.zeros_like(aflag_out)
+    if kube_terms is not None:
+        kube_nodes = integer_nodes(cap_refs[0][:], cap_refs[1][:], profile.units)
+    if kube_terms:
+        kflag_out[:] = jnp.zeros_like(kflag_out)
 
     # The loop only needs to reach the tile's last valid candidate — a
     # data-dependent early exit the lax.scan formulation cannot express.
@@ -441,14 +545,19 @@ def _cycle_kernel(
         affinity = None
         if affinity_terms is not None:
             affinity = _affinity_step(node_bits_ref, [ref[pl.ds(k, 1), :] for ref in aside_refs])
-        assign, any_fit, best, new_cpu, new_ram, placed, named = _fit_score_place(
+        kube = None
+        if kube_terms is not None:
+            kube = _kube_step(profile, kube_nodes, [ref[pl.ds(k, 1), :] for ref in kside_refs])
+        assign, any_fit, best, new_cpu, new_ram, placed, named, softly = _fit_score_place(
             profile, alive, node_ok, iota, cpu_out[:], ram_out[:],
-            req_cpu, req_ram, valid, spread, affinity,
+            req_cpu, req_ram, valid, spread, affinity, kube,
         )
         if placed is not None:
             _spread_store_decision(table_out, zbest_out, sflag_out, k, placed)
         if named is not None:
             aflag_out[pl.ds(k, 1), :] = _named_flags(named)
+        if softly is not None:
+            kflag_out[pl.ds(k, 1), :] = _soft_flags(softly)
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         assign_out[pl.ds(k, 1), :] = assign.astype(jnp.int32)
@@ -472,14 +581,15 @@ _SELECT_VMEM_LIMIT = 100 * 1024 * 1024
 
 
 def select_kernel_fits(
-    n_nodes: int, n_pods: int, k_pods: int, spread_shape=None, affinity_terms=None
+    n_nodes: int, n_pods: int, k_pods: int, spread_shape=None, affinity_terms=None, kube_terms=None
 ) -> bool:
     """Whether the selection+cycle kernel's VMEM blocks fit: 6 pod blocks of
     (Pp, 128) in + 1 pod scratch, 3 node blocks in + 2 out, 5 candidate
     output blocks, all int32, double-buffered across grid programs by
     Mosaic; with the spread filter one node block, two pod blocks, two
     candidate output blocks and the table more; with the label filters one
-    node block, their pod planes and one candidate output block more. The pod blocks dominate; the budget is more
+    node block, their pod planes and one candidate output block more; with
+    the integer scorers _kube_blocks'. The pod blocks dominate; the budget is more
     generous than the candidate kernel's because this kernel REPLACES the
     (C, P) lexsort and gathers, so its win grows with P (v5e VMEM is
     ~128 MiB/core)."""
@@ -488,10 +598,11 @@ def select_kernel_fits(
     kp_pad = -(-k_pods // _SUB) * _SUB
     s = int(spread_shape is not None)
     a_node, a_side = _affinity_blocks(affinity_terms)
+    k_node, k_side, k_out = _kube_blocks(kube_terms)
     resident = (
-        (5 + s + a_node) * np_pad
-        + (7 + 2 * s + a_side) * pp_pad
-        + (5 + 2 * s + a_node) * kp_pad
+        (5 + s + a_node + k_node) * np_pad
+        + (7 + 2 * s + a_side + k_side) * pp_pad
+        + (5 + 2 * s + a_node + k_out) * kp_pad
         + _spread_table_rows(spread_shape)
     ) * _LANE * 4
     return 2 * resident <= int(0.8 * _SELECT_VMEM_LIMIT)
@@ -503,6 +614,7 @@ def _select_cycle_kernel(
     profile,        # pipeline.CompiledProfile (kernel static)
     spread_shape,   # (G, Z) static, None without the spread filter
     affinity_terms,  # static number of term planes, None without the label filters
+    kube_terms,     # static number of preferred-term planes, None without the integer scorers
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -518,23 +630,30 @@ def _select_cycle_kernel(
     # refs: with the spread filter the inputs domain (Np, LC), table and
     # limits (G*8, LC), live domains (8, LC), the pods' workload and match
     # bits (Pp, LC); with the label filters node_bits (Np, LC), the pods'
-    # term masks and untolerated taints (Pp, LC each); then the outputs cpu,
+    # term masks and untolerated taints (Pp, LC each); with the integer
+    # scorers cap_cpu, cap_ram (Np, LC) and the pods' soft planes (Pp, LC
+    # each); then the outputs cpu,
     # ram (Np, LC), cand (the selected pod slot), valid, assign, fitany, best
     # (Kp, LC); with the spread filter the carried table (G*8, LC) and the
     # decisions' placed domain and flags (Kp, LC); with the label filters
-    # their flags (Kp, LC); last the scratch rem (Pp, LC): not-yet-selected
-    # eligible pods.
+    # their flags (Kp, LC); with soft planes the label scores' flags (Kp, LC);
+    # last the scratch rem (Pp, LC): not-yet-selected eligible pods.
     if spread_shape is not None:
         domain_ref, table_in, limit_ref, zalive_ref, pgroup_ref, pbits_ref = refs[:6]
         refs = refs[6:]
     if affinity_terms is not None:
         node_bits_ref, *aside_refs = refs[: affinity_terms + 2]
         refs = refs[affinity_terms + 2 :]
-        aflag_out = refs[-2]
+    if kube_terms is not None:
+        cap_refs, kside_refs, refs = _kube_refs(refs, kube_terms)
+    *refs, rem_ref = refs
+    if kube_terms:
+        *refs, kflag_out = refs
+    if affinity_terms is not None:
+        aflag_out = refs[-1]
     if spread_shape is not None:
         table_out, zbest_out, sflag_out = refs[7:10]
     cpu_out, ram_out, cand_out, valid_out, assign_out, fitany_out, best_out = refs[:7]
-    rem_ref = refs[-1]
     """Fused queue selection + scheduling cycle: candidate k is extracted
     IN-KERNEL by an iterated per-lane lexicographic argmin over
     (queue win, off, seq) — exactly the sorted order of the batched
@@ -566,6 +685,10 @@ def _select_cycle_kernel(
         sflag_out[:] = jnp.zeros_like(sflag_out)
     if affinity_terms is not None:
         aflag_out[:] = jnp.zeros_like(aflag_out)
+    if kube_terms is not None:
+        kube_nodes = integer_nodes(cap_refs[0][:], cap_refs[1][:], profile.units)
+    if kube_terms:
+        kflag_out[:] = jnp.zeros_like(kflag_out)
 
     iota_p = jax.lax.broadcasted_iota(jnp.int32, elig_ref.shape, 0)
     # Early exit: the deepest per-lane queue in this tile bounds the loop.
@@ -606,14 +729,22 @@ def _select_cycle_kernel(
                 node_bits_ref,
                 [jnp.sum(jnp.where(sel, ref[:], i0), axis=0, keepdims=True) for ref in aside_refs],
             )
-        assign, any_fit, best, new_cpu, new_ram, placed, named = _fit_score_place(
+        kube = None
+        if kube_terms is not None:
+            kube = _kube_step(
+                profile, kube_nodes,
+                [jnp.sum(jnp.where(sel, ref[:], i0), axis=0, keepdims=True) for ref in kside_refs],
+            )
+        assign, any_fit, best, new_cpu, new_ram, placed, named, softly = _fit_score_place(
             profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid, spread, affinity,
+            rc, rr, valid, spread, affinity, kube,
         )
         if placed is not None:
             _spread_store_decision(table_out, zbest_out, sflag_out, k, placed)
         if named is not None:
             aflag_out[pl.ds(k, 1), :] = _named_flags(named)
+        if softly is not None:
+            kflag_out[pl.ds(k, 1), :] = _soft_flags(softly)
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         cand_out[pl.ds(k, 1), :] = jnp.where(valid, slot, i0)
@@ -650,6 +781,7 @@ def fused_select_schedule_cycle(
     profile=None,  # pipeline.CompiledProfile; None = the default profile
     spread=None,  # (domain, counts, limits, zone_alive, pod group, pod bits)
     affinity=None,  # (node_bits, the pods' term planes..., their untolerated taints)
+    kube=None,  # (cap_cpu, cap_ram[, the pods' preferred-term planes..., weights, untolerated soft taints])
 ):
     """Fused selection + scheduling loop in VMEM.
 
@@ -663,8 +795,10 @@ def fused_select_schedule_cycle(
     filter's four operands and the pods' (C, P) workload and match bits) three
     more follow: each decision's placed domain and spread flags, (C, K)
     int32, and the count table after the launch, (C, G, Z). With `affinity`
-    (_affinity_operands) one more follows, last: each decision's label-filter
-    flags, (C, K) int32 (_named_flags)."""
+    (_affinity_operands) one more follows: each decision's label-filter
+    flags, (C, K) int32 (_named_flags). With `kube` (_kube_operands) that
+    holds soft planes one more follows, last: each decision's label-score
+    flags, (C, K) int32 (_soft_flags)."""
     C, P = eligible.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
     K = k_pods
@@ -700,22 +834,26 @@ def fused_select_schedule_cycle(
         affinity_terms, affinity_args, affinity_in = _affinity_operands(
             affinity, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
         )
+        kube_terms, kube_args, kube_in = _kube_operands(
+            kube, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, cand_spec, cand_spec]
         spread_shapes = [table_shape] + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)] * 2
-    if affinity is not None:
-        spread_out = spread_out + [cand_spec]
-        spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)]
+    for flags in (affinity is not None, bool(kube_terms)):
+        if flags:
+            spread_out = spread_out + [cand_spec]
+            spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)]
     kernel = functools.partial(
-        _select_cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms
+        _select_cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms, kube_terms
     )
     with jax.enable_x64(False):
         cpu_o, ram_o, cand_o, valid_o, assign_o, fitany_o, best_o, *spread_o = pl.pallas_call(
             kernel,
             name="fused_select_schedule_cycle",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec] * 3 + [pod_spec] * 6 + spread_in + affinity_in,
+            in_specs=[node_spec] * 3 + [pod_spec] * 6 + spread_in + affinity_in + kube_in,
             out_specs=[node_spec] * 2 + [cand_spec] * 5 + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -734,10 +872,11 @@ def fused_select_schedule_cycle(
             interpret=interpret,
         )(
             alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p, reqc_p, reqr_p,
-            *spread_args, *affinity_args,
+            *spread_args, *affinity_args, *kube_args,
         )
 
     with jax.named_scope("kernel_io"):
+        soft_o = (spread_o.pop()[:K, :C].T,) if kube_terms else ()
         named_o = (spread_o.pop()[:K, :C].T,) if affinity is not None else ()
         return (
             cand_o[:K, :C].T,
@@ -749,6 +888,7 @@ def fused_select_schedule_cycle(
             _unprep_node(ram_o, nodes_lane_major, N, C),
             *_spread_results(spread_o, spread_shape, K, C),
             *named_o,
+            *soft_o,
         )
 
 
@@ -1519,6 +1659,7 @@ def fused_schedule_cycle(
     profile=None,  # pipeline.CompiledProfile; None = the default profile
     spread=None,  # (domain, counts, limits, zone_alive, cand group, cand bits)
     affinity=None,  # (node_bits, the candidates' term masks..., their untolerated taints)
+    kube=None,  # (cap_cpu, cap_ram[, the candidates' preferred-term masks..., weights, untolerated soft taints])
 ):
     """Run the K-pod scheduling loop in VMEM.
 
@@ -1530,8 +1671,10 @@ def fused_schedule_cycle(
     candidates' (C, K) workload and match bits) three more follow: the placed
     node's domain and the decision's spread flags, (C, K) int32 each, and the
     count table after the launch, (C, G, Z). With `affinity`
-    (_affinity_operands) one more follows, last: each decision's label-filter
-    flags, (C, K) int32 (_named_flags).
+    (_affinity_operands) one more follows: each decision's label-filter
+    flags, (C, K) int32 (_named_flags). With `kube` (_kube_operands) that
+    holds soft planes one more follows, last: each decision's label-score
+    flags, (C, K) int32 (_soft_flags).
     """
     C, K = valid.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
@@ -1561,15 +1704,19 @@ def fused_schedule_cycle(
         affinity_terms, affinity_args, affinity_in = _affinity_operands(
             affinity, nodes_lane_major, Np, Cp, Kp, node_spec, cand_spec
         )
+        kube_terms, kube_args, kube_in = _kube_operands(
+            kube, nodes_lane_major, Np, Cp, Kp, node_spec, cand_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, cand_spec, cand_spec]
         spread_shapes = [table_shape] + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)] * 2
-    if affinity is not None:
-        spread_out = spread_out + [cand_spec]
-        spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)]
+    for flags in (affinity is not None, bool(kube_terms)):
+        if flags:
+            spread_out = spread_out + [cand_spec]
+            spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((Kp, Cp), jnp.int32)]
     kernel = functools.partial(
-        _cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms
+        _cycle_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms, kube_terms
     )
     # Trace the kernel with x64 semantics OFF: the batched path enables
     # jax_enable_x64 for its f64 time arrays, but under x64 pallas_call's own
@@ -1582,7 +1729,8 @@ def fused_schedule_cycle(
             grid=(Cp // _LANE,),
             in_specs=[node_spec, node_spec, node_spec, cand_spec, cand_spec, cand_spec]
             + spread_in
-            + affinity_in,
+            + affinity_in
+            + kube_in,
             out_specs=[node_spec, node_spec, cand_spec, cand_spec, cand_spec] + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -1593,9 +1741,10 @@ def fused_schedule_cycle(
             ]
             + spread_shapes,
             interpret=interpret,
-        )(alive_p, cpu_p, ram_p, valid_p, reqc_p, reqr_p, *spread_args, *affinity_args)
+        )(alive_p, cpu_p, ram_p, valid_p, reqc_p, reqr_p, *spread_args, *affinity_args, *kube_args)
 
     with jax.named_scope("kernel_io"):
+        soft_o = (spread_o.pop()[:K, :C].T,) if kube_terms else ()
         named_o = (spread_o.pop()[:K, :C].T,) if affinity is not None else ()
         return (
             assign_o[:K, :C].T != 0,
@@ -1605,32 +1754,35 @@ def fused_schedule_cycle(
             _unprep_node(ram_o, nodes_lane_major, N, C),
             *_spread_results(spread_o, spread_shape, K, C),
             *named_o,
+            *soft_o,
         )
 
 
 # --- round-4 megakernel: selection + cycle + commit in ONE launch -----------
 
 def select_commit_kernel_fits(
-    n_nodes: int, n_pods: int, k_pods: int, spread_shape=None, affinity_terms=None
+    n_nodes: int, n_pods: int, k_pods: int, spread_shape=None, affinity_terms=None, kube_terms=None
 ) -> bool:
     """VMEM budget for the megakernel: 3 node blocks in + 2 out, 9 pod
     blocks in + 4 out + 1 scratch, 3 K-shaped blocks (fused_select_cycle_commit
     says what is left of them) and the (8, LANE) stats block; with the spread filter one node block, two pod blocks in and one
     out, and the table, its limits, the live domains and a stats tile more;
     with the label filters one node block, their pod planes and a stats tile
-    more; double-buffered by Mosaic (~2x block bytes)."""
+    more; with the integer scorers _kube_blocks' and a stats tile;
+    double-buffered by Mosaic (~2x block bytes)."""
     Np = -(-n_nodes // _SUB) * _SUB
     Pp = -(-n_pods // _SUB) * _SUB
     Kp = -(-k_pods // _SUB) * _SUB
     s = int(spread_shape is not None)
     a_node, a_side = _affinity_blocks(affinity_terms)
+    k_node, k_side, k_out = _kube_blocks(kube_terms)
     per_lane_bytes = (
         2
         * (
-            (5 + s + a_node) * Np
-            + (14 + 3 * s + a_side) * Pp
+            (5 + s + a_node + k_node) * Np
+            + (14 + 3 * s + a_side + k_side) * Pp
             + 3 * Kp
-            + 8 * (1 + a_node)
+            + 8 * (1 + a_node + k_out)
             + _spread_table_rows(spread_shape)
         )
         * 4 * _LANE
@@ -1669,6 +1821,7 @@ def _select_cycle_commit_kernel(
     profile,        # pipeline.CompiledProfile (kernel static)
     spread_shape,   # (G, Z) static, None without the spread filter
     affinity_terms,  # static number of term planes, None without the label filters
+    kube_terms,     # static number of preferred-term planes, None without the integer scorers
     alive_ref,      # (Np, LC) int32
     alloc_cpu_ref,  # (Np, LC) int32
     alloc_ram_ref,  # (Np, LC) int32
@@ -1690,7 +1843,9 @@ def _select_cycle_commit_kernel(
     # refs: with the spread filter the inputs domain (Np, LC), table and
     # limits (G*8, LC), live domains (8, LC), the pods' workload and match
     # bits (Pp, LC); with the label filters node_bits (Np, LC), the pods'
-    # term masks and untolerated taints (Pp, LC each); then the outputs
+    # term masks and untolerated taints (Pp, LC each); with the integer
+    # scorers cap_cpu, cap_ram (Np, LC) and the pods' soft planes (Pp, LC
+    # each); then the outputs
     #   cpu_out, ram_out      (Np, LC) int32
     #   phase_out, node_out   (Pp, LC) int32
     #   start_out, park_out   (Pp, LC) float32 (+inf = untouched)
@@ -1704,7 +1859,9 @@ def _select_cycle_commit_kernel(
     # (row 0 assignments of constrained pods, row 1 those with a live domain
     # closed); with the label filters astats_out (8, LC) int32 (row 0 the
     # attempts of pods that name their nodes, row 1 those of them that the
-    # labels and taints alone refused); last the scratches rem (Pp, LC) and
+    # labels and taints alone refused); with soft planes kstats_out (8, LC)
+    # int32 (row 0 the decisions a label scorer had something to normalise
+    # by, row 1 those of them honoured); last the scratches rem (Pp, LC) and
     # live (SMEM, row tiles).
     if spread_shape is not None:
         domain_ref, table_in, limit_ref, zalive_ref, pgroup_ref, pbits_ref = refs[:6]
@@ -1712,11 +1869,16 @@ def _select_cycle_commit_kernel(
     if affinity_terms is not None:
         node_bits_ref, *aside_refs = refs[: affinity_terms + 2]
         refs = refs[affinity_terms + 2 :]
-        astats_out = refs[-3]
+    if kube_terms is not None:
+        cap_refs, kside_refs, refs = _kube_refs(refs, kube_terms)
+    *refs, rem_ref, live_ref = refs
+    if kube_terms:
+        *refs, kstats_out = refs
+    if affinity_terms is not None:
+        astats_out = refs[-1]
     if spread_shape is not None:
         table_out, zone_out, sstats_out = refs[7:10]
     cpu_out, ram_out, phase_out, node_out, start_out, park_out, stats_out = refs[:7]
-    rem_ref, live_ref = refs[-2:]
     """The whole-window scheduling megakernel (VERDICT r3 item 2): queue
     SELECTION (iterated 3-key argmin, _select_cycle_kernel), the
     fit/score/place CYCLE, and the decision COMMIT (the per-pod phase/node/
@@ -1774,6 +1936,10 @@ def _select_cycle_commit_kernel(
         sstats_out[:] = jnp.zeros_like(sstats_out)
     if affinity_terms is not None:
         astats_out[:] = jnp.zeros_like(astats_out)
+    if kube_terms is not None:
+        kube_nodes = integer_nodes(cap_refs[0][:], cap_refs[1][:], profile.units)
+    if kube_terms:
+        kstats_out[:] = jnp.zeros_like(kstats_out)
 
     alive = alive_ref[:] != i0
     iota_n = jax.lax.broadcasted_iota(jnp.int32, alive.shape, 0)
@@ -1802,6 +1968,11 @@ def _select_cycle_commit_kernel(
         # row's value as a maximum over the fill); a lane with nothing left
         # reads it, and is not valid.
         carried += tuple((ref, jnp.int32(-(2**31))) for ref in aside_refs)
+    n_affinity = len(carried) - 3 - n_spread
+    if kube_terms:
+        # And its preferred-term masks (bit 31 again), weights and
+        # untolerated soft taints.
+        carried += tuple((ref, jnp.int32(-(2**31))) for ref in kside_refs)
 
     def body(k, before):
         slot, (rc, rr, waited, *pod_planes) = _select_first(
@@ -1822,10 +1993,13 @@ def _select_cycle_commit_kernel(
             )
         affinity = None
         if affinity_terms is not None:
-            affinity = _affinity_step(node_bits_ref, pod_planes[n_spread:])
-        assign, any_fit, best, new_cpu, new_ram, placed, named = _fit_score_place(
+            affinity = _affinity_step(node_bits_ref, pod_planes[n_spread : n_spread + n_affinity])
+        kube = None
+        if kube_terms is not None:
+            kube = _kube_step(profile, kube_nodes, pod_planes[n_spread + n_affinity :])
+        assign, any_fit, best, new_cpu, new_ram, placed, named, softly = _fit_score_place(
             profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid, spread, affinity,
+            rc, rr, valid, spread, affinity, kube,
         )
         if placed is not None:
             tiles, zbest, constrained, closed = placed
@@ -1835,6 +2009,9 @@ def _select_cycle_commit_kernel(
         if named is not None:
             astats_out[0:1, :] = astats_out[0:1, :] + named[0].astype(jnp.int32)
             astats_out[1:2, :] = astats_out[1:2, :] + named[1].astype(jnp.int32)
+        if softly is not None:
+            kstats_out[0:1, :] = kstats_out[0:1, :] + softly[0].astype(jnp.int32)
+            kstats_out[1:2, :] = kstats_out[1:2, :] + softly[1].astype(jnp.int32)
         cpu_out[:] = new_cpu
         ram_out[:] = new_ram
         park = valid & ~any_fit
@@ -1910,6 +2087,7 @@ def fused_select_cycle_commit(
     profile=None,  # pipeline.CompiledProfile; None = the default profile
     spread=None,  # (domain, counts, limits, zone_alive, pod group, pod bits)
     affinity=None,  # (node_bits, the pods' term planes..., their untolerated taints)
+    kube=None,  # (cap_cpu, cap_ram[, the pods' preferred-term planes..., weights, untolerated soft taints])
 ):
     """Megakernel wrapper: the whole cycle, drained, in one launch. Of the
     three K-shaped operands it reads park_t[:, 0], a cluster's per-pod
@@ -1929,9 +2107,12 @@ def fused_select_cycle_commit(
     the pods' (C, P) workload and match bits) two more follow: the placed node's
     domain a pod, (C, P) int32 with -2 where the cycle placed nothing, and
     the (C, 2) counters (assignments of constrained pods, those with a live
-    domain closed). With `affinity` (_affinity_operands) one more follows,
-    last: the (C, 2) counters (attempts of pods that name their nodes, those
-    of them that the labels and taints alone refused)."""
+    domain closed). With `affinity` (_affinity_operands) one more follows:
+    the (C, 2) counters (attempts of pods that name their nodes, those
+    of them that the labels and taints alone refused). With `kube`
+    (_kube_operands) that holds soft planes one more follows, last: the
+    (C, 2) counters (decisions a label scorer had something to normalise by,
+    those of them honoured)."""
     C, P = eligible.shape
     N = alloc_cpu.shape[0] if nodes_lane_major else alloc_cpu.shape[1]
     K = k_pods
@@ -1975,6 +2156,9 @@ def fused_select_cycle_commit(
         affinity_terms, affinity_args, affinity_in = _affinity_operands(
             affinity, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
         )
+        kube_terms, kube_args, kube_in = _kube_operands(
+            kube, nodes_lane_major, Np, Cp, Pp, node_spec, pod_spec
+        )
     spread_out, spread_shapes = [], []
     if spread is not None:
         spread_out = [table_spec, pod_spec, tile_spec]
@@ -1983,18 +2167,20 @@ def fused_select_cycle_commit(
             jax.ShapeDtypeStruct((Pp, Cp), jnp.int32),
             jax.ShapeDtypeStruct((SPREAD_ZONE_TILE, Cp), jnp.int32),
         ]
-    if affinity is not None:
-        spread_out = spread_out + [stat_spec]
-        spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((8, Cp), jnp.int32)]
+    for stats in (affinity is not None, bool(kube_terms)):
+        if stats:
+            spread_out = spread_out + [stat_spec]
+            spread_shapes = spread_shapes + [jax.ShapeDtypeStruct((8, Cp), jnp.int32)]
     kernel = functools.partial(
-        _select_cycle_commit_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms
+        _select_cycle_commit_kernel, N, K, profile or DEFAULT_PROFILE, spread_shape, affinity_terms,
+        kube_terms,
     )
     with jax.enable_x64(False):
         (cpu_o, ram_o, phase_o, node_o, start_o, park_o, stats_o, *spread_o) = pl.pallas_call(
             kernel,
             name="fused_select_cycle_commit",
             grid=(Cp // _LANE,),
-            in_specs=[node_spec] * 3 + [pod_spec] * 9 + [cand_spec] * 3 + spread_in + affinity_in,
+            in_specs=[node_spec] * 3 + [pod_spec] * 9 + [cand_spec] * 3 + spread_in + affinity_in + kube_in,
             out_specs=[node_spec] * 2 + [pod_spec] * 4 + [stat_spec] + spread_out,
             out_shape=[
                 jax.ShapeDtypeStruct((Np, Cp), jnp.int32),
@@ -2017,10 +2203,11 @@ def fused_select_cycle_commit(
         )(
             alive_p, cpu_p, ram_p, elig_p, qwin_p, qoff_p, qseq_p,
             reqc_p, reqr_p, waited_p, phase_p, node_p,
-            park_p, park_p, park_p, *spread_args, *affinity_args,
+            park_p, park_p, park_p, *spread_args, *affinity_args, *kube_args,
         )
 
     with jax.named_scope("kernel_io"):
+        soft_o = (spread_o.pop()[:2, :C].T,) if kube_terms else ()
         named_o = (spread_o.pop()[:2, :C].T,) if affinity is not None else ()
         return (
             _unprep_node(cpu_o, nodes_lane_major, N, C),
@@ -2032,4 +2219,5 @@ def fused_select_cycle_commit(
             stats_o[:, :C].T,
             *((spread_o[1][:P, :C].T, spread_o[2][:2, :C].T) if spread_o else ()),
             *named_o,
+            *soft_o,
         )

@@ -63,6 +63,24 @@ def test_a_legs_deployment_and_load_are_the_cells_files(cell, config, traffic):
     assert sim_config.scheduling_cycle_interval == dep["scheduling_cycle_interval_s"]
 
 
+def test_the_kubescore_legs_records_carry_the_pools_taints_and_soft_terms():
+    """The one leg whose records are not traffic_gen's bare ones: the cell's
+    own generator and placer (benchmark/kubescore_gen.py, kubescore_program.py)."""
+    dep = _data("configs", "sched1k-kubescore")["deployment"]
+    leg = chip_smoke.leg_inputs("sched1k-kubescore.montecarlo", rehearsed=False, clusters=128)
+    nodes = [event.node for _, event in leg.cluster_events]
+    assert len(nodes) == dep["nodes"] == 1000 and leg.width == 128
+    assert {(n.status.capacity.cpu, n.status.capacity.ram // 1024**3) for n in nodes} == {
+        (p["cpu_millicores"], p["ram_gib"]) for p in dep["pools"]
+    }
+    assert sorted({t.effect for n in nodes for t in n.spec.taints}) == ["NoSchedule", "PreferNoSchedule"]
+    pods = [event.pod for _, event in leg.workload]
+    assert len(pods) == 2000 and leg.config.scheduler_profile == "kube_default"
+    preferring = [p for p in pods if p.spec.node_affinity is not None and p.spec.node_affinity.preferred]
+    assert 0.25 < len(preferring) / len(pods) < 0.35
+    assert {t.weight for p in preferring for t in p.spec.node_affinity.preferred} == {1, 50}
+
+
 def test_a_legs_overrides_replace_one_number_of_the_files():
     """The shapes no cell has: the nodes come from another configuration's
     machine count, the arrivals run longer, the batch is narrower; everything
@@ -85,9 +103,9 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     records = [json.loads(line) for line in lines if line.startswith("{")]
     assert json.loads(lines[-1]) == records[-1]
     assert [r.get("leg") for r in records] == [
-        "start", "pure", "composed", "served", "cli", "faults", "summary", None,
+        "start", "pure", "composed", "served", "cli", "faults", "kubescore", "summary", None,
     ]
-    start, pure, composed, served, cli, faults, summary, result = records
+    start, pure, composed, served, cli, faults, kubescore, summary, result = records
     assert start["cpu_plumbing"] is True
     assert start["device"]["platform"] == "cpu"
     assert os.path.basename(start["compile_cache"]) == ".jax_cache"
@@ -95,7 +113,7 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     # uniform pods: lockstep; no mesh; four clusters: the event loop on its scatter path
     kernels = {"cycle": "candidate", "interpret": True, "ranking": "float32", "events": "scatter", "sharding": None}
     ca_kernels = {**kernels, "ca_up": "kernel", "ca_down": "kernel"}
-    for rec in (pure, composed, served, cli, faults):
+    for rec in (pure, composed, served, cli, faults, kubescore):
         assert rec.pop("wall_s") >= 0
     assert pure == {
         "leg": "pure", "clusters": 4, "nodes": 8, "pods": 128,
@@ -125,10 +143,17 @@ def test_cpu_plumbing_runs_every_leg(capsys):
         "pod_interruptions": 11, "pods_succeeded": 400, "reference": "lax.scan",
         "mismatches": 0,
     }
+    # four machine shapes, preferred terms, a soft-tainted pool: no float ranks a node
+    assert kubescore == {
+        "leg": "kubescore", "clusters": 4, "nodes": 20, "pods": 256,
+        "formulation": {**kernels, "ranking": "integer"}, "decisions": 132,
+        "soft_attempts": 116, "soft_honoured": 96, "reference": "lax.scan",
+        "mismatches": 0,
+    }
     assert summary.pop("wall_s") >= 0
     assert summary == {
         "leg": "summary", "cpu_plumbing": True, "devices_used": 1,
-        "legs": ["pure", "composed", "served", "cli", "faults"], "claim": None,
+        "legs": ["pure", "composed", "served", "cli", "faults", "kubescore"], "claim": None,
     }
     # The chip check reads the last stdout line and takes these keys only.
     assert result == {"ok": True, "device": start["device"]}

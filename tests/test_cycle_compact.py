@@ -121,9 +121,13 @@ CASES = {
 }
 
 
-def single_launch(launch, nodes, eligible, pod_planes, pod_time, spread, n_eligible, K, lane_major, affinity=None):
+def single_launch(
+    launch, nodes, eligible, pod_planes, pod_time, spread, n_eligible, K, lane_major, affinity=None, kube=None
+):
     """The comparison: the wrapper called once on the whole batch."""
-    return launch(nodes, eligible, pod_planes, pod_time, spread, affinity), jnp.zeros(eligible.shape[:1], jnp.bool_)
+    return launch(nodes, eligible, pod_planes, pod_time, spread, affinity, kube), jnp.zeros(
+        eligible.shape[:1], jnp.bool_
+    )
 
 
 def run(case, **kwargs):

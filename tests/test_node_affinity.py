@@ -34,6 +34,7 @@ from kubernetriks_tpu.core.types import (
     NodeSelectorTerm,
     Pod,
     PodConditionType,
+    PreferredSchedulingTerm,
     Taint,
     Toleration,
     TopologySpreadConstraint,
@@ -74,6 +75,7 @@ def _pod(name="p", selector=None, terms=None, tolerations=(), preferred=None, fi
                 for term in terms or []
             ],
             preferred=list(preferred or []),
+            has_required=terms is not None,
         )
     pod.spec.tolerations = [Toleration(*t) for t in tolerations]
     return pod
@@ -343,7 +345,10 @@ def _build(pods, nodes=None, config=None):
         (_pod(terms=[[("cores", "Gt", ["4"])]]), "operator Gt"),
         (_pod(terms=[[("cores", "Lt", ["4"])]]), "operator Lt"),
         (_pod(terms=[[("pool", "In", ["a"])]], fields=[{"key": "metadata.name", "operator": "In", "values": ["n"]}]), "matchFields"),
-        (_pod(preferred=[{"weight": 1, "preference": {}}]), "preferredDuringScheduling"),
+        (
+            _pod(preferred=[PreferredSchedulingTerm(1, NodeSelectorTerm([NodeSelectorRequirement("pool", "In", ["a"])]))]),
+            "preferredDuringScheduling",
+        ),
         (_pod(terms=[[("pool", "In", [])]]), "In without values"),
         (_pod(terms=[]), "without nodeSelectorTerms"),
         (_pod(tolerations=[("dedicated", "Equal", "batch", "NoExecute")]), "effect NoExecute"),

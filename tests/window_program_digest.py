@@ -1,7 +1,8 @@
 """Digests of the window programs the accepted benchmark cells' rehearsal
 builds lower, debug locations stripped: the yardstick of "this PR did not
 change what a cell compiles". Imports nothing newer than the PR that added
-the cell (PR 47 for `sched1k-pools.montecarlo`, PR 43 for
+the cell (PR 50 for `sched1k-kubescore.montecarlo`, PR 47 for
+`sched1k-pools.montecarlo`, PR 43 for
 `sched1k-faults.montecarlo`, PR 41 for `sched1k-backlog.bursts`, PR 37 for
 `sched1k-spread.montecarlo`, PR 33 for the six before it), so the same file
 runs on an older checkout over the cells that checkout has:
@@ -36,6 +37,7 @@ CELLS = [
     "sched1k.saturated",
     "sched1k-faults.montecarlo",
     "sched1k-pools.montecarlo",
+    "sched1k-kubescore.montecarlo",
 ]
 
 # `loc(...)` trailers and `#loc` lines: where in the source an op was traced.
@@ -88,7 +90,10 @@ def rehearsal_engine(cell_name: str):
         return program.build_engine(
             config_text, compiled, resettable=True, mesh=mesh, **batch_jobs._engine_kwargs(cell)
         )
-    if driver in ("batch_jobs_labelled", "batch_jobs_bursts", "batch_jobs_faults", "batch_jobs_pools"):
+    if driver in (
+        "batch_jobs_labelled", "batch_jobs_bursts", "batch_jobs_faults", "batch_jobs_pools",
+        "batch_jobs_kubescore",
+    ):
         import importlib
 
         from benchmark.drivers import batch_jobs
@@ -132,7 +137,8 @@ def digest(cell_name: str) -> str:
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    digests = {cell: digest(cell) for cell in CELLS}
+    have = {w["name"] for w in json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))["workloads"]}
+    digests = {cell: digest(cell) for cell in CELLS if cell in have}
     print(json.dumps(digests, indent=1))
     if "--write" in sys.argv:
         with open(DIGESTS, "w") as fh:
