@@ -53,8 +53,12 @@ Legs:
   runs it alone.
 - kubescore: `sched1k-kubescore.montecarlo`'s pools, labels, taints and
   classes (benchmark/kubescore_gen.py) on one lane tile of clusters, ranked
-  as kube-scheduler ranks (`ranking: integer`: the one leg whose argmax is
-  not a float32 compare), the megakernel against the lax.scan engine.
+  as kube-scheduler ranks (`ranking: integer`), the megakernel against the
+  lax.scan engine.
+- pools: `sched1k-pools.montecarlo`'s four machine shapes, tainted pool and
+  classes (benchmark/pools_gen.py) on one lane tile of clusters, ranked by
+  the exact key (`ranking: exact`), the megakernel against the lax.scan
+  engine. With kubescore the two legs whose argmax is not a float32 compare.
 """
 
 from __future__ import annotations
@@ -98,6 +102,7 @@ CHIP_LEGS = dict(
     faults=({"clusters": 256}, "megakernel", "kernel"),
     # One lane tile: the integer chain is a lane's own.
     kubescore=({"clusters": 128}, "megakernel"),
+    pools=({"clusters": 128}, "megakernel"),
 )
 PLUMBING_LEGS = dict(
     pure=[({}, "candidate")],
@@ -107,7 +112,17 @@ PLUMBING_LEGS = dict(
     cli_clusters=2,
     faults=({}, "candidate", "scatter"),
     kubescore=({"clusters": 4}, "candidate"),
+    pools=({"clusters": 4}, "candidate"),
 )
+
+
+# The drivers whose records are not traffic_gen's bare ones: their generator
+# and the module that puts a record's labels, taints and terms on the
+# program's objects.
+RECORDS_OF = {
+    "batch_jobs_pools": ("pools_gen", "pools_program"),
+    "batch_jobs_kubescore": ("kubescore_gen", "kubescore_program"),
+}
 
 
 class Leg(NamedTuple):
@@ -162,13 +177,14 @@ def leg_inputs(cell_name: str, rehearsed: bool, clusters=None, nodes=None, horiz
     if horizon_s is not None:
         traffic["plain"]["horizon_s"] = horizon_s
     api, gen, place = program.program_api(), traffic_gen, ()
-    if traffic["driver"] == "batch_jobs_kubescore":
+    if traffic["driver"] in RECORDS_OF:
         # Records with labels, taints and placements, and how they go onto
         # the program's objects.
-        from benchmark import kubescore_gen as gen, kubescore_program
+        import importlib
 
-        api = kubescore_program.program_api()
-        place = (kubescore_program.placer(api),)
+        gen, records_program = (importlib.import_module("benchmark." + m) for m in RECORDS_OF[traffic["driver"]])
+        api = records_program.program_api()
+        place = (records_program.placer(api),)
     config = api.SimulationConfig.from_yaml(deployment.config_yaml(cell.config_name, dep))
     seed = int(traffic.get("base_workload_seed", SEED))
     cluster_events = gen.to_events(gen.cluster_records(dep), api, *place)
@@ -273,32 +289,36 @@ def faults_leg(shape, run, rehearsed) -> dict:
     )
 
 
-def kubescore_leg(shape, run, rehearsed) -> dict:
-    """kube-scheduler's integer scores through the dense kernel set against
-    the lax.scan engine: four machine shapes, preferred terms and a
-    PreferNoSchedule pool, no float in the ranking."""
+def ranked_leg(name: str, ranking: str, counters, shape, run, rehearsed) -> dict:
+    """A node-pool cell (`sched1k-<name>.montecarlo`: four machine shapes,
+    labels, a tainted pool, pods placed by class) through the dense kernel
+    set against the lax.scan engine, where no float32 compare ranks a node:
+    the exact key (pools), kube-scheduler's integer scores over preferred
+    terms and a PreferNoSchedule pool (kubescore). `counters`: the cell's
+    pair of label counters, the second a part of the first."""
     from kubernetriks_tpu.batched.state import compare_states
 
     overrides, cycle = shape
     t0 = time.perf_counter()
-    leg = leg_inputs("sched1k-kubescore.montecarlo", rehearsed, **overrides)
+    leg = leg_inputs(f"sched1k-{name}.montecarlo", rehearsed, **overrides)
     sim = leg.build(leg.width, **leg.forced())
     ref = leg.build(leg.width, use_pallas=False)
     for s in (sim, ref):
         s.step_until_time(run["warm_until"])
         s.step_until_time(run["warm_until"] + run["chunk"])
     formulation = sim.kernel_formulation()
-    assert formulation["cycle"] == cycle and formulation["ranking"] == "integer", formulation
+    assert formulation["cycle"] == cycle and formulation["ranking"] == ranking, formulation
     decisions = int(np.asarray(sim.state.metrics.scheduling_decisions).sum())
     sim.metrics_summary()
     report = sim.telemetry_report()["counters"]
-    assert 0 < report["soft_honoured"] <= report["soft_attempts"], report
+    whole, part = counters
+    assert 0 <= report[part] <= report[whole] and report[whole] > 0, report
     mismatches = compare_states(ref.state, sim.state)
     assert not mismatches, mismatches
     return emit(
-        "kubescore", t0, clusters=leg.width, nodes=sim.n_nodes, pods=sim.n_pods,
+        name, t0, clusters=leg.width, nodes=sim.n_nodes, pods=sim.n_pods,
         formulation=formulation, decisions=decisions,
-        soft_attempts=report["soft_attempts"], soft_honoured=report["soft_honoured"],
+        **{whole: report[whole], part: report[part]},
         reference="lax.scan", mismatches=0,
     )
 
@@ -476,7 +496,7 @@ def main(argv=None) -> int:
         "JAX has: debugs this script, proves nothing about the chip",
     )
     parser.add_argument(
-        "--only", choices=("pure", "composed", "served", "cli", "faults", "kubescore"),
+        "--only", choices=("pure", "composed", "served", "cli", "faults", "kubescore", "pools"),
         help="run this leg alone (one chip)",
     )
     args = parser.parse_args(argv)
@@ -549,8 +569,12 @@ def main(argv=None) -> int:
             legs.append(cli_leg(shapes["cli_clusters"]))
         if wanted("faults"):
             legs.append(faults_leg(shapes["faults"], shapes["pure_run"], rehearsed))
-        if wanted("kubescore"):
-            legs.append(kubescore_leg(shapes["kubescore"], shapes["pure_run"], rehearsed))
+        for name, ranking, counters in (
+            ("kubescore", "integer", ("soft_attempts", "soft_honoured")),
+            ("pools", "exact", ("affinity_attempts", "affinity_attempts_refused")),
+        ):
+            if wanted(name):
+                legs.append(ranked_leg(name, ranking, counters, shapes[name], shapes["pure_run"], rehearsed))
 
     print(
         json.dumps(

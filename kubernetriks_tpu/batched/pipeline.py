@@ -770,20 +770,44 @@ def soft_raw_scores(soft: SoftFacts):
     return affinity, taints
 
 
-def _normalized(raw, fit, axis: int, reverse: bool):
+def _normalized(raw, fit, axis: int, reverse: bool, most=None):
     """(upstream's DefaultNormalizeScore over the nodes in `fit`, the largest
-    raw score there): raw as its share of the largest, in whole points."""
+    raw score there): raw as its share of the largest, in whole points.
+    `most`: that largest, from a caller that holds it already."""
     i0, hundred = jnp.int32(0), jnp.int32(100)
-    most = jnp.max(jnp.where(fit, raw, i0), axis=axis, keepdims=True)
+    if most is None:
+        most = jnp.max(jnp.where(fit, raw, i0), axis=axis, keepdims=True)
     share = jnp.where(most > i0, floor_quotient(raw * hundred, most, biased_reciprocal(most)), i0)
     return (hundred - share if reverse else share), most
 
 
-def integer_scores(profile: CompiledProfile, fit, cpu, ram, rc, rr, nodes: IntegerNodes, soft, axis: int):
+def soft_normalised(profile: CompiledProfile, soft: SoftFacts) -> Tuple[bool, bool]:
+    """Which of (NodeAffinity, TaintToleration) integer_scores normalises in
+    a build with these soft planes: the profile scores by it and the planes
+    hold something for it. Reads the statics of `soft` alone."""
+    scored = dict(profile.scores)
+    return NODE_AFFINITY in scored and bool(soft.terms), TAINT_TOLERATION in scored and soft.n_taints > 0
+
+
+def soft_feasible_raws(fit, soft: SoftFacts):
+    """soft_raw_scores with 0 off the fit set: what _normalized reduces over
+    the node axis to each scorer's `most`. The kernels, which meet the nodes
+    a row block at a time, fold these into running maxima in a sweep of their
+    own and hand integer_scores the two as `mosts`."""
+    i0 = jnp.int32(0)
+    return tuple(None if raw is None else jnp.where(fit, raw, i0) for raw in soft_raw_scores(soft))
+
+
+def integer_scores(
+    profile: CompiledProfile, fit, cpu, ram, rc, rr, nodes: IntegerNodes, soft, axis: int, mosts=(None, None)
+):
     """(total, soft part, soft attempt): the profile's weighted integer score,
     -1 off the fit set; of it the label scorers' part (None without `soft`,
     the build's SoftFacts or None) and whether either had something to
-    normalise by (M > 0) for this candidate."""
+    normalise by (M > 0) for this candidate. `mosts` = (NodeAffinity's,
+    TaintToleration's) largest raw score over the feasible nodes where the
+    caller has reduced them already (soft_feasible_raws), else they are
+    reduced here along `axis`."""
     i0, hundred = jnp.int32(0), jnp.int32(100)
     weights = {name: jnp.int32(int(weight)) for name, weight in profile.scores}
     free_cpu = to_units(cpu - rc, profile.units[0])
@@ -811,11 +835,12 @@ def integer_scores(profile: CompiledProfile, fit, cpu, ram, rc, rr, nodes: Integ
     part = attempt = None
     if soft is not None:
         raw_affinity, raw_taints = soft_raw_scores(soft)
-        if NODE_AFFINITY in weights and raw_affinity is not None:
-            score, most = _normalized(raw_affinity, fit, axis, reverse=False)
+        by_affinity, by_taints = soft_normalised(profile, soft)
+        if by_affinity:
+            score, most = _normalized(raw_affinity, fit, axis, reverse=False, most=mosts[0])
             part, attempt = add(part, NODE_AFFINITY, score), most > i0
-        if TAINT_TOLERATION in weights and raw_taints is not None:
-            score, most = _normalized(raw_taints, fit, axis, reverse=True)
+        if by_taints:
+            score, most = _normalized(raw_taints, fit, axis, reverse=True, most=mosts[1])
             part = add(part, TAINT_TOLERATION, score)
             attempt = most > i0 if attempt is None else attempt | (most > i0)
     if part is not None:

@@ -14,7 +14,8 @@ count, which lax.scan formulations cannot express.
   src/core/scheduler/plugin.rs:33-63) + last-wins argmax
   (kube_scheduler.rs:140-150) must see the allocatable updates of pods
   0..k-1; the node tile stays pinned in VMEM across the loop (one HBM
-  round-trip per cycle instead of K). The profile is a kernel static —
+  round-trip per cycle instead of K) and a step reads it in row blocks
+  (_fit_score_place, "node row blocks"). The profile is a kernel static —
   each profile compiles its own kernel, selected at engine build.
 - `_select_cycle_kernel` (fused_select_schedule_cycle): the same loop with
   candidate EXTRACTION in-kernel via an iterated per-lane lexicographic
@@ -37,7 +38,7 @@ against the lax.scan engine in chip_smoke.py.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 from kubernetriks_tpu.batched.pipeline import (
     DEFAULT_PROFILE,
     SPREAD_ZONE_TILE,
+    IntegerNodes,
     NodeFacts,
     SoftFacts,
     affinity_names_nodes,
@@ -61,8 +63,9 @@ from kubernetriks_tpu.batched.pipeline import (
     integer_nodes,
     integer_scores,
     profile_fit_mask,
-    soft_honoured,
-    profile_fit_score,
+    profile_score,
+    soft_feasible_raws,
+    soft_normalised,
     spread_alive_tile,
     spread_node_mask,
     spread_place,
@@ -264,10 +267,10 @@ def _affinity_operands(affinity, nodes_lane_major: bool, Np: int, Cp: int, side_
 
 
 def _affinity_step(node_bits_ref, side):
-    """The `affinity` argument of _fit_score_place: the node plane and the
-    candidate's (1, LC) term masks and untolerated-taint mask."""
+    """The `affinity` argument of _fit_score_place: the node plane's ref and
+    the candidate's (1, LC) term masks and untolerated-taint mask."""
     *terms, forbid = side
-    return node_bits_ref[:], terms, forbid
+    return node_bits_ref, terms, forbid
 
 
 def _kube_operands(kube, nodes_lane_major: bool, Np: int, Cp: int, side_rows: int, node_spec, side_spec):
@@ -301,14 +304,15 @@ def _kube_refs(refs, kube_terms):
     return refs[:2], refs[2 : 2 + n_side], refs[2 + n_side :]
 
 
-def _kube_step(profile, nodes, side):
-    """The `kube` argument of _fit_score_place: the launch's IntegerNodes and
-    the candidate's (1, LC) preferred-term masks, weights and untolerated
-    soft taints (none in a build without soft planes)."""
+def _kube_step(profile, nodes_at, side):
+    """The `kube` argument of _fit_score_place: the launch's IntegerNodes as
+    _with_integer_nodes hands them, and the candidate's (1, LC)
+    preferred-term masks, weights and untolerated soft taints (none in a
+    build without soft planes)."""
     if not side:
-        return nodes, None
+        return nodes_at, None
     *terms, weights, forbid = side
-    return nodes, (tuple(terms), weights, forbid, profile.soft_taints)
+    return nodes_at, (tuple(terms), weights, forbid, profile.soft_taints)
 
 
 def _soft_flags(softly):
@@ -318,8 +322,132 @@ def _soft_flags(softly):
     return attempt.astype(jnp.int32) + jnp.int32(2) * honoured.astype(jnp.int32)
 
 
+# --- node row blocks: what a step of a decision kernel sweeps on the node side
+#
+# A step reads the (Np, LC) node tile a block of rows at a time, the way the
+# pod side reads its live row tiles. Every elementwise plane of the filter
+# chain and the score then has a block's few vregs, which the register file
+# holds, where a plane of the whole tile (Np / 8 vregs against a file of 64)
+# was written to VMEM and read back once an intermediate (PERF.md section 6,
+# PR 51). What a step reduces over the node axis (the best node, whether any
+# node fits, the integer scorers' M) it carries through the sweep as ONE vreg a
+# word: row r competes with the rows congruent to it mod 8, elementwise, as
+# _select_first's pod rows do, and the 8 survivors of a lane are reduced once
+# a sweep with the reducers the scan path applies to the whole axis. The sweep
+# visits rows in rising order and a later row wins a tie, so the survivor of
+# a position is its HIGHEST-slot best and the reduction's last-max-wins finds
+# the whole tile's: the decisions are a whole-tile sweep's bit for bit.
+#
+# Block height: read off the tile, not a knob. The decide sweep takes 40 rows
+# (5 vregs a plane): what the static schedule of the exact key's body still
+# holds in registers without a spill. The place sweep keeps no intermediate
+# (a load, a compare, a subtraction, a store), so it takes 200 rows and pays
+# the loop's branch a fifth as often. A tile the height does not divide takes
+# the largest multiple of 8 under it that does (no row is seen twice: the
+# place sweep is not idempotent), a tile no taller than it is one block.
+_DECIDE_ROWS = 40
+_PLACE_ROWS = 200
+
+
+def _node_block(n_rows: int, most: int = _DECIDE_ROWS) -> int:
+    """Rows a block of a node tile of n_rows sublane-padded rows, `most` at
+    most."""
+    if n_rows <= most:
+        return n_rows
+    return max(b for b in range(_SUB, most + 1, _SUB) if n_rows % b == 0)
+
+
+def _sweep_node_blocks(n_rows: int, block: int, body, init=None):
+    """body(first row of the block, carry) -> carry, over the tile's blocks
+    of `block` rows in rising order."""
+    if block == n_rows:
+        return body(0, init)
+    return _while_i32(
+        jnp.int32(0),
+        jnp.int32(n_rows // block),
+        lambda b, c: body(pl.multiple_of(b * jnp.int32(block), _SUB), c),
+        init,
+    )
+
+
+def _row_groups(x):
+    """A block's plane as its (8, LC) row groups, one vreg each."""
+    return [x[g * _SUB : (g + 1) * _SUB, :] for g in range(x.shape[0] // _SUB)]
+
+
+def _fold_max(run, plane):
+    """The running (8, LC) maximum `run` with a block's int32 plane folded in."""
+    for group in _row_groups(plane):
+        run = jnp.maximum(run, group)
+    return run
+
+
+def _fold_best(run, words, ok, carried, least: bool):
+    """The running best row a (sublane, lane) position with a block folded in.
+    `run` = (the best's rank words..., its carried values...), (8, LC) each;
+    `words` the block's rank words, compared lexicographically, the least
+    winning if `least` else the largest; `ok` which rows may win at all;
+    `carried` the planes whose value at the best row rides along (its slot
+    first). A row that ties the best so far takes its place: rows come in
+    rising order, so the later one is the higher slot."""
+    n = len(words)
+    oks = [None] * (words[0].shape[0] // _SUB) if ok is None else _row_groups(ok)
+    for fine, *new in zip(oks, *(_row_groups(x) for x in (*words, *carried))):
+        take = None
+        for w, r in zip(reversed(new[:n]), reversed(run[:n])):
+            ahead = (w < r) if least else (w > r)
+            take = (ahead | (w == r)) if take is None else (ahead | ((w == r) & take))
+        if fine is not None:
+            take = take & fine
+        run = tuple(jnp.where(take, x, r) for x, r in zip(new, run))
+    return run
+
+
+def _with_integer_nodes(profile, cap_refs, run) -> None:
+    """run(nodes_at) with the launch's IntegerNodes made once and held in
+    VMEM for its steps: nodes_at(first row, rows) reads a block of the six
+    planes. The planes are what the *_fits gates count as the integer
+    scorers' node blocks beside the two capacities (_kube_blocks); they are
+    allocated here, in the kernel's own scope, so no pallas_call lists them.
+    `cap_refs` None (a build that ranks otherwise): run(None)."""
+    if cap_refs is None:
+        return run(None)
+    cap_cpu_ref, cap_ram_ref = cap_refs
+    n_rows, lanes = cap_cpu_ref.shape
+    block = _node_block(n_rows)
+
+    def scoped(*refs):
+        def fill(start, _):
+            rows = pl.ds(start, block)
+            nodes = integer_nodes(cap_cpu_ref[rows, :], cap_ram_ref[rows, :], profile.units)
+            for ref, plane in zip(refs, nodes):
+                ref[rows, :] = plane
+
+        _sweep_node_blocks(n_rows, block, fill)
+        run(lambda start, rows: IntegerNodes(*(ref[pl.ds(start, rows), :] for ref in refs)))
+
+    pl.run_scoped(
+        scoped, *(pltpu.VMEM((n_rows, lanes), dtype) for dtype in (jnp.int32,) * 3 + (jnp.float32,) * 3)
+    )
+
+
+class _NodeBlock(NamedTuple):
+    """A block of the node planes and one candidate's masks over it; None
+    what the build has not."""
+
+    slots: jnp.ndarray  # (B, LC) the rows' node slots
+    ok: Optional[jnp.ndarray]  # which of them are real nodes; None: all
+    cpu: jnp.ndarray
+    ram: jnp.ndarray
+    fit: jnp.ndarray  # the whole filter chain
+    rest: Optional[jnp.ndarray]  # the chain without the two label filters
+    domain: Optional[jnp.ndarray]
+    soft: Optional[SoftFacts]
+
+
 def _fit_score_place(
-    profile, alive, node_ok, iota_n, cpu, ram, rc, rr, valid, spread=None, affinity=None, kube=None
+    profile, n_nodes: int, alive_ref, cpu_ref, ram_ref, rc, rr, valid, spread=None, affinity=None, kube=None,
+    one_block: bool = False,
 ):
     """ONE in-kernel definition of the per-candidate decision core shared by
     _cycle_kernel, _select_cycle_kernel and _select_cycle_commit_kernel:
@@ -331,13 +459,27 @@ def _fit_score_place(
     update for the placed node. `profile` is a kernel STATIC (a
     pipeline.CompiledProfile closed over via functools.partial); its
     expressions inline into the kernel body like the shape statics do.
-    Inputs: (Np, LC) node tiles, (1, LC) candidate requests/validity.
-    Returns (assign (1, LC) bool, any_fit (1, LC) bool, best (1, LC) i32,
-    new_cpu (Np, LC), new_ram (Np, LC), placed, named, softly): the last
-    three None without `spread` / `affinity` / soft planes.
+    Inputs: the (Np, LC) node tiles as REFS (alive, and the two allocatables,
+    which the core reads and updates in place), the count of real nodes,
+    (1, LC) candidate requests/validity. Returns (assign (1, LC) bool, any_fit
+    (1, LC) bool, best (1, LC) i32, placed, named, softly): the last three
+    None without `spread` / `affinity` / soft planes.
+
+    What a step sweeps: the node tile in row blocks (see "node row blocks"
+    above), twice. The DECIDE sweep loads a block of every node plane, makes
+    its fit mask and score with the scan path's own elementwise functions
+    and folds them into the running best; the PLACE sweep subtracts the
+    candidate's requests at the chosen row and stores the two allocatables a
+    block. The integer scorers decide in two sweeps: the label scorers
+    normalise by their largest raw score over the feasible nodes (M), so one
+    sweep makes the two M and the next, with the raws made again, ranks.
+    Nothing of (Np, LC) shape is a value: the widest is a block. `one_block`
+    makes the whole tile the one block of every sweep, for the caller whose
+    step is a single basic block that this arithmetic has to share
+    (_select_cycle_kernel).
 
     `spread` (a build whose pods are held to topology-spread constraints;
-    pipeline.spread_*) = (domain (Np, LC) node plane, the count table's G
+    pipeline.spread_*) = (the domain node plane's ref, the count table's G
     tiles, the limits' G tiles, zone_alive (8, LC) bool, n_domains, the
     candidate's workload and match bits (1, LC)): the SECOND thing the core
     carries across a cycle's placements. The table is read by this
@@ -346,81 +488,178 @@ def _fit_score_place(
     assigned & a live domain was closed).
 
     `affinity` (a build with a taint, a selector, an affinity or a
-    toleration; pipeline.affinity_node_masks) = (node_bits (Np, LC) node
-    plane, the candidate's term masks, its untolerated-taint mask, (1, LC)
+    toleration; pipeline.affinity_node_masks) = (the node_bits node plane's
+    ref, the candidate's term masks, its untolerated-taint mask, (1, LC)
     each). Nothing is carried: `named` = (the candidate names its nodes and
     is valid, and of those: no node passed the chain although a live node
     passed every filter but the two label filters), (1, LC) bool each.
 
     `kube` (a build whose profile scores in integers, as kube-scheduler
-    does; pipeline.integer_scores) = (the launch's IntegerNodes, and in a
-    build with soft planes the candidate's preferred-term masks, packed
-    weights, untolerated soft taints (1, LC) and the static count of soft
-    taint bits; `affinity` is then given: its node plane holds the bits).
-    The one scoring chain with reductions over the node axis before the
-    argmax. `softly` = (the candidate is valid and a label scorer had
-    something to normalise by, and of those: it went to a node with the
-    largest label score among the feasible ones), (1, LC) bool each."""
+    does; pipeline.integer_scores) = (a function from a block's rows to its
+    IntegerNodes, and in a build with soft planes the candidate's
+    preferred-term masks, packed weights, untolerated soft taints (1, LC)
+    and the static count of soft taint bits; `affinity` is then given: its
+    node plane holds the bits). `softly` = (the candidate is valid and a
+    label scorer had something to normalise by, and of those: it went to a
+    node with the largest label score among the feasible ones), (1, LC) bool
+    each."""
     i0 = jnp.int32(0)
     neg1 = jnp.int32(-1)
+    n_rows, lanes = cpu_ref.shape
+    block = n_rows if one_block else _node_block(n_rows)
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (block, lanes), 0)
 
-    facts = None
+    def vreg(fill):
+        return jnp.full((_SUB, lanes), fill)
+
     if spread is not None:
-        domain, tiles, limits, zone_alive, n_domains, group, bits = spread
-        zone_ok, constrained, closed = spread_zone_ok(tiles, limits, zone_alive, group, bits)
-        facts = NodeFacts(spread_ok=spread_node_mask(domain, zone_ok, constrained, n_domains))
+        domain_ref, tiles, limits, zone_alive, n_domains, workload, bits = spread
+        zone_ok, constrained, closed = spread_zone_ok(tiles, limits, zone_alive, workload, bits)
     if affinity is not None:
-        node_bits, terms, forbid = affinity
-        rest = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
-        affinity_ok, taints_ok = affinity_node_masks(node_bits, terms, forbid)
-        facts = (facts or NodeFacts())._replace(affinity_ok=affinity_ok, taints_ok=taints_ok)
-    part = None
+        node_bits_ref, terms, forbid = affinity
+    nodes_at, soft_side = kube if kube is not None else (None, None)
+
+    def load(start) -> _NodeBlock:
+        rows = pl.ds(start, block)
+        slots = iota_b + start
+        alive = alive_ref[rows, :] != i0
+        cpu, ram = cpu_ref[rows, :], ram_ref[rows, :]
+        facts = rest = domain = soft = None
+        if spread is not None:
+            domain = domain_ref[rows, :]
+            facts = NodeFacts(spread_ok=spread_node_mask(domain, zone_ok, constrained, n_domains))
+        if affinity is not None:
+            node_bits = node_bits_ref[rows, :]
+            rest = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
+            affinity_ok, taints_ok = affinity_node_masks(node_bits, terms, forbid)
+            facts = (facts or NodeFacts())._replace(affinity_ok=affinity_ok, taints_ok=taints_ok)
+            if soft_side is not None:
+                soft = SoftFacts(node_bits, *soft_side)
+        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
+        # Padded sublanes are never real nodes.
+        ok = None if n_nodes == n_rows else slots < jnp.int32(n_nodes)
+        return _NodeBlock(slots, ok, cpu, ram, fit, rest, domain, soft)
+
+    # The integer scorers' first sweep: the label scorers' M, and whether
+    # either has something to normalise by.
+    mosts, soft_attempt = (None, None), None
+    if soft_side is not None:
+        normalised = soft_normalised(profile, SoftFacts(None, *soft_side))
+
+        def most_block(start, run):
+            at = load(start)
+            raws = soft_feasible_raws(at.fit, at.soft)
+            return tuple(_fold_max(m, raw) if wanted else None for wanted, m, raw in zip(normalised, run, raws))
+
+        run = _sweep_node_blocks(n_rows, block, most_block, tuple(vreg(i0) if wanted else None for wanted in normalised))
+        mosts = tuple(None if m is None else jnp.max(m, axis=0, keepdims=True) for m in run)
+        for most in mosts:
+            if most is not None:
+                soft_attempt = most > i0 if soft_attempt is None else soft_attempt | (most > i0)
+    ranks_softly = soft_attempt is not None
+
+    # The decide sweep. `run` = the best row's rank words, its slot, and what
+    # else of it the step reads afterwards (its domain, its label score).
     if kube is not None:
-        nodes, soft_side = kube
-        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
-        soft = None if soft_side is None else SoftFacts(affinity[0], *soft_side)
-        total, part, soft_attempt = integer_scores(profile, fit, cpu, ram, rc, rr, nodes, soft, axis=0)
-        best = integer_best_node(total, node_ok, iota_n, axis=0)
+        least, words0 = False, (vreg(neg1),)
     elif profile.exact_bits:
-        fit = profile_fit_mask(profile, alive, cpu, ram, rc, rr, facts)
-        hi, lo = exact_least_allocated_key(fit, cpu, ram, rc, rr, profile.exact_bits)
-        best = exact_best_node(hi, lo, node_ok, iota_n, axis=0)
+        least, words0 = True, (vreg(jnp.int32(2**31 - 1)),) * 2
     else:
-        fit, score = profile_fit_score(profile, alive, cpu, ram, rc, rr, facts)
-        max_score = jnp.max(score, axis=0, keepdims=True)
-        best = jnp.max(
-            jnp.where((score == max_score) & node_ok, iota_n, neg1),
-            axis=0,
-            keepdims=True,
-        )
-    # any() lowers to an i1 reduction Mosaic rejects; reduce in i32. Padded
-    # slots never fit (alive is 0 there).
-    any_fit = jnp.max(fit.astype(jnp.int32), axis=0, keepdims=True) > i0
+        least, words0 = False, (vreg(jnp.float32(_NEG_INF)),)
+    n_words = len(words0)
+    rides = 1 + int(spread is not None) + int(ranks_softly)  # the slot, the domain, the label score
+
+    def decide_block(start, carry):
+        run, any_fit, any_rest, most_part = carry
+        at = load(start)
+        carried = [at.slots] + ([at.domain] if spread is not None else [])
+        if kube is not None:
+            total, part, _ = integer_scores(
+                profile, at.fit, at.cpu, at.ram, rc, rr, nodes_at(start, block), at.soft, axis=0, mosts=mosts
+            )
+            words = (total,)
+            if ranks_softly:
+                carried.append(part)
+                most_part = _fold_max(most_part, jnp.where(at.fit, part, neg1))
+        elif profile.exact_bits:
+            words = exact_least_allocated_key(at.fit, at.cpu, at.ram, rc, rr, profile.exact_bits)
+        else:
+            words = (profile_score(profile, at.fit, at.cpu, at.ram, rc, rr),)
+        run = _fold_best(run, words, at.ok, carried, least)
+        # any() lowers to an i1 reduction Mosaic rejects; reduce in i32. Padded
+        # slots never fit (alive is 0 there).
+        any_fit = _fold_max(any_fit, at.fit.astype(jnp.int32))
+        if affinity is not None:
+            any_rest = _fold_max(any_rest, at.rest.astype(jnp.int32))
+        return run, any_fit, any_rest, most_part
+
+    run, any_fit, any_rest, most_part = _sweep_node_blocks(
+        n_rows,
+        block,
+        decide_block,
+        (
+            words0 + (vreg(neg1),) * rides,
+            vreg(i0),
+            vreg(i0) if affinity is not None else None,
+            vreg(neg1) if ranks_softly else None,
+        ),
+    )
+    # The 8 survivors of a lane, by the reducers of the whole axis.
+    rank, slots, rode = run[:n_words], run[n_words], list(run[n_words + 1 :])
+    held = slots >= i0
+    if kube is not None:
+        best = integer_best_node(*rank, held, slots, axis=0)
+    elif profile.exact_bits:
+        best = exact_best_node(*rank, held, slots, axis=0)
+    else:
+        max_score = jnp.max(rank[0], axis=0, keepdims=True)
+        best = jnp.max(jnp.where((rank[0] == max_score) & held, slots, neg1), axis=0, keepdims=True)
+    any_fit = jnp.max(any_fit, axis=0, keepdims=True) > i0
     assign = valid & any_fit
-    upd = assign & (iota_n == best)
-    new_cpu = cpu - jnp.where(upd, rc, i0)
-    new_ram = ram - jnp.where(upd, rr, i0)
+
+    # The place sweep: the chosen row of a lane gives up the requests.
+    wide = n_rows if one_block else _node_block(n_rows, _PLACE_ROWS)
+    iota_w = jax.lax.broadcasted_iota(jnp.int32, (wide, lanes), 0)
+
+    def place_block(start, _):
+        rows = pl.ds(start, wide)
+        upd = assign & ((iota_w + start) == best)
+        cpu_ref[rows, :] = cpu_ref[rows, :] - jnp.where(upd, rc, i0)
+        ram_ref[rows, :] = ram_ref[rows, :] - jnp.where(upd, rr, i0)
+
+    _sweep_node_blocks(n_rows, wide, place_block)
+
+    def of_placed(plane):
+        """What the chosen node's row holds of a plane that rode with the
+        best, -1 where the candidate went nowhere."""
+        at_best = jnp.max(jnp.where(slots == best, plane, neg1), axis=0, keepdims=True)
+        return jnp.where(assign, at_best, neg1)
+
     placed = named = softly = None
-    if part is not None:
-        soft_attempt = valid & soft_attempt
-        softly = (soft_attempt, soft_attempt & assign & soft_honoured(part, fit, upd, axis=0))
     if spread is not None:
-        zbest = jnp.max(jnp.where(upd, domain, neg1), axis=0, keepdims=True)
+        zbest = of_placed(rode.pop(0))
         placed = (spread_place(tiles, zbest, assign, bits), zbest, assign & constrained, assign & closed)
+    if ranks_softly:
+        # pipeline.soft_honoured over the tile: the largest label score among
+        # the feasible nodes against the chosen node's.
+        got = of_placed(rode.pop(0))
+        soft_attempt = valid & soft_attempt
+        honoured = (got == jnp.max(most_part, axis=0, keepdims=True)) & (got >= i0)
+        softly = (soft_attempt, soft_attempt & assign & honoured)
     if affinity is not None:
         attempt = valid & affinity_names_nodes(forbid)
-        any_rest = jnp.max(rest.astype(jnp.int32), axis=0, keepdims=True) > i0
+        any_rest = jnp.max(any_rest, axis=0, keepdims=True) > i0
         named = (attempt, attempt & ~any_fit & any_rest)
-    return assign, any_fit, best, new_cpu, new_ram, placed, named, softly
+    return assign, any_fit, best, placed, named, softly
 
 
 def _spread_step(refs, n_workloads: int, n_domains: int, group, bits):
     """The `spread` argument of _fit_score_place from a kernel's refs
-    (domain, the carried table, limits, live domains) and the candidate's
-    workload and bits."""
+    (domain, which the core reads a block of rows at a time; the carried
+    table, limits, live domains) and the candidate's workload and bits."""
     domain_ref, table_ref, limit_ref, zalive_ref = refs
     return (
-        domain_ref[:],
+        domain_ref,
         _spread_tiles(table_ref, n_workloads),
         _spread_tiles(limit_ref, n_workloads),
         zalive_ref[:] != jnp.int32(0),
@@ -502,9 +741,6 @@ def _cycle_kernel(
 
     cpu_out[:] = alloc_cpu_ref[:]
     ram_out[:] = alloc_ram_ref[:]
-    alive = alive_ref[:] != i0  # (Np, LC)
-    iota = jax.lax.broadcasted_iota(jnp.int32, alive.shape, 0)
-    node_ok = iota < jnp.int32(n_real)  # padded sublanes are never real nodes
 
     # Outputs must be fully initialized even for skipped iterations.
     assign_out[:] = jnp.zeros_like(assign_out)
@@ -516,8 +752,6 @@ def _cycle_kernel(
         sflag_out[:] = jnp.zeros_like(sflag_out)
     if affinity_terms is not None:
         aflag_out[:] = jnp.zeros_like(aflag_out)
-    if kube_terms is not None:
-        kube_nodes = integer_nodes(cap_refs[0][:], cap_refs[1][:], profile.units)
     if kube_terms:
         kflag_out[:] = jnp.zeros_like(kflag_out)
 
@@ -531,7 +765,7 @@ def _cycle_kernel(
     k_live = jnp.max(jnp.where(valid_ref[:] != i0, iota_k + jnp.int32(1), i0))
     k_bound = jnp.minimum(k_live, jnp.int32(k_pods))
 
-    def body(k):
+    def body(k, nodes_at):
         req_cpu = req_cpu_ref[pl.ds(k, 1), :]  # (1, LC) int32
         req_ram = req_ram_ref[pl.ds(k, 1), :]
         valid = valid_ref[pl.ds(k, 1), :] != i0
@@ -547,10 +781,9 @@ def _cycle_kernel(
             affinity = _affinity_step(node_bits_ref, [ref[pl.ds(k, 1), :] for ref in aside_refs])
         kube = None
         if kube_terms is not None:
-            kube = _kube_step(profile, kube_nodes, [ref[pl.ds(k, 1), :] for ref in kside_refs])
-        assign, any_fit, best, new_cpu, new_ram, placed, named, softly = _fit_score_place(
-            profile, alive, node_ok, iota, cpu_out[:], ram_out[:],
-            req_cpu, req_ram, valid, spread, affinity, kube,
+            kube = _kube_step(profile, nodes_at, [ref[pl.ds(k, 1), :] for ref in kside_refs])
+        assign, any_fit, best, placed, named, softly = _fit_score_place(
+            profile, n_real, alive_ref, cpu_out, ram_out, req_cpu, req_ram, valid, spread, affinity, kube
         )
         if placed is not None:
             _spread_store_decision(table_out, zbest_out, sflag_out, k, placed)
@@ -558,8 +791,6 @@ def _cycle_kernel(
             aflag_out[pl.ds(k, 1), :] = _named_flags(named)
         if softly is not None:
             kflag_out[pl.ds(k, 1), :] = _soft_flags(softly)
-        cpu_out[:] = new_cpu
-        ram_out[:] = new_ram
         assign_out[pl.ds(k, 1), :] = assign.astype(jnp.int32)
         fitany_out[pl.ds(k, 1), :] = any_fit.astype(jnp.int32)
         best_out[pl.ds(k, 1), :] = best
@@ -567,11 +798,14 @@ def _cycle_kernel(
     # An explicit i32-carried while loop: with jax_enable_x64 on, fori_loop
     # canonicalizes its induction variable to i64, which Mosaic cannot return
     # from the loop-body region.
-    def loop_body(k):
-        body(k)
-        return k + jnp.int32(1)
+    def run(nodes_at):
+        def loop_body(k):
+            body(k, nodes_at)
+            return k + jnp.int32(1)
 
-    jax.lax.while_loop(lambda k: k < k_bound, loop_body, jnp.int32(0))
+        jax.lax.while_loop(lambda k: k < k_bound, loop_body, jnp.int32(0))
+
+    _with_integer_nodes(profile, cap_refs if kube_terms is not None else None, run)
 
 
 # The selection kernel asks Mosaic for a raised scoped-VMEM limit; its
@@ -669,9 +903,6 @@ def _select_cycle_kernel(
 
     cpu_out[:] = alloc_cpu_ref[:]
     ram_out[:] = alloc_ram_ref[:]
-    alive = alive_ref[:] != i0
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, alive.shape, 0)
-    node_ok = iota_n < jnp.int32(n_nodes)
 
     cand_out[:] = jnp.zeros_like(cand_out)
     valid_out[:] = jnp.zeros_like(valid_out)
@@ -685,8 +916,6 @@ def _select_cycle_kernel(
         sflag_out[:] = jnp.zeros_like(sflag_out)
     if affinity_terms is not None:
         aflag_out[:] = jnp.zeros_like(aflag_out)
-    if kube_terms is not None:
-        kube_nodes = integer_nodes(cap_refs[0][:], cap_refs[1][:], profile.units)
     if kube_terms:
         kflag_out[:] = jnp.zeros_like(kflag_out)
 
@@ -695,7 +924,7 @@ def _select_cycle_kernel(
     depth = jnp.max(jnp.sum(elig_ref[:], axis=0, keepdims=True))
     k_bound = jnp.minimum(depth, jnp.int32(k_pods))
 
-    def body(k):
+    def body(k, nodes_at):
         rem = rem_ref[:] != i0  # (Pp, LC)
         # Per-lane lexicographic argmin over (win, off-bits, seq).
         w = jnp.where(rem, qwin_ref[:], bigi)
@@ -732,12 +961,16 @@ def _select_cycle_kernel(
         kube = None
         if kube_terms is not None:
             kube = _kube_step(
-                profile, kube_nodes,
+                profile, nodes_at,
                 [jnp.sum(jnp.where(sel, ref[:], i0), axis=0, keepdims=True) for ref in kside_refs],
             )
-        assign, any_fit, best, new_cpu, new_ram, placed, named, softly = _fit_score_place(
-            profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid, spread, affinity, kube,
+        # One block: this step is ONE basic block whose pod side, whole-block
+        # sweeps bound by loads and stores, leaves most VALU slots idle, and
+        # the node side's arithmetic fills them only from inside that block; in
+        # loops of its own it ran after it (PERF.md section 6, PR 51: - 4.0%
+        # in sched1k.saturated).
+        assign, any_fit, best, placed, named, softly = _fit_score_place(
+            profile, n_nodes, alive_ref, cpu_out, ram_out, rc, rr, valid, spread, affinity, kube, one_block=True
         )
         if placed is not None:
             _spread_store_decision(table_out, zbest_out, sflag_out, k, placed)
@@ -745,8 +978,6 @@ def _select_cycle_kernel(
             aflag_out[pl.ds(k, 1), :] = _named_flags(named)
         if softly is not None:
             kflag_out[pl.ds(k, 1), :] = _soft_flags(softly)
-        cpu_out[:] = new_cpu
-        ram_out[:] = new_ram
         cand_out[pl.ds(k, 1), :] = jnp.where(valid, slot, i0)
         valid_out[pl.ds(k, 1), :] = valid.astype(jnp.int32)
         assign_out[pl.ds(k, 1), :] = assign.astype(jnp.int32)
@@ -754,11 +985,14 @@ def _select_cycle_kernel(
         best_out[pl.ds(k, 1), :] = best
         rem_ref[:] = jnp.where(sel, i0, rem_ref[:])
 
-    def loop_body(k):
-        body(k)
-        return k + i1
+    def run(nodes_at):
+        def loop_body(k):
+            body(k, nodes_at)
+            return k + i1
 
-    jax.lax.while_loop(lambda k: k < k_bound, loop_body, jnp.int32(0))
+        jax.lax.while_loop(lambda k: k < k_bound, loop_body, jnp.int32(0))
+
+    _with_integer_nodes(profile, cap_refs if kube_terms is not None else None, run)
 
 
 @functools.partial(
@@ -1892,8 +2126,11 @@ def _select_cycle_commit_kernel(
     two requests and waited to pick the lane's next pod with its values
     (_select_first), and one pass writing phase/node/start/park/rem where
     the row is the chosen slot. The node side (_fit_score_place) sweeps the
-    whole (Np, LC) tile as before. The copies and +inf fills before the
-    loop are whole-block, once a launch. stats_out rows 5/6 report, per
+    (Np, LC) tile in row blocks too, every one of them (any node may be the
+    best): a decide sweep that reads a block of each node plane and folds it
+    into the running best, a place sweep that writes the two allocatables
+    back (the integer scorers' decide half is two sweeps). The copies and
+    +inf fills before the loop are whole-block, once a launch. stats_out rows 5/6 report, per
     lane of the program: live tiles x steps (the row tiles swept) and steps
     — the ring's cycle_rows_swept_share, over the block's tiles.
 
@@ -1936,14 +2173,9 @@ def _select_cycle_commit_kernel(
         sstats_out[:] = jnp.zeros_like(sstats_out)
     if affinity_terms is not None:
         astats_out[:] = jnp.zeros_like(astats_out)
-    if kube_terms is not None:
-        kube_nodes = integer_nodes(cap_refs[0][:], cap_refs[1][:], profile.units)
     if kube_terms:
         kstats_out[:] = jnp.zeros_like(kstats_out)
 
-    alive = alive_ref[:] != i0
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, alive.shape, 0)
-    node_ok = iota_n < jnp.int32(n_nodes)
     rem_ref[:] = elig_ref[:]
     k_bound = jnp.max(jnp.sum(elig_ref[:], axis=0, keepdims=True))
     k_table = jnp.int32(k_pods)
@@ -1974,7 +2206,7 @@ def _select_cycle_commit_kernel(
         # untolerated soft taints.
         carried += tuple((ref, jnp.int32(-(2**31))) for ref in kside_refs)
 
-    def body(k, before):
+    def body(k, before, nodes_at):
         slot, (rc, rr, waited, *pod_planes) = _select_first(
             n_live,
             live_ref,
@@ -1996,10 +2228,9 @@ def _select_cycle_commit_kernel(
             affinity = _affinity_step(node_bits_ref, pod_planes[n_spread : n_spread + n_affinity])
         kube = None
         if kube_terms is not None:
-            kube = _kube_step(profile, kube_nodes, pod_planes[n_spread + n_affinity :])
-        assign, any_fit, best, new_cpu, new_ram, placed, named, softly = _fit_score_place(
-            profile, alive, node_ok, iota_n, cpu_out[:], ram_out[:],
-            rc, rr, valid, spread, affinity, kube,
+            kube = _kube_step(profile, nodes_at, pod_planes[n_spread + n_affinity :])
+        assign, any_fit, best, placed, named, softly = _fit_score_place(
+            profile, n_nodes, alive_ref, cpu_out, ram_out, rc, rr, valid, spread, affinity, kube
         )
         if placed is not None:
             tiles, zbest, constrained, closed = placed
@@ -2012,8 +2243,6 @@ def _select_cycle_commit_kernel(
         if softly is not None:
             kstats_out[0:1, :] = kstats_out[0:1, :] + softly[0].astype(jnp.int32)
             kstats_out[1:2, :] = kstats_out[1:2, :] + softly[1].astype(jnp.int32)
-        cpu_out[:] = new_cpu
-        ram_out[:] = new_ram
         park = valid & ~any_fit
 
         # COMMIT: the chosen slot's row is the scatter mask (slot -1, a
@@ -2054,11 +2283,14 @@ def _select_cycle_commit_kernel(
 
         return after
 
-    def loop_body(carry):
-        k, before = carry
-        return k + i1, body(k, before)
+    def run(nodes_at):
+        def loop_body(carry):
+            k, before = carry
+            return k + i1, body(k, before, nodes_at)
 
-    jax.lax.while_loop(lambda carry: carry[0] < k_bound, loop_body, (i0, jnp.zeros_like(pst)))
+        jax.lax.while_loop(lambda carry: carry[0] < k_bound, loop_body, (i0, jnp.zeros_like(pst)))
+
+    _with_integer_nodes(profile, cap_refs if kube_terms is not None else None, run)
 
 
 @functools.partial(

@@ -81,6 +81,26 @@ def test_the_kubescore_legs_records_carry_the_pools_taints_and_soft_terms():
     assert {t.weight for p in preferring for t in p.spec.node_affinity.preferred} == {1, 50}
 
 
+def test_the_pools_legs_records_carry_the_pools_taint_and_hard_terms():
+    """The leg that runs the exact key on the chip outside the benchmark: the
+    pools cell's own generator and placer (benchmark/pools_gen.py,
+    pools_program.py), requests that do not move in lockstep with the four
+    machine shapes."""
+    dep = _data("configs", "sched1k-pools")["deployment"]
+    leg = chip_smoke.leg_inputs("sched1k-pools.montecarlo", rehearsed=False, clusters=128)
+    nodes = [event.node for _, event in leg.cluster_events]
+    assert len(nodes) == dep["nodes"] == 1000 and leg.width == 128
+    assert {(n.status.capacity.cpu, n.status.capacity.ram // 1024**3) for n in nodes} == {
+        (p["cpu_millicores"], p["ram_gib"]) for p in dep["pools"]
+    }
+    assert {t.effect for n in nodes for t in n.spec.taints} == {"NoSchedule"}
+    pods = [event.pod for _, event in leg.workload]
+    assert leg.config.scheduler_profile == "node_pools"
+    assert any(p.spec.node_selector for p in pods) and any(p.spec.tolerations for p in pods)
+    assert any(p.spec.node_affinity is not None and p.spec.node_affinity.required_terms for p in pods)
+    assert len({(p.spec.resources.requests.cpu, p.spec.resources.requests.ram) for p in pods}) > 1
+
+
 def test_a_legs_overrides_replace_one_number_of_the_files():
     """The shapes no cell has: the nodes come from another configuration's
     machine count, the arrivals run longer, the batch is narrower; everything
@@ -103,9 +123,9 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     records = [json.loads(line) for line in lines if line.startswith("{")]
     assert json.loads(lines[-1]) == records[-1]
     assert [r.get("leg") for r in records] == [
-        "start", "pure", "composed", "served", "cli", "faults", "kubescore", "summary", None,
+        "start", "pure", "composed", "served", "cli", "faults", "kubescore", "pools", "summary", None,
     ]
-    start, pure, composed, served, cli, faults, kubescore, summary, result = records
+    start, pure, composed, served, cli, faults, kubescore, pools, summary, result = records
     assert start["cpu_plumbing"] is True
     assert start["device"]["platform"] == "cpu"
     assert os.path.basename(start["compile_cache"]) == ".jax_cache"
@@ -113,7 +133,7 @@ def test_cpu_plumbing_runs_every_leg(capsys):
     # uniform pods: lockstep; no mesh; four clusters: the event loop on its scatter path
     kernels = {"cycle": "candidate", "interpret": True, "ranking": "float32", "events": "scatter", "sharding": None}
     ca_kernels = {**kernels, "ca_up": "kernel", "ca_down": "kernel"}
-    for rec in (pure, composed, served, cli, faults, kubescore):
+    for rec in (pure, composed, served, cli, faults, kubescore, pools):
         assert rec.pop("wall_s") >= 0
     assert pure == {
         "leg": "pure", "clusters": 4, "nodes": 8, "pods": 128,
@@ -150,10 +170,17 @@ def test_cpu_plumbing_runs_every_leg(capsys):
         "soft_attempts": 116, "soft_honoured": 96, "reference": "lax.scan",
         "mismatches": 0,
     }
+    # the same pools under hard terms alone, requests out of lockstep: the exact key ranks
+    assert pools == {
+        "leg": "pools", "clusters": 4, "nodes": 20, "pods": 256,
+        "formulation": {**kernels, "ranking": "exact"}, "decisions": 180,
+        "affinity_attempts": 104, "affinity_attempts_refused": 0, "reference": "lax.scan",
+        "mismatches": 0,
+    }
     assert summary.pop("wall_s") >= 0
     assert summary == {
         "leg": "summary", "cpu_plumbing": True, "devices_used": 1,
-        "legs": ["pure", "composed", "served", "cli", "faults", "kubescore"], "claim": None,
+        "legs": ["pure", "composed", "served", "cli", "faults", "kubescore", "pools"], "claim": None,
     }
     # The chip check reads the last stdout line and takes these keys only.
     assert result == {"ok": True, "device": start["device"]}
